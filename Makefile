@@ -76,7 +76,8 @@
 #                       tier-1 line deselects `kernels`; a bare
 #                       ROADMAP tier-1 run still includes them).
 #                       Mosaic lowering itself is TPU-gated
-#                       (runtime/verify.py, tpu_run.sh A/B step).
+#                       (runtime/verify.py; tests/test_tpu_lowering.py
+#                       compiles for a described v5e without a chip).
 #   make verify-sharded — the ICI-sharded SERVING path (ISSUE 12):
 #                       `sharded`-marked tests on the forced
 #                       8-host-device CPU mesh (< 60 s): steered-ring
@@ -187,7 +188,7 @@ verify-wire:
 	timeout -k 10 60 env JAX_PLATFORMS=cpu \
 	$(PY) -m pytest tests/test_wire_pump.py $(PYTEST_FLAGS) \
 	  -m 'wire and not slow' \
-	&& timeout -k 10 120 env JAX_PLATFORMS=cpu BNG_BENCH_PROBE_WINDOW=0 \
+	&& timeout -k 10 120 env JAX_PLATFORMS=cpu \
 	  BNG_BENCH_TIMEOUT=90 BNG_BENCH_LOG=/tmp/_wire_ab.jsonl \
 	  BNG_WIRE_AB_BATCH=1024 BNG_BENCH_LAT_STEPS=10 \
 	  $(PY) bench.py --wire-ab \
@@ -242,7 +243,7 @@ verify-kernels:
 	$(PY) -m pytest tests/ $(PYTEST_FLAGS) \
 	  -m 'kernels and not slow' \
 	&& timeout -k 10 30 $(PY) -m bng_tpu.analysis --select gather \
-	&& timeout -k 10 180 env JAX_PLATFORMS=cpu BNG_BENCH_PROBE_WINDOW=0 \
+	&& timeout -k 10 180 env JAX_PLATFORMS=cpu \
 	  BNG_BENCH_TIMEOUT=150 $(PY) bench.py --autotune --dry-run \
 	| $(PY) -c "import json,sys; \
 	r=json.loads([l for l in sys.stdin if l.startswith('{')][-1]); \

@@ -43,13 +43,22 @@ class BNGConfig:
     # device Engine — tables hash-sharded over the mesh, the ring
     # classifier steering popped batches to owner shards, checkpoints/
     # blue-green swap/chaos audit all sharded-aware. On a machine with
-    # no accelerator the mesh is CPU-virtual (forced host device count,
-    # the tier-1 posture); set JAX_PLATFORMS=tpu to use real chips.
+    # the mesh is the first N attached devices (fewer is an error); under
+    # JAX_PLATFORMS=cpu it is CPU-virtual (forced host device count, the
+    # tier-1 posture).
     # batch_size is the AGGREGATE batch (split evenly across shards).
     shards: int = 1
     # per-shard table geometry for the sharded path (buckets per cuckoo
     # table; sized for the per-shard subscriber slice)
     shard_nbuckets: int = 1 << 10
+    # single-device table capacities, in entries (0 = the table class's
+    # own default, which is what every test builds). Each becomes a
+    # bucket count through ops.table.nbuckets_for (~50% load, 4-way,
+    # power of two). The reference ships 1M-entry subscriber and QoS
+    # maps (bpf/maps.h:10) and 4M NAT sessions (bpf/nat44.c:38-40).
+    max_subscribers: int = 0  # subscriber/VLAN/circuit-ID, QoS, antispoof, garden
+    max_nat_sessions: int = 0  # NAT session + reverse tables
+    max_nat_subscribers: int = 0  # subscriber -> port-block table
     # latency-tiered scheduler (runtime/scheduler.py): express DHCP lane +
     # depth-pipelined bulk lane instead of the monolithic pipelined loop
     scheduler_enabled: bool = False
@@ -237,6 +246,17 @@ class BNGConfig:
     node_id: str = "bng0"
 
 
+def _sized(entries: int, *bucket_args: str) -> dict:
+    """Constructor kwargs that size each named bucket count for
+    `entries` keys; {} (the table's own default) when the config left
+    the capacity unset."""
+    if not entries:
+        return {}
+    from bng_tpu.ops.table import nbuckets_for
+
+    return dict.fromkeys(bucket_args, nbuckets_for(entries))
+
+
 def pppoe_sid(sess) -> str:
     """One Acct-Session-Id format for a PPPoE session — shared by
     accounting start/stop, the CoA locator, and HA replication keys
@@ -385,16 +405,24 @@ class BNGApp:
         # whose wiring is engine-specific degrade with a warning
         # (tracked in sharded_blockers, exported like fleet_blockers).
         self.sharded_blockers: list[str] = []
+        if cfg.shards > 1 and _os.environ.get(
+                "JAX_PLATFORMS", "").lower() == "cpu":
+            # asked for the CPU (tier-1 posture): force the host-device
+            # mesh BEFORE any backend init (XLA_FLAGS
+            # --xla_force_host_platform_device_count). Otherwise the
+            # mesh is the attached devices, and fewer than N is an error
+            # (parallel/sharded.py make_mesh).
+            from bng_tpu.utils.jaxenv import force_cpu
+
+            force_cpu(cfg.shards)
+        # persistent compile cache, before the first compile and after
+        # the CPU mesh is forced (the helper initialises the backend)
+        from bng_tpu.utils.jaxenv import enable_compilation_cache
+
+        cache_dir = enable_compilation_cache()
+        if cache_dir:
+            self.log.info("compile cache", dir=cache_dir)
         if cfg.shards > 1:
-            import os as _sh_os
-
-            if "tpu" not in _sh_os.environ.get("JAX_PLATFORMS", "").lower():
-                # CPU tier-1 posture: force the host-device mesh BEFORE
-                # any backend init (XLA_FLAGS
-                # --xla_force_host_platform_device_count)
-                from bng_tpu.utils.jaxenv import force_cpu
-
-                force_cpu(cfg.shards)
             from bng_tpu.parallel.sharded import (ShardedCluster,
                                                   ShardedFastPathSink)
 
@@ -437,13 +465,16 @@ class BNGApp:
                           batch_per_shard=cluster.b,
                           nbuckets=cfg.shard_nbuckets)
         else:
-            fastpath = c["fastpath"] = FastPathTables()
+            fastpath = c["fastpath"] = FastPathTables(**_sized(
+                cfg.max_subscribers,
+                "sub_nbuckets", "vlan_nbuckets", "cid_nbuckets"))
         fastpath.set_server_config(
             parse_mac(cfg.server_mac),
             ip_to_u32(cfg.server_ip))
 
         # 2. antispoof + walled garden (main.go:509-564)
-        c["antispoof"] = AntispoofTables()
+        c["antispoof"] = AntispoofTables(
+            **_sized(cfg.max_subscribers, "nbuckets"))
         if cfg.walled_garden_enabled:
             garden = c["walledgarden"] = wg.WalledGardenManager(
                 wg.WalledGardenConfig(portal_ip=cfg.portal_ip,
@@ -641,7 +672,8 @@ class BNGApp:
                 return profile
 
         # 6. QoS (main.go:977-995)
-        qos = None if cfg.shards > 1 else QoSTables()
+        qos = None if cfg.shards > 1 else QoSTables(
+            **_sized(cfg.max_subscribers, "nbuckets"))
         if qos is not None:
             c["qos"] = qos
         policies = c["policies"] = PolicyManager()
@@ -685,10 +717,14 @@ class BNGApp:
             nat = c["nat"] = NATManager(
                 public_ips=[ip_to_u32(ip) for ip in cfg.nat_public_ips],
                 ports_per_subscriber=cfg.nat_ports_per_subscriber,
-                log_sink=nat_logger.log_device_event)
+                log_sink=nat_logger.log_device_event,
+                **_sized(cfg.max_nat_sessions, "sessions_nbuckets"),
+                **_sized(cfg.max_nat_subscribers, "sub_nat_nbuckets"))
             def nat_hook(ip, now):
                 nat.allocate_nat(ip, int(now))
         else:
+            # NAT off: the engine still threads a NAT table set, so it
+            # gets the smallest one — no capacity applies to it
             nat = NATManager(public_ips=[ip_to_u32("203.0.113.1")],
                              sessions_nbuckets=256, sub_nat_nbuckets=64)
 
@@ -777,7 +813,8 @@ class BNGApp:
         if cfg.walled_garden_enabled and cfg.shards <= 1:
             from bng_tpu.runtime.engine import GardenTables
 
-            garden_tables = GardenTables()
+            garden_tables = GardenTables(
+                **_sized(cfg.max_subscribers, "nbuckets"))
         pppoe_tables = None
         if cfg.pppoe_enabled and cfg.shards <= 1:
             from bng_tpu.runtime.tables import PPPoEFastPathTables
@@ -1440,7 +1477,16 @@ class BNGApp:
             # registered — shard i's batch region holds shard i's
             # subscribers and the common case never punts. AF_XDP attach
             # is an engine-path feature for now (sharded_blockers).
-            ring = c["ring"] = c["cluster"].make_ring(frame_size=2048)
+            # sized from the batch: a shard's region of one window must
+            # fit the ring's depth, and two windows stay in flight (the
+            # default 1024/4096 refused --batch-size 8192 over 4 shards)
+            b = c["cluster"].b
+            depth = max(1024, 1 << (b - 1).bit_length())
+            nframes = max(4096, 1 << (4 * depth * cfg.shards - 1).bit_length())
+            ring = c["ring"] = c["cluster"].make_ring(
+                nframes=nframes, frame_size=2048, depth=depth)
+            self.log.info("sharded ring built", ring=type(ring).__name__,
+                          depth=depth, nframes=nframes)
             self._on_close(ring.close)
             self._on_close(lambda: c["cluster"].flush_pipeline(
                 self._slow_path))
@@ -1469,6 +1515,7 @@ class BNGApp:
                                     pump_path=cfg.wire_pump or None)
             c["wire_attachment"] = att
             self.log.info("wire attach", mode=att.mode,
+                          ring=type(ring).__name__,
                           interface=cfg.wire_if or "(none)",
                           detail=att.detail)
             if cfg.wire_if and att.mode == xsk_mod.MODE_MEMORY:
@@ -2247,8 +2294,10 @@ def run_loadtest(args) -> int:
     net = ipaddress.ip_network(args.pool_cidr)
     server_ip = int(net.network_address + 1)
     server_mac = parse_mac("02:aa:bb:cc:dd:01")
+    from bng_tpu.ops.table import nbuckets_for
+
     # size the subscriber table for the MAC working set at <50% load
-    sub_nb = 1 << max(10, (args.macs // 2).bit_length())
+    sub_nb = nbuckets_for(args.macs)
     # update_slots must cover a full warmup batch of inserts per step or
     # the device cache lags the host table and renewals miss spuriously
     fastpath = FastPathTables(sub_nbuckets=sub_nb, vlan_nbuckets=1 << 10,

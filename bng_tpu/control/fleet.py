@@ -844,7 +844,7 @@ class SlowPathFleet:
         return worker
 
     def _spawn_one(self, i: int) -> tuple:
-        """(process, conn) for worker slot i — caller owns the telemetry
+        """(process, conn) for worker slot i — caller owns the child
         env window (see _spawn_workers)."""
         parent, child = self._mp_ctx.Pipe(duplex=True)
         p = self._mp_ctx.Process(target=_worker_main,
@@ -855,37 +855,43 @@ class SlowPathFleet:
         child.close()
         return p, parent
 
-    class _telemetry_env:
-        """Children build their own per-frame latency histograms only
-        when the parent traces — env is the only channel that survives
-        both spawn and fork. Set ONLY around the worker starts and
-        restored after: a leaked BNG_TELEMETRY=1 would force-arm every
-        later BNGApp in this process and make every later fleet's
-        workers pay armed per-frame costs forever."""
+    class _child_env:
+        """The environment worker children start with. Env is the only
+        channel that survives both spawn and fork; it is set ONLY around
+        the worker starts and restored after.
+
+        - JAX_PLATFORMS=cpu: the parent holds the chip, and a chip
+          belongs to one process. `spawn` re-imports __main__ (and
+          bng_tpu with it) in the child; pinned to the CPU backend, no
+          child can ever initialise the accelerator.
+        - BNG_TELEMETRY=1 when the parent traces, so children build
+          their own per-frame latency histograms. A leaked flag would
+          force-arm every later BNGApp in this process and make every
+          later fleet's workers pay armed per-frame costs forever."""
 
         def __enter__(self):
-            self.was = os.environ.get("BNG_TELEMETRY")
-            self.set = tele.enabled()
-            if self.set:
-                os.environ["BNG_TELEMETRY"] = "1"
+            want = {"JAX_PLATFORMS": "cpu"}
+            if tele.enabled():
+                want["BNG_TELEMETRY"] = "1"
+            self.was = {k: os.environ.get(k) for k in want}
+            os.environ.update(want)
             return self
 
         def __exit__(self, *exc):
             # every child inherited its env at start(); restore ours even
-            # when a spawn fails mid-loop (a leaked armed flag outlives
-            # this fleet, per the warning above)
-            if self.set:
-                if self.was is None:
-                    os.environ.pop("BNG_TELEMETRY", None)
+            # when a spawn fails mid-loop
+            for k, v in self.was.items():
+                if v is None:
+                    os.environ.pop(k, None)
                 else:
-                    os.environ["BNG_TELEMETRY"] = self.was
+                    os.environ[k] = v
 
     def _spawn_workers(self) -> None:
         """Build a fresh worker set for the CURRENT self.n."""
         if self.mode == "inline":
             self._inline = [self._make_inline(i) for i in range(self.n)]
             return
-        with self._telemetry_env():
+        with self._child_env():
             for i in range(self.n):
                 p, conn = self._spawn_one(i)
                 self._procs.append(p)
@@ -1653,7 +1659,7 @@ class SlowPathFleet:
             if self.mode == "inline":
                 self._inline[w] = self._make_inline(w)
             else:
-                with self._telemetry_env():
+                with self._child_env():
                     p, conn = self._spawn_one(w)
                 self._procs[w], self._conns[w] = p, conn
             self._dead.discard(w)

@@ -33,22 +33,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU memory spaces; unavailable on CPU-only jaxlib (interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except (ImportError, NotImplementedError):  # pragma: no cover - env specific
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 LANE_TILE = 256  # rows per grid cell; [256, 256] eq tiles feed the MXU
 SUBLANES = 8  # Mosaic tiling: rank>=2 blocks need (8k, 128m) trailing dims
 
 
 def _block(shape, index_map):
-    if _VMEM is not None:
-        return pl.BlockSpec(shape, index_map, memory_space=_VMEM)
-    return pl.BlockSpec(shape, index_map)
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _seg_kernel(slot_i_ref, slot_j_ref, vec_ref, pref_ref, tot_ref,

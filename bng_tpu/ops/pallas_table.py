@@ -39,8 +39,13 @@ Layout notes (Mosaic tiling wants (8k, 128m) trailing dims):
 Interpret-mode caveats (PERF_NOTES §13): on every non-TPU backend the
 kernel runs under `interpret=True` — same semantics, executed by the
 Pallas interpreter — so the whole tier-1 suite exercises the kernel
-without hardware. Mosaic lowering is only proven by the TPU gate
-(runtime/verify.py `table_lookup[pallas]`, tpu_run.sh A/B step).
+without hardware. Mosaic lowering is a separate question, and today the
+answer is NO: compiled for a described v5e
+(tests/test_tpu_lowering.py), Mosaic refuses the per-lane row DMAs —
+"Slice shape along dimension 1 must be aligned to tiling (128), but is
+32": a packed [NB, WAYS*KW] probe row is 32 words, a quarter of a lane
+tile. The kernel needs a 128-word row layout before it can run on a
+chip (ROADMAP D11).
 
 Impl selection lives in ops/table.py (`BNG_TABLE_IMPL=xla|pallas|auto`,
 the qos_kernel[sort|pallas] mold); this module is only the kernel.
@@ -55,18 +60,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _ANY = pltpu.ANY
-except (ImportError, NotImplementedError):  # pragma: no cover - env specific
-    # Even interpret mode needs pltpu (PrefetchScalarGridSpec, VMEM
-    # scratch, DMA descriptors) — without it the kernel cannot run in
-    # ANY mode. pallas_probe raises a clear error; the selector default
-    # ("xla") means such jaxlibs simply never take this path.
-    pltpu = None
-    _ANY = None
+from jax.experimental.pallas import tpu as pltpu
 
 from bng_tpu.ops.hashing import SEED1, SEED2, hash_words
 
@@ -164,18 +158,22 @@ def _probe_kernel(idx_ref, krows_ref, vals_ref, qw_ref, stash_ref, svals_ref,
         sm = stash_ref[K, :][None, :] != 0  # (1, SP) used row
         for k in range(K):
             sm = sm & (qws[k][:, None] == stash_ref[k, :][None, :])
-        cum = jnp.cumsum(sm.astype(jnp.int32), axis=1)
-        sfirst = sm & (cum == 1)  # first stash match per lane
-        found_s = jnp.any(sm, axis=1)
-        sidx = jnp.sum(jnp.where(
-            sfirst, jax.lax.broadcasted_iota(jnp.int32, (T, SP), 1), 0),
-            axis=1)
+        # first stash match per lane = the lowest matching stash index
+        # (a lane-min; Mosaic has no cumsum lowering)
+        lane_i = jax.lax.broadcasted_iota(jnp.int32, (T, SP), 1)
+        sidx = jnp.min(jnp.where(sm, lane_i, np.int32(SP)), axis=1)
+        sfirst = sm & (lane_i == sidx[:, None])
+        found_s = sidx < SP
         sbase = np.int32(nbuckets * WAYS)
         slot = jnp.where(found_b, slot,
                          jnp.where(found_s, sbase + sidx, 0))
         for v in range(V):
-            sval = jnp.sum(jnp.where(sfirst, svals_ref[v, :][None, :],
-                                     np.uint32(0)), axis=1, dtype=jnp.uint32)
+            # one-hot sum in int32 (Mosaic reduces no unsigned ints);
+            # the bitcast round-trip keeps every uint32 word exact
+            srow = jax.lax.bitcast_convert_type(svals_ref[v, :], jnp.int32)
+            sval = jax.lax.bitcast_convert_type(
+                jnp.sum(jnp.where(sfirst, srow[None, :], np.int32(0)),
+                        axis=1), jnp.uint32)
             vcols[v] = jnp.where(found_b, vcols[v],
                                  jnp.where(found_s, sval, 0))
         found = found_b | found_s
@@ -241,8 +239,8 @@ def _probe_jit(krows, stash_rows, vals, query, nbuckets, stash, interpret):
         num_scalar_prefetch=1,
         grid=(nt,),
         in_specs=[
-            pl.BlockSpec(memory_space=_ANY),  # krows stay in HBM
-            pl.BlockSpec(memory_space=_ANY),  # vals stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # krows stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # vals stay in HBM
             pl.BlockSpec((K, 1, SUBLANES, T), lambda i, idx_ref: (0, i, 0, 0)),
             pl.BlockSpec((KP, SP), lambda i, idx_ref: (0, 0)),
             pl.BlockSpec((VR, SP), lambda i, idx_ref: (0, 0)),
@@ -283,12 +281,6 @@ def pallas_probe(krows: jax.Array, stash_rows: jax.Array, vals: jax.Array,
     every other backend runs the Pallas interpreter (ADVICE r1: a GPU
     backend must not try to compile the Mosaic kernel).
     """
-    if pltpu is None:  # pragma: no cover - env specific
-        raise RuntimeError(
-            "pallas TPU support unavailable in this jaxlib "
-            "(jax.experimental.pallas.tpu failed to import) — the fused "
-            "table probe cannot run even in interpret mode; use "
-            "BNG_TABLE_IMPL=xla")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _probe_jit(krows, stash_rows, vals, query, nbuckets, stash,

@@ -45,10 +45,12 @@ MAX_KICKS = 128  # bounded cuckoo eviction walk (host side)
 #   "pallas" — the fused probe kernel (ops.pallas_table); on CPU it runs
 #              in interpret mode (tests), on TPU compiled via Mosaic
 #   "auto"   — self-timed: bench.py races both post-compile and pins the
-#              winner (set_auto_choice); until resolved, pallas on TPU
-#              and xla elsewhere
+#              winner (set_auto_choice); until raced, xla on every
+#              backend — the TPU compiler refuses the pallas kernel
+#              today (tests/test_tpu_lowering.py keeps the strict xfail;
+#              ROADMAP D11), and auto must never pick what cannot compile
 # Default from BNG_TABLE_IMPL; "xla" until the pallas path has been
-# timed on hardware (flip to "auto" once it wins — PERF_NOTES §13).
+# timed on hardware (PERF_NOTES §13).
 TABLE_IMPL = os.environ.get("BNG_TABLE_IMPL", "xla")
 
 TABLE_IMPLS = ("xla", "pallas")
@@ -88,11 +90,8 @@ def resolved_table_impl() -> str:
         return _FORCED[-1]
     impl = TABLE_IMPL
     if impl == "auto":
-        if _AUTO_CHOICE is not None:
-            return _AUTO_CHOICE
-        # Mosaic lowering is TPU-only; un-raced auto favors the kernel
-        # there and the known-good cascade everywhere else
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        # un-raced auto is the cascade that compiles everywhere
+        return _AUTO_CHOICE if _AUTO_CHOICE is not None else "xla"
     if impl not in TABLE_IMPLS:
         raise ValueError(
             f"BNG_TABLE_IMPL={impl!r}: expected one of "
@@ -108,6 +107,14 @@ def current_impl_label() -> str:
         return resolved_table_impl()
     except Exception:  # noqa: BLE001 — a bad env var must not sink a line
         return TABLE_IMPL
+
+
+def nbuckets_for(entries: int) -> int:
+    """Bucket count that holds `entries` keys at about 50% load of the
+    4-way buckets, a power of two and at least 2^10 — the one sizing
+    rule behind every table built from a subscriber or flow count
+    (`bng run` capacities, `bng loadtest`, bench.py)."""
+    return 1 << max(10, (entries // 2).bit_length())
 
 
 def way_stride(key_words: int) -> int:

@@ -61,24 +61,10 @@ def make_mesh(n_devices: int) -> Mesh:
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the top-level API (check_vma
-    kwarg) landed after 0.4.x; older jaxlibs ship it as
-    jax.experimental.shard_map (check_rep kwarg). Replication checking is
-    off either way — the stats psums are deliberately cross-chip."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            # jax versions where shard_map is top-level but the kwarg is
-            # still the older check_rep spelling
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """Replication checking is off: the stats psums are deliberately
+    cross-chip."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _sharded_geom(geom: PipelineGeom, n: int) -> PipelineGeom:

@@ -2,9 +2,10 @@
 
 One implementation of the compile/mtime-cache/CDLL/lock dance for every
 native module (bngring, bngxsk, ...): the reference gets this from its
-Makefile + cgo; here the .so is compiled from source on first use so the
-package works from a plain checkout, and falls back to None (callers
-degrade to their Python/stub paths) when no toolchain exists.
+Makefile + cgo; here the .so is compiled from native/*.cpp on first use
+(`*.so` is git-ignored: a checkout has no library until then). Where no
+toolchain exists, `load` returns None and callers take their Python/stub
+paths — logged once per library, never in silence.
 """
 
 from __future__ import annotations
@@ -22,18 +23,17 @@ _libs: dict[str, object] = {}
 _lock = threading.Lock()
 
 
-def _build(src: str, so_path: str) -> str | None:
+def _build(src: str, so_path: str) -> str:
+    """Path of an up-to-date .so, building it if stale. Raises OSError /
+    SubprocessError with the reason when it cannot."""
     if not os.path.exists(src):
-        return None
+        raise FileNotFoundError(src)
     if (os.path.exists(so_path)
             and os.path.getmtime(so_path) >= os.path.getmtime(src)):
         return so_path
     cmd = ["g++", "-O2", "-g", "-Wall", "-fPIC", "-std=c++17", "-shared",
            "-o", so_path, src]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
-        return None
+    subprocess.run(cmd, check=True, capture_output=True, timeout=120)
     return so_path
 
 
@@ -41,19 +41,23 @@ def load(src_name: str, configure: Callable[[C.CDLL], None]):
     """Load (building if stale) native/<src_name>.cpp as a CDLL.
 
     configure(lib) declares argtypes/restypes once. Returns the cached
-    CDLL, or None when the source/toolchain is unavailable.
+    CDLL, or None (logged, and remembered) when the source or toolchain
+    is unavailable.
     """
     with _lock:
         if src_name in _libs:
             return _libs[src_name]
         src = os.path.join(SRC_DIR, f"{src_name}.cpp")
         so_path = os.path.join(_HERE, f"lib{src_name}.so")
-        path = _build(src, so_path)
-        if path is None:
-            return None
         try:
-            lib = C.CDLL(path)
-        except OSError:
+            lib = C.CDLL(_build(src, so_path))
+        except (OSError, subprocess.SubprocessError) as e:
+            from bng_tpu.utils.structlog import get_logger
+
+            get_logger("nativelib").warning(
+                "native library unavailable, Python path in use",
+                lib=src_name, error=f"{type(e).__name__}: {e}")
+            _libs[src_name] = None  # decided once: no rebuild per call
             return None
         configure(lib)
         _libs[src_name] = lib

@@ -51,6 +51,15 @@ from bng_tpu.ops.table import HostTable, TableGeom, TableUpdate, apply_update
 from bng_tpu.utils.net import mac_to_u64, split_u64
 
 
+def mac_key_rows(macs_u64) -> np.ndarray:
+    """[N] MAC-as-u64 -> [N, 2] (hi, lo) uint32 key rows, the layout every
+    MAC-keyed table probes with (bulk twin of utils.net.split_u64)."""
+    macs_u64 = np.asarray(macs_u64, dtype=np.uint64)
+    return np.stack([(macs_u64 >> np.uint64(32)).astype(np.uint32),
+                     (macs_u64 & np.uint64(0xFFFFFFFF)).astype(np.uint32)],
+                    axis=1)
+
+
 def pack_cid_host(circuit_id: bytes) -> np.ndarray:
     """32-byte (padded/truncated) circuit-id -> 8 big-endian uint32 words.
 
@@ -136,12 +145,8 @@ class FastPathTables:
         placement passes). MACs must be unique and not already present.
         Follow with device_tables() for a full upload.
         """
-        macs_u64 = np.asarray(macs_u64, dtype=np.uint64)
-        n = len(macs_u64)
-        keys = np.zeros((n, 2), dtype=np.uint32)
-        keys[:, 0] = (macs_u64 >> np.uint64(32)).astype(np.uint32)  # hi
-        keys[:, 1] = (macs_u64 & np.uint64(0xFFFFFFFF)).astype(np.uint32)  # lo
-        vals = np.zeros((n, ASSIGN_WORDS), dtype=np.uint32)
+        keys = mac_key_rows(macs_u64)
+        vals = np.zeros((len(keys), ASSIGN_WORDS), dtype=np.uint32)
         vals[:, AV_POOL_ID] = pool_ids
         vals[:, AV_IP] = ips
         vals[:, AV_VLAN] = vlan_ids

@@ -16,8 +16,6 @@ like* must already have been recorded. So:
     shed_burst           admission shed count over the burst threshold
     worker_death         a fleet worker's IPC died (control/fleet.py)
     invariant_violation  the cross-authority auditor found one (chaos/)
-    backend_fallback     the bench ran on CPU when a TPU was expected
-                         (bench.py — the VERDICT "What's weak" §1 class)
 - dump volume is bounded twice: a min interval between dumps and a hard
   per-process dump cap, so a flapping trigger can't fill a disk.
 
@@ -41,7 +39,6 @@ TRIG_LATENCY = "latency_excursion"
 TRIG_SHED = "shed_burst"
 TRIG_WORKER = "worker_death"
 TRIG_INVARIANT = "invariant_violation"
-TRIG_BACKEND = "backend_fallback"
 # an SLO burn-rate window (telemetry/slo.py SLOMonitor) or a storm
 # budget (slo.check_budget) crossed its per-stage latency budget
 TRIG_SLO = "slo_breach"

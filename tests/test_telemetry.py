@@ -192,41 +192,6 @@ class TestFlightRecorder:
             assert spans.trigger("worker_death") is None  # rate-limited
         assert rec.triggers["worker_death"] == 2  # counted regardless
 
-    def test_backend_fallback_dump_and_json_flag(self, tmp_path):
-        """The acceptance path: a CPU-fallback bench run must dump the
-        flight recorder and flag it at the TOP of the JSON. Drives
-        bench._finalize_diag / _order_line directly (the code the child
-        dispatch runs before every print)."""
-        sys.path.insert(0, _ROOT)
-        try:
-            import bench
-        finally:
-            sys.path.remove(_ROOT)
-        rec = FlightRecorder(RecorderConfig(capacity=8,
-                                            out_dir=str(tmp_path)))
-        rec.set_backend("cpu")
-        old = dict(bench._DIAG)
-        bench._DIAG.clear()
-        try:
-            with spans.armed(Tracer(recorder=rec)) as tr:
-                _traced_batches(tr, 3)
-                bench._DIAG["backend_fallback"] = "cpu"
-                bench._DIAG["backend_error"] = "probe timed out"
-                bench._finalize_diag()
-                line = bench._order_line({"metric": "m", "value": 1.0,
-                                          **bench._DIAG})
-            assert bench._DIAG["flight_record"].startswith(str(tmp_path))
-            d = json.load(open(bench._DIAG["flight_record"]))
-            assert d["reason"] == "backend_fallback"
-            assert d["meta"]["backend"] == "cpu"
-            assert len(d["records"]) == 3
-            # fallback keys lead the object
-            assert list(line)[:3] == ["backend_fallback", "backend_error",
-                                      "flight_record"]
-        finally:
-            bench._DIAG.clear()
-            bench._DIAG.update(old)
-
     def test_invariant_violation_triggers_dump(self, tmp_path):
         """A planted double-lease must land a flight dump the moment the
         auditor proves it (the chaos <-> telemetry wiring)."""
