@@ -1,0 +1,532 @@
+"""CPU rehearsal of benchmark/run.py at tiny sizes, and the contract's
+shape: names, files found by name, the trace reduction on a recording,
+and the `correct` check's controls. No number from here is a device
+metric: the runs below report platform cpu."""
+
+import copy
+import glob
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+from benchmark import run as bench_run  # noqa: E402
+from benchmark.lib import app as applib  # noqa: E402
+from benchmark.lib import gen, layers, trace  # noqa: E402
+
+BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+TINY_ARGV = {
+    "tiny-cgnat": ["--pool-cidr", "10.0.0.0/11", "--batch-size", "256",
+                   "--synthetic-subs", "1", "--scheduler-enabled",
+                   "--max-subscribers", "4096", "--max-nat-sessions", "512",
+                   "--max-nat-subscribers", "128"],
+    "tiny-sharded": ["--pool-cidr", "10.0.0.0/11", "--batch-size", "256",
+                     "--synthetic-subs", "1", "--shards", "4",
+                     "--shard-nbuckets", "1024"],
+}
+TINY_CELLS = {  # tiny cell -> (the cell its layer files name, config, traffic)
+    "tiny.flood": ("cgnat-1M.flood-64B", "tiny-cgnat", "tiny-flood"),
+    "tiny.renew": ("cgnat-1M.renew-under-load", "tiny-cgnat", "tiny-renew"),
+    # the sharded cell's files are in place; BENCHMARK.json does not hold it
+    # yet (PERF.md section 7), so it reports what the other flood cell does
+    "tiny4.flood": ("sharded4-1M.flood-64B", "tiny-sharded", "tiny-flood-32"),
+}
+REPORTS_LIKE = {"sharded4-1M.flood-64B": "cgnat-1M.flood-64B"}
+
+
+def _write(path, obj):
+    with open(path, "w") as f:
+        json.dump(obj, f)
+
+
+@pytest.fixture(scope="module")
+def tiny_dir(tmp_path_factory):
+    """A temporary copy of the benchmark with one configuration, one
+    traffic mix and one layer metric dropped in as files: nothing of the
+    harness's code is touched to pick them up."""
+    top = tmp_path_factory.mktemp("bench")
+    bdir = os.path.join(top, "benchmark")
+    shutil.copytree(os.path.join(ROOT, "benchmark"), bdir)
+    bench = copy.deepcopy(BENCH)
+    sizes = {"subscribers": 4096, "nat_subscribers": 128,
+             "flows_per_nat_subscriber": 2}
+    for name, argv in TINY_ARGV.items():
+        base = "ipoe-sharded4-1M" if "sharded" in name else "ipoe-cgnat-1M"
+        cfg = applib.load_named("configs", base, bdir)
+        cfg.update(name=name, argv=argv, sizes=sizes)
+        if "nat_public_ips" in cfg:
+            cfg["nat_public_ips"]["count"] = 4
+        _write(os.path.join(bdir, "configs", name + ".json"), cfg)
+        bench["configs"].append({"name": name, "source": "test",
+                                 "file": f"benchmark/configs/{name}.json",
+                                 "reduced": [], "why": "test"})
+    flood = applib.load_named("traffic", "flood-64B", bdir)
+    flood.update(name="tiny-flood", pool_frames=2048, dhcp_share=0.05,
+                 warmup_frames=400)
+    _write(os.path.join(bdir, "traffic", "tiny-flood.json"), flood)
+    # The sharded lookup's exchange holds 2 x lanes / shards keys a
+    # destination (ops/table.py exchange_capacity): 1,024 of a 2,048-lane
+    # shard at the cell's size, where at most 2,048 frames are outstanding
+    # over four shards, and 32 of the 64 lanes here. Holding the frames
+    # outstanding to 32 keeps the rehearsal inside it as the cell is, so
+    # that no DHCP frame is punted to the host and the guarantee is held.
+    flood32 = dict(flood, name="tiny-flood-32",
+                   outstanding_cap_of_ring_depth=32 / 1024)
+    _write(os.path.join(bdir, "traffic", "tiny-flood-32.json"), flood32)
+    renew = applib.load_named("traffic", "renew-under-load", bdir)
+    renew.update(name="tiny-renew", dhcp_rate=100, data_rate=1000,
+                 warmup_frames=400)
+    _write(os.path.join(bdir, "traffic", "tiny-renew.json"), renew)
+    stands_for = {v[0]: k for k, v in TINY_CELLS.items()}
+    for cell, (_real, cfg, mix) in TINY_CELLS.items():
+        bench["workloads"].append({"name": cell, "config": cfg, "traffic": mix,
+                                   "chips": 4 if "4" in cell else 1,
+                                   "why": "test"})
+    for m in bench["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"] += [tiny for real, tiny in stands_for.items()
+                               if REPORTS_LIKE.get(real, real) in m["workloads"]]
+    for path in glob.glob(os.path.join(bdir, "layers", "*.json")):
+        m = json.load(open(path))
+        m["cells"] += [stands_for[c] for c in list(m["cells"])]
+        _write(path, m)
+    # the dropped-in layer metric: a counter nobody read before
+    _write(os.path.join(bdir, "layers", "test.batches.json"), {
+        "name": "test.batches", "unit": "batches/s", "better": "higher",
+        "source": "program_counter", "layer": "engine (runtime/engine.py)",
+        "moves": "served_kpps", "cells": ["tiny.flood"],
+        "read": {"kind": "counter", "path": "engine.batches", "per": "second"}})
+    _write(os.path.join(top, "BENCHMARK.json"), bench)
+    return bdir
+
+
+def _run(tiny_dir, capsys, cell, *extra, seed=3000000019):
+    capsys.readouterr()
+    rc = bench_run.main(["--workload", cell, "--seed", str(seed),
+                         "--seconds", "1.5", "--bench-dir", tiny_dir, *extra])
+    out = capsys.readouterr().out.strip().splitlines()
+    assert rc == 0
+    return json.loads(out[-1]), out
+
+
+# -- rehearsal of every cell's phases --------------------------------------
+
+@pytest.mark.parametrize("cell", sorted(TINY_CELLS))
+def test_cell_rehearses_on_cpu(tiny_dir, capsys, cell):
+    if "4" in cell:
+        import jax
+
+        if len(jax.devices()) < 4:
+            pytest.skip("needs four (virtual) devices")
+    res, out = _run(tiny_dir, capsys, cell, "--trace", "0")
+    assert set(res) == RESULT_KEYS
+    assert res["correct"] is True and res["failed"] == 0, out[-14:]
+    assert res["attempted"] > 0
+    assert res["device"]["platform"] == "cpu"
+    assert "memory_peak_bytes" not in res["device"]  # no device metric here
+    real = REPORTS_LIKE.get(TINY_CELLS[cell][0], TINY_CELLS[cell][0])
+    want = {m["name"] for m in BENCH["end_to_end"]
+            if real in m.get("workloads", [real])}
+    assert set(res["metrics"]) == want
+    for m in res["metrics"].values():
+        assert set(m) == {"value", "unit"} and m["value"] > 0
+    assert any(line.startswith("check lost_frames=0 limit=0") for line in out)
+    # held in every cell, the sharded one too: no punt to the host
+    assert any(line == "check host_slow_path_dhcp=0 limit=0" for line in out)
+    assert any(line.startswith("selectors: ") for line in out)
+    assert any(line.startswith("programs built or loaded: ") for line in out)
+
+
+def test_traced_run_reads_span_counter_and_bench_span_files(tiny_dir, capsys):
+    res, _ = _run(tiny_dir, capsys, "tiny.flood", "--trace", "1")
+    assert set(res) == RESULT_KEYS
+    got = res["metrics"]
+    # one metric of each host-side reader kind, and the dropped-in file
+    for name in ("ring.us_per_frame", "sched.bulk_occupancy", "gen.share",
+                 "loop.us_per_frame", "test.batches"):
+        assert got[name]["value"] > 0, name
+    assert "fused_step.device_p50_us" not in got  # no device trace on the CPU
+    assert "busy_s" not in res["device"]
+
+
+def test_traced_latency_cell_reads_lane_spans(tiny_dir, capsys):
+    res, _ = _run(tiny_dir, capsys, "tiny.renew", "--trace", "1")
+    got = res["metrics"]
+    for name in ("sched.express_wait_p99_us", "sched.bulk_wait_p99_us",
+                 "loop.beat_p99_us", "gen.late_p99_us", "loop.offer_p99_us",
+                 "loop.fwd_p99_us"):
+        assert got[name]["value"] > 0, name
+    assert got["slow.punt_share"]["value"] == 0
+
+
+# -- `correct` has to be able to fail ---------------------------------------
+
+@pytest.mark.parametrize("control", bench_run.CONTROLS)
+def test_control_run_is_not_correct(tiny_dir, capsys, control):
+    """A reply with one flipped checksum byte, and a reply built from a
+    binding one update behind: both have to come out as not correct."""
+    res, out = _run(tiny_dir, capsys, "tiny.flood", "--control", control,
+                    seed=11)
+    assert res["correct"] is False and res["failed"] > 0
+    bad = [ln for ln in out if ln.startswith("check sampled_replies_differing=")]
+    assert bad and not bad[0].startswith("check sampled_replies_differing=0 ")
+
+
+def test_broken_timed_path_is_not_correct(tiny_dir, capsys, monkeypatch):
+    """The rest of a run with the timed path broken underneath: the ring
+    gives back every DHCP reply with another address in it."""
+    real_pop = bench_run.Loop._pop
+
+    def pop(self):
+        got = real_pop(self)
+        return [(raw[:58 + 2] + bytes([raw[60] ^ 1]) + raw[61:], fl)
+                if len(raw) > 300 else (raw, fl) for raw, fl in got]
+
+    monkeypatch.setattr(bench_run.Loop, "_pop", pop)
+    res, _ = _run(tiny_dir, capsys, "tiny.flood", seed=12)
+    assert res["correct"] is False
+
+
+def test_lost_frame_is_not_correct(tiny_dir, capsys, monkeypatch):
+    """A step that swallows part of what it was given."""
+    real_pop = bench_run.Loop._pop
+    state = {"swallowed": False}
+
+    def pop(self):
+        got = real_pop(self)
+        # once, in the measured loop (the warm-up's is built without a seed)
+        if got and self.in_window and not state["swallowed"]:
+            state["swallowed"] = True
+            self.popped -= 1
+            return got[1:]
+        return got
+
+    real_init = bench_run.Loop.__init__
+
+    def init(self, app, traffic, seed=0, tamper=None):
+        real_init(self, app, traffic, seed, tamper)
+        self.in_window = bool(seed)
+
+    monkeypatch.setattr(bench_run.Loop, "__init__", init)
+    monkeypatch.setattr(bench_run.Loop, "_pop", pop)
+    res, out = _run(tiny_dir, capsys, "tiny.flood", seed=13)
+    assert res["correct"] is False and res["failed"] > 0
+    assert not any(ln.startswith("check lost_frames=0 ") for ln in out)
+
+
+def test_stalled_loop_holds_what_is_due_and_fails_nothing(tiny_dir, capsys,
+                                                          monkeypatch):
+    """An open-loop frame that finds no room is held and offered at a later
+    beat, timed from when it was due: a loop that stalls, with little room
+    in front of it, delays frames and fails none."""
+    real_init = bench_run.Loop.__init__
+
+    def init(self, app, traffic, seed=0, tamper=None):
+        real_init(self, app, traffic, seed, tamper)
+        if not seed:  # the warm-up's loop
+            return
+        self.cap = 64
+        drive, state = app.drive_once, {"beats": 0}
+
+        def stalling():
+            state["beats"] += 1
+            if state["beats"] == 50:
+                bench_run.time.sleep(0.5)
+            return drive()
+
+        monkeypatch.setattr(app, "drive_once", stalling)
+
+    monkeypatch.setattr(bench_run.Loop, "__init__", init)
+    res, out = _run(tiny_dir, capsys, "tiny.renew", seed=14)
+    assert res["correct"] is True and res["failed"] == 0, out[-14:]
+    line = [ln for ln in out if ln.startswith("window: ")][0]
+    held = int(re.search(r"held at most (\d+) at once", line).group(1))
+    assert held > 64 and "never offered 0," in line
+    assert res["attempted"] == int(re.search(r"pushed (\d+),", line).group(1))
+    assert any(ln == "check frames_never_offered=0 limit=0" for ln in out)
+    # what waited is in the latencies: the stall is half a second long
+    assert res["metrics"]["fwd_p95_us"]["value"] > 100_000
+
+
+def test_stale_control_is_planted_in_the_table_that_is_uploaded():
+    """The control changes what the device holds, not what the reference
+    says: one address in eight of the DHCP table is the one from before."""
+    lay = applib.Layout({"sizes": {"subscribers": 64, "nat_subscribers": 8,
+                                   "flows_per_nat_subscriber": 2}}, 7)
+    idx = np.arange(64)
+    sound = applib.dhcp_table_ips(lay, idx, stale=False)
+    stale = applib.dhcp_table_ips(lay, idx, stale=True)
+    assert (sound == lay.sub_ips(idx)).all()
+    assert ((stale != sound) == (idx % 8 == 0)).all()
+    assert (lay.sub_ips(idx) == sound).all()  # the layout's own are untouched
+
+
+@pytest.mark.parametrize("name", sorted(
+    os.path.basename(p)[:-5]
+    for p in glob.glob(os.path.join(ROOT, "benchmark", "configs", "*.json"))))
+def test_every_configuration_holds_the_four_guarantees(name):
+    """No configuration may let the slow path answer what the device table
+    could: `check` holds `host_slow_path_dhcp` to 0 in every cell."""
+    cfg = applib.load_named("configs", name)
+    want = applib.load_named("configs", "ipoe-cgnat-1M")["guarantees"]
+    assert cfg["guarantees"] == want and len(want) == 4
+    assert "slow_path_may_answer" not in cfg
+    import inspect
+
+    src = inspect.getsource(bench_run.check)
+    assert 'hold("host_slow_path_dhcp"' in src and "punts_allowed" not in src
+
+
+def test_flood_tops_the_ring_up_before_every_beat():
+    """However few slots came free, the next beat fills them: the generator
+    is a queue that is never empty, up to the cap on frames outstanding."""
+    class Ring:
+        depth = 8
+
+        def __init__(self):
+            self.rx = []
+
+        def rx_push_batch(self, frames, from_access):
+            self.rx += frames
+            return len(frames)
+
+        def stats(self):
+            return {"drop": 0}
+
+    class Stats:
+        dropped = 0
+
+    class Engine:
+        stats = Stats()
+
+    class App:
+        def __init__(self):
+            self.components = {"ring": Ring(), "engine": Engine()}
+
+    class Mix:
+        flood, n = True, 8
+        mix = {"outstanding_cap_of_ring_depth": 1.0}
+        streams = [gen.Stream(True, range(4), [b"a"] * 4),
+                   gen.Stream(False, range(4, 8), [b"n"] * 4)]
+
+    app = App()
+    loop = bench_run.Loop(app, Mix())
+    assert loop._push(0.0) == 8 and loop._push(0.0) == 0  # full: no room
+    loop.popped += 1  # one reply left
+    assert loop._push(0.0) == 1 and loop.outstanding() == 8
+    loop.popped += 3
+    assert loop._push(0.0) == 3
+    assert (loop.push_beats, loop.cap_full, loop.ring_short) == (4, 1, 0)
+    assert "refill_min_share_of_cap" not in applib.load_named("traffic",
+                                                              "flood-64B")
+
+
+def test_counter_per_counter_and_per_frame_latency_readers():
+    class Plan:
+        flood = True
+
+    ctx = layers.Context(plan=Plan(), loop=None, window=2.0, served=10,
+                         c0={"a": {"sum": 1.0, "n": 2}},
+                         c1={"a": {"sum": 2.5, "n": 5}}, tracer=None,
+                         profile=None, setup_s=0.0, n_devices=1)
+    read = {"kind": "counter", "path": "a.sum", "per": "a.n"}
+    assert layers.read_counter(read, ctx) == pytest.approx(0.5)  # the window's own
+    assert layers.read_counter(dict(read, per="second"), ctx) == pytest.approx(0.75)
+    assert layers.read_counter(dict(read, per="a.missing"), ctx) is None
+    occ = applib.load_named("layers", "sched.bulk_occupancy")["read"]
+    assert occ["per"] == "sched.bulk.batches" and "delta" not in occ
+
+    class Loop:
+        spans = [(0.0, 0.1, 0.2, 0.3, 1, 1)]
+
+    ctx.loop = Loop()
+    ctx._lat = {"dhcp_us": np.arange(1.0, 101.0), "data_us": np.arange(1.0, 201.0),
+                "late_us": np.zeros(3)}
+    for span, top in (("dhcp", 100), ("data", 200)):
+        got = layers.read_bench_span({"span": span, "stat": "p99"}, ctx)
+        assert top * 0.98 < got < top
+
+
+# -- no chip, no result -------------------------------------------------------
+
+def test_refuses_to_run_without_the_chip():
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    out = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "cgnat-1M.flood-64B", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], capture_output=True, text=True, timeout=300, env=env,
+        cwd=ROOT)
+    assert out.returncode != 0
+    assert '"correct"' not in out.stdout
+
+
+def test_cpu_is_refused_at_the_cells_real_size():
+    with pytest.raises(SystemExit):
+        bench_run.find_devices(1, 1_000_000)
+
+
+# -- the contract's shape -----------------------------------------------------
+
+def test_names_units_and_lengths():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        names = [e["name"] for e in BENCH[group]]
+        assert len(names) == len(set(names))
+        assert all(NAME.match(n) for n in names), names
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+    for w in BENCH["workloads"]:
+        assert NAME.match(w["config"]) and NAME.match(w["traffic"])
+        assert 1 <= len(w["why"]) <= 200 and w["chips"] in (1, 4)
+        assert "no frame crossed a link" in w["why"]
+    for c in BENCH["configs"]:
+        assert 1 <= len(c["source"]) <= 200 and len(c["reduced"]) <= 16
+        assert all(NAME.match(k) for k in c["reduced"])
+    assert any(m["name"] == "setup_s" and m["bound"] <= 0.25
+               for m in BENCH["end_to_end"])
+    assert all(0.01 <= m["bound"] <= 0.25 for m in BENCH["end_to_end"])
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert four <= max(1, len(BENCH["workloads"]) // 2)
+    assert len(json.dumps(BENCH)) < 64 * 1024
+
+
+def test_every_cell_resolves_its_files_by_name():
+    configs = {c["name"]: c for c in BENCH["configs"]}
+    for w in BENCH["workloads"]:
+        cfg = applib.load_named("configs", w["config"])
+        mix = applib.load_named("traffic", w["traffic"])
+        entry = configs[w["config"]]
+        assert entry["file"] == f"benchmark/configs/{w['config']}.json"
+        assert cfg["name"] == w["config"] and mix["name"] == w["traffic"]
+        assert cfg["chips"] == w["chips"]
+        assert cfg["source"] == entry["source"]
+        assert cfg["reduced"] == entry["reduced"]
+        assert cfg["guarantees"] and mix["kind"] in ("flood", "fixed_rate")
+    assert {c["name"] for c in BENCH["configs"]} == {
+        w["config"] for w in BENCH["workloads"]}
+    for p in BENCH["paths"]:
+        assert os.path.isdir(os.path.join(ROOT, p))
+
+
+def test_layer_files_and_benchmark_json_agree():
+    cells = {w["name"] for w in BENCH["workloads"]}
+    reports = {m["name"]: set(m.get("workloads", cells))
+               for m in BENCH["end_to_end"]}
+    files = {m["name"]: m for m in layers.layer_files(applib.BENCH_DIR)}
+    listed = {m["name"]: m for m in BENCH["per_layer"]}
+    # a file may name a cell that is not (yet) in BENCHMARK.json
+    assert set(listed) <= set(files)
+    for name, m in listed.items():
+        f = files[name]
+        assert m["workloads"] == [c for c in f["cells"] if c in cells]
+        assert m["workloads"], name
+        for key in ("unit", "better", "source", "layer", "moves"):
+            assert m[key] == f[key], (name, key)
+        assert f["source"] == layers.SOURCE_OF_KIND[f["read"]["kind"]]
+        # `moves` names an end-to-end metric that each of its cells reports
+        assert set(m["workloads"]) <= reports[m["moves"]], name
+    for cell in cells:
+        assert any(cell in m["workloads"] for m in BENCH["per_layer"])
+    peaks = json.load(open(os.path.join(applib.HERE, "peaks.json")))
+    assert peaks["TPU v5 lite"]["hbm_gbytes_per_s"] == 819
+
+
+# -- the generator's frames ---------------------------------------------------
+
+def test_patched_frames_equal_the_codecs():
+    from bng_tpu.control import dhcp_codec, packets
+
+    macs = np.array([0x02AA00000005, 0x02AA000FFFFF], np.uint64)
+    xids = np.array([0x01000007, 0x7F00FFFF], np.uint32)
+    ips = np.array([0x0A100005, 0x0A1FFFFF], np.uint32)
+    kinds = np.array([gen.DISCOVER, gen.REQUEST])
+    rows = gen.dhcp_frames(macs, kinds, xids, ips, 0x0A000001)
+    assert bytes(rows[0]) == gen.dhcp_frame(int(macs[0]), dhcp_codec.DISCOVER,
+                                            int(xids[0]))
+    assert bytes(rows[1]) == gen.dhcp_frame(
+        int(macs[1]), dhcp_codec.REQUEST, int(xids[1]),
+        requested_ip=int(ips[1]), server_id=0x0A000001)
+
+    src_mac = np.frombuffer(bytes.fromhex("02aa00000005" "02aa00000006"),
+                            np.uint8).reshape(2, 6)
+    dst_mac = np.frombuffer(bytes.fromhex("02aabbccdd01"), np.uint8)
+    buf = gen.data_frames(src_mac, dst_mac, [0x0A100005, 0x0A100006],
+                          [0x5DB80001, 0x5DB80002], [40000, 40001], [443, 443],
+                          np.array([17, 6]), [7, 0xFFFFFFFE])
+    for row, proto in zip(buf, (17, 6)):
+        raw = bytes(row)
+        d = packets.decode(raw)
+        assert len(raw) == 60 and d.proto == proto and d.ip_checksum_ok
+        assert d.l4_checksum != 0 and applib.l4_checksum_ok(raw)
+        assert (d.src_port, d.dst_port) in ((40000, 443), (40001, 443))
+    assert bytes(buf[0][-4:]) == (7).to_bytes(4, "big")
+    tcp = packets.tcp_packet(bytes(src_mac[1]), bytes(dst_mac), 0x0A100006,
+                             0x5DB80002, 40001, 443, bytes(buf[1][54:]))
+    assert bytes(buf[1]) == tcp
+
+
+def test_every_seed_offers_the_same_amount_in_another_order():
+    class App:  # what Traffic reads of the app
+        class config:
+            server_mac = "02:aa:bb:cc:dd:01"
+            server_ip = "10.0.0.1"
+
+    cfg = {"sizes": {"subscribers": 4096, "nat_subscribers": 128,
+                     "flows_per_nat_subscriber": 2}}
+    mix = applib.load_named("traffic", "renew-under-load")
+    prov = {"nat_ip": np.full(256, 0xC6120001, np.uint32),
+            "nat_port": np.arange(256, dtype=np.uint32) + 1024}
+    big = 2**31 + 11
+    a, b = (gen.Traffic(mix, applib.Layout(cfg, s), prov, App, s, 1.0)
+            for s in (5, big))
+    assert a.n == b.n and (a.kind == b.kind).sum() > 0
+    assert [len(s.frames) for s in a.streams] == [len(s.frames) for s in b.streams]
+    assert a.frames != b.frames
+    again = gen.Traffic(mix, applib.Layout(cfg, big), prov, App, big, 1.0)
+    assert again.frames == b.frames and (again.due == b.due).all()
+
+
+# -- the trace reduction, on a small recorded trace ---------------------------
+
+def test_trace_reduction_on_the_recorded_trace():
+    path = os.path.join(applib.HERE, "testdata", "trace_small.json")
+    data = json.load(open(path))
+    want = json.load(open(os.path.join(applib.HERE, "testdata",
+                                       "trace_small.expect.json")))
+    got = trace.reduce(data, want["n_devices"], want["window_s"])
+    assert got["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
+    assert got["idle_share"] == pytest.approx(want["idle_share"], rel=1e-9)
+    assert 0 < got["busy_s"] < got["window_s"]
+    progs = {}
+    for name, _t, d in got["programs"]:
+        progs.setdefault(name, []).append(d)
+    assert {k: len(v) for k, v in progs.items()} == want["program_counts"]
+    assert len(got["breakdown"]["device_ops"]) <= 10
+    assert got["breakdown"]["idle_gaps"][0][0] == want["longest_gap_label"]
+    # a hand-made case: two overlapping ops and one apart, on two devices
+    hand = {"planes": {
+        "/device:TPU:0": {"XLA Ops": [["a", 0, 4e8], ["b", 2e8, 4e8],
+                                      ["all-to-all.1", 8e8, 1e8]],
+                          "XLA Modules": [["jit_step", 0, 9e8]]},
+        "/device:TPU:1": {"XLA Ops": [["a", 0, 2e8]], "XLA Modules": []},
+        "/host:CPU": {"bench": [["bench.drive_once", 5e8, 4e8]]}}}
+    got = trace.reduce(hand, 2, 1.0)
+    assert got["busy_s"] == pytest.approx((0.7 + 0.2) / 2)
+    assert got["idle_share"] == pytest.approx(0.8)  # the worst device
+    assert got["collective_share"] == pytest.approx(0.1)
+    assert got["breakdown"]["idle_gaps"] == [["bench.drive_once", pytest.approx(0.2)]]
+    assert trace.reduce({"planes": {}}, 1, 1.0) is None
