@@ -431,7 +431,8 @@ def main(on_tpu: bool) -> None:
             device_source = sd.source
             tr = tele.tracer()
             if tr is not None:  # the `device` stage in stage_breakdown
-                tr.observe_many(tele.DEVICE, sd.us)
+                tr.observe_many(tele.DEVICE, sd.us,
+                                lane=tele.LANE_BENCH)
         else:
             _DIAG["device_profile_error"] = "no per-execution events in trace"
     except Exception as e:  # profiling must never sink the benchmark
@@ -523,7 +524,7 @@ def main(on_tpu: bool) -> None:
         # per-stage p50/p99 from the telemetry tracer (dispatch /
         # device_wait are host decomposition; `device` is the fenced
         # profiler distribution above)
-        "stage_breakdown": (tele.tracer().breakdown()
+        "stage_breakdown": (_stage_breakdown(tele.tracer())
                             if tele.tracer() is not None else {}),
         **({"profile_top_ops": profile_top} if profile_top else {}),
         **({"offer_profile_top_ops": offer_profile_top} if offer_profile_top else {}),
@@ -591,6 +592,17 @@ def _timed_loop(step, args, steps, batch, carry: bool = False):
 # merged into every emitted JSON line: backend-fallback diagnostics etc.
 _DIAG: dict = {}
 
+
+def _stage_breakdown(tracer) -> dict:
+    """A line's `stage_breakdown`: every stage with its lanes merged, but
+    `device` stays what the ledger's history holds under that name, the
+    profiler-fenced samples (lane `bench`). The served path's by-readiness
+    samples (lanes express / bulk, an upper bound) never trend as it."""
+    bd = tracer.breakdown(lanes=True)
+    out = {k: v for k, v in bd.items() if "@" not in k and k != "device"}
+    if "device@bench" in bd:
+        out["device"] = bd["device@bench"]
+    return out
 
 
 def _persist(line: dict) -> None:
@@ -1119,7 +1131,7 @@ def sharded_serving_bench(on_tpu: bool, n_shards: int) -> None:
     Ledger identity: `n_shards` rides every emitted line and the cohort
     key (telemetry/ledger.py) so an aggregate 8-shard number can never
     trend against single-device history. The per-shard stage breakdown
-    (merged ShardTelemetry histograms) lands in stage_breakdown for the
+    (the Tracer's `sharded` lane) lands in stage_breakdown for the
     per-stage gate, and the run REFUSES to publish if any steered frame
     misteered (missteer_total must be 0 on a ring this bench built)."""
     import jax
@@ -1229,7 +1241,11 @@ def sharded_serving_bench(on_tpu: bool, n_shards: int) -> None:
     compile_s = time.time() - t_c
 
     _mark(f"sharded serving: measuring {STEPS} pipelined windows...")
+    from bng_tpu.telemetry import spans as _tele
+
     processed = 0
+    # the loop's stage times are the Tracer's (lane `sharded`)
+    tracer = _tele.tracer() or _tele.arm(_tele.Tracer())
     t0 = time.time()
     for k in range(STEPS):
         _feed(B)
@@ -1254,9 +1270,11 @@ def sharded_serving_bench(on_tpu: bool, n_shards: int) -> None:
                          "pass_total": snap["pass_total"]},
             **_DIAG}))
         sys.exit(2)
-    stage_breakdown = {s: {"p50_us": h["p50_us"], "p99_us": h["p99_us"],
-                           "count": h["count"]}
-                       for s, h in snap["merged_stages"].items()}
+    stage_breakdown = {s.split("@")[0]: {"p50_us": h["p50_us"],
+                                         "p99_us": h["p99_us"],
+                                         "count": h["count"]}
+                       for s, h in tracer.breakdown(lanes=True).items()
+                       if s.endswith("@sharded")}
     _emit("Sharded serving Mpps (ring-steered)", mpps, "Mpps",
           12.5 * n_shards, devices=n_shards, n_shards=n_shards,
           batch=B, subscribers=N, flows=N_FLOWS,
@@ -1434,7 +1452,8 @@ def scheduler_bench(on_tpu: bool, checkpoint_interval_s: float = 0.0) -> None:
             from bng_tpu.telemetry import spans as _tele
 
             if _tele.tracer() is not None:  # `device` stage, fenced
-                _tele.tracer().observe_many(_tele.DEVICE, sd.us)
+                _tele.tracer().observe_many(
+                    _tele.DEVICE, sd.us, lane=_tele.LANE_BENCH)
         else:
             _DIAG["sched_profile_error"] = "no per-execution events in trace"
     except Exception as e:  # profiling must never sink the benchmark
@@ -1520,7 +1539,7 @@ def scheduler_bench(on_tpu: bool, checkpoint_interval_s: float = 0.0) -> None:
     if _tele2.tracer() is not None:
         # scheduler paths are span-instrumented end to end — the full
         # lifecycle breakdown (lane_wait/dispatch/device_wait/slow/reply)
-        line["stage_breakdown"] = _tele2.tracer().breakdown()
+        line["stage_breakdown"] = _stage_breakdown(_tele2.tracer())
     line = {**line, **{k: v for k, v in _DIAG.items()
                        if k not in line}}
     print(json.dumps(line))
@@ -1783,7 +1802,8 @@ def express_ab_bench(on_tpu: bool) -> None:
                 device_source = sd.source
                 tele.tracer().observe_many(
                     tele.DEVICE, [u / dev_scale for u in sd.us]
-                    if dev_scale != 1.0 else sd.us)
+                    if dev_scale != 1.0 else sd.us,
+                    lane=tele.LANE_BENCH)
             else:
                 _DIAG[f"ab_{path_name}_profile_error"] = "no events in trace"
         except Exception as e:  # profiling must never sink the benchmark
@@ -1830,7 +1850,7 @@ def express_ab_bench(on_tpu: bool) -> None:
         }
         # breakdown taken AFTER the profiling pass so the cohort line
         # carries the profiler-fenced `device` stage the SLO gate reads
-        line["stage_breakdown"] = st["tracer"].breakdown()
+        line["stage_breakdown"] = _stage_breakdown(st["tracer"])
         line = {**line, **{k: v for k, v in _DIAG.items()
                            if k not in line}}
         print(json.dumps(line))

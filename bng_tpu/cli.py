@@ -1867,6 +1867,17 @@ class BNGApp:
         ring = self.components.get("ring")
         if ring is None:
             return 0
+        from bng_tpu.telemetry import spans as tele
+
+        tele.beat_begin()  # the container every lap below tiles
+        try:
+            return self._drive_beat(ring)
+        finally:
+            tele.beat_end()
+
+    def _drive_beat(self, ring) -> int:
+        from bng_tpu.telemetry import spans as tele
+
         att = self.components.get("wire_attachment")
         pumped = 0
         if att is not None and att.xsk is not None:
@@ -1910,6 +1921,7 @@ class BNGApp:
                 continue
             with self._ctl:
                 pending = src.drain_pending()
+                t0 = tele.t() if pending else None
                 for i, frame in enumerate(pending):
                     if ring.tx_inject(frame, from_access=True):
                         moved += 1
@@ -1918,6 +1930,7 @@ class BNGApp:
                         # order-preserving, via the public API
                         src.requeue(pending[i:], front=True)
                         break
+                tele.lap(tele.TX, t0)
         if att is not None and att.xsk is not None:
             pumped += att.xsk.pump()  # verdicts -> kernel after the step
         return moved + pumped
@@ -1961,14 +1974,18 @@ class BNGApp:
             # hot (no idle sleep) so the close fires at max_wait_us, not
             # at sleep granularity
             moved = 1
-        for c in sched.drain_completions():
+        done = sched.drain_completions()
+        t0 = tele.t() if done else None
+        for c in done:
             if c.frame is None:
                 continue
             if c.verdict in ("tx", "fwd", "slow"):
                 # slow completions carry the handler's reply frame; a full
                 # TX ring drops it (the client's retransmit recovers, the
-                # reference's socket-write failure mode)
+                # reference's socket-write failure mode) and counts it:
+                # ring.stats()["tx_refused"]
                 ring.tx_inject(c.frame, from_access=c.from_access)
+        tele.lap(tele.TX, t0)
         return moved
 
     def _push_synthetic(self, ring, per_beat: int = 16) -> None:
@@ -2486,7 +2503,7 @@ def run_loadtest(args) -> int:
         # monitor gate on, persisted so the lines are gate-consumable
         from bng_tpu.telemetry import slo as slo_mod
 
-        res.slo = slo_mod.evaluate(stage_breakdown)
+        res.slo = slo_mod.evaluate(tracer.breakdown(lanes=True))
     if getattr(args, "bench_log", ""):
         # schema'd ledger line (telemetry/ledger.py): stage_breakdown +
         # SLO verdict + env fingerprint ride every loadtest run so

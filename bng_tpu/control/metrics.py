@@ -326,6 +326,15 @@ class BNGMetrics:
         self.sched_completions_evicted = r.counter(
             "bng_sched_completions_evicted_total",
             "Completions evicted from the bounded delivery deque")
+        self.sched_blocked_retires = r.counter(
+            "bng_sched_bulk_blocked_retires_total",
+            "Bulk dispatches that found the completion ring full, so the "
+            "loop blocked on the oldest step (the one place the bulk "
+            "lane waits on the device)")
+        self.sched_express_behind_bulk = r.counter(
+            "bng_sched_express_behind_bulk_total",
+            "Express batches dispatched while a bulk step was in flight "
+            "on the same device: they wait it out")
         self.sched_batch_occupancy = r.histogram(
             "bng_sched_batch_occupancy_ratio",
             "Dispatched batch fill ratio (1.0 = full close)", lbl_lane,
@@ -662,10 +671,10 @@ class BNGMetrics:
         self.shard_psum_hits = r.counter(
             "bng_shard_psum_dhcp_hits_total",
             "DHCP fast-path hits psum-reduced over the mesh")
-        self.shard_stage_p99 = r.gauge(
-            "bng_shard_stage_p99_us",
-            "Per-shard stage p99 from the sharded-path histograms",
-            ("shard", "stage"))
+        self.sharded_stage_p99 = r.gauge(
+            "bng_sharded_stage_p99_us",
+            "Sharded-loop stage p99 (the Tracer's `sharded` lane; one "
+            "program over the mesh, so one value a stage)", ("stage",))
         # antispoof stage (ops/antispoof.py AST_* words). The reference
         # streams violations over a perf-event buffer; here the device
         # counts and the host logs rate-limited, so the counters are the
@@ -796,9 +805,11 @@ class BNGMetrics:
         self.wire_tx_pending.set(pump.tx_pending())
 
     def collect_sharded(self, cluster) -> None:
-        """Sharded-path telemetry (parallel/sharded.py ShardTelemetry)
-        -> bng_shard_* families: per-shard verdict/punt counters + the
-        per-shard stage p99s, from one snapshot."""
+        """Sharded-path counters (parallel/sharded.py ShardTelemetry)
+        -> bng_shard_* families, from one snapshot; the loop's stage
+        p99s from the armed Tracer's `sharded` lane."""
+        from bng_tpu.telemetry import spans as tele
+
         snap = cluster.telemetry.snapshot()
         self.shard_psum_hits.set_total(snap["psum_dhcp_hits"])
         for i, sh in enumerate(snap["per_shard"]):
@@ -808,9 +819,11 @@ class BNGMetrics:
                                             verdict=verdict)
             self.shard_nat_punts.set_total(sh["nat_punts"], shard=shard)
             self.shard_missteers.set_total(sh["missteers"], shard=shard)
-            for stage, s in sh["stages"].items():
-                self.shard_stage_p99.set(s["p99_us"], shard=shard,
-                                         stage=stage)
+        tr = tele.tracer()
+        for stage, name in enumerate(tele.STAGE_NAMES if tr else ()):
+            h = tr.lane_hist(tele.LANE_SHARDED, stage)
+            if h.n:
+                self.sharded_stage_p99.set(h.percentile(99), stage=name)
 
     # -- collection (metrics.go:555-623) -------------------------------
 
@@ -939,7 +952,10 @@ class BNGMetrics:
         self.sched_oversize_dropped.set_total(snap.get("oversize_dropped", 0))
         self.sched_completions_evicted.set_total(
             snap.get("completions_dropped", 0))
+        self.sched_blocked_retires.set_total(
+            (snap.get("bulk") or {}).get("blocked_retires", 0))
         ex = snap.get("express") or {}
+        self.sched_express_behind_bulk.set_total(ex.get("behind_bulk", 0))
         self.express_program_dispatches.set_total(
             ex.get("aot_dispatches", 0), program="aot-express")
         self.express_program_dispatches.set_total(
@@ -1196,7 +1212,8 @@ class _StageLatencyExport:
                f"# TYPE {self.name} histogram"]
         from bng_tpu.telemetry.spans import STAGE_NAMES
 
-        for i, h in enumerate(self.tracer.hists):
+        for i in range(len(STAGE_NAMES)):
+            h = self.tracer.stage_hist(i)
             if not h.n:
                 continue
             stage = STAGE_NAMES[i]

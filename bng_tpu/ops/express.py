@@ -229,6 +229,7 @@ def parse_express(frame: bytes) -> ExpressDesc | None:
                        msg_type=mtype, relayed=relayed, use_bcast=use_bcast)
 
 
+@jax.named_scope("dhcp")  # metadata only: the device trace's stage name
 def express_verdicts(
     tables: DHCPTables,
     desc: jax.Array,

@@ -118,6 +118,10 @@ class PipelineResult(NamedTuple):
     edge_stats: jax.Array | None = None  # [EDGE_NSTATS] when edge on
 
 
+# Each stage below runs under a `jax.named_scope` (parse, antispoof, dhcp,
+# garden, nat44, qos, edge, pppoe, rewrite). Metadata only: the HLO and its
+# op names are the same, and a recorded device trace can be summed by stage
+# (`python -m bng_tpu.utils.profiling <trace dir>`).
 def pipeline_step(
     tables: PipelineTables,
     pkt: jax.Array,
@@ -143,20 +147,24 @@ def pipeline_step(
         # access-side only: a session ethertype arriving from the core is
         # foreign traffic — leave it untouched (PASS, host decides)
         et_gated = jnp.where(from_access, et, 0)
-        pppoe_dec = pppoe_decap(pkt, length, vo, et_gated,
-                                tables.pppoe_by_sid, geom.pppoe)
+        with jax.named_scope("pppoe"):
+            pppoe_dec = pppoe_decap(pkt, length, vo, et_gated,
+                                    tables.pppoe_by_sid, geom.pppoe)
         pkt = jnp.where(pppoe_dec.done[:, None], pppoe_dec.out_pkt, pkt)
         length = jnp.where(pppoe_dec.done, pppoe_dec.out_len, length)
 
-    parsed = parse_batch(pkt, length)
+    with jax.named_scope("parse"):
+        parsed = parse_batch(pkt, length)
 
     # --- antispoof (TC ingress on access side; antispoof.c:188-293) ---
-    spoof = antispoof_kernel(pkt, parsed, tables.spoof, geom.spoof,
-                             tables.spoof_ranges, tables.spoof_config)
+    with jax.named_scope("antispoof"):
+        spoof = antispoof_kernel(pkt, parsed, tables.spoof, geom.spoof,
+                                 tables.spoof_ranges, tables.spoof_config)
     spoof_drop = spoof.dropped & from_access
 
     # --- DHCP fast path (XDP; dhcp_fastpath.c:619-813) ---
-    dhcp = dhcp_fastpath(pkt, length, parsed, tables.dhcp, geom.dhcp, now_s)
+    with jax.named_scope("dhcp"):
+        dhcp = dhcp_fastpath(pkt, length, parsed, tables.dhcp, geom.dhcp, now_s)
     dhcp_tx = dhcp.is_reply & from_access
     dhcp_slow = dhcp.is_dhcp & from_access & ~dhcp_tx
     # DHCP traffic bypasses antispoof (XDP-before-TC for TX; DISCOVER src
@@ -170,29 +178,33 @@ def pipeline_step(
     if tables.garden is not None:
         from bng_tpu.ops.garden import garden_kernel
 
-        garden = garden_kernel(
-            parsed,
-            from_access & parsed.is_ipv4 & ~dhcp.is_dhcp,
-            tables.garden, geom.garden, tables.garden_allowed)
+        with jax.named_scope("garden"):
+            garden = garden_kernel(
+                parsed,
+                from_access & parsed.is_ipv4 & ~dhcp.is_dhcp,
+                tables.garden, geom.garden, tables.garden_allowed)
         garden_drop = garden.gate_drop
         garden_stats = garden.stats
 
     # --- NAT44 (TC; nat44.c:565-948) — not for DHCP or gated lanes ---
-    nat = nat44_kernel(pkt, length, parsed, tables.nat, geom.nat, now_s)
+    with jax.named_scope("nat44"):
+        nat = nat44_kernel(pkt, length, parsed, tables.nat, geom.nat, now_s)
     natable = ~dhcp.is_dhcp & ~spoof_drop & ~garden_drop
     nat_fwd = nat.translated & natable
     nat_punt = nat.punted & natable
 
     # --- QoS (TC; qos_ratelimit.c:126-222) ---
     # upload: access-side lanes keyed by src ip (qos_ingress_prog :178)
-    up = qos_kernel(parsed.src_ip, length, from_access & parsed.is_ipv4 & ~dhcp.is_dhcp,
-                    tables.qos_up, geom.qos, now_us)
+    with jax.named_scope("qos"):
+        up = qos_kernel(parsed.src_ip, length, from_access & parsed.is_ipv4 & ~dhcp.is_dhcp,
+                        tables.qos_up, geom.qos, now_us)
     # download: core-side lanes keyed by POST-DNAT dst ip (the subscriber
     # address — after DNAT the dst is the private ip, qos_egress_prog :126).
     # Read it from the rewritten bytes: covers translated and untouched lanes.
-    dnat_dst = B_.be32_at(nat.out_pkt, parsed.l3_off + 16)
-    down = qos_kernel(dnat_dst, length, ~from_access & parsed.is_ipv4,
-                      tables.qos_down, geom.qos, now_us)
+    with jax.named_scope("qos"):
+        dnat_dst = B_.be32_at(nat.out_pkt, parsed.l3_off + 16)
+        down = qos_kernel(dnat_dst, length, ~from_access & parsed.is_ipv4,
+                          tables.qos_down, geom.qos, now_us)
     qos_drop = (up.dropped & from_access) | (down.dropped & ~from_access)
 
     # --- edge protection (bng_tpu/edge): intercept tap-match + next-hop
@@ -210,18 +222,19 @@ def pipeline_step(
     if tables.tap is not None:
         from bng_tpu.edge.ops import route_rewrite, tap_match
 
-        sub_ip = jnp.where(from_access, parsed.src_ip, dnat_dst)
-        peer_ip = jnp.where(from_access, parsed.dst_ip, parsed.src_ip)
-        data_lane = parsed.is_ipv4 & ~dhcp.is_dhcp
-        tap = tap_match(sub_ip, parsed.src_port, parsed.dst_port,
-                        parsed.proto, peer_ip, data_lane, tables.tap,
-                        tables.tap_filters, tables.tap_config, geom.tap)
-        mirror = tap.mirror
-        rt = route_rewrite(data_pkt, sub_ip, data_lane & from_access,
-                           tables.route, geom.route)
-        data_pkt = rt.out_pkt
-        route_fwd = rt.hit
-        edge_stats = jnp.concatenate([tap.stats, rt.stats])
+        with jax.named_scope("edge"):
+            sub_ip = jnp.where(from_access, parsed.src_ip, dnat_dst)
+            peer_ip = jnp.where(from_access, parsed.dst_ip, parsed.src_ip)
+            data_lane = parsed.is_ipv4 & ~dhcp.is_dhcp
+            tap = tap_match(sub_ip, parsed.src_port, parsed.dst_port,
+                            parsed.proto, peer_ip, data_lane, tables.tap,
+                            tables.tap_filters, tables.tap_config, geom.tap)
+            mirror = tap.mirror
+            rt = route_rewrite(data_pkt, sub_ip, data_lane & from_access,
+                               tables.route, geom.route)
+            data_pkt = rt.out_pkt
+            route_fwd = rt.hit
+            edge_stats = jnp.concatenate([tap.stats, rt.stats])
 
     # --- PPPoE encap post-stage: downstream data whose post-DNAT dst is
     # an OPEN PPPoE session gets its AC framing here (the reference builds
@@ -232,36 +245,39 @@ def pipeline_step(
     if tables.pppoe_by_ip is not None:
         from bng_tpu.ops.pppoe import pppoe_encap
 
-        enc_et = jnp.where(~from_access, parsed.ethertype, 0)
-        pppoe_enc = pppoe_encap(nat.out_pkt, length, parsed.vlan_offset,
-                                enc_et, dnat_dst, tables.pppoe_by_ip,
-                                geom.pppoe, tables.pppoe_server_mac)
+        with jax.named_scope("pppoe"):
+            enc_et = jnp.where(~from_access, parsed.ethertype, 0)
+            pppoe_enc = pppoe_encap(nat.out_pkt, length, parsed.vlan_offset,
+                                    enc_et, dnat_dst, tables.pppoe_by_ip,
+                                    geom.pppoe, tables.pppoe_server_mac)
 
     # --- verdict combination (precedence: TX > DROP > FWD > PASS) ---
-    drop = (spoof_drop | qos_drop | garden_drop) & ~dhcp_tx
-    # a routed (next-hop-rewritten) lane forwards even when NAT left it
-    # untouched — the non-CGNAT routed-subscriber case
-    fwd = nat_fwd | (route_fwd & ~drop & ~dhcp_tx)
-    out_pkt = jnp.where(dhcp_tx[:, None], dhcp.out_pkt, data_pkt)
-    out_len = jnp.where(dhcp_tx, dhcp.out_len, length)
-    if pppoe_enc is not None:
-        enc_done = pppoe_enc.done & ~drop & ~dhcp_tx
-        out_pkt = jnp.where(enc_done[:, None], pppoe_enc.out_pkt, out_pkt)
-        out_len = jnp.where(enc_done, pppoe_enc.out_len, out_len)
-        # an encapsulated frame forwards even when NAT left it untouched
-        # (routed/IPoE-free deployments still need the PPP framing)
-        fwd = fwd | enc_done
-    verdict = jnp.where(
-        dhcp_tx, VERDICT_TX,
-        jnp.where(drop, VERDICT_DROP,
-                  jnp.where(fwd, VERDICT_FWD, VERDICT_PASS)),
-    ).astype(jnp.int32)
+    with jax.named_scope("rewrite"):
+        drop = (spoof_drop | qos_drop | garden_drop) & ~dhcp_tx
+        # a routed (next-hop-rewritten) lane forwards even when NAT left it
+        # untouched — the non-CGNAT routed-subscriber case
+        fwd = nat_fwd | (route_fwd & ~drop & ~dhcp_tx)
+        out_pkt = jnp.where(dhcp_tx[:, None], dhcp.out_pkt, data_pkt)
+        out_len = jnp.where(dhcp_tx, dhcp.out_len, length)
+        if pppoe_enc is not None:
+            enc_done = pppoe_enc.done & ~drop & ~dhcp_tx
+            out_pkt = jnp.where(enc_done[:, None], pppoe_enc.out_pkt, out_pkt)
+            out_len = jnp.where(enc_done, pppoe_enc.out_len, out_len)
+            # an encapsulated frame forwards even when NAT left it untouched
+            # (routed/IPoE-free deployments still need the PPP framing)
+            fwd = fwd | enc_done
+        verdict = jnp.where(
+            dhcp_tx, VERDICT_TX,
+            jnp.where(drop, VERDICT_DROP,
+                      jnp.where(fwd, VERDICT_FWD, VERDICT_PASS)),
+        ).astype(jnp.int32)
 
     # NAT accounting only for lanes that actually forward: a packet the
     # pipeline drops (QoS/antispoof) must not advance session counters
-    new_sessions = nat44_update_sessions(
-        tables.nat.sessions, nat, parsed, length,
-        keep=nat_fwd & ~drop, now_s=now_s)
+    with jax.named_scope("nat44"):
+        new_sessions = nat44_update_sessions(
+            tables.nat.sessions, nat, parsed, length,
+            keep=nat_fwd & ~drop, now_s=now_s)
     new_tables = tables._replace(
         nat=tables.nat._replace(sessions=new_sessions),
         qos_up=up.table,

@@ -31,7 +31,6 @@ arrays with a leading mesh dimension.
 from __future__ import annotations
 
 import functools
-import time
 from typing import NamedTuple
 
 import numpy as np
@@ -48,6 +47,7 @@ from bng_tpu.runtime.engine import (AntispoofTables, GardenTables, QoSTables,
                                     _apply_all_updates)
 from bng_tpu.runtime.tables import (FastPathTables,
                                     PPPoEFastPathTables)
+from bng_tpu.telemetry import spans as tele
 from bng_tpu.utils.net import mac_to_u64, split_u64
 
 AXIS = "shard"
@@ -112,10 +112,11 @@ def _sharded_step_jit(mesh: Mesh, geom: PipelineGeom, n: int,
                                 now_s, now_us)
         new_tables1 = jax.tree.map(lambda x: x[None], res.tables)
         # global stats over ICI (per-CPU map -> one counter)
-        dhcp_stats = jax.lax.psum(res.dhcp_stats, AXIS)
-        nat_stats = jax.lax.psum(res.nat_stats, AXIS)
-        qos_stats = jax.lax.psum(res.qos_stats, AXIS)
-        spoof_stats = jax.lax.psum(res.spoof_stats, AXIS)
+        with jax.named_scope("stats"):
+            dhcp_stats = jax.lax.psum(res.dhcp_stats, AXIS)
+            nat_stats = jax.lax.psum(res.nat_stats, AXIS)
+            qos_stats = jax.lax.psum(res.qos_stats, AXIS)
+            spoof_stats = jax.lax.psum(res.spoof_stats, AXIS)
         out = (res.verdict, res.out_pkt, res.out_len, new_tables1,
                dhcp_stats, nat_stats, qos_stats, spoof_stats,
                res.nat_punt, res.spoof_violation)
@@ -183,19 +184,17 @@ def _sharded_dhcp_jit(mesh: Mesh, geom: PipelineGeom, n: int,
 
 
 class ShardTelemetry:
-    """Per-shard stage histograms + verdict/punt counters — the
-    observability prerequisite for promoting the 8-chip dryrun to the
-    serving path (ROADMAP [scale]).
+    """Per-shard verdict/punt counters — the sharded path's counts.
 
-    The sharded step is ONE program over the mesh, so host-visible
-    per-shard latency attribution has exactly two honest quantities:
-    the host `dispatch` cost (device_put + drain + enqueue) and the
-    `device_wait` force — each recorded as one lap per step into every
-    shard's histogram that had real lanes in the batch (an idle shard
-    accumulates nothing; `total` = dispatch + wait). What DOES differ
-    per shard is the work: verdict counts (tx/fwd/drop/pass), NAT
-    egress-miss punts and antispoof violations are counted from each
-    shard's lane region of the batch.
+    Counters only. The sharded step is ONE program over the mesh, so
+    there is no per-shard latency to tell apart: the loop's times are
+    the Tracer's (telemetry/spans.py, lane `sharded`: ring, pack, drain,
+    dispatch, device, device_wait, reply, tx), stamped once a step by the
+    loop itself, and the snapshot carries the Tracer's tiling and
+    device-occupancy sums as its `trace` subtree. What DOES differ per
+    shard is the work: verdict counts (tx/fwd/drop/pass), NAT egress-miss
+    punts and antispoof violations are counted from each shard's lane
+    region of the batch.
 
     PASS accounting (the serving-path split, ISSUE 12): now that the
     ring classifier owns the steering decision, wrong-shard punts are
@@ -210,23 +209,13 @@ class ShardTelemetry:
     remains the historical upper bound. DHCP hits are psum-reduced ON
     DEVICE (ops cross-shard answer) — the host folds the global
     counter.
-
-    Histograms are telemetry/hist.py LatencyHists, so per-shard
-    distributions merge into a fleet-wide view by plain counter
-    addition — the same associative/commutative merge law the
-    slow-path fleet's worker histograms use (test-pinned).
     """
 
-    STAGES = ("dispatch", "device_wait", "total")
     VERDICT_NAMES = ("pass", "drop", "tx", "fwd")
 
     def __init__(self, n_shards: int, batch_per_shard: int):
-        from bng_tpu.telemetry.hist import LatencyHist
-
         self.n = n_shards
         self.b = batch_per_shard
-        self.hists = [{s: LatencyHist() for s in self.STAGES}
-                      for _ in range(n_shards)]
         self.frames = np.zeros((n_shards,), dtype=np.int64)
         self.verdicts = np.zeros((n_shards, 4), dtype=np.int64)
         self.nat_punts = np.zeros((n_shards,), dtype=np.int64)
@@ -241,18 +230,8 @@ class ShardTelemetry:
         self.frames += real.sum(axis=1)
         return real
 
-    def _lap(self, shard_active: np.ndarray, dispatch_us: float,
-             wait_us: float) -> None:
-        for i in np.nonzero(shard_active)[0]:
-            h = self.hists[int(i)]
-            h["dispatch"].record(dispatch_us)
-            h["device_wait"].record(wait_us)
-            h["total"].record(dispatch_us + wait_us)
-        self.steps += 1
-
     def record_fused(self, length, verdict, nat_punt, viol,
-                     dhcp_hits: int, dispatch_us: float,
-                     wait_us: float, missteer=None) -> None:
+                     dhcp_hits: int, missteer=None) -> None:
         real = self._active(length)
         v = np.asarray(verdict).reshape(self.n, self.b)
         for k in range(4):
@@ -270,34 +249,21 @@ class ShardTelemetry:
             self.violations += (np.asarray(viol).reshape(self.n, self.b)
                                 & real).sum(axis=1)
         self.psum_dhcp_hits += int(dhcp_hits)
-        self._lap(real.any(axis=1), dispatch_us, wait_us)
+        self.steps += 1
 
-    def record_dhcp(self, length, is_reply, dhcp_hits: int,
-                    dispatch_us: float, wait_us: float) -> None:
+    def record_dhcp(self, length, is_reply, dhcp_hits: int) -> None:
         real = self._active(length)
         rep = np.asarray(is_reply).reshape(self.n, self.b) & real
         self.dhcp_replies += rep.sum(axis=1)
         self.verdicts[:, 2] += rep.sum(axis=1)  # replies TX
         self.verdicts[:, 0] += (real & ~rep).sum(axis=1)  # misses punt
         self.psum_dhcp_hits += int(dhcp_hits)
-        self._lap(real.any(axis=1), dispatch_us, wait_us)
-
-    def merged(self):
-        """Fold every shard's histograms into one per-stage view —
-        LatencyHist.merge (counter addition), the fleet's worker-
-        histogram discipline, so order never matters."""
-        from bng_tpu.telemetry.hist import LatencyHist
-
-        out = {s: LatencyHist() for s in self.STAGES}
-        for shard in self.hists:
-            for s in self.STAGES:
-                out[s].merge(shard[s])
-        return out
+        self.steps += 1
 
     def snapshot(self) -> dict:
-        """The MULTICHIP JSON / metrics payload: per-shard stage
-        summaries + counters, the merged view, and the psum-reduced
-        global DHCP hit counter."""
+        """The MULTICHIP JSON / metrics payload: per-shard counters, the
+        psum-reduced global DHCP hit counter, and the Tracer's sums for
+        the loop (`trace`: armed, or as the last disarm left them)."""
         per_shard = []
         for i in range(self.n):
             verdicts = {name: int(self.verdicts[i, k])
@@ -313,8 +279,6 @@ class ShardTelemetry:
                 "missteers": int(self.missteers[i]),
                 "violations": int(self.violations[i]),
                 "dhcp_replies": int(self.dhcp_replies[i]),
-                "stages": {s: self.hists[i][s].summary()
-                           for s in self.STAGES if self.hists[i][s].n},
             })
         return {
             "shards": self.n,
@@ -330,8 +294,7 @@ class ShardTelemetry:
             "missteer_total": int(self.missteers.sum()),
             "nat_punt_total": int(self.nat_punts.sum()),
             "per_shard": per_shard,
-            "merged_stages": {s: h.summary()
-                              for s, h in self.merged().items() if h.n},
+            "trace": tele.trace_sums(),
         }
 
 
@@ -455,8 +418,8 @@ class ShardedCluster:
         from bng_tpu.utils.structlog import SlowPathErrorLog
 
         self._slow_err_log = SlowPathErrorLog("sharded")
-        # per-shard stage histograms + psum-hit/punt counters (merged
-        # like the fleet's worker histograms). dryrun_multichip stamps
+        # per-shard verdict + psum-hit/punt counters (the loop's times
+        # are the Tracer's, lane `sharded`). dryrun_multichip stamps
         # the snapshot into its MULTICHIP JSON; a composition root that
         # owns a cluster AND a BNGMetrics exports it via
         # BNGMetrics.collect_sharded (the serving-path promotion's
@@ -829,13 +792,19 @@ class ShardedCluster:
         Outputs stay device futures (async half)."""
         if self.tables is None:
             self.sync_tables()
+        t0 = tele.t()
         sh = NamedSharding(self.mesh, P(AXIS))
         pkt_d = jax.device_put(pkt, sh)
         len_d = jax.device_put(length.astype(np.uint32), sh)
+        tele.lap(tele.PACK, t0)
+        t0 = tele.t()
         upd = self._drain_fastpath()
+        tele.lap(tele.DRAIN, t0)
+        t0 = tele.t()
         dhcp1, is_reply, out_pkt, out_len, stats = self._dhcp_step(
             self.tables.dhcp, upd, pkt_d, len_d, jnp.uint32(now_s))
         self.tables = self.tables._replace(dhcp=dhcp1)
+        tele.lap(tele.DISPATCH, t0)
         return is_reply, out_pkt, out_len, stats
 
     def _dispatch_fused(self, pkt, length, from_access, now_s: int,
@@ -845,17 +814,23 @@ class ShardedCluster:
         device futures (async half)."""
         if self.tables is None:
             self.sync_tables()
+        t0 = tele.t()
         sh = NamedSharding(self.mesh, P(AXIS))
         pkt_d = jax.device_put(pkt, sh)
         len_d = jax.device_put(length.astype(np.uint32), sh)
         fa_d = jax.device_put(from_access, sh)
+        tele.lap(tele.PACK, t0)
         # drain FIRST: a bulk-build resync rebinds self.tables, and Python
         # evaluates arguments left-to-right — reading self.tables before
         # the drain would pass (and donate) the stale pre-resync reference
+        t0 = tele.t()
         upd = self._drain_updates()
+        tele.lap(tele.DRAIN, t0)
+        t0 = tele.t()
         raw = self._step(self.tables, upd, pkt_d, len_d, fa_d,
                          jnp.uint32(now_s), jnp.uint32(now_us))
         self.tables = raw[3]
+        tele.lap(tele.DISPATCH, t0)
         return raw
 
     def dhcp_step(self, pkt: np.ndarray, length: np.ndarray, now_s: int):
@@ -868,20 +843,23 @@ class ShardedCluster:
         """
         from bng_tpu.ops.dhcp import ST_HIT
 
-        t0 = time.perf_counter()
-        is_reply, out_pkt, out_len, stats = self._dispatch_dhcp(
-            pkt, length, now_s)
-        t1 = time.perf_counter()
-        out = {
-            "is_reply": np.asarray(is_reply),
-            "out_pkt": out_pkt,
-            "out_len": np.asarray(out_len),
-            "dhcp_stats": np.asarray(stats),
-        }
-        t2 = time.perf_counter()
+        tok = tele.begin_batch(tele.LANE_SHARDED, len(length))
+        try:
+            is_reply, out_pkt, out_len, stats = self._dispatch_dhcp(
+                pkt, length, now_s)
+        except BaseException:
+            tele.cancel_batch(tok)
+            raise
+        tele.device_up(tok)
+        t0 = tele.t()
+        out = {"is_reply": np.asarray(is_reply)}
+        tele.device_down(tok)
+        out.update(out_pkt=out_pkt, out_len=np.asarray(out_len),
+                   dhcp_stats=np.asarray(stats))
+        tele.lap(tele.DEVICE_WAIT, t0, tok)
         self.telemetry.record_dhcp(
-            length, out["is_reply"], int(out["dhcp_stats"][ST_HIT]),
-            (t1 - t0) * 1e6, (t2 - t1) * 1e6)
+            length, out["is_reply"], int(out["dhcp_stats"][ST_HIT]))
+        tele.end_batch(tok)
         return out
 
     def process_ring(self, ring, now_s: int, now_us: int,
@@ -915,12 +893,14 @@ class ShardedCluster:
             # retire it — WITH this call's handlers, or its PASS frames
             # would pop from the slow ring and vanish (Engine parity)
             self.flush_pipeline(slow_path, violation_sink)
+        t0 = tele.t()
         pkt, length, flags = self._staging(self._stage_idx, pkt_slot)
         got = ring.assemble_sharded(pkt, length, flags)
         if not got:
+            tele.lap(tele.RING, t0)
             return 0
         entry = self._dispatch_ring_batch(ring, pkt, length, flags, got,
-                                          now_s, now_us)
+                                          now_s, now_us, t0)
         self._retire(entry, slow_path, violation_sink)
         return got
 
@@ -944,13 +924,18 @@ class ShardedCluster:
         try:
             # 1. feed the mesh first: assemble into the buffer prev is NOT
             # using, so its frames stay intact until retirement
+            self._probe(prev)
+            t0 = tele.t()
             idx = 1 - self._stage_idx
             pkt, length, flags = self._staging(idx, pkt_slot)
             got = ring.assemble_sharded(pkt, length, flags)
-            if got:
+            if not got:
+                tele.lap(tele.RING, t0)
+            else:
                 try:
                     entry = self._dispatch_ring_batch(
-                        ring, pkt, length, flags, got, now_s, now_us)
+                        ring, pkt, length, flags, got, now_s, now_us, t0,
+                        prev)
                 except BaseException:
                     # fail closed: the assemble opened a ring window that
                     # must not wedge. complete() retires FIFO, so the
@@ -987,24 +972,50 @@ class ShardedCluster:
         return self._ring_bufs[idx]
 
     def _dispatch_ring_batch(self, ring, pkt, length, flags, got,
-                             now_s: int, now_us: int):
+                             now_s: int, now_us: int, t_ring=None,
+                             prev=None):
         """Dispatch one assembled window to the mesh WITHOUT forcing the
         outputs (they stay device futures until _retire) — the async half
-        of the beat, so a pipelined caller overlaps demux with compute."""
+        of the beat, so a pipelined caller overlaps demux with compute.
+        `t_ring` is the Tracer origin of the assemble that filled the
+        window; `prev` the window still in flight, probed for readiness
+        between the stages. The entry's last field is the batch token."""
         from bng_tpu.runtime.ring import FLAG_DHCP_CTRL
 
-        real = length > 0
-        all_ctrl = bool(((flags[real] & FLAG_DHCP_CTRL) != 0).all())
-        t0 = time.perf_counter()
-        if all_ctrl:  # the multichip OFFER-latency fast lane
-            is_reply, out_pkt, out_len, stats = self._dispatch_dhcp(
-                pkt, length, now_s)
-            out = ("dhcp", is_reply, out_pkt, out_len, stats)
-        else:
-            out = ("fused", self._dispatch_fused(
-                pkt, length, (flags & 0x1) != 0, now_s, now_us))
-        dispatch_us = (time.perf_counter() - t0) * 1e6
-        return (ring, out, pkt, length, flags, got, now_s, dispatch_us)
+        tok = tele.begin_batch(tele.LANE_SHARDED, got)
+        tele.lap(tele.RING, t_ring, tok)
+        self._probe(prev)
+        try:
+            t0 = tele.t()
+            real = length > 0
+            all_ctrl = bool(((flags[real] & FLAG_DHCP_CTRL) != 0).all())
+            fa = None if all_ctrl else (flags & 0x1) != 0
+            tele.lap(tele.PACK, t0, tok)
+            if all_ctrl:  # the multichip OFFER-latency fast lane
+                is_reply, out_pkt, out_len, stats = self._dispatch_dhcp(
+                    pkt, length, now_s)
+                out = ("dhcp", is_reply, out_pkt, out_len, stats)
+            else:
+                out = ("fused", self._dispatch_fused(pkt, length, fa,
+                                                     now_s, now_us))
+        except BaseException:
+            tele.cancel_batch(tok)  # a failed dispatch must not leak a slot
+            raise
+        self._probe(prev)  # before this window goes up: was the mesh idle?
+        tele.device_up(tok)
+        return (ring, out, pkt, length, flags, got, now_s, tok)
+
+    @staticmethod
+    def _probe(entry) -> None:
+        """Armed only: has the mesh finished this in-flight window? The
+        loop looks between its stages, so device time by readiness is at
+        most one host stage late (spans.py device_down). Disarmed: one
+        global load and compare."""
+        if entry is not None and tele.device_pending(entry[-1]):
+            out = entry[1]
+            first = out[1] if out[0] == "dhcp" else out[1][0]
+            if first.is_ready():
+                tele.device_down(entry[-1])
 
     def _retire(self, entry, slow_path, violation_sink) -> int:
         """Force a dispatched window's outputs and demux verdicts back to
@@ -1014,13 +1025,15 @@ class ShardedCluster:
         from bng_tpu.ops.dhcp import ST_HIT
         from bng_tpu.runtime.ring import VERDICT_PASS, VERDICT_TX
 
-        ring, out, pkt, length, flags, got, now_s, dispatch_us = entry
+        ring, out, pkt, length, flags, got, now_s, tok = entry
         B = self.n * self.b
         real = length > 0
-        t0 = time.perf_counter()
+        tele.focus(tok)
+        t0 = tele.t()
         if out[0] == "dhcp":
             _, is_reply, out_pkt, out_len, stats = out
             is_reply_h = np.asarray(is_reply)
+            tele.device_down(tok)
             verdict = np.where(is_reply_h, np.uint8(VERDICT_TX),
                                np.uint8(VERDICT_PASS))
             punt = np.zeros((B,), dtype=bool)
@@ -1030,10 +1043,10 @@ class ShardedCluster:
             self._fold_stats(dhcp=stats_h)
             out_pkt_h = np.asarray(out_pkt)
             out_len_h = np.asarray(out_len).astype(np.uint32)
-            wait_us = (time.perf_counter() - t0) * 1e6
+            tele.lap(tele.DEVICE_WAIT, t0, tok)
+            t0 = tele.t()
             self.telemetry.record_dhcp(length, is_reply_h,
-                                       int(stats_h[ST_HIT]),
-                                       dispatch_us, wait_us)
+                                       int(stats_h[ST_HIT]))
         else:
             (verdict_d, out_pkt, out_len, _tables, dhcp_stats, nat_stats,
              qos_stats, spoof_stats, nat_punt, viol_d, *tails) = out[1]
@@ -1043,6 +1056,7 @@ class ShardedCluster:
             mir = tails.pop(0) if self.edge is not None else None
             e_stats = tails.pop(0) if self.edge is not None else None
             verdict = np.asarray(verdict_d).astype(np.uint8)
+            tele.device_down(tok)
             punt = np.asarray(nat_punt)
             viol = np.asarray(viol_d)
             dhcp_h = np.asarray(dhcp_stats)
@@ -1058,7 +1072,9 @@ class ShardedCluster:
                                    if e_stats is not None else None))
             out_pkt_h = np.asarray(out_pkt)
             out_len_h = np.asarray(out_len).astype(np.uint32)
-            wait_us = (time.perf_counter() - t0) * 1e6
+            tele.lap(tele.DEVICE_WAIT, t0, tok)
+            self._probe(self._inflight)
+            t0 = tele.t()
             # exact missteer classification (ISSUE 12): a PASS lane that
             # is not a NAT new-flow punt and whose affinity owner is a
             # DIFFERENT shard punted because the steering put it in the
@@ -1073,10 +1089,15 @@ class ShardedCluster:
                     missteer[lane] = True
             self.telemetry.record_fused(length, verdict, punt, viol,
                                         int(dhcp_h[ST_HIT]),
-                                        dispatch_us, wait_us,
                                         missteer=missteer)
+        tele.lap(tele.REPLY, t0, tok)
+        self._probe(self._inflight)
+        t0 = tele.t()
         ring.complete(verdict, out_pkt_h, out_len_h, B)
+        tele.lap(tele.TX, t0, tok)
+        self._probe(self._inflight)
 
+        t0 = tele.t()
         if violation_sink is not None:
             for lane in np.nonzero(viol)[0]:
                 violation_sink(int(lane),
@@ -1101,10 +1122,15 @@ class ShardedCluster:
                 elif slow_path is not None:
                     reply = slow_path(frame)
                     if reply is not None:
+                        t1 = tele.t()
                         ring.tx_inject(reply, from_access=(fl & 0x1) != 0)
+                        tele.lap(tele.TX, t1, tok)
             except Exception as e:  # noqa: BLE001 — slow path is untrusted input
                 self.stats["slow_errors"] += 1
                 self._slow_err_log.report(e, path="ring", lane=int(lane))
+        tele.lap(tele.SLOW, t0, tok)
+        self._probe(self._inflight)
+        tele.end_batch(tok)
         return got
 
     def _fold_stats(self, **deltas) -> None:
@@ -1187,17 +1213,25 @@ class ShardedCluster:
         """
         from bng_tpu.ops.dhcp import ST_HIT
 
-        t0 = time.perf_counter()
-        out = self._dispatch_fused(pkt, length, from_access, now_s, now_us)
-        t1 = time.perf_counter()
+        tok = tele.begin_batch(tele.LANE_SHARDED, len(length))
+        try:
+            out = self._dispatch_fused(pkt, length, from_access, now_s,
+                                       now_us)
+        except BaseException:
+            tele.cancel_batch(tok)
+            raise
+        tele.device_up(tok)
         (verdict, out_pkt, out_len, _new_tables, dhcp_stats, nat_stats,
          qos_stats, spoof_stats, nat_punt, viol, *tails) = out
         tails = list(tails)
         garden_stats = [tails.pop(0)] if self.garden is not None else []
         pppoe_stats = [tails.pop(0)] if self.pppoe is not None else []
         edge_out = list(tails[:2]) if self.edge is not None else []
+        t0 = tele.t()
+        verdict_h = np.asarray(verdict)
+        tele.device_down(tok)
         res = {
-            "verdict": np.asarray(verdict),
+            "verdict": verdict_h,
             "out_pkt": out_pkt,
             "out_len": np.asarray(out_len),
             "dhcp_stats": np.asarray(dhcp_stats),
@@ -1214,11 +1248,11 @@ class ShardedCluster:
                 "edge_stats": np.asarray(edge_out[1])}
                if edge_out else {}),
         }
-        t2 = time.perf_counter()
+        tele.lap(tele.DEVICE_WAIT, t0, tok)
         self.telemetry.record_fused(
             length, res["verdict"], res["nat_punt"], res["violation"],
-            int(res["dhcp_stats"][ST_HIT]),
-            (t1 - t0) * 1e6, (t2 - t1) * 1e6)
+            int(res["dhcp_stats"][ST_HIT]))
+        tele.end_batch(tok)
         return res
 
     # ---- serving-path operations (quiesce / checkpoint / swap / expiry) --

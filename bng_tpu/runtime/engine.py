@@ -66,6 +66,7 @@ from bng_tpu.utils.structlog import ErrorLog, SlowPathErrorLog
 PKT_SLOT = 1536
 
 
+@jax.named_scope("updates")  # metadata only: the device trace's stage name
 def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
     """upd layout: 7 mandatory entries + optional named tails — garden
     (garden_upd, allowed_rows), then pppoe (sid_upd, ip_upd), then edge
@@ -1362,10 +1363,11 @@ class Engine:
         return n
 
     def _apply_ring_verdicts(self, ring, res: PipelineResult, pkt, length,
-                             n: int, now: float) -> None:
+                             n: int, now: float, tok=None) -> None:
         """Force the step's outputs and demux verdicts back to the ring."""
         t0 = tele.t()
         vv = np.asarray(res.verdict)[:n]
+        tele.device_down(tok)  # the pipelined loop's window, seen ready
         out_pkt = np.asarray(res.out_pkt)
         out_len = np.asarray(res.out_len).astype(np.uint32)
         tele.lap(tele.DEVICE_WAIT, t0)
@@ -1491,6 +1493,7 @@ class Engine:
                                   pkt, length, n)
                     raise
                 tele.lap(tele.DISPATCH, t0, tok)
+                tele.device_up(tok)
                 self._inflight = (ring, res, pkt, length, n, now, tok)
                 self._stage_idx = idx
         finally:
@@ -1505,7 +1508,7 @@ class Engine:
             return 0
         ring, res, pkt, length, n, now, tok = entry
         tele.focus(tok)
-        self._apply_ring_verdicts(ring, res, pkt, length, n, now)
+        self._apply_ring_verdicts(ring, res, pkt, length, n, now, tok)
         self._fold_stats(res)
         tele.end_batch(tok)
         return n

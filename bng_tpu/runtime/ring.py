@@ -272,6 +272,7 @@ class NativeRing:
         self.frame_size = frame_size
         self.depth = depth
         self.n_shards = n_shards
+        self._tx_refused = 0
 
     @property
     def umem_ptr(self):
@@ -316,7 +317,10 @@ class NativeRing:
     def tx_inject(self, frame: bytes, from_access: bool = True) -> bool:
         buf = np.frombuffer(frame, dtype=np.uint8)
         fl = FLAG_FROM_ACCESS if from_access else 0
-        return self._lib.bng_ring_tx_inject(self._h, _u8p(buf), len(frame), fl) == 0
+        ok = self._lib.bng_ring_tx_inject(self._h, _u8p(buf), len(frame), fl) == 0
+        if not ok:
+            self._tx_refused += 1
+        return ok
 
     # -- batch wire verbs (the vector wire pump, runtime/xsk.py) --------
     def umem_view(self) -> np.ndarray:
@@ -461,7 +465,11 @@ class NativeRing:
     def stats(self) -> dict:
         s = RingStats()
         self._lib.bng_ring_get_stats(self._h, C.byref(s))
-        return {f: getattr(s, f) for f, _ in RingStats._fields_}
+        out = {f: getattr(s, f) for f, _ in RingStats._fields_}
+        # replies tx_inject refused (TX ring full / no free frame): the
+        # caller's frame is gone, so it is counted where it is refused
+        out["tx_refused"] = self._tx_refused
+        return out
 
 
 def wire_pump(a, b, budget: int = 256) -> int:
@@ -527,6 +535,7 @@ class PyRing:
         self._pub_ips: dict[int, int] = {}
         self._pub_sorted = None  # (keys u64 sorted, vals i64) mirror
         self._stats = {k: 0 for k, _ in RingStats._fields_}
+        self._stats["tx_refused"] = 0  # tx_inject said no: the frame is gone
         if self._vec:
             # SoA frame store: slot-indexed, preallocated once. The
             # invariant: a slot reachable from an RX queue is ZERO
@@ -663,6 +672,7 @@ class PyRing:
     def tx_inject(self, frame: bytes, from_access: bool = True) -> bool:
         if (len(frame) > self.frame_size or self._free == 0
                 or len(self._tx) >= self.depth):
+            self._stats["tx_refused"] += 1
             return False
         self._free -= 1
         fl = FLAG_FROM_ACCESS if from_access else 0

@@ -146,6 +146,12 @@ class TieredScheduler:
         self.completions: deque[Completion] = deque()
         self.completions_dropped = 0
         self.oversize_dropped = 0
+        # always-on integers, no clock: express batches dispatched while
+        # a bulk step was in flight on their device (their wait is the
+        # `device_wait` lap's; no clean `device` sample exists), and bulk
+        # retires that blocked because the completion ring overflowed
+        self.express_behind_bulk = 0
+        self.bulk_blocked_retires = 0
         self._seq = 0
         # bulk-lane dhcp read replica (lazy; refreshed on cadence/resync)
         self._bulk_dhcp = None
@@ -391,7 +397,9 @@ class TieredScheduler:
         if upd is None:
             return
         self._prefetched_upd = None
+        t0 = tele.t()
         self.engine.apply_updates_now(upd)
+        tele.lap(tele.DRAIN, t0)
         self._drains_applied += 1
 
     def quiesce(self, now: float | None = None) -> int:
@@ -512,6 +520,7 @@ class TieredScheduler:
                 # buffer is free to rewrite after depth+1 dispatches);
                 # the fill is ONE stacked numpy assignment, not a
                 # per-frame copy loop
+                tp = tele.t()
                 desc = self._desc_bufs[self._desc_i]
                 self._desc_i = (self._desc_i + 1) % len(self._desc_bufs)
                 desc[:] = 0
@@ -520,6 +529,7 @@ class TieredScheduler:
                     idxs = [i for i, p in enumerate(pend)
                             if p.desc is not None]
                     desc[idxs] = rows
+                tele.lap(tele.PACK, tp, tok)
                 res = eng.run_express_aot(exe, desc, now,
                                           device=self._express_dev)
                 # snapshot the pool/server config of THIS dispatch's
@@ -531,8 +541,10 @@ class TieredScheduler:
                 self.express_aot_dispatches += 1
                 tele.set_meta("express_program", "aot-express")
             else:
+                tp = tele.t()
                 pkt, length = eng._pack_frames([p.frame for p in pend],
                                                self.express.cfg.batch)
+                tele.lap(tele.PACK, tp, tok)
                 res = eng._run_dhcp_batch(pkt, length, now,
                                           device=self._express_dev)
                 self.express_jit_dispatches += 1
@@ -541,6 +553,11 @@ class TieredScheduler:
             tele.cancel_batch(tok)  # a failed dispatch must not leak a slot
             raise
         tele.lap(tele.DISPATCH, t0, tok)
+        shares = self._express_dev in (None, self._bulk_dev)
+        behind = shares and len(self._bulk_ring) > 0
+        if behind:
+            self.express_behind_bulk += 1
+        tele.device_up(tok, 0 if shares else 1, sample=not behind)
         self._observe_dispatch(LANE_EXPRESS, len(pend), reason)
         over = self._express_ring.push(
             InflightEntry(res, pend, now, reason, trace=tok,
@@ -566,6 +583,7 @@ class TieredScheduler:
         tele.focus(entry.trace)
         t0 = tele.t()
         verdict = np.asarray(res.verdict)[:n]
+        tele.device_down(entry.trace)
         out_len = np.asarray(res.out_len)
         tele.lap(tele.DEVICE_WAIT, t0, entry.trace)
         out_rows = None
@@ -591,6 +609,7 @@ class TieredScheduler:
                 eng.stats.passed += 1
                 self._complete(p, LANE_EXPRESS, "slow", replies.get(i), now)
         tele.lap(tele.REPLY, t0, entry.trace)
+        self._trace_sojourn(entry, tele.LANE_EXPRESS_L)
         tele.end_batch(entry.trace)
         self._observe_retire(LANE_EXPRESS, entry, now)
         return n
@@ -606,6 +625,7 @@ class TieredScheduler:
         tele.focus(entry.trace)
         t0 = tele.t()
         block = np.asarray(entry.res.block)[:n]
+        tele.device_down(entry.trace)
         tele.lap(tele.DEVICE_WAIT, t0, entry.trace)
         eng._fold_stats(entry.res)
         now = self.clock()
@@ -629,6 +649,7 @@ class TieredScheduler:
                 eng.stats.passed += 1
                 self._complete(p, LANE_EXPRESS, "slow", replies.get(i), now)
         tele.lap(tele.REPLY, t0, entry.trace)
+        self._trace_sojourn(entry, tele.LANE_EXPRESS_L)
         tele.end_batch(entry.trace)
         self._observe_retire(LANE_EXPRESS, entry, now)
         return n
@@ -698,7 +719,12 @@ class TieredScheduler:
     def _pump_bulk(self, now: float) -> int:
         retired = 0
         # opportunistic: retire the already-finished FIFO prefix
-        for entry in self._bulk_ring.pop_ready(self._entry_ready):
+        ready = self._bulk_ring.pop_ready(self._entry_ready)
+        for i, entry in enumerate(ready):
+            # first seen ready HERE; a second entry ready at the same look
+            # finished at a time nobody saw: no `device` sample for it
+            tele.device_down(entry.trace, clean=i == 0)
+        for entry in ready:
             retired += self._retire_bulk(entry)
         while True:
             reason = self.bulk.close_reason(now)
@@ -709,6 +735,7 @@ class TieredScheduler:
             if over is not None:
                 # the completion ring overflowed its depth: the single
                 # place the bulk lane blocks on device results
+                self.bulk_blocked_retires += 1
                 retired += self._retire_bulk(over)
         return retired
 
@@ -724,8 +751,10 @@ class TieredScheduler:
         if (self._bulk_dhcp is not None and not refresh_due
                 and self._replica_resync == eng.resync_count):
             return
+        t0 = tele.t()
         self._bulk_dhcp = jax.tree_util.tree_map(self._copy_to_bulk,
                                                  eng.tables.dhcp)
+        tele.lap(tele.DRAIN, t0)
         self._replica_resync = eng.resync_count
         self._replica_refreshes += 1
 
@@ -749,9 +778,11 @@ class TieredScheduler:
         if tok is not None:
             tele.observe(tele.LANE_WAIT, (now - pend[0].enq_t) * 1e6, tok)
         B = self.bulk.cfg.batch
+        t0 = tele.t()
         pkt, length = eng._pack_frames([p.frame for p in pend], B)
         fa = np.zeros((B,), dtype=bool)
         fa[: len(pend)] = [p.from_access for p in pend]
+        tele.lap(tele.PACK, t0, tok)
         t0 = tele.t()
         try:
             self._ensure_bulk_replica()
@@ -779,6 +810,7 @@ class TieredScheduler:
             tele.cancel_batch(tok)  # a failed dispatch must not leak a slot
             raise
         tele.lap(tele.DISPATCH, t0, tok)
+        tele.device_up(tok)
         if eng.resync_count != before:
             # a bulk-build resync fired inside the drain: the replica we
             # just threaded derives from pre-resync leaves; rebuild next
@@ -792,7 +824,9 @@ class TieredScheduler:
                      or self._bulk_seq % self.cfg.drain_every == 0)):
             # step N is on the device; build + start uploading step N+1's
             # bounded scatter NOW so the next dispatch pays no drain cost
+            t0 = tele.t()
             self._prefetched_upd = eng.prefetch_bulk_updates()
+            tele.lap(tele.DRAIN, t0, tok)
             self._drains_prefetched += 1
         self._observe_dispatch(LANE_BULK, len(pend), reason)
         return self._bulk_ring.push(
@@ -807,6 +841,7 @@ class TieredScheduler:
         tele.focus(entry.trace)
         t0 = tele.t()
         vv = np.asarray(res.verdict)[:n]
+        tele.device_down(entry.trace)
         out_len = np.asarray(res.out_len)
         punt = np.asarray(res.nat_punt)[:n]
         viol = np.asarray(res.spoof_violation)[:n]
@@ -853,6 +888,7 @@ class TieredScheduler:
             if viol[i] and eng.violation_sink is not None:
                 eng.violation_sink(i, p.frame)
         tele.lap(tele.REPLY, t0, entry.trace)
+        self._trace_sojourn(entry, tele.LANE_BULK_L)
         tele.end_batch(entry.trace, punt=punts)
         self._observe_retire(LANE_BULK, entry, now)
         return n
@@ -867,6 +903,15 @@ class TieredScheduler:
             self.completions_dropped += 1
         self.completions.append(Completion(
             p.tag, lane, verdict, frame, p.from_access, now - p.enq_t))
+
+    def _trace_sojourn(self, entry: InflightEntry, lane: int) -> None:
+        """Armed only: every frame's enqueue -> completion, once a
+        retired batch (the `sojourn` stage of its lane)."""
+        if tele.enabled():
+            done = self.clock()
+            tele.observe_many(tele.SOJOURN,
+                              [(done - p.enq_t) * 1e6 for p in entry.pending],
+                              entry.trace, lane=lane)
 
     def drain_completions(self) -> list[Completion]:
         out = list(self.completions)
@@ -922,6 +967,11 @@ class TieredScheduler:
         out["express"]["aot_misses"] = self.express_aot_misses
         out["express"]["loop"] = self.express_loop
         out["express"]["fallbacks"] = dict(self.express_fallbacks)
+        out["express"]["behind_bulk"] = self.express_behind_bulk
+        out["bulk"]["blocked_retires"] = self.bulk_blocked_retires
+        # the Tracer's tiling and device-occupancy sums (armed, or as the
+        # last disarm left them; zeros if never armed)
+        out["trace"] = tele.trace_sums()
         if self._devloop is not None:
             out["express"]["devloop"] = self._devloop.stats()
         out["completions_dropped"] = self.completions_dropped

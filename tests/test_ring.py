@@ -165,6 +165,55 @@ class TestRingBasics:
         r.close()
 
 
+class TestTxRefused:
+    def test_full_tx_ring_refuses_and_counts(self, ring_cls):
+        """A reply the TX ring has no room for is refused AND counted
+        (`stats()["tx_refused"]`): cli.py _drive_scheduler ignores the
+        refusal, so the count is the only trace of the lost frame."""
+        r = ring_cls(nframes=64, frame_size=256, depth=8)
+        assert r.stats()["tx_refused"] == 0
+        took = sum(r.tx_inject(bytes([i]) * 40, from_access=True)
+                   for i in range(12))
+        assert took == 8
+        assert r.stats()["tx_refused"] == 4
+        assert r.stats()["tx"] == 8
+        assert r.tx_pop() is not None           # room again
+        assert r.tx_inject(b"z" * 40)
+        assert r.stats()["tx_refused"] == 4
+        r.close()
+
+    def test_drive_scheduler_counts_what_it_loses(self):
+        """S-a, counted and not repaired: one retire of more replies than
+        the TX ring is deep loses the rest; `ring.tx_refused` says how
+        many."""
+        from bng_tpu.cli import BNGApp, BNGConfig
+        from bng_tpu.control import packets
+        from bng_tpu.utils.net import ip_to_u32
+
+        app = BNGApp(BNGConfig(synthetic_subs=1, batch_size=32,
+                               scheduler_enabled=True,
+                               dhcpv6_enabled=False, slaac_enabled=False))
+        app.config.synthetic_subs = 0  # the ring without its generator
+        try:
+            ring = app.components["ring"]
+            from bng_tpu.runtime.scheduler import Completion
+
+            sched = app.components["scheduler"]
+            frame = packets.udp_packet(b"\x02" * 6, b"\x04" * 6,
+                                       ip_to_u32("10.0.0.9"),
+                                       ip_to_u32("93.184.216.34"), 4000, 443,
+                                       b"x" * 18)
+            n = ring.depth + 5
+            for i in range(n):
+                sched.completions.append(
+                    Completion(i, "bulk", "fwd", frame, True, 0.0))
+            app.drive_once()
+            assert ring.stats()["tx_refused"] == 5
+            assert ring.tx_pending() == ring.depth
+        finally:
+            app.close()
+
+
 class TestWire:
     def test_loopback_pump_flips_direction(self, ring_cls):
         a = ring_cls(nframes=32, frame_size=256, depth=16)

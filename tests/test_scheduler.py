@@ -290,6 +290,91 @@ class TestPipelineDepth:
         assert retired == 40
 
 
+class TestTracedLoop:
+    """PR 25: the scheduler's stamps tile, the occupancy calls pair up,
+    the always-on integers count, and the `trace` subtree freezes."""
+
+    def _drive(self, sched, clock, base, n_bulk=24, n_dhcp=2):
+        for i in range(n_bulk):
+            sched.submit(data_frame(base + i))
+        for i in range(n_dhcp):
+            sched.submit(discover(mac_of(base + i), 100 + base + i))
+        clock.advance(0.01)
+        return sched.poll()
+
+    def test_trace_subtree_is_the_same_before_and_after_the_drain(self):
+        from bng_tpu.telemetry import spans as tele
+
+        engine, _, clock = build_stack(batch_size=8)
+        sched = TieredScheduler(engine, SchedulerConfig(
+            bulk_batch=8, bulk_depth=2, express_batch=4,
+            express_device_index=-1), clock=clock)
+        assert "beats" in sched.stats_snapshot()["trace"]  # always present
+        tr = tele.arm(tele.Tracer(keep_events=1 << 12))
+        try:
+            c0 = sched.stats_snapshot()
+            assert c0["trace"]["beats"] == 0 == c0["trace"]["batches"]
+            for k in range(3):
+                tele.beat_begin()
+                self._drive(sched, clock, 100 * k)
+                tele.beat_end()
+        finally:
+            tele.disarm()
+        frozen = sched.stats_snapshot()["trace"]
+        # more traffic and the drain, disarmed: nothing moves the sums
+        self._drive(sched, clock, 900)
+        sched.flush()
+        c1 = sched.stats_snapshot()
+        assert c1["trace"] == frozen
+        assert frozen["beats"] == 3 and frozen["batches"] >= 9
+        for stage in ("pack", "dispatch", "drain", "device", "device_wait",
+                      "reply", "sojourn", "lane_wait"):
+            assert frozen["stage_ns"][stage] > 0, stage
+        assert 0 < frozen["beat_self_ns"] < frozen["stage_ns"]["beat"]
+        assert sum(frozen["starved_ns"].values()) == \
+            frozen["beat_starved_ns"] + frozen["starved_ns"]["outside"]
+        # all that went up came down, but what was still in flight at
+        # disarm: the drain retires those unseen (at most the bulk depth)
+        assert len(tr._dev) <= 2
+        assert len(tr._free) == tr.OPEN_SLOTS - len(tr._dev)
+        # sojourns: one per frame retired while armed, by lane
+        soj = [e for e in tr.events if e[0] == tele.SOJOURN]
+        assert {e[1] for e in soj} == {tele.LANE_EXPRESS_L, tele.LANE_BULK_L}
+        assert len(tr.events) == len(tr.event_beats)
+        assert all(b >= 0 for e, b in zip(tr.events, tr.event_beats)
+                   if e[0] == tele.SOJOURN)  # retired inside a beat
+        assert tr.lane_hist(tele.LANE_BULK_L, tele.DEVICE).n >= 1
+
+    def test_always_on_integers_count_disarmed(self):
+        engine, _, clock = build_stack(batch_size=8)
+        sched = TieredScheduler(engine, SchedulerConfig(
+            bulk_batch=8, bulk_depth=2, express_batch=4,
+            express_device_index=-1), clock=clock)
+        # five full bulk batches in one poll: depth 2, so three retires block
+        for i in range(40):
+            sched.submit(data_frame(i))
+        sched.poll()
+        snap = sched.stats_snapshot()
+        assert snap["bulk"]["blocked_retires"] == 3
+        assert snap["express"]["behind_bulk"] == 0
+        # an express batch dispatched while bulk steps are in flight on
+        # the device it shares
+        assert len(sched._bulk_ring) == 2
+        for i in range(4):
+            sched.submit(discover(mac_of(i), 7 + i))
+        sched.poll()
+        snap = sched.stats_snapshot()
+        assert snap["express"]["behind_bulk"] == 1
+        assert snap["express"]["batches"] == 1
+        sched.flush()
+        # what an operator reads them from
+        metrics = BNGMetrics()
+        metrics.collect_scheduler(sched)
+        text = metrics.expose()
+        assert "bng_sched_bulk_blocked_retires_total 3" in text
+        assert "bng_sched_express_behind_bulk_total 1" in text
+
+
 @pytest.mark.hotpath
 class TestUpdateDrainCadence:
     def test_bulk_drains_every_n_dispatches(self):

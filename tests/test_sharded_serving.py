@@ -166,6 +166,98 @@ class TestMissteer:
 # sharded checkpoints: round-trips, re-shard, rejects
 # ---------------------------------------------------------------------------
 
+class TestShardedLoopOnTheTracer:
+    """PR 25: the sharded loop has no clock of its own. Armed, its stages
+    are the Tracer's `sharded` lane; disarmed, it reads no clock at all."""
+
+    def _beats(self, cl, ring, macs, n=3):
+        out = 0
+        for k in range(n):
+            for i, mac in enumerate(macs[:4]):
+                assert ring.rx_push(discover(mac, 10 * k + i),
+                                    from_access=True)
+            out += cl.process_ring_pipelined(ring, NOW + k, k * 1000)
+        return out + cl.flush_pipeline()
+
+    def test_disarmed_loop_reads_no_clock(self, monkeypatch):
+        import time
+
+        import bng_tpu.parallel.sharded as sharded_mod
+        from bng_tpu.telemetry import spans as tele
+
+        assert not hasattr(sharded_mod, "time")
+        cl = make_cluster()
+        macs = populate(cl)
+        cl.sync_tables()
+        ring = cl.make_ring(nframes=256, frame_size=2048, depth=64)
+        self._beats(cl, ring, macs, n=1)  # compile outside the stub
+        reads = []
+        for name in ("perf_counter", "perf_counter_ns", "monotonic",
+                     "monotonic_ns", "time", "time_ns"):
+            real = getattr(time, name)
+            monkeypatch.setattr(
+                time, name, lambda real=real, name=name:
+                (reads.append(name), real())[1])
+        stub = tele.Tracer(clock=lambda: reads.append("tracer") or 0)
+        assert not tele.enabled()
+        assert self._beats(cl, ring, macs) == 12
+        cl.dhcp_step(*self._dhcp_batch(cl, macs), NOW)
+        monkeypatch.undo()
+        assert reads == [], reads
+        assert stub.sums()["beats"] == 0
+        snap = cl.telemetry.snapshot()
+        assert snap["steps"] >= 5 and "trace" in snap
+        assert "stages" not in snap["per_shard"][0]
+
+    def _dhcp_batch(self, cl, macs):
+        B = cl.n * cl.b
+        pkt = np.zeros((B, 2048), dtype=np.uint8)
+        length = np.zeros((B,), dtype=np.uint32)
+        f = discover(macs[0], 99)
+        pkt[0, : len(f)] = np.frombuffer(f, dtype=np.uint8)
+        length[0] = len(f)
+        return pkt, length
+
+    def test_armed_loop_stamps_the_sharded_lane(self):
+        from bng_tpu.telemetry import spans as tele
+
+        cl = make_cluster()
+        macs = populate(cl)
+        cl.sync_tables()
+        ring = cl.make_ring(nframes=256, frame_size=2048, depth=64)
+        self._beats(cl, ring, macs, n=1)
+        with tele.armed(keep_events=1 << 12) as tr:
+            for k in range(3):
+                tele.beat_begin()
+                for i, mac in enumerate(macs[:4]):
+                    ring.rx_push(discover(mac, 50 + 10 * k + i),
+                                 from_access=True)
+                cl.process_ring_pipelined(ring, NOW + k, k * 1000)
+                tele.beat_end()
+            tele.beat_begin()
+            cl.flush_pipeline()
+            tele.beat_end()
+            snap = cl.telemetry.snapshot()
+        lane = tele.LANE_SHARDED
+        for stage in (tele.RING, tele.PACK, tele.DRAIN, tele.DISPATCH,
+                      tele.DEVICE, tele.DEVICE_WAIT, tele.REPLY, tele.TX,
+                      tele.TOTAL):
+            h = tr.lane_hist(lane, stage)
+            # pack is two laps a window: flag columns, then the staging
+            want = 6 if stage == tele.PACK else 3
+            assert h.n == want, tele.STAGE_NAMES[stage]
+        assert not tr._dev and len(tr._free) == tr.OPEN_SLOTS
+        t = snap["trace"]
+        assert t["beats"] == 4 and t["batches"] == 3
+        assert sum(t["starved_ns"].values()) == \
+            t["beat_starved_ns"] + t["starved_ns"]["outside"]
+        # the loop's stages tile its beats: little is left unclaimed
+        assert t["beat_self_ns"] < 0.5 * t["stage_ns"]["beat"]
+        beats = [b for e, b in zip(tr.events, tr.event_beats)
+                 if e[1] == lane and e[0] == tele.DISPATCH]
+        assert beats == [0, 1, 2]  # one dispatch a beat, the flush none
+
+
 def save_bytes(cl, dhcp=None) -> bytes:
     return encode_checkpoint(
         build_sharded_checkpoint(cl, 1, float(NOW), dhcp=dhcp))

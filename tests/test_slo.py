@@ -46,11 +46,49 @@ class TestSpec:
         assert dev[0].p99_limit_us == \
             slo.HEADLINE_TARGETS["offer_device_only_p99_us"] == 50.0
 
+    def test_device_spec_reads_the_express_lane_only(self, tmp_path):
+        """A 236 ms bulk step's `device` sample must neither breach nor
+        dilute the 50 us OFFER budget: the spec is bound to the express
+        lane, in the one-shot verdict and in the live monitor."""
+        dev = [s for s in slo.DEFAULT_SLOS if s.stage == "device"][0]
+        assert dev.lane == "express"
+        with pytest.raises(ValueError, match="unknown lane"):
+            slo.SLOSpec("device", 50.0, lane="warp")
+        tr = tele.Tracer()
+        bulk = tr.begin(tele.LANE_BULK_L, 8)
+        ex = tr.begin(tele.LANE_EXPRESS_L, 2)
+        mon = slo.SLOMonitor(tr, slos=(dev,), window_s=10.0, burn_windows=1)
+        mon.tick(0.0)
+        for _ in range(64):
+            tr.observe(tele.DEVICE, 236_000.0, bulk)
+            tr.observe(tele.DEVICE, 30.0, ex)
+        # bench.py's profiler-fenced samples are another quantity and go
+        # to another lane: they neither dilute nor breach the served path's
+        tr.observe_many(tele.DEVICE, [829.0] * 64, lane=tele.LANE_BENCH)
+        assert tr.lane_hist(tele.LANE_EXPRESS_L, tele.DEVICE).n == 64
+        assert slo.evaluate(tr.breakdown(lanes=True), (dev,))["ok"]
+        # a breakdown without lane entries is read merged, as before
+        assert not slo.evaluate(tr.breakdown(), (dev,))["ok"]
+        assert mon.tick(11.0) == []
+        assert mon.snapshot()["window_p99_us"]["device"] < 50.0
+        for _ in range(64):
+            tr.observe(tele.DEVICE, 900.0, ex)       # the express lane's own
+        assert mon.tick(22.0) == ["device"]
+        assert not slo.evaluate(tr.breakdown(lanes=True), (dev,))["ok"]
+        # no express sample at all: nothing to judge, not a breach
+        quiet = tele.Tracer()
+        quiet.observe(tele.DEVICE, 236_000.0, quiet.begin(tele.LANE_BULK_L, 1))
+        assert slo.evaluate(quiet.breakdown(lanes=True), (dev,))["ok"]
+
     def test_parse_budgets(self):
         specs = slo.parse_budgets(["dispatch:1000", "fleet:2000:64"])
         assert specs[0].stage == "dispatch"
         assert specs[0].p99_limit_us == 1000.0 and specs[0].per == 1.0
         assert specs[1].per == 64.0
+        # an override moves the limit and keeps the lane the spec judges
+        dev = slo.parse_budgets(["device:5000"])[0]
+        assert (dev.p99_limit_us, dev.lane) == (5000.0, "express")
+        assert specs[0].lane == ""
         with pytest.raises(ValueError, match="bad SLO budget"):
             slo.parse_budgets(["dispatch"])
         with pytest.raises(ValueError, match="unknown stage"):
@@ -101,8 +139,8 @@ class TestBudgetRehome:
         hand-built expectation."""
         tr = tele.Tracer()
         for _ in range(4):
-            tr.hists[tele.FLEET].record(1000.0)   # mean 1000
-            tr.hists[tele.ADMIT].record(10.0)     # mean 10
+            tr.observe(tele.FLEET, 1000.0)   # mean 1000
+            tr.observe(tele.ADMIT, 10.0)     # mean 10
         lines = (
             slo.BudgetLine("admit", limit_us=50.0),            # ok
             slo.BudgetLine("fleet", limit_us=100.0, per=5.0),  # 200 > 100
@@ -117,14 +155,14 @@ class TestBudgetRehome:
 
     def test_clean_budget_verdict(self):
         tr = tele.Tracer()
-        tr.hists[tele.ADMIT].record(1.0)
+        tr.observe(tele.ADMIT, 1.0)
         v = slo.check_budget(tr, (slo.BudgetLine("admit", 100.0),))
         assert v == {"ok": True, "breaches": []}
 
     def test_breach_fires_slo_breach_trigger(self, tmp_path):
         rec = FlightRecorder(RecorderConfig(out_dir=str(tmp_path)))
         with tele.armed(recorder=rec) as tr:
-            tr.hists[tele.FLEET].record(1000.0)
+            tr.observe(tele.FLEET, 1000.0)
             slo.check_budget(tr, (slo.BudgetLine("fleet", 1.0),))
         assert rec.triggers.get("slo_breach") == 1
 
@@ -252,9 +290,7 @@ class TestShardTelemetry:
         verdict = rng.integers(0, 4, size=n * b).astype(np.uint8)
         punt = rng.integers(0, 2, size=n * b).astype(bool)
         viol = np.zeros(n * b, dtype=bool)
-        st.record_fused(length, verdict, punt, viol, 7,
-                        dispatch_us=100.0 * (seed + 1),
-                        wait_us=10.0 * (seed + 1))
+        st.record_fused(length, verdict, punt, viol, 7)
         return length, verdict, punt
 
     def test_counters_from_lane_regions(self):
@@ -266,7 +302,7 @@ class TestShardTelemetry:
         verdict = np.array([2, 0, 1, 1, 3, 3, 1, 0], dtype=np.uint8)
         punt = np.array([0, 1, 0, 0, 0, 0, 0, 0], dtype=bool)
         viol = np.array([0, 0, 0, 0, 0, 0, 1, 0], dtype=bool)
-        st.record_fused(length, verdict, punt, viol, 5, 100.0, 10.0)
+        st.record_fused(length, verdict, punt, viol, 5)
         snap = st.snapshot()
         s0, s1 = snap["per_shard"]
         # shard 0: 2 real lanes (tx, pass); padding lanes never counted
@@ -286,7 +322,7 @@ class TestShardTelemetry:
         st = ShardTelemetry(2, 2)
         length = np.array([100, 100, 100, 0], dtype=np.uint32)
         is_reply = np.array([True, False, True, False])
-        st.record_dhcp(length, is_reply, 2, 50.0, 5.0)
+        st.record_dhcp(length, is_reply, 2)
         snap = st.snapshot()
         assert snap["per_shard"][0]["dhcp_replies"] == 1
         assert snap["per_shard"][0]["verdicts"]["pass"] == 1
@@ -295,36 +331,46 @@ class TestShardTelemetry:
         assert snap["per_shard"][1]["verdicts"]["pass"] == 0
 
     def test_merge_laws(self):
-        """The merged view is plain counter addition over per-shard
-        histograms — associative and commutative, the same law the
+        """The sharded loop's stage times live on the Tracer's `sharded`
+        lane; a stage's merged histogram is plain counter addition over
+        its lanes — associative and commutative, the same law the
         fleet's worker-histogram merge is pinned to."""
-        from bng_tpu.parallel.sharded import ShardTelemetry
         from bng_tpu.telemetry.hist import LatencyHist
 
-        st = ShardTelemetry(3, 4)
-        for seed in range(5):
-            self._rec(st, seed)
-        merged = st.merged()
-        for stage in ShardTelemetry.STAGES:
-            fwd = LatencyHist()
-            for shard in st.hists:
-                fwd.merge(shard[stage])
-            rev = LatencyHist()
-            for shard in reversed(st.hists):
-                rev.merge(shard[stage])
+        tr = tele.Tracer()
+        toks = {lane: tr.begin(lane, 4) for lane in
+                (tele.LANE_SHARDED, tele.LANE_BULK_L, tele.LANE_EXPRESS_L)}
+        rng = np.random.default_rng(5)
+        for stage in (tele.DISPATCH, tele.DEVICE_WAIT, tele.REPLY):
+            for lane, tok in toks.items():
+                for us in rng.integers(1, 5000, size=7 + lane):
+                    tr.observe(stage, float(us), tok)
+            lanes = [tr.lane_hist(lane, stage) for lane in toks]
+            fwd, rev = LatencyHist(), LatencyHist()
+            for h in lanes:
+                fwd.merge(h)
+            for h in reversed(lanes):
+                rev.merge(h)
             assert np.array_equal(fwd.counts, rev.counts)
-            assert np.array_equal(merged[stage].counts, fwd.counts)
-            assert merged[stage].n == sum(sh[stage].n for sh in st.hists)
+            assert np.array_equal(tr.stage_hist(stage).counts, fwd.counts)
+            assert tr.stage_hist(stage).n == sum(h.n for h in lanes)
+            assert tr.lane_hist(tele.LANE_SHARDED, stage).n == \
+                7 + tele.LANE_SHARDED
 
     def test_idle_shard_records_nothing(self):
         from bng_tpu.parallel.sharded import ShardTelemetry
 
         st = ShardTelemetry(2, 2)
         length = np.array([100, 100, 0, 0], dtype=np.uint32)
-        st.record_fused(length, np.zeros(4, np.uint8), None, None, 0,
-                        10.0, 1.0)
-        assert st.hists[0]["total"].n == 1
-        assert st.hists[1]["total"].n == 0  # idle shard: no lap
+        st.record_fused(length, np.zeros(4, np.uint8), None, None, 0)
+        snap = st.snapshot()
+        assert snap["steps"] == 1  # one program over the mesh: one step
+        assert snap["per_shard"][0]["frames"] == 2
+        assert snap["per_shard"][1]["frames"] == 0  # idle shard: no lane
+        assert sum(snap["per_shard"][1]["verdicts"].values()) == 0
+        # counters only: the loop's times are the Tracer's
+        assert "stages" not in snap["per_shard"][0]
+        assert "merged_stages" not in snap
 
     def test_snapshot_is_json_serializable(self):
         from bng_tpu.parallel.sharded import ShardTelemetry
@@ -367,15 +413,23 @@ class TestMetricsExport:
         cl = _FakeCluster()
         length = np.array([100, 100, 100, 0], dtype=np.uint32)
         verdict = np.array([2, 0, 3, 0], dtype=np.uint8)
-        cl.telemetry.record_fused(length, verdict, None, None, 3,
-                                  20.0, 2.0)
+        cl.telemetry.record_fused(length, verdict, None, None, 3)
         m = BNGMetrics()
-        m.collect_sharded(cl)
+        with tele.armed() as tr:
+            tok = tr.begin(tele.LANE_SHARDED, 3)
+            tr.observe(tele.DISPATCH, 20.0, tok)
+            tr.observe(tele.DISPATCH, 900.0, tr.begin(tele.LANE_BULK_L, 1))
+            m.collect_sharded(cl)
         text = m.expose()
         assert "bng_shard_psum_dhcp_hits_total 3" in text
         assert ('bng_shard_frames_total{shard="0",verdict="tx"} 1'
                 in text)
-        assert 'bng_shard_stage_p99_us{shard="0",stage="total"}' in text
+        # one program over the mesh: one value a stage, from the
+        # Tracer's sharded lane (the bulk lane's 900 us is not in it)
+        line = [ln for ln in text.splitlines() if ln.startswith(
+            'bng_sharded_stage_p99_us{stage="dispatch"}')]
+        assert line and float(line[0].split()[-1]) < 30.0
+        assert "bng_shard_stage_p99_us" not in text
 
 
 class TestLoadtestResultField:

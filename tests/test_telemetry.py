@@ -284,7 +284,7 @@ class TestFleetTracing:
             from bng_tpu.control.fleet import shard_for_mac
             shards = {shard_for_mac(m, 2) for m in macs}
             assert shards == {0, 1}  # both workers saw traffic
-            assert tr.hists[spans.WORKER].n >= 2 * len(macs)
+            assert tr.stage_hist(spans.WORKER).n >= 2 * len(macs)
         fleet.close()
 
     def test_chaos_worker_kill_dumps_flight_record(self, tmp_path):
@@ -571,7 +571,7 @@ class TestDoraTracingE2E:
                     p.encode().ljust(320, b"\x00"))
                 out = fleet.handle_batch([(0, frame)])
                 assert out[0][1] is not None
-                assert tr.hists[spans.WORKER].n >= 1
+                assert tr.stage_hist(spans.WORKER).n >= 1
             finally:
                 fleet.close()
 
@@ -589,3 +589,271 @@ class TestDoraTracingE2E:
         # and `trace status` sees the dir
         rc = cli.main(["trace", "status", "--trace-dir", str(tmp_path)])
         assert rc == 0
+
+
+# ---------------------------------------------------------------------------
+# one clock inside the loop (PR 25): beats tile, events carry ids beside
+# them, device occupancy and starvation by readiness, per-lane histograms
+# ---------------------------------------------------------------------------
+
+class _Clock:
+    """A scripted ns clock: the test sets `.now`, the Tracer reads it."""
+
+    def __init__(self):
+        self.now = 0
+
+    def __call__(self):
+        return self.now
+
+
+def _lap(tr, clk, stage, a, b, tok=None):
+    clk.now = b
+    tr.lap(stage, a, tok)
+
+
+class TestBeatTiling:
+    def test_children_plus_self_is_the_beat_and_nested_laps_count_once(self):
+        clk = _Clock()
+        tr = Tracer(clock=clk, keep_events=64)
+        clk.now = 1_000
+        tr.beat_begin()
+        _lap(tr, clk, spans.RING, 1_100, 1_300)         # 200
+        # drain [1500,1600] nested inside dispatch [1400,1900]: union 500
+        _lap(tr, clk, spans.DRAIN, 1_500, 1_600)
+        _lap(tr, clk, spans.DISPATCH, 1_400, 1_900)
+        # two laps that overlap by 50: union 250
+        _lap(tr, clk, spans.REPLY, 2_000, 2_150)
+        _lap(tr, clk, spans.TX, 2_100, 2_250)
+        clk.now = 3_000
+        tr.beat_end()
+        # a lap outside any beat claims nothing of a beat
+        _lap(tr, clk, spans.OPS, 3_100, 3_500)
+        clk.now = 4_000
+        tr.beat_begin()
+        _lap(tr, clk, spans.RING, 3_900, 4_400)         # clipped to 400
+        clk.now = 4_500
+        tr.beat_end()
+        s = tr.sums()
+        assert s["beats"] == 2
+        assert s["stage_ns"]["beat"] == 2_000 + 500
+        # what no lap claims: the beats less the union of their children
+        assert s["beat_self_ns"] == 2_500 - (200 + 500 + 250 + 400)
+        # the per-stage sums are plain sums of samples (the nested lap's
+        # 100 ns is in `drain` AND inside `dispatch`'s 500)
+        assert s["stage_ns"]["drain"] == 100
+        assert s["stage_ns"]["dispatch"] == 500
+        assert s["stage_ns"]["ring"] == 200 + 500
+        assert tr.stage_hist(spans.BEAT).n == 2
+
+    def test_events_stay_4_tuples_with_beat_ids_beside_them(self):
+        clk = _Clock()
+        tr = Tracer(clock=clk, keep_events=64)
+        _lap(tr, clk, spans.OPS, 10, 20)                # no batch, no beat
+        clk.now = 100
+        tr.beat_begin()
+        tok = tr.begin(spans.LANE_BULK_L, 3)
+        _lap(tr, clk, spans.DISPATCH, 110, 150, tok)
+        tok2 = tr.begin(spans.LANE_EXPRESS_L, 1)
+        _lap(tr, clk, spans.DISPATCH, 160, 170, tok2)
+        tr.observe_many(spans.SOJOURN, [5.0, 7.0], tok)
+        clk.now = 200
+        tr.end(tok)
+        tr.beat_end()
+        assert all(len(e) == 4 for e in tr.events)
+        assert len(tr.events) == len(tr.event_beats)
+        rows = list(zip(tr.events, tr.event_beats))
+        by = lambda stage: [(e, i) for e, i in rows if e[0] == stage]  # noqa: E731
+        assert by(spans.OPS)[0][1] == -1
+        (e_b, beat_b), (e_x, beat_x) = by(spans.DISPATCH)
+        assert (e_b[1], beat_b) == (spans.LANE_BULK_L, 0)
+        assert (e_x[1], beat_x) == (spans.LANE_EXPRESS_L, 0)
+        soj = by(spans.SOJOURN)
+        assert [e[3] for e, _ in soj] == [5_000, 7_000]
+        assert all(i == 0 and e[1] == spans.LANE_BULK_L for e, i in soj)
+        assert by(spans.TOTAL)[0][1] == 0
+        assert by(spans.BEAT)[0] == ((spans.BEAT, 0, 100, 100), 0)
+        # a lap after the beat's end, and the next beat's
+        _lap(tr, clk, spans.OPS, 210, 220)
+        clk.now = 300
+        tr.beat_begin()
+        _lap(tr, clk, spans.RING, 310, 320)
+        assert list(tr.event_beats)[-2:] == [-1, 1]
+        # the exporters unpack 4-tuples
+        assert len(chrome_trace(tr)["traceEvents"]) >= len(tr.events)
+
+
+class TestDeviceOccupancy:
+    def test_depth_two_samples_run_from_the_previous_ready(self):
+        clk = _Clock()
+        tr = Tracer(clock=clk, keep_events=64)
+        a = tr.begin(spans.LANE_BULK_L, 8)
+        clk.now = 1_000
+        tr.device_up(a)
+        b = tr.begin(spans.LANE_BULK_L, 8)
+        clk.now = 1_500
+        tr.device_up(b)                      # queued behind a
+        clk.now = 237_000
+        tr.device_down(a)                    # first seen ready
+        tr.device_down(a)                    # the retire says it again
+        clk.now = 473_000
+        tr.device_down(b)
+        dev = [e for e in tr.events if e[0] == spans.DEVICE]
+        # a: from its own dispatch end; b: from when a was seen ready
+        assert [e[3] for e in dev] == [236_000, 236_000]
+        assert [e[2] for e in dev] == [1_000, 237_000]
+        h = tr.lane_hist(spans.LANE_BULK_L, spans.DEVICE)
+        assert h.n == 2 and not tr.lane_hist(spans.LANE_EXPRESS_L,
+                                             spans.DEVICE).n
+
+    def test_express_behind_bulk_gives_no_sample_and_keeps_the_bulk_one(self):
+        clk = _Clock()
+        tr = Tracer(clock=clk)
+        bulk = tr.begin(spans.LANE_BULK_L, 8)
+        clk.now = 1_000
+        tr.device_up(bulk)
+        ex = tr.begin(spans.LANE_EXPRESS_L, 2)
+        clk.now = 100_000
+        tr.device_up(ex, sample=False)       # queued behind the bulk step
+        clk.now = 238_000
+        tr.device_down(ex)                   # forced: the bulk step is done
+        clk.now = 238_100
+        tr.device_down(bulk)                 # seen at the next pop_ready
+        assert tr.lane_hist(spans.LANE_EXPRESS_L, spans.DEVICE).n == 0
+        assert tr.lane_hist(spans.LANE_BULK_L, spans.DEVICE).max_us == \
+            pytest.approx(237.1)
+        # two seen ready at one look: the second finished nobody knows when
+        c = tr.begin(spans.LANE_BULK_L, 8)
+        d = tr.begin(spans.LANE_BULK_L, 8)
+        clk.now = 300_000
+        tr.device_up(c)
+        tr.device_up(d)
+        clk.now = 900_000
+        tr.device_down(c)
+        tr.device_down(d, clean=False)
+        assert tr.lane_hist(spans.LANE_BULK_L, spans.DEVICE).n == 2
+
+    def test_starvation_is_charged_to_the_stage_whose_lap_overlaps_it(self):
+        clk = _Clock()
+        tr = Tracer(clock=clk)
+        a = tr.begin(spans.LANE_BULK_L, 8)
+        clk.now = 1_000
+        tr.device_up(a)
+        clk.now = 5_000
+        tr.device_down(a)                    # idle from 5,000
+        # between beats, under no lap: the caller's
+        clk.now = 6_000
+        tr.beat_begin()                      # outside: 1,000
+        _lap(tr, clk, spans.RING, 6_100, 6_400)          # ring 300
+        # pack nested in dispatch; the device goes up at dispatch's end
+        b = tr.begin(spans.LANE_BULK_L, 8)
+        _lap(tr, clk, spans.PACK, 6_600, 6_700, b)       # pack 100
+        _lap(tr, clk, spans.DISPATCH, 6_500, 7_000, b)   # dispatch 400
+        tr.device_up(b)                      # window [5,000, 7,000] closes
+        _lap(tr, clk, spans.DRAIN, 7_000, 7_500, b)      # busy: no charge
+        clk.now = 8_000
+        tr.device_down(b)                    # idle again from 8,000
+        _lap(tr, clk, spans.REPLY, 7_900, 8_600, b)      # reply 600
+        clk.now = 9_000
+        tr.beat_end()                        # in the beat, under no lap
+        clk.now = 9_500
+        tr.finish()                          # outside: 500 more
+        s = tr.sums()
+        st = s["starved_ns"]
+        assert (st["ring"], st["pack"], st["dispatch"], st["reply"]) == \
+            (300, 100, 400, 600)
+        assert st["drain"] == 0
+        # the beat's own: [6000,7000] less 800 claimed, [8000,9000] less 600
+        assert st["beat"] == 200 + 400
+        assert st["outside"] == 1_000 + 500
+        # the invariant: by stage + `outside` = all the time nothing was in
+        # flight, [5000,7000] + [8000,9500]. What a layer metric reads is
+        # all but `outside` (the caller's time between beats; a profiler's
+        # stop alone can take seconds there)
+        assert sum(st.values()) == 2_000 + 1_500
+        assert s["beat_starved_ns"] == 2_000 + 1_500 - st["outside"]
+
+    def test_sums_freeze_at_disarm_and_stay_readable(self):
+        clk = _Clock()
+        zero = spans.trace_sums()
+        assert zero["beats"] == 0 and set(zero["stage_ns"]) == \
+            set(spans.STAGE_NAMES)
+        tr = spans.arm(Tracer(clock=clk))
+        assert spans.trace_sums()["beats"] == 0      # c0: right after arm
+        clk.now = 100
+        spans.beat_begin()
+        spans.lap(spans.RING, 120)
+        clk.now = 300
+        spans.beat_end()
+        spans.disarm()
+        frozen = spans.trace_sums()
+        assert frozen["beats"] == 1 and frozen["stage_ns"]["beat"] == 200
+        # the drain after the window: disarmed hooks add nothing
+        spans.beat_begin()
+        spans.lap(spans.RING, spans.t())
+        spans.beat_end()
+        spans.device_up(None)
+        assert spans.trace_sums() == frozen == tr.sums()
+
+    def test_per_lane_histograms_keep_lanes_apart(self):
+        tr = Tracer()
+        bulk = tr.begin(spans.LANE_BULK_L, 8)
+        ex = tr.begin(spans.LANE_EXPRESS_L, 2)
+        for _ in range(40):
+            tr.observe(spans.DEVICE, 236_000.0, bulk)
+            tr.observe(spans.DEVICE, 30.0, ex)
+        assert tr.stage_hist(spans.DEVICE).n == 80        # merged view
+        bd = tr.breakdown(lanes=True)
+        assert bd["device"]["p99_us"] > 200_000
+        assert bd["device@express"]["p99_us"] < 40
+        assert bd["device@bulk"]["count"] == 40
+        assert "device@express" not in tr.breakdown()
+
+
+class TestOneClockWithTheDeviceTrace:
+    def test_beats_anchor_the_tracer_clock_in_a_profiler_session(
+            self, tmp_path, monkeypatch):
+        """While a `jax.profiler` session runs, every beat leaves a
+        `bng.beat` annotation that carries the Tracer clock's reading at
+        its entry; the reducer over the recorded directory finds them
+        (no device plane on the CPU: the device parts stay empty)."""
+        import jax
+        import jax.numpy as jnp
+
+        from bng_tpu.utils import profiling
+
+        f = jax.jit(lambda a: (a @ a).sum())
+        x = jnp.ones((64, 64))
+        f(x).block_until_ready()
+        events = tmp_path / "events.json"
+        monkeypatch.setenv("BNG_TRACE_EVENTS", str(events))
+        tr = spans.arm(Tracer(keep_events=256))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for _ in range(3):
+                spans.beat_begin()
+                t0 = spans.t()
+                f(x).block_until_ready()
+                spans.lap(spans.DISPATCH, t0)
+                spans.beat_end()
+        finally:
+            jax.profiler.stop_trace()
+            spans.disarm()
+        out = profiling.reduce_trace(str(tmp_path), str(events))
+        assert out["anchors"] == 3 and out["devices"] == 0
+        log = json.loads(events.read_text())
+        assert len(log["events"]) == len(log["beats"]) == len(tr.events)
+        assert log["sums"]["beats"] == 3
+        beats = [e for e in log["events"] if log["stages"][e[0]] == "beat"]
+        assert len(beats) == 3
+
+    def test_scope_is_the_first_named_scope_on_the_op_path(self):
+        from bng_tpu.utils.profiling import _scope_of
+
+        assert _scope_of("jit(step)/dhcp/jit(take_along_axis)/gather:") == \
+            "dhcp"
+        assert _scope_of("jit(step)/jit(main)/nat44/while/body/x") == "nat44"
+        assert _scope_of("jit(step)/updates/scatter") == "updates"
+        assert _scope_of("jit(copy)/copy") == "(no scope)"
+        assert _scope_of("") == "(no scope)"
