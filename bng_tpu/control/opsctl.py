@@ -273,19 +273,17 @@ class FleetAutoscaler:
             self._last_look, self._last_shed = now, shed
             self._last_busy = busy
             return None
-        if busy < self._last_busy:
-            # a resize/rolling restart reset the per-worker stats the
-            # busy counter sums over — this look's delta is meaningless.
-            # Re-baseline and decide nothing: a negative delta must not
-            # credit a "calm" hysteresis look while the fleet may in
-            # fact be saturated.
-            self._last_look, self._last_shed = now, shed
-            self._last_busy = busy
-            return None
+        # a resize/rolling restart reset the per-worker stats the busy
+        # counter sums over: this look's busy delta is meaningless (and
+        # whether the fresh sum has passed the old one is a race with the
+        # wall clock). The shed counter is the fleet's own and never
+        # resets, so shedding still grows the fleet; nothing else is
+        # decided, and a negative delta never credits a "calm" look.
+        reset = busy < self._last_busy
         dt = now - self._last_look
         shed_delta = shed - self._last_shed
         busy_frac = ((busy - self._last_busy)
-                     / (dt * max(1, self.fleet.n))) if dt > 0 else 0.0
+                     / (dt * max(1, self.fleet.n))) if dt > 0 and not reset else 0.0
         self._last_look, self._last_shed = now, shed
         self._last_busy = busy
         if now - self._last_change < cfg.cooldown_s:
@@ -297,6 +295,8 @@ class FleetAutoscaler:
             self._last_change = now
             self.decisions += 1
             return min(cfg.max_workers, n + 1)
+        if reset:
+            return None
         if busy_frac <= cfg.busy_lo and shed_delta == 0:
             self._calm += 1
             if self._calm >= cfg.hold and n > cfg.min_workers:

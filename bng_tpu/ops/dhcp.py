@@ -5,8 +5,11 @@ TPU re-expression of the XDP program dhcp_fastpath_prog
 batch; `return XDP_PASS/XDP_TX` becomes per-lane verdict masks; the three
 eBPF map lookups become cuckoo-table gathers; the in-place packet rewrite +
 bpf_xdp_adjust_tail becomes a canonical-reply compose with per-lane VLAN
-reinsertion (a single gather — TPUs shift bytes with index arithmetic, not
-memmove).
+reinsertion. A byte shift whose amount takes a few static values (the tags:
+0/4/8; the options tail after 0, 1 or 2 DNS servers: 0/6/10) is a select
+among statically shifted copies at the reply's own width, never a per-byte
+gather over the slot: one over [8192, 1536] ran at 1.0 GB/s of a v5e's 819
+(ops/bytes.py's header; PERF.md section 6, PR 26).
 
 Parity notes (cited against /root/reference):
 - msg-type extraction at fixed offsets {0,1,3,4,5,6}: dhcp_fastpath.c:216-250
@@ -160,6 +163,23 @@ def _prefix_to_mask(plen):
     sh1 = jnp.minimum(sh, 16)
     sh2 = sh - sh1
     return (full << sh1) << sh2
+
+
+def _placed(seg, at: int, width: int):
+    """[B, n] segment at static column `at` of a zeroed [B, width] row
+    (cut at `width`)."""
+    n = seg.shape[1]
+    return jnp.pad(seg, ((0, 0), (at, max(width - at - n, 0))))[:, :width]
+
+
+def _placed_at(seg, at, choices: tuple[int, ...], width: int):
+    """`_placed` at a per-lane column `at` [B] that takes one of the static
+    `choices`: a select among the placed copies, one pass each, where a
+    gather would move one byte an index."""
+    out = _placed(seg, choices[-1], width)
+    for c in reversed(choices[:-1]):
+        out = jnp.where((at == c)[:, None], _placed(seg, c, width), out)
+    return out
 
 
 def dhcp_fastpath(
@@ -359,33 +379,36 @@ def dhcp_fastpath(
         B_.const_seg(Bsz, 255),
     ], axis=1)
 
-    # compose options area [B, _OPT_MAX]: head is fixed-offset; dns and tail
-    # shift with dns_sz, handled by two index-arithmetic gathers
+    # compose options area [B, _OPT_MAX]: head and dns sit at fixed offsets,
+    # tail follows dns (dns_sz is 0, 6 or 10)
     oj = jnp.arange(_OPT_MAX, dtype=jnp.int32)[None, :]
-    head_p = jnp.zeros((Bsz, _OPT_MAX), dtype=jnp.uint8).at[:, :_OPT_HEAD].set(head)
-    dns_idx = jnp.broadcast_to(jnp.clip(oj - _OPT_HEAD, 0, _OPT_DNS_MAX - 1), (Bsz, _OPT_MAX))
-    tail_idx = jnp.clip(oj - _OPT_HEAD - dns_sz[:, None], 0, _OPT_TAIL - 1)
-    dns_g = jnp.take_along_axis(dns, dns_idx, axis=1)
-    tail_g = jnp.take_along_axis(tail, tail_idx, axis=1)
+    head_dns = _placed(jnp.concatenate([head, dns], axis=1), 0, _OPT_MAX)
+    tail_p = _placed_at(tail, _OPT_HEAD + dns_sz,
+                        (_OPT_HEAD, _OPT_HEAD + 6, _OPT_HEAD + _OPT_DNS_MAX), _OPT_MAX)
     opt_area = jnp.where(
-        oj < _OPT_HEAD,
-        head_p,
-        jnp.where(
-            oj < (_OPT_HEAD + dns_sz[:, None]),
-            dns_g,
-            jnp.where(oj < opt_len[:, None], tail_g, 0),
-        ),
+        oj < _OPT_HEAD + dns_sz[:, None],
+        head_dns,
+        jnp.where(oj < opt_len[:, None], tail_p, 0),
     )
-    canon = jnp.concatenate([canon, opt_area.astype(jnp.uint8)], axis=1)
+    canon = jnp.concatenate([canon, opt_area], axis=1)
 
     # --- final compose with VLAN reinsertion ---
-    canon_L = jnp.zeros((Bsz, L), dtype=jnp.uint8).at[:, :CANON_LEN].set(canon)
-    jj = jnp.arange(L, dtype=jnp.int32)[None, :]
-    vo = parsed.vlan_offset[:, None]
-    shift_idx = jnp.clip(jj - vo, 0, L - 1)
-    canon_shift = jnp.take_along_axis(canon_L, shift_idx, axis=1)
-    out = jnp.where(jj < 12, canon_L, jnp.where(jj < 14 + vo, pkt, canon_shift))
+    # Everything after the MACs moves down by the request's tag bytes
+    # (vlan_offset is 0, 4 or 8). Composed at the reply's own width W, then
+    # zero-padded to the slot: out_len <= CANON_LEN + 8, and a narrower slot
+    # cuts the reply short.
+    W = min(L, CANON_LEN + 8)
+    jj = jnp.arange(W, dtype=jnp.int32)[None, :]
+    vo = parsed.vlan_offset
+    shifted = _placed_at(canon, vo, (0, 4, 8), W)
+    # bytes 0..11 the reply's MACs, 12..14+vo the request's tags + ethertype
+    out = jnp.where(
+        jj < 12,
+        _placed(canon, 0, W),
+        jnp.where(jj < 14 + vo[:, None], pkt[:, :W], shifted),
+    )
     out = jnp.where(jj < out_len[:, None].astype(jnp.int32), out, 0)
+    out = jnp.pad(out, ((0, 0), (0, L - W)))
 
     return DHCPResult(
         is_reply=reply,

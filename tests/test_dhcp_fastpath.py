@@ -321,3 +321,160 @@ class TestBatch:
         assert np.asarray(res.is_reply)[:4].tolist() == [True, False, False, True]
         st = np.asarray(res.stats)
         assert st[ST_TOTAL] == 3 and st[ST_HIT] == 2 and st[ST_MISS] == 1
+
+
+# --- reply bytes against a plain numpy reference -------------------------
+# The reference below shares nothing with ops/dhcp.py: it builds the
+# canonical (untagged) reply with struct, then reinserts the request's
+# VLAN tags the way the kernel always has, one byte index a column:
+# np.take_along_axis by clip(j - vlan_offset, 0, L-1). The kernel's
+# compose (selects over statically shifted copies) must give the same
+# bytes, and zeros from out_len to the slot's end.
+
+import struct
+
+_DNS_POOLS = {  # pool_id -> (dns1, dns2)
+    0: (0, 0),
+    1: (ip_to_u32("9.9.9.9"), 0),
+    2: (ip_to_u32("9.9.9.9"), ip_to_u32("1.1.1.1")),
+}
+_TAGS = {"untagged": None, "dot1q": [100], "qinq": [200, 31]}
+_MODES = ("broadcast", "relayed", "ciaddr_unicast")
+_RELAY_IP = ip_to_u32("10.9.9.9")
+
+
+def _ip_csum(hdr: bytes) -> int:
+    s = sum(struct.unpack("!10H", hdr))
+    while s >> 16:
+        s = (s & 0xFFFF) + (s >> 16)
+    return ~s & 0xFFFF
+
+
+def _ref_canonical(frame: bytes, yiaddr: int, pool: dict) -> tuple[bytes, int]:
+    """(canonical untagged reply, vlan_offset) for one request frame."""
+    vo = 0
+    if frame[12:14] in (b"\x81\x00", b"\x88\xa8"):
+        vo = 8 if frame[16:18] == b"\x81\x00" else 4
+    d = 14 + vo + 20 + 8  # BOOTP start (IHL is 5 in every test frame)
+    xid, secs, flags = frame[d + 4:d + 8], frame[d + 8:d + 10], frame[d + 10:d + 12]
+    ciaddr, giaddr, chaddr = frame[d + 12:d + 16], frame[d + 24:d + 28], frame[d + 28:d + 44]
+    mtype = frame[d + 240 + 2]  # option 53 leads in every test frame
+    relayed = giaddr != b"\0\0\0\0"
+    bcast = not relayed and (flags[0] & 0x80 or ciaddr == b"\0\0\0\0")
+    dst_mac = frame[6:12] if relayed else BCAST_MAC if bcast else chaddr[:6]
+    server = struct.pack("!I", SERVER_IP)
+    lease = pool["lease"]
+    opts = bytes([53, 1, dhcp_codec.OFFER if mtype == dhcp_codec.DISCOVER else dhcp_codec.ACK])
+    opts += bytes([54, 4]) + server + bytes([51, 4]) + struct.pack("!I", lease)
+    opts += bytes([1, 4]) + struct.pack("!I", (0xFFFFFFFF << (32 - pool["prefix"])) & 0xFFFFFFFF)
+    opts += bytes([3, 4]) + struct.pack("!I", pool["gateway"])
+    servers = [a for a in pool["dns"] if a]
+    if servers:
+        opts += bytes([6, 4 * len(servers)]) + b"".join(struct.pack("!I", a) for a in servers)
+    opts += bytes([58, 4]) + struct.pack("!I", lease // 2)
+    opts += bytes([59, 4]) + struct.pack("!I", lease * 7 // 8) + b"\xff"
+    bootp = (bytes([2, 1, 6, 0]) + xid + secs + flags + ciaddr + struct.pack("!I", yiaddr)
+             + server + giaddr + chaddr + bytes(192) + struct.pack("!I", 0x63825363) + opts)
+    udp = struct.pack("!HHHH", 67, 67 if relayed else 68, 8 + len(bootp), 0)
+    ip = struct.pack("!BBHHHBBH", 0x45, 0, 20 + len(udp) + len(bootp), 0, 0, 64, 17, 0)
+    ip += server + (giaddr if relayed else b"\xff" * 4)
+    ip = ip[:10] + struct.pack("!H", _ip_csum(ip)) + ip[12:]
+    return dst_mac + SERVER_MAC + b"\x08\x00" + ip + udp + bootp, vo
+
+
+def _ref_out(frames, canon, vo, slot):
+    """Today's VLAN reinsertion over a [n, slot] batch, in numpy."""
+    n = len(frames)
+    pkt = np.zeros((n, slot), np.uint8)
+    canon_l = np.zeros((n, slot), np.uint8)
+    for i, (f, c) in enumerate(zip(frames, canon)):
+        pkt[i, :len(f)] = np.frombuffer(f, np.uint8)
+        canon_l[i, :len(c)] = np.frombuffer(c, np.uint8)
+    j = np.arange(slot)[None, :]
+    v = np.asarray(vo)[:, None]
+    shifted = np.take_along_axis(canon_l, np.clip(j - v, 0, slot - 1), axis=1)
+    out = np.where(j < 12, canon_l, np.where(j < 14 + v, pkt, shifted))
+    out_len = np.array([len(c) for c in canon])[:, None] + v
+    return np.where(j < out_len, out, 0).astype(np.uint8), out_len[:, 0]
+
+
+@functools.lru_cache(maxsize=1)
+def _parity_tables():
+    t = FastPathTables(sub_nbuckets=256, vlan_nbuckets=64, cid_nbuckets=64, max_pools=16)
+    t.set_server_config(SERVER_MAC, SERVER_IP)
+    pools = {}
+    for pid, (d1, d2) in _DNS_POOLS.items():
+        pools[pid] = dict(prefix=20 + pid, gateway=ip_to_u32(f"10.{pid}.0.1"),
+                          dns=(d1, d2), lease=3600 * (pid + 1))
+        t.add_pool(pid, network=ip_to_u32(f"10.{pid}.0.0"), prefix_len=20 + pid,
+                   gateway=pools[pid]["gateway"], dns_primary=d1, dns_secondary=d2,
+                   lease_time=pools[pid]["lease"])
+    return t, pools
+
+
+def _junk_frames():
+    rng = np.random.default_rng(26)
+    junk = []
+    for i in range(3):
+        b = bytearray(rng.integers(0, 256, 90 + 130 * i, dtype=np.uint8).tobytes())
+        if i == 1:
+            b[12:14] = b"\x81\x00"
+        if i == 2:
+            b[12:14], b[16:18] = b"\x88\xa8", b"\x81\x00"
+        junk.append(bytes(b))
+    junk.append(packets.tcp_packet(bytes.fromhex("020000000001"), SERVER_MAC,
+                                   ip_to_u32("10.0.0.5"), ip_to_u32("1.1.1.1"), 1, 2))
+    return junk
+
+
+@pytest.mark.parametrize("slot", [512, 1536])
+@pytest.mark.parametrize("mode", _MODES)
+@pytest.mark.parametrize("n_dns", [0, 1, 2])
+@pytest.mark.parametrize("tags", list(_TAGS))
+def test_reply_bytes_match_numpy_reference(tags, n_dns, mode, slot):
+    t, pools = _parity_tables()
+    pool = pools[n_dns]
+    tag_no = list(_TAGS).index(tags)
+    mode_no = _MODES.index(mode)
+    frames, yiaddrs = [], []
+    for k, mtype in enumerate((dhcp_codec.DISCOVER, dhcp_codec.REQUEST)):
+        mac = bytes([2, 0x26, tag_no, n_dns, mode_no, k])
+        ip = ip_to_u32(f"10.{n_dns}.{1 + tag_no}.{10 + 2 * mode_no + k}")
+        t.add_subscriber(mac, pool_id=n_dns, ip=ip, lease_expiry=NOW + 600)
+        kw = {}
+        if mode == "relayed":
+            kw = dict(giaddr=_RELAY_IP)
+        elif mode == "ciaddr_unicast":
+            kw = dict(ciaddr=ip, src_ip=ip)
+        elif k:  # broadcast: a DISCOVER with no ciaddr, a REQUEST with the flag
+            kw = dict(ciaddr=ip, src_ip=ip, broadcast=True)
+        frames.append(dhcp_frame(mac, mtype, vlans=_TAGS[tags], **kw))
+        yiaddrs.append(ip)
+    junk = _junk_frames()
+
+    pkt = np.zeros((B, slot), dtype=np.uint8)
+    length = np.zeros((B,), dtype=np.uint32)
+    for i, f in enumerate(frames + junk):
+        pkt[i, :len(f)] = np.frombuffer(f, dtype=np.uint8)
+        length[i] = len(f)
+    res = _jitted(t.geom)(jnp.asarray(pkt), jnp.asarray(length), t.device_tables(),
+                          jnp.uint32(NOW))
+
+    canon, vo = zip(*(_ref_canonical(f, ip, pool) for f, ip in zip(frames, yiaddrs)))
+    assert vo == ({"untagged": 0, "dot1q": 4, "qinq": 8}[tags],) * 2
+    want, want_len = _ref_out(frames, canon, vo, slot)
+    got, got_len = np.asarray(res.out_pkt), np.asarray(res.out_len)
+    n = len(frames)
+    assert np.asarray(res.is_reply)[:n].all()
+    assert got_len[:n].tolist() == want_len.tolist()
+    assert got.shape == (B, slot)
+    for i in range(n):
+        assert bytes(got[i]) == bytes(want[i]), f"lane {i} differs from the reference"
+        assert not got[i, got_len[i]:].any(), "bytes beyond out_len must be zero"
+        # the host parser reads what the reference built
+        dec = packets.decode(bytes(got[i, :got_len[i]]))
+        assert dec.vlans == (_TAGS[tags] or []) and dec.ip_checksum_ok
+        assert dhcp_codec.decode(dec.payload).yiaddr == yiaddrs[i]
+    # junk and data lanes in the same batch (and the empty lanes) answer nothing
+    assert not np.asarray(res.is_reply)[n:].any()
+    assert not got_len[n:].any()

@@ -79,11 +79,7 @@ class TestQoSLookupShape:
 
 
 class TestDHCPFastpathShape:
-    def test_table_probes_are_wide_row_gathers(self):
-        """All three fast-path table probes (sub K=2, vlan K=1, cid K=8)
-        must gather packed bucket rows: 4x [1,32] (sub+vlan, KW=8) and
-        2x [1,64] (cid, KW=16). The 18 narrow key/used gathers of the
-        unpacked layout must not come back."""
+    def _lowered(self, L):
         from bng_tpu.ops.dhcp import dhcp_fastpath
         from bng_tpu.ops.parse import parse_batch
         from bng_tpu.runtime.tables import FastPathTables
@@ -92,8 +88,7 @@ class TestDHCPFastpathShape:
         fp = FastPathTables(sub_nbuckets=256, vlan_nbuckets=64,
                             cid_nbuckets=64, max_pools=16)
         fp.set_server_config(bytes.fromhex("02aabbccdd01"), ip_to_u32("10.0.0.1"))
-        tables = fp.device_tables()
-        B, L = 256, 512
+        B = 256
         pkt = jnp.zeros((B, L), dtype=jnp.uint8)
         ln = jnp.full((B,), 300, dtype=jnp.uint32)
 
@@ -102,13 +97,35 @@ class TestDHCPFastpathShape:
             res = dhcp_fastpath(pkt, ln, par, tables, fp.geom, jnp.uint32(1))
             return res.is_reply, res.out_pkt, res.out_len
 
-        hlo = _stablehlo(step, tables, pkt, ln)
+        return _stablehlo(step, fp.device_tables(), pkt, ln)
+
+    def test_table_probes_are_wide_row_gathers(self):
+        """All three fast-path table probes (sub K=2, vlan K=1, cid K=8)
+        must gather packed bucket rows: 4x [1,32] (sub+vlan, KW=8) and
+        2x [1,64] (cid, KW=16). The 18 narrow key/used gathers of the
+        unpacked layout must not come back."""
+        hlo = self._lowered(512)
         assert _count(r"slice_sizes = array<i64: 1, 32>", hlo) == 4
         assert _count(r"slice_sizes = array<i64: 1, 64>", hlo) == 2
         # per-lane packet-byte reads ([1,1]) are fine; whole-column
         # table-probe gathers ([S,1] operands) are the serialized shape
         narrow_1d = _count(r"slice_sizes = array<i64: 1>(?!,)", hlo)
         assert narrow_1d == 0, f"{narrow_1d} 1-D narrow gathers"
+
+    def test_reply_compose_has_no_byte_gather(self):
+        """The reply compose shifts bytes by one of three static amounts
+        (VLAN reinsertion 0/4/8, the options tail 0/6/10): selects over
+        statically shifted copies. A per-byte `take_along_axis` over the
+        slot measured 1.0 GB/s on a v5e (140 ms of a step at [8192, 1536],
+        PERF.md section 6, PR 26) and must not come back. What stays are
+        the request-side field reads, at most 32 bytes a lane."""
+        hlo = self._lowered(1536)
+        gathers = re.findall(r'"stablehlo\.gather"[^\n]*-> tensor<([0-9x]+)x(\w+)>', hlo)
+        assert gathers, "the pattern no longer finds the gathers"
+        wide = [(dims, ty) for dims, ty in gathers
+                if ty == "ui8" and "x" in dims and int(dims.split("x")[-1]) > 32]
+        assert not wide, f"byte gathers wider than 32 columns: {wide}"
+        assert len(gathers) <= 22, f"{len(gathers)} gathers in dhcp_fastpath (22 since PR 26)"
 
 
 class TestNAT44Shape:

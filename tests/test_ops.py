@@ -701,6 +701,24 @@ class TestAutoscaler:
         clock.advance(1.0)
         assert auto.target(clock()) == 1
 
+    def test_transition_reset_still_grows_on_shed(self):
+        """The look after a transition knows nothing of busy time, but
+        the shed counter never resets: shedding grows the fleet whether
+        or not the fresh busy sum has passed the old one (that race with
+        the wall clock made `bng chaos run` print 5 or 6 workers)."""
+        for busy_after in (0.0, 5.0):  # behind the old sum / past it
+            clock, fleet = self._fleet()
+            auto = FleetAutoscaler(
+                fleet, AutoscaleConfig(min_workers=1, max_workers=4,
+                                       busy_hi=1e18, busy_lo=-1.0,
+                                       cooldown_s=0.0), clock=clock)
+            fleet._last_stats = [{"busy_s": 1.0}, {"busy_s": 1.0}]
+            auto.target(clock())  # baseline
+            fleet._last_stats = [{"busy_s": busy_after}, {}]
+            fleet.admission.stats.shed["inbox_full"] = 7
+            clock.advance(1.0)
+            assert auto.target(clock()) == 3
+
     def test_autoscaler_resize_failure_keeps_tick_alive(self):
         """An autoscaler-triggered resize that raises must be contained
         by the tick loop — crashing the dataplane process on a failed
