@@ -1,35 +1,22 @@
-"""The one traffic generator: every mix is a data file it reads.
+"""The generator's tools: frames as byte matrices patched with numpy.
 
-All frames are built during set-up, as byte matrices patched with numpy
-(no codec call per frame), and every frame carries a 32-bit id that comes
-back with its reply: a DHCP frame in its xid (offset 46), a data frame in
-the last four bytes of its payload (NAT rewrites headers only).
-
-Two kinds of mix:
-
-- ``flood``: a pool of frames, cycled. Before each beat the loop tops the
-  RX ring up (see `Loop` in run.py for the bound on frames outstanding).
-- ``fixed_rate``: open loop. A fixed number of arrivals, drawn uniformly
-  over the window from the seed (a Poisson process given its count, so
-  every seed offers the same number of frames), each timed from when it
-  was due.
-
-A mix's frames split into two streams by the side they enter on: the
-access side (DHCP and upstream data) and the network side (downstream).
+All frames are built during set-up (no codec call per frame): `data_frames`
+and `dhcp_frames` make whole pools at once, `Stream` holds the frames that
+enter on one side in offer order. Which frames a mix holds, and what each
+carries, is the kit's `Traffic` (kits/ipoe.py is the default), which reads
+the mix's data file and builds its pools with these.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from benchmark.lib.app import ROUTER_MAC, SUB_IP_BASE, Layout
-
 DISCOVER, REQUEST, UP, DOWN = 0, 1, 2, 3
 DATA_LEN = 60  # 64 on the wire with the FCS
 _PAD = b"bng-benchmark-pad"
 
 
-def _mac_cols(mac_u64) -> np.ndarray:
+def mac_cols(mac_u64) -> np.ndarray:
     """[N] uint64 -> [N, 6] wire-order bytes."""
     return (np.asarray(mac_u64, np.uint64).astype(">u8").view(np.uint8)
             .reshape(-1, 8)[:, 2:])
@@ -144,14 +131,14 @@ def dhcp_frames(macs, kinds, xids, req_ips, server_ip: int) -> np.ndarray:
             raise ValueError(f"unexpected DHCP template: {at_mac} {at_xid} {at_ip}")
         out[rows] = np.frombuffer(tpl, np.uint8)
         for at in at_mac:
-            out[rows, at:at + 6] = _mac_cols(macs[rows])
+            out[rows, at:at + 6] = mac_cols(macs[rows])
         out[rows, 46:50] = _be32(xids[rows])
         for at in at_ip:
             out[rows, at:at + 4] = _be32(req_ips[rows])
     return out
 
 
-def _rows(buf: np.ndarray) -> list[bytes]:
+def row_bytes(buf: np.ndarray) -> list[bytes]:
     big, w = buf.tobytes(), buf.shape[1]
     return [big[i * w:(i + 1) * w] for i in range(len(buf))]
 
@@ -167,104 +154,3 @@ class Stream:
         self.at = 0  # next frame to offer
         self.seen = 0  # fixed_rate: frames that have come due so far
         self.sent = 0  # frames the ring accepted (a flood pool cycles)
-
-
-class Traffic:
-    """One mix, built for one layout and seed. `kind[i]`, `key[i]` (a
-    subscriber index or a flow id) and `due[i]` describe frame id i."""
-
-    def __init__(self, mix: dict, lay: Layout, prov: dict, app, seed: int,
-                 seconds: float, stream: int = 0):
-        from bng_tpu.utils.net import ip_to_u32, parse_mac
-
-        self.mix, self.lay = mix, lay
-        self.flood = mix["kind"] == "flood"
-        if not self.flood and mix["kind"] != "fixed_rate":
-            raise ValueError(f"unknown traffic kind {mix['kind']!r}")
-        rng = np.random.default_rng([int(seed), 0x7AF, stream])
-        server_mac = np.frombuffer(parse_mac(app.config.server_mac), np.uint8)
-        server_ip = ip_to_u32(app.config.server_ip)
-        if self.flood:
-            pool = int(mix["pool_frames"])
-            n_dhcp = int(round(pool * mix["dhcp_share"]))
-            n_data = pool - n_dhcp
-        else:
-            n_dhcp = int(round(mix["dhcp_rate"] * seconds))
-            n_data = int(round(mix["data_rate"] * seconds))
-        # half the data frames enter from the network side, each the
-        # downstream twin of an upstream frame's flow
-        n_down = n_data // 2
-        n_up = n_data - n_down
-        n = n_dhcp + n_data
-        self.n = n
-        ids = np.arange(n)
-        self.kind = np.empty(n, np.int8)
-        self.key = np.empty(n, np.int64)
-        renew = rng.random(n_dhcp) < mix["renewal_ratio"]
-        self.kind[:n_dhcp] = np.where(renew, REQUEST, DISCOVER)
-        # a client renews once in a window: no MAC twice while they last
-        self.key[:n_dhcp] = (rng.choice(lay.subscribers, n_dhcp, replace=False)
-                             if n_dhcp <= lay.subscribers
-                             else rng.integers(0, lay.subscribers, n_dhcp))
-        flow_up = rng.integers(0, lay.nat_flows, n_up)
-        flow_down = flow_up[:n_down]
-        self.kind[n_dhcp:n_dhcp + n_up] = UP
-        self.kind[n_dhcp + n_up:] = DOWN
-        self.key[n_dhcp:n_dhcp + n_up] = flow_up
-        self.key[n_dhcp + n_up:] = flow_down
-        self.xid_base = lay.xid_base
-
-        d = slice(0, n_dhcp)
-        dh = dhcp_frames(lay.sub_macs(self.key[d]), self.kind[d],
-                         (ids[d] + self.xid_base).astype(np.uint32),
-                         lay.sub_ips(self.key[d]), server_ip)
-        src, dst, sport, dport, proto = lay.flows(flow_up)
-        sub = src.astype(np.int64) - SUB_IP_BASE
-        up = data_frames(_mac_cols(lay.sub_macs(sub)), server_mac, src, dst,
-                         sport, dport, proto, ids[n_dhcp:n_dhcp + n_up])
-        _src, dst, _sport, dport, proto = lay.flows(flow_down)
-        down = data_frames(np.frombuffer(ROUTER_MAC, np.uint8), server_mac,
-                           dst, prov["nat_ip"][flow_down], dport,
-                           prov["nat_port"][flow_down], proto,
-                           ids[n_dhcp + n_up:])
-        frames = _rows(dh) + _rows(up) + _rows(down)
-
-        acc_ids, net_ids = ids[:n_dhcp + n_up], ids[n_dhcp + n_up:]
-        if self.flood:
-            self.due = None
-            acc_ids, net_ids = rng.permutation(acc_ids), rng.permutation(net_ids)
-            due_acc = due_net = None
-        else:
-            self.due = np.empty(n, np.float64)
-            self.due[:] = rng.random(n) * seconds
-            acc_ids = acc_ids[np.argsort(self.due[acc_ids], kind="stable")]
-            net_ids = net_ids[np.argsort(self.due[net_ids], kind="stable")]
-            due_acc, due_net = self.due[acc_ids], self.due[net_ids]
-        self.streams = [
-            Stream(True, acc_ids, [frames[i] for i in acc_ids], due_acc),
-            Stream(False, net_ids, [frames[i] for i in net_ids], due_net)]
-        self.frames = frames
-        self.is_dhcp = self.kind <= REQUEST
-
-    # -- what a reply to frame id i has to be ------------------------------
-
-    def reply_id(self, raw: bytes) -> tuple[bool, int]:
-        """(is a DHCP reply, frame id) of one frame the ring gave back."""
-        if len(raw) >= 240 and raw[23] == 17 and raw[34:36] == b"\x00\x43":
-            return True, int.from_bytes(raw[46:50], "big") - self.xid_base
-        return False, int.from_bytes(raw[-4:], "big")
-
-    def expected_data(self, i: int, app) -> tuple | None:
-        """(src_ip, src_port, dst_ip, dst_port, proto, payload) that data
-        frame i leaves with, by the host NATManager's session mirror."""
-        from benchmark.lib.app import nat_mapping, nat_of
-
-        cols = self.lay.flows([self.key[i]])
-        src, dst, sport, dport, proto = (int(c[0]) for c in cols)
-        got = nat_mapping(nat_of(app, src), (src, dst, sport, dport, proto))
-        if got is None:
-            return None
-        payload = self.frames[i][42 if proto == 17 else 54:]
-        if self.kind[i] == UP:
-            return (got[0], got[1], dst, dport, proto, payload)
-        return (dst, dport, src, sport, proto, payload)

@@ -50,6 +50,7 @@ class Context:
     setup_s: float
     n_devices: int
     trace: dict | None = None
+    left_out: tuple = ()  # per-layer metrics whose reader found nothing
     _lat: dict | None = None
 
     @property
@@ -152,6 +153,12 @@ def read_span(read: dict, ctx: Context):
 
     if ctx.tracer is None or not ctx.tracer.events:
         return None
+    # a stage or lane this program's Tracer does not have (the parent of the
+    # PR that added it, on which the driver runs the same files) stamps
+    # nothing: nothing to read, not an error
+    if (read["stage"] not in tele.STAGE_NAMES
+            or read.get("lane") not in (None, *tele.LANE_NAMES)):
+        return None
     stage = tele.STAGE_NAMES.index(read["stage"])
     lane = tele.LANE_NAMES.index(read["lane"]) if read.get("lane") else None
     us = [d / 1000.0 for s, ln, _t0, d in ctx.tracer.events
@@ -253,12 +260,15 @@ def per_layer(ctx: Context, bench_dir: str, cell: str) -> dict:
     if prof is not None and prof.t_stop is not None:
         ctx.trace = tracelib.reduce_dir(prof.dir, ctx.n_devices,
                                         prof.t_stop - prof.t_start)
-    out = {}
+    out, left_out = {}, []
     for m in layer_files(bench_dir):
         if cell not in m["cells"]:
             continue
         value = READERS[m["read"]["kind"]](m["read"], ctx)
-        if value is not None:
-            out[m["name"]] = {"value": value * m["read"].get("scale", 1.0),
-                              "unit": m["unit"]}
+        if value is None:
+            left_out.append(m["name"])
+            continue
+        out[m["name"]] = {"value": value * m["read"].get("scale", 1.0),
+                          "unit": m["unit"]}
+    ctx.left_out = tuple(left_out)
     return out

@@ -6,7 +6,9 @@
 A new process each time: build the app from the configuration's argv,
 fill the tables and build the traffic from the seed, warm up the cell's
 own shapes, measure for `--seconds`, drain, check what came out against
-the host reference, print one JSON line last. No chip, no result.
+the host reference, print one JSON line last. No chip, no result. What
+belongs to the deployment (addresses, provisioning, frames, reference) is
+the configuration's kit, `benchmark/kits/<name>.py`.
 
     --sweep r1,r2,...   fixed_rate cells only: one set-up, then --seconds at
                         each data rate (frames/s), to find the knee K
@@ -93,11 +95,10 @@ class Loop:
         self.app, self.tr = app, traffic
         self.ring = app.components["ring"]
         self.tamper = tamper
-        c = app.components
-        if "cluster" in c:
+        if applib.shape(app) == "cluster":
             self.drops = lambda: int(self.ring.stats()["drop"])
         else:
-            self.drops = lambda: c["engine"].stats.dropped
+            self.drops = lambda: app.components["engine"].stats.dropped
         # frames outstanding never exceed what the TX ring can hold: the
         # loop drops a reply it cannot inject (cli.py _drive_scheduler),
         # and a frame lost after the ring accepted it breaks the
@@ -330,19 +331,18 @@ def make_tamper(rate: int = 64):
     return tamper
 
 
-def check(app, traffic, loop, c0: dict, c1: dict,
-          seed: int) -> tuple[bool, int, list[str]]:
+def check(app, kit, traffic, loop, c0: dict, c1: dict,
+          seed: int) -> tuple[bool, int, list[str], dict]:
     """`correct`: the counts balance and a seeded sample of what the ring
-    gave back is what the host reference says. Every number compared is
-    printed beside its limit; every comparison is exact (limit 0)."""
-    from benchmark.lib import app as applib
-    from bng_tpu.control import packets
-
-    lines, ok = [], True
+    gave back is what the kit's reference says. Every number compared is
+    printed beside its limit, and returned so ({name: {value, limit}}) for
+    the result line; every comparison is exact (limit 0)."""
+    lines, compared, ok = [], {}, True
 
     def hold(name: str, value: int, limit: int = 0) -> None:
         nonlocal ok
         lines.append(f"check {name}={value} limit={limit}")
+        compared[name] = {"value": int(value), "limit": limit}
         ok = ok and abs(value) <= limit
 
     lost = loop.outstanding()
@@ -376,44 +376,32 @@ def check(app, traffic, loop, c0: dict, c1: dict,
     take = list(rng.permutation(d_idx)[:SAMPLE // 2]) if d_idx else []
     rest = SAMPLE - len(take)
     take += list(rng.permutation(x_idx)[:rest]) if x_idx else []
-    ref = applib.ReferenceDHCP(app)
-    lay = traffic.lay
-    bad = n_d = n_x = 0
+    ref = kit.Reference(app, traffic)
+    bad = 0
+    seen = dict.fromkeys(ref.kinds, 0)
     first_bad = None
     for i in take:
         raw = flat[i]
         is_d, fid = tags[i]
         good = 0 <= fid < traffic.n and bool(traffic.is_dhcp[fid]) == is_d
-        if good and is_d:
-            n_d += 1
-            sub = int(traffic.key[fid])
-            ip = int(lay.sub_ips([sub])[0])
-            want = ref.reply(traffic.frames[fid], lay.mac_base + sub, ip)
-            good = want is not None and raw == want
-        elif good:
-            n_x += 1
-            want = traffic.expected_data(fid, app)
-            d = packets.decode(raw)
-            good = (want is not None
-                    and (d.src_ip, d.src_port, d.dst_ip, d.dst_port, d.proto,
-                         d.payload) == want
-                    and d.ip_checksum_ok and applib.l4_checksum_ok(raw))
+        if good:
+            seen[is_d] = seen.get(is_d, 0) + 1
+            good = ref.holds(fid, raw)
         if not good:
             bad += 1
             first_bad = first_bad or (is_d, fid, raw.hex())
     hold("sampled_replies_differing", bad)
-    lines.append(f"check sample: {n_d} DHCP replies byte-for-byte, {n_x} data "
-                 f"frames by mapping, payload and both checksums, of "
-                 f"{len(flat)} kept")
-    if n_d == 0 or n_x == 0:
-        lines.append("check sample: a kind of reply is missing from the sample")
-        ok = False
+    lines.append("check sample: "
+                 + ", ".join(f"{seen[k]} {what}" for k, what in ref.kinds.items())
+                 + f", of {len(flat)} kept")
+    # a sample without one of the kit's kinds of reply proves nothing of it
+    hold("sample_kinds_missing", sum(not seen[k] for k in ref.kinds))
     if first_bad:
         lines.append(f"check first differing reply: dhcp={first_bad[0]} "
                      f"id={first_bad[1]} {first_bad[2][:160]}")
     hold("frames_never_offered", loop.unoffered)
     failed = max(lost, 0) + loop.unoffered + bad
-    return ok, failed, lines
+    return ok, failed, lines, compared
 
 
 def main(argv=None) -> int:
@@ -433,7 +421,7 @@ def main(argv=None) -> int:
         import jax
 
         from benchmark.lib import app as applib
-        from benchmark.lib import gen, layers
+        from benchmark.lib import layers
         from bng_tpu.telemetry import spans as tele
         from bng_tpu.utils.jaxenv import enable_compilation_cache
     except ImportError as e:
@@ -449,14 +437,16 @@ def main(argv=None) -> int:
         if name.endswith("backend_compile_duration") else None)
 
     bench, cell, config, mix = load_cell(args.bench_dir, args.workload)
-    lay = applib.Layout(config, args.seed)
+    kit = applib.load_kit(config, args.bench_dir)
+    lay = kit.Layout(config, args.seed)
     devs = find_devices(int(cell["chips"]), lay.subscribers)
     on_chip = devs[0].platform == "tpu"
     say(f"device: platform={devs[0].platform} kind={devs[0].device_kind} "
         f"count={len(devs)} jax={jax.__version__}")
     say(f"compile cache: {enable_compilation_cache()}")
     say(f"cell: {cell['name']} seed={args.seed} seconds={args.seconds} "
-        f"trace={args.trace} control={args.control}")
+        f"trace={args.trace} control={args.control} "
+        f"kit={config.get('kit', applib.DEFAULT_KIT)}")
 
     t0 = time.time()
     app = applib.build_app(config)
@@ -464,9 +454,7 @@ def main(argv=None) -> int:
         n_public = config.get("nat_public_ips", {}).get("count", 0)
         say(f"build: bng run {' '.join(config['argv'])} (+{n_public} public "
             f"IPs; synthetic generator off) in {time.time() - t0:.1f} s")
-        prov = (applib.provision_sharded if "cluster" in app.components
-                else applib.provision)(app, lay,
-                                       stale=args.control == "stale-binding")
+        prov = kit.provision(app, lay, stale=args.control == "stale-binding")
         resident = sum(x.nbytes for x in applib.table_leaves(app))
         say(f"provisioned: {lay.subscribers} subscribers, {lay.nat_flows} NAT "
             f"flows, {resident} bytes of table leaves; seconds "
@@ -476,16 +464,16 @@ def main(argv=None) -> int:
         t0 = time.time()
         warm_mix = dict(mix, kind="flood", pool_frames=mix["warmup_frames"],
                         dhcp_share=mix["warmup_dhcp_share"])
-        warm = gen.Traffic(warm_mix, lay, prov, app, args.seed, 0.0, stream=1)
+        warm = kit.Traffic(warm_mix, lay, prov, app, args.seed, 0.0, stream=1)
         rates = [float(r) for r in args.sweep.split(",") if r]
         if rates:
             if mix["kind"] != "fixed_rate":
                 raise SystemExit("run.py: --sweep needs a fixed_rate cell")
-            plans = [gen.Traffic(dict(mix, data_rate=r), lay, prov, app,
+            plans = [kit.Traffic(dict(mix, data_rate=r), lay, prov, app,
                                  args.seed, args.seconds, stream=2 + i)
                      for i, r in enumerate(rates)]
         else:
-            plans = [gen.Traffic(mix, lay, prov, app, args.seed, args.seconds)]
+            plans = [kit.Traffic(mix, lay, prov, app, args.seed, args.seconds)]
         say(f"traffic: {sum(p.n for p in plans)} frames built in "
             f"{time.time() - t0:.1f} s")
         t0 = time.time()
@@ -550,7 +538,8 @@ def main(argv=None) -> int:
             f"beats it left no room in {loop.cap_full} and less than was due in "
             f"{loop.cap_cut}; the ring took less than it was given in "
             f"{loop.ring_short}")
-        correct, failed, lines = check(app, plan, loop, c0, c1, args.seed)
+        correct, failed, lines, compared = check(app, kit, plan, loop, c0, c1,
+                                                 args.seed)
         for line in lines:
             say(line)
         ctx = layers.Context(plan=plan, loop=loop, window=window, served=served,
@@ -560,6 +549,9 @@ def main(argv=None) -> int:
             say(line)
         if args.trace:
             metrics = layers.per_layer(ctx, args.bench_dir, cell["name"])
+            if ctx.left_out:
+                say("per-layer metrics with nothing to read, left out of the "
+                    "line: " + ", ".join(ctx.left_out))
         else:
             metrics = layers.end_to_end(ctx, bench, cell["name"])
         device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
@@ -573,7 +565,13 @@ def main(argv=None) -> int:
             device["busy_s"] = ctx.trace["busy_s"]
             device["window_s"] = ctx.trace["window_s"]
             result["breakdown"] = ctx.trace["breakdown"]
+        # what `correct` compared, each beside its limit: last in the line,
+        # and the last lines on standard error
+        result["compared"] = compared
         say(json.dumps(result))
+        for name, c in compared.items():
+            print(f"check {name}={c['value']} limit={c['limit']}",
+                  file=sys.stderr, flush=True)
         return 0
     finally:
         app.close()

@@ -20,12 +20,14 @@ sys.path.insert(0, ROOT)
 
 from benchmark import run as bench_run  # noqa: E402
 from benchmark.lib import app as applib  # noqa: E402
+from benchmark.kits import ipoe  # noqa: E402
 from benchmark.lib import gen, layers, trace  # noqa: E402
 
 BENCH = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}  # the last: each number `correct` compared, and its limit
 
 TINY_ARGV = {
     "tiny-cgnat": ["--pool-cidr", "10.0.0.0/11", "--batch-size", "256",
@@ -36,14 +38,22 @@ TINY_ARGV = {
                      "--synthetic-subs", "1", "--shards", "4",
                      "--shard-nbuckets", "1024"],
 }
+# the loop a box with a NIC runs: the same app without the scheduler
+TINY_ARGV["tiny-wire"] = [a for a in TINY_ARGV["tiny-cgnat"]
+                          if a != "--scheduler-enabled"]
+DROPIN_KIT = "ipoe-dot1q"
+BASE_OF = {"tiny-cgnat": "ipoe-cgnat-1M", "tiny-sharded": "ipoe-sharded4-1M",
+           "tiny-wire": "ipoe-cgnat-1M-wire"}
 TINY_CELLS = {  # tiny cell -> (the cell its layer files name, config, traffic)
     "tiny.flood": ("cgnat-1M.flood-64B", "tiny-cgnat", "tiny-flood"),
     "tiny.renew": ("cgnat-1M.renew-under-load", "tiny-cgnat", "tiny-renew"),
-    # the sharded cell's files are in place; BENCHMARK.json does not hold it
-    # yet (PERF.md section 7), so it reports what the other flood cell does
     "tiny4.flood": ("sharded4-1M.flood-64B", "tiny-sharded", "tiny-flood-32"),
+    # the wire cell's files are in place; BENCHMARK.json does not hold the
+    # cell (PERF.md section 7: on the chip the program's ghost lanes break
+    # the DHCP hit balance), so it reports what the other flood cell does
+    "tiny-wire.flood": ("cgnat-1M-wire.flood-64B", "tiny-wire", "tiny-flood"),
 }
-REPORTS_LIKE = {"sharded4-1M.flood-64B": "cgnat-1M.flood-64B"}
+REPORTS_LIKE = {"cgnat-1M-wire.flood-64B": "cgnat-1M.flood-64B"}
 
 
 def _write(path, obj):
@@ -63,8 +73,7 @@ def tiny_dir(tmp_path_factory):
     sizes = {"subscribers": 4096, "nat_subscribers": 128,
              "flows_per_nat_subscriber": 2}
     for name, argv in TINY_ARGV.items():
-        base = "ipoe-sharded4-1M" if "sharded" in name else "ipoe-cgnat-1M"
-        cfg = applib.load_named("configs", base, bdir)
+        cfg = applib.load_named("configs", BASE_OF[name], bdir)
         cfg.update(name=name, argv=argv, sizes=sizes)
         if "nat_public_ips" in cfg:
             cfg["nat_public_ips"]["count"] = 4
@@ -102,6 +111,18 @@ def tiny_dir(tmp_path_factory):
         m = json.load(open(path))
         m["cells"] += [stands_for[c] for c in list(m["cells"])]
         _write(path, m)
+    # the dropped-in kit: a file in kits/, a configuration that names it, a
+    # cell on that configuration; it reports what the cell beside it does
+    shutil.copy(os.path.join(ROOT, "tests", "benchmark", "dropin", DROPIN_KIT + ".py"),
+                os.path.join(bdir, "kits"))
+    cfg = applib.load_named("configs", "tiny-wire", bdir)
+    cfg.update(name="tiny-dot1q", kit=DROPIN_KIT)
+    _write(os.path.join(bdir, "configs", "tiny-dot1q.json"), cfg)
+    bench["workloads"].append({"name": "tiny-dot1q.flood", "config": "tiny-dot1q",
+                               "traffic": "tiny-flood", "chips": 1, "why": "test"})
+    for m in bench["end_to_end"]:
+        if "tiny-wire.flood" in m.get("workloads", []):
+            m["workloads"].append("tiny-dot1q.flood")
     # the dropped-in layer metric: a counter nobody read before
     _write(os.path.join(bdir, "layers", "test.batches.json"), {
         "name": "test.batches", "unit": "batches/s", "better": "higher",
@@ -116,7 +137,9 @@ def _run(tiny_dir, capsys, cell, *extra, seed=3000000019):
     capsys.readouterr()
     rc = bench_run.main(["--workload", cell, "--seed", str(seed),
                          "--seconds", "1.5", "--bench-dir", tiny_dir, *extra])
-    out = capsys.readouterr().out.strip().splitlines()
+    captured = capsys.readouterr()
+    out = captured.out.strip().splitlines()
+    _run.err = captured.err.strip().splitlines()  # the run's standard error
     assert rc == 0
     return json.loads(out[-1]), out
 
@@ -143,6 +166,14 @@ def test_cell_rehearses_on_cpu(tiny_dir, capsys, cell):
     for m in res["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
     assert any(line.startswith("check lost_frames=0 limit=0") for line in out)
+    assert list(res)[-1] == "compared"
+    assert res["compared"]["lost_frames"] == {"value": 0, "limit": 0}
+    assert res["compared"]["sample_kinds_missing"] == {"value": 0, "limit": 0}
+    assert all(abs(c["value"]) <= c["limit"] for c in res["compared"].values())
+    # the same, as the last lines on standard error
+    n = len(res["compared"])
+    assert _run.err[-n:] == [f"check {k}={c['value']} limit={c['limit']}"
+                             for k, c in res["compared"].items()]
     # held in every cell, the sharded one too: no punt to the host
     assert any(line == "check host_slow_path_dhcp=0 limit=0" for line in out)
     assert any(line.startswith("selectors: ") for line in out)
@@ -171,17 +202,77 @@ def test_traced_latency_cell_reads_lane_spans(tiny_dir, capsys):
     assert got["slow.punt_share"]["value"] == 0
 
 
+def test_traced_wire_cell_reads_the_ring_lane_and_the_engines_tiling(tiny_dir,
+                                                                     capsys):
+    """The loop without a scheduler: the `wire.*` files read lane `ring`'s
+    spans and the Tracer's sums under `engine.trace`; the device trace's
+    file finds nothing on the CPU, is left out, and the run says so."""
+    res, out = _run(tiny_dir, capsys, "tiny-wire.flood", "--trace", "1")
+    assert res["correct"] is True, out[-14:]
+    got = res["metrics"]
+    wire = {m["name"] for m in layers.layer_files(tiny_dir)
+            if m["name"].startswith("wire")}
+    assert len(wire) == 9
+    assert all("cgnat-1M-wire.flood-64B" in m["cells"]
+               for m in layers.layer_files(applib.BENCH_DIR)
+               if m["name"] in wire | {"gen.share", "loop.us_per_frame"})
+    # with a window always in flight the Tracer sees the device starved only
+    # when a beat finds the ring empty: the share may be 0, the others not
+    zero_ok = {"wire.device_starved_share"}
+    for name in wire - {"wire_step.device_p50_us"} | {"gen.share",
+                                                      "loop.us_per_frame"}:
+        assert got[name]["value"] >= 0 and (got[name]["value"] > 0
+                                            or name in zero_ok), name
+    assert got["wire.unattributed_share"]["value"] < 100.0
+    # one window of at most the cap's frames a step, two in flight
+    assert 1 <= got["wire.frames_per_step"]["value"] <= 1024
+    assert "wire_step.device_p50_us" not in got
+    said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
+    assert said and "wire_step.device_p50_us" in said[0]
+    sel = [ln for ln in out if ln.startswith("selectors: ")][0]
+    assert sel.endswith("ring=NativeRing loop=engine") and "host_path=" in sel
+    assert not any(name.startswith("sched.") for name in got)
+
+
 # -- `correct` has to be able to fail ---------------------------------------
 
+@pytest.mark.parametrize("cell", ["tiny.flood", "tiny-wire.flood"])
 @pytest.mark.parametrize("control", bench_run.CONTROLS)
-def test_control_run_is_not_correct(tiny_dir, capsys, control):
+def test_control_run_is_not_correct(tiny_dir, capsys, control, cell):
     """A reply with one flipped checksum byte, and a reply built from a
-    binding one update behind: both have to come out as not correct."""
-    res, out = _run(tiny_dir, capsys, "tiny.flood", "--control", control,
-                    seed=11)
+    binding one update behind: both have to come out as not correct, on the
+    scheduler's loop and on the engine's."""
+    res, out = _run(tiny_dir, capsys, cell, "--control", control, seed=11)
     assert res["correct"] is False and res["failed"] > 0
     bad = [ln for ln in out if ln.startswith("check sampled_replies_differing=")]
     assert bad and not bad[0].startswith("check sampled_replies_differing=0 ")
+
+
+@pytest.mark.parametrize("control", [None, "stale-binding"])
+def test_a_dropped_in_kit_serves_its_deployment_and_its_control_fails(
+        tiny_dir, capsys, control):
+    """A deployment added as files: `kits/ipoe-dot1q.py`, a configuration
+    with `"kit": "ipoe-dot1q"`, a cell. The harness imports the kit by the
+    name in the configuration: tagged frames in, the tagged reference held
+    against what came out, `correct` true; with the kit's stale-binding
+    control planted, false."""
+    assert not os.path.exists(os.path.join(ROOT, "benchmark", "kits",
+                                           DROPIN_KIT + ".py"))
+    extra = ["--control", control] if control else []
+    res, out = _run(tiny_dir, capsys, "tiny-dot1q.flood", *extra, seed=21)
+    assert any(ln.startswith("cell: ") and ln.endswith("kit=" + DROPIN_KIT)
+               for ln in out)
+    sample = [ln for ln in out if ln.startswith("check sample: ")][0]
+    assert "the access tag back on" in sample and " 0 " not in sample
+    if control:
+        assert res["correct"] is False and res["failed"] > 0
+        assert not any(ln.startswith("check sampled_replies_differing=0 ")
+                       for ln in out)
+    else:
+        assert res["correct"] is True and res["failed"] == 0, out[-14:]
+        assert any(ln == "check dhcp_accepted_minus_device_hits=0 limit=0"
+                   for ln in out)
+        assert set(res["metrics"]) == {"served_kpps", "setup_s"}
 
 
 def test_broken_timed_path_is_not_correct(tiny_dir, capsys, monkeypatch):
@@ -263,11 +354,11 @@ def test_stalled_loop_holds_what_is_due_and_fails_nothing(tiny_dir, capsys,
 def test_stale_control_is_planted_in_the_table_that_is_uploaded():
     """The control changes what the device holds, not what the reference
     says: one address in eight of the DHCP table is the one from before."""
-    lay = applib.Layout({"sizes": {"subscribers": 64, "nat_subscribers": 8,
+    lay = ipoe.Layout({"sizes": {"subscribers": 64, "nat_subscribers": 8,
                                    "flows_per_nat_subscriber": 2}}, 7)
     idx = np.arange(64)
-    sound = applib.dhcp_table_ips(lay, idx, stale=False)
-    stale = applib.dhcp_table_ips(lay, idx, stale=True)
+    sound = ipoe.dhcp_table_ips(lay, idx, stale=False)
+    stale = ipoe.dhcp_table_ips(lay, idx, stale=True)
     assert (sound == lay.sub_ips(idx)).all()
     assert ((stale != sound) == (idx % 8 == 0)).all()
     assert (lay.sub_ips(idx) == sound).all()  # the layout's own are untouched
@@ -357,6 +448,53 @@ def test_counter_per_counter_and_per_frame_latency_readers():
     for span, top in (("dhcp", 100), ("data", 200)):
         got = layers.read_bench_span({"span": span, "stat": "p99"}, ctx)
         assert top * 0.98 < got < top
+
+
+def test_span_reader_returns_nothing_for_a_stage_or_lane_the_program_lacks():
+    """The driver lays a PR's layer files over the parent's checkout too,
+    whose Tracer may lack the stage or lane a file names: nothing to read,
+    the metric is left out of the line, and nothing raises."""
+    from bng_tpu.telemetry import spans as tele
+
+    class Tracer:
+        events = [(tele.STAGE_NAMES.index("ring"),
+                   tele.LANE_NAMES.index("ring"), 0, 4000)]
+
+    ctx = layers.Context(plan=None, loop=None, window=1.0, served=2, c0={},
+                         c1={}, tracer=Tracer(), profile=None, setup_s=0.0,
+                         n_devices=1)
+    read = {"kind": "span", "stage": "ring", "lane": "ring",
+            "stat": "sum_per_frame"}
+    assert layers.read_span(read, ctx) == pytest.approx(2.0)
+    assert layers.read_span(dict(read, stage="a-later-stage"), ctx) is None
+    assert layers.read_span(dict(read, lane="a-later-lane"), ctx) is None
+    assert layers.read_span(dict(read, lane="bulk"), ctx) is None  # no event
+
+
+def test_an_app_of_an_unknown_shape_is_refused_by_name():
+    """`idle`, `counters` and `selectors` choose among three shapes by what
+    the app holds; an app that fits none is an error that says what it
+    holds, not a KeyError in whichever function indexed first."""
+    class Ring:
+        def rx_pending(self):
+            return 0
+
+        def stats(self):
+            return {}
+
+    class App:
+        components = {"ring": Ring(), "pppoe_only_dataplane": object()}
+
+    for read in (applib.shape, applib.idle, applib.counters, applib.selectors):
+        with pytest.raises(applib.BenchError, match="pppoe_only_dataplane"):
+            read(App())
+
+    class Sched:  # a scheduler in front of a ring without rx_pop is bypassed
+        components = {"ring": Ring(), "scheduler": object(), "engine": object()}
+
+    assert applib.shape(Sched()) == "engine"
+    with pytest.raises(applib.BenchError, match="no file"):
+        applib.load_kit({"name": "x", "kit": "not-there"})
 
 
 # -- no chip, no result -------------------------------------------------------
@@ -471,7 +609,7 @@ def test_patched_frames_equal_the_codecs():
         raw = bytes(row)
         d = packets.decode(raw)
         assert len(raw) == 60 and d.proto == proto and d.ip_checksum_ok
-        assert d.l4_checksum != 0 and applib.l4_checksum_ok(raw)
+        assert d.l4_checksum != 0 and ipoe.l4_checksum_ok(raw)
         assert (d.src_port, d.dst_port) in ((40000, 443), (40001, 443))
     assert bytes(buf[0][-4:]) == (7).to_bytes(4, "big")
     tcp = packets.tcp_packet(bytes(src_mac[1]), bytes(dst_mac), 0x0A100006,
@@ -491,13 +629,70 @@ def test_every_seed_offers_the_same_amount_in_another_order():
     prov = {"nat_ip": np.full(256, 0xC6120001, np.uint32),
             "nat_port": np.arange(256, dtype=np.uint32) + 1024}
     big = 2**31 + 11
-    a, b = (gen.Traffic(mix, applib.Layout(cfg, s), prov, App, s, 1.0)
+    a, b = (ipoe.Traffic(mix, ipoe.Layout(cfg, s), prov, App, s, 1.0)
             for s in (5, big))
     assert a.n == b.n and (a.kind == b.kind).sum() > 0
     assert [len(s.frames) for s in a.streams] == [len(s.frames) for s in b.streams]
     assert a.frames != b.frames
-    again = gen.Traffic(mix, applib.Layout(cfg, big), prov, App, big, 1.0)
+    again = ipoe.Traffic(mix, ipoe.Layout(cfg, big), prov, App, big, 1.0)
     assert again.frames == b.frames and (again.due == b.due).all()
+
+
+# SHA-256 taken on the parent commit (47e83f5, before PR 27 moved this code
+# out of lib/app.py and lib/gen.py), by the arithmetic of the test below
+PINNED = {
+    "tiny-flood": ("9ef3253d77ef08d6d95137f1969b76610d6b6e9c4fa24cd5edf6d0e52f53e673",
+                   "6cb9f97aa0abdd703c14e32a642c9e463bc2a2d9cc090d933cb11ca229b85355"),
+    "tiny-renew": ("2d5aba4724c72a63ddf653f3c89421e04a80f1a6078be0dabbb7836495befa30",
+                   "0d79ef5e7278e7070a2ac9c606382930b4844020cf0891c5193d9dd8d52d29b0"),
+}
+
+
+def test_the_default_kit_builds_the_bytes_it_built_before_the_move(tiny_dir):
+    """The default kit is the harness's old code, moved: for a fixed seed
+    and the tiny mixes, the frame pool with its offer order, and for 64
+    frame ids the reference's reply, `reply_id` of it and `expected_data`,
+    are what the parent commit built."""
+    import hashlib
+
+    seed = 2027
+    cfg = applib.load_named("configs", "tiny-cgnat", tiny_dir)
+    assert "kit" not in cfg
+    kit = applib.load_kit(cfg, tiny_dir)
+    assert kit.__name__ == "benchmark.kits." + applib.DEFAULT_KIT
+    lay = kit.Layout(cfg, seed)
+    app = applib.build_app(cfg)
+    try:
+        prov = kit.provision(app, lay)
+        for mix_name, (pool, replies) in PINNED.items():
+            tr = kit.Traffic(applib.load_named("traffic", mix_name, tiny_dir),
+                             lay, prov, app, seed, 1.5)
+            h = hashlib.sha256()
+            for f in tr.frames:
+                h.update(f)
+            for st in tr.streams:
+                h.update(st.ids.tobytes())
+                if st.due is not None:
+                    h.update(np.asarray(st.due, np.float64).tobytes())
+            assert h.hexdigest() == pool, mix_name
+            ref = kit.Reference(app, tr)
+            n_d = int(tr.is_dhcp.sum())
+            ids = [*range(32), *range(n_d, n_d + 16), *range(tr.n - 16, tr.n)]
+            h = hashlib.sha256()
+            for i in ids:
+                if tr.is_dhcp[i]:
+                    sub = int(tr.key[i])
+                    want = ref.dhcp.reply(tr.frames[i], lay.mac_base + sub,
+                                          int(lay.sub_ips([sub])[0]))
+                    assert ref.holds(i, want) and not ref.holds(i, want[:-1])
+                    h.update(want)
+                    h.update(repr(tr.reply_id(want)).encode())
+                else:
+                    h.update(repr(tr.expected_data(i, app)).encode())
+                    h.update(repr(tr.reply_id(tr.frames[i])).encode())
+            assert h.hexdigest() == replies, mix_name
+    finally:
+        app.close()
 
 
 # -- the trace reduction, on a small recorded trace ---------------------------

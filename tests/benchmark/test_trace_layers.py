@@ -1,6 +1,7 @@
 """CPU rehearsal of the layer files that read the Tracer's tiling, device
-occupancy and counters (PR 25): in a `--trace 1` run of each cell, every
-such file that lists the cell returns a number. The values are a CPU's:
+occupancy and counters (PR 25, and PR 27's `wire.*` on the engine's own
+loop): in a `--trace 1` run of each cell, every such file that lists the
+cell returns a number. The values are a CPU's:
 no number from here is a device metric."""
 
 import glob
@@ -18,10 +19,10 @@ ACCEPTED = {  # the per-layer metrics PR 24's benchmark had
     "sched.bulk_occupancy", "sched.bulk_wait_p99_us",
     "sched.express_wait_p99_us", "slow.punt_share", "sharded.imbalance",
     "sharded.collective_share", "sharded_step.device_p50_us"}
-NEW = {}
+NEW = {}  # what was added since, read from the program's spans and counters
 for _path in glob.glob(os.path.join(ROOT, "benchmark", "layers", "*.json")):
     _m = json.load(open(_path))
-    if _m["name"] not in ACCEPTED:
+    if _m["name"] not in ACCEPTED and _m["read"]["kind"] in ("span", "counter"):
         NEW[_m["name"]] = _m
 # by what they read, these are above 0 wherever the loop moved a frame
 POSITIVE = {
@@ -32,24 +33,29 @@ POSITIVE = {
     "sharded.ring_us_per_frame", "sharded.pack_us_per_frame",
     "sharded.reply_us_per_frame", "sharded.dispatch_us_per_step",
     "sharded.device_wait_us_per_step", "sharded.drain_us_per_step",
-    "sharded.tx_us_per_frame"}
+    "sharded.tx_us_per_frame",
+    "wire.ring_us_per_frame", "wire.dispatch_p50_us", "wire.device_p50_us",
+    "wire.device_wait_p50_us", "wire.reply_us_per_frame",
+    "wire.frames_per_step"}
 
 
 def test_the_new_files_are_data_and_run_on_a_program_without_the_spans():
     """The driver lays these files over the parent's checkout too, whose
-    `STAGE_NAMES` and `LANE_NAMES` lack what PR 25 added: `read_span` looks
-    a stage up by `.index()`, which raises there. So a `span` file names
-    only stages and lanes the parent had; everything new is read through
-    `counter`, which returns nothing where the path is missing."""
+    `STAGE_NAMES` and `LANE_NAMES` lacked what PR 25 added, and until PR 27
+    `read_span` raised on a name it did not find. So PR 25's `span` files
+    name only stages and lanes its parent had, and everything new went
+    through `counter`, which returns nothing where the path is missing;
+    PR 27's `wire.*` span files name lane `ring`, which PR 25's parent had
+    too. Since PR 27 a span file may name any stage: the reader returns
+    nothing for one the program lacks (test_benchmark.py)."""
     parent_stages = {"ring", "admit", "lane_wait", "dispatch", "loop_fill",
                      "loop_wait", "loop_retire", "device", "device_wait",
                      "fleet", "worker", "slow_path", "reply", "ops",
                      "wire_rx", "wire_tx", "total"}
     parent_lanes = {"engine", "express", "bulk", "ring", "bench"}
-    assert len(NEW) == 29
+    assert len(NEW) == 29 + 8  # PR 25's, and PR 27's wire.* less the device trace's
     for m in NEW.values():
         read = m["read"]
-        assert read["kind"] in ("span", "counter"), m["name"]
         assert m["source"] == {"span": "program_span",
                                "counter": "program_counter"}[read["kind"]]
         if read["kind"] == "span":
@@ -62,8 +68,8 @@ def test_the_new_files_are_data_and_run_on_a_program_without_the_spans():
         flood = True
 
     ctx = layers.Context(plan=Plan(), loop=None, window=2.0, served=10,
-                         c0={"sched": {}, "sharded": {}, "ring": {}},
-                         c1={"sched": {}, "sharded": {}, "ring": {}},
+                         c0={"sched": {}, "sharded": {}, "ring": {}, "engine": {}},
+                         c1={"sched": {}, "sharded": {}, "ring": {}, "engine": {}},
                          tracer=None, profile=None, setup_s=0.0, n_devices=1)
     for m in NEW.values():
         assert layers.READERS[m["read"]["kind"]](m["read"], ctx) is None
