@@ -511,6 +511,7 @@ class Engine:
         self.stats = EngineStats()
         self._inflight = None  # pipelined ring mode (process_ring_pipelined)
         self._stage_bufs = [None, None]  # ping-pong staging (lazy alloc)
+        self._stage_high = [0, 0]  # lanes each buffer's last window filled
         self._stage_idx = 0
         # slow-path failures are counted AND logged (rate-limited): the
         # counter alone dropped the traceback (server.go:330 logs each)
@@ -1441,6 +1442,23 @@ class Engine:
             )
         return self._stage_bufs[idx]
 
+    def _mask_stale_lanes(self, idx: int, n: int) -> None:
+        """Keep the staging invariant: a lane beyond `n` is inert (length
+        0, flags 0). `ring.assemble` fills rows 0..n and leaves the rest
+        as this buffer's last window left them, and only the engine knows
+        how long that window was: a high-water mark a buffer, so the
+        clear is at most the previous window's lanes. The rows' bytes
+        stay: every stage of both device programs gates on `length`
+        (tests/test_inert_lanes.py holds the loop to process_ring's
+        state, which starts from zeros)."""
+        _pkt, length, flags = self._stage_bufs[idx]
+        high = self._stage_high[idx]
+        if high > n:
+            tele.masked_lanes(int(np.count_nonzero(length[n:high])))
+            length[n:high] = 0
+            flags[n:high] = 0
+        self._stage_high[idx] = n
+
     def process_ring_pipelined(self, ring, now: float | None = None) -> int:
         """Double-buffered ring loop: dispatch batch k+1, THEN retire k.
 
@@ -1453,6 +1471,14 @@ class Engine:
         retires FIFO, matching this loop's order). Per-batch latency grows
         by one batch window; call flush_pipeline() before reading final
         state (shutdown/tests). Returns frames retired this call.
+
+        Invariant, as process_ring and the sharded loop keep it: every
+        lane beyond the assembled count that reaches a device program is
+        inert (length 0, flags 0). The staging buffers are reused and
+        `assemble` leaves rows n..B alone, so the loop clears what the
+        buffer's last window left there (_mask_stale_lanes) before either
+        program sees it; otherwise a short window after a long one has
+        the stale frames counted, NAT-accounted and policed again.
         """
         now = now if now is not None else self.clock()
         prev = self._inflight
@@ -1466,6 +1492,7 @@ class Engine:
             t0 = tele.t()
             n = ring.assemble(pkt, length, flags)
             if n:
+                self._mask_stale_lanes(idx, n)
                 tok = tele.begin_batch(tele.LANE_RING_L, n)
                 tele.lap(tele.RING, t0, tok)
                 now_s = np.uint32(int(now))

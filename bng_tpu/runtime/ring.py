@@ -372,7 +372,16 @@ class NativeRing:
     # -- consumer --
     def assemble(self, out: np.ndarray, out_len: np.ndarray,
                  out_flags: np.ndarray) -> int:
-        """Fill out[B, slot] (uint8 C-contiguous) from RX; returns count."""
+        """Fill out[B, slot] (uint8 C-contiguous) from RX; returns count n.
+
+        Rows 0..n of out / out_len / out_flags are written whole (zero
+        beyond each frame's length). Rows n..B are the caller's and are
+        left as they were: a caller that reuses a buffer makes them inert
+        (length 0, flags 0) itself before a device program sees them --
+        Engine.process_ring_pipelined does, by a high-water mark a
+        buffer (engine.py _mask_stale_lanes); process_ring passes fresh
+        zeros. assemble_sharded is the other contract: it zeroes its
+        padding rows, because they lie between shards' ranges."""
         B, slot = out.shape
         return int(self._lib.bng_batch_assemble(
             self._h, _u8p(out), _u32p(out_len), _u32p(out_flags), B, slot))
@@ -775,6 +784,9 @@ class PyRing:
 
     def assemble(self, out: np.ndarray, out_len: np.ndarray,
                  out_flags: np.ndarray) -> int:
+        """NativeRing.assemble's contract, on both host paths: rows 0..n
+        written whole, rows n..B left as they were -- making those inert
+        is the caller's (the one statement: NativeRing.assemble)."""
         if len(self._inflight) >= self.MAX_INFLIGHT:
             return 0
         if self._vec:

@@ -178,6 +178,9 @@ class Tracer:
         self._idle_since: int | None = None  # STARVE_DEV, nothing in flight
         self.starved_ns = [0] * NSTAGES
         self.starved_outside_ns = 0
+        # stale lanes the engine's pipelined loop made inert (engine.py
+        # _mask_stale_lanes): lanes that held a length beyond a window's end
+        self.masked_lanes = 0
         self._frozen: dict | None = None  # sums() as finish() left them
 
     # -- batch records ----------------------------------------------------
@@ -521,6 +524,7 @@ class Tracer:
             "beat_starved_ns": int(sum(self.starved_ns)),
             "starved_ns": starved,
             "p99_us": p99,
+            "masked_lanes": int(self.masked_lanes),
         }
 
     def write_events(self, path: str) -> None:
@@ -674,6 +678,14 @@ def add(tok: int | None = None, shed: int = 0, punt: int = 0) -> None:
     if _ACTIVE is None:
         return
     _ACTIVE.add(tok, shed=shed, punt=punt)
+
+
+def masked_lanes(n: int) -> None:
+    """Count `n` stale lanes made inert before a dispatch (the engine's
+    pipelined ring loop). Disarmed: global load + None compare."""
+    if _ACTIVE is None:
+        return
+    _ACTIVE.masked_lanes += n
 
 
 def trigger(reason: str, detail: str = "") -> str | None:

@@ -199,7 +199,13 @@ uint32_t bng_ring_out_pop_desc_batch(bng_ring *r, uint64_t *addrs,
 /* Pop up to max_batch RX frames into out[b*slot .. b*slot+len) and
  * out_len[b]/out_flags[b]; parks the popped descriptors in the in-flight
  * table. Frames longer than slot are truncated (slot bytes staged; full
- * frame stays in UMEM for TX-side use). Returns number of frames. */
+ * frame stays in UMEM for TX-side use). Returns number of frames, n.
+ * Rows [0, n) are written whole (zeroed beyond each frame's length); rows
+ * [n, max_batch) of out / out_len / out_flags are NOT touched: they are
+ * the caller's, and a caller that reuses a buffer makes them inert
+ * (out_len 0, out_flags 0) itself before the device parses them -- the
+ * Python engine's pipelined loop does (engine.py _mask_stale_lanes).
+ * bng_batch_assemble_sharded, below, zeroes its own padding rows. */
 uint32_t bng_batch_assemble(bng_ring *r, uint8_t *out, uint32_t *out_len,
                             uint32_t *out_flags, uint32_t max_batch,
                             uint32_t slot);

@@ -1,0 +1,218 @@
+"""The pipelined ring loop's staging invariant: a lane beyond the
+assembled count is inert.
+
+`Engine.process_ring_pipelined` reuses two staging buffers, and
+`assemble` (both rings) leaves the rows beyond its count as the buffer's
+last window left them. A short window after a long one into the same
+buffer must leave the device exactly where `Engine.process_ring`, which
+starts every call from zeros, leaves it: the DHCP stats, NAT's session
+counters, the QoS buckets, and every reply's bytes. Seeded random tables
+and frames, tiny sizes, CPU.
+"""
+
+import jax
+import numpy as np
+import pytest
+
+from bng_tpu.control import dhcp_codec, packets
+from bng_tpu.control.nat import NATManager
+from bng_tpu.control.pool import Pool, PoolManager
+from bng_tpu.ops.antispoof import MODE_STRICT
+from bng_tpu.ops.dhcp import ST_HIT
+from bng_tpu.runtime.engine import AntispoofTables, Engine, QoSTables
+from bng_tpu.runtime.ring import NativeRing, PyRing, load_native
+from bng_tpu.runtime.tables import FastPathTables
+from bng_tpu.telemetry import spans
+from bng_tpu.utils.net import ip_to_u32
+
+SERVER_MAC = bytes.fromhex("02aabbccdd01")
+SERVER_IP = ip_to_u32("10.0.0.1")
+T0 = 1_753_000_000
+SUBS = 24
+BATCH = 16
+
+RINGS = {
+    "py-scalar": lambda **kw: PyRing(host_path="scalar", **kw),
+    "py-vector": lambda **kw: PyRing(host_path="vector", **kw),
+    "native": lambda **kw: NativeRing(**kw),
+}
+# windows in frames, by call: the third lands in the first's buffer and
+# the fourth in the second's, each far shorter than what it finds there
+WINDOWS = (14, 12, 3, 2)
+
+
+def _stack(seed):
+    """One engine over seeded random tables: every subscriber has a DHCP
+    row, a QoS row a few frames deep, a strict binding, a NAT block and
+    two flows. Returns the engine and what the frames are made from."""
+    rng = np.random.default_rng(seed)
+    fastpath = FastPathTables(sub_nbuckets=256, vlan_nbuckets=64,
+                              cid_nbuckets=64, max_pools=16)
+    fastpath.set_server_config(SERVER_MAC, SERVER_IP)
+    PoolManager(fastpath).add_pool(Pool(
+        pool_id=1, network=ip_to_u32("10.0.0.0"), prefix_len=24,
+        gateway=SERVER_IP, dns_primary=ip_to_u32("1.1.1.1"), lease_time=3600))
+    nat = NATManager(public_ips=[ip_to_u32("203.0.113.1")],
+                     sessions_nbuckets=256, sub_nat_nbuckets=64)
+    qos = QoSTables(nbuckets=256)
+    spoof = AntispoofTables(nbuckets=256)
+    spoof.set_config(MODE_STRICT, log_violations=True)
+    macs = [bytes([0x02, *rng.integers(0, 256, 5).tolist()])
+            for _ in range(SUBS)]
+    ips = ip_to_u32("10.0.0.10") + rng.permutation(200)[:SUBS]
+    flows = []
+    for mac, ip in zip(macs, (int(x) for x in ips)):
+        fastpath.add_subscriber(mac, pool_id=1, ip=ip,
+                                lease_expiry=T0 + 86400)
+        # buckets of 2-6 frames: the stale lanes' bytes would drain them
+        qos.set_subscriber(ip, down_bps=80_000, up_bps=80_000,
+                           down_burst=int(rng.integers(600, 1800)),
+                           up_burst=int(rng.integers(600, 1800)))
+        spoof.add_binding(mac, ip, MODE_STRICT)
+        assert nat.allocate_nat(ip, T0) is not None
+        for _ in range(2):
+            dst = int(ip_to_u32("93.184.0.0") + rng.integers(1, 60000))
+            sport, proto = int(rng.integers(20000, 60000)), int(rng.choice([6, 17]))
+            nat_ip, nat_port = nat.handle_new_flow(ip, dst, sport, 443, proto,
+                                                   64, T0)
+            flows.append((mac, ip, dst, sport, proto, nat_ip, nat_port))
+    engine = Engine(fastpath, nat, qos, spoof, batch_size=BATCH,
+                    clock=lambda: float(T0))
+    return engine, macs, ips, flows
+
+
+def _dhcp(rng, macs, ips):
+    i = int(rng.integers(0, SUBS))
+    xid = int(rng.integers(1, 2**31))
+    if rng.random() < 0.5:
+        p = dhcp_codec.build_request(macs[i], dhcp_codec.DISCOVER, xid=xid)
+    else:
+        p = dhcp_codec.build_request(macs[i], dhcp_codec.REQUEST, xid=xid,
+                                     requested_ip=int(ips[i]),
+                                     server_id=SERVER_IP)
+    p.options.append((dhcp_codec.OPT_PARAM_REQ_LIST, bytes([1, 3, 6, 51, 54])))
+    frame = packets.udp_packet(macs[i], b"\xff" * 6, 0, 0xFFFFFFFF, 68, 67,
+                               p.encode().ljust(320, b"\x00"))
+    return frame, True
+
+
+def _data(rng, flows):
+    mac, ip, dst, sport, proto, nat_ip, nat_port = flows[
+        int(rng.integers(0, len(flows)))]
+    payload = bytes(rng.integers(0, 256, int(rng.integers(8, 200)),
+                                 dtype=np.uint8))
+    make = packets.udp_packet if proto == 17 else packets.tcp_packet
+    if rng.random() < 0.5:  # upstream, SNAT
+        return make(mac, SERVER_MAC, ip, dst, sport, 443, payload), True
+    return make(b"\x02\x99" * 3, SERVER_MAC, dst, nat_ip, 443, nat_port,
+                payload), False  # the matching downstream, DNAT
+
+
+def _windows(path, seed, macs, ips, flows):
+    """The same seeded frames in the same windows for both loops. `mixed`:
+    every window DHCP and data, so each rides the fused step. `dhcp`: the
+    third window is DHCP alone, and rides the DHCP-only fast lane into the
+    buffer a long mixed window used."""
+    rng = np.random.default_rng(seed + 1)
+    out = []
+    for k, size in enumerate(WINDOWS):
+        if path == "dhcp" and k == 2:
+            out.append([_dhcp(rng, macs, ips) for _ in range(size)])
+            continue
+        n_dhcp = max(1, size // 3)
+        win = ([_dhcp(rng, macs, ips) for _ in range(n_dhcp)]
+               + [_data(rng, flows) for _ in range(size - n_dhcp)])
+        out.append([win[i] for i in rng.permutation(size)])
+    return out
+
+
+def _serve(ring_kind, pipelined, path, seed):
+    engine, macs, ips, flows = _stack(seed)
+    ring = RINGS[ring_kind](nframes=128, frame_size=1024, depth=32)
+    fast_lane = []
+    real = engine._run_dhcp_batch
+
+    def spy(*a, **k):
+        fast_lane.append(1)
+        return real(*a, **k)
+
+    engine._run_dhcp_batch = spy
+    step = engine.process_ring_pipelined if pipelined else engine.process_ring
+    replies = {"tx": [], "fwd": []}
+
+    def pop():
+        for name, one in (("tx", ring.tx_pop), ("fwd", ring.fwd_pop)):
+            while (got := one()) is not None:
+                replies[name].append(got[0])
+
+    try:
+        for k, win in enumerate(_windows(path, seed, macs, ips, flows)):
+            for frame, from_access in win:
+                assert ring.rx_push(frame, from_access=from_access)
+            step(ring, now=T0 + 0.02 * k)
+            pop()
+        engine.flush_pipeline()
+        pop()
+        assert ring.slow_pop() is None  # nothing punted: the tables moved
+        # on the device alone, so both loops' updates line up step for step
+        state = {
+            "stats": {k: np.asarray(getattr(engine.stats, k)).copy()
+                      for k in ("dhcp", "nat", "qos", "spoof")},
+            "verdicts": (engine.stats.tx, engine.stats.fwd,
+                         engine.stats.dropped, engine.stats.passed),
+            "tables": [(jax.tree_util.keystr(kp), np.asarray(x)) for kp, x in
+                       jax.tree_util.tree_flatten_with_path(engine.tables)[0]],
+            "replies": replies,
+            "fast_lane": len(fast_lane),
+            "batches": engine.stats.batches,
+        }
+    finally:
+        ring.close()
+    return state
+
+
+@pytest.mark.parametrize("path", ["mixed", "dhcp"])
+@pytest.mark.parametrize("ring_kind", sorted(RINGS))
+def test_short_window_after_long_leaves_process_rings_state(ring_kind, path):
+    if ring_kind == "native" and load_native() is None:
+        pytest.skip("native toolchain unavailable")
+    seed = 20280 + sorted(RINGS).index(ring_kind)
+    want = _serve(ring_kind, False, path, seed)
+    got = _serve(ring_kind, True, path, seed)
+
+    # the scenario is the one the defect needs: four dispatches, device
+    # DHCP hits, NAT and QoS at work, the fast lane taken where meant
+    assert want["batches"] == got["batches"] == len(WINDOWS)
+    assert want["fast_lane"] == got["fast_lane"] == (1 if path == "dhcp" else 0)
+    assert want["stats"]["dhcp"][ST_HIT] > 0
+    assert want["stats"]["nat"].sum() > 0 and want["stats"]["qos"].sum() > 0
+    assert want["verdicts"][0] > 0 and want["verdicts"][1] > 0
+    assert want["verdicts"][3] == 0
+    assert len(want["replies"]["tx"]) == want["verdicts"][0]
+
+    for name in want["stats"]:
+        assert (got["stats"][name] == want["stats"][name]).all(), (
+            name, got["stats"][name], want["stats"][name])
+    assert got["verdicts"] == want["verdicts"]
+    # every leaf of the device's tables: the DHCP rows, NAT's sessions with
+    # their packet and byte counters, both QoS bucket tables
+    assert len(got["tables"]) == len(want["tables"])
+    for (name, a), (_name, b) in zip(got["tables"], want["tables"]):
+        assert (a == b).all(), name
+    assert got["replies"] == want["replies"]
+
+
+def test_masked_lanes_is_a_sum_of_every_tracer_and_counts_ghost_lanes():
+    zero = spans.Tracer().sums()
+    assert zero["masked_lanes"] == 0  # never armed: the key, at zero
+    assert "masked_lanes" in spans.trace_sums()
+    assert spans._ZERO_SUMS["masked_lanes"] == 0
+    with spans.armed() as tr:
+        _serve("py-scalar", True, "mixed", 20289)
+    # each buffer once: what its long window held beyond its short one
+    assert tr.sums()["masked_lanes"] == (
+        (WINDOWS[0] - WINDOWS[2]) + (WINDOWS[1] - WINDOWS[3]))
+    assert spans.trace_sums()["masked_lanes"] == tr.sums()["masked_lanes"]
+    with spans.armed() as tr:
+        _serve("py-scalar", False, "mixed", 20289)  # fresh zeros a call
+    assert tr.sums()["masked_lanes"] == 0
