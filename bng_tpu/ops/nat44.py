@@ -5,8 +5,9 @@ architecture (nat44.c:6-9: first packet of a new flow goes slow-path, later
 packets fast-path) maps perfectly onto the host-single-writer table design:
 
 - Established flows: device translates at line rate from the `sessions` /
-  `reverse` cuckoo tables and updates per-session counters with HBM
-  scatter-adds (the per-CPU-atomic role of nat44.c:286-292).
+  `reverse` cuckoo tables and updates per-session counters with one
+  whole-row HBM write per session a batch (the per-CPU-atomic role of
+  nat44.c:286-292).
 - New flows (session miss) return verdict PASS; the host NAT manager
   (bng_tpu.control.nat) performs RFC 6431 port-block allocation + RFC 4787
   EIM host-side — the get_eim_mapping/allocate_port_from_block logic
@@ -277,37 +278,31 @@ def nat44_update_sessions(
     `keep` is the pipeline's final forward decision: packets dropped by
     QoS/antispoof after translation must not be billed to the subscriber
     (the kernel hooks get this for free from hook ordering; here the
-    accounting pass is explicitly gated).
+    accounting pass is explicitly gated). A lane is an egress hit or an
+    ingress hit, never both (`nat44_kernel` splits lanes by source address).
+
+    One whole-row write per distinct slot. What the TPU compiler and a v5e
+    showed (PERF.md section 6, PR 29, at `u32[2097216, 16]` and 8,192
+    lanes): whole rows scatter natively; a part-row window
+    (`vals.at[slot, 11:15].add`) serialises into a `while` of one
+    `dynamic-update-slice` a lane, whatever the lanes hold; a single column
+    (`vals.at[slot, 7].set`) relayouts the whole table twice around a flat
+    scatter. Those three loops took 41 ms alone, 34 ms of a 94 ms step.
     """
     Bsz = length.shape[0]
     egress_hit = res.egress_hit & keep
     ingress_hit = res.ingress_hit & keep
-    hit_any = egress_hit | ingress_hit
-    slot = jnp.where(egress_hit, res.e_slot, res.i_slot)
-    # out-of-bounds slot for non-hit lanes -> dropped by scatter
+    # a lane that touches nothing points past the table: it sorts behind
+    # every row, and the scatter drops it
     S = sessions.vals.shape[0]
-    upd_slot = jnp.where(hit_any, slot, S).astype(jnp.int32)
-    plen = length.astype(jnp.uint32)
-    vals = sessions.vals
-    zeros = jnp.zeros((Bsz,), dtype=jnp.uint32)
-    ones = jnp.ones((Bsz,), dtype=jnp.uint32)
-    add_block = jnp.stack(
-        [
-            jnp.where(egress_hit, ones, zeros),  # SV_PKTS_OUT
-            jnp.where(ingress_hit, ones, zeros),  # SV_PKTS_IN
-            jnp.where(egress_hit, plen, zeros),  # SV_BYTES_OUT
-            jnp.where(ingress_hit, plen, zeros),  # SV_BYTES_IN
-        ],
-        axis=1,
-    )
-    vals = vals.at[upd_slot, SV_PKTS_OUT : SV_BYTES_IN + 1].add(add_block, mode="drop")
-    vals = vals.at[upd_slot, SV_LAST_SEEN].set(
-        jnp.broadcast_to(now_s, (Bsz,)).astype(jnp.uint32), mode="drop")
+    slot = jnp.where(egress_hit, res.e_slot,
+                     jnp.where(ingress_hit, res.i_slot, S)).astype(jnp.int32)
 
-    # TCP state machine on ingress (nat44.c:885-895). Scatter-max keeps
-    # duplicate-slot batches deterministic: states are ordered
-    # NEW < ESTABLISHED < FIN_WAIT < CLOSING, so a FIN/RST lane always
-    # wins over a same-batch ACK lane regardless of scatter order.
+    # TCP state machine on ingress (nat44.c:885-895). States are ordered
+    # NEW < ESTABLISHED < FIN_WAIT < CLOSING and a slot takes the max over
+    # its lanes, so a FIN/RST lane always wins over a same-batch ACK lane
+    # whatever the lane order. 0 is max's identity on u32: the lanes that
+    # write no state (egress, UDP, ICMP) carry it.
     fin_or_rst = (parsed.tcp_flags & 0x05) != 0  # FIN|RST
     ack = (parsed.tcp_flags & 0x10) != 0
     cur_state = res.i_state
@@ -315,6 +310,34 @@ def nat44_update_sessions(
         fin_or_rst, NAT_STATE_CLOSING,
         jnp.where((cur_state == NAT_STATE_NEW) & ack, NAT_STATE_ESTABLISHED, cur_state),
     ).astype(jnp.uint32)
-    state_slot = jnp.where(ingress_hit & parsed.is_tcp, res.i_slot, S).astype(jnp.int32)
-    vals = vals.at[state_slot, SV_STATE].max(new_state, mode="drop")
+    state = jnp.where(ingress_hit & parsed.is_tcp, new_state, 0)
+
+    # Sort the lanes by (slot, state): a slot's lanes (the up and the down
+    # packet of one flow, say) become one run, whose last lane carries the
+    # run's largest state. The counters ride along as operands of the sort.
+    up = egress_hit.astype(jnp.uint32)
+    down = ingress_hit.astype(jnp.uint32)
+    plen = length.astype(jnp.uint32)
+    # SV_PKTS_OUT, SV_PKTS_IN, SV_BYTES_OUT, SV_BYTES_IN
+    slot, state, *counts = jax.lax.sort(
+        (slot, state, up, down, up * plen, down * plen), num_keys=2)
+    counts = jnp.stack(counts, axis=1)
+    edge = slot[1:] != slot[:-1]
+    run_head = jnp.concatenate([jnp.ones((1,), dtype=bool), edge])
+    run_last = jnp.concatenate([edge, jnp.ones((1,), dtype=bool)])
+    # a run's sums: the running sum less what it was before the run's head.
+    # u32 wraps, and the difference is exact mod 2**32 all the same.
+    csum = jnp.cumsum(counts, axis=0)
+    head_at = jax.lax.cummax(jnp.where(run_head, jnp.arange(Bsz), 0))
+    run_sums = csum - (csum - counts)[head_at]
+
+    # read-modify-write of the rows at the runs' ends, on the array written
+    rows = sessions.vals.at[slot].get(mode="clip")
+    word = jnp.arange(SESSION_WORDS)
+    sums = jnp.pad(run_sums, ((0, 0), (SV_PKTS_OUT, SESSION_WORDS - 1 - SV_BYTES_IN)))
+    # last_seen is set, not raised: a host clock that steps back shows
+    new_rows = jnp.where(
+        word == SV_LAST_SEEN, jnp.uint32(now_s),
+        jnp.where(word == SV_STATE, jnp.maximum(rows, state[:, None]), rows + sums))
+    vals = sessions.vals.at[jnp.where(run_last, slot, S)].set(new_rows, mode="drop")
     return sessions._replace(vals=vals)

@@ -22,6 +22,13 @@ Design rationale (vs. the reference's kernel hash maps, bpf/maps.h:99-234):
 
 Capacity sizing: ways=4 buckets sustain >90% load factor, so a 1M-entry
 subscriber table (bpf/maps.h:10 MAX_SUBSCRIBERS) fits in 2^18 buckets x 4.
+
+Writing a table inside the step: scatter whole rows (`vals.at[idx].set /
+.add / .max(rows)`), which a TPU does natively, duplicates or not. A
+part-row window (`vals.at[idx, a:b]`) compiles to a serial `while` of one
+`dynamic-update-slice` a lane, and a single column (`vals.at[idx, c]`) to
+two relayouts of the whole table around a flat scatter (PERF.md section 6,
+PR 29: 34 ms of a 94 ms step for 8,192 lanes into `u32[2097216, 16]`).
 """
 
 from __future__ import annotations

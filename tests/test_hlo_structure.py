@@ -163,6 +163,17 @@ class TestNAT44Shape:
         assert total <= 22, f"gather explosion: {total}"
         scatters = _count(r'"stablehlo\.scatter"', hlo)
         assert scatters <= 4, f"scatter explosion: {scatters}"
+        # every write into the session rows is a whole-row scatter: a
+        # part-row window compiles to a serial loop over the lanes on a
+        # TPU, a single column to two relayouts of the table (PERF.md
+        # section 6, PR 29; tests/test_tpu_lowering.py holds the compiled
+        # step to no `while`)
+        S, W = tables.sessions.vals.shape
+        into_rows = re.findall(
+            rf"\(tensor<{S}x{W}xui32>, tensor<[0-9x]+xi32>, "
+            rf"tensor<([0-9x]+)xui32>\) -> tensor<{S}x{W}xui32>", hlo)
+        assert into_rows, "the pattern no longer finds the scatters"
+        assert all(upd == f"{B}x{W}" for upd in into_rows), into_rows
 
 
 class TestShardedExchangeShape:

@@ -50,13 +50,33 @@ def _device_bytes(compiled) -> int:
             + m.generated_code_size_in_bytes)
 
 
-def test_fused_step_fits_one_chip(one_chip):
-    """B=8192 over the 1M/1M table set, donated: compiles and fits."""
-    compiled = compile_for(verify.build_pipeline(REAL_1M), one_chip)
-    m = compiled.memory_analysis()
+def _whiles(compiled) -> list[str]:
+    """The `while` instructions of a compiled program, one line each."""
+    return [line.strip() for line in compiled.as_text().splitlines()
+            if " while(" in line]
+
+
+@pytest.fixture(scope="module")
+def fused_step(one_chip):
+    """B=8192 over the 1M/1M table set, donated: one compile (27 s) for
+    every test of the fused step."""
+    return compile_for(verify.build_pipeline(REAL_1M), one_chip)
+
+
+def test_fused_step_fits_one_chip(fused_step):
+    m = fused_step.memory_analysis()
     # the tables are donated: nearly every argument byte is aliased
     assert m.alias_size_in_bytes > 0.9 * m.argument_size_in_bytes
-    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert _device_bytes(fused_step) < V5E_HBM_BYTES
+
+
+def test_fused_step_has_no_while(fused_step):
+    """A part-row window scattered into a table compiles to a serial
+    `while` over the lanes, a single column to two table-sized relayout
+    loops (ops/nat44.py nat44_update_sessions): 34 ms of a 94 ms step on
+    a v5e until PR 29, and the step's only loops. Every table write is a
+    whole-row scatter, which the chip does natively."""
+    assert _whiles(fused_step) == []
 
 
 @pytest.mark.parametrize("build", [
@@ -90,8 +110,10 @@ def test_table_probe_pallas_compiles(one_chip):
     compile_for(verify.build_table("pallas", False, g=REAL_1M), one_chip)
 
 
-def test_sharded_step_compiles_for_four_chips(topo):
-    """1M subscribers hash-sharded four ways over a 2x2 v5e host."""
+@pytest.fixture(scope="module")
+def sharded_step(topo):
+    """1M subscribers hash-sharded four ways over a 2x2 v5e host: the
+    compiled mesh step, and one shard's session rows `S`."""
     from bng_tpu.parallel.sharded import AXIS
 
     mesh = Mesh(np.array(topo.devices), (AXIS,))
@@ -99,11 +121,27 @@ def test_sharded_step_compiles_for_four_chips(topo):
         batch=REAL_1M.batch // 4, sub_nbuckets=1 << 17,
         side_nbuckets=1 << 17, nat_sessions_nbuckets=1 << 17,
         sub_nat_nbuckets=1 << 15)
-    compiled = compile_for(verify.build_sharded(mesh, per_shard))
+    fn, args = verify.build_sharded(mesh, per_shard)
+    return compile_for((fn, args)), args[0].nat.sessions.vals.shape[1]
+
+
+def test_sharded_step_compiles_for_four_chips(sharded_step):
+    compiled, _ = sharded_step
     # memory_analysis of a mesh program is per device
     assert _device_bytes(compiled) < V5E_HBM_BYTES
     # the hash-sharded DHCP lookup exchanges keys/results over ICI
     assert "all-to-all" in compiled.as_text()
+
+
+def test_sharded_step_loops_over_no_session_table(sharded_step):
+    """No `while` of the mesh step carries the session table, in its own
+    shape or in a relayout's (`[S,16]`, `[1,16,S]`, flat): the accounting
+    pass writes whole rows there too."""
+    compiled, S = sharded_step
+    shapes = (f"{S},16]", f"16,{S}]", f"[{16 * S}]")
+    over_table = [w for w in _whiles(compiled)
+                  if any(shape in w for shape in shapes)]
+    assert over_table == []
 
 
 @pytest.mark.slow  # ~47s of CPU compiles; bench.py --verify-lowering too
