@@ -48,9 +48,9 @@
 #                       BNG_TELEMETRY=1 (< 30 s): disarmed-overhead
 #                       bound, histogram merge laws, flight-recorder
 #                       wrap + every anomaly trigger, Chrome-trace
-#                       schema. The engine-compiling DORA e2e lives in
-#                       the same file under @pytest.mark.slow (tier-1
-#                       runs it; this target stays fast).
+#                       schema. The engine-compiling DORA e2e
+#                       (TestDoraTracingE2E) runs in tier-1; this
+#                       target deselects it and stays fast.
 #   make verify-static — bngcheck static analyzer (< 30 s, no jax):
 #                       `bng check` must exit 0 against the checked-in
 #                       baseline (bng_tpu/analysis/baseline.json), then
@@ -63,21 +63,6 @@
 #                       (.bngcheck_cache.json). Part of `verify`: a PR
 #                       that violates a dataplane invariant fails here
 #                       before the test suite even starts.
-#   make verify-kernels — Pallas table-probe kernel gate (ISSUE 11):
-#                       the `kernels`-marked tests (interpret-mode
-#                       bit-exactness vs xla_lookup AND the host
-#                       mirror across every table geometry, impl
-#                       dispatch, HLO no-narrow-gather pins, the
-#                       sharded step under the kernel), the BNG014
-#                       narrow-gather lint, and `bench.py --autotune
-#                       --dry-run` (tiny CPU sweep to a temp ledger —
-#                       proves the sweep/ledger plumbing without
-#                       hardware). A prerequisite of `verify` (whose
-#                       tier-1 line deselects `kernels`; a bare
-#                       ROADMAP tier-1 run still includes them).
-#                       Mosaic lowering itself is TPU-gated
-#                       (runtime/verify.py; tests/test_tpu_lowering.py
-#                       compiles for a described v5e without a chip).
 #   make verify-sharded — the ICI-sharded SERVING path (ISSUE 12):
 #                       `sharded`-marked tests on the forced
 #                       8-host-device CPU mesh (< 60 s): steered-ring
@@ -91,12 +76,9 @@
 #                       tier-1 line deselects `sharded`; a bare ROADMAP
 #                       tier-1 run still includes them).
 #   make verify-express — AOT express OFFER-path gate (ISSUE 13):
-#                       ALL `express`-marked tests (slow included —
-#                       this target owns the full 4-geometry x 2-impl
-#                       byte-identity matrix vs `_dhcp_jit`; the
-#                       heavier combos are slow-marked so the ROADMAP
-#                       tier-1 run carries only geometry 0 under both
-#                       impls): descriptor-parse semantics, express-
+#                       ALL `express`-marked tests (slow included):
+#                       the geometry byte-identity matrix vs
+#                       `_dhcp_jit`, descriptor-parse semantics, express-
 #                       reply identity vs the codec-built reply, AOT
 #                       cache hit-without-retrace and loud-miss
 #                       fallback (counter + flight dump + ring-meta
@@ -120,9 +102,7 @@
 #                       and the memory-rung four-scenario serving twin
 #                       (DORA + NAT punt + QoS drop + PPPoE through
 #                       the full kernel-rings->pump->engine loop) in
-#                       <60s, plus the `bench.py --wire-ab` plumbing
-#                       smoke against a TEMP ledger (the repo ledger
-#                       stays legacy-only). The veth e2e (slow tier)
+#                       <60s. The veth e2e (slow tier)
 #                       self-skips without CAP_NET_ADMIN. A
 #                       prerequisite of `verify` (whose tier-1 line
 #                       deselects `wire`; the ROADMAP tier-1 command
@@ -147,18 +127,17 @@ PYTEST_FLAGS = -q --continue-on-collection-errors -p no:cacheprovider \
 
 .PHONY: verify verify-slow verify-all verify-load verify-chaos \
         verify-telemetry verify-static verify-sanitize verify-ops \
-        verify-storm verify-perf verify-kernels verify-sharded \
+        verify-storm verify-perf verify-sharded \
         verify-express verify-hostpath verify-wire verify-cluster \
-        verify-edge verify-devloop verify-fabric verify-multibox
+        verify-edge verify-fabric verify-multibox
 
-verify: verify-static verify-storm verify-perf verify-kernels \
+verify: verify-static verify-storm verify-perf \
         verify-sharded verify-express verify-hostpath verify-wire \
-        verify-cluster verify-edge verify-devloop verify-fabric \
-        verify-multibox
+        verify-cluster verify-edge verify-fabric verify-multibox
 	set -o pipefail; rm -f /tmp/_t1.log; \
 	timeout -k 10 $(TIER1_TIMEOUT) env JAX_PLATFORMS=cpu \
 	$(PY) -m pytest tests/ $(PYTEST_FLAGS) \
-	-m 'not slow and not storm and not perf and not kernels and not sharded and not express and not hostpath and not wire and not cluster and not edge and not devloop and not fabric and not multibox' \
+	-m 'not slow and not storm and not perf and not sharded and not express and not hostpath and not wire and not cluster and not edge and not fabric and not multibox' \
 	2>&1 | tee /tmp/_t1.log
 
 verify-sharded:
@@ -188,18 +167,6 @@ verify-wire:
 	timeout -k 10 60 env JAX_PLATFORMS=cpu \
 	$(PY) -m pytest tests/test_wire_pump.py $(PYTEST_FLAGS) \
 	  -m 'wire and not slow' \
-	&& timeout -k 10 120 env JAX_PLATFORMS=cpu \
-	  BNG_BENCH_TIMEOUT=90 BNG_BENCH_LOG=/tmp/_wire_ab.jsonl \
-	  BNG_WIRE_AB_BATCH=1024 BNG_BENCH_LAT_STEPS=10 \
-	  $(PY) bench.py --wire-ab \
-	| $(PY) -c "import json,sys; \
-	r=json.loads([l for l in sys.stdin if l.startswith('{')][-1]); \
-	assert r['metric'].startswith('wire A/B'), r; \
-	assert r['value'] >= 2.0, ('ISSUE 15 exit: vector pump < 2x', r); \
-	assert r['pump_stats_match'], r; \
-	print('verify-wire OK: vector %.1fx, ceiling %.2f -> %.2f Mpps' \
-	% (r['value'], r['scalar_wire_mpps_ceiling'], \
-	r['vector_wire_mpps_ceiling']))" \
 	&& echo "verify-wire OK"
 
 verify-cluster:
@@ -216,13 +183,6 @@ verify-edge:
 	  $(PYTEST_FLAGS) -m 'edge and not slow' \
 	&& echo "verify-edge OK"
 
-verify-devloop:
-	set -o pipefail; \
-	timeout -k 10 60 env JAX_PLATFORMS=cpu \
-	$(PY) -m pytest tests/test_devloop.py $(PYTEST_FLAGS) \
-	  -m 'devloop' \
-	&& echo "verify-devloop OK"
-
 verify-fabric:
 	set -o pipefail; \
 	timeout -k 10 60 env JAX_PLATFORMS=cpu \
@@ -236,22 +196,6 @@ verify-multibox:
 	$(PY) -m pytest tests/test_multibox.py $(PYTEST_FLAGS) \
 	  -m 'multibox and not slow' \
 	&& echo "verify-multibox OK"
-
-verify-kernels:
-	set -o pipefail; \
-	timeout -k 10 240 env JAX_PLATFORMS=cpu \
-	$(PY) -m pytest tests/ $(PYTEST_FLAGS) \
-	  -m 'kernels and not slow' \
-	&& timeout -k 10 30 $(PY) -m bng_tpu.analysis --select gather \
-	&& timeout -k 10 180 env JAX_PLATFORMS=cpu \
-	  BNG_BENCH_TIMEOUT=150 $(PY) bench.py --autotune --dry-run \
-	| $(PY) -c "import json,sys; \
-	r=json.loads([l for l in sys.stdin if l.startswith('{')][-1]); \
-	assert r['metric'] == 'autotune best point' and r['points'] >= 2, r; \
-	assert r['best']['table_impl'] in ('xla', 'pallas'), r; \
-	print('verify-kernels OK: best', r['best']['table_impl'], \
-	'B=%d' % r['best']['batch'], '%.3f Mpps' % r['value'])" \
-	&& echo "verify-kernels OK"
 
 verify-slow:
 	env JAX_PLATFORMS=cpu $(PY) -m pytest tests/ $(PYTEST_FLAGS) -m slow
@@ -300,7 +244,7 @@ verify-telemetry:
 	set -o pipefail; \
 	timeout -k 10 30 env JAX_PLATFORMS=cpu BNG_TELEMETRY=1 \
 	$(PY) -m pytest tests/test_telemetry.py $(PYTEST_FLAGS) \
-	  -m 'telemetry and not slow' \
+	  -m 'telemetry and not slow' -k 'not TestDoraTracingE2E' \
 	&& echo "verify-telemetry OK"
 
 verify-static:

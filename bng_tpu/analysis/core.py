@@ -81,10 +81,10 @@ class SourceFile:
         return SourceFile(path=rel, abspath=abspath, text=text, tree=tree)
 
 
-# default scan set: the package + the bench driver. tests/ is excluded —
+# default scan set: the package. tests/ is excluded —
 # it plants violations deliberately (this file's own test fixtures) and
 # exercises private surfaces the production rules don't govern.
-SCAN_GLOBS = ("bng_tpu/**/*.py", "bench.py")
+SCAN_GLOBS = ("bng_tpu/**/*.py",)
 
 
 class Project:

@@ -5,8 +5,7 @@ The gray-failure class that let three bench rounds publish CPU numbers
 as TPU headlines (VERDICT r5, PR 5 postmortem): a wall-clock window
 around an ASYNC jitted dispatch measures enqueue cost, not device time.
 The telemetry design rule is explicit — device time comes only from
-`profiling.profile_step_durations` (block_until_ready inside the
-capture) or a window that contains its own force.
+a profiler trace or a window that contains its own force.
 
 The pass finds function-local timing windows:
 
@@ -18,7 +17,7 @@ and flags windows that contain a dispatch to one of the async step
 surfaces (`_step`, `_dhcp_step`, `_dispatch_step`, `_run_dhcp_batch`,
 `dispatch_scheduled_bulk`, `submit`/`poll`, `process_ring_pipelined`)
 but no fence (`block_until_ready`, `device_get`, `np.asarray`,
-`flush`/`flush_pipeline`/`quiesce`, `profile_step_durations`, `.item`).
+`flush`/`flush_pipeline`/`quiesce`, `.item`).
 Synchronous surfaces (`process`, `process_dhcp`, `process_ring`) force
 their own outputs and are not dispatch hazards.
 """
@@ -36,7 +35,7 @@ ASYNC_DISPATCH = {"_step", "_dhcp_step", "_dispatch_step",
                   "_run_dhcp_batch", "dispatch_scheduled_bulk",
                   "submit", "poll", "process_ring_pipelined", "step_fn"}
 FENCES = {"block_until_ready", "device_get", "asarray", "array", "item",
-          "flush", "flush_pipeline", "quiesce", "profile_step_durations",
+          "flush", "flush_pipeline", "quiesce",
           "drain_completions_blocking", "wait"}
 
 

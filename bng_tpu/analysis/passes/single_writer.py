@@ -38,7 +38,6 @@ MUTATORS = {
     "resync_tables", "restore_arrays",
     "arm_tap", "disarm_tap", "set_tap_filters",
     "set_route", "clear_route",
-    "fill_slot", "adopt_cursors",
     "watch", "reset", "reset_peer",
     "set_manifest", "accept_chunk",
 }
@@ -70,17 +69,9 @@ ALLOWED_WRITERS = {
                                    "builds its own instance's pools + "
                                    "fastpath from the carved spec "
                                    "(same role as cli.py, per member)",
-    "bench.py": "bench provisioning",
     "bng_tpu/edge/tables.py": "edge host authority (tap/route mirrors)",
     "bng_tpu/edge/compile.py": "warrant/route compilers are the edge "
                                "tables' owning managers",
-    "bng_tpu/devloop/ring.py": "descriptor-ring host authority: "
-                               "fill_slot/adopt_cursors ARE the ring "
-                               "cursor mutators (ISSUE 18)",
-    "bng_tpu/devloop/host.py": "the devloop pump owns its ring: slot "
-                               "fills at admission, cursor adoption at "
-                               "retire — a writer outside the pump "
-                               "bypasses the quiesce/audit story",
     "bng_tpu/cluster/coordinator.py": "fabric membership authority "
                                       "(ISSUE 19): watches slots on "
                                       "plan apply, resets the view + "
@@ -99,7 +90,7 @@ ALLOWED_WRITERS = {
 TABLE_RECEIVERS = {
     "fastpath", "tables", "sub", "vlan", "cid", "bindings", "subscribers",
     "qos", "up", "down", "antispoof", "garden", "pppoe", "by_sid", "by_ip",
-    "edge", "tap", "route", "ring", "devloop", "cursors",
+    "edge", "tap", "route", "ring",
     "fabric_detector", "fabric_transport",
     "handoff", "receiver",
 }

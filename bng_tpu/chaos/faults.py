@@ -31,9 +31,6 @@ Instrumented points and the kinds each site honors:
                       bytes handed to the decoder)
     engine.dispatch   fail | delay      (runtime/engine.py device step)
     engine.slow_drain fail              (slow-lane batch drain)
-    devloop.dispatch  fail              (devloop/host.py megakernel ring
-                                        dispatch: the staged slots re-
-                                        dispatch per-batch, loudly)
     ha.push           drop_delta        (control/ha.py ActiveSyncer)
     ha.connect        fail              (StandbySyncer peer timeout)
     nat.expire        skew              (NATManager.expire_sessions now)
@@ -92,7 +89,6 @@ POINT_KINDS: dict[str, tuple[str, ...]] = {
     "ckpt.read": (TRUNCATE, BITFLIP, IO_ERROR),
     "engine.dispatch": (FAIL, DELAY),
     "engine.slow_drain": (FAIL,),
-    "devloop.dispatch": (FAIL,),
     "ha.push": (DROP_DELTA,),
     "ha.connect": (FAIL,),
     "nat.expire": (SKEW,),

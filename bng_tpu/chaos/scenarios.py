@@ -46,12 +46,6 @@ Scenario list:
     route_flap_rewrite        next-hop rewrite rides a link flap as
                               bounded dirty-slot deltas; traffic
                               re-forwards via the survivor
-    devloop_storm             express OFFER storm through the device-
-                              resident serving loop against a saturated
-                              bulk lane, with a mid-storm injected
-                              megakernel dispatch failure; reply bytes
-                              must match a fault-free control sweep and
-                              the ring cursor audit must close clean
     cluster_partial_partition sever exactly the a<->b fabric link while
                               both still reach c (NEAT): mutual
                               suspicion but no accusation quorum, so no
@@ -1250,150 +1244,6 @@ def cluster_failover_redora(seed: int) -> dict:
     return out_rep
 
 
-def _build_devloop_stack(clock, devloop_k: int):
-    """Tiered scheduler with the devloop express lane armed + 32
-    pre-provisioned subscribers — geometry pinned to tests/test_express
-    (sub 256 / vlan 64 / cid 64, engine B=32, express B=8) so a test
-    session reuses every compiled program."""
-    from bng_tpu.control.nat import NATManager
-    from bng_tpu.runtime.engine import Engine
-    from bng_tpu.runtime.scheduler import SchedulerConfig, TieredScheduler
-    from bng_tpu.runtime.tables import FastPathTables
-
-    base = int(clock())
-    fp = FastPathTables(sub_nbuckets=256, vlan_nbuckets=64,
-                        cid_nbuckets=64, max_pools=8)
-    fp.set_server_config(SERVER_MAC, SERVER_IP)
-    fp.add_pool(1, ip_to_u32("10.0.0.0"), 24, SERVER_IP,
-                ip_to_u32("8.8.8.8"), ip_to_u32("8.8.4.4"), 3600)
-    subs = []
-    for i in range(32):
-        mac = _mac(0xD00 + i)
-        ip = ip_to_u32("10.0.0.0") + 10 + i
-        fp.add_subscriber(mac, 1, ip, base + 600)
-        subs.append((mac, ip))
-    nat = NATManager(public_ips=[ip_to_u32("203.0.113.1")],
-                     sessions_nbuckets=64, sub_nat_nbuckets=64)
-    eng = Engine(fp, nat, batch_size=32, pkt_slot=512, clock=clock)
-    sched = TieredScheduler(eng, SchedulerConfig(
-        express_batch=8, bulk_batch=32, express_aot=True,
-        express_loop="devloop", devloop_k=devloop_k), clock=clock)
-    return sched, subs
-
-
-def _devloop_sweep(seed: int, rounds: int, devloop_k: int,
-                   plan: FaultPlan | None) -> dict:
-    """One storm sweep on a FRESH stack: each round submits a full
-    ring's worth of express DHCP (k slots x express batch) interleaved
-    with a saturated bulk batch, all through `process()` (which
-    flushes, so partial rings never carry across rounds). Returns the
-    deterministic digest the scenario diffs: reply byte hash, verdict
-    counts, loop/fallback counters, cursor audit."""
-    import hashlib
-
-    clock = SimClock()
-    sched, subs = _build_devloop_stack(clock, devloop_k)
-    per_round = devloop_k * sched.express.cfg.batch
-    tx_sha = hashlib.sha256()
-    counts = {"tx": 0, "slow": 0, "fwd": 0, "dropped": 0}
-    peer = ip_to_u32("198.51.100.9")
-
-    def storm() -> None:
-        for r in range(rounds):
-            frames, kinds = [], []
-            for j in range(per_round):
-                mac, ip = subs[(seed + r * 7 + j) % len(subs)]
-                xid = 0xD0000 + r * 256 + j
-                if (r + j) % 3 == 2:  # renew REQUESTs ride the storm too
-                    frames.append(_renew(mac, ip, xid))
-                else:
-                    frames.append(_discover(mac, xid))
-                kinds.append(True)
-            for j in range(sched.bulk.cfg.batch):  # saturate the bulk lane
-                mac, ip = subs[(seed + j) % len(subs)]
-                frames.append(packets.udp_packet(
-                    mac, SERVER_MAC, ip, peer, 40000 + j, 443,
-                    b"devloop-storm-bulk"))
-                kinds.append(True)
-            out = sched.process(frames, now=clock())
-            for verdict in ("tx", "fwd"):
-                for i, frame in out[verdict]:
-                    tx_sha.update(i.to_bytes(4, "big"))
-                    tx_sha.update(frame)
-                counts[verdict] += len(out[verdict])
-            counts["slow"] += len(out["slow"])
-            counts["dropped"] += len(out["dropped"])
-            clock.advance(0.01)
-
-    if plan is not None:
-        with armed(plan, log=False) as inj:
-            storm()
-        injected = [list(t) for t in inj.injected]
-    else:
-        storm()
-        injected = []
-
-    sched.quiesce(now=clock())
-    pump = sched._devloop
-    audit = pump.audit() if pump is not None else {"consistent": False}
-    stats = pump.stats() if pump is not None else {}
-    return {
-        "loop": sched.express_loop,
-        "counts": counts,
-        "reply_sha": tx_sha.hexdigest(),
-        "ring_dispatches": stats.get("dispatches", 0),
-        "ring_batches": stats.get("batches", 0),
-        "fallback_slots": stats.get("fallback_slots", 0),
-        "fallbacks": dict(sorted(sched.express_fallbacks.items())),
-        "injected": injected,
-        "cursor_seq": audit.get("seq", -1),
-        "audit_consistent": bool(audit.get("consistent", False)),
-    }
-
-
-def devloop_storm(seed: int) -> dict:
-    """Express OFFER storm through the device-resident serving loop
-    (devloop/) against a saturated bulk lane, with a mid-storm injected
-    ``devloop.dispatch`` failure. The control sweep serves every round
-    through full descriptor rings; the faulted sweep loses its second
-    ring dispatch to the injected fault, which must degrade LOUDLY
-    (fallback counter + per-batch re-dispatch of every staged slot) and
-    never silently: reply bytes must be byte-identical to the control
-    sweep, the express frames all still answer, and the quiesce-time
-    cursor audit must close consistent in both sweeps — faults degrade
-    service, never consistency."""
-    rounds, devloop_k = 6, 4
-    fault_round = 2 + seed % 3  # mid-storm: ring dispatch 2, 3 or 4
-    control = _devloop_sweep(seed, rounds, devloop_k, None)
-    faulted = _devloop_sweep(
-        seed, rounds, devloop_k,
-        FaultPlan(seed, [FaultSpec("devloop.dispatch", FAIL,
-                                   at_hit=fault_round)]))
-
-    out_rep = {
-        "name": "devloop_storm", "seed": seed,
-        "rounds": rounds, "devloop_k": devloop_k,
-        "fault_round": fault_round,
-        "control": control, "faulted": faulted,
-        "replies_identical": control["reply_sha"] == faulted["reply_sha"],
-    }
-    out_rep["ok"] = (
-        control["loop"] == "devloop" and faulted["loop"] == "devloop"
-        and out_rep["replies_identical"]
-        and control["counts"]["tx"] > 0
-        and control["counts"] == faulted["counts"]
-        and control["fallback_slots"] == 0 and not control["fallbacks"]
-        and faulted["fallback_slots"] == devloop_k
-        and faulted["fallbacks"].get("devloop_miss", 0) == 1
-        and faulted["injected"] == [["devloop.dispatch", "fail",
-                                     fault_round]]
-        and faulted["ring_dispatches"] == control["ring_dispatches"] - 1
-        and faulted["cursor_seq"] == control["cursor_seq"] - devloop_k
-        and control["audit_consistent"]
-        and faulted["audit_consistent"])
-    return out_rep
-
-
 # ---------------------------------------------------------------------------
 # 13. cluster partial partition: no quorum, no demotion, no double-carve
 # ---------------------------------------------------------------------------
@@ -1840,7 +1690,6 @@ SCENARIOS = {
     "intercept_tap_live": intercept_tap_live,
     "route_flap_rewrite": route_flap_rewrite,
     "cluster_failover_redora": cluster_failover_redora,
-    "devloop_storm": devloop_storm,
     "cluster_partial_partition": cluster_partial_partition,
     "cluster_gray_member": cluster_gray_member,
     "cluster_host_loss": cluster_host_loss,

@@ -2886,7 +2886,7 @@ def run_chaos(args) -> int:
         with open(args.out, "w") as f:
             f.write(text + "\n")
     if args.bench_log:
-        # diffable per-scenario lines next to bench.py's results; the
+        # diffable per-scenario lines in the perf ledger; the
         # wallclock/run_id/schema stamp lives only in the appender
         # (telemetry/ledger.py), never in the compared report bytes
         from bng_tpu.telemetry import ledger as ledger_mod

@@ -773,7 +773,7 @@ class SlowPathFleet:
         self.fallback = fallback
         # host-path snapshot (ISSUE 14): vector = batched classify /
         # steer / admit pre-pass in handle_batch; resolved once at
-        # construction like Engine.table_impl
+        # construction
         self.host_path = hostpath.resolved_host_path()
         self._vec = self.host_path == "vector"
         self.refills = 0

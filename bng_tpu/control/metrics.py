@@ -358,10 +358,9 @@ class BNGMetrics:
         # express rung-fallback family (ISSUE 18 gray-failure
         # hardening): every event where the express lane served below
         # its configured rung, by reason — compile_failed (AOT refused
-        # to lower at setup), geometry_miss (per-dispatch cache miss),
-        # devloop_compile_failed / devloop_unavailable / devloop_miss
-        # (the ring megakernel degrading to per-batch). Any nonzero
-        # rate here under a supposedly-healthy config is a gray failure.
+        # to lower at setup), geometry_miss (per-dispatch cache miss).
+        # Any nonzero rate here under a supposedly-healthy config is a
+        # gray failure.
         self.express_fallback = r.counter(
             "bng_express_fallback_total",
             "Express serving-rung fallback events by reason",
@@ -963,10 +962,6 @@ class BNGMetrics:
         self.express_aot_miss.set_total(ex.get("aot_misses", 0))
         for reason, n in (ex.get("fallbacks") or {}).items():
             self.express_fallback.set_total(n, reason=reason)
-        dl = ex.get("devloop")
-        if dl:
-            self.express_program_dispatches.set_total(
-                dl.get("dispatches", 0), program="devloop")
 
     def collect_fleet(self, fleet) -> None:
         """SlowPathFleet.stats_snapshot() -> bng_slowpath_* families."""

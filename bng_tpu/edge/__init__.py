@@ -2,8 +2,7 @@
 next-hop route rewrite on the fast path.
 
 - `edge.ops` — the two device kernels (tap_match, route_rewrite) and
-  their word layouts, probed via the `BNG_TABLE_IMPL`-dispatched
-  `lookup()`.
+  their word layouts, probed via `lookup()`.
 - `edge.tables` — `EdgeTables`, the host single-writer authority whose
   bounded deltas ride the engine's existing update drain.
 - `edge.compile` — warrant/routing compilers + the `MirrorPump` host

@@ -2,7 +2,7 @@
 route rewrite (ISSUE 17).
 
 Both kernels follow the `ops/antispoof.py` mold: a bucketized-cuckoo
-probe through the `BNG_TABLE_IMPL`-dispatched `lookup()`, dense side
+probe through `lookup()`, dense side
 arrays for per-row config, and a packed uint32 stats vector the engine
 folds host-side.
 

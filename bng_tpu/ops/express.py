@@ -18,9 +18,8 @@ the 50us `device` budget cares about:
   deems ineligible is exactly a frame the device program would have
   PASSed.
 - **Device (`express_verdicts`):** the three-tier cuckoo probe
-  (VLAN -> circuit-ID -> MAC, `BNG_TABLE_IMPL`-selectable via
-  ops/table.device_lookup), lease-expiry and pool-validity checks, and
-  a [B, XD_WORDS] verdict block: verdict + yiaddr + pool/lease words.
+  (VLAN -> circuit-ID -> MAC, ops/table.device_lookup), lease-expiry
+  and pool-validity checks, and a [B, XD_WORDS] verdict block: verdict + yiaddr + pool/lease words.
   No packet bytes enter or leave the program.
 - **Retire (host):** the verdict block selects a preassembled
   `ExpressWireTemplate` (control/dhcp_codec.py, built on the same

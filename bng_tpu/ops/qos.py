@@ -23,7 +23,6 @@ role). Timestamps are µs with wrap-safe uint32 arithmetic.
 
 from __future__ import annotations
 
-import os
 from typing import NamedTuple
 
 import jax
@@ -43,54 +42,22 @@ QOS_NSTATS = 4
 # QoS table geometry is the packed-bucket table's
 QoSGeom = QTableGeom
 
-# Same-bucket aggregation strategy:
-#   "sort"   — stable argsort + segment cumsum (works on every backend)
-#   "pallas" — MXU tiled equality-matmul kernel (ops.pallas_qos); on CPU
-#              it runs in interpret mode (tests), on TPU compiled
-#   "auto"   — pallas on TPU, sort elsewhere
-# Default from BNG_QOS_PREFIX; "sort" until the pallas path has been
-# timed on hardware (flip to "auto" once it wins).
-PREFIX_IMPL = os.environ.get("BNG_QOS_PREFIX", "sort")
+# same-bucket aggregation strategy; read by benchmark/lib/app.py selectors()
+PREFIX_IMPL = "sort"
 
 
 def _prefix_consumed(limited, slot, lens_u, avail):
-    """Returns (allowed, consumed_f32, is_head) using the configured impl.
+    """Returns (allowed, consumed_f32, is_head): stable argsort + segment
+    cumsum, u32-exact to 4 GB a batch.
 
     allowed: sequential-TBF admission per lane (arrival = lane order);
     consumed: admitted bytes of the lane's bucket (valid on limited lanes);
     is_head: first limited lane of each bucket in the batch.
     """
     Bsz = slot.shape[0]
-    impl = PREFIX_IMPL
-    if impl == "auto":
-        # Mosaic lowering is TPU-only; every other backend gets the sort
-        impl = "pallas" if jax.default_backend() == "tpu" else "sort"
-
     # lanes without a limit get unique negative ids -> group with nobody
     slot_eff = jnp.where(limited, slot, -1 - jnp.arange(Bsz, dtype=jnp.int32))
 
-    if impl == "pallas":
-        from bng_tpu.ops.pallas_qos import seg_prefix_total
-
-        # NOTE: f32 matmul accumulation is exact only below 2^24 bytes
-        # per bucket per batch (the sort path's u32 cumsum is exact to
-        # 2^32); a single bucket attempting >16.7MB in one batch can
-        # flip a boundary admission vs the sort/eBPF reference.
-        # Mosaic lowering is TPU-only: every other backend (cpu, gpu, ...)
-        # runs interpret mode (ADVICE r1: a GPU backend must not try to
-        # compile the Mosaic kernel).
-        interp = jax.default_backend() != "tpu"
-        lens_f = lens_u.astype(jnp.float32)
-        cum_incl, _ = seg_prefix_total(slot_eff, lens_f, interpret=interp,
-                                       compute="prefix")
-        allowed = ~limited | (cum_incl <= avail)
-        admitted = jnp.where(allowed & limited, lens_f, 0.0)
-        _, consumed = seg_prefix_total(slot_eff, admitted, interpret=interp,
-                                       compute="total")
-        is_head = limited & (cum_incl <= lens_f)  # no earlier same-bucket lane
-        return allowed, consumed, is_head
-
-    # ---- sort path ----
     # Narrow (1-word-per-index) gathers are the measured TPU pathology
     # (PERF_NOTES.md §2; >=8-word rows gather at full speed), so the
     # permutation moves ONE packed [B,8] row per lane instead of four
@@ -181,9 +148,6 @@ def qos_kernel(
     avail = jnp.minimum(res.tokens + refill, burst_f)
 
     # --- same-bucket aggregation (sequential TBF admission per lane) ---
-    # impl-pluggable: stable-sort segment cumsum (u32-exact to 4GB per
-    # batch), or the Pallas MXU equality-matmul kernel (ops.pallas_qos,
-    # f32-exact to 2^24 bytes per bucket per batch) — see PREFIX_IMPL.
     lens_u = pkt_len.astype(jnp.uint32)
     allowed, consumed, first = _prefix_consumed(limited, res.slot, lens_u, avail)
     dropped = limited & ~allowed

@@ -33,8 +33,6 @@ PR 29: 34 ms of a 94 ms step for 8,192 lanes into `u32[2097216, 16]`).
 
 from __future__ import annotations
 
-import contextlib
-import os
 from typing import NamedTuple
 
 import numpy as np
@@ -47,80 +45,12 @@ from bng_tpu.ops.hashing import SEED1, SEED2, hash_words, mix32
 WAYS = 4  # slots per bucket; one bucket = one contiguous gather
 MAX_KICKS = 128  # bounded cuckoo eviction walk (host side)
 
-# Probe implementation (the qos_kernel[sort|pallas] mold):
-#   "xla"    — the composed wide-gather cascade below (every backend)
-#   "pallas" — the fused probe kernel (ops.pallas_table); on CPU it runs
-#              in interpret mode (tests), on TPU compiled via Mosaic
-#   "auto"   — self-timed: bench.py races both post-compile and pins the
-#              winner (set_auto_choice); until raced, xla on every
-#              backend — the TPU compiler refuses the pallas kernel
-#              today (tests/test_tpu_lowering.py keeps the strict xfail;
-#              ROADMAP D11), and auto must never pick what cannot compile
-# Default from BNG_TABLE_IMPL; "xla" until the pallas path has been
-# timed on hardware (PERF_NOTES §13).
-TABLE_IMPL = os.environ.get("BNG_TABLE_IMPL", "xla")
-
-TABLE_IMPLS = ("xla", "pallas")
-
-# resolved "auto" winner (bench.py --autotune / _pick_table_impl)
-_AUTO_CHOICE: str | None = None
-
-# trace-time override stack: jitted-program factories (engine, sharded)
-# pin the impl PER COMPILED PROGRAM so one process can hold programs
-# traced under different impls (the A/B race) without global races
-_FORCED: list[str] = []
-
-
-def set_auto_choice(impl: str | None) -> None:
-    """Pin the winner of an auto self-timing race (None clears)."""
-    global _AUTO_CHOICE
-    if impl is not None and impl not in TABLE_IMPLS:
-        raise ValueError(f"unknown table impl {impl!r}")
-    _AUTO_CHOICE = impl
-
-
-@contextlib.contextmanager
-def forced_impl(impl: str):
-    """Trace-time impl pin — wrap the traced body, not the jit call."""
-    if impl not in TABLE_IMPLS:
-        raise ValueError(f"unknown table impl {impl!r}")
-    _FORCED.append(impl)
-    try:
-        yield
-    finally:
-        _FORCED.pop()
-
-
-def resolved_table_impl() -> str:
-    """The impl device_lookup dispatches to at trace time."""
-    if _FORCED:
-        return _FORCED[-1]
-    impl = TABLE_IMPL
-    if impl == "auto":
-        # un-raced auto is the cascade that compiles everywhere
-        return _AUTO_CHOICE if _AUTO_CHOICE is not None else "xla"
-    if impl not in TABLE_IMPLS:
-        raise ValueError(
-            f"BNG_TABLE_IMPL={impl!r}: expected one of "
-            f"{TABLE_IMPLS + ('auto',)}")
-    return impl
-
-
-def current_impl_label() -> str:
-    """Best-effort impl label for fingerprints/bench lines — never
-    raises and never triggers backend init beyond what is already up
-    (ledger.environment_fingerprint calls this via sys.modules)."""
-    try:
-        return resolved_table_impl()
-    except Exception:  # noqa: BLE001 — a bad env var must not sink a line
-        return TABLE_IMPL
-
 
 def nbuckets_for(entries: int) -> int:
     """Bucket count that holds `entries` keys at about 50% load of the
     4-way buckets, a power of two and at least 2^10 — the one sizing
     rule behind every table built from a subscriber or flow count
-    (`bng run` capacities, `bng loadtest`, bench.py)."""
+    (`bng run` capacities, `bng loadtest`)."""
     return 1 << max(10, (entries // 2).bit_length())
 
 
@@ -302,26 +232,10 @@ def sharded_lookup(state: TableState, query: jax.Array, g: TableGeom) -> LookupR
 
 
 def device_lookup(state: TableState, query: jax.Array, nbuckets: int, stash: int) -> LookupResult:
-    """Impl-dispatched batched probe (every hot-path kernel funnels
-    here: DHCP 3-tier chain, NAT44 forward/reverse, antispoof, garden,
-    PPPoE, and the sharded step's local probe).
-
-    Resolution happens at TRACE time (resolved_table_impl): the fused
-    Pallas kernel when selected, else the XLA wide-gather cascade.
-    Both are bit-identical (tests/test_pallas_table.py pins it).
-
-    query: [B, K] uint32 key words.
-    """
-    if resolved_table_impl() == "pallas":
-        from bng_tpu.ops.pallas_table import pallas_lookup
-
-        return pallas_lookup(state, query, nbuckets, stash)
-    return xla_lookup(state, query, nbuckets, stash)
-
-
-def xla_lookup(state: TableState, query: jax.Array, nbuckets: int, stash: int) -> LookupResult:
-    """Branch-free batched lookup: 2 wide bucket-row gathers + stash
-    broadcast + 1 value-row gather — no narrow gathers anywhere.
+    """Branch-free batched lookup (every hot-path kernel funnels here:
+    DHCP 3-tier chain, NAT44 forward/reverse, antispoof, garden, PPPoE,
+    and the sharded step's local probe): 2 wide bucket-row gathers +
+    stash broadcast + 1 value-row gather — no narrow gathers anywhere.
 
     query: [B, K] uint32 key words.
     """

@@ -41,7 +41,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from bng_tpu.control.nat import NATManager
 from bng_tpu.edge.tables import EdgeTables
 from bng_tpu.ops.pipeline import PipelineGeom, PipelineTables, pipeline_step
-from bng_tpu.ops import table as table_mod
 from bng_tpu.ops.table import TableGeom, shard_owner
 from bng_tpu.runtime.engine import (AntispoofTables, GardenTables, QoSTables,
                                     _apply_all_updates)
@@ -89,11 +88,7 @@ def _sharded_geom(geom: PipelineGeom, n: int) -> PipelineGeom:
 
 
 @functools.lru_cache(maxsize=4)
-def _sharded_step_jit(mesh: Mesh, geom: PipelineGeom, n: int,
-                      table_impl: str = "xla"):
-    """`table_impl` pins the device_lookup implementation (Pallas fused
-    probe vs XLA cascade — ops.table.forced_impl) for this compiled
-    mesh program, same discipline as Engine._pipeline_jit."""
+def _sharded_step_jit(mesh: Mesh, geom: PipelineGeom, n: int):
     geom_sh = _sharded_geom(geom, n)
 
     has_garden = geom.garden is not None
@@ -107,9 +102,8 @@ def _sharded_step_jit(mesh: Mesh, geom: PipelineGeom, n: int,
         # host table deltas land here, inside the donated step — the
         # bpf_map_update_elem replacement, same as the single-chip Engine
         tables = _apply_all_updates(tables, upd)
-        with table_mod.forced_impl(table_impl):
-            res = pipeline_step(tables, pkt, length, fa, geom_sh,
-                                now_s, now_us)
+        res = pipeline_step(tables, pkt, length, fa, geom_sh,
+                            now_s, now_us)
         new_tables1 = jax.tree.map(lambda x: x[None], res.tables)
         # global stats over ICI (per-CPU map -> one counter)
         with jax.named_scope("stats"):
@@ -148,8 +142,7 @@ def _sharded_step_jit(mesh: Mesh, geom: PipelineGeom, n: int,
 
 
 @functools.lru_cache(maxsize=4)
-def _sharded_dhcp_jit(mesh: Mesh, geom: PipelineGeom, n: int,
-                      table_impl: str = "xla"):
+def _sharded_dhcp_jit(mesh: Mesh, geom: PipelineGeom, n: int):
     """Sharded DHCP-only program — the multichip OFFER latency fast lane.
 
     Mirrors Engine._dhcp_jit (reference hook-order parity: the DHCP fast
@@ -168,9 +161,8 @@ def _sharded_dhcp_jit(mesh: Mesh, geom: PipelineGeom, n: int,
         dhcp = jax.tree.map(lambda x: x[0], dhcp1)
         upd = jax.tree.map(lambda x: x[0], upd1)
         dhcp = apply_fastpath_updates(dhcp, upd)
-        with table_mod.forced_impl(table_impl):
-            par = parse_batch(pkt, length)
-            res = dhcp_fastpath(pkt, length, par, dhcp, dhcp_geom, now_s)
+        par = parse_batch(pkt, length)
+        res = dhcp_fastpath(pkt, length, par, dhcp, dhcp_geom, now_s)
         return (jax.tree.map(lambda x: x[None], dhcp), res.is_reply,
                 res.out_pkt, res.out_len, jax.lax.psum(res.stats, AXIS))
 
@@ -397,15 +389,9 @@ class ShardedCluster:
             tap=self.edge[0].geom if edge_enabled else None,
             route=self.edge[0].geom if edge_enabled else None,
         )
-        # table-probe impl resolved once at cluster construction (the
-        # Engine discipline); dryrun_multichip stamps it into the
-        # MULTICHIP-TELEMETRY line so a Pallas multichip artifact can
-        # never read as an XLA one
-        self.table_impl = table_mod.resolved_table_impl()
-        self._step = _sharded_step_jit(self.mesh, self.geom, self.n,
-                                       self.table_impl)
-        self._dhcp_step = _sharded_dhcp_jit(self.mesh, self.geom, self.n,
-                                            self.table_impl)
+        self.table_impl = "xla"  # read by benchmark/lib/app.py selectors()
+        self._step = _sharded_step_jit(self.mesh, self.geom, self.n)
+        self._dhcp_step = _sharded_dhcp_jit(self.mesh, self.geom, self.n)
         self.tables = None  # lazily built on first step / sync()
         # ping-pong ring staging: the in-flight batch owns one buffer set
         # while the next assembles into the other (Engine._staging role)

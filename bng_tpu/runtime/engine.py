@@ -52,7 +52,6 @@ from bng_tpu.ops.pipeline import (
 from bng_tpu.ops.qos import QOS_NSTATS
 from bng_tpu.ops.antispoof import ANTISPOOF_WORDS
 from bng_tpu.ops.qtable import HostQTable, QTableGeom, apply_qupdate
-from bng_tpu.ops import table as table_mod
 from bng_tpu.ops.table import HostTable, TableGeom, apply_update
 from bng_tpu.runtime import hostpath
 from bng_tpu.runtime.ring import FLAG_DHCP_CTRL
@@ -110,16 +109,11 @@ def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
 
 
 @functools.lru_cache(maxsize=8)
-def _pipeline_jit(geom: PipelineGeom, table_impl: str = "xla"):
-    """`table_impl` pins the device_lookup implementation for THIS
-    compiled program (ops.table.forced_impl runs at trace time): two
-    engines in one process can hold programs traced under different
-    impls (the bench A/B race) without racing a global."""
+def _pipeline_jit(geom: PipelineGeom):
     def step(tables, upd, pkt, length, from_access, now_s, now_us):
         tables = _apply_all_updates(tables, upd)
-        with table_mod.forced_impl(table_impl):
-            return pipeline_step(tables, pkt, length, from_access, geom,
-                                 now_s, now_us)
+        return pipeline_step(tables, pkt, length, from_access, geom,
+                             now_s, now_us)
 
     # donate the device tables: updates + counter writes are in-place
     return jax.jit(step, donate_argnums=(0,))
@@ -177,7 +171,7 @@ def _apply_updates_jit(geom: PipelineGeom, has_garden: bool, has_pppoe: bool,
 
 
 @functools.lru_cache(maxsize=8)
-def _dhcp_jit(geom, table_impl: str = "xla"):
+def _dhcp_jit(geom):
     """DHCP-only device program — the latency fast lane.
 
     In the reference the DHCP fast path is its OWN XDP program
@@ -201,16 +195,15 @@ def _dhcp_jit(geom, table_impl: str = "xla"):
 
     def step(dhcp_tables, upd, pkt, length, now_s):
         dhcp_tables = apply_fastpath_updates(dhcp_tables, upd)
-        with table_mod.forced_impl(table_impl):
-            par = parse_batch(pkt, length)
-            res = dhcp_fastpath(pkt, length, par, dhcp_tables, geom, now_s)
+        par = parse_batch(pkt, length)
+        res = dhcp_fastpath(pkt, length, par, dhcp_tables, geom, now_s)
         return dhcp_tables, res.is_reply, res.out_pkt, res.out_len, res.stats
 
     return jax.jit(step, donate_argnums=(0, 2))
 
 
 @functools.lru_cache(maxsize=8)
-def _express_jit(geom, table_impl: str = "xla"):
+def _express_jit(geom):
     """AOT express-lane OFFER program — the minimal program the 50us
     device budget permits (ISSUE 13).
 
@@ -233,8 +226,7 @@ def _express_jit(geom, table_impl: str = "xla"):
 
     def step(dhcp_tables, upd, desc, now_s):
         dhcp_tables = apply_fastpath_updates(dhcp_tables, upd)
-        with table_mod.forced_impl(table_impl):
-            res = express_verdicts(dhcp_tables, desc, geom, now_s)
+        res = express_verdicts(dhcp_tables, desc, geom, now_s)
         return dhcp_tables, res.block, res.stats
 
     return jax.jit(step, donate_argnums=(0, 2))
@@ -242,26 +234,8 @@ def _express_jit(geom, table_impl: str = "xla"):
 
 # AOT-compiled express executables, shared across engines of one
 # geometry (the _dhcp_jit sharing discipline, extended to compiled
-# executables): (dhcp geom, batch, table impl, device) -> Compiled.
+# executables): (dhcp geom, batch, device) -> Compiled.
 _EXPRESS_AOT: dict = {}
-
-
-@functools.lru_cache(maxsize=1)
-def _process_default_device():
-    """The device the executable itself would place host arrays on.
-    Cached once per process: jax backends are process-stable, and the
-    devloop dispatch path asks on every ring."""
-    return jax.local_devices()[0]
-
-
-@functools.lru_cache(maxsize=512)
-def _u32_scalar(v: int):
-    """Device-resident u32 scalar, cached by value. The devloop ring
-    passes `n_slots` (always k on a full ring) and `now` (advances once
-    a second) on every dispatch — converting them fresh costs ~0.4ms of
-    host ceremony per ring on CPU. Safe to share: neither argument is
-    in the megakernel's donate set."""
-    return jnp.uint32(v)
 
 
 class _ExpressAotResult(NamedTuple):
@@ -542,18 +516,14 @@ class Engine:
             device_tables if device_tables is not None
             else self._device_tables())
         # jit cache is keyed on geometry so Engine instances with identical
-        # table shapes share one compile (tests build many engines). The
-        # table-probe impl (BNG_TABLE_IMPL / autotune choice) is resolved
-        # ONCE at engine construction and keys the cache too — an env/auto
-        # flip after construction needs a new Engine, same discipline the
-        # qos PREFIX_IMPL documents for its jits.
-        self.table_impl = table_mod.resolved_table_impl()
-        self._step = _pipeline_jit(self.geom, self.table_impl)
-        self._dhcp_step = _dhcp_jit(fastpath.geom, self.table_impl)
+        # table shapes share one compile (tests build many engines).
+        self.table_impl = "xla"  # read by benchmark/lib/app.py selectors()
+        self._step = _pipeline_jit(self.geom)
+        self._dhcp_step = _dhcp_jit(fastpath.geom)
         # host-path snapshot (ISSUE 14): vector = batch-native frame
         # staging through a cycling preallocated pool instead of a
         # fresh np.zeros + per-frame copy loop per dispatch. Resolved
-        # once at construction, like table_impl.
+        # once at construction.
         self.host_path = hostpath.resolved_host_path()
         self._stage_pool = (hostpath.StagingPool(self.L)
                             if self.host_path == "vector" else None)
@@ -1082,7 +1052,7 @@ class Engine:
         # share an executable (a call-time shape mismatch would crash
         # the dispatch instead of falling back)
         return (self.fastpath.geom, len(self.fastpath.pools),
-                self.fastpath.update_slots, batch, self.table_impl,
+                self.fastpath.update_slots, batch,
                 None if device is None else str(device))
 
     def express_aot(self, batch: int, device=None):
@@ -1094,7 +1064,7 @@ class Engine:
     def compile_express_aot(self, batch: int, device=None):
         """`jax.jit(...).lower(...).compile()` the express program for
         one fixed batch geometry — engine/scheduler init time, NEVER the
-        dispatch path. Cached on (geometry, impl, device) so engines of
+        dispatch path. Cached on (geometry, device) so engines of
         one shape share a single executable. Lowering uses the live
         chain's avals plus an EMPTY update batch (same pytree shapes as
         a real drain; a real make_updates() here would consume dirty
@@ -1111,7 +1081,7 @@ class Engine:
         upd = jax.device_put(self.fastpath.empty_updates(), dev)
         desc = jax.device_put(jnp.zeros((batch, XD_WORDS), jnp.uint32), dev)
         now_d = jax.device_put(jnp.uint32(0), dev)
-        exe = _express_jit(self.fastpath.geom, self.table_impl).lower(
+        exe = _express_jit(self.fastpath.geom).lower(
             self.tables.dhcp, upd, desc, now_d).compile()
         _EXPRESS_AOT[key] = exe
         return exe
@@ -1153,116 +1123,6 @@ class Engine:
             nat_stats=np.zeros(NAT_NSTATS, dtype=np.uint32),
             qos_stats=np.zeros(QOS_NSTATS, dtype=np.uint32),
             spoof_stats=np.zeros(ANTISPOOF_NSTATS, dtype=np.uint32))
-
-    # -- devloop megakernel path (devloop/host.py ring pump) --------------
-
-    def devloop_aot(self, k: int, batch: int, device=None):
-        """The compiled devloop megakernel for this (k, batch) ring
-        geometry, or None — the geometry-miss contract mirrors
-        express_aot: a None never compiles on the serving path."""
-        from bng_tpu.devloop import kernel
-
-        return kernel.get_compiled(self, k, batch, device)
-
-    def compile_devloop_aot(self, k: int, batch: int, device=None):
-        """Compile the devloop megakernel at setup time (the
-        compile_express_aot discipline — never on the dispatch path)."""
-        from bng_tpu.devloop import kernel
-
-        if device is not None:
-            self._place_dhcp_chain(device)
-        return kernel.compile_devloop(self, k, batch, device)
-
-    def prepare_devloop_dispatch(self, ring, n_slots: int, now: float,
-                                 device=None):
-        """Main-thread half of a devloop ring dispatch: fault point,
-        update drain and argument staging — everything that must stay
-        ORDERED with admission and the control plane so two chaos runs
-        drain the same deltas at the same ring boundaries. Returns
-        ``((upd, ring_d, n_d, now_d), resynced)``; `resynced` flags a
-        bulk-build resync inside the drain (the engine chain was
-        rebound wholesale — the pump must re-seed its device-resident
-        chain from `tables.dhcp` before the next call)."""
-        self._dispatch_fault()
-        chain_before = self.tables.dhcp
-        upd = self._drain_fastpath_updates()
-        resynced = self.tables.dhcp is not chain_before
-        # donation safety (the run_express_aot guard): the program
-        # donates the ring and writes verdict blocks over it. The pump
-        # stages from numpy (fresh device buffer); defensively copy a
-        # jax-array ring rather than consume a caller's live buffer.
-        ring_d = (jnp.array(ring, copy=True) if isinstance(ring, jax.Array)
-                  else jnp.asarray(ring))
-        if device is not None and device != _process_default_device():
-            # explicit placement ONLY when the express stream lives off
-            # the process-default device: on the default device the
-            # executable places host arrays itself, and walking the
-            # ~26 chain/update leaves through device_put costs ~1.5ms
-            # of pure dispatch ceremony per ring on CPU — the exact
-            # host-side cost this lane exists to amortize. Placement
-            # AFTER the drain (resync rebinds self.tables).
-            self._place_dhcp_chain(device)
-            upd = jax.device_put(upd, device)
-            ring_d = jax.device_put(ring_d, device)
-            n_d = jax.device_put(jnp.uint32(int(n_slots)), device)
-            now_d = jax.device_put(jnp.uint32(int(now)), device)
-        else:
-            n_d = _u32_scalar(int(n_slots))
-            now_d = _u32_scalar(int(now))
-        return (upd, ring_d, n_d, now_d), resynced
-
-    @staticmethod
-    def call_devloop_aot(exe, dhcp_chain, cursors, prepared, device=None):
-        """Executable half of a ring dispatch: PURE — touches no engine
-        state, so the pump's dispatch worker may run it off the main
-        thread while admission keeps filling the next ring. The chain
-        is double-buffered (input NOT donated): `dhcp_chain` stays a
-        live, readable handle while the call is in flight, which is
-        what lets `tables.dhcp` remain published to the rest of the
-        engine until the retire adopts the returned chain."""
-        from bng_tpu.devloop.kernel import DevloopResult
-
-        cur_d = (cursors if isinstance(cursors, jax.Array)
-                 else jnp.asarray(cursors))
-        if (device is not None and device != _process_default_device()
-                and not isinstance(cursors, jax.Array)):
-            cur_d = jax.device_put(cur_d, device)
-        upd, ring_d, n_d, now_d = prepared
-        dhcp_tables, blocks, cursors_out, stats = exe(
-            dhcp_chain, upd, ring_d, n_d, cur_d, now_d)
-        return DevloopResult(
-            dhcp_tables=dhcp_tables, blocks=blocks, cursors=cursors_out,
-            dhcp_stats=stats,
-            nat_stats=np.zeros(NAT_NSTATS, dtype=np.uint32),
-            qos_stats=np.zeros(QOS_NSTATS, dtype=np.uint32),
-            spoof_stats=np.zeros(ANTISPOOF_NSTATS, dtype=np.uint32))
-
-    def adopt_devloop_chain(self, dhcp_tables, *, count: bool = True) -> None:
-        """Publish a retired ring's output chain as the authoritative
-        dhcp table state (main thread, at retire — the single
-        `engine.tables` writer discipline, BNG041). Monotone: with
-        depth>1 rings in flight each retire publishes an older chain
-        than the worker is already threading; the final flush publishes
-        the newest. ``count=False`` republishes a chain without claiming
-        a ring dispatch happened (the pump's resync-race repair)."""
-        self.tables = self.tables._replace(dhcp=dhcp_tables)
-        if count:
-            self.stats.batches += 1
-
-    def run_devloop_aot(self, exe, ring, n_slots: int, cursors, now: float,
-                        device=None):
-        """Synchronous composition of one ring dispatch (prepare ->
-        call -> adopt): one update drain, one executable call, one
-        table-chain thread for the WHOLE ring — the k-fold amortization
-        this lane exists for. The pump splits these halves across its
-        dispatch worker; tests and direct callers get the one-shot
-        form. Callers must adopt the returned `cursors` handle."""
-        prepared, _resynced = self.prepare_devloop_dispatch(
-            ring, n_slots, now, device)
-        res = self.call_devloop_aot(exe, self.tables.dhcp, cursors,
-                                    prepared, device)
-        self.adopt_devloop_chain(res.dhcp_tables)
-        return res
 
     def _dispatch_step(self, pkt, length, fa, now_s, now_us) -> PipelineResult:
         """Enqueue one jitted step (async — outputs are futures). The table

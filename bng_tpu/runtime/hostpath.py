@@ -28,13 +28,11 @@ rules:
    batch test cannot prove uncoupled — so the two paths can never
    disagree, and the unpressured fast path touches no per-frame Python.
 
-Path selection mirrors BNG_TABLE_IMPL (ops/table.py): BNG_HOST_PATH=
-scalar|vector, resolved at construction time by the consumers (PyRing,
-SlowPathFleet, Engine). The default stays `scalar` until the vector
-cohort has baselined in the perf ledger (`bench.py --host-ab` emits
-both cohorts under distinct `host_path` identities; the gate refuses
-cross-path comparison with rc=3) — the same flip-after-measurement
-discipline the table kernels and the AOT express lane followed.
+Path selection: BNG_HOST_PATH=scalar|vector, resolved at construction
+time by the consumers (PyRing, SlowPathFleet, Engine). The default stays
+`scalar` until the two have been raced on the chip over the cells of
+`BENCHMARK.json` (`benchmark/run.py`; ROADMAP D2-host): the race ends in
+the deletion of one side.
 """
 
 from __future__ import annotations
@@ -50,17 +48,16 @@ FLAG_DHCP_CTRL = 0x2
 
 HOST_PATHS = ("scalar", "vector")
 
-# Default from BNG_HOST_PATH; "scalar" until the vector cohort has
-# baselined in the ledger (flip once --host-ab history exists — the
-# BNG_TABLE_IMPL discipline).
+# Default from BNG_HOST_PATH; "scalar" until the chip race (ROADMAP
+# D2-host).
 HOST_PATH = os.environ.get("BNG_HOST_PATH", "scalar")
 
 
 def resolved_host_path() -> str:
     """The host path ring/fleet/engine constructions resolve against.
     Resolution happens at CONSTRUCTION time (the resolved choice is
-    snapshotted per instance, like Engine.table_impl): an env flip
-    after construction needs new instances."""
+    snapshotted per instance): an env flip after construction needs
+    new instances."""
     if HOST_PATH not in HOST_PATHS:
         raise ValueError(
             f"BNG_HOST_PATH={HOST_PATH!r}: expected one of {HOST_PATHS}")

@@ -503,7 +503,7 @@ class PyRing:
     """Pure-Python ring with the NativeRing API (the _stub.go fallback).
 
     Two host paths (ISSUE 14), selected per instance by BNG_HOST_PATH
-    (or the `host_path` kwarg) in the BNG_TABLE_IMPL mold:
+    (or the `host_path` kwarg):
 
     - ``scalar`` (default) — the original per-frame implementation:
       frames live as bytes in deques, classify/steer run the scalar

@@ -101,14 +101,6 @@ class SchedulerConfig:
     # one exists, else share). An int pins jax.devices()[i]; -1 forces
     # same-device mode (single-chip: interleave-only isolation).
     express_device_index: int | None = None
-    # device-resident express serving loop (ISSUE 18): "aot" = the
-    # per-batch AOT lane (default until the devloop cohort baselines in
-    # the perf ledger), "devloop" = the k-batch ring megakernel
-    # (bng_tpu/devloop/), "auto" = devloop when its megakernel compiles,
-    # aot otherwise. BNG_EXPRESS_LOOP overrides.
-    express_loop: str = "aot"
-    devloop_k: int = 8        # ring slots per megakernel dispatch
-    devloop_depth: int = 2    # in-flight rings (async retire window)
 
 
 class Completion(NamedTuple):
@@ -177,14 +169,12 @@ class TieredScheduler:
         self.express_jit_dispatches = 0
         self._aot_enabled = (self.cfg.express_aot
                              and os.environ.get("BNG_EXPRESS_AOT") != "0")
-        # express rung-fallback accounting (ISSUE 18 gray-failure
-        # hardening): reason -> count, folded into
+        # express rung-fallback accounting: reason -> count, folded into
         # bng_express_fallback_total by control/metrics.py. Populated by
         # _note_fallback and the dispatch-time geometry-miss path — any
         # express serving rung below the one configured shows up here.
         self.express_fallbacks: dict[str, int] = {}
-        self._devloop = None           # DevloopPump when the loop is live
-        self.express_loop = "aot"      # the RESOLVED loop (cf. cfg wish)
+        self.express_loop = "aot"  # read by benchmark/lib/app.py selectors()
         # _aot_ready gates the per-frame admission parse only: after a
         # permanent compile failure no executable will ever consume a
         # descriptor, so submit() must not keep paying parse_express on
@@ -205,7 +195,6 @@ class TieredScheduler:
         self._ensure_engine_staging()
         if self._aot_enabled:
             self._compile_express_aot()
-        self._setup_devloop()
 
     def _ensure_engine_staging(self) -> None:
         """Declare this scheduler's worst-case in-flight dispatch count
@@ -230,11 +219,9 @@ class TieredScheduler:
                                             self._express_dev)
             self._aot_ready = True
         except Exception as e:  # noqa: BLE001 — downgrade, never brick
-            # gray-failure hardening (ISSUE 18): before this, the
-            # permanent downgrade only warn()ed once at setup — count it
-            # and flight-record it so a cluster serving every OFFER
-            # through the jit-full rung is visible in metrics, not just
-            # in one scrollback line
+            # count it and flight-record it, so a cluster serving every
+            # OFFER through the jit-full rung is visible in metrics, not
+            # just in one scrollback line
             self._note_fallback(
                 "compile_failed",
                 f"express AOT compile failed, jit-full will serve: "
@@ -250,49 +237,6 @@ class TieredScheduler:
         tele.trigger(TRIG_EXPRESS_FALLBACK,
                      f"express fallback ({reason}): {detail}")
         self._log.warning("express fallback", reason=reason, detail=detail)
-
-    def _setup_devloop(self) -> None:
-        """Resolve + arm the express serving loop (ISSUE 18). The
-        devloop megakernel compiles HERE (init / engine-adopt), never on
-        the dispatch path; any refusal to arm falls back to the
-        per-batch AOT lane loudly when devloop was explicitly asked
-        for."""
-        if self._devloop is not None:
-            self._devloop.close()  # release the old pump's worker thread
-        self._devloop = None
-        want = os.environ.get("BNG_EXPRESS_LOOP", self.cfg.express_loop)
-        if want not in ("aot", "devloop", "auto"):
-            raise ValueError(
-                f"BNG_EXPRESS_LOOP/express_loop must be aot|devloop|auto,"
-                f" got {want!r}")
-        self.express_loop = "aot"
-        if want == "aot":
-            return
-        if not (self._aot_enabled and self._aot_ready):
-            # no descriptors at admission -> nothing to stage in a ring;
-            # explicit devloop requests degrade LOUDLY, auto quietly
-            # (the compile-failure fallback above already fired)
-            if want == "devloop":
-                self._note_fallback(
-                    "devloop_unavailable",
-                    "devloop requires the AOT express lane (descriptor "
-                    "admission); serving per-batch")
-            return
-        k = int(os.environ.get("BNG_DEVLOOP_K", self.cfg.devloop_k))
-        try:
-            self.engine.compile_devloop_aot(k, self.express.cfg.batch,
-                                            self._express_dev)
-        except Exception as e:  # noqa: BLE001 — downgrade, never brick
-            self._note_fallback(
-                "devloop_compile_failed",
-                f"megakernel k={k} batch={self.express.cfg.batch} "
-                f"refused to compile, per-batch AOT will serve: "
-                f"{type(e).__name__}: {e}")
-            return
-        from bng_tpu.devloop.host import DevloopPump
-
-        self._devloop = DevloopPump(self, k, self.cfg.devloop_depth)
-        self.express_loop = "devloop"
 
     def _pick_express_device(self):
         idx = self.cfg.express_device_index
@@ -369,11 +313,6 @@ class TieredScheduler:
             reason = self.express.close_reason(now) or CLOSE_FLUSH
             pend, reason = self.express.close_batch(now, reason)
             retired += self._dispatch_express(pend, now, reason)
-        if self._devloop is not None:
-            # ship the partial ring + retire every in-flight ring BEFORE
-            # the per-batch ring drain: a devloop miss re-dispatches
-            # slots through the direct path, which lands entries there
-            retired += self._devloop.flush(now)
         retired += self._retire_express_all()
         while len(self.bulk):
             reason = self.bulk.close_reason(now) or CLOSE_FLUSH
@@ -413,11 +352,6 @@ class TieredScheduler:
         lanes stay usable; traffic resumes on the next submit/poll."""
         retired = self.flush(now)
         jax.block_until_ready(jax.tree_util.tree_leaves(self.engine.tables))
-        if self._devloop is not None:
-            # the ring's cursor handle materializes too: after quiesce
-            # the devloop audit (cursor-vs-host agreement) is legal —
-            # nothing in flight ahead of the handle, nothing donated
-            jax.block_until_ready(self._devloop.ring.cursors)
         return retired
 
     def adopt_engine(self, engine) -> int:
@@ -438,10 +372,6 @@ class TieredScheduler:
             # changed geometry compiles here, at the flip, not on the
             # first post-flip dispatch
             self._compile_express_aot()
-        # re-arm the serving loop against the standby's geometry — a
-        # standby that refuses to lower the megakernel downgrades the
-        # loop to per-batch AOT at the flip, loudly, never mid-dispatch
-        self._setup_devloop()
         return retired
 
     # -- express lane ----------------------------------------------------
@@ -454,26 +384,10 @@ class TieredScheduler:
                 break
             pend, reason = self.express.close_batch(now, reason)
             retired += self._dispatch_express(pend, now, reason)
-        if self._devloop is not None:
-            # the loop's own beat: opportunistic ring retire + the ring
-            # deadline close (a partial ring must not strand slots)
-            retired += self._devloop.poll(now)
         return retired + self._retire_express_all()
 
     def _dispatch_express(self, pend, now: float, reason: str) -> int:
-        """Route one closed express batch to the resolved serving loop:
-        the devloop ring pump stages it as one ring slot (device touched
-        once per k batches), the per-batch path dispatches immediately.
-        Returns frames retired as a side effect (ring overflow)."""
-        if not pend:
-            return 0
-        if self._devloop is not None:
-            return self._devloop.add_batch(pend, now, reason)
-        return self._dispatch_express_direct(pend, now, reason)
-
-    def _dispatch_express_direct(self, pend, now: float,
-                                 reason: str) -> int:
-        """Dispatch one express batch per-batch; returns frames retired
+        """Dispatch one closed express batch; returns frames retired
         as a side effect of the completion ring overflowing its depth.
 
         AOT path: descriptor rows (staged at admission) go straight to
@@ -509,8 +423,8 @@ class TieredScheduler:
                     self.express_fallbacks.get("geometry_miss", 0) + 1)
                 tele.trigger(TRIG_EXPRESS_AOT_MISS,
                              f"no compiled express program for batch="
-                             f"{self.express.cfg.batch} impl="
-                             f"{eng.table_impl}: jit-full fallback served")
+                             f"{self.express.cfg.batch}: jit-full "
+                             f"fallback served")
         t0 = tele.t()
         cfg_epoch = None
         try:
@@ -965,15 +879,12 @@ class TieredScheduler:
         out["express"]["aot_dispatches"] = self.express_aot_dispatches
         out["express"]["jit_dispatches"] = self.express_jit_dispatches
         out["express"]["aot_misses"] = self.express_aot_misses
-        out["express"]["loop"] = self.express_loop
         out["express"]["fallbacks"] = dict(self.express_fallbacks)
         out["express"]["behind_bulk"] = self.express_behind_bulk
         out["bulk"]["blocked_retires"] = self.bulk_blocked_retires
         # the Tracer's tiling and device-occupancy sums (armed, or as the
         # last disarm left them; zeros if never armed)
         out["trace"] = tele.trace_sums()
-        if self._devloop is not None:
-            out["express"]["devloop"] = self._devloop.stats()
         out["completions_dropped"] = self.completions_dropped
         out["oversize_dropped"] = self.oversize_dropped
         return out

@@ -2,16 +2,14 @@
 
 The reference refuses to ship an eBPF program the kernel verifier rejects
 (cmd/verify-bpf/main.go:58-112, bpf/test-verifier.sh). The TPU analog of
-"passes the verifier" is "lowers through Mosaic/XLA for the TPU target":
-interpret-mode tests are false confidence — a kernel can pass its CPU
-suite while Mosaic rejects its block shapes.
+"passes the verifier" is "compiles for the TPU target": a program can
+pass its CPU suite and still be refused by the chip's compiler.
 
 Every hot program has a builder here: `build_*(geometry)` returns the
 jitted program and its arguments. Two callers share them:
 
   - `verify_tpu_lowering()` compiles each at the TOY geometry for the
-    ATTACHED backend (`python bench.py --verify-lowering`, exit != 0 on
-    failure; bench also runs it before the headline);
+    ATTACHED backend;
   - tests/test_tpu_lowering.py compiles each at the REAL_1M geometry
     for a DESCRIBED v5e (`compile_for`), with no chip attached.
 
@@ -80,8 +78,8 @@ def _fastpath(g: Geometry):
     return fp
 
 
-def build_qos(impl: str, g: Geometry = TOY):
-    import bng_tpu.ops.qos as qos_mod
+def build_qos(g: Geometry = TOY):
+    from bng_tpu.ops.qos import qos_kernel
     from bng_tpu.runtime.engine import QoSTables
 
     B = g.batch
@@ -93,33 +91,15 @@ def build_qos(impl: str, g: Geometry = TOY):
     active = jnp.ones((B,), dtype=bool)
 
     def kernel(t, i, l):
-        old = qos_mod.PREFIX_IMPL  # read at trace time
-        qos_mod.PREFIX_IMPL = impl
-        try:
-            return qos_mod.qos_kernel(i, l, active, t, qos.geom,
-                                      jnp.uint32(1)).allowed
-        finally:
-            qos_mod.PREFIX_IMPL = old
+        return qos_kernel(i, l, active, t, qos.geom, jnp.uint32(1)).allowed
 
     return jax.jit(kernel), (qos.up.device_state(), ips, lens)
 
 
-def build_pallas_seg(compute: str = "both", B: int = 1024):
-    """The raw Pallas QoS kernel, interpret=False: real Mosaic lowering."""
-    from bng_tpu.ops.pallas_qos import seg_prefix_total
-
-    slot = jnp.asarray((np.arange(B) % 37).astype(np.int32))
-    vec = jnp.full((B,), 900.0, dtype=jnp.float32)
-    return (jax.jit(lambda s, v: seg_prefix_total(
-        s, v, interpret=False, compute=compute)), (slot, vec))
-
-
-def build_table(impl: str, interpret: bool | None = None, g: Geometry = TOY):
-    """The impl-dispatched probe (the surface every hot-path kernel
-    funnels through) on the subscriber-table shape. impl='pallas',
-    interpret=False forces real Mosaic lowering."""
-    from bng_tpu.ops import table as table_mod
-    from bng_tpu.ops.table import HostTable
+def build_table(g: Geometry = TOY):
+    """The batched probe (the surface every hot-path kernel funnels
+    through) on the subscriber-table shape."""
+    from bng_tpu.ops.table import HostTable, device_lookup
 
     K, V = 2, 8
     t = HostTable(g.sub_nbuckets, K, V, stash=g.stash, name="verify")
@@ -130,32 +110,26 @@ def build_table(impl: str, interpret: bool | None = None, g: Geometry = TOY):
     nb, stash = t.nbuckets, t.stash
 
     def look(state, q):
-        with table_mod.forced_impl(impl):
-            if impl == "pallas" and interpret is not None:
-                from bng_tpu.ops.pallas_table import pallas_lookup
-
-                r = pallas_lookup(state, q, nb, stash, interpret=interpret)
-            else:
-                r = table_mod.device_lookup(state, q, nb, stash)
+        r = device_lookup(state, q, nb, stash)
         return r.found, r.slot, r.vals
 
     return jax.jit(look), (t.device_state(), jnp.asarray(keys))
 
 
-def build_dhcp_express(impl: str, g: Geometry = TOY):
+def build_dhcp_express(g: Geometry = TOY):
     """The express-lane OFFER program (donated chain + aliased packet
-    batch) under one table impl."""
+    batch)."""
     from bng_tpu.runtime.engine import _dhcp_jit
 
     B = g.express_batch
     fp = _fastpath(g)
-    return _dhcp_jit(fp.geom, impl), (
+    return _dhcp_jit(fp.geom), (
         fp.device_tables(), fp.empty_updates(),
         jnp.zeros((B, g.pkt_slot), dtype=jnp.uint8),
         jnp.zeros((B,), dtype=jnp.uint32), jnp.uint32(1))
 
 
-def build_express_aot(impl: str, g: Geometry = TOY):
+def build_express_aot(g: Geometry = TOY):
     """The AOT express OFFER program (ISSUE 13): descriptor in, verdict
     block out, tables + descriptor donated. Exactly the lower+compile
     the serving path performs at scheduler init — a program that fails
@@ -165,24 +139,10 @@ def build_express_aot(impl: str, g: Geometry = TOY):
     from bng_tpu.runtime.engine import _express_jit
 
     fp = _fastpath(g)
-    return _express_jit(fp.geom, impl), (
+    return _express_jit(fp.geom), (
         fp.device_tables(), fp.empty_updates(),
         jnp.zeros((g.express_batch, XD_WORDS), dtype=jnp.uint32),
         jnp.uint32(1))
-
-
-def build_express_ring(impl: str, k: int = 8, g: Geometry = TOY):
-    """The device-resident express loop's k-slot ring megakernel
-    (devloop/kernel.py), as compile_devloop lowers it."""
-    from bng_tpu.devloop.kernel import _devloop_jit
-    from bng_tpu.devloop.ring import CUR_WORDS
-    from bng_tpu.ops.express import XD_WORDS
-
-    fp = _fastpath(g)
-    return _devloop_jit(fp.geom, k, impl), (
-        fp.device_tables(), fp.empty_updates(),
-        jnp.zeros((k, g.express_batch, XD_WORDS), jnp.uint32),
-        jnp.uint32(0), jnp.zeros((CUR_WORDS,), jnp.uint32), jnp.uint32(0))
 
 
 def _engine(g: Geometry):
@@ -239,7 +199,7 @@ def build_sharded(mesh, g: Geometry = TOY):
                                     sharding=split)
 
     now = jax.ShapeDtypeStruct((), jnp.uint32, sharding=whole)
-    return _sharded_step_jit(mesh, eng.geom, n, eng.table_impl), (
+    return _sharded_step_jit(mesh, eng.geom, n), (
         jax.tree.map(stacked, eng.tables),
         jax.tree.map(stacked, eng._empty_updates()),
         lanes(g.pkt_slot, dtype=jnp.uint8), lanes(dtype=jnp.uint32),
@@ -247,7 +207,7 @@ def build_sharded(mesh, g: Geometry = TOY):
 
 
 def _check_sharded() -> None:
-    """Sharded step over every attached device (n=1 on the bench chip —
+    """Sharded step over every attached device (n=1 on one chip —
     the 8-way variant is exercised by dryrun_multichip on the CPU mesh)."""
     from bng_tpu.parallel.sharded import ShardedCluster
 
@@ -260,54 +220,36 @@ def _check_sharded() -> None:
     cl.dhcp_step(pkt, ln, 1)  # the sharded control fast lane too
 
 
-def _compiles(build: Callable, *a) -> Callable[[], None]:
+def _compiles(build: Callable) -> Callable[[], None]:
     def check() -> None:
-        compile_for(build(*a))
+        compile_for(build())
 
     return check
 
 
-# (name, check, tpu_only).  tpu_only checks force real Mosaic lowering and
-# cannot run elsewhere; the rest also run on CPU so the *harness itself*
+# (name, check). Every check also runs on the CPU, so the *harness itself*
 # (table constructors, kernel signatures) is exercised by the plain test
-# suite — round 3 found the gate broken by NATManager API drift that the
-# auto-skip had hidden.
-CHECKS: list[tuple[str, Callable[[], None], bool]] = [
-    ("qos_kernel[sort]", _compiles(build_qos, "sort"), False),
-    ("qos_kernel[pallas]", _compiles(build_qos, "pallas"), True),
-    ("pallas_seg_prefix_total", _compiles(build_pallas_seg), True),
-    # the impl-dispatched cuckoo probe (ISSUE 11): the interp variant
-    # exercises the Pallas harness on every backend; the compiled
-    # variant is the Mosaic gate for the fused probe kernel
-    ("table_lookup[xla]", _compiles(build_table, "xla"), False),
-    ("table_lookup[pallas-interp]", _compiles(build_table, "pallas", True),
-     False),
-    ("table_lookup[pallas]", _compiles(build_table, "pallas", False), True),
-    ("dhcp_express[xla]", _compiles(build_dhcp_express, "xla"), False),
-    ("dhcp_express[pallas]", _compiles(build_dhcp_express, "pallas"), True),
+# suite — round 3 found the gate broken by NATManager API drift.
+CHECKS: list[tuple[str, Callable[[], None]]] = [
+    ("qos_kernel", _compiles(build_qos)),
+    ("table_lookup", _compiles(build_table)),
+    ("dhcp_express", _compiles(build_dhcp_express)),
     # the AOT minimal OFFER program (ISSUE 13) — the architecture the
     # offer_device_only_p99_us gate measures on the express lane
-    ("express_aot[xla]", _compiles(build_express_aot, "xla"), False),
-    ("express_aot[pallas]", _compiles(build_express_aot, "pallas"), True),
-    ("express_ring[xla]", _compiles(build_express_ring, "xla"), False),
-    ("fused_pipeline_step", _compiles(build_pipeline), False),
-    ("sharded_step", _check_sharded, False),
+    ("express_aot", _compiles(build_express_aot)),
+    ("fused_pipeline_step", _compiles(build_pipeline)),
+    ("sharded_step", _check_sharded),
 ]
 
 
-def verify_tpu_lowering(verbose: bool = True,
-                        tpu: bool = True) -> list[tuple[str, str | None]]:
+def verify_tpu_lowering(verbose: bool = True) -> list[tuple[str, str | None]]:
     """Compile every hot program for the attached backend.
 
-    tpu=False (CPU test suite) skips the Mosaic-only checks but still
-    compiles everything else, catching harness/API drift off-hardware.
     Returns [(name, None | error_string)]. Raises nothing; callers decide
-    (pytest asserts, bench exits non-zero).
+    (pytest asserts).
     """
     results: list[tuple[str, str | None]] = []
-    for name, check, tpu_only in CHECKS:
-        if tpu_only and not tpu:
-            continue
+    for name, check in CHECKS:
         try:
             check()
             results.append((name, None))

@@ -69,14 +69,13 @@ _ERRS = {
 }
 
 # ---------------------------------------------------------------------------
-# pump path selection (the BNG_HOST_PATH / BNG_TABLE_IMPL mold)
+# pump path selection (the BNG_HOST_PATH mold)
 # ---------------------------------------------------------------------------
 
 WIRE_PUMPS = ("scalar", "vector")
 
-# Default from BNG_WIRE_PUMP; "scalar" until the vector cohort has
-# baselined in the ledger (flip once --wire-ab history exists — the
-# flip-after-measurement discipline every impl selector follows).
+# Default from BNG_WIRE_PUMP; "scalar" until the chip race (ROADMAP
+# D2-host).
 WIRE_PUMP = os.environ.get("BNG_WIRE_PUMP", "scalar")
 
 
@@ -217,8 +216,8 @@ class _FifoU64:
 
 class SimKernelRings:
     """Deterministic in-process stand-in for the kernel's AF_XDP rings —
-    the memory rung's wire kernel (tests, `bench.py --wire-ab`,
-    `bng loadtest --wire` without privileges).
+    the memory rung's wire kernel (tests, `bng loadtest --wire` without
+    privileges).
 
     Same four verbs as XskKernel over the ring's REAL UMEM: fill
     stockpiles the pump's free frames, `inject()` plays the far end of

@@ -33,7 +33,7 @@ across that identity. This module is both halves plus the trend gate:
   noisy cohort (PERF_NOTES §12). A stage every cohort line carries but
   the candidate dropped is a coverage regression, flagged by name.
 
-rc contract (`bng perf gate` / `bench.py --gate`):
+rc contract (`bng perf gate`):
   0 clean (or vacuous: cohort smaller than --min-cohort)
   1 regression — stderr names the regressed stage(s)/key(s)
   2 internal error (unreadable ledger, error-line candidate)
@@ -103,15 +103,10 @@ def environment_fingerprint() -> dict:
                                   or str(dev))
         except Exception:  # noqa: BLE001 — backend may be half-up
             pass
-    # table-probe impl (xla | pallas): rides the fingerprint so Pallas
-    # and XLA runs are never silently compared (cohort_key keys on it).
-    # sys.modules only — importing ops.table here would drag jax in.
-    tbl = sys.modules.get("bng_tpu.ops.table")
-    if tbl is not None:
-        try:
-            env["table_impl"] = tbl.current_impl_label()
-        except Exception:  # noqa: BLE001 — fingerprint is best-effort
-            pass
+    # table-probe impl: one probe in the tree; recorded lines carry the
+    # field and cohort_key keys on it
+    if "bng_tpu.ops.table" in sys.modules:
+        env["table_impl"] = "xla"
     # host serving path (scalar | vector, ISSUE 14): same discipline —
     # a vectorized-host run must never trend against scalar history
     hp = sys.modules.get("bng_tpu.runtime.hostpath")
@@ -236,9 +231,8 @@ def host_path(line: dict) -> str:
     """Which HOST serving path staged the run (ISSUE 14): `scalar` (the
     original per-frame ring/admission/pack loops) vs `vector` (the
     batch-native SoA path behind BNG_HOST_PATH). The top-level stamp
-    wins (`bench.py --host-ab` records it per cohort), then the env
-    fingerprint. Unstamped lines predate the vector path and ran the
-    per-frame loops — defaulting to `scalar` keeps existing history one
+    wins, then the env fingerprint. Unstamped lines predate the vector
+    path and ran the per-frame loops — defaulting to `scalar` keeps existing history one
     cohort. The two paths do the same work with different host
     machinery: a host-stage trend across them is an architecture
     comparison, not a regression signal (rc=3 refusal, the table_impl
@@ -253,8 +247,8 @@ def host_path(line: dict) -> str:
 def wire_pump(line: dict) -> str:
     """Which wire-pump implementation moved the run's frames (ISSUE
     15): `scalar` (the per-frame ctypes loop) vs `vector` (the batch
-    verbs behind BNG_WIRE_PUMP). The top-level stamp wins (`bench.py
-    --wire-ab` records it per cohort), then the env fingerprint.
+    verbs behind BNG_WIRE_PUMP). The top-level stamp wins, then the env
+    fingerprint.
     Unstamped lines predate the vector pump (or never touched a wire
     loop) and ran — if anything — the per-frame pump: defaulting to
     `scalar` keeps existing history one cohort. A wire-stage trend
@@ -269,10 +263,9 @@ def wire_pump(line: dict) -> str:
 
 def n_shards(line: dict) -> int:
     """How many dataplane shards served the run (ISSUE 12): the
-    top-level stamp wins (`bench.py --shards` records it on every
-    line), then the legacy spelling `devices` (the config-5 sharded
-    bench always recorded its mesh width there), then the env
-    fingerprint. Unstamped lines are single-device by construction —
+    top-level stamp wins, then the legacy spelling `devices` (the
+    config-5 sharded bench always recorded its mesh width there), then
+    the env fingerprint. Unstamped lines are single-device by construction —
     defaulting to 1 keeps existing history one cohort. An aggregate
     8-shard Mpps line must never trend against single-device history:
     the cohort keys on this."""
@@ -335,7 +328,7 @@ def _gateable(line: dict) -> bool:
 
 def newest_gateable_index(lines: list[dict]) -> int | None:
     """Index of the line gate() would pick as candidate — callers that
-    must tie a verdict to a specific run (bench.py --gate) compare this
+    must tie a verdict to a specific run compare this
     against the pre-run line count, so a run that appended nothing (or
     only an error line) can never get a CLEAN verdict about stale
     history."""
@@ -530,8 +523,8 @@ def gate(lines: list[dict], last_k: int = 8, min_cohort: int = 3,
     """Gate the newest gateable line against its comparable history.
 
     ``metric`` narrows candidacy to one metric's newest line; the
-    default gates whatever run landed last (the `bench.py --gate`
-    posture: you just appended a line, is it a regression?)."""
+    default gates whatever run landed last (you just appended a line,
+    is it a regression?)."""
     rep = GateReport()
     corrupt = sum(1 for ln in lines if "_corrupt" in ln)
     if corrupt:
@@ -698,10 +691,9 @@ def gate_file(path: str, **kw) -> GateReport:
 
 
 def default_ledger_path() -> str:
-    """$BNG_BENCH_LOG, or bench_runs.jsonl at the repo root (next to
-    bench.py). The ONE resolution rule — bench._persist, `bench.py
-    --gate` and `bng perf` all call this, so they can never gate a
-    different file than the run appended to."""
+    """$BNG_BENCH_LOG, or bench_runs.jsonl at the repo root. The ONE
+    resolution rule — every appender and `bng perf` call this, so they
+    can never gate a different file than the run appended to."""
     envp = os.environ.get("BNG_BENCH_LOG")
     if envp:
         return envp

@@ -53,12 +53,10 @@ TRIG_EXPRESS_AOT_MISS = "express_aot_miss"
 # fallback must dump the flight ring and flip bng_wire_rung, never
 # masquerade as wire serving
 TRIG_WIRE_FALLBACK = "wire_rung_fallback"
-# the express lane fell back a rung (ISSUE 18 gray-failure hardening):
-# a devloop megakernel miss / compile failure re-dispatching per-batch,
-# or the per-batch AOT compile itself failing back to jit-full. Before
-# this trigger the compile-failure path only warn()ed once at setup —
-# a cluster could serve every OFFER through the slow architecture with
-# healthy-looking aggregate counters and no flight-record evidence
+# the express lane fell back a rung: the per-batch AOT compile failing
+# back to jit-full. Without this trigger a cluster could serve every
+# OFFER through the slow architecture with healthy-looking aggregate
+# counters and no flight-record evidence
 TRIG_EXPRESS_FALLBACK = "express_fallback"
 # the cluster fabric's failure detector changed a member's verdict
 # (ISSUE 19): suspect (beats stopped — possible partition), gray (beats

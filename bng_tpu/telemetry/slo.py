@@ -49,8 +49,7 @@ from bng_tpu.telemetry.hist import counts_percentile
 from bng_tpu.telemetry.spans import LANE_NAMES, STAGE_NAMES
 
 # the paper's headline targets (BASELINE.md / PAPER.md): the trend gate
-# (telemetry/ledger.py) annotates every gated run against these, and
-# bench.py's vs_baseline columns are derived from the same constants.
+# (telemetry/ledger.py) annotates every gated run against these.
 HEADLINE_TARGETS = {
     # <50us p99 for the device-only OFFER program @1M subscribers
     "offer_device_only_p99_us": 50.0,
@@ -105,9 +104,8 @@ class SLOSpec:
 # express dispatch's occupancy BY READINESS on the served path (scheduler
 # retire; an upper bound on execution: launch latency, the outputs' copy
 # and the delay until the host looks are inside it, spans.py). Nothing
-# else feeds that lane: bench.py's profiler-fenced samples go to lane
-# `bench`, and a 236 ms bulk step's sample to lane `bulk`. The target is
-# NOT met today, so a `bng run --telemetry-enabled` monitor breaches
+# else feeds that lane: a bulk step's sample goes to lane `bulk`. The
+# target is NOT met today, so a `bng run --telemetry-enabled` monitor breaches
 # `device` in every window with express traffic, and is meant to: on a
 # v5e the served path reads p50 1,829 / p99 4,918 us against a fenced
 # express step of 829 us (PERF.md §5, renew cell), until the step's cost
@@ -119,14 +117,9 @@ DEFAULT_SLOS: tuple[SLOSpec, ...] = (
     SLOSpec("lane_wait", 50_000.0,
             description="scheduler enqueue -> dispatch (oldest frame)"),
     SLOSpec("dispatch", 50_000.0, description="host-side jitted dispatch"),
-    SLOSpec("loop_fill", 2_000.0,
-            description="devloop: descriptor rows -> ring slot, per batch"),
-    SLOSpec("loop_wait", 100_000.0,
-            description="devloop: slot staged -> ring dispatch (bounded "
-                        "by the ring deadline)"),
-    SLOSpec("loop_retire", 50_000.0,
-            description="devloop: ring force + per-slot demux, amortized "
-                        "per batch"),
+    SLOSpec("loop_fill", 2_000.0, description="unstamped (spans.py)"),
+    SLOSpec("loop_wait", 100_000.0, description="unstamped (spans.py)"),
+    SLOSpec("loop_retire", 50_000.0, description="unstamped (spans.py)"),
     SLOSpec("device", HEADLINE_TARGETS["offer_device_only_p99_us"],
             lane="express",
             description="express dispatch on the device, by readiness "

@@ -232,7 +232,6 @@ class TestBulkOnLiveStepLoop:
         return packets.udp_packet(mac, b"\xff" * 6, 0, 0xFFFFFFFF, 68, 67,
                                   p.encode().ljust(320, b"\x00"))
 
-    @pytest.mark.slow  # compile-heavy; tier-1 runs -m 'not slow'
     def test_engine_step_after_bulk_serves_new_subscribers(self):
         from bng_tpu.control.nat import NATManager
         from bng_tpu.runtime.engine import Engine
@@ -298,18 +297,30 @@ class TestBulkOnLiveStepLoop:
 class TestReferenceCapacityGeometry:
     """The reference's NAT geometry (bpf/nat44.c:38-40 — 4M sessions,
     2M EIM endpoints, i.e. 2 flows per internal endpoint) stands up
-    through the bulk path. Scaled 20x down for CPU CI (the full 4M build
-    runs in the chip window via tpu_run.sh config2-4M); the STRUCTURE —
+    through the bulk path. Scaled 20x down for CPU CI; the STRUCTURE —
     sessions:EIM = 2:1, unique 5-tuples, reverse rows per session — is
     what this pins."""
 
-    def test_4m_geometry_scaled(self, monkeypatch):
-        import bench
-
-        monkeypatch.setenv("BNG_BENCH_EIM_SHARE", "2")
-        n_flows, n_subs = 200_000, 50_000
-        nat, flows = bench._build_nat_flows(n_flows, n_subs, NOW)
-        assert len(flows) == n_flows, bench._DIAG
+    def test_4m_geometry_scaled(self):
+        n_flows, n_subs, share = 200_000, 50_000, 2
+        sess_nb = 1 << (n_flows * 2 // 4).bit_length()
+        # 1008 64-port blocks a public IP: a pool that holds n_subs blocks
+        nat = NATManager(
+            public_ips=[ip_to_u32("203.0.113.1") + i
+                        for i in range(-(-n_subs // 1008) + 1)],
+            ports_per_subscriber=64, sessions_nbuckets=sess_nb,
+            sub_nat_nbuckets=sess_nb, stash=256)
+        fi = np.arange(n_flows, dtype=np.int64)
+        src_ips = ((10 << 24) + 2 + fi % n_subs).astype(np.uint32)
+        dst_ips = (ip_to_u32("93.184.0.0") + fi // n_subs).astype(np.uint32)
+        # `share` flows use one internal endpoint (src_ip, src_port); a
+        # distinct dst per shared sport keeps the 5-tuples unique
+        sports = (20000 + (fi // n_subs) // share).astype(np.uint32)
+        assert nat.bulk_allocate_nat(np.unique(src_ips), NOW) == n_subs
+        _, _, ok = nat.bulk_flows(src_ips, dst_ips, sports,
+                                  np.uint32(443), np.uint32(17), 100, NOW)
+        flows = np.stack([src_ips, dst_ips, sports], axis=1)[ok]
+        assert len(flows) == n_flows
         assert nat.sessions.count == n_flows
         assert nat.reverse.count == n_flows
         # the reference ratio: half as many EIM endpoints as sessions

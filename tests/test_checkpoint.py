@@ -555,7 +555,6 @@ def _client_frame(mac, msg_type, **kw):
                               pkt.encode().ljust(320, b"\x00"))
 
 
-@pytest.mark.slow  # compile-heavy; tier-1 runs -m 'not slow'
 class TestEngineRoundTrip:
     def test_save_restore_fastpath_parity(self, tmp_path):
         from bng_tpu.control import dhcp_codec, packets

@@ -69,7 +69,6 @@ def data_frame(src_mac, src_ip, dst_ip, sport, dport, payload=b"data", proto="ud
 
 
 class TestDORA:
-    @pytest.mark.slow  # compile-heavy; tier-1 runs -m 'not slow'
     def test_full_dora_then_fastpath(self, stack):
         engine, server, nat, qos, spoof, clock = stack
         mac = bytes.fromhex("02c0ffee0001")
@@ -464,7 +463,6 @@ class TestDeviceWalledGarden:
                         slow_path=server.handle_frame, clock=clock)
         return engine, server, nat, garden, clock
 
-    @pytest.mark.slow  # compile-heavy; tier-1 runs -m 'not slow'
     def test_pre_auth_drops_on_device_post_auth_passes(self):
         engine, server, nat, garden, clock = self._stack_with_garden()
         mac = bytes.fromhex("02aabb000077")

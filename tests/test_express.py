@@ -5,14 +5,14 @@ The acceptance surface of the minimal-program express lane:
 - **Byte identity vs the full program**: the whole express path
   (admission descriptor -> AOT probe program -> host template patch-in)
   produces replies bit-identical to `_dhcp_jit`'s on-device compose,
-  across >=4 table geometries and under BOTH table impls (`xla` and
-  `pallas` in interpret mode), over the full addressing matrix
+  across >=4 table geometries, over the full addressing matrix
   (broadcast/unicast/relayed, VLAN/QinQ, option-82, DISCOVER/REQUEST,
   dns variants, expired/unknown -> slow).
 - **Byte identity vs the codec**: an express template reply equals the
   slow-path server's codec-built reply for the same request (the
   express retire path routes through ReplyTemplate patch-in
-  unconditionally).
+  unconditionally); storms of several batches and a partial one decode,
+  by the codec, to the bindings the tables were given.
 - **AOT cache discipline**: a geometry hit serves without retracing
   (ops/express.TRACE_COUNT is a trace-time counter); a geometry miss
   falls back to the jit-full path LOUDLY (miss counter + flight-record
@@ -21,7 +21,8 @@ The acceptance surface of the minimal-program express lane:
   over express-fed breakdowns.
 
 Geometries are kept tiny: the express program is small, but each
-(geometry, impl) also compiles the full `_dhcp_jit` comparison program.
+geometry of the identity matrix also compiles the full `_dhcp_jit`
+comparison program.
 """
 
 from __future__ import annotations
@@ -35,7 +36,6 @@ from bng_tpu.control.metrics import BNGMetrics
 from bng_tpu.control.nat import NATManager
 from bng_tpu.control.pool import Pool, PoolManager
 from bng_tpu.ops import express as ex
-from bng_tpu.ops import table as table_mod
 from bng_tpu.runtime.engine import Engine
 from bng_tpu.runtime.scheduler import SchedulerConfig, TieredScheduler
 from bng_tpu.runtime.tables import FastPathTables
@@ -198,32 +198,15 @@ GEOMETRIES = [
     dict(sub_nb=256, vlan_nb=64, cid_nb=128, batch=8),
 ]
 
-# each combo compiles the full _dhcp_jit comparison program (~10s on
-# CPU, ~20s under pallas): geometry 0 stays in the fast tier under the
-# default xla impl, the pallas column and the rest of the matrix ride
-# the `slow` mark — `make verify-express` runs the WHOLE express marker
-# (no slow deselect), so the 4-geometry x 2-impl identity claim stays
-# machine-checked on every verify (pallas end-to-end coverage stays in
-# tier-1 via test_pallas_table)
-_IDENTITY_COMBOS = [
-    pytest.param(gi, impl,
-                 marks=(() if gi == 0 and impl == "xla"
-                        else (pytest.mark.slow,)),
-                 id=f"{gi}-{impl}")
-    for gi in range(len(GEOMETRIES)) for impl in ("xla", "pallas")
-]
-
-
 class TestByteIdentity:
-    @pytest.mark.parametrize("gi,impl", _IDENTITY_COMBOS)
-    def test_express_matches_dhcp_jit(self, gi, impl, monkeypatch):
-        monkeypatch.setattr(table_mod, "TABLE_IMPL", impl)
+    # each geometry compiles the full _dhcp_jit comparison program
+    @pytest.mark.parametrize("gi", range(len(GEOMETRIES)))
+    def test_express_matches_dhcp_jit(self, gi):
         g = GEOMETRIES[gi]
         frames = case_frames()
         sched_aot = build_sched(build_fp(g["sub_nb"], g["vlan_nb"],
                                          g["cid_nb"]),
                                 g["batch"], express_aot=True)
-        assert sched_aot.engine.table_impl == impl
         out_aot = run_express(sched_aot, frames)
         sched_jit = build_sched(build_fp(g["sub_nb"], g["vlan_nb"],
                                          g["cid_nb"]),
@@ -239,8 +222,7 @@ class TestByteIdentity:
         snap = sched_aot.stats_snapshot()["express"]
         assert snap["aot_dispatches"] >= 1 and snap["aot_misses"] == 0
 
-    def test_expired_and_unknown_go_slow_on_both_paths(self, monkeypatch):
-        monkeypatch.setattr(table_mod, "TABLE_IMPL", "xla")
+    def test_expired_and_unknown_go_slow_on_both_paths(self):
         frames = [dhcp_frame(mac_of(9), dhcp_codec.DISCOVER),  # expired
                   dhcp_frame(mac_of(77), dhcp_codec.DISCOVER)]  # unknown
         for aot in (True, False):
@@ -253,7 +235,18 @@ class TestByteIdentity:
 # ---------------------------------------------------------------------------
 
 class TestCodecIdentity:
-    def test_express_reply_matches_codec_built(self):
+    # the request shapes a client or a relay sends for one binding; the
+    # first DISCOVER makes the lease, `shape` is the frame compared
+    @pytest.mark.parametrize("shape", [
+        dict(msg_type=dhcp_codec.DISCOVER),
+        dict(msg_type=dhcp_codec.DISCOVER, broadcast=True),
+        dict(msg_type=dhcp_codec.DISCOVER, giaddr=ip_to_u32("10.9.9.9")),
+        dict(msg_type=dhcp_codec.REQUEST),
+        dict(msg_type=dhcp_codec.REQUEST, broadcast=True),
+        dict(msg_type=dhcp_codec.REQUEST, giaddr=ip_to_u32("10.9.9.9")),
+    ], ids=["discover", "discover-bcast-flag", "discover-relayed",
+            "request", "request-bcast-flag", "request-relayed"])
+    def test_express_reply_matches_codec_built(self, shape):
         clock = FakeClock()
         fp = build_fp()
         pools = PoolManager(fp)
@@ -265,16 +258,114 @@ class TestCodecIdentity:
         server = DHCPServer(SERVER_MAC, SERVER_IP, pools,
                             fastpath_tables=fp, clock=clock)
         mac = mac_of(40)
-        frame = dhcp_frame(mac, dhcp_codec.DISCOVER)
+        offer = server.handle_frame(dhcp_frame(mac, dhcp_codec.DISCOVER))
+        assert offer is not None
+        yiaddr = dhcp_codec.decode(packets.decode(offer).payload).yiaddr
+        frame = dhcp_frame(mac, **shape)
         codec_reply = server.handle_frame(frame)
         assert codec_reply is not None
-        yiaddr = dhcp_codec.decode(packets.decode(codec_reply).payload).yiaddr
+        assert dhcp_codec.decode(
+            packets.decode(codec_reply).payload).yiaddr == yiaddr
         # install the same binding on the fast path; the express reply
         # must be byte-identical to the server's template-rendered frame
         fp.add_subscriber(mac, 1, yiaddr, NOW + 3600)
         sched = build_sched(fp, 8, express_aot=True, clock=clock)
         out = run_express(sched, [frame])
         assert out["tx"][0] == codec_reply
+
+
+# ---------------------------------------------------------------------------
+# the AOT lane over storms: what the codec reads back from every reply
+# ---------------------------------------------------------------------------
+
+# per frame of case_frames(): the message type and yiaddr a reply must
+# decode to, from the bindings build_fp() installs; pools 1-3 lease for
+# 3600, 7200 and 600 s
+CASE_REPLIES = [
+    (dhcp_codec.OFFER, "10.0.0.50", 3600),
+    (dhcp_codec.ACK, "10.1.0.60", 7200),
+    (dhcp_codec.OFFER, "10.2.0.70", 600),
+    (dhcp_codec.OFFER, "10.0.0.80", 3600),
+    (dhcp_codec.OFFER, "10.1.0.90", 7200),
+    (dhcp_codec.OFFER, "10.0.0.99", 3600),
+    (dhcp_codec.ACK, "10.0.0.50", 3600),
+    (dhcp_codec.ACK, "10.0.0.50", 3600),
+]
+
+
+def storm_frames(n: int) -> list[bytes]:
+    """n frames cycling the case matrix: several express batches plus a
+    partial one."""
+    base = case_frames()
+    return [base[i % len(base)] for i in range(n)]
+
+
+def assert_codec_reads_bindings(out: dict, frames: list[bytes]) -> None:
+    """Every frame answered on the device, and each reply decodes (by the
+    codec, nothing the device computed) to its case's binding."""
+    assert out["slow"] == [] and sorted(out["tx"]) == list(range(len(frames)))
+    for i, frame in enumerate(frames):
+        req = dhcp_codec.decode(packets.decode(frame).payload)
+        d = packets.decode(out["tx"][i])
+        rep = dhcp_codec.decode(d.payload)
+        mtype, yiaddr, lease = CASE_REPLIES[i % len(CASE_REPLIES)]
+        assert d.ip_checksum_ok, f"frame {i}"
+        assert (rep.op, rep.msg_type, rep.yiaddr) == (
+            2, mtype, ip_to_u32(yiaddr)), f"frame {i}"
+        assert (rep.xid, rep.chaddr[:6]) == (req.xid, req.chaddr[:6])
+        assert rep.server_id == SERVER_IP
+        assert rep.opt(dhcp_codec.OPT_LEASE_TIME) == lease.to_bytes(4, "big")
+
+
+class TestAotLaneStorms:
+    # (express_batch, full batches, sub_nb, vlan_nb, cid_nb)
+    @pytest.mark.parametrize("batch,k,sub_nb,vlan_nb,cid_nb", [
+        (8, 4, 256, 64, 64), (8, 2, 128, 32, 32), (4, 2, 64, 32, 32)])
+    def test_storm_replies_decode_to_bindings(self, batch, k, sub_nb,
+                                              vlan_nb, cid_nb):
+        """k full batches, one more, and a half one closed by the flush."""
+        frames = storm_frames(batch * k + batch + batch // 2)
+        sched = build_sched(build_fp(sub_nb, vlan_nb, cid_nb), batch,
+                            express_aot=True)
+        assert_codec_reads_bindings(run_express(sched, frames), frames)
+        snap = sched.stats_snapshot()["express"]
+        assert snap["aot_dispatches"] >= k + 2 and snap["aot_misses"] == 0
+
+    def test_multi_round_identity_and_lease_state(self):
+        """The chain threads batch to batch: later rounds see what the
+        earlier ones wrote, identically on the AOT lane and on
+        `_dhcp_jit`, and the codec reads the bindings every round."""
+        frames = storm_frames(32)
+        aot = build_sched(build_fp(), 8, express_aot=True)
+        jit = build_sched(build_fp(), 8, express_aot=False)
+        for _ in range(3):
+            got = run_express(aot, frames)
+            assert got == run_express(jit, frames)
+            assert_codec_reads_bindings(got, frames)
+
+    def test_two_fresh_stacks_are_byte_identical(self):
+        frames = storm_frames(8 * 5 + 5)
+
+        def sweep():
+            sched = build_sched(build_fp(), 8, express_aot=True)
+            out = [run_express(sched, frames) for _ in range(2)]
+            sched.quiesce(now=float(NOW))
+            return out, sched.stats_snapshot()["express"]
+
+        out_a, snap_a = sweep()
+        out_b, snap_b = sweep()
+        assert out_a == out_b
+        assert snap_a == snap_b
+        assert_codec_reads_bindings(out_a[1], frames)
+
+    def test_snapshot_surfaces_lane_stats(self):
+        sched = build_sched(build_fp(), 8, express_aot=True)
+        sched.process(storm_frames(32))
+        snap = sched.stats_snapshot()["express"]
+        assert snap["aot_enabled"] and snap["aot_dispatches"] >= 4
+        assert snap["jit_dispatches"] == 0 and snap["aot_misses"] == 0
+        assert snap["fallbacks"] == {}
+        assert snap["queue_depth"] == 0 and snap["inflight"] == 0
 
 
 # ---------------------------------------------------------------------------

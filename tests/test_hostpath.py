@@ -640,10 +640,9 @@ class TestChaosParity:
 
 
 # ---------------------------------------------------------------------------
-# scheduler end-to-end A/B (compile-heavy: slow tier)
+# scheduler end-to-end A/B
 # ---------------------------------------------------------------------------
 
-@pytest.mark.slow
 class TestSchedulerExpressAB:
     def test_express_replies_identical(self):
         from bng_tpu.control.nat import NATManager

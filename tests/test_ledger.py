@@ -441,8 +441,8 @@ class TestGateKeys:
         assert "stage:fleet" in [r["key"] for r in rep.regressions]
 
     def test_newest_gateable_index(self):
-        """bench.py --gate ties its verdict to THIS run by comparing
-        this index against the pre-run line count: an error-only or
+        """A caller ties its verdict to THIS run by comparing this
+        index against the pre-run line count: an error-only or
         append-less run must never earn a CLEAN verdict about stale
         history."""
         lines = [_tpu_line(0), _tpu_line(1),
@@ -580,3 +580,59 @@ class TestFingerprint:
         assert env["host"]
         # conftest initialized jax on cpu: device identity rides along
         assert env.get("platform") == "cpu"
+
+
+# ---------------------------------------------------------------------------
+# express_loop cohort identity of recorded lines
+# ---------------------------------------------------------------------------
+
+_STAGES = {"dispatch": 100.0, "device": 40.0, "total": 800.0}
+
+
+def _line(i: int, scale: float = 1.0) -> dict:
+    return {
+        "schema_version": 1, "run_id": f"dl{i:02d}",
+        "metric": "Mpps/chip DHCP+NAT44 fast path",
+        "value": 0.05 * scale, "unit": "Mpps",
+        "batch": 8192, "subscribers": 1_000_000, "flows": 1_000_000,
+        "device": "TPU v5e chip0",
+        "env": {"platform": "tpu", "device_kind": "TPU v5e"},
+        "stage_breakdown": {
+            s: {"count": 200, "p50_us": v / 2, "p99_us": v * (1 + 0.02 * i),
+                "p999_us": v * 1.2, "mean_us": v / 2, "max_us": v * 1.3}
+            for s, v in _STAGES.items()},
+    }
+
+
+class TestExpressLoopCohort:
+    def test_accessor_defaults_to_per_batch(self):
+        assert ledger.express_loop({}) == "per-batch"
+        assert ledger.express_loop({"express_loop": "devloop"}) == "devloop"
+
+    def test_devloop_never_scored_against_per_batch_history(self,
+                                                            tmp_path):
+        """The loop changes what a `dispatch` lap measures (one batch
+        vs an amortized ring share): rc=3 refusal, never a trend."""
+        path = str(tmp_path / "ledger.jsonl")
+        for i in range(5):
+            ledger.append(path, _line(i))  # unstamped -> per-batch
+        cand = _line(9, scale=5.0)  # would look like a huge move
+        cand["express_loop"] = "devloop"
+        ledger.append(path, cand)
+        rep = ledger.gate_file(path)
+        assert rep.rc == ledger.GATE_INCOMPARABLE
+        assert "devloop" in rep.notes[0]
+
+    def test_devloop_cohort_gates_within_itself(self, tmp_path):
+        path = str(tmp_path / "ledger.jsonl")
+        for i in range(5):
+            ledger.append(path, _line(i))
+        for i in range(4):  # devloop history: 2x the per-batch headline
+            ln = _line(20 + i, scale=2.0)
+            ln["express_loop"] = "devloop"
+            ledger.append(path, ln)
+        bad = _line(30, scale=1.1)  # regressed vs ITS cohort only
+        bad["express_loop"] = "devloop"
+        ledger.append(path, bad)
+        rep = ledger.gate_file(path)
+        assert rep.rc == ledger.GATE_REGRESSION, rep.to_dict()

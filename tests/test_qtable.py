@@ -225,3 +225,79 @@ class TestTimestampWrap:
         r2 = qos_kernel(ips, lens, active, st, qos.geom, t2)
         assert list(np.asarray(r2.allowed)) == [True, True, False, False], \
             np.asarray(r2.allowed)
+
+
+def ref_prefix_consumed(limited, slot, lens, avail):
+    """O(B^2) numpy reference of `ops/qos.py _prefix_consumed`: a lane
+    passes iff the bytes of its bucket's limited lanes up to and
+    including it fit the tokens; a dropped lane's bytes stay in the
+    prefix."""
+    B = len(slot)
+    allowed = np.ones((B,), dtype=bool)
+    is_head = np.zeros((B,), dtype=bool)
+    for i in range(B):
+        if not limited[i]:
+            continue
+        same = limited[: i + 1] & (slot[: i + 1] == slot[i])
+        allowed[i] = int(lens[: i + 1][same].sum()) <= int(avail[i])
+        is_head[i] = same.sum() == 1
+    consumed = np.zeros((B,), dtype=np.int64)
+    for i in range(B):
+        if limited[i]:
+            same = limited & (slot == slot[i])
+            consumed[i] = lens[same & allowed].sum()
+    return allowed, consumed, is_head
+
+
+class TestPrefixConsumed:
+    """`_prefix_consumed` (the same-bucket aggregation inside
+    `qos_kernel`) against the numpy reference."""
+
+    def _check(self, limited, slot, lens, avail):
+        from bng_tpu.ops.qos import _prefix_consumed
+
+        allowed, consumed, is_head = _prefix_consumed(
+            jnp.asarray(limited), jnp.asarray(slot), jnp.asarray(lens),
+            jnp.asarray(avail.astype(np.float32)))
+        ref_a, ref_c, ref_h = ref_prefix_consumed(limited, slot, lens, avail)
+        np.testing.assert_array_equal(np.asarray(allowed), ref_a)
+        np.testing.assert_array_equal(np.asarray(is_head), ref_h)
+        np.testing.assert_array_equal(
+            np.asarray(consumed)[limited].astype(np.int64), ref_c[limited])
+
+    @pytest.mark.parametrize("B", [64, 256, 768, 1000])
+    def test_matches_reference(self, B):
+        rng = np.random.default_rng(B)
+        nb = max(2, B // 8)
+        slot = rng.integers(0, nb, size=B).astype(np.int32)
+        lens = rng.integers(64, 1500, size=B).astype(np.uint32)
+        # tokens a bucket: some buckets admit everything, some cut mid-batch
+        avail = rng.integers(0, 12_000, size=nb)[slot].astype(np.uint32)
+        self._check(np.ones((B,), dtype=bool), slot, lens, avail)
+
+    def test_unlimited_lanes_never_group(self):
+        """A lane without a limit shares no prefix with anybody, whatever
+        its slot number says: it passes, heads nothing, and adds nothing
+        to the limited lanes of the same slot."""
+        B = 128
+        rng = np.random.default_rng(7)
+        slot = rng.integers(0, 4, size=B).astype(np.int32)
+        limited = rng.random(B) < 0.5
+        lens = np.full((B,), 100, dtype=np.uint32)
+        avail = np.full((B,), 1_000, dtype=np.uint32)
+        self._check(limited, slot, lens, avail)
+
+    def test_sequential_order_within_bucket(self):
+        # one bucket, tokens for exactly 2 packets: lanes 0,1 pass, 2+ drop
+        from bng_tpu.ops.qos import qos_kernel
+        from bng_tpu.runtime.engine import QoSTables
+
+        qos = QoSTables(nbuckets=64)
+        qos.set_subscriber(0x0A000002, down_bps=8_000, up_bps=8_000,
+                           up_burst=2000, down_burst=2000)
+        ips = np.full((8,), 0x0A000002, dtype=np.uint32)
+        lens = np.full((8,), 1000, dtype=np.uint32)
+        res = qos_kernel(jnp.asarray(ips), jnp.asarray(lens),
+                         jnp.ones((8,), dtype=bool),
+                         qos.up.device_state(), qos.geom, jnp.uint32(1))
+        assert list(np.asarray(res.allowed)) == [True, True] + [False] * 6

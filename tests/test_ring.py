@@ -568,7 +568,6 @@ class TestDHCPClassify:
             assert classify_dhcp(f) == (fl[i] & FLAG_DHCP_CTRL)
         ring.complete(np.zeros((n,), dtype=np.uint8), pkt, ln, n)
 
-    @pytest.mark.slow  # compile-heavy; tier-1 runs -m 'not slow'
     def test_all_control_batch_takes_fast_lane(self, ring_cls):
         ring = ring_cls(nframes=64, frame_size=1024, depth=32)
         eng_test = TestRingEngine()

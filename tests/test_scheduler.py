@@ -192,9 +192,6 @@ class TestDeadlineClose:
 
 
 @pytest.mark.hotpath
-@pytest.mark.slow  # compile-heavy pair (~38s: bulk_depth in-flight
-# traces); still runs armed under make verify-sanitize ('hotpath or
-# analysis or race' has no slow filter) and in verify-slow
 class TestExpressNeverBehindBulk:
     def test_express_completes_while_bulk_in_flight(self):
         engine, _, clock = build_stack(batch_size=8)

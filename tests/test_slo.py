@@ -62,8 +62,8 @@ class TestSpec:
         for _ in range(64):
             tr.observe(tele.DEVICE, 236_000.0, bulk)
             tr.observe(tele.DEVICE, 30.0, ex)
-        # bench.py's profiler-fenced samples are another quantity and go
-        # to another lane: they neither dilute nor breach the served path's
+        # samples fed to another lane neither dilute nor breach the
+        # served path's
         tr.observe_many(tele.DEVICE, [829.0] * 64, lane=tele.LANE_BENCH)
         assert tr.lane_hist(tele.LANE_EXPRESS_L, tele.DEVICE).n == 64
         assert slo.evaluate(tr.breakdown(lanes=True), (dev,))["ok"]

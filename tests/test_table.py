@@ -5,6 +5,8 @@ TPU analog of the reference's Go<->eBPF struct layout tests
 on layout and hashing bit-for-bit, or table data is silently corrupted.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -161,3 +163,150 @@ class TestDeviceLookup:
         res = f(state, make_queries([[7, 8]], 2))
         assert bool(res.found[0])
         assert np.asarray(res.vals)[0].tolist() == [70, 80]
+
+
+def build_table(nbuckets, K, V, stash, n_entries, seed):
+    rng = np.random.default_rng(seed)
+    t = HostTable(nbuckets, K, V, stash=stash, name="t")
+    keys = rng.integers(0, 2**32, size=(n_entries, K), dtype=np.uint32)
+    keys = np.unique(keys, axis=0)
+    vals = rng.integers(0, 2**32, size=(len(keys), V), dtype=np.uint32)
+    for i in range(len(keys)):
+        t.insert(keys[i], vals[i])
+    return t, keys
+
+
+def query_mix(keys, K, B, seed, miss_frac=0.3):
+    """Hits + misses + in-batch duplicates."""
+    rng = np.random.default_rng(seed + 1)
+    if len(keys):
+        q = keys[rng.integers(0, len(keys), B)].copy()
+    else:
+        q = np.zeros((B, K), np.uint32)
+    miss = rng.random(B) < miss_frac
+    q[miss] = rng.integers(0, 2**32, size=(int(miss.sum()), K),
+                           dtype=np.uint32)
+    return q
+
+
+# every table geometry the repo ships, plus the edge shapes:
+#   (nbuckets, K, V, stash, n_entries, B)
+GEOMETRIES = [
+    pytest.param(1 << 8, 2, 8, 64, 200, 256, id="dhcp-sub"),
+    pytest.param(1 << 6, 1, 8, 64, 100, 64, id="vlan-small-batch"),
+    pytest.param(1 << 6, 8, 8, 64, 100, 300, id="cid-k8-kw16"),
+    pytest.param(1 << 8, 4, 16, 64, 300, 512, id="nat-sessions-v16"),
+    pytest.param(1 << 8, 4, 8, 64, 300, 512, id="nat-reverse-v8"),
+    pytest.param(1 << 3, 2, 8, 32, 38, 128, id="overfull-stash-hits"),
+    pytest.param(1 << 8, 2, 8, 0, 100, 128, id="no-stash"),
+    pytest.param(1 << 6, 2, 8, 64, 0, 128, id="empty-table"),
+    # the 1M-subscriber sub-table geometry (K=2, V=8, stash=256) at
+    # reduced nbuckets — same shapes/dtypes, CI-sized population
+    pytest.param(1 << 12, 2, 8, 256, 6000, 1024, id="1m-geometry-reduced"),
+    pytest.param(1 << 6, 1, 8, 64, 120, 96, id="antispoof-garden-k1"),
+    pytest.param(1 << 7, 1, 8, 64, 300, 256, id="sub-nat-k1-loaded"),
+    pytest.param(1 << 6, 3, 8, 16, 150, 200, id="k3-odd-key"),
+    pytest.param(1 << 4, 4, 16, 64, 100, 64, id="sessions-overfull-stash"),
+    pytest.param(1 << 8, 2, 8, 64, 400, 4096, id="batch-4096"),
+    pytest.param(1 << 2, 2, 8, 8, 20, 33, id="four-buckets-odd-batch"),
+]
+
+
+class TestDeviceLookupGeometries:
+    @pytest.mark.parametrize("nbuckets,K,V,stash,n,B", GEOMETRIES)
+    def test_device_lookup_equals_host(self, nbuckets, K, V, stash, n, B):
+        t, keys = build_table(nbuckets, K, V, stash, n, seed=nbuckets + K)
+        q = query_mix(keys, K, B, seed=nbuckets)
+        got = device_lookup(t.device_state(), jnp.asarray(q), nbuckets, stash)
+        found = np.asarray(got.found)
+        assert np.array_equal(
+            np.where(found[:, None], np.asarray(got.vals), 0),
+            t.lookup_batch_host(q))
+        assert np.array_equal(
+            found, np.array([t.lookup(k) is not None for k in q]))
+
+    def test_stash_geometry_actually_exercises_stash(self):
+        """The overfull geometry must place entries in the stash, or the
+        stash-broadcast path of the probe is untested."""
+        t, _ = build_table(1 << 3, 2, 8, 32, 38, seed=10)
+        assert int(np.count_nonzero(
+            np.asarray(t.device_state().stash_rows)[:, 2])) > 0
+
+    def test_nonaligned_batch_padding(self):
+        """B not a multiple of 128, below it and straddling it."""
+        t, keys = build_table(1 << 6, 2, 8, 64, 80, seed=3)
+        state = t.device_state()
+        for B in (7, 129):
+            q = query_mix(keys, 2, B, seed=B)
+            got = device_lookup(state, jnp.asarray(q), t.nbuckets, t.stash)
+            found = np.asarray(got.found)
+            assert np.array_equal(
+                np.where(found[:, None], np.asarray(got.vals), 0),
+                t.lookup_batch_host(q)), B
+
+    def test_probe_keeps_wide_row_shape(self):
+        """The cascade probes via 2 packed [1,32] row gathers (the
+        test_hlo_structure contract, pinned at the probe alone so a
+        regression is attributable)."""
+        t, keys = build_table(1 << 10, 2, 8, 64, 500, seed=6)
+
+        def look(state, q):
+            r = device_lookup(state, q, t.nbuckets, t.stash)
+            return r.found, r.slot, r.vals
+
+        hlo = jax.jit(look).lower(t.device_state(),
+                                  jnp.asarray(keys[:256])).as_text()
+        assert len(re.findall(r"slice_sizes = array<i64: 1, 32>", hlo)) == 2
+
+    def test_slot_values_match_host_placement(self):
+        """slot indices agree with the host mirror's physical placement
+        (the device-authoritative writers — NAT accounting — scatter by
+        these slots, so they must be placement-exact, not just
+        found-consistent)."""
+        t, keys = build_table(1 << 5, 2, 8, 16, 100, seed=8)
+        got = device_lookup(t.device_state(), jnp.asarray(keys[:64]),
+                            t.nbuckets, t.stash)
+        assert np.asarray(got.found).all()
+        assert [int(s) for s in np.asarray(got.slot)] == [
+            t._find_slot(k) for k in keys[:64]]
+
+
+class TestWidenedRowCheckpointCompat:
+    """The row widenings (nat reverse 4->8, pppoe 6->8) must not
+    cold-start pre-upgrade checkpoints: a declared pure-pad historical
+    width restores with the value rows zero-padded; anything undeclared
+    still rejects (reject-on-mismatch is the default)."""
+
+    def test_narrow_checkpoint_pads_into_widened_table(self):
+        old = HostTable(1 << 5, 4, 4, stash=8, name="nat_reverse")
+        key = np.arange(4, dtype=np.uint32)
+        old.insert(key, np.asarray([9, 8, 7, 6], dtype=np.uint32))
+        arrays = {k: v.copy() for k, v in old.checkpoint_arrays().items()}
+        geom = old.checkpoint_geom()
+
+        new = HostTable(1 << 5, 4, 8, stash=8, name="nat_reverse",
+                        compat_val_pad_from=(4,))
+        assert new.restore_arrays(arrays, geom) == 1
+        got = new.lookup(key)
+        assert got is not None
+        assert list(got) == [9, 8, 7, 6, 0, 0, 0, 0]
+
+    def test_undeclared_width_still_rejects(self):
+        old = HostTable(1 << 5, 4, 4, stash=8, name="t")
+        arrays = old.checkpoint_arrays()
+        geom = old.checkpoint_geom()
+        new = HostTable(1 << 5, 4, 8, stash=8, name="t")  # no compat decl
+        with pytest.raises(ValueError):
+            new.restore_arrays(arrays, geom)
+
+    def test_live_nat_and_pppoe_tables_declare_compat(self):
+        from bng_tpu.control.nat import NATManager
+        from bng_tpu.runtime.tables import PPPoEFastPathTables
+        from bng_tpu.utils.net import ip_to_u32
+
+        nat = NATManager(public_ips=[ip_to_u32("203.0.113.1")],
+                         sessions_nbuckets=1 << 8, sub_nat_nbuckets=1 << 8)
+        assert nat.reverse.compat_val_pad_from == (4,)
+        pp = PPPoEFastPathTables(nbuckets=1 << 8)
+        assert pp.by_sid.compat_val_pad_from == (6,)
+        assert pp.by_ip.compat_val_pad_from == (6,)

@@ -1,11 +1,12 @@
 """Telemetry subsystem tests (bng_tpu/telemetry): disarmed-overhead
 bound, histogram merge laws, flight-recorder wrap + anomaly triggers
 (incl. forced backend fallback), Chrome-trace export schema, and DORA
-through tracing — host-only through the fleet in the fast tier, full
-engine + scheduler + fleet under @pytest.mark.slow.
+through tracing — host-only through the fleet, and the full engine +
+scheduler + fleet.
 
-`make verify-telemetry` runs the 'telemetry and not slow' set with
-BNG_TELEMETRY=1 in the environment (< 30 s — no XLA compiles there).
+`make verify-telemetry` runs the 'telemetry and not slow' set less
+TestDoraTracingE2E with BNG_TELEMETRY=1 in the environment (< 30 s — no
+XLA compiles there).
 """
 
 from __future__ import annotations
@@ -363,37 +364,6 @@ class TestMetricsExport:
 
 
 # ---------------------------------------------------------------------------
-# profiling percentile (satellite fix)
-# ---------------------------------------------------------------------------
-
-class TestStepDurationsPercentile:
-    def test_matches_numpy_percentile_property(self):
-        """Property test pinning the sort-once interpolating percentile
-        to numpy.percentile's default (linear) method."""
-        from bng_tpu.utils.profiling import StepDurations
-
-        rng = np.random.default_rng(11)
-        for size in (1, 2, 3, 7, 50, 501):
-            vals = rng.lognormal(2, 1.3, size).tolist()
-            sd = StepDurations(us=vals, source="device")
-            for q in (0.0, 10.0, 25.0, 50.0, 90.0, 99.0, 99.9, 100.0):
-                assert sd.percentile(q) == pytest.approx(
-                    float(np.percentile(np.asarray(vals), q)),
-                    rel=1e-12, abs=1e-12), (size, q)
-
-    def test_sort_cache_and_empty(self):
-        from bng_tpu.utils.profiling import StepDurations
-
-        sd = StepDurations(us=[], source="none")
-        assert sd.percentile(99) == 0.0
-        sd2 = StepDurations(us=[3.0, 1.0, 2.0], source="device")
-        assert sd2.percentile(50) == 2.0
-        assert sd2.percentile(50) == 2.0  # cached-sort path
-        with pytest.raises(ValueError):
-            sd2.percentile(101.0)
-
-
-# ---------------------------------------------------------------------------
 # full engine + scheduler + fleet e2e (XLA compiles: slow tier)
 # ---------------------------------------------------------------------------
 
@@ -455,7 +425,6 @@ def _dora_frames():
     return discover, request
 
 
-@pytest.mark.slow
 class TestDoraTracingE2E:
     def test_dora_through_scheduler_and_fleet(self, tmp_path):
         """The tentpole e2e: DORA for 32 subscribers through the tiered
