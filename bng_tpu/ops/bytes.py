@@ -8,13 +8,24 @@ verifier-safe fixed-offset parsing style (bpf/dhcp_fastpath.c:216-250).
 Offsets may be per-lane (`[B]` int32) because VLAN tagging shifts L3 by
 0/4/8 bytes per packet (bpf/dhcp_fastpath.c:352-428).
 
-What the reads cost: a gather moves ONE byte an index, so `bytes_at` is
-for fields (a MAC, an xid, a 32-byte circuit-ID), never for moving a
-packet. Measured on a v5e (PERF.md sections 5-6, PR 25-26): a
+What the reads cost, and which form to use. A per-lane offset makes a
+read a gather, and a gather moves ONE byte an index whatever it holds:
+measured on a v5e (PERF.md section 6, PR 25, 26, 31), a
 `take_along_axis` over a [8192, 1536] slot ran at 1.0 GB/s of 819 (over
-130 ms), one over [8192, 32] takes 2.7 ms. Where a shift takes a few
-static values, build the shifted copies with pad/slice and select per
-lane (ops/dhcp.py's reply compose).
+130 ms) and one over [8192, 32] takes 2.8-2.9 ms, paid by every lane of the
+batch. So:
+
+- a field at a STATIC column is a slice: pass the readers a Python int
+  (`u8_at(pkt, 6)`, `bytes_at(win, 28, 16)`), and nothing is gathered;
+- a field at a base that takes a FEW static values (the DHCP header
+  behind 0/1/2 tags and an IHL of 5..15: 13 bases) is a static slice of
+  `window_at(pkt, base, bases, n)`, the select among the statically
+  sliced copies of the slot, built once for all such fields;
+- a shift by a few static amounts is the same select over pad/slice
+  copies (ops/dhcp.py's reply compose, `_placed_at`);
+- `u8_at` .. `bytes_at` with a per-lane offset are for a base that is
+  truly free (antispoof's IPv6 source behind extension headers), and
+  for fields, never for moving a packet.
 """
 
 from __future__ import annotations
@@ -29,7 +40,10 @@ def _off(offs):
 
 
 def u8_at(pkt, offs):
-    """Gather one byte per lane at per-lane offsets -> [B] uint32."""
+    """One byte per lane -> [B] uint32: a gather at per-lane offsets, a
+    slice at a static (Python int) column."""
+    if isinstance(offs, int):
+        return pkt[:, offs].astype(jnp.uint32)
     idx = jnp.clip(_off(offs), 0, pkt.shape[1] - 1)
     return jnp.take_along_axis(pkt, idx[:, None], axis=1)[:, 0].astype(jnp.uint32)
 
@@ -43,10 +57,29 @@ def be32_at(pkt, offs):
 
 
 def bytes_at(pkt, offs, n: int):
-    """Gather n consecutive bytes per lane -> [B, n] uint8 (n static)."""
+    """n consecutive bytes per lane -> [B, n] uint8 (n static): a gather
+    at per-lane offsets, a slice at a static (Python int) column."""
+    if isinstance(offs, int):
+        return pkt[:, offs:offs + n]
     idx = _off(offs)[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
     idx = jnp.clip(idx, 0, pkt.shape[1] - 1)
     return jnp.take_along_axis(pkt, idx, axis=1)
+
+
+def window_at(pkt, offs, bases: tuple[int, ...], n: int):
+    """`bytes_at(pkt, offs, n)` for a per-lane `offs` [B] that takes one of
+    the static `bases`: a select among the statically sliced copies of the
+    slot, one pass each, where the gather moves one byte an index. Columns
+    past the slot's end read its last byte, as `bytes_at`'s clip does. A
+    lane at none of the bases reads at the last one: its caller masks it."""
+    offs = _off(offs)
+    short = max(bases) + n - pkt.shape[1]
+    if short > 0:
+        pkt = jnp.pad(pkt, ((0, 0), (0, short)), mode="edge")
+    out = pkt[:, bases[-1]:bases[-1] + n]
+    for b in reversed(bases[:-1]):
+        out = jnp.where((offs == b)[:, None], pkt[:, b:b + n], out)
+    return out
 
 
 # Per-lane writes are SELECTS, not scatters: a scatter with per-lane

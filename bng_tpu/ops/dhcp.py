@@ -11,6 +11,19 @@ among statically shifted copies at the reply's own width, never a per-byte
 gather over the slot: one over [8192, 1536] ran at 1.0 GB/s of a v5e's 819
 (ops/bytes.py's header; PERF.md section 6, PR 26).
 
+The request is read the same way. The BOOTP header starts at `dhcp_off` =
+14 + tags (0/4/8) + IHL (20..60) + 8, one of 13 static columns
+(`REQ_BASES`), and everything the kernel reads of it lies in the `REQ_WIN`
+bytes from there: the header fields, the magic, and the 64-byte option
+window of both scans. `B_.window_at` builds that window once a step and
+every field, the nine circuit-ID candidates among them, is a static slice
+of it. Alone on a v5e at [8192, 1536] (PERF.md section 6, PR 31): the
+circuit-ID scan as nine [B, 32] gathers 26.4 ms, over the window 0.38 ms
+(one [B, 64] gather and slices of it 5.7, one dynamic slice a lane 10.4);
+parse_batch + this kernel 36.1 ms before, 4.8 ms since. A new request
+field is one more slice of `req`; `B_.bytes_at` with a per-lane offset has
+no place here.
+
 Parity notes (cited against /root/reference):
 - msg-type extraction at fixed offsets {0,1,3,4,5,6}: dhcp_fastpath.c:216-250
 - circuit-ID extraction at fixed positions {3, 12..19}: dhcp_fastpath.c:267-323
@@ -69,6 +82,14 @@ _OPT_TAIL = 13  # 58(6) + 59(6) + 255(1)
 _OPT_MAX = _OPT_HEAD + _OPT_DNS_MAX + _OPT_TAIL
 CANON_LEN = _ETH + _IP + _UDP + _BOOTP + _OPT_MAX  # 332
 
+# request geometry: the columns `dhcp_off` takes on a lane parse_batch calls
+# IPv4/UDP (no tag, 802.1Q, QinQ; IHL 5..15), 42..90 by 4, and the bytes
+# read from there: BOOTP header + the option scans' window (c:276)
+_OPT_SCAN = 64
+REQ_BASES = tuple(sorted({_ETH + tags + ihl + _UDP
+                          for tags in (0, 4, 8) for ihl in range(20, 64, 4)}))
+REQ_WIN = _BOOTP + _OPT_SCAN  # 304
+
 
 class DHCPTables(NamedTuple):
     """Device-side state for the DHCP fast path (pytree)."""
@@ -96,53 +117,56 @@ class DHCPResult(NamedTuple):
     stats: jax.Array  # [NSTATS] uint32 batch deltas
 
 
-def _extract_msg_type(pkt, opts_off, opts_in_bounds):
-    """Fixed-offset option-53 scan. Parity: get_dhcp_msg_type."""
+def _extract_msg_type(opts, opts_in_bounds):
+    """Fixed-offset option-53 scan over the option window `opts` [B, 64].
+    Parity: get_dhcp_msg_type."""
     found = jnp.zeros_like(opts_in_bounds)
-    mtype = jnp.zeros(pkt.shape[0], dtype=jnp.uint32)
+    mtype = jnp.zeros(opts.shape[0], dtype=jnp.uint32)
     for o in (0, 1, 3, 4, 5, 6):  # same offsets, same order as the reference
-        ok = (B_.u8_at(pkt, opts_off + o) == 53) & (B_.u8_at(pkt, opts_off + o + 1) == 1)
+        ok = (B_.u8_at(opts, o) == 53) & (B_.u8_at(opts, o + 1) == 1)
         take = ok & ~found & opts_in_bounds
-        mtype = jnp.where(take, B_.u8_at(pkt, opts_off + o + 2), mtype)
+        mtype = jnp.where(take, B_.u8_at(opts, o + 2), mtype)
         found = found | take
     return jnp.where(opts_in_bounds, mtype, 0)
 
 
-def _extract_circuit_id(pkt, opts_off, length):
-    """Fixed-position Option-82 circuit-ID extraction.
+def _extract_circuit_id(opts, opts_off, length):
+    """Fixed-position Option-82 circuit-ID extraction from the option
+    window `opts` [B, 64], which starts at column `opts_off` of a frame of
+    `length` bytes.
 
     Parity: extract_circuit_id_fixed (dhcp_fastpath.c:267-323).
     Returns (found [B] bool, cid [B, 32] uint8 zero-padded).
     """
-    Bsz = pkt.shape[0]
-    scan_ok = (opts_off.astype(jnp.uint32) + 64) <= length
+    Bsz = opts.shape[0]
+    opts_off = opts_off.astype(jnp.uint32)
+    scan_ok = (opts_off + _OPT_SCAN) <= length
 
     found = jnp.zeros((Bsz,), dtype=bool)
     cid = jnp.zeros((Bsz, CID_KEY_LEN), dtype=jnp.uint8)
 
     def try_pos(found, cid, tag_off, len_off, sub_off, cidlen_off, cid_off, extra_ok):
-        tag = B_.u8_at(pkt, opts_off + tag_off)
-        o82len = B_.u8_at(pkt, opts_off + len_off)
-        sub1 = B_.u8_at(pkt, opts_off + sub_off)
-        cl = B_.u8_at(pkt, opts_off + cidlen_off)
-        in_b = (opts_off.astype(jnp.uint32) + cid_off + cl) <= length
+        tag = B_.u8_at(opts, tag_off)
+        o82len = B_.u8_at(opts, len_off)
+        sub1 = B_.u8_at(opts, sub_off)
+        cl = B_.u8_at(opts, cidlen_off)
+        in_b = (opts_off + cid_off + cl) <= length
         ok = (
             scan_ok & extra_ok & (tag == 82) & (o82len >= 4) & (sub1 == 1)
             & (cl > 0) & (cl <= CID_KEY_LEN) & in_b & ~found
         )
-        raw = B_.bytes_at(pkt, opts_off + cid_off, CID_KEY_LEN)  # [B, 32]
+        raw = B_.bytes_at(opts, cid_off, CID_KEY_LEN)  # [B, 32]
         mask = jnp.arange(CID_KEY_LEN)[None, :] < cl[:, None]
         cand = jnp.where(mask, raw, 0)
         cid = jnp.where(ok[:, None], cand, cid)
         return found | ok, cid
 
     # Position A: [53][1][x][82][len][sub=1][cl][cid...] (tag at opts+3)
-    o82len_a = B_.u8_at(pkt, opts_off + 4)
-    a_extra = (opts_off.astype(jnp.uint32) + 5 + o82len_a) <= length
+    a_extra = (opts_off + 5 + B_.u8_at(opts, 4)) <= length
     found, cid = try_pos(found, cid, 3, 4, 5, 6, 7, a_extra)
     # Positions 12..19
     for p in range(12, 20):
-        p_extra = (opts_off.astype(jnp.uint32) + p + 8) <= length
+        p_extra = (opts_off + p + 8) <= length
         found, cid = try_pos(found, cid, p, p + 1, p + 2, p + 3, p + 4, p_extra)
     return found, cid
 
@@ -202,8 +226,12 @@ def dhcp_fastpath(
     is_dhcp_port = parsed.is_udp & (parsed.dst_port == DHCP_SERVER_PORT)
     hdr_in_bounds = (dhcp_off.astype(jnp.uint32) + _BOOTP) <= length
     base = is_dhcp_port & hdr_in_bounds
-    op = B_.u8_at(pkt, dhcp_off)
-    magic = B_.be32_at(pkt, dhcp_off + 236)
+    # every request byte read below is a static slice of this window; a
+    # lane at none of REQ_BASES is not IPv4/UDP, so `base` masks it
+    req = B_.window_at(pkt, dhcp_off, REQ_BASES, REQ_WIN)
+    opts = req[:, _BOOTP:]
+    op = B_.u8_at(req, 0)
+    magic = B_.be32_at(req, 236)
     base = base & (op == BOOTREQUEST) & (magic == DHCP_MAGIC)
 
     # vlan_packets counts every tagged frame the hook sees, not just DHCP
@@ -215,7 +243,7 @@ def dhcp_fastpath(
     # --- message type (parity :639-645) ---
     opts_off = dhcp_off + 240
     opts_in_bounds = (opts_off.astype(jnp.uint32) + 12) <= length
-    mtype = _extract_msg_type(pkt, opts_off, opts_in_bounds & base)
+    mtype = _extract_msg_type(opts, opts_in_bounds & base)
     is_fast_type = (mtype == DISCOVER) | (mtype == REQUEST)
     wrong_type = base & ~is_fast_type
     stats = stats.at[ST_MISS].add(count(wrong_type))
@@ -228,13 +256,13 @@ def dhcp_fastpath(
     vlan_hit = vlan_res.found & parsed.is_vlan & elig
 
     # 2) circuit-ID
-    cid_found, cid_bytes = _extract_circuit_id(pkt, opts_off, length)
+    cid_found, cid_bytes = _extract_circuit_id(opts, opts_off, length)
     cid_res = lookup(tables.cid, pack_cid_words(cid_bytes), geom.cid)
     cid_hit = cid_res.found & cid_found & elig & ~vlan_hit
 
     # 3) MAC (chaddr at dhcp_off+28)
-    mac_hi = B_.be16_at(pkt, dhcp_off + 28)
-    mac_lo = B_.be32_at(pkt, dhcp_off + 30)
+    mac_hi = B_.be16_at(req, 28)
+    mac_lo = B_.be32_at(req, 30)
     mac_key = jnp.stack([mac_hi, mac_lo], axis=1)
     mac_res = lookup(tables.sub, mac_key, geom.sub)
     mac_hit = mac_res.found & elig & ~vlan_hit & ~cid_hit
@@ -274,13 +302,13 @@ def dhcp_fastpath(
 
     reply_type = jnp.where(mtype == DISCOVER, OFFER, ACK)
 
-    xid_b = B_.bytes_at(pkt, dhcp_off + 4, 4)
-    secs_b = B_.bytes_at(pkt, dhcp_off + 8, 2)
-    flags = B_.be16_at(pkt, dhcp_off + 10)
-    ciaddr = B_.be32_at(pkt, dhcp_off + 12)
-    giaddr = B_.be32_at(pkt, dhcp_off + 24)
-    chaddr_b = B_.bytes_at(pkt, dhcp_off + 28, 16)
-    giaddr_b = B_.bytes_at(pkt, dhcp_off + 24, 4)
+    xid_b = B_.bytes_at(req, 4, 4)
+    secs_b = B_.bytes_at(req, 8, 2)
+    flags = B_.be16_at(req, 10)
+    ciaddr = B_.be32_at(req, 12)
+    giaddr = B_.be32_at(req, 24)
+    chaddr_b = B_.bytes_at(req, 28, 16)
+    giaddr_b = B_.bytes_at(req, 24, 4)
 
     relayed = giaddr != 0
     # broadcast decision (parity: setup_reply_l2_headers :436-462 — every
@@ -291,7 +319,7 @@ def dhcp_fastpath(
     stats = stats.at[ST_UCAST].add(count(reply & ~use_bcast))  # covers relay :743
 
     # L2 dest: relay -> requester's src MAC; bcast -> ff:..; else chaddr
-    req_src = B_.bytes_at(pkt, jnp.zeros_like(dhcp_off) + 6, 6)
+    req_src = B_.bytes_at(pkt, 6, 6)
     bcast_mac = jnp.full((Bsz, 6), 0xFF, dtype=jnp.uint8)
     dst_mac = jnp.where(
         relayed[:, None], req_src, jnp.where(use_bcast[:, None], bcast_mac, chaddr_b[:, :6])
