@@ -819,7 +819,12 @@ class BNGApp:
         if cfg.pppoe_enabled and cfg.shards <= 1:
             from bng_tpu.runtime.tables import PPPoEFastPathTables
 
+            from bng_tpu.control.pppoe.server import PPPoEServerConfig
+
+            # sized for the access concentrator's whole session space
+            # (16-bit ids: the server below takes the same ceiling)
             pppoe_tables = c["pppoe_tables"] = PPPoEFastPathTables(
+                **_sized(PPPoEServerConfig.max_sessions, "nbuckets"),
                 server_mac=parse_mac(cfg.server_mac))
         if cfg.shards > 1:
             # the cluster IS the dataplane: drive_once feeds its steered
@@ -2051,9 +2056,13 @@ class BNGApp:
         # no wire to write to.
         pppoe = c.get("pppoe")
         if pppoe is not None:
+            from bng_tpu.telemetry import spans as tele
+
+            t0 = tele.t()  # a walk over every session: a beat waits for it
             for frame in pppoe.tick(now):
                 if ring is not None:
                     ring.tx_inject(frame, from_access=True)
+            tele.lap(tele.SLOW, t0)
         slaac = c.get("slaac")
         if slaac is not None:
             for frame in slaac.tick(now):

@@ -78,15 +78,13 @@ def eth_vlan(pkt: jax.Array) -> tuple[jax.Array, jax.Array]:
     """VLAN peel only: per-lane (vlan_offset, inner ethertype).
 
     The PPPoE decap pre-stage needs just these two fields BEFORE the full
-    parse (which must see the decapped bytes) — 4 halfword reads instead
-    of the whole Parsed gather set."""
-    B = pkt.shape[0]
-    zero32 = jnp.zeros((B,), dtype=jnp.int32)
-    et0 = be16_at(pkt, zero32 + 12)
+    parse (which must see the decapped bytes): three halfwords at static
+    columns, so three slices and no gather."""
+    et0 = be16_at(pkt, 12)
     outer_tagged = (et0 == ETH_P_8021Q) | (et0 == ETH_P_8021AD)
-    et1 = be16_at(pkt, zero32 + 16)
+    et1 = be16_at(pkt, 16)
     inner_tagged = outer_tagged & (et1 == ETH_P_8021Q)
-    et2 = be16_at(pkt, zero32 + 20)
+    et2 = be16_at(pkt, 20)
     vlan_offset = jnp.where(inner_tagged, 8,
                             jnp.where(outer_tagged, 4, 0)).astype(jnp.int32)
     ethertype = jnp.where(inner_tagged, et2, jnp.where(outer_tagged, et1, et0))

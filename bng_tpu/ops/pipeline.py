@@ -143,15 +143,15 @@ def pipeline_step(
         from bng_tpu.ops.parse import eth_vlan
         from bng_tpu.ops.pppoe import pppoe_decap
 
-        vo, et = eth_vlan(pkt)
-        # access-side only: a session ethertype arriving from the core is
-        # foreign traffic — leave it untouched (PASS, host decides)
-        et_gated = jnp.where(from_access, et, 0)
         with jax.named_scope("pppoe"):
+            vo, et = eth_vlan(pkt)
+            # access-side only: a session ethertype arriving from the core
+            # is foreign traffic — leave it untouched (PASS, host decides)
+            et_gated = jnp.where(from_access, et, 0)
             pppoe_dec = pppoe_decap(pkt, length, vo, et_gated,
                                     tables.pppoe_by_sid, geom.pppoe)
-        pkt = jnp.where(pppoe_dec.done[:, None], pppoe_dec.out_pkt, pkt)
-        length = jnp.where(pppoe_dec.done, pppoe_dec.out_len, length)
+            pkt = jnp.where(pppoe_dec.done[:, None], pppoe_dec.out_pkt, pkt)
+            length = jnp.where(pppoe_dec.done, pppoe_dec.out_len, length)
 
     with jax.named_scope("parse"):
         parsed = parse_batch(pkt, length)

@@ -18,8 +18,10 @@ Frame layouts:
 
 Validation on decap mirrors pppoe_session dispatch (server.go:466-499):
 ver/type 0x11, code 0, session id found in the session table and bound
-to the same MAC. Byte movement is index arithmetic (one gather), not
-per-lane scatters.
+to the same MAC. Byte movement is a select among statically shifted
+copies of the slot and every header read a static slice (the framing
+sits behind 0, 1 or 2 VLAN tags: three bases), never a per-lane gather
+or scatter over the slot's width.
 """
 
 from __future__ import annotations
@@ -38,6 +40,7 @@ ETH_PPPOE_DISC = 0x8863
 PPP_IPV4 = 0x0021
 PPP_IPV6 = 0x0057
 PPPOE_HDR = 8  # 6B PPPoE header + 2B PPP protocol
+HDR_BASES = (14, 18, 22)  # where it starts behind 0 / 1 / 2 VLAN tags
 
 # session table value words (device mirror of control.pppoe.PPPoESession);
 # padded to the 8-word gather-fast row shape (BNG014 / PERF_NOTES §2)
@@ -58,19 +61,27 @@ class PPPoEResult(NamedTuple):
     stats: jax.Array  # [PPPOE_NSTATS] uint32
 
 
-def _shift_bytes(pkt, shift, gate, start):
-    """Shift packet bytes at/after per-lane `start` by per-lane +/-shift.
+def _shift_bytes(pkt, shift, amounts: tuple[int, ...], start):
+    """Shift packet bytes at/after per-lane `start` by per-lane `shift`,
+    which is 0 (the lane stays as it is) or one of the static `amounts`.
 
     Positive shift contracts (decap: byte j reads from j+shift), negative
     expands (encap). Bytes before `start` (L2 addresses and any VLAN
-    tags) never move. One gather per call.
+    tags) never move. A select among the statically shifted copies of the
+    slot, one pass each: a per-lane index over the slot's width would be
+    a gather, which moves one byte an index (ops/bytes.py). Columns
+    shifted in from beyond the slot read its edge byte, as a clipped
+    index would; they lie past every `out_len`.
     """
     L = pkt.shape[1]
     jj = jnp.arange(L, dtype=jnp.int32)[None, :]
-    src = jnp.clip(jj + shift[:, None], 0, L - 1)
-    moved = jnp.take_along_axis(pkt, src, axis=1)
-    keep_head = jj < jnp.asarray(start).reshape(-1, 1)
-    return jnp.where(gate[:, None] & ~keep_head, moved, pkt)
+    moves = jj >= jnp.asarray(start).reshape(-1, 1)
+    out = pkt
+    for s in amounts:
+        pad = ((0, 0), (0, s)) if s > 0 else ((0, 0), (-s, 0))
+        copy = jnp.pad(pkt, pad, mode="edge")[:, max(s, 0):max(s, 0) + L]
+        out = jnp.where((shift == s)[:, None] & moves, copy, out)
+    return out
 
 
 def pppoe_decap(
@@ -82,7 +93,6 @@ def pppoe_decap(
     geom: TableGeom,
 ) -> PPPoEResult:
     """Strip PPPoE+PPP framing from established-session IPv4/IPv6 data."""
-    Bsz, L = pkt.shape
     length = length.astype(jnp.uint32)
     et_off = 12 + vlan_offset  # offset of the ethertype field itself
     ph = et_off + 2  # PPPoE header start
@@ -91,11 +101,13 @@ def pppoe_decap(
     is_disc = ethertype == ETH_PPPOE_DISC
     hdr_ok = (ph.astype(jnp.uint32) + PPPOE_HDR) <= length
 
-    ver_type = B_.u8_at(pkt, ph)
-    code = B_.u8_at(pkt, ph + 1)
-    session_id = B_.be16_at(pkt, ph + 2)
-    plen = B_.be16_at(pkt, ph + 4)  # PPPoE payload length (PPP proto + data)
-    ppp_proto = B_.be16_at(pkt, ph + 6)
+    # the 8 bytes of PPPoE header + PPP protocol, behind 0 / 1 / 2 tags
+    hdr = B_.window_at(pkt, ph, HDR_BASES, PPPOE_HDR)
+    ver_type = B_.u8_at(hdr, 0)
+    code = B_.u8_at(hdr, 1)
+    session_id = B_.be16_at(hdr, 2)
+    plen = B_.be16_at(hdr, 4)  # PPPoE payload length (PPP proto + data)
+    ppp_proto = B_.be16_at(hdr, 6)
 
     # length-field validation parity with codec.PPPoEPacket.decode: the
     # declared payload must fit the frame (frames may carry Ethernet
@@ -111,9 +123,8 @@ def pppoe_decap(
     is_ctrl = is_disc | (well_formed & ~is_data) | is_malformed
 
     # session validation: id+MAC must match the table (server.go:478-487)
-    z = jnp.zeros((Bsz,), dtype=jnp.int32)
-    src_mac_hi = B_.be16_at(pkt, z + 6)
-    src_mac_lo = B_.be32_at(pkt, z + 8)
+    src_mac_hi = B_.be16_at(pkt, 6)
+    src_mac_lo = B_.be32_at(pkt, 8)
     res = lookup(sessions, session_id[:, None].astype(jnp.uint32), geom)
     bound = (
         res.found
@@ -125,7 +136,7 @@ def pppoe_decap(
 
     # contract by 8: bytes after the ethertype slide left, ethertype
     # becomes the inner protocol
-    out = _shift_bytes(pkt, jnp.where(ok, PPPOE_HDR, 0).astype(jnp.int32), ok, et_off)
+    out = _shift_bytes(pkt, jnp.where(ok, PPPOE_HDR, 0), (PPPOE_HDR,), et_off)
     inner_et = jnp.where(ppp_proto == PPP_IPV4, ETH_P_IP, ETH_P_IPV6)
     out = B_.scatter_be16_at_masked(out, et_off, inner_et, ok)
     # inner frame = L2 up to ethertype (et_off+2) + IP bytes (plen-2);
@@ -179,7 +190,7 @@ def pppoe_encap(
     ok = is_v4 & res.found & ((length + PPPOE_HDR) <= L)
 
     # expand by 8 after the ethertype
-    out = _shift_bytes(pkt, jnp.where(ok, -PPPOE_HDR, 0).astype(jnp.int32), ok, et_off)
+    out = _shift_bytes(pkt, jnp.where(ok, -PPPOE_HDR, 0), (-PPPOE_HDR,), et_off)
     out = B_.scatter_be16_at_masked(out, et_off, jnp.full((Bsz,), ETH_PPPOE_SESSION, dtype=jnp.uint32), ok)
     ph = et_off + 2
     payload_len = length - et_off.astype(jnp.uint32)  # PPP proto (2B) + IP bytes
@@ -224,7 +235,7 @@ def qinq_push(pkt, length, s_tag, c_tag, gate):
     length = length.astype(jnp.uint32)
     ok = gate & ((length + 8) <= L)
     z = jnp.zeros((Bsz,), dtype=jnp.int32)
-    out = _shift_bytes(pkt, jnp.where(ok, -8, 0).astype(jnp.int32), ok, z + 12)
+    out = _shift_bytes(pkt, jnp.where(ok, -8, 0), (-8,), z + 12)
     out = B_.scatter_be16_at_masked(out, z + 12, jnp.full((Bsz,), ETH_P_8021AD, dtype=jnp.uint32), ok)
     out = B_.scatter_be16_at_masked(out, z + 14, s_tag & 0x0FFF, ok)
     out = B_.scatter_be16_at_masked(out, z + 16, jnp.full((Bsz,), ETH_P_8021Q, dtype=jnp.uint32), ok)
@@ -237,5 +248,5 @@ def qinq_pop(pkt, length, vlan_offset, gate):
     length = length.astype(jnp.uint32)
     vo = vlan_offset.astype(jnp.int32)
     ok = gate & (vo > 0)
-    out = _shift_bytes(pkt, jnp.where(ok, vo, 0), ok, jnp.full_like(vo, 12))
+    out = _shift_bytes(pkt, jnp.where(ok, vo, 0), (4, 8), jnp.full_like(vo, 12))
     return out, jnp.where(ok, length - vo.astype(jnp.uint32), length), ok

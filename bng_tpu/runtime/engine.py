@@ -38,7 +38,7 @@ from bng_tpu.control.nat import NATManager, apply_nat_updates
 from bng_tpu.ops.antispoof import ANTISPOOF_NSTATS, AntispoofGeom
 from bng_tpu.ops.dhcp import NSTATS as DHCP_NSTATS
 from bng_tpu.ops.nat44 import NAT_NSTATS
-from bng_tpu.ops.pppoe import PPPOE_NSTATS
+from bng_tpu.ops.pppoe import PPPOE_NSTATS, PST_DECAP, PST_ENCAP, PST_MISS
 from bng_tpu.ops.pipeline import (
     PipelineGeom,
     PipelineResult,
@@ -1165,7 +1165,10 @@ class Engine:
             self.stats.garden += np.asarray(gs, dtype=np.uint64)
         ps = getattr(res, "pppoe_stats", None)
         if ps is not None:
-            self.stats.pppoe += np.asarray(ps, dtype=np.uint64)
+            ps = np.asarray(ps, dtype=np.uint64)
+            self.stats.pppoe += ps
+            tele.pppoe_lanes(int(ps[PST_DECAP]), int(ps[PST_ENCAP]),
+                             int(ps[PST_MISS]))
         es = getattr(res, "edge_stats", None)
         if es is not None:
             self.stats.edge += np.asarray(es, dtype=np.uint64)

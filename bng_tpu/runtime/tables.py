@@ -330,6 +330,20 @@ class PPPoEFastPathTables:
         if sess.assigned_ip:
             self.by_ip.insert([sess.assigned_ip], row)
 
+    def sessions_up_bulk(self, session_ids, macs_u64, ips) -> None:
+        """Publish many OPEN sessions at once (a warm restart, a takeover,
+        a provisioning run): `session_up`'s rows through `bulk_insert`.
+        As after any bulk build, the next upload is a whole one
+        (`Engine.resync_tables`)."""
+        sids = np.asarray(session_ids, dtype=np.uint32)
+        ips = np.asarray(ips, dtype=np.uint32)
+        rows = np.zeros((len(sids), PPPOE_WORDS), dtype=np.uint32)
+        rows[:, PS_SESSION_ID] = sids
+        rows[:, [PS_MAC_HI, PS_MAC_LO]] = mac_key_rows(macs_u64)
+        rows[:, PS_IP] = ips
+        self.by_sid.bulk_insert(sids[:, None], rows)
+        self.by_ip.bulk_insert(ips[:, None], rows)
+
     def session_down(self, event) -> None:
         """on_close hook (takes the server's TeardownEvent)."""
         sess = getattr(event, "session", event)

@@ -41,6 +41,7 @@ class Geometry(NamedTuple):
     sub_nat_nbuckets: int = 1 << 10
     max_pools: int = 4
     stash: int = 64
+    pppoe_nbuckets: int = 0  # PPPoE session tables; 0 = no PPPoE stage
 
 
 TOY = Geometry()
@@ -52,6 +53,9 @@ REAL_1M = Geometry(batch=8192, pkt_slot=1536, sub_nbuckets=1 << 19,
                    side_nbuckets=1 << 19,
                    nat_sessions_nbuckets=1 << 19, sub_nat_nbuckets=1 << 17,
                    max_pools=256)
+# the same with the PPPoE stage compiled in, its two session tables sized
+# for an access concentrator's 65,535 sessions (`bng run --pppoe-enabled`)
+REAL_1M_PPPOE = REAL_1M._replace(pppoe_nbuckets=1 << 15)
 
 
 def compile_for(built, sharding=None):
@@ -151,6 +155,7 @@ def _engine(g: Geometry):
     from bng_tpu.control.nat import NATManager
     from bng_tpu.runtime.engine import (AntispoofTables, Engine, GardenTables,
                                         QoSTables)
+    from bng_tpu.runtime.tables import PPPoEFastPathTables
     from bng_tpu.utils.net import ip_to_u32
 
     return Engine(
@@ -161,6 +166,8 @@ def _engine(g: Geometry):
         qos=QoSTables(nbuckets=g.side_nbuckets),
         antispoof=AntispoofTables(nbuckets=g.side_nbuckets, stash=g.stash),
         garden=GardenTables(nbuckets=g.side_nbuckets, stash=g.stash),
+        pppoe=(PPPoEFastPathTables(nbuckets=g.pppoe_nbuckets, stash=g.stash)
+               if g.pppoe_nbuckets else None),
         batch_size=g.batch, pkt_slot=g.pkt_slot)
 
 

@@ -27,7 +27,9 @@ order:
        fleet       slow-path fleet scatter/gather (control/fleet.py)
        worker      per-frame worker handler time (merged from worker
                    processes' own histograms)
-       slow_path   slow-path drain total (engine._handle_slow_lanes)
+       slow_path   slow-path drain total (engine._handle_slow_lanes), and
+                   the once-a-second PPPoE session walk (cli.py tick:
+                   keepalive and timeouts over every session)
        reply       verdict demux + reply encode/inject
        wire_rx     wire pump ingress: kernel fill-ring feed + kernel RX
                    drain -> ring submit (runtime/xsk.py WirePump; the
@@ -177,6 +179,10 @@ class Tracer:
         # stale lanes the engine's pipelined loop made inert (engine.py
         # _mask_stale_lanes): lanes that held a length beyond a window's end
         self.masked_lanes = 0
+        # lanes the device PPPoE stage decapsulated, encapsulated, and
+        # punted for a session it does not hold (engine.py _fold_stats);
+        # 0 in a program without the stage
+        self.pppoe_decap = self.pppoe_encap = self.pppoe_miss = 0
         self._frozen: dict | None = None  # sums() as finish() left them
 
     # -- batch records ----------------------------------------------------
@@ -521,6 +527,9 @@ class Tracer:
             "starved_ns": starved,
             "p99_us": p99,
             "masked_lanes": int(self.masked_lanes),
+            "pppoe_decap": int(self.pppoe_decap),
+            "pppoe_encap": int(self.pppoe_encap),
+            "pppoe_miss": int(self.pppoe_miss),
         }
 
     def write_events(self, path: str) -> None:
@@ -682,6 +691,16 @@ def masked_lanes(n: int) -> None:
     if _ACTIVE is None:
         return
     _ACTIVE.masked_lanes += n
+
+
+def pppoe_lanes(decap: int, encap: int, miss: int) -> None:
+    """Count one retired step's PPPoE lanes: decapsulated, encapsulated,
+    punted for an unknown session. Disarmed: global load + None compare."""
+    if _ACTIVE is None:
+        return
+    _ACTIVE.pppoe_decap += decap
+    _ACTIVE.pppoe_encap += encap
+    _ACTIVE.pppoe_miss += miss
 
 
 def trigger(reason: str, detail: str = "") -> str | None:

@@ -18,7 +18,7 @@ import jax
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from bng_tpu.runtime import verify
-from bng_tpu.runtime.verify import REAL_1M, compile_for
+from bng_tpu.runtime.verify import REAL_1M, REAL_1M_PPPOE, compile_for
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -76,6 +76,21 @@ def test_fused_step_has_no_while(fused_step):
     a v5e until PR 29, and the step's only loops. Every table write is a
     whole-row scatter, which the chip does natively."""
     assert _whiles(fused_step) == []
+
+
+def test_fused_step_with_the_pppoe_stage_fits_and_has_no_while(one_chip):
+    """`bng run --pppoe-enabled` at the 1M geometry, the two session
+    tables sized for 65,535 sessions: the step whose decap and encap move
+    the whole [8192, 1536] slot by 8 bytes, as selects over static
+    shifts. It fits, and the byte moves bring no loop."""
+    from bng_tpu.control.pppoe.server import PPPoEServerConfig
+    from bng_tpu.ops.table import nbuckets_for
+
+    assert REAL_1M_PPPOE.pppoe_nbuckets == nbuckets_for(
+        PPPoEServerConfig.max_sessions)  # as cli.py sizes the tables
+    compiled = compile_for(verify.build_pipeline(REAL_1M_PPPOE), one_chip)
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert _whiles(compiled) == []
 
 
 @pytest.mark.parametrize("build", [
