@@ -6,7 +6,18 @@ loops on v5e, while >=8-word row gathers run at full speed. The qtable
 bucket-packing (round 3) and the generic-table way_stride relayout
 (round 3.6) killed every narrow PROBE gather, and ISSUE 11 widened the
 last narrow VALUE rows (nat reverse 4->8, pppoe 6->8). This pass makes
-that discipline machine-checked instead of folklore:
+that discipline machine-checked instead of folklore.
+
+Eight words is the floor for a GATHERED row, not a good width for a
+STORED one: an array whose minor dimension is under 128 has padded
+tiled forms, and at 1M rows the compiler copied the QoS table between
+them every step (PR 33: 4.83 ms a table alone, 8.7 ms of a 27.0 ms
+fused step; 0.98 held lane-dense). The QoS table is therefore held
+[nbuckets/4, 128], sixteen 8-word ways a stored row (ops/qtable.py),
+and gathered by stored row; ops/table.py's [S, 16] and [NB, 64] arrays
+still pay such copies (ROADMAP D4).
+This pass does not see stored widths; tests/test_hlo_structure.py
+holds the QoS table's one shape.
 
 - **BNG014 / table construction**: any `HostTable(...)` whose resolved
   `val_words` is < 8 — its device `vals[slot]` gather is exactly the

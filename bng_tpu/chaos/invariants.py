@@ -372,12 +372,12 @@ def _audit_qos_mirror(report: AuditReport, engine) -> None:
     key/flags/rate/burst/priority through the bounded drain, and after
     the drain the config words must agree bit-exact on every slot.
     Caller has drained (pending_dirty()==0) and quiesced."""
-    from bng_tpu.ops.qtable import QW_LAST_US, QW_TOKENS
+    from bng_tpu.ops.qtable import QW_LAST_US, QW_TOKENS, way_rows
 
     for label, host, dev_rows in (
             ("qos.up", engine.qos.up, engine.tables.qos_up.rows),
             ("qos.down", engine.qos.down, engine.tables.qos_down.rows)):
-        got = np.asarray(dev_rows)
+        got = way_rows(dev_rows, host.nbuckets)
         report.checks[f"mirror_slots.{label}"] = host.S
         if host.rows.shape != got.shape:
             report.add("qos-mirror-mismatch", label,

@@ -28,10 +28,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from bng_tpu.ops.qtable import QTableGeom, QTableState, qlookup, write_token_rows
+from bng_tpu.ops.qtable import (QW_TOKENS, QTableGeom, QTableState, qlookup,
+                                write_token_rows)
 
 # token_bucket fields (parity: qos_ratelimit.c:24-31) live in the packed
-# 8-word way rows of ops/qtable.py (policy + token state in one row)
+# 8-word ways of ops/qtable.py (policy + token state in one way row)
 QOS_WORDS = 8
 
 # stats (parity: struct qos_stats, qos_ratelimit.c:53-58)
@@ -46,13 +47,26 @@ QoSGeom = QTableGeom
 PREFIX_IMPL = "sort"
 
 
-def _prefix_consumed(limited, slot, lens_u, avail):
-    """Returns (allowed, consumed_f32, is_head): stable argsort + segment
-    cumsum, u32-exact to 4 GB a batch.
+class SortedLanes(NamedTuple):
+    """The lanes in slot order (stable: arrival order within a bucket),
+    as the token writeback wants them: a stored row's lanes are one run."""
+
+    slot: jax.Array  # [B] int32, negative on a lane without a limit
+    head: jax.Array  # [B] bool, first limited lane of its bucket
+    avail: jax.Array  # [B] float32
+    consumed: jax.Array  # [B] float32 admitted bytes of the lane's bucket
+    ride: jax.Array  # [B, 3] uint32, the caller's columns
+
+
+def _prefix_consumed(limited, slot, lens_u, avail, ride):
+    """Returns (allowed, consumed_f32, is_head, sorted lanes): stable
+    argsort + segment cumsum, u32-exact to 4 GB a batch.
 
     allowed: sequential-TBF admission per lane (arrival = lane order);
     consumed: admitted bytes of the lane's bucket (valid on limited lanes);
-    is_head: first limited lane of each bucket in the batch.
+    is_head: first limited lane of each bucket in the batch;
+    ride: [B, 3] uint32 carried through the sort in the packed row's
+    spare words.
     """
     Bsz = slot.shape[0]
     # lanes without a limit get unique negative ids -> group with nobody
@@ -65,15 +79,17 @@ def _prefix_consumed(limited, slot, lens_u, avail):
     # an inverse-permutation + three gathers. tests/test_hlo_structure.py
     # pins these counts.
     order = jnp.argsort(slot_eff, stable=True)
-    avail_int = jnp.clip(avail, 0.0, 4.0e9).astype(jnp.uint32)
-    zero = jnp.zeros_like(lens_u)
-    packed = jnp.stack(
-        [slot_eff.astype(jnp.uint32), lens_u, avail_int,
-         limited.astype(jnp.uint32), zero, zero, zero, zero], axis=1)  # [B, 8]
+    avail = avail.astype(jnp.float32)
+    packed = jnp.concatenate(
+        [jnp.stack([slot_eff.astype(jnp.uint32), lens_u,
+                    jax.lax.bitcast_convert_type(avail, jnp.uint32),
+                    limited.astype(jnp.uint32)], axis=1),
+         ride, jnp.zeros((Bsz, 1), dtype=jnp.uint32)], axis=1)  # [B, 8]
     ps = packed[order]
     s_sorted = ps[:, 0].astype(jnp.int32)
     lens_sorted = ps[:, 1]
-    avail_sorted = ps[:, 2]
+    avail_f_sorted = jax.lax.bitcast_convert_type(ps[:, 2], jnp.float32)
+    avail_sorted = jnp.clip(avail_f_sorted, 0.0, 4.0e9).astype(jnp.uint32)
     limited_sorted = ps[:, 3] != 0
 
     csum = jnp.cumsum(lens_sorted)
@@ -98,15 +114,19 @@ def _prefix_consumed(limited, slot, lens_u, avail):
         jnp.where(is_head_sorted, adm_csum - admitted_sorted, 0))
     consumed_sorted = seg_end - adm_base
 
+    head_sorted = is_head_sorted & limited_sorted
     zs = jnp.zeros_like(consumed_sorted)
     res_sorted = jnp.stack(
         [allowed_sorted.astype(jnp.uint32), consumed_sorted,
-         (is_head_sorted & limited_sorted).astype(jnp.uint32),
+         head_sorted.astype(jnp.uint32),
          zs, zs, zs, zs, zs], axis=1)  # [B, 8] — wide unsort scatter
     res = jnp.zeros((Bsz, 8), dtype=jnp.uint32).at[order].set(res_sorted)
     return (res[:, 0] != 0,
             res[:, 1].astype(jnp.float32),
-            (res[:, 2] != 0) & limited)
+            (res[:, 2] != 0) & limited,
+            SortedLanes(slot=s_sorted, head=head_sorted, avail=avail_f_sorted,
+                        consumed=consumed_sorted.astype(jnp.float32),
+                        ride=ps[:, 4:7]))
 
 
 class QoSResult(NamedTuple):
@@ -149,14 +169,16 @@ def qos_kernel(
 
     # --- same-bucket aggregation (sequential TBF admission per lane) ---
     lens_u = pkt_len.astype(jnp.uint32)
-    allowed, consumed, first = _prefix_consumed(limited, res.slot, lens_u, avail)
+    ride = jnp.stack([res.burst, res.row[:, QW_TOKENS], res.last_us], axis=1)
+    allowed, _, _, run = _prefix_consumed(limited, res.slot, lens_u, avail, ride)
     dropped = limited & ~allowed
-    new_tokens = jnp.clip(avail - consumed, 0.0, burst_f)
-    S = table.rows.shape[0]
-    wslot = jnp.where(first, res.slot, S).astype(jnp.int32)
-    # head lanes rewrite their whole way row (one wide [B,8] scatter —
-    # no scalar token/timestamp scatters; see qtable.write_token_rows)
-    new_table = write_token_rows(table, wslot, res.row, new_tokens, now_us)
+    # the head lane of each bucket writes its way's tokens and timestamp,
+    # in the sort's order: the lanes of one stored row are one run there,
+    # merged into one whole-row write (qtable.write_token_rows)
+    new_tokens = jnp.clip(run.avail - run.consumed, 0.0,
+                          run.ride[:, 0].astype(jnp.float32))
+    new_table = write_token_rows(table, run.slot, run.head, run.ride[:, 1:3],
+                                 new_tokens, now_us)
 
     priority = jnp.where(has_policy, res.priority, 0)
 

@@ -1,4 +1,4 @@
-"""Bucket-packed QoS policy table — one wide gather per hash probe.
+"""Bucket-packed QoS policy table — one lane-dense row gather per hash probe.
 
 Why this exists (measured on a real v5e through the round-3 profiling
 sessions): the generic cuckoo table (ops/table.py) stores keys as [S, K]
@@ -9,22 +9,50 @@ and occupancy as [S]. For the QoS table K=1, so a probe compiles to many
 That one layout artifact made the QoS kernel the bottleneck of the whole
 dataplane (VERDICT r2: 0.114 Mpps standalone, 65ms fixed cost).
 
-So the QoS table is **way-granular**: every 4-way bucket is four
-consecutive 8-word rows, and ALL of a subscriber's state — policy AND
-mutable token state — lives in its one row:
+So the QoS table is **way-granular**: a way is one 8-word row, and ALL
+of a subscriber's state — policy AND mutable token state — lives in it:
 
-    rows[nbuckets*4, 8] u32:
         +0 key (subscriber ip)   +1 flags (bit0 = used)
         +2 rate_lo  +3 rate_hi   +4 burst  +5 priority
         +6 tokens (f32 bitcast)  +7 last_us
 
-A lookup is exactly two [B, 32] row gathers (rows viewed [nbuckets, 32]:
-bucket 1, bucket 2) plus branch-free lane compares — tokens included, no
-separate narrow token gather. The QoS kernel's token writeback is ONE
-wide [B, 8] row scatter (the head lane of each bucket rewrites its whole
-way row: policy words unchanged, +6/+7 updated). Host policy sync is a
-wide [U, 8] row scatter at changed slots only, so sibling ways' device-
-authoritative tokens are never touched by an update.
+On the device the table is held **lane-dense**, in the one shape every op
+of the step reads and writes it in: ``rows[nbuckets/4, 128]``, four
+4-way buckets (sixteen ways) a stored row. Way ``s`` is words
+``(s % 16) * 8 ..+8`` of stored row ``s // 16``; bucket ``b`` is words
+``(b % 4) * 32 ..+32`` of stored row ``b // 4``. The host mirror, the
+hashing, the slot numbering and the checkpoint stay way rows ``[S, 8]``;
+``device_state()`` reshapes on the host, where it is free, and
+``way_rows()`` is the one way back.
+
+Why 128 words (measured on a v5e at the deployment's 1M policies, PR 33,
+PERF.md section 6): a u32 array's minor dimension is tiled to 128 lanes.
+Held as ``[nbuckets*4, 8]`` the 67 MB table had three physical forms —
+the transposed one the compiler kept it in, the 8-in-128 padded one the
+row scatters wanted (1.07 GB), and the ``[nbuckets, 32]`` one the probe
+gathered (268 MB) — and every step copied the whole table between them:
+eight whole-table ops for the two tables, 8.7 ms of a 27.0 ms step, paid
+whatever the batch held. The 58 us a narrow gather costs had been
+measured on a table small enough for those copies to be free. With a
+minor dimension of 128 the tiling pads nothing and there is no cheaper
+form to move to: no op has the whole array as operand but the in-place
+scatters and the gathers themselves. Policy sync + QoS kernel alone,
+B = 8192, 524,288 buckets, ms a call: ``[nbuckets*4, 8]`` 4.83,
+``[nbuckets, 32]`` 1.27 (the compiler holds it transposed), this 0.98;
+the fused step 27.0 -> 18.4 ms.
+
+- A lookup is two [B, 128] stored-row gathers, the bucket's 32 words by
+  a select among the row's four static slices, then branch-free lane
+  compares — tokens included, no separate narrow token gather.
+- The token writeback is ONE whole-row scatter at unique indices: the
+  lanes come sorted by slot (ops/qos.py), the ways a batch rewrites in
+  one stored row are merged as u32 deltas over the run, and the run's
+  last lane sets the row (the form of ops/nat44.py's accounting). Never
+  a part-row window (``rows.at[r, c:c+8]``): that compiles to a serial
+  loop of one dynamic-update-slice a lane (PR 29: 34 ms of 94).
+- Host policy sync is a read-modify-write of whole stored rows: the host
+  ships each dirty stored row once with a mask of the ways it replaces,
+  so sibling ways keep their device-authoritative tokens.
 
 Parity: the row carries the same fields as the reference's
 ``struct token_bucket`` (bpf/qos_ratelimit.c:24-31); the host mirror
@@ -48,7 +76,10 @@ from bng_tpu.ops.hashing import SEED1, SEED2, hash_words
 
 WAYS = 4
 SLOT_W = 8  # words per way row
-ROW_W = WAYS * SLOT_W  # 32 — the probe gather width
+ROW_W = WAYS * SLOT_W  # 32 — one bucket
+ROW_BUCKETS = 4  # buckets per stored row
+ROW_SLOTS = ROW_BUCKETS * WAYS  # 16 ways per stored row
+STORE_W = ROW_SLOTS * SLOT_W  # 128 — the device array's minor dimension
 MAX_KICKS = 128
 
 # word offsets within a way row
@@ -65,21 +96,49 @@ def _u2f(u: int) -> float:
     return float(np.array(u, dtype=np.uint32).view(np.float32))
 
 
-class QTableState(NamedTuple):
-    """Device array (a pytree of one leaf; host writes policy rows, the
-    QoS kernel writes token state — both as wide row scatters)."""
+def stored_rows(nbuckets: int) -> int:
+    """Stored rows of a table's device array. A table of fewer than
+    ROW_BUCKETS buckets is padded to one whole row; the tail is never
+    hashed to."""
+    return -(-nbuckets // ROW_BUCKETS)
 
-    rows: jax.Array  # [NB*4, 8] uint32 packed way rows
+
+def to_stored(way_rows: np.ndarray) -> np.ndarray:
+    """Host way rows [S, 8] in the device's shape [R, 128] (a view from
+    ROW_BUCKETS buckets up)."""
+    S = way_rows.shape[0]
+    R = stored_rows(S // WAYS)
+    if R * ROW_SLOTS != S:
+        way_rows = np.concatenate(
+            [way_rows, np.zeros((R * ROW_SLOTS - S, SLOT_W), way_rows.dtype)])
+    return way_rows.reshape(R, STORE_W)
+
+
+def way_rows(rows, nbuckets: int) -> np.ndarray:
+    """The device array (or a mesh-stacked one, [n, R, 128]) as way rows
+    [..., S, 8] on the host — the one way out for whatever reads device
+    state by slot (checkpoint fold, mirror audit)."""
+    a = np.asarray(rows)
+    return a.reshape(*a.shape[:-2], -1, SLOT_W)[..., : nbuckets * WAYS, :]
+
+
+class QTableState(NamedTuple):
+    """Device array (a pytree of one leaf; host writes policy words, the
+    QoS kernel writes token state — both as whole stored-row scatters)."""
+
+    rows: jax.Array  # [stored_rows(NB), 128] uint32, sixteen ways a row
 
 
 class QTableUpdate(NamedTuple):
-    """Bounded dirty-slot scatter (host -> device policy sync).
+    """Bounded dirty-row batch (host -> device policy sync).
 
-    slot >= NB*4 rows are dropped padding. Only changed slots are written,
-    so sibling ways keep their device-side token state untouched."""
+    Each dirty stored row ships once, with a bit per way the host
+    replaces; the other ways keep their device-side token state. row >=
+    stored_rows(NB) is dropped padding."""
 
-    slot: jax.Array  # [U] int32 global slot indices
-    rows: jax.Array  # [U, 8] uint32 full replacement way rows
+    row: jax.Array  # [U] int32 stored-row indices, unique below the padding
+    ways: jax.Array  # [U] uint32, bit k set: way k of the row is replaced
+    rows: jax.Array  # [U, 128] uint32 the host's stored rows
 
 
 class QTableGeom(NamedTuple):
@@ -105,12 +164,16 @@ class QLookup(NamedTuple):
 
 
 def apply_qupdate(state: QTableState, upd: QTableUpdate) -> QTableState:
-    """Scatter dirty way rows (inside jit) — one wide row scatter."""
-    return QTableState(rows=state.rows.at[upd.slot].set(upd.rows, mode="drop"))
+    """Policy sync (inside jit): gather the batch's stored rows, take the
+    host's words in the ways it replaces, set the rows back."""
+    cur = state.rows.at[upd.row].get(mode="clip")
+    bit = (upd.ways[:, None] >> jnp.arange(ROW_SLOTS, dtype=jnp.uint32)) & 1
+    new = jnp.where(jnp.repeat(bit, SLOT_W, axis=1) != 0, upd.rows, cur)
+    return QTableState(rows=state.rows.at[upd.row].set(new, mode="drop"))
 
 
 def qlookup(state: QTableState, ip: jax.Array, g: QTableGeom) -> QLookup:
-    """Branch-free probe: 2 wide row gathers + lane compares.
+    """Branch-free probe: 2 stored-row gathers + lane compares.
 
     ip: [B] uint32 keys.
     """
@@ -119,12 +182,16 @@ def qlookup(state: QTableState, ip: jax.Array, g: QTableGeom) -> QLookup:
     b1 = (hash_words([ip], SEED1) & mask).astype(jnp.int32)
     b2 = (hash_words([ip], SEED2) & mask).astype(jnp.int32)
 
-    wide = state.rows.reshape(g.nbuckets, ROW_W)
-    r1 = wide[b1]  # [B, 32] — the fast gather shape
-    r2 = wide[b2]
-    cand = jnp.concatenate(
-        [r1.reshape(Bsz, WAYS, SLOT_W), r2.reshape(Bsz, WAYS, SLOT_W)], axis=1
-    )  # [B, 2W, 8]
+    def bucket_words(b):
+        stored = state.rows[b // ROW_BUCKETS]  # [B, 128] from the array as it stands
+        at = b % ROW_BUCKETS
+        words = stored[:, :ROW_W]
+        for i in range(1, ROW_BUCKETS):
+            words = jnp.where((at == i)[:, None],
+                              stored[:, i * ROW_W:(i + 1) * ROW_W], words)
+        return words.reshape(Bsz, WAYS, SLOT_W)
+
+    cand = jnp.concatenate([bucket_words(b1), bucket_words(b2)], axis=1)  # [B, 2W, 8]
 
     match = (cand[:, :, QW_KEY] == ip[:, None]) & (
         (cand[:, :, QW_FLAGS] & FLAG_USED) != 0
@@ -153,22 +220,43 @@ def qlookup(state: QTableState, ip: jax.Array, g: QTableGeom) -> QLookup:
     )
 
 
-def write_token_rows(state: QTableState, wslot: jax.Array, row: jax.Array,
-                     tokens: jax.Array, now_us: jax.Array) -> QTableState:
-    """Device-side token writeback: head lanes rewrite their way row with
-    updated +6/+7 — one wide [B, 8] row scatter, no scalar scatters.
+def write_token_rows(state: QTableState, slot: jax.Array, head: jax.Array,
+                     old: jax.Array, tokens: jax.Array,
+                     now_us: jax.Array) -> QTableState:
+    """Device-side token writeback: every head lane's way gets its new
+    +6/+7 — one whole stored-row scatter at unique indices.
 
-    wslot: [B] int32, >= NB*4 where the lane must not write (dropped).
-    row: [B, 8] the looked-up way rows (policy words are rewritten with
-    the values read this same step — the host applies updates between
-    steps, so the sequencing is linear and nothing can be clobbered).
+    The lanes come SORTED by slot (ops/qos.py _prefix_consumed), so the
+    lanes of one stored row are one run. slot: [B] int32, negative where
+    the lane has no bucket; head: [B] bool, the lane that writes its way;
+    old: [B, 2] uint32, the +6/+7 the lane read this same step; tokens:
+    [B] float32. A head lane's change is the u32 difference new - old on
+    its own two words: the ways are disjoint words, so the run's sum is
+    exact, and the run's last lane sets row + sum (ops/nat44.py's form).
     """
-    Bsz = wslot.shape[0]
+    Bsz = slot.shape[0]
+    R = state.rows.shape[0]
     tok_u = jax.lax.bitcast_convert_type(tokens.astype(jnp.float32), jnp.uint32)
     now_b = jnp.broadcast_to(now_us, (Bsz,)).astype(jnp.uint32)
-    new_row = jnp.concatenate(
-        [row[:, :QW_TOKENS], tok_u[:, None], now_b[:, None]], axis=1)
-    return QTableState(rows=state.rows.at[wslot].set(new_row, mode="drop"))
+    delta = jnp.where(head[:, None], jnp.stack([tok_u, now_b], axis=1) - old, 0)
+    at = jnp.arange(ROW_SLOTS, dtype=jnp.int32)[None, :] == (slot % ROW_SLOTS)[:, None]
+    lane = jnp.where(at[:, :, None], delta[:, None, :], 0).reshape(Bsz, -1)
+
+    row = slot // ROW_SLOTS
+    edge = row[1:] != row[:-1]
+    run_head = jnp.concatenate([jnp.ones((1,), dtype=bool), edge])
+    run_last = jnp.concatenate([edge, jnp.ones((1,), dtype=bool)])
+    csum = jnp.cumsum(lane, axis=0)
+    head_at = jax.lax.cummax(jnp.where(run_head, jnp.arange(Bsz), 0))
+    run_sums = csum - (csum - lane)[head_at]
+
+    # a run of lanes with a bucket holds a head; the others point past
+    # the table and the scatter drops them
+    idx = jnp.where(run_last & (slot >= 0), row, R)
+    cur = state.rows.at[idx].get(mode="clip")
+    sums = jnp.pad(run_sums.reshape(Bsz, ROW_SLOTS, 2),
+                   ((0, 0), (0, 0), (QW_TOKENS, 0))).reshape(Bsz, STORE_W)
+    return QTableState(rows=state.rows.at[idx].set(cur + sums, mode="drop"))
 
 
 class HostQTable:
@@ -176,7 +264,7 @@ class HostQTable:
 
     Same role as ops/table.py:HostTable (pkg/ebpf loader map-CRUD), with
     slot-granular dirty tracking: a policy change marks its way row dirty
-    and the whole 8-word row (config + re-seeded tokens) is rescattered.
+    and the whole 8-word way (config + re-seeded tokens) is replaced.
     """
 
     def __init__(self, nbuckets: int, name: str = ""):
@@ -368,7 +456,7 @@ class HostQTable:
     def device_state(self) -> QTableState:
         self._dirty.clear()
         self._dirty_all = False
-        return QTableState(rows=jnp.asarray(self.rows))
+        return QTableState(rows=jnp.asarray(to_stored(self.rows)))
 
     def dirty_count(self) -> int:
         return self.S if self._dirty_all else len(self._dirty)
@@ -383,21 +471,30 @@ class HostQTable:
         return len(self._dirty) - before
 
     def make_update(self, max_slots: int) -> QTableUpdate:
-        """Drain up to max_slots dirty way rows (bounded host->HBM traffic)."""
+        """Drain the dirty ways of up to max_slots stored rows (bounded
+        host->HBM traffic): each row ships once, whole, with a bit per
+        dirty way."""
         if self._dirty_all:
             raise RuntimeError(
                 f"qos table {self.name!r}: bulk_insert invalidated delta sync; "
                 "call device_state() for a full upload first")
-        take = sorted(self._dirty)[:max_slots]
-        self._dirty.difference_update(take)
-        n = len(take)
-        slot = np.full((max_slots,), self.S, dtype=np.int32)
-        rows = np.zeros((max_slots, SLOT_W), dtype=np.uint32)
-        if n:
-            ss = np.asarray(take, dtype=np.int32)
-            slot[:n] = ss
-            rows[:n] = self.rows[ss]
-        return QTableUpdate(slot=jnp.asarray(slot), rows=jnp.asarray(rows))
+        if not self._dirty:  # nothing to ship: the batch that is already placed
+            return self.empty_update(max_slots)
+        ss = np.asarray(sorted(self._dirty), dtype=np.int64)
+        rr, first = np.unique(ss // ROW_SLOTS, return_index=True)
+        n = min(len(rr), max_slots)
+        if n < len(rr):  # the ways of the rows beyond the batch wait
+            ss = ss[: first[n]]
+        self._dirty.difference_update(ss.tolist())
+        row = np.full((max_slots,), stored_rows(self.nbuckets), dtype=np.int32)
+        ways = np.zeros((max_slots,), dtype=np.uint32)
+        rows = np.zeros((max_slots, STORE_W), dtype=np.uint32)
+        row[:n] = rr[:n]
+        np.bitwise_or.at(ways, np.searchsorted(rr, ss // ROW_SLOTS),
+                         np.uint32(1) << (ss % ROW_SLOTS).astype(np.uint32))
+        rows[:n] = to_stored(self.rows)[rr[:n]]
+        return QTableUpdate(row=jnp.asarray(row), ways=jnp.asarray(ways),
+                            rows=jnp.asarray(rows))
 
     def empty_update(self, max_slots: int) -> QTableUpdate:
         """All-padding QTableUpdate (no-op scatter), built without touching
@@ -409,6 +506,8 @@ class HostQTable:
         upd = cache.get(max_slots)
         if upd is None:
             upd = cache[max_slots] = QTableUpdate(
-                slot=jnp.full((max_slots,), self.S, dtype=jnp.int32),
-                rows=jnp.zeros((max_slots, SLOT_W), dtype=jnp.uint32))
+                row=jnp.full((max_slots,), stored_rows(self.nbuckets),
+                             dtype=jnp.int32),
+                ways=jnp.zeros((max_slots,), dtype=jnp.uint32),
+                rows=jnp.zeros((max_slots, STORE_W), dtype=jnp.uint32))
         return upd

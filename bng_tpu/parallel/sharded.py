@@ -1273,14 +1273,16 @@ class ShardedCluster:
         the pre-checkpoint fetch, per shard. Engine parity including the
         uploaded-mask discipline: host rows the bounded drain has not
         shipped yet stay authoritative. Call behind quiesce()."""
-        from bng_tpu.ops.qtable import QW_FLAGS, QW_LAST_US, QW_TOKENS
+        from bng_tpu.ops.qtable import (QW_FLAGS, QW_LAST_US, QW_TOKENS,
+                                        way_rows)
         from bng_tpu.runtime.engine import Engine
 
         if self.tables is None:
             return
         sess_dev = np.asarray(self.tables.nat.sessions.vals)
-        qos_up_dev = np.asarray(self.tables.qos_up.rows)
-        qos_down_dev = np.asarray(self.tables.qos_down.rows)
+        nbuckets = self.qos[0].geom.nbuckets
+        qos_up_dev = way_rows(self.tables.qos_up.rows, nbuckets)
+        qos_down_dev = way_rows(self.tables.qos_down.rows, nbuckets)
         for i in range(self.n):
             sessions = self.nat[i].sessions
             mask = Engine._uploaded_mask(sessions,

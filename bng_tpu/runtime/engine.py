@@ -1534,7 +1534,8 @@ class Engine:
         _uploaded_mask); not-yet-drained host writes stay authoritative.
         Call behind quiesce(): a fetch that overlaps an in-flight
         scatter could tear a row."""
-        from bng_tpu.ops.qtable import QW_FLAGS, QW_LAST_US, QW_TOKENS
+        from bng_tpu.ops.qtable import (QW_FLAGS, QW_LAST_US, QW_TOKENS,
+                                        way_rows)
 
         dev = self.fetch_session_vals()
         mask = self._uploaded_mask(self.nat.sessions,
@@ -1542,7 +1543,7 @@ class Engine:
         self.nat.sessions.vals[mask] = dev[mask]
         for host, dev_rows in ((self.qos.up, self.tables.qos_up.rows),
                                (self.qos.down, self.tables.qos_down.rows)):
-            rows = np.asarray(dev_rows)
+            rows = way_rows(dev_rows, host.nbuckets)
             live = self._uploaded_mask(host,
                                        (host.rows[:, QW_FLAGS] & 1) != 0)
             host.rows[live, QW_TOKENS] = rows[live, QW_TOKENS]

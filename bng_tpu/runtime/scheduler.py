@@ -62,8 +62,9 @@ from bng_tpu.telemetry.recorder import (TRIG_EXPRESS_AOT_MISS,
                                         TRIG_EXPRESS_FALLBACK)
 from bng_tpu.runtime import hostpath
 from bng_tpu.runtime.engine import _ExpressAotResult
-from bng_tpu.runtime.lanes import (CLOSE_FLUSH, CompletionRing, InflightEntry,
-                                   Lane, LaneConfig, LANE_BULK, LANE_EXPRESS)
+from bng_tpu.runtime.lanes import (CLOSE_DEADLINE, CLOSE_FLUSH, CompletionRing,
+                                   InflightEntry, Lane, LaneConfig, LANE_BULK,
+                                   LANE_EXPRESS)
 from bng_tpu.runtime.ring import classify_dhcp
 from bng_tpu.utils.net import prefix_to_mask
 from bng_tpu.utils.structlog import get_logger
@@ -380,6 +381,15 @@ class TieredScheduler:
         retired = 0
         while True:
             reason = self.express.close_reason(now)
+            if (reason is None and len(self.express)
+                    and self.bulk.close_reason(now) is not None):
+                # a bulk close is waiting in THIS beat, and its pack,
+                # dispatch and drain take some 14 ms of this thread: the
+                # express frames that came in with it would pass their
+                # deadline seventy times over before the next look, and
+                # then ship behind that very step (PERF.md section 6,
+                # PR 33: `lane_wait` p95 15.6 ms against a 200 us deadline)
+                reason = CLOSE_DEADLINE
             if reason is None:
                 break
             pend, reason = self.express.close_batch(now, reason)

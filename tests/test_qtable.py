@@ -10,14 +10,17 @@ import jax.numpy as jnp
 import pytest
 
 from bng_tpu.ops.qtable import (
-    QW_TOKENS, HostQTable, QTableGeom, WAYS, apply_qupdate, qlookup,
+    QW_BURST, QW_LAST_US, QW_TOKENS, ROW_BUCKETS, ROW_SLOTS, SLOT_W, STORE_W,
+    HostQTable, QTableGeom, WAYS, apply_qupdate, qlookup, stored_rows, way_rows,
 )
 
 
 def _set_device_tokens(st, slot, value: float):
     """Simulate the device-side token writeback for one slot."""
     u = np.array(value, dtype=np.float32).view(np.uint32)
-    return st._replace(rows=st.rows.at[slot, QW_TOKENS].set(jnp.uint32(u)))
+    return st._replace(rows=st.rows.at[
+        slot // ROW_SLOTS, (slot % ROW_SLOTS) * SLOT_W + QW_TOKENS
+    ].set(jnp.uint32(u)))
 
 
 def _mk(nbuckets=256, n=100, seed=0):
@@ -256,9 +259,10 @@ class TestPrefixConsumed:
     def _check(self, limited, slot, lens, avail):
         from bng_tpu.ops.qos import _prefix_consumed
 
-        allowed, consumed, is_head = _prefix_consumed(
+        allowed, consumed, is_head, _ = _prefix_consumed(
             jnp.asarray(limited), jnp.asarray(slot), jnp.asarray(lens),
-            jnp.asarray(avail.astype(np.float32)))
+            jnp.asarray(avail.astype(np.float32)),
+            jnp.zeros((len(slot), 3), dtype=jnp.uint32))
         ref_a, ref_c, ref_h = ref_prefix_consumed(limited, slot, lens, avail)
         np.testing.assert_array_equal(np.asarray(allowed), ref_a)
         np.testing.assert_array_equal(np.asarray(is_head), ref_h)
@@ -301,3 +305,161 @@ class TestPrefixConsumed:
                          jnp.ones((8,), dtype=bool),
                          qos.up.device_state(), qos.geom, jnp.uint32(1))
         assert list(np.asarray(res.allowed)) == [True, True] + [False] * 6
+
+
+def _fill_stored_row(t: HostQTable, r: int, n: int = ROW_SLOTS) -> list[int]:
+    """Install n policies in stored row r of t (keys whose first bucket
+    lies in it and has a free way). Returns their ips, slot order."""
+    ips, ip = [], 1
+    while len(ips) < n:
+        b1, _ = t._buckets(ip)
+        if b1 // ROW_BUCKETS == r and not all(
+                t.rows[b1 * WAYS + w][1] & 1 for w in range(WAYS)):
+            slot = t.insert(ip, rate_bps=8_000_000, burst=50_000 + ip)
+            assert slot // ROW_SLOTS == r
+            ips.append(ip)
+        ip += 1
+    return sorted(ips, key=lambda i: t.lookup(i)["slot"])
+
+
+def _f32_bits(v) -> np.ndarray:
+    return np.asarray(v, dtype=np.float32).view(np.uint32)
+
+
+class TestStoredRows:
+    """The device array is [nbuckets/4, 128], sixteen ways a stored row:
+    what one step writes into one stored row is merged into one write."""
+
+    @pytest.mark.parametrize("nbuckets", [1, 2, 4, 256])
+    def test_view_round_trips(self, nbuckets):
+        t, _ = _mk(nbuckets=nbuckets, n=min(100, nbuckets * 3))
+        st = t.device_state()
+        assert st.rows.shape == (stored_rows(nbuckets), STORE_W)
+        view = way_rows(st.rows, nbuckets)
+        assert view.shape == t.rows.shape
+        np.testing.assert_array_equal(view, t.rows)
+        # a table under one stored row is padded; the tail holds nothing
+        assert not np.asarray(st.rows).reshape(-1, SLOT_W)[t.S:].any()
+        # and a mesh-stacked array reads the same way, shard by shard
+        both = way_rows(jnp.stack([st.rows, st.rows]), nbuckets)
+        np.testing.assert_array_equal(both[1], t.rows)
+
+    @pytest.mark.parametrize("n", [2, 4, 16])
+    def test_ways_of_one_stored_row_all_land(self, n):
+        """n ways of stored row 1 rewritten in one step, their lanes mixed
+        with another row's: every way gets its tokens and timestamp, and
+        the row's other ways keep all eight words."""
+        from bng_tpu.ops.qos import qos_kernel
+
+        t = HostQTable(16)
+        row1 = _fill_stored_row(t, 1)
+        other = _fill_stored_row(t, 3, 5)
+        hit = row1[:n] if n < ROW_SLOTS else row1
+        rng = np.random.default_rng(n)
+        lanes = np.concatenate([np.repeat(hit, 3), other, other])
+        rng.shuffle(lanes)
+        lens = rng.integers(64, 1500, size=len(lanes)).astype(np.uint32)
+        before = t.rows.copy()
+        res = qos_kernel(jnp.asarray(lanes.astype(np.uint32)), jnp.asarray(lens),
+                         jnp.ones((len(lanes),), dtype=bool), t.device_state(),
+                         QTableGeom(16), jnp.uint32(0))
+        assert np.asarray(res.allowed).all()
+        got = way_rows(res.table.rows, 16)
+        want = before.copy()
+        for ip in list(hit) + list(other):
+            slot = t.lookup(ip)["slot"]
+            spent = int(lens[lanes == ip].sum())
+            want[slot, QW_TOKENS] = _f32_bits(
+                np.float32(before[slot, QW_BURST]) - np.float32(spent))
+            want[slot, QW_LAST_US] = 0
+        np.testing.assert_array_equal(got, want)
+        # the ways no lane met still hold what the host put there
+        idle = [t.lookup(ip)["slot"] for ip in row1 if ip not in hit]
+        np.testing.assert_array_equal(got[idle], before[idle])
+
+    def test_update_and_writeback_meet_in_one_stored_row(self):
+        """One step: the host installs a policy in a stored row whose
+        sibling way the kernel charges. Both land; the sibling's tokens
+        are the device's, the new way's the host's."""
+        import jax
+
+        from bng_tpu.ops.qos import qos_kernel
+
+        t = HostQTable(16)
+        a, = _fill_stored_row(t, 2, 1)
+        st = t.device_state()
+        b, = [ip for ip in _fill_stored_row(t, 2, 2) if ip != a]
+        g = QTableGeom(16)
+
+        @jax.jit
+        def step(st, upd, ips, lens):
+            return qos_kernel(ips, lens, jnp.ones(ips.shape, dtype=bool),
+                              apply_qupdate(st, upd), g, jnp.uint32(7)).table
+
+        out = step(st, t.make_update(4),
+                   jnp.asarray(np.asarray([a, a], dtype=np.uint32)),
+                   jnp.asarray(np.asarray([1000, 500], dtype=np.uint32)))
+        got = way_rows(out.rows, 16)
+        sa, sb = t.lookup(a)["slot"], t.lookup(b)["slot"]
+        assert sa // ROW_SLOTS == sb // ROW_SLOTS == 2
+        np.testing.assert_array_equal(got[sb], t.rows[sb])  # installed, untouched
+        # 7 us at 1 B/us refill onto a full bucket: still the burst
+        assert got[sa, QW_TOKENS] == _f32_bits(np.float32(50_000 + a) - np.float32(1500))
+        assert got[sa, QW_LAST_US] == 7
+        np.testing.assert_array_equal(got[sa, :QW_TOKENS], t.rows[sa, :QW_TOKENS])
+
+    def test_dirty_ways_of_one_stored_row_ship_once(self):
+        t = HostQTable(16)
+        t.device_state()
+        ips = _fill_stored_row(t, 1, 5) + _fill_stored_row(t, 3, 2)
+        assert t.dirty_count() == 7
+        upd = t.make_update(8)
+        assert t.dirty_count() == 0
+        row, ways = np.asarray(upd.row), np.asarray(upd.ways)
+        assert list(row[:2]) == [1, 3] and (row[2:] == stored_rows(16)).all()
+        assert bin(int(ways[0])).count("1") == 5 and bin(int(ways[1])).count("1") == 2
+        assert not ways[2:].any()
+        for k, r in enumerate((1, 3)):
+            np.testing.assert_array_equal(
+                np.asarray(upd.rows)[k].reshape(ROW_SLOTS, SLOT_W),
+                t.rows[r * ROW_SLOTS:(r + 1) * ROW_SLOTS])
+        for ip in ips:
+            s = t.lookup(ip)["slot"]
+            assert ways[list(row).index(s // ROW_SLOTS)] >> (s % ROW_SLOTS) & 1
+
+    def test_update_batch_is_bounded_by_stored_rows(self):
+        """make_update(n) drains n stored rows' dirty ways; the rest wait."""
+        t = HostQTable(16)
+        t.device_state()
+        _fill_stored_row(t, 0, 3), _fill_stored_row(t, 1, 3), _fill_stored_row(t, 2, 3)
+        upd = t.make_update(2)
+        assert list(np.asarray(upd.row)) == [0, 1]
+        assert t.dirty_count() == 3
+        assert list(np.asarray(t.make_update(2).row)) == [2, stored_rows(16)]
+
+    def test_full_batch_in_64_stored_rows_matches_reference(self):
+        """B = 8192, every lane in one of 64 stored rows (1,024 ways):
+        verdicts and the table against the plain per-packet reference."""
+        from bng_tpu.ops.qos import qos_kernel
+
+        B = 8192
+        t, ips = _mk(nbuckets=256, n=700, seed=11)
+        assert stored_rows(256) == 64
+        rng = np.random.default_rng(12)
+        lanes = ips[rng.integers(0, len(ips), size=B)]
+        lens = rng.integers(64, 1500, size=B).astype(np.uint32)
+        slot = np.asarray([t.lookup(int(ip))["slot"] for ip in lanes], dtype=np.int32)
+        before = t.rows.copy()
+        avail = before[slot, QW_BURST]
+        ref_a, ref_c, _ = ref_prefix_consumed(
+            np.ones((B,), dtype=bool), slot, lens, avail)
+        res = qos_kernel(jnp.asarray(lanes), jnp.asarray(lens),
+                         jnp.ones((B,), dtype=bool), t.device_state(),
+                         QTableGeom(256), jnp.uint32(0))
+        np.testing.assert_array_equal(np.asarray(res.allowed), ref_a)
+        assert not ref_a.all() and ref_a.any()  # some buckets cut mid-batch
+        want = before.copy()
+        want[slot, QW_TOKENS] = _f32_bits(
+            avail.astype(np.float32) - ref_c.astype(np.float32))
+        want[slot, QW_LAST_US] = 0
+        np.testing.assert_array_equal(way_rows(res.table.rows, 256), want)

@@ -258,6 +258,35 @@ class TestExpressNeverBehindBulk:
         lanes_in_order = [c.lane for c in sched.drain_completions()]
         assert lanes_in_order.index(LANE_EXPRESS) < lanes_in_order.index(LANE_BULK)
 
+    def test_young_express_frames_ship_ahead_of_a_waiting_bulk_close(self):
+        """Express frames younger than their deadline, a bulk batch aged
+        past its own: the bulk close takes milliseconds of this thread, so
+        the express batch ships in the same poll and AHEAD of it, not a
+        beat later behind that step. With no bulk close waiting the
+        deadline still decides."""
+        engine, _, clock = build_stack(batch_size=8)
+        sched = TieredScheduler(engine, SchedulerConfig(
+            express_batch=64, express_max_wait_us=200.0, bulk_batch=8,
+            bulk_max_wait_us=2000.0, bulk_depth=2), clock=clock)
+        for i in range(3):
+            sched.submit(data_frame(i))
+        clock.advance(3000e-6)  # the bulk frames are past their deadline
+        for i in range(2):
+            sched.submit(discover(mac_of(300 + i), 0x5000 + i))
+        clock.advance(50e-6)  # the express frames are not past theirs
+        sched.poll()
+        assert sched.express.stats.batches == 1
+        assert sched.express.stats.batches_deadline == 1
+        assert sched.bulk.stats.batches == 1
+        sched.flush()
+        lanes_in_order = [c.lane for c in sched.drain_completions()]
+        assert lanes_in_order == [LANE_EXPRESS] * 2 + [LANE_BULK] * 3
+        # no bulk close waiting: young express frames keep filling
+        sched.submit(discover(mac_of(310), 0x5100))
+        clock.advance(50e-6)
+        sched.poll()
+        assert sched.express.stats.batches == 1
+
 
 @pytest.mark.hotpath
 class TestPipelineDepth:
@@ -440,7 +469,8 @@ class TestUpdateDrainCadence:
         assert engine.qos.up.dirty_count() == 0  # drained somewhere...
         slot = engine.qos.up._find(ip_to_u32("10.9.9.9"))
         assert slot is not None
-        dev_row = np.asarray(engine.tables.qos_up.rows)[slot]
+        from bng_tpu.ops.qtable import way_rows
+        dev_row = way_rows(engine.tables.qos_up.rows, engine.qos.up.nbuckets)[slot]
         assert np.array_equal(dev_row, engine.qos.up.rows[slot])  # ...and on device
 
     def test_no_drain_steps_carry_live_dense_config(self):

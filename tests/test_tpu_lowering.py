@@ -11,6 +11,8 @@ process may load the TPU library, and every xdist worker imports every
 test file. Keep all described-chip tests in THIS file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -106,6 +108,25 @@ def test_table_probe_compiles(one_chip):
     compile_for(verify.build_table(REAL_1M), one_chip)
 
 
+def _table_relayouts(compiled, shape: str) -> list[str]:
+    """The `copy` / `reshape` / `transpose` instructions of a compiled
+    program whose result is a u32 array of `shape`: a table moved between
+    physical forms. (`copy-start` / `copy-done`, the compiler's moves
+    between memory spaces, keep the form and are not matched.)"""
+    text = compiled.as_text()
+    assert f"u32[{shape}]" in text, f"no u32[{shape}] in the program"
+    pat = re.compile(r"= u32\[" + re.escape(shape)
+                     + r"\]\{[^}]*\} (copy|reshape|transpose)\(")
+    return [line.strip()[:160] for line in text.splitlines() if pat.search(line)]
+
+
+def test_fused_step_keeps_the_qos_tables_in_one_form(fused_step):
+    """Held as [nbuckets*4, 8] the two QoS tables were copied between three
+    tiled forms every step: eight whole-table ops, 8.7 ms of a 27.0 ms
+    step on a v5e until PR 33. Held [nbuckets/4, 128] (ops/qtable.py) the
+    chip's compiler has no other form to move them to."""
+    assert _table_relayouts(fused_step, f"{(1 << 19) // 4},128") == []
+
 @pytest.fixture(scope="module")
 def sharded_step(topo):
     """1M subscribers hash-sharded four ways over a 2x2 v5e host: the
@@ -127,6 +148,11 @@ def test_sharded_step_compiles_for_four_chips(sharded_step):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
     # the hash-sharded DHCP lookup exchanges keys/results over ICI
     assert "all-to-all" in compiled.as_text()
+
+
+def test_sharded_step_keeps_the_qos_tables_in_one_form(sharded_step):
+    compiled, _ = sharded_step
+    assert _table_relayouts(compiled, f"1,{(1 << 17) // 4},128") == []
 
 
 def test_sharded_step_loops_over_no_session_table(sharded_step):
