@@ -231,6 +231,11 @@ class BNGConfig:
     # server_mac); set a global address when clients reach us via a relay
     dhcpv6_server_ip: str = ""
     slaac_enabled: bool = True
+    # device IPv6 stage (ops/v6.py): a subscriber's IPv6 data frames are
+    # bound (IA_NA /128), policed on its rate plan and forwarded on the
+    # chip; its table is sized from max_subscribers. Off: an IPv6 frame
+    # is judged by antispoof and left to the host
+    ipv6_fastpath: bool = False
     # wire (AF_XDP attach ladder; runtime/xsk.py)
     wire_if: str = ""  # NIC to bind AF_XDP on ("" = in-memory ring only)
     wire_queue: int = 0
@@ -429,6 +434,7 @@ class BNGApp:
             self.sharded_blockers = [name for flag, name in (
                 (cfg.scheduler_enabled, "scheduler"),
                 (cfg.pppoe_enabled, "pppoe"),
+                (cfg.ipv6_fastpath, "ipv6-fastpath"),
                 (cfg.wire_if, "wire"),
                 (cfg.slowpath_workers > 1, "slowpath-fleet")) if flag]
             if self.sharded_blockers:
@@ -826,6 +832,12 @@ class BNGApp:
             pppoe_tables = c["pppoe_tables"] = PPPoEFastPathTables(
                 **_sized(PPPoEServerConfig.max_sessions, "nbuckets"),
                 server_mac=parse_mac(cfg.server_mac))
+        v6_tables = None
+        if cfg.ipv6_fastpath and cfg.shards <= 1:
+            from bng_tpu.runtime.tables import V6FastPathTables
+
+            v6_tables = c["v6_tables"] = V6FastPathTables(
+                c["antispoof"], **_sized(cfg.max_subscribers, "nbuckets"))
         if cfg.shards > 1:
             # the cluster IS the dataplane: drive_once feeds its steered
             # ring loop; the slow path is attached per beat (10b)
@@ -834,7 +846,7 @@ class BNGApp:
             c["engine"] = Engine(
                 fastpath=fastpath, nat=nat, qos=qos,
                 antispoof=c["antispoof"],
-                garden=garden_tables, pppoe=pppoe_tables,
+                garden=garden_tables, pppoe=pppoe_tables, v6=v6_tables,
                 batch_size=cfg.batch_size, slow_path=dhcp.handle_frame,
                 clock=self.clock)
             self.log.info("engine built", batch_size=cfg.batch_size,
@@ -1080,6 +1092,29 @@ class BNGApp:
                 self._slow_path = demux
             else:
                 c["engine"].slow_path = demux
+            if v6_tables is not None and "dhcpv6" in c:
+                # an IA_NA lease reaches the device tables as a DHCPv4
+                # lease reaches the fast-path cache. A Lease6 carries a
+                # DUID and no MAC: the MAC is the requesting frame's,
+                # which the demux has in hand (None for a relayed
+                # message: the relay's MAC is not the subscriber's)
+                from bng_tpu.ops.dhcp import AV_IP
+
+                def _v6_lease(lease, _demux=demux, _fp=fastpath):
+                    mac = _demux.dhcpv6_requester
+                    if lease.is_pd or mac is None:
+                        return
+                    sub = _fp.get_subscriber(mac)
+                    v6_tables.bind(mac, lease.address,
+                                   ipv4=int(sub[AV_IP]) if sub is not None
+                                   else 0)
+
+                def _v6_release(lease):
+                    if not lease.is_pd:
+                        v6_tables.unbind(lease.address)
+
+                c["dhcpv6"].on_lease = _v6_lease
+                c["dhcpv6"].on_release = _v6_release
 
         # 10b2. slow-path fleet: shard DHCPv4 across N shared-nothing
         # workers (control/fleet.py). Workers own per-worker lease
@@ -1103,6 +1138,7 @@ class BNGApp:
         if cfg.slowpath_workers > 1:
             blockers = [name for flag, name in (
                 (cfg.pppoe_enabled, "pppoe"),
+                (cfg.ipv6_fastpath, "ipv6-fastpath"),
                 (cfg.shards > 1, "sharded")) if flag]
             if blockers:
                 # more than a log line: the degradation is exported as
@@ -2039,6 +2075,8 @@ class BNGApp:
             self._tick_locked(now)
 
     def _tick_locked(self, now: float) -> None:
+        from bng_tpu.telemetry import spans as tele
+
         c = self.components
         ha = c.get("ha")
         if ha is not None and hasattr(ha, "tick"):  # StandbySyncer only
@@ -2056,8 +2094,6 @@ class BNGApp:
         # no wire to write to.
         pppoe = c.get("pppoe")
         if pppoe is not None:
-            from bng_tpu.telemetry import spans as tele
-
             t0 = tele.t()  # a walk over every session: a beat waits for it
             for frame in pppoe.tick(now):
                 if ring is not None:
@@ -2077,7 +2113,9 @@ class BNGApp:
             budget = self.config.expire_batch or None
             c["dhcp"].cleanup_expired(int(now), max_reaps=budget)
             if c.get("dhcpv6") is not None:
+                t0 = tele.t()  # a walk over every lease: a beat waits for it
                 c["dhcpv6"].cleanup_expired(now, max_reaps=budget)
+                tele.lap(tele.SLOW, t0)
             if "cluster" in c:
                 c["cluster"].expire(int(now))
             else:
@@ -2206,6 +2244,13 @@ class BNGApp:
                 "auth_failures": pppoe.stats.auth_failure,
                 "device": {"decap": int(eng.stats.pppoe[0]),
                            "encap": int(eng.stats.pppoe[1])}}
+        v6_tables = self.components.get("v6_tables")
+        if v6_tables is not None and eng is not None:
+            fwd_up, fwd_down, miss, ctrl = (int(x) for x in eng.stats.v6)
+            out["ipv6_fastpath"] = {
+                "bound": v6_tables.by_addr.count,
+                "device": {"fwd_up": fwd_up, "fwd_down": fwd_down,
+                           "miss": miss, "ctrl": ctrl}}
         nat = self.components.get("nat")
         if nat is not None:  # registered only when nat_enabled
             out["nat"] = {"sessions": nat.sessions.count,

@@ -503,6 +503,8 @@ class DHCPv6Server:
             if lease is not None and self.addr_pool is not None:
                 # do NOT return to free list (conflict): just forget it
                 self.addr_pool._allocated.pop(lease.address, None)
+            if lease is not None and self.on_release:
+                self.on_release(lease)  # the binding goes as on a release
         r.add_status(p6.STATUS_SUCCESS, "declined")
         return r
 
@@ -524,6 +526,21 @@ class DHCPv6Server:
             self.addr_pool.release(lease.address)
         if self.on_release:
             self.on_release(lease)
+
+    def adopt_na_leases(self, duids, addresses, expiry: float,
+                        iaid: int = 1) -> int:
+        """Commit many IA_NA bindings at once (a warm restart, a takeover,
+        a provisioning run): each address is taken out of the pool and
+        held as a granted lease, so the expiry sweep walks what a
+        deployment's would. No lease hook fires: the caller publishes the
+        bindings in bulk. Returns how many the pool could hold."""
+        held = 0
+        for duid, addr in zip(duids, addresses):
+            if self.addr_pool.allocate_specific(addr):
+                self.leases[(duid, iaid, False)] = Lease6(
+                    duid, iaid, addr, 128, expiry)
+                held += 1
+        return held
 
     def cleanup_expired(self, now: float | None = None,
                         max_reaps: int | None = None) -> int:

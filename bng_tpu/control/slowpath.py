@@ -46,6 +46,11 @@ class SlowPathDemux:
         # CHAP-Success + IPCP Conf-Req); the ring's slow contract is one
         # inline reply, the rest queue here for drain_pending()
         self._pending: list[bytes] = []
+        # source MAC of the frame whose DHCPv6 message the server is
+        # handling right now, for a lease hook (a Lease6 has only the
+        # DUID); None outside a call and for a relayed message, whose
+        # frame is the relay's
+        self.dhcpv6_requester: bytes | None = None
 
     def __call__(self, frame: bytes) -> bytes | None:
         if len(frame) < 14:
@@ -112,7 +117,14 @@ class SlowPathDemux:
         payload = frame[udp + 8 : udp + udp_len]
         if not payload:
             return None
-        reply = self.dhcpv6.handle_message(payload)
+        from bng_tpu.control.dhcpv6.protocol import RELAY_FORW, RELAY_REPL
+
+        if payload[0] != RELAY_FORW:
+            self.dhcpv6_requester = frame[6:12]
+        try:
+            reply = self.dhcpv6.handle_message(payload)
+        finally:
+            self.dhcpv6_requester = None
         if reply is None:
             return None
         self.stats["dhcp6"] += 1
@@ -122,8 +134,6 @@ class SlowPathDemux:
                              b"\x02\xbb\x00\x00\x00\x01")
         # RFC 8415 §7.2: clients listen on 546, RELAY AGENTS on 547 — a
         # Relay-Reply framed to 546 would never reach the relay's socket
-        from bng_tpu.control.dhcpv6.protocol import RELAY_REPL
-
         dport = (DHCP6_SERVER_PORT if reply and reply[0] == RELAY_REPL
                  else DHCP6_CLIENT_PORT)
         return packets.udp6_packet(server_mac, client_mac,

@@ -46,6 +46,21 @@ class AntispoofResult(NamedTuple):
     dropped: jax.Array  # [B] bool
     violation: jax.Array  # [B] bool (includes log-only violations)
     stats: jax.Array  # [ANTISPOOF_NSTATS] uint32
+    # what the `v6` stage of ops/pipeline.py takes from the binding row
+    # this kernel gathered anyway; None unless the parse read `dst6`
+    v6_bound: jax.Array | None = None  # [B] bool: IPv6 data, source == the row's /128
+    v6_ctrl: jax.Array | None = None  # [B] bool: IPv6 control (is_v6_control)
+    bound_v4: jax.Array | None = None  # [B] uint32: the row's AB_IPV4 (0: no row)
+
+
+def is_v6_control(src6: jax.Array, dst6: jax.Array) -> jax.Array:
+    """IPv6 frames that can never carry a bound global /128 as source and
+    still have to reach the host: source in fe80::/10 or ::, destination in
+    ff00::/8 or fe80::/10 (RS, NS, DHCPv6 SOLICIT to ff02::1:2). [B, 4]
+    big-endian words each -> [B] bool."""
+    link_local = lambda a: (a[:, 0] >> 22) == 0x3FA  # noqa: E731
+    return (link_local(src6) | jnp.all(src6 == 0, axis=1)
+            | ((dst6[:, 0] >> 24) == 0xFF) | link_local(dst6))
 
 
 def antispoof_kernel(
@@ -97,6 +112,16 @@ def antispoof_kernel(
     # loose mode with no binding allows (antispoof.c:273-277)
     v6_allowed = jnp.where(v6_valid, v6_match, mode == MODE_LOOSE)
     v6_viol = parsed.is_ipv6 & ~disabled & ~v6_allowed
+    v6 = {}
+    if parsed.dst6 is not None:
+        # a program that forwards IPv6: control is no violation (a
+        # subscriber has to be able to ask for the lease that binds it),
+        # and the pipeline gets the match and the row's QoS key
+        with jax.named_scope("v6"):
+            ctrl = parsed.is_ipv6 & is_v6_control(src6_words, parsed.dst6)
+            v6_viol = v6_viol & ~ctrl
+            v6 = dict(v6_bound=parsed.is_ipv6 & ~ctrl & v6_valid & v6_match,
+                      v6_ctrl=ctrl, bound_v4=res.vals[:, AB_IPV4])
     v6_drop = v6_viol & (mode != MODE_LOG_ONLY)
 
     dropped = v4_drop | v6_drop
@@ -110,4 +135,5 @@ def antispoof_kernel(
     stats = stats.at[AST_V6_VIOL].add(jnp.sum(v6_drop, dtype=jnp.uint32))
     stats = stats.at[AST_LOGGED].add(jnp.sum(violation & log_on, dtype=jnp.uint32))
 
-    return AntispoofResult(dropped=dropped, violation=violation & log_on, stats=stats)
+    return AntispoofResult(dropped=dropped, violation=violation & log_on,
+                           stats=stats, **v6)

@@ -18,7 +18,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from bng_tpu.ops.bytes import be16_at, be32_at, u8_at
+from bng_tpu.ops.bytes import be16_at, be32_at, u8_at, window_at
 
 ETH_P_IP = 0x0800
 ETH_P_IPV6 = 0x86DD
@@ -46,7 +46,7 @@ class Parsed(NamedTuple):
     vlan_offset: jax.Array  # int32: 0 / 4 / 8
     # L3 (IPv4)
     is_ipv4: jax.Array  # bool: ethertype==0x0800 and header in bounds
-    is_ipv6: jax.Array  # bool (antispoof needs the flag; no v6 L4 parse)
+    is_ipv6: jax.Array  # bool: ethertype 0x86DD, fixed header in bounds; no v6 L4 parse
     l3_off: jax.Array  # int32: 14 + vlan_offset
     ihl_bytes: jax.Array  # int32
     total_len: jax.Array  # uint32 (IP total length field)
@@ -62,6 +62,9 @@ class Parsed(NamedTuple):
     src_port: jax.Array  # uint32 (ICMP: echo id for egress tracking)
     dst_port: jax.Array
     tcp_flags: jax.Array  # uint32 (byte 13 of TCP header; 0 otherwise)
+    # IPv6 destination, [B, 4] uint32 big-endian words; None unless the
+    # parse was asked for it (the `v6` stage of ops/pipeline.py)
+    dst6: jax.Array | None = None
 
 
 def mac_words_at(pkt, off):
@@ -91,8 +94,28 @@ def eth_vlan(pkt: jax.Array) -> tuple[jax.Array, jax.Array]:
     return vlan_offset, ethertype
 
 
-def parse_batch(pkt: jax.Array, length: jax.Array) -> Parsed:
-    """Parse [B, L] uint8 packets with [B] uint32 actual lengths."""
+# the values `l3_off` takes: no tag, one tag, QinQ
+L3_BASES = (14, 18, 22)
+
+
+def words_be(b: jax.Array) -> jax.Array:
+    """[B, 4k] uint8 -> [B, k] uint32 big-endian words."""
+    w = b.astype(jnp.uint32).reshape(b.shape[0], -1, 4)
+    return (w[:, :, 0] << 24) | (w[:, :, 1] << 16) | (w[:, :, 2] << 8) | w[:, :, 3]
+
+
+def ipv6_dst_words(pkt: jax.Array, l3_off: jax.Array) -> jax.Array:
+    """The IPv6 destination (16 bytes at `l3_off + 24`) as [B, 4] words:
+    a select among three static slices, since `l3_off` takes three values
+    (no per-lane 16-byte gather, ops/bytes.py)."""
+    return words_be(window_at(pkt, l3_off + 24,
+                              tuple(b + 24 for b in L3_BASES), 16))
+
+
+def parse_batch(pkt: jax.Array, length: jax.Array, v6: bool = False) -> Parsed:
+    """Parse [B, L] uint8 packets with [B] uint32 actual lengths. `v6`
+    (static) also reads the IPv6 destination, for a program that forwards
+    IPv6 (`Parsed.dst6`); without it no v6 address leaves the parser."""
     B = pkt.shape[0]
     zero32 = jnp.zeros((B,), dtype=jnp.int32)
     length = length.astype(jnp.uint32)
@@ -149,6 +172,11 @@ def parse_batch(pkt: jax.Array, length: jax.Array) -> Parsed:
     dst_port = jnp.where(is_icmp, icmp_id, jnp.where(is_udp | is_tcp, dp, 0))
     tcp_flags = jnp.where(is_tcp, u8_at(pkt, l4_off + 13), 0)
 
+    dst6 = None
+    if v6:
+        with jax.named_scope("v6"):
+            dst6 = ipv6_dst_words(pkt, l3_off)
+
     return Parsed(
         dst_mac_hi=dst_mac_hi,
         dst_mac_lo=dst_mac_lo,
@@ -176,4 +204,5 @@ def parse_batch(pkt: jax.Array, length: jax.Array) -> Parsed:
         src_port=src_port,
         dst_port=dst_port,
         tcp_flags=tcp_flags,
+        dst6=dst6,
     )

@@ -83,6 +83,10 @@ class PipelineTables(NamedTuple):
     tap_filters: jax.Array | None = None  # [F, 4] uint32
     tap_config: jax.Array | None = None  # [2] uint32
     route: TableState | None = None
+    # IPv6 forwarding (ops/v6.py): bound /128 -> the subscriber's IPv4
+    # address. None = no `v6` stage compiled in: an IPv6 frame is judged
+    # by antispoof and otherwise left to the host, as before the stage.
+    v6_by_addr: TableState | None = None
 
 
 class PipelineGeom(NamedTuple):
@@ -94,6 +98,7 @@ class PipelineGeom(NamedTuple):
     pppoe: TableGeom | None = None
     tap: TableGeom | None = None
     route: TableGeom | None = None
+    v6: TableGeom | None = None
 
 
 class PipelineResult(NamedTuple):
@@ -116,10 +121,11 @@ class PipelineResult(NamedTuple):
     # (engine mirror_sink) extracts wid != 0 lanes for RecordCC/HI3.
     mirror: jax.Array | None = None
     edge_stats: jax.Array | None = None  # [EDGE_NSTATS] when edge on
+    v6_stats: jax.Array | None = None  # [V6_NSTATS] when the v6 stage is on
 
 
 # Each stage below runs under a `jax.named_scope` (parse, antispoof, dhcp,
-# garden, nat44, qos, edge, pppoe, rewrite). Metadata only: the HLO and its
+# garden, nat44, qos, edge, pppoe, v6, rewrite). Metadata only: the HLO and its
 # op names are the same, and a recorded device trace can be summed by stage
 # (`python -m bng_tpu.utils.profiling <trace dir>`).
 def pipeline_step(
@@ -153,8 +159,9 @@ def pipeline_step(
             pkt = jnp.where(pppoe_dec.done[:, None], pppoe_dec.out_pkt, pkt)
             length = jnp.where(pppoe_dec.done, pppoe_dec.out_len, length)
 
+    v6_on = tables.v6_by_addr is not None
     with jax.named_scope("parse"):
-        parsed = parse_batch(pkt, length)
+        parsed = parse_batch(pkt, length, v6=v6_on)
 
     # --- antispoof (TC ingress on access side; antispoof.c:188-293) ---
     with jax.named_scope("antispoof"):
@@ -170,6 +177,16 @@ def pipeline_step(
     # DHCP traffic bypasses antispoof (XDP-before-TC for TX; DISCOVER src
     # 0.0.0.0 must reach the slow path)
     spoof_drop = spoof_drop & ~dhcp.is_dhcp
+
+    # --- IPv6 beside the NAT'd IPv4 (ops/v6.py): which lanes are bound
+    # IPv6 data, and the v4 address that names each one's QoS buckets ---
+    v6 = None
+    if v6_on:
+        from bng_tpu.ops.v6 import v6_lanes, v6_stats
+
+        with jax.named_scope("v6"):
+            v6 = v6_lanes(parsed, spoof, from_access, tables.v6_by_addr,
+                          geom.v6)
 
     # --- walled-garden gate (device-side; BEYOND the reference, whose
     # garden maps have no consuming bpf program — ops/garden.py) ---
@@ -195,15 +212,23 @@ def pipeline_step(
 
     # --- QoS (TC; qos_ratelimit.c:126-222) ---
     # upload: access-side lanes keyed by src ip (qos_ingress_prog :178)
+    # a subscriber's v6 bytes draw on the buckets its v4 address names,
+    # in the same call and the same sort as its v4 bytes
+    up_key, up_on = parsed.src_ip, from_access & parsed.is_ipv4 & ~dhcp.is_dhcp
+    if v6 is not None:
+        up_key, up_on = jnp.where(v6.up, v6.qos_key, up_key), up_on | v6.up
     with jax.named_scope("qos"):
-        up = qos_kernel(parsed.src_ip, length, from_access & parsed.is_ipv4 & ~dhcp.is_dhcp,
-                        tables.qos_up, geom.qos, now_us)
+        up = qos_kernel(up_key, length, up_on, tables.qos_up, geom.qos, now_us)
     # download: core-side lanes keyed by POST-DNAT dst ip (the subscriber
     # address — after DNAT the dst is the private ip, qos_egress_prog :126).
     # Read it from the rewritten bytes: covers translated and untouched lanes.
     with jax.named_scope("qos"):
         dnat_dst = B_.be32_at(nat.out_pkt, parsed.l3_off + 16)
-        down = qos_kernel(dnat_dst, length, ~from_access & parsed.is_ipv4,
+        down_key, down_on = dnat_dst, ~from_access & parsed.is_ipv4
+        if v6 is not None:
+            down_key = jnp.where(v6.down, v6.qos_key, down_key)
+            down_on = down_on | v6.down
+        down = qos_kernel(down_key, length, down_on,
                           tables.qos_down, geom.qos, now_us)
     qos_drop = (up.dropped & from_access) | (down.dropped & ~from_access)
 
@@ -266,6 +291,10 @@ def pipeline_step(
             # an encapsulated frame forwards even when NAT left it untouched
             # (routed/IPoE-free deployments still need the PPP framing)
             fwd = fwd | enc_done
+        if v6 is not None:
+            # a bound IPv6 lane forwards with its bytes untouched (NAT
+            # never translates one: `data_pkt` holds the frame as it came)
+            fwd = fwd | (v6.up | v6.down)
         verdict = jnp.where(
             dhcp_tx, VERDICT_TX,
             jnp.where(drop, VERDICT_DROP,
@@ -301,4 +330,6 @@ def pipeline_step(
                                         else pppoe_enc.stats)),
         mirror=mirror,
         edge_stats=edge_stats,
+        v6_stats=(None if v6 is None
+                  else v6_stats(v6, verdict == VERDICT_FWD)),
     )

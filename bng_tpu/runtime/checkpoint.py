@@ -225,7 +225,7 @@ def _denamespace(prefix: str, arrays: dict) -> dict:
 def build_checkpoint(seq: int, now: float, *, engine=None, scheduler=None,
                      fastpath=None, nat=None, qos=None, antispoof=None,
                      garden=None, pppoe=None, edge=None, dhcp=None, ha=None,
-                     fleet=None, cluster_plan=None,
+                     fleet=None, cluster_plan=None, v6=None,
                      node_id: str = "") -> Checkpoint:
     """Collect a consistent snapshot of the authoritative state.
 
@@ -244,6 +244,7 @@ def build_checkpoint(seq: int, now: float, *, engine=None, scheduler=None,
         garden = garden if garden is not None else engine.garden
         pppoe = pppoe if pppoe is not None else engine.pppoe
         edge = edge if edge is not None else getattr(engine, "edge", None)
+        v6 = v6 if v6 is not None else getattr(engine, "v6", None)
         if scheduler is not None:
             scheduler.quiesce()
         else:
@@ -290,6 +291,10 @@ def build_checkpoint(seq: int, now: float, *, engine=None, scheduler=None,
         m, a = edge.checkpoint_state()
         meta["components"]["edge"] = m
         arrays.update(_ns("edge", a))
+    if v6 is not None:
+        m, a = v6.checkpoint_state()
+        meta["components"]["v6"] = m
+        arrays.update(_ns("v6", a))
     if dhcp is not None:
         meta["components"]["dhcp"] = dhcp.export_leases()
     if ha is not None:
@@ -422,6 +427,12 @@ def _verify_components(ckpt: Checkpoint, comps: dict, targets: dict) -> None:
                          comps["edge"]["geom"][t], f"edge.{t}")
         _check_dense(a, "tap_filters", ed.tap_filters, "edge")
         _check_dense(a, "tap_config", ed.tap_config, "edge")
+    if "v6" in comps:
+        a = _denamespace("v6", ckpt.arrays)
+        _check_table(targets["v6"].by_addr,
+                     {k: a.get(f"by_addr.{k}")
+                      for k in ("keys", "vals", "used")},
+                     comps["v6"]["geom"]["by_addr"], "v6.by_addr")
     # dry-parse the dict-driven components: their meta is consumed
     # during mutation, so a parse fault there must be caught HERE or the
     # reject would leave the process half-hydrated
@@ -466,7 +477,8 @@ def _verify_components(ckpt: Checkpoint, comps: dict, targets: dict) -> None:
 def restore_checkpoint(ckpt: Checkpoint, *, engine=None, fastpath=None,
                        nat=None, qos=None, antispoof=None, garden=None,
                        pppoe=None, edge=None, dhcp=None, ha=None,
-                       fleet=None, cluster_coord=None) -> dict[str, int]:
+                       fleet=None, cluster_coord=None,
+                       v6=None) -> dict[str, int]:
     """Hydrate the host mirrors from a decoded checkpoint and re-upload.
 
     Reject-on-mismatch: every table component present in the checkpoint
@@ -491,6 +503,7 @@ def restore_checkpoint(ckpt: Checkpoint, *, engine=None, fastpath=None,
         garden = garden if garden is not None else engine.garden
         pppoe = pppoe if pppoe is not None else engine.pppoe
         edge = edge if edge is not None else getattr(engine, "edge", None)
+        v6 = v6 if v6 is not None else getattr(engine, "v6", None)
     comps = dict(ckpt.meta.get("components", {}))
     for name in _PAYLOAD_JSON_COMPONENTS:
         if name in comps:
@@ -498,7 +511,7 @@ def restore_checkpoint(ckpt: Checkpoint, *, engine=None, fastpath=None,
     targets = {"fastpath": fastpath, "nat": nat, "qos": qos,
                "antispoof": antispoof, "garden": garden, "pppoe": pppoe,
                "edge": edge, "dhcp": dhcp, "ha": ha, "fleet": fleet,
-               "cluster_plan": cluster_coord}
+               "cluster_plan": cluster_coord, "v6": v6}
     missing = []
     for name in comps:
         tgt = targets.get(name)
@@ -558,6 +571,10 @@ def restore_checkpoint(ckpt: Checkpoint, *, engine=None, fastpath=None,
             got = edge.restore_state(comps["edge"],
                                      _denamespace("edge", ckpt.arrays))
             rows.update({f"edge.{k}": v for k, v in got.items()})
+        if "v6" in comps:
+            got = v6.restore_state(comps["v6"],
+                                   _denamespace("v6", ckpt.arrays))
+            rows.update({f"v6.{k}": v for k, v in got.items()})
         if "dhcp" in comps or "fleet" in comps:
             worker_books = (list(comps["fleet"]["workers"])
                             if "fleet" in comps else [])

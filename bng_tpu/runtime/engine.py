@@ -50,13 +50,16 @@ from bng_tpu.ops.pipeline import (
     pipeline_step,
 )
 from bng_tpu.ops.qos import QOS_NSTATS
+from bng_tpu.ops.v6 import (V6_NSTATS, V6ST_CTRL, V6ST_FWD_DOWN, V6ST_FWD_UP,
+                            V6ST_MISS)
 from bng_tpu.ops.antispoof import ANTISPOOF_WORDS
 from bng_tpu.ops.qtable import HostQTable, QTableGeom, apply_qupdate
 from bng_tpu.ops.table import HostTable, TableGeom, apply_update
 from bng_tpu.runtime import hostpath
 from bng_tpu.runtime.ring import FLAG_DHCP_CTRL
 from bng_tpu.runtime.tables import (FastPathTables, PPPoEFastPathTables,
-                                    apply_fastpath_updates, mac_key_rows)
+                                    V6FastPathTables, apply_fastpath_updates,
+                                    mac_key_rows)
 from bng_tpu.utils.structlog import ErrorLog, SlowPathErrorLog
 
 # default per-lane packet slot: a full MTU frame (1500) + headroom for
@@ -69,8 +72,9 @@ PKT_SLOT = 1536
 def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
     """upd layout: 7 mandatory entries + optional named tails — garden
     (garden_upd, allowed_rows), then pppoe (sid_upd, ip_upd), then edge
-    (tap_upd, tap_filters, tap_config, route_upd) — each present exactly
-    when the corresponding device stage is compiled in."""
+    (tap_upd, tap_filters, tap_config, route_upd), then v6 (by_addr_upd) —
+    each present exactly when the corresponding device stage is compiled
+    in."""
     fp_upd, nat_upd, qup, qdown, sp_upd, sp_ranges, sp_config, *tails = upd
     tails = list(tails)
     g_state, g_allowed = tables.garden, tables.garden_allowed
@@ -88,6 +92,9 @@ def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
         e_filters = tails.pop(0)
         e_config = tails.pop(0)
         e_route = apply_update(e_route, tails.pop(0))
+    v6_by_addr = tables.v6_by_addr
+    if v6_by_addr is not None:
+        v6_by_addr = apply_update(v6_by_addr, tails.pop(0))
     return PipelineTables(
         dhcp=apply_fastpath_updates(tables.dhcp, fp_upd),
         nat=apply_nat_updates(tables.nat, nat_upd),
@@ -105,6 +112,7 @@ def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
         tap_filters=e_filters,
         tap_config=e_config,
         route=e_route,
+        v6_by_addr=v6_by_addr,
     )
 
 
@@ -132,7 +140,8 @@ def _apply_updates_jit(geom: PipelineGeom, has_garden: bool, has_pppoe: bool,
     bulk drain's fastpath entry is always the empty no-op update, and
     the authoritative chain may live on the express lane's own device —
     including it would force a cross-device program. geom rides in the
-    key only to separate engines whose update pytrees differ."""
+    key only to separate engines whose update pytrees differ (the v6
+    tail's presence is `geom.v6`'s)."""
     del geom, has_garden, has_pppoe, has_edge
 
     def apply_only(tables, upd):
@@ -154,6 +163,9 @@ def _apply_updates_jit(geom: PipelineGeom, has_garden: bool, has_pppoe: bool,
             e_filters = tails.pop(0)
             e_config = tails.pop(0)
             e_route = apply_update(e_route, tails.pop(0))
+        v6_by_addr = tables.v6_by_addr
+        if v6_by_addr is not None:
+            v6_by_addr = apply_update(v6_by_addr, tails.pop(0))
         from bng_tpu.control.nat import apply_nat_updates
 
         return tables._replace(
@@ -165,7 +177,7 @@ def _apply_updates_jit(geom: PipelineGeom, has_garden: bool, has_pppoe: bool,
             garden=g_state, garden_allowed=g_allowed,
             pppoe_by_sid=p_sid, pppoe_by_ip=p_ip,
             tap=e_tap, tap_filters=e_filters, tap_config=e_config,
-            route=e_route)
+            route=e_route, v6_by_addr=v6_by_addr)
 
     return jax.jit(apply_only, donate_argnums=(0,))
 
@@ -280,6 +292,9 @@ class EngineStats:
     pppoe: np.ndarray = field(default_factory=lambda: np.zeros(PPPOE_NSTATS, dtype=np.uint64))
     # device edge protection: tap mirror + route rewrite (edge/ops.py EST_*)
     edge: np.ndarray = field(default_factory=lambda: np.zeros(4, dtype=np.uint64))
+    # device IPv6 stage: forwarded up, forwarded down, downstream miss,
+    # control passed to the host (ops/v6.py V6ST_*)
+    v6: np.ndarray = field(default_factory=lambda: np.zeros(V6_NSTATS, dtype=np.uint64))
     batches: int = 0
     tx: int = 0
     fwd: int = 0
@@ -352,16 +367,22 @@ class AntispoofTables:
         row[AB_MODE] = mode
         self.bindings.insert([hi, lo], row)
 
-    def bulk_add_bindings(self, macs_u64, ipv4s, mode: int) -> None:
-        """Vectorized v4 binding install for table builds at the
-        1M-subscriber scale (MACs unique and not already bound)."""
-        from bng_tpu.ops.antispoof import AB_IPV4, AB_MODE, AB_VALIDS, VALID_V4
+    def bulk_add_bindings(self, macs_u64, ipv4s, mode: int,
+                          ipv6_words=None) -> None:
+        """Vectorized binding install for table builds at the
+        1M-subscriber scale (MACs unique and not already bound): the v4
+        address, and with `ipv6_words` [N, 4] each MAC's /128 beside it."""
+        from bng_tpu.ops.antispoof import (AB_IPV4, AB_MODE, AB_V6_0,
+                                           AB_VALIDS, VALID_V4, VALID_V6)
 
         keys = mac_key_rows(macs_u64)
         rows = np.zeros((len(keys), ANTISPOOF_WORDS), dtype=np.uint32)
         rows[:, AB_IPV4] = ipv4s
         rows[:, AB_VALIDS] = VALID_V4
         rows[:, AB_MODE] = mode
+        if ipv6_words is not None:
+            rows[:, AB_V6_0:AB_V6_0 + 4] = ipv6_words
+            rows[:, AB_VALIDS] |= VALID_V6
         self.bindings.bulk_insert(keys, rows)
 
     def add_binding_v6(self, mac, ipv6_words: list[int], mode: int) -> None:
@@ -450,6 +471,7 @@ class Engine:
         device_tables: "PipelineTables | None" = None,
         edge: "EdgeTables | None" = None,
         mirror_sink: Callable[[int, bytes, int], None] | None = None,
+        v6: "V6FastPathTables | None" = None,
     ):
         self.fastpath = fastpath
         self.nat = nat
@@ -468,6 +490,10 @@ class Engine:
         # the compiled pipeline; the composition root passes EdgeTables
         # when intercept/routing programs are wired (edge/compile.py)
         self.edge = edge
+        # None = no IPv6 stage in the compiled pipeline (an IPv6 frame is
+        # judged by antispoof and left to the host); the composition root
+        # passes V6FastPathTables under `bng run --ipv6-fastpath`
+        self.v6 = v6
         # host retire hook for MIRROR-flagged lanes: (lane, frame, wid).
         # The MirrorPump (edge/compile.py) feeds RecordCC/HI3 export here.
         self.mirror_sink = mirror_sink
@@ -506,6 +532,7 @@ class Engine:
             pppoe=self.pppoe.geom if self.pppoe else None,
             tap=self.edge.geom if self.edge else None,
             route=self.edge.geom if self.edge else None,
+            v6=self.v6.geom if self.v6 else None,
         )
         # `device_tables` adopts a prebuilt geometry-identical device
         # pytree (the blue/green standby's snapshot-hydrated chain,
@@ -553,6 +580,7 @@ class Engine:
             tap_config=(jnp.asarray(self.edge.tap_config)
                         if self.edge else None),
             route=(self.edge.route.device_state() if self.edge else None),
+            v6_by_addr=(self.v6.by_addr.device_state() if self.v6 else None),
         )
 
     def resync_tables(self) -> None:
@@ -603,6 +631,8 @@ class Engine:
                self.pppoe.by_ip.make_update(self.pppoe.update_slots))
               if self.pppoe else ()),
             *(self.edge.make_updates() if self.edge else ()),
+            *((self.v6.by_addr.make_update(self.v6.update_slots),)
+              if self.v6 else ()),
         ))
 
     # -- latency-tiered scheduler support (runtime/scheduler.py) ----------
@@ -633,6 +663,8 @@ class Engine:
                self.pppoe.by_ip.make_update(self.pppoe.update_slots))
               if self.pppoe else ()),
             *(self.edge.make_updates() if self.edge else ()),
+            *((self.v6.by_addr.make_update(self.v6.update_slots),)
+              if self.v6 else ()),
         )
 
     def _empty_updates(self):
@@ -658,6 +690,8 @@ class Engine:
                self.pppoe.by_ip.empty_update(self.pppoe.update_slots))
               if self.pppoe else ()),
             *(self.edge.empty_updates() if self.edge else ()),
+            *((self.v6.by_addr.empty_update(self.v6.update_slots),)
+              if self.v6 else ()),
         )
 
     def prefetch_bulk_updates(self):
@@ -1172,6 +1206,12 @@ class Engine:
         es = getattr(res, "edge_stats", None)
         if es is not None:
             self.stats.edge += np.asarray(es, dtype=np.uint64)
+        vs = getattr(res, "v6_stats", None)
+        if vs is not None:
+            vs = np.asarray(vs, dtype=np.uint64)
+            self.stats.v6 += vs
+            tele.v6_lanes(int(vs[V6ST_FWD_UP] + vs[V6ST_FWD_DOWN]),
+                          int(vs[V6ST_MISS]), int(vs[V6ST_CTRL]))
 
     def _run_step(self, pkt, length, fa, now_s, now_us) -> PipelineResult:
         """Dispatch + fold (the synchronous step both process paths use)."""
@@ -1501,6 +1541,8 @@ class Engine:
         if self.edge is not None:
             out["edge/tap"] = self.edge.tap
             out["edge/route"] = self.edge.route
+        if self.v6 is not None:
+            out["v6/by_addr"] = self.v6.by_addr
         return out
 
     def pending_dirty(self) -> int:

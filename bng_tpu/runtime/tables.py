@@ -40,6 +40,8 @@ from bng_tpu.ops.dhcp import (
     DHCPGeom,
     DHCPTables,
 )
+from bng_tpu.ops.antispoof import (AB_IPV4, AB_MODE, AB_V6_0, AB_VALIDS,
+                                    VALID_V6)
 from bng_tpu.ops.pppoe import (
     PPPOE_WORDS,
     PS_IP,
@@ -48,6 +50,7 @@ from bng_tpu.ops.pppoe import (
     PS_SESSION_ID,
 )
 from bng_tpu.ops.table import HostTable, TableGeom, TableUpdate, apply_update
+from bng_tpu.ops.v6 import V6_WORDS, VA_IPV4, VA_MAC_HI, VA_MAC_LO
 from bng_tpu.utils.net import mac_to_u64, split_u64
 
 
@@ -378,3 +381,104 @@ class PPPoEFastPathTables:
                 meta["geom"][t])
         self.server_mac[:] = arrays["server_mac"]
         return rows
+
+
+def v6_words(addr) -> np.ndarray:
+    """A 16-byte IPv6 address -> 4 big-endian uint32 words."""
+    return np.frombuffer(bytes(addr), dtype=">u4").astype(np.uint32)
+
+
+class V6FastPathTables:
+    """Host side of the device IPv6 stage (ops/v6.py): a subscriber's IA_NA
+    /128 lives in two places, and this is the one writer of both — the v6
+    words of the MAC's antispoof binding row (the upstream check, and the
+    row's IPv4 address as QoS key) and the by-address table (the downstream
+    lookup, whose value is that IPv4 address).
+
+    The DHCPv6 server's lease hooks land here (`bind` / `unbind`: the
+    slow-path-populates-cache shape of pkg/antispoof/manager.go:200-283
+    AddBindingV6), and `bulk_bind` is the same write for a provisioning run
+    or a warm restart at the subscriber tables' size.
+    """
+
+    def __init__(self, antispoof, nbuckets: int = 1 << 12, stash: int = 64,
+                 update_slots: int = 128):
+        self.antispoof = antispoof  # runtime.engine.AntispoofTables
+        self.by_addr = HostTable(nbuckets, key_words=4, val_words=V6_WORDS,
+                                 stash=stash, name="v6_by_addr")
+        self.geom = TableGeom(nbuckets, stash)
+        self.update_slots = update_slots
+
+    @staticmethod
+    def _addr_rows(ipv4s, macs_u64) -> np.ndarray:
+        rows = np.zeros((len(macs_u64), V6_WORDS), dtype=np.uint32)
+        rows[:, VA_IPV4] = ipv4s
+        rows[:, [VA_MAC_HI, VA_MAC_LO]] = mac_key_rows(macs_u64)
+        return rows
+
+    def bind(self, mac, addr: bytes, ipv4: int = 0) -> None:
+        """One IA_NA lease: the MAC's binding row gets the /128 (keeping
+        the row's mode, or the default mode for a MAC bound for the first
+        time) and the by-address row appears. A MAC that held another /128
+        is renumbered: the old address stops matching with this update.
+        `ipv4` names the subscriber's QoS buckets where the binding row
+        holds no IPv4 address yet."""
+        key = mac_to_u64(mac) if not isinstance(mac, int) else int(mac)
+        lo, hi = split_u64(key)
+        bindings = self.antispoof.bindings
+        row = bindings.lookup([hi, lo])
+        if row is not None and row[AB_VALIDS] & VALID_V6:
+            self.by_addr.delete(row[AB_V6_0:AB_V6_0 + 4])
+        mode = int(row[AB_MODE]) if row is not None else int(
+            self.antispoof.config[0])
+        words = v6_words(addr)
+        self.antispoof.add_binding_v6(key, words, mode)
+        if row is None or not row[AB_IPV4]:
+            bindings.update_val_words([hi, lo], AB_IPV4, [ipv4])
+        else:
+            ipv4 = int(row[AB_IPV4])
+        self.by_addr.insert(words, self._addr_rows([ipv4], [key])[0])
+
+    def unbind(self, addr: bytes) -> bool:
+        """Release, decline or expiry of one IA_NA lease: the by-address
+        row goes, and the binding row it names loses its v6 words (a row
+        that held nothing else goes whole). False: no such address."""
+        words = v6_words(addr)
+        val = self.by_addr.lookup(words)
+        if val is None:
+            return False
+        self.by_addr.delete(words)
+        key = [val[VA_MAC_HI], val[VA_MAC_LO]]
+        bindings = self.antispoof.bindings
+        row = bindings.lookup(key)
+        if row is not None and np.array_equal(row[AB_V6_0:AB_V6_0 + 4], words):
+            row[AB_V6_0:AB_V6_0 + 4] = 0
+            row[AB_VALIDS] &= ~np.uint32(VALID_V6)
+            if row[AB_VALIDS]:
+                bindings.insert(key, row)
+            else:
+                bindings.delete(key)
+        return True
+
+    def bulk_bind(self, macs_u64, ipv4s, addr_words, mode: int) -> None:
+        """Dual-stack bindings for MACs not bound yet, at the
+        1M-subscriber scale: each antispoof row with both addresses and
+        each by-address row in one vectorized pass. As after any bulk
+        build, the next upload is a whole one (`Engine.resync_tables`)."""
+        addr_words = np.asarray(addr_words, dtype=np.uint32).reshape(-1, 4)
+        self.antispoof.bulk_add_bindings(macs_u64, ipv4s, mode,
+                                         ipv6_words=addr_words)
+        self.by_addr.bulk_insert(addr_words,
+                                 self._addr_rows(ipv4s, macs_u64))
+
+    # -- checkpoint/warm-restart (runtime/checkpoint.py): the binding rows
+    # ride the antispoof component; this is the by-address table --------
+    def checkpoint_state(self) -> tuple[dict, dict]:
+        return ({"geom": {"by_addr": self.by_addr.checkpoint_geom()}},
+                {f"by_addr.{k}": v
+                 for k, v in self.by_addr.checkpoint_arrays().items()})
+
+    def restore_state(self, meta: dict, arrays: dict) -> dict[str, int]:
+        return {"by_addr": self.by_addr.restore_arrays(
+            {k: arrays[f"by_addr.{k}"] for k in ("keys", "vals", "used")},
+            meta["geom"]["by_addr"])}

@@ -42,6 +42,7 @@ class Geometry(NamedTuple):
     max_pools: int = 4
     stash: int = 64
     pppoe_nbuckets: int = 0  # PPPoE session tables; 0 = no PPPoE stage
+    v6_nbuckets: int = 0  # IPv6 by-address table; 0 = no v6 stage
 
 
 TOY = Geometry()
@@ -56,6 +57,9 @@ REAL_1M = Geometry(batch=8192, pkt_slot=1536, sub_nbuckets=1 << 19,
 # the same with the PPPoE stage compiled in, its two session tables sized
 # for an access concentrator's 65,535 sessions (`bng run --pppoe-enabled`)
 REAL_1M_PPPOE = REAL_1M._replace(pppoe_nbuckets=1 << 15)
+# the same with the IPv6 stage compiled in, its by-address table sized for
+# 1,000,000 IA_NA bindings (`bng run --ipv6-fastpath`)
+REAL_1M_V6 = REAL_1M._replace(v6_nbuckets=1 << 19)
 
 
 def compile_for(built, sharding=None):
@@ -155,19 +159,22 @@ def _engine(g: Geometry):
     from bng_tpu.control.nat import NATManager
     from bng_tpu.runtime.engine import (AntispoofTables, Engine, GardenTables,
                                         QoSTables)
-    from bng_tpu.runtime.tables import PPPoEFastPathTables
+    from bng_tpu.runtime.tables import PPPoEFastPathTables, V6FastPathTables
     from bng_tpu.utils.net import ip_to_u32
 
+    spoof = AntispoofTables(nbuckets=g.side_nbuckets, stash=g.stash)
     return Engine(
         _fastpath(g),
         NATManager(public_ips=[ip_to_u32("203.0.113.1")],
                    sessions_nbuckets=g.nat_sessions_nbuckets,
                    sub_nat_nbuckets=g.sub_nat_nbuckets, stash=g.stash),
         qos=QoSTables(nbuckets=g.side_nbuckets),
-        antispoof=AntispoofTables(nbuckets=g.side_nbuckets, stash=g.stash),
+        antispoof=spoof,
         garden=GardenTables(nbuckets=g.side_nbuckets, stash=g.stash),
         pppoe=(PPPoEFastPathTables(nbuckets=g.pppoe_nbuckets, stash=g.stash)
                if g.pppoe_nbuckets else None),
+        v6=(V6FastPathTables(spoof, nbuckets=g.v6_nbuckets, stash=g.stash)
+            if g.v6_nbuckets else None),
         batch_size=g.batch, pkt_slot=g.pkt_slot)
 
 

@@ -183,6 +183,10 @@ class Tracer:
         # punted for a session it does not hold (engine.py _fold_stats);
         # 0 in a program without the stage
         self.pppoe_decap = self.pppoe_encap = self.pppoe_miss = 0
+        # lanes the device IPv6 stage forwarded (both directions), passed
+        # to the host for a destination it does not hold, and passed as
+        # control (engine.py _fold_stats); 0 in a program without the stage
+        self.v6_fwd = self.v6_miss = self.v6_ctrl = 0
         self._frozen: dict | None = None  # sums() as finish() left them
 
     # -- batch records ----------------------------------------------------
@@ -530,6 +534,9 @@ class Tracer:
             "pppoe_decap": int(self.pppoe_decap),
             "pppoe_encap": int(self.pppoe_encap),
             "pppoe_miss": int(self.pppoe_miss),
+            "v6_fwd": int(self.v6_fwd),
+            "v6_miss": int(self.v6_miss),
+            "v6_ctrl": int(self.v6_ctrl),
         }
 
     def write_events(self, path: str) -> None:
@@ -701,6 +708,16 @@ def pppoe_lanes(decap: int, encap: int, miss: int) -> None:
     _ACTIVE.pppoe_decap += decap
     _ACTIVE.pppoe_encap += encap
     _ACTIVE.pppoe_miss += miss
+
+
+def v6_lanes(fwd: int, miss: int, ctrl: int) -> None:
+    """Count one retired step's IPv6 lanes: forwarded, downstream misses,
+    control passed to the host. Disarmed: global load + None compare."""
+    if _ACTIVE is None:
+        return
+    _ACTIVE.v6_fwd += fwd
+    _ACTIVE.v6_miss += miss
+    _ACTIVE.v6_ctrl += ctrl
 
 
 def trigger(reason: str, detail: str = "") -> str | None:

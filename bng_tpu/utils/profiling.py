@@ -20,7 +20,7 @@ import os
 # the `jax.named_scope` names of ops/pipeline.py, ops/express.py, the
 # update scatter (runtime/engine.py) and the sharded step's psums
 SCOPES = ("parse", "antispoof", "dhcp", "garden", "nat44", "qos", "edge",
-          "pppoe", "rewrite", "updates", "stats")
+          "pppoe", "v6", "rewrite", "updates", "stats")
 BEAT = "bng.beat"  # the Tracer's anchor annotation (telemetry/spans.py)
 # stages that are laps of the host thread (the rest are fed durations:
 # lane_wait, device, sojourn; or span batches across beats: total)
@@ -71,9 +71,11 @@ def _events(plane, line_name: str):
 
 
 def _scope_of(op_path: str) -> str:
-    """The first named scope on an op's `tf_op` path."""
-    return next((part for part in op_path.split("/") if part in SCOPES),
-                "(no scope)")
+    """The innermost named scope on an op's `tf_op` path: a scope opened
+    inside another stage's (the v6 destination window inside `parse`, the
+    control test inside `antispoof`) owns what it adds."""
+    return next((part for part in reversed(op_path.split("/"))
+                 if part in SCOPES), "(no scope)")
 
 
 def reduce_trace(trace_dir: str, events_path: str | None = None) -> dict:

@@ -1,0 +1,90 @@
+"""The cell `dualstack-cgnat-1M-wire.flood-64B` in test_benchmark.py's own
+rehearsal directory, through the stand-in tests/conftest.py gives it (the
+fixture's literals lack the cell, and this directory's conftest.py may not
+be edited): its configuration, its kit and its four layer files are found
+by name, at 4,096 dual-stack subscribers of whom 128 are behind NAT.
+tests/test_dualstack_cell_rehearsal.py is the longer rehearsal, past the
+pool's wrap and with both controls. No number from here is a device
+metric, and no position in a `workloads` list is pinned."""
+
+from test_benchmark import BENCH, TINY_CELLS, _run, tiny_dir  # noqa: F401
+
+from benchmark.lib import app as applib
+from benchmark.lib import layers
+
+REAL = "dualstack-cgnat-1M-wire.flood-64B"
+FILES = {"dualstack_step.device_p50_us", "dualstack.loop_us_per_frame",
+         "dualstack.gen_share", "dualstack.beat_p99_us"}
+
+
+def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
+    cell = {w["name"]: w for w in BENCH["workloads"]}[REAL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "dualstack-cgnat-1M-wire", "flood-64B", 1)
+    assert len(cell["why"]) <= 200 and "no frame crossed a link" in cell["why"]
+    cfg = applib.load_named("configs", cell["config"])
+    assert cfg["kit"] == "dualstack" and cfg["reduced"] == ["max_nat_sessions"]
+    assert cfg["architecture"] is None and cfg["chips"] == 1
+    assert cfg["sizes"] == {
+        "subscribers": 1_000_000, "nat_subscribers": 250_000,
+        "flows_per_nat_subscriber": 4, "v6_bindings": 1_000_000,
+        "v6_data_share_pct": 40}
+    assert cfg["argv"] == applib.load_named("configs", "ipoe-cgnat-1M-wire")[
+        "argv"] + ["--ipv6-fastpath"]
+    assert all(k in cfg for k in ("source", "deployment", "assumed", "off",
+                                  "forwarding", "resident"))
+    entry = {c["name"]: c for c in BENCH["configs"]}[cell["config"]]
+    assert entry["source"] == cfg["source"] and len(entry["source"]) <= 200
+    assert entry["reduced"] == cfg["reduced"]
+    named = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
+             if REAL in m["cells"]}
+    assert named == FILES
+    # kinds the pinned counts of span / counter / wire* files let in
+    assert all(m["read"]["kind"] in ("bench_span", "trace_program")
+               and m["cells"] == [REAL] and not m["name"].startswith("wire")
+               for m in layers.layer_files(applib.BENCH_DIR)
+               if m["name"] in FILES)
+    assert {m["name"] for m in BENCH["per_layer"]
+            if REAL in m["workloads"]} == FILES
+    assert all(m["workloads"] == [REAL] and m["moves"] == "served_kpps"
+               for m in BENCH["per_layer"] if m["name"] in FILES)
+    served = {m["name"]: m for m in BENCH["end_to_end"]}["served_kpps"]
+    assert REAL in served["workloads"]
+    kit = applib.load_kit(cfg)
+    assert hasattr(kit, "stage_bytes") and hasattr(kit, "Plain")
+
+
+def test_the_plain_reference_imports_nothing_of_the_device_code():
+    import ast
+
+    kit = applib.load_kit({"kit": "dualstack"})
+    with open(kit.__file__) as f:
+        top = ast.parse(f.read())
+    # at import the kit takes nothing of the program (its provisioning
+    # imports the bulk writers where it calls them, as every kit does)
+    names = {a.name for n in top.body if isinstance(n, ast.Import)
+             for a in n.names} | {n.module for n in top.body
+                                  if isinstance(n, ast.ImportFrom)}
+    assert names and not any(n.startswith("bng_tpu") for n in names), names
+    plain = next(n for n in top.body
+                 if isinstance(n, ast.ClassDef) and n.name == "Plain")
+    assert not [n for n in ast.walk(plain)
+                if isinstance(n, (ast.Import, ast.ImportFrom))]
+    used = {n.value.id for n in ast.walk(plain)
+            if isinstance(n, ast.Attribute) and isinstance(n.value, ast.Name)}
+    assert used <= {"self", "struct", "ipaddress", "src", "dst"}, used
+
+
+def test_the_stand_in_rehearses_traced(tiny_dir, capsys):  # noqa: F811
+    assert TINY_CELLS["tiny-dualstack.flood"][0] == REAL
+    res, out = _run(tiny_dir, capsys, "tiny-dualstack.flood", "--trace", "1")
+    assert res["correct"] is True and res["failed"] == 0, out[-16:]
+    assert any(ln.startswith("cell: ") and ln.endswith("kit=dualstack")
+               for ln in out)
+    got = res["metrics"]
+    assert set(got) == FILES - {"dualstack_step.device_p50_us"}
+    assert all(m["value"] > 0 for m in got.values())
+    said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
+    assert said and "dualstack_step.device_p50_us" in said[0]
+    sample = [ln for ln in out if ln.startswith("check sample: ")][0]
+    assert "IPv6 byte-for-byte" in sample and "none-" not in sample

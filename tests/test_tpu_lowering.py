@@ -20,7 +20,8 @@ import jax
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from bng_tpu.runtime import verify
-from bng_tpu.runtime.verify import REAL_1M, REAL_1M_PPPOE, compile_for
+from bng_tpu.runtime.verify import (REAL_1M, REAL_1M_PPPOE, REAL_1M_V6,
+                                    compile_for)
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -93,6 +94,20 @@ def test_fused_step_with_the_pppoe_stage_fits_and_has_no_while(one_chip):
     compiled = compile_for(verify.build_pipeline(REAL_1M_PPPOE), one_chip)
     assert _device_bytes(compiled) < V5E_HBM_BYTES
     assert _whiles(compiled) == []
+
+
+def test_fused_step_with_the_v6_stage_fits_and_has_no_while(one_chip):
+    """`bng run --ipv6-fastpath` at the 1M geometry, the by-address table
+    sized for 1,000,000 IA_NA bindings: the step with the destination
+    window, one more table probe and QoS keyed for both families. It fits,
+    brings no loop, and moves no table between physical forms."""
+    from bng_tpu.ops.table import nbuckets_for
+
+    assert REAL_1M_V6.v6_nbuckets == nbuckets_for(1_000_000)  # as cli.py sizes it
+    compiled = compile_for(verify.build_pipeline(REAL_1M_V6), one_chip)
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert _whiles(compiled) == []
+    assert _table_relayouts(compiled, f"{(1 << 19) // 4},128") == []
 
 
 @pytest.mark.parametrize("build", [
