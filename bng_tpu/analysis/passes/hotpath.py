@@ -56,6 +56,7 @@ DISPATCH_SCOPE: dict[str, set[str]] = {
     "bng_tpu/runtime/engine.py": {
         "_dispatch_step", "_run_dhcp_batch", "dispatch_scheduled_bulk",
         "_drain_updates", "_make_bulk_updates", "_empty_updates",
+        "_updates", "_drain_with_resync", "_drain_fastpath_updates",
         "_pack_frames", "_dispatch_fault", "_staging",
     },
     "bng_tpu/runtime/scheduler.py": {

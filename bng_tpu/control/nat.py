@@ -59,7 +59,8 @@ from bng_tpu.ops.nat44 import (
     NAT_STATE_CLOSING,
 )
 from bng_tpu.ops.parse import PROTO_ICMP, PROTO_TCP, PROTO_UDP
-from bng_tpu.ops.table import HostTable, TableGeom, TableUpdate, apply_update
+from bng_tpu.ops.table import (HostTable, TableGeom, TableUpdate,
+                               apply_update, placed)
 from bng_tpu.utils.structlog import ErrorLog
 
 # timeouts in seconds (parity: bpf/nat44.c:49-53)
@@ -671,10 +672,16 @@ class NATManager:
             self.sessions.make_update(self.update_slots),
             self.reverse.make_update(self.update_slots),
             self.sub_nat.make_update(self.update_slots),
-            jnp.asarray(self.hairpin),
-            jnp.asarray(self.alg),
-            jnp.asarray(self.config_array()),
+            *self._placed_config(),
         )
+
+    def _placed_config(self) -> tuple:
+        """hairpin / alg / config as every update batch carries them: the
+        device copies, placed again when the host's bytes changed
+        (ops/table.py placed)."""
+        return (placed(self, "hairpin", self.hairpin),
+                placed(self, "alg", self.alg),
+                placed(self, "config", self.config_array()))
 
     # -- checkpoint/warm-restart (runtime/checkpoint.py) ----------------
     _CKPT_TABLES = ("sessions", "reverse", "sub_nat")
@@ -775,14 +782,13 @@ class NATManager:
         """No-op table-delta batch (dirty tracking untouched) for the
         scheduler's no-drain bulk steps; pending session deltas stay
         queued for the next drain-cadence step. The scatter buffers come
-        from the empty_update caches; hairpin/alg/config are re-read per
-        call because the step applies them wholesale (a cached snapshot
-        would revert live NAT config between drains)."""
+        from the empty_update caches; hairpin/alg/config are compared
+        with what was last placed on every call, because the step
+        applies them wholesale (a snapshot taken once would revert live
+        NAT config between drains)."""
         return (
             self.sessions.empty_update(self.update_slots),
             self.reverse.empty_update(self.update_slots),
             self.sub_nat.empty_update(self.update_slots),
-            jnp.asarray(self.hairpin),
-            jnp.asarray(self.alg),
-            jnp.asarray(self.config_array()),
+            *self._placed_config(),
         )

@@ -12,8 +12,6 @@ here; nothing else writes (bngcheck single-writer allowlist).
 from __future__ import annotations
 
 import numpy as np
-import jax
-import jax.numpy as jnp
 
 from bng_tpu.edge.ops import (
     ROUTE_WORDS,
@@ -33,7 +31,7 @@ from bng_tpu.edge.ops import (
     TW_FLAG,
     TW_WID,
 )
-from bng_tpu.ops.table import HostTable, TableGeom
+from bng_tpu.ops.table import HostTable, TableGeom, placed
 
 MAX_TAP_FILTERS = 64
 
@@ -154,16 +152,17 @@ class EdgeTables:
         """(tap delta, filters, config, route delta) — the edge tail of
         the engine's per-step update batch."""
         return (self.tap.make_update(self.update_slots),
-                jnp.asarray(self.tap_filters),
-                jnp.asarray(self.tap_config),
+                placed(self, "tap_filters", self.tap_filters),
+                placed(self, "tap_config", self.tap_config),
                 self.route.make_update(self.update_slots))
 
     def empty_updates(self):
         """No-op deltas that do not consume dirty tracking (scheduler
-        bulk lane); dense arrays are re-read — they apply wholesale."""
+        bulk lane); the dense arrays are compared with what was last
+        placed on every call — they apply wholesale."""
         return (self.tap.empty_update(self.update_slots),
-                jnp.asarray(self.tap_filters),
-                jnp.asarray(self.tap_config),
+                placed(self, "tap_filters", self.tap_filters),
+                placed(self, "tap_config", self.tap_config),
                 self.route.empty_update(self.update_slots))
 
     def dirty_count(self) -> int:

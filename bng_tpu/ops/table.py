@@ -280,6 +280,26 @@ def device_lookup(state: TableState, query: jax.Array, nbuckets: int, stash: int
     return LookupResult(found=found, slot=slot, vals=vals)
 
 
+def placed(owner, name: str, host: np.ndarray) -> jax.Array:
+    """The device copy of `owner`'s small dense array `name` (a pools /
+    server / hairpin / ranges / allowlist block that rides every update
+    batch and is applied wholesale): placed once, and placed again only
+    when the host array's bytes differ from what was last placed. The
+    compare is on bytes (2 KB at the most), so a write in place is seen,
+    and a write made before a drain is in that drain's batch. Like the
+    empty_update cache this leans on no program donating its updates;
+    the init upload (device_tables) must NOT come from here, the step
+    donates the tables it is given."""
+    cache = owner.__dict__.setdefault("_placed", {})
+    now = host.tobytes()
+    hit = cache.get(name)
+    if hit is None or hit[0] != now:
+        # from a copy: on the CPU backend asarray may alias host memory,
+        # and the owner writes its array in place
+        hit = cache[name] = (now, jnp.asarray(host.copy()))
+    return hit[1]
+
+
 class HostTable:
     """Host-authoritative mirror of one device table (numpy, single writer).
 
@@ -536,11 +556,14 @@ class HostTable:
         A drained bucket slot carries its whole (current) bucket row with
         still-dirty siblings masked used=0 (their vals have not shipped —
         see _pack_bucket_rows); each sibling rewrites the row on its own
-        drain."""
+        drain. With nothing dirty the batch is `empty_update`'s: the one
+        that is already on the device, so a clean table uploads nothing."""
         if self._dirty_all:
             raise RuntimeError(
                 f"table {self.name!r}: bulk_insert invalidated delta sync; "
                 "call device_state() for a full upload first")
+        if not self._dirty:  # nothing to ship: the batch that is already placed
+            return self.empty_update(max_slots)
         take = sorted(self._dirty)[:max_slots]
         self._dirty.difference_update(take)
         base = self.nbuckets * WAYS
@@ -579,10 +602,13 @@ class HostTable:
         Built WITHOUT touching dirty tracking — the latency scheduler's
         no-drain bulk steps pass this instead of make_update() so pending
         host deltas stay queued for the next drain-cadence step rather
-        than being consumed by a step that won't ship them. The result is
-        cached per size: update buffers are not donated by the jitted
-        step, so one device-resident copy serves every no-drain step
-        (zero host->HBM traffic, the entire point of the cadence)."""
+        than being consumed by a step that won't ship them; a clean
+        make_update() returns it too. The result is cached per size: no
+        program donates its update argument (`donate_argnums` is the
+        tables, and the packet batch of the express programs) and no
+        caller writes into a batch, so one device-resident copy serves
+        every step that ships nothing (zero host->HBM traffic). A
+        program that donated its updates would delete this cache."""
         cache = getattr(self, "_empty_upd_cache", None)
         if cache is None:
             cache = self._empty_upd_cache = {}

@@ -54,7 +54,7 @@ from bng_tpu.ops.v6 import (V6_NSTATS, V6ST_CTRL, V6ST_FWD_DOWN, V6ST_FWD_UP,
                             V6ST_MISS)
 from bng_tpu.ops.antispoof import ANTISPOOF_WORDS
 from bng_tpu.ops.qtable import HostQTable, QTableGeom, apply_qupdate
-from bng_tpu.ops.table import HostTable, TableGeom, apply_update
+from bng_tpu.ops.table import HostTable, TableGeom, apply_update, placed
 from bng_tpu.runtime import hostpath
 from bng_tpu.runtime.ring import FLAG_DHCP_CTRL
 from bng_tpu.runtime.tables import (FastPathTables, PPPoEFastPathTables,
@@ -594,11 +594,19 @@ class Engine:
         self.tables = self._device_tables()
         self.resync_count += 1
 
-    def _drain_with_resync(self, drain):
-        """Run a make-updates drain; on the bulk-build "full upload" signal
-        (bulk_insert abandoned dirty tracking) answer with one full device
-        re-upload and drain again (now-clean) — a bulk build on a live
-        engine must not brick the step loop."""
+    def _drain_with_resync(self, drain, fastpath: bool, rest: bool):
+        """Run a make-updates drain over the fastpath tables, the rest, or
+        both; on the bulk-build "full upload" signal (bulk_insert
+        abandoned dirty tracking) answer with one full device re-upload
+        and drain again (now-clean) — a bulk build on a live engine must
+        not brick the step loop. The tracer hears how many of the drained
+        tables hold something to ship (a batch is built and uploaded)
+        and how many are clean (the batch already on the chip serves)."""
+        if tele.t() is not None:
+            tabs = [t for name, t in self.host_mirror_tables().items()
+                    if (fastpath if name.startswith("fastpath/") else rest)]
+            built = sum(1 for t in tabs if t.dirty_count())
+            tele.drain_tables(built, len(tabs) - built)
         try:
             return drain()
         except RuntimeError as e:
@@ -607,33 +615,43 @@ class Engine:
             self.resync_tables()
             return drain()
 
+    def _updates(self, fastpath: bool, rest: bool):
+        """The update batch a fused step receives, in _apply_all_updates'
+        order. `fastpath` / `rest`: whether the dirty sets of the fastpath
+        tables / of every other table are drained (make_update), or stay
+        queued behind the no-op batch (empty_update). Only what changed is
+        uploaded either way: a clean table's batch is the one already on
+        the chip (HostTable.make_update), and the dense config arrays
+        (spoof ranges/config, garden allowlist, NAT hairpin/alg/config,
+        DHCP pools/server, the edge set's) are compared with what was
+        last placed and placed again when they differ (ops/table.py
+        placed) — the step applies them wholesale, so a write made before
+        this call is in this batch."""
+        sp, g, p, e, v = (self.antispoof, self.garden, self.pppoe, self.edge,
+                          self.v6)
+
+        def one(t, slots):
+            return t.make_update(slots) if rest else t.empty_update(slots)
+
+        return (
+            (self.fastpath.make_updates() if fastpath
+             else self.fastpath.empty_updates()),
+            self.nat.make_updates() if rest else self.nat.empty_updates(),
+            one(self.qos.up, self.qos.update_slots),
+            one(self.qos.down, self.qos.update_slots),
+            one(sp.bindings, sp.update_slots),
+            placed(sp, "ranges", sp.ranges),
+            placed(sp, "config", sp.config),
+            *((one(g.subscribers, g.update_slots),
+               placed(g, "allowed", g.allowed)) if g else ()),
+            *((p.make_updates() if rest else p.empty_updates()) if p else ()),
+            *((e.make_updates() if rest else e.empty_updates()) if e else ()),
+            *((one(v.by_addr, v.update_slots),) if v else ()),
+        )
+
     def _drain_updates(self):
-        # vector host path (ISSUE 14): a clean mirror set drains the
-        # CACHED no-op batch instead of rebuilding fresh scatter buffers
-        # for every table (~1.7ms/table-set per dispatch with zero dirty
-        # slots) — the _drain_fastpath_updates discipline extended to
-        # the fused step. Any dirty slot anywhere takes the real bounded
-        # drain; dense config arrays are re-read wholesale either way,
-        # so the device sees identical state.
-        if self._stage_pool is not None and self.pending_dirty() == 0:
-            return self._empty_updates()
-        return self._drain_with_resync(lambda: (
-            self.fastpath.make_updates(),
-            self.nat.make_updates(),
-            self.qos.up.make_update(self.qos.update_slots),
-            self.qos.down.make_update(self.qos.update_slots),
-            self.antispoof.bindings.make_update(self.antispoof.update_slots),
-            jnp.asarray(self.antispoof.ranges),
-            jnp.asarray(self.antispoof.config),
-            *((self.garden.subscribers.make_update(self.garden.update_slots),
-               jnp.asarray(self.garden.allowed)) if self.garden else ()),
-            *((self.pppoe.by_sid.make_update(self.pppoe.update_slots),
-               self.pppoe.by_ip.make_update(self.pppoe.update_slots))
-              if self.pppoe else ()),
-            *(self.edge.make_updates() if self.edge else ()),
-            *((self.v6.by_addr.make_update(self.v6.update_slots),)
-              if self.v6 else ()),
-        ))
+        return self._drain_with_resync(lambda: self._updates(True, True),
+                                       True, True)
 
     # -- latency-tiered scheduler support (runtime/scheduler.py) ----------
     #
@@ -649,50 +667,14 @@ class Engine:
         bulk-owned table, a NO-OP for the fastpath tables — the express
         lane is the single consumer of the fastpath drain (one
         authoritative device DHCP chain, never forked)."""
-        return (
-            self.fastpath.empty_updates(),
-            self.nat.make_updates(),
-            self.qos.up.make_update(self.qos.update_slots),
-            self.qos.down.make_update(self.qos.update_slots),
-            self.antispoof.bindings.make_update(self.antispoof.update_slots),
-            jnp.asarray(self.antispoof.ranges),
-            jnp.asarray(self.antispoof.config),
-            *((self.garden.subscribers.make_update(self.garden.update_slots),
-               jnp.asarray(self.garden.allowed)) if self.garden else ()),
-            *((self.pppoe.by_sid.make_update(self.pppoe.update_slots),
-               self.pppoe.by_ip.make_update(self.pppoe.update_slots))
-              if self.pppoe else ()),
-            *(self.edge.make_updates() if self.edge else ()),
-            *((self.v6.by_addr.make_update(self.v6.update_slots),)
-              if self.v6 else ()),
-        )
+        return self._drain_with_resync(lambda: self._updates(False, True),
+                                       False, True)
 
     def _empty_updates(self):
         """No-op update batch for scheduler bulk steps between
-        drain-cadence points. The big scatter buffers (update_slots x row
-        words per table — the real per-step host->HBM traffic) come from
-        the per-table empty_update caches; the small dense config arrays
-        (spoof ranges/config, garden allowlist, NAT hairpin/alg/config,
-        DHCP pools/server) are re-read from host state EVERY call because
-        the step applies them wholesale — a cached snapshot would revert
-        live config changes on every no-drain step."""
-        return (
-            self.fastpath.empty_updates(),
-            self.nat.empty_updates(),
-            self.qos.up.empty_update(self.qos.update_slots),
-            self.qos.down.empty_update(self.qos.update_slots),
-            self.antispoof.bindings.empty_update(self.antispoof.update_slots),
-            jnp.asarray(self.antispoof.ranges),
-            jnp.asarray(self.antispoof.config),
-            *((self.garden.subscribers.empty_update(self.garden.update_slots),
-               jnp.asarray(self.garden.allowed)) if self.garden else ()),
-            *((self.pppoe.by_sid.empty_update(self.pppoe.update_slots),
-               self.pppoe.by_ip.empty_update(self.pppoe.update_slots))
-              if self.pppoe else ()),
-            *(self.edge.empty_updates() if self.edge else ()),
-            *((self.v6.by_addr.empty_update(self.v6.update_slots),)
-              if self.v6 else ()),
-        )
+        drain-cadence points: no dirty set is consumed, and the dense
+        config arrays are as live as in any other batch (_updates)."""
+        return self._updates(False, False)
 
     def prefetch_bulk_updates(self):
         """Build (and start uploading) the NEXT bulk drain's update batch
@@ -706,7 +688,7 @@ class Engine:
         returned batch: it must reach the device via the next
         dispatch_scheduled_bulk(upd=...) or apply_updates_now(), or host
         and HBM silently diverge."""
-        return self._drain_with_resync(self._make_bulk_updates)
+        return self._make_bulk_updates()
 
     def apply_updates_now(self, upd) -> None:
         """Apply one already-built BULK update batch with no packet batch
@@ -740,7 +722,7 @@ class Engine:
         if upd is not None:
             pass  # prefetched drain: built (and uploading) since step N-1
         elif drain:
-            upd = self._drain_with_resync(self._make_bulk_updates)
+            upd = self._make_bulk_updates()
         else:
             upd = self._empty_updates()
         # read self.tables AFTER the drain (a bulk-build resync rebinds it)
@@ -1063,18 +1045,11 @@ class Engine:
     def _drain_fastpath_updates(self):
         """Fastpath-only update drain for the express programs. The
         steady-state fast lane has NOTHING dirty (lease writes arrive in
-        bursts from the slow path), and building a real drain allocates
-        fresh scatter buffers for every table — ~40% of the express
-        dispatch's host cost measured on CPU. A clean mirror set drains
-        the CACHED no-op batch instead (pools/server still re-read
-        wholesale, exactly like the bulk lane's empty drain); any dirty
-        slot takes the real bounded drain, so an OFFER still always sees
-        the newest lease. Shapes are identical either way — both batches
-        feed the same compiled programs."""
-        fp = self.fastpath
-        if fp.dirty_count() == 0:
-            return fp.empty_updates()
-        return self._drain_with_resync(fp.make_updates)
+        bursts from the slow path), and a clean table's make_update is
+        the batch already on the chip; any dirty slot takes the real
+        bounded drain, so an OFFER always sees the newest lease. Shapes
+        are identical either way — both feed the same compiled programs."""
+        return self._drain_with_resync(self.fastpath.make_updates, True, False)
 
     # -- AOT express OFFER path (runtime/scheduler.py fast lane) ----------
 
@@ -1520,8 +1495,8 @@ class Engine:
         """{name: HostTable|HostQTable} of every sparse host mirror this
         engine drains — the delta-replay walk surface (runtime/ops.py).
         Dense config arrays (pools/server, spoof ranges, garden allowed,
-        NAT hairpin/alg) are re-read wholesale on every drain and need
-        no diffing."""
+        NAT hairpin/alg) ride every batch whole (ops/table.py placed) and
+        need no diffing."""
         out = {
             "fastpath/sub": self.fastpath.sub,
             "fastpath/vlan": self.fastpath.vlan,

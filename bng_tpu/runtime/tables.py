@@ -49,7 +49,8 @@ from bng_tpu.ops.pppoe import (
     PS_MAC_LO,
     PS_SESSION_ID,
 )
-from bng_tpu.ops.table import HostTable, TableGeom, TableUpdate, apply_update
+from bng_tpu.ops.table import (HostTable, TableGeom, TableUpdate,
+                               apply_update, placed)
 from bng_tpu.ops.v6 import V6_WORDS, VA_IPV4, VA_MAC_HI, VA_MAC_LO
 from bng_tpu.utils.net import mac_to_u64, split_u64
 
@@ -230,8 +231,8 @@ class FastPathTables:
             sub=self.sub.make_update(self.update_slots),
             vlan=self.vlan.make_update(self.update_slots),
             cid=self.cid.make_update(self.update_slots),
-            pools=jnp.asarray(self.pools),
-            server=jnp.asarray(self.server),
+            pools=placed(self, "pools", self.pools),
+            server=placed(self, "server", self.server),
         )
 
     def empty_updates(self) -> FastPathUpdates:
@@ -241,16 +242,16 @@ class FastPathTables:
         express lane is the single consumer of the real fastpath drain
         (one authoritative device DHCP chain), and the bulk lane's DHCP
         leaves are a read replica. The sub/vlan/cid scatter buffers are
-        cached (they are the per-step transfer cost); pools/server are
-        re-read every call — the step applies those dense arrays
-        wholesale, so the replica tracks live pool/server config even
-        between replica refreshes."""
+        cached; pools/server are compared with what was last placed on
+        every call (ops/table.py placed) — the step applies those dense
+        arrays wholesale, so the replica tracks live pool/server config
+        even between replica refreshes."""
         return FastPathUpdates(
             sub=self.sub.empty_update(self.update_slots),
             vlan=self.vlan.empty_update(self.update_slots),
             cid=self.cid.empty_update(self.update_slots),
-            pools=jnp.asarray(self.pools),
-            server=jnp.asarray(self.server),
+            pools=placed(self, "pools", self.pools),
+            server=placed(self, "server", self.server),
         )
 
     def dirty_count(self) -> int:

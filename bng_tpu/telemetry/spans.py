@@ -179,6 +179,10 @@ class Tracer:
         # stale lanes the engine's pipelined loop made inert (engine.py
         # _mask_stale_lanes): lanes that held a length beyond a window's end
         self.masked_lanes = 0
+        # table batches the engine's update drains built and uploaded, and
+        # those a clean table answered with the batch already on the chip
+        # (engine.py _drain_with_resync)
+        self.drain_built = self.drain_cached = 0
         # lanes the device PPPoE stage decapsulated, encapsulated, and
         # punted for a session it does not hold (engine.py _fold_stats);
         # 0 in a program without the stage
@@ -531,6 +535,8 @@ class Tracer:
             "starved_ns": starved,
             "p99_us": p99,
             "masked_lanes": int(self.masked_lanes),
+            "drain_built": int(self.drain_built),
+            "drain_cached": int(self.drain_cached),
             "pppoe_decap": int(self.pppoe_decap),
             "pppoe_encap": int(self.pppoe_encap),
             "pppoe_miss": int(self.pppoe_miss),
@@ -698,6 +704,16 @@ def masked_lanes(n: int) -> None:
     if _ACTIVE is None:
         return
     _ACTIVE.masked_lanes += n
+
+
+def drain_tables(built: int, cached: int) -> None:
+    """Count one update drain's tables: `built` held dirty slots (a batch
+    was built and uploaded), `cached` were clean (the batch already on
+    the chip served). Disarmed: global load + None compare."""
+    if _ACTIVE is None:
+        return
+    _ACTIVE.drain_built += built
+    _ACTIVE.drain_cached += cached
 
 
 def pppoe_lanes(decap: int, encap: int, miss: int) -> None:

@@ -216,3 +216,44 @@ def test_masked_lanes_is_a_sum_of_every_tracer_and_counts_ghost_lanes():
     with spans.armed() as tr:
         _serve("py-scalar", False, "mixed", 20289)  # fresh zeros a call
     assert tr.sums()["masked_lanes"] == 0
+
+
+def test_drain_tables_adds_up_to_the_tables_drained_and_is_silent_disarmed():
+    """`drain_built` / `drain_cached` (PR 35): per drain, the tables that
+    held dirty slots and the clean ones, whose batch is already on the
+    chip. Together they are the tables that drain went over."""
+    zero = spans.Tracer().sums()
+    assert zero["drain_built"] == zero["drain_cached"] == 0
+    assert {"drain_built", "drain_cached"} <= set(spans.trace_sums())
+    engine, macs, ips, _flows = _stack(20291)
+    n = len(engine.host_mirror_tables())  # 3 fastpath + 6: no garden, no edge
+
+    def counts(tr):
+        s = tr.sums()
+        return s["drain_built"], s["drain_cached"]
+
+    with spans.armed() as tr:
+        engine._drain_updates()  # the whole set, clean since the upload
+        assert counts(tr) == (0, n)
+        assert engine.fastpath.touch_lease(macs[0], T0 + 5)
+        engine.qos.set_subscriber(int(ips[1]), down_bps=1, up_bps=1)  # both ways
+        engine._drain_updates()
+        assert counts(tr) == (3, n + n - 3)
+        assert engine.pending_dirty() == 0
+        engine.antispoof.add_binding(macs[2], int(ips[2]), MODE_STRICT)
+        engine._make_bulk_updates()  # every table but the fastpath's three
+        assert counts(tr) == (4, 2 * n - 3 + (n - 3) - 1)
+        engine._drain_fastpath_updates()  # those three
+        assert counts(tr) == (4, 3 * n - 7 + 3)
+        engine._empty_updates()  # drains nothing: counts nothing
+        assert counts(tr) == (4, 3 * n - 4)
+        assert sum(counts(tr)) == n + n + (n - 3) + 3
+    # a loop's worth: four windows, a drain a dispatch, every table clean
+    with spans.armed() as tr:
+        got = _serve("py-scalar", True, "mixed", 20289)
+    assert counts(tr) == (0, got["batches"] * n)
+    # disarmed, nothing is stamped: the last tracer's sums stand
+    assert engine.fastpath.touch_lease(macs[0], T0 + 9)
+    engine._drain_updates()
+    assert counts(tr) == (0, got["batches"] * n)
+    assert spans.trace_sums()["drain_built"] == 0

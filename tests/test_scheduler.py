@@ -94,6 +94,12 @@ def mac_of(i: int) -> bytes:
     return (0x02B0 << 32 | i).to_bytes(6, "big")
 
 
+def np_key(mac: bytes):
+    """The sub table's key words of a MAC (hi, lo)."""
+    k = int.from_bytes(mac, "big")
+    return [k >> 32, k & 0xFFFFFFFF]
+
+
 # ---------------------------------------------------------------------------
 # lanes: pure host-side policy (no device)
 # ---------------------------------------------------------------------------
@@ -490,36 +496,114 @@ class TestUpdateDrainCadence:
         sp_ranges = np.asarray(after[5])
         assert (sp_ranges[:, 1] == ip_to_u32("172.16.0.0")).any()
 
+    @pytest.mark.parametrize("drain", ["_empty_updates", "_drain_updates",
+                                       "_make_bulk_updates"])
+    @pytest.mark.parametrize("array", ["spoof_ranges", "nat_hairpin",
+                                       "nat_config", "pools", "server"])
+    def test_every_batch_carries_live_dense_config(self, drain, array):
+        """The twin of the test above for every builder and for the nat /
+        fastpath arrays (PR 35: a dense array is placed once and placed
+        again when its bytes changed). A change made after a batch was
+        built is in the NEXT batch, the batch built before keeps what it
+        was given, and an unchanged array is the same device array."""
+        import numpy as np
+
+        engine, _, clock = build_stack()
+        build = getattr(engine, drain)
+        write, leaf, seen = {
+            "spoof_ranges": (
+                lambda: engine.antispoof.add_allowed_range(
+                    ip_to_u32("172.16.0.0"), 12),
+                lambda u: u[5],
+                lambda a: (a[:, 1] == ip_to_u32("172.16.0.0")).any()),
+            "nat_hairpin": (
+                lambda: engine.nat.add_hairpin_ip(ip_to_u32("203.0.113.9")),
+                lambda u: u[1][3],
+                lambda a: (a == ip_to_u32("203.0.113.9")).any()),
+            # config_array() is a fresh array a call: the compare is on
+            # bytes, not on the array's identity
+            "nat_config": (
+                lambda: setattr(engine.nat, "ports_per_subscriber", 77),
+                lambda u: u[1][5],
+                lambda a: int(a[3]) == 77),
+            "pools": (
+                lambda: engine.fastpath.add_pool(
+                    3, ip_to_u32("10.3.0.0"), 24, ip_to_u32("10.3.0.1")),
+                lambda u: u[0].pools,
+                lambda a: (a[3] != 0).any()),
+            "server": (
+                lambda: engine.fastpath.set_server_config(
+                    SERVER_MAC, ip_to_u32("10.0.0.2")),
+                lambda u: u[0].server,
+                lambda a: (a == ip_to_u32("10.0.0.2")).any()),
+        }[array]
+        before = build()
+        assert not seen(np.asarray(leaf(before)))
+        assert leaf(build()) is leaf(before)  # unchanged: nothing placed
+        write()
+        after = build()
+        assert seen(np.asarray(leaf(after)))
+        assert leaf(after) is not leaf(before)
+        assert not seen(np.asarray(leaf(before)))  # a batch is a snapshot
+        assert leaf(build()) is leaf(after)
+        # the other dense arrays were not placed again for this write
+        others = [lambda u: u[5], lambda u: u[6], lambda u: u[1][3],
+                  lambda u: u[1][4], lambda u: u[1][5],
+                  lambda u: u[0].pools, lambda u: u[0].server]
+        same = sum(o(after) is o(before) for o in others)
+        assert same == len(others) - 1
+
     def test_express_drains_fastpath_every_dispatch(self):
-        """The drain is LOGICALLY per-dispatch; PR 13 refined the build:
-        a CLEAN mirror set serves the cached no-op batch (make_updates
-        allocated fresh scatter buffers per call — ~40% of the express
-        dispatch's host cost with zero dirty slots), while ANY dirty
-        slot takes the real bounded drain on the very next dispatch
+        """The drain is LOGICALLY per-dispatch, and only what changed is
+        uploaded (PR 35; PR 13 had a shortcut of its own here): clean
+        tables answer with the batch already on the chip, so a dispatch
+        with nothing dirty builds no table batch (`drain_built` 0), while
+        ANY dirty slot is built and shipped on the very next dispatch
         (lease visibility pinned by the next test)."""
+        import numpy as np
+
+        from bng_tpu.telemetry import spans
+
         engine, _, clock = build_stack()
         sched = TieredScheduler(engine, SchedulerConfig(
             express_batch=8), clock=clock)
-        fp_calls = []
-        orig = engine.fastpath.make_updates
-        engine.fastpath.make_updates = lambda: (fp_calls.append(1), orig())[1]
-        for i in range(16):
-            sched.submit(discover(mac_of(300 + i), 0x5000 + i))
-        sched.poll()
-        assert sched.express.stats.batches == 2
-        # nothing was dirty at either dispatch: the cached no-op batch
-        # served both — no fresh drain build on the clean fast path
-        assert len(fp_calls) == 0
-        # a host-side table write makes the NEXT dispatch drain for real
-        engine.fastpath.add_subscriber(mac_of(390), pool_id=1,
-                                       ip=ip_to_u32("10.0.0.90"),
-                                       lease_expiry=int(clock()) + 600)
-        for i in range(8):
-            sched.submit(discover(mac_of(320 + i), 0x5100 + i))
-        sched.poll()
-        assert sched.express.stats.batches == 3
-        assert len(fp_calls) == 1
-        assert engine.fastpath.dirty_count() == 0  # delta shipped
+        fp, drained = engine.fastpath, []
+        orig = engine._drain_fastpath_updates
+        engine._drain_fastpath_updates = (
+            lambda: (drained.append(orig()), drained[-1])[1])
+        n = fp.update_slots
+        with spans.armed() as tr:
+            for i in range(16):
+                sched.submit(discover(mac_of(300 + i), 0x5000 + i))
+            sched.poll()
+            assert sched.express.stats.batches == 2
+            # a drain a dispatch, and nothing was dirty at either: every
+            # leaf is the cached batch's, nothing was built or uploaded
+            assert len(drained) == 2
+            for upd in drained:
+                assert upd.sub is fp.sub.empty_update(n)
+                assert upd.vlan is fp.vlan.empty_update(n)
+                assert upd.cid is fp.cid.empty_update(n)
+            assert drained[0].pools is drained[1].pools
+            assert drained[0].server is drained[1].server
+            assert tr.sums()["drain_built"] == 0
+            assert tr.sums()["drain_cached"] == 2 * 3
+            # a host-side table write makes the NEXT dispatch drain for real
+            fp.add_subscriber(mac_of(390), pool_id=1,
+                              ip=ip_to_u32("10.0.0.90"),
+                              lease_expiry=int(clock()) + 600)
+            for i in range(8):
+                sched.submit(discover(mac_of(320 + i), 0x5100 + i))
+            sched.poll()
+            assert sched.express.stats.batches == 3
+            assert len(drained) == 3
+            assert drained[2].sub is not fp.sub.empty_update(n)  # built
+            assert drained[2].vlan is fp.vlan.empty_update(n)  # still clean
+            assert tr.sums()["drain_built"] == 1
+            assert tr.sums()["drain_cached"] == 2 * 3 + 2
+        assert fp.dirty_count() == 0  # delta shipped
+        slot = fp.sub._find_slot(np_key(mac_of(390)))
+        assert np.asarray(engine.tables.dhcp.sub.vals)[slot].any()  # on device
 
     def test_pending_lease_reaches_device_via_express_drain(self):
         """A lease installed host-side between steps is visible to the

@@ -310,3 +310,114 @@ class TestWidenedRowCheckpointCompat:
         pp = PPPoEFastPathTables(nbuckets=1 << 8)
         assert pp.by_sid.compat_val_pad_from == (6,)
         assert pp.by_ip.compat_val_pad_from == (6,)
+
+
+def _make_update_old_body(t: HostTable, max_slots: int):
+    """HostTable.make_update as it was before a clean table answered with
+    the batch already on the chip: six fresh arrays and six uploads, dirty
+    or not. Kept here as the plain reference the new one is held to."""
+    from bng_tpu.ops.table import TableUpdate
+
+    if t._dirty_all:
+        raise RuntimeError(f"table {t.name!r}: full upload first")
+    take = sorted(t._dirty)[:max_slots]
+    t._dirty.difference_update(take)
+    base = t.nbuckets * WAYS
+    b_take = sorted({s // WAYS for s in take if s < base})
+    s_take = [s - base for s in take if s >= base]
+    U = max_slots
+    bidx = np.full((U,), t.nbuckets, dtype=np.int32)
+    brows = np.zeros((U, WAYS * t.KW), dtype=np.uint32)
+    sidx = np.full((U,), t.stash, dtype=np.int32)
+    srows = np.zeros((U, t.KW), dtype=np.uint32)
+    idx = np.full((U,), t.S, dtype=np.int32)
+    vv = np.zeros((U, t.V), dtype=np.uint32)
+    if b_take:
+        bs = np.asarray(b_take, dtype=np.int32)
+        bidx[: len(bs)] = bs
+        brows[: len(bs)] = t._pack_bucket_rows(bs, mask_dirty=True)
+    if s_take:
+        ss = np.asarray(s_take, dtype=np.int32)
+        sidx[: len(ss)] = ss
+        srows[: len(ss)] = t._pack_stash_rows(ss)
+    if take:
+        ts = np.asarray(take, dtype=np.int32)
+        idx[: len(ts)] = ts
+        vv[: len(ts)] = t.vals[ts]
+    return TableUpdate(bidx=jnp.asarray(bidx), brows=jnp.asarray(brows),
+                       sidx=jnp.asarray(sidx), srows=jnp.asarray(srows),
+                       idx=jnp.asarray(idx), vals=jnp.asarray(vv))
+
+
+def _twin_tables(where: str):
+    """Two tables with the same rows, uploaded whole and clean; `where`
+    says which kind of slot the next write lands in."""
+    out = []
+    for _ in range(2):
+        t = HostTable(nbuckets=2, key_words=1, val_words=2, stash=8, name=where)
+        # stash: fill both buckets' ways first, so the write spills
+        for i in range(1, 1 + (2 * WAYS if where == "stash" else 3)):
+            t.insert([i], [i * 10, i])
+        out.append(t)
+    return out
+
+
+@pytest.mark.parametrize("where", ["bucket", "stash"])
+class TestCleanTableDrainsTheBatchOnTheChip:
+    """Only what changed is uploaded (PR 35): a clean table's make_update
+    is empty_update's batch, leaf for leaf the same objects; one dirty
+    slot builds and ships what the old body built."""
+
+    def test_clean_make_update_is_the_cached_batch(self, where):
+        t, _ = _twin_tables(where)
+        t.device_state()
+        empty = t.empty_update(8)
+        got = t.make_update(8)
+        assert all(a is b for a, b in zip(got, empty))
+        assert t.make_update(8) is t.empty_update(8)  # and again: no rebuild
+        assert t.make_update(4) is t.empty_update(4)  # a cache a size
+        assert t.make_update(4) is not empty
+        # dirty tracking is left alone: nothing queued, nothing invented
+        assert t.dirty_count() == 0 and not t._dirty_all
+
+    def test_one_dirty_slot_builds_what_the_old_body_built(self, where):
+        new, old = _twin_tables(where)
+        st_new, st_old = new.device_state(), old.device_state()
+        key = 100
+        for t in (new, old):
+            t.insert([key], [7, 9])
+            # full buckets: the eviction walk ends in the stash
+            assert any(s >= t.nbuckets * WAYS for s in t._dirty) == (
+                where == "stash")
+        u_new, u_old = new.make_update(16), _make_update_old_body(old, 16)
+        assert u_new is not new.empty_update(16)
+        for name, a, b in zip(u_new._fields, u_new, u_old):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+        st_new, st_old = apply_update(st_new, u_new), apply_update(st_old, u_old)
+        for name, a, b in zip(st_new._fields, st_new, st_old):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+        res = device_lookup(st_new, make_queries([[key]], 1), new.nbuckets,
+                            new.stash)
+        assert bool(res.found[0]) and np.asarray(res.vals)[0].tolist() == [7, 9]
+        # drained: the next one is the cached batch again, and applying it
+        # changes nothing
+        assert new.dirty_count() == 0
+        again = new.make_update(16)
+        assert again is new.empty_update(16)
+        st_same = apply_update(st_new, again)
+        for a, b in zip(st_same, st_new):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_dirty_all_raises_before_the_cache_answers(self, where):
+        t = HostTable(nbuckets=64, key_words=1, val_words=2,
+                      stash=4 if where == "bucket" else 8, name=where)
+        t.device_state()
+        assert t.make_update(8) is t.empty_update(8)  # the cache is warm
+        n = t.stash + 1  # a bulk build beyond the stash abandons the deltas
+        t.bulk_insert(np.arange(1, 1 + n, dtype=np.uint32)[:, None],
+                      np.ones((n, 2), np.uint32))
+        assert t._dirty_all and not t._dirty
+        with pytest.raises(RuntimeError, match="full upload"):
+            t.make_update(8)
+        t.device_state()
+        assert t.make_update(8) is t.empty_update(8)

@@ -36,6 +36,14 @@ MASKED = {
              "per": "engine.batches"}}
 
 
+# PR 35's two (section 7 row 1 xvi): of the tables a step's drain went
+# over, those built and uploaded, and those answered from the chip
+DRAIN = [dict(MASKED, name=f"engine.drain_{kind}_per_step", unit="tables",
+              read={"kind": "counter", "path": f"engine.trace.drain_{kind}",
+                    "per": "engine.batches"})
+         for kind in ("built", "cached")]
+
+
 def _write(path, obj):
     with open(path, "w") as f:
         json.dump(obj, f)
@@ -71,7 +79,8 @@ def wrap_dir(tmp_path_factory):
     for m in bench["end_to_end"]:
         if m["name"] == "served_kpps":
             m["workloads"].append(CELL)
-    _write(os.path.join(bdir, "layers", MASKED["name"] + ".json"), MASKED)
+    for m in (MASKED, *DRAIN):
+        _write(os.path.join(bdir, "layers", m["name"] + ".json"), m)
     for name in ("wire.frames_per_step", "wire.ring_us_per_frame"):
         m = applib.load_named("layers", name, bdir)
         m["cells"].append(CELL)
@@ -101,7 +110,8 @@ def test_the_hit_balance_holds_across_the_pools_wrap(wrap_dir, capsys, seed,
                                                      trace):
     """Untraced as the driver times it, and traced: there `masked_lanes`
     is read through `engine.trace` by the dropped-in layer file, above 0
-    once a short window lands in a buffer a full one used."""
+    once a short window lands in a buffer a full one used, and PR 35's
+    drain counters beside it."""
     res, out = _run(wrap_dir, capsys, seed, "--trace", trace)
     assert "check dhcp_accepted_minus_device_hits=0 limit=0" in out
     assert res["correct"] is True and res["failed"] == 0, out[-14:]
@@ -114,6 +124,11 @@ def test_the_hit_balance_holds_across_the_pools_wrap(wrap_dir, capsys, seed,
         assert 0 < got[MASKED["name"]]["value"] < \
             got["wire.frames_per_step"]["value"]
         assert got["wire.ring_us_per_frame"]["value"] > 0
+        # the tables are clean in the window (no slow-path DHCP, no punt):
+        # every step's drain is answered from the chip, table for table
+        # (3 fastpath, 3 NAT, 2 QoS, antispoof, the garden's)
+        assert got["engine.drain_built_per_step"]["value"] == 0
+        assert got["engine.drain_cached_per_step"]["value"] == 10
 
 
 def test_the_stale_binding_control_still_fails_and_by_the_sample_alone(
