@@ -21,6 +21,7 @@ from __future__ import annotations
 import glob
 import json
 import os
+import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -50,6 +51,7 @@ class Context:
     setup_s: float
     n_devices: int
     trace: dict | None = None
+    reduce_s: float = 0.0  # what reducing the trace and the event log took
     left_out: tuple = ()  # per-layer metrics whose reader found nothing
     _lat: dict | None = None
 
@@ -258,8 +260,11 @@ def per_layer(ctx: Context, bench_dir: str, cell: str) -> dict:
     """Every layer file that lists this cell, read once."""
     prof = ctx.profile
     if prof is not None and prof.t_stop is not None:
+        t0 = time.perf_counter()
         ctx.trace = tracelib.reduce_dir(prof.dir, ctx.n_devices,
-                                        prof.t_stop - prof.t_start)
+                                        prof.t_stop - prof.t_start,
+                                        tracelib.events_of(ctx.tracer))
+        ctx.reduce_s = time.perf_counter() - t0
     out, left_out = {}, []
     for m in layer_files(bench_dir):
         if cell not in m["cells"]:

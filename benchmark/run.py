@@ -549,6 +549,10 @@ def main(argv=None) -> int:
             say(line)
         if args.trace:
             metrics = layers.per_layer(ctx, args.bench_dir, cell["name"])
+            if ctx.trace is not None:
+                say(f"trace reduced in {ctx.reduce_s:.3f} s: "
+                    f"{len(tracer.events)} events in the Tracer's log, idle "
+                    f"gaps under {len(ctx.trace['gaps'])} labels")
             if ctx.left_out:
                 say("per-layer metrics with nothing to read, left out of the "
                     "line: " + ", ".join(ctx.left_out))

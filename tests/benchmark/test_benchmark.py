@@ -41,19 +41,44 @@ TINY_ARGV = {
 # the loop a box with a NIC runs: the same app without the scheduler
 TINY_ARGV["tiny-wire"] = [a for a in TINY_ARGV["tiny-cgnat"]
                           if a != "--scheduler-enabled"]
+# 4,096 subscribers, 128 NAT subscribers, every one of them PPPoE; and the
+# same 4,096 dual stack, 128 of them behind NAT
+TINY_ARGV["tiny-pppoe"] = TINY_ARGV["tiny-wire"] + ["--pppoe-enabled",
+                                                    "--pppoe-auth", "none"]
+TINY_ARGV["tiny-dualstack"] = TINY_ARGV["tiny-wire"] + ["--ipv6-fastpath"]
 DROPIN_KIT = "ipoe-dot1q"
 BASE_OF = {"tiny-cgnat": "ipoe-cgnat-1M", "tiny-sharded": "ipoe-sharded4-1M",
-           "tiny-wire": "ipoe-cgnat-1M-wire"}
-TINY_CELLS = {  # tiny cell -> (the cell its layer files name, config, traffic)
+           "tiny-wire": "ipoe-cgnat-1M-wire",
+           "tiny-pppoe": "pppoe-cgnat-1M-wire",
+           "tiny-dualstack": "dualstack-cgnat-1M-wire"}
+# tiny cell -> (the cell its layer files name, config, traffic). A layer file
+# that names a cell without a stand-in here stops `tiny_dir` with a KeyError:
+# a PR that adds a cell adds its stand-in to these three literals
+TINY_CELLS = {
     "tiny.flood": ("cgnat-1M.flood-64B", "tiny-cgnat", "tiny-flood"),
     "tiny.renew": ("cgnat-1M.renew-under-load", "tiny-cgnat", "tiny-renew"),
     "tiny4.flood": ("sharded4-1M.flood-64B", "tiny-sharded", "tiny-flood-32"),
-    # the wire cell's files are in place; BENCHMARK.json does not hold the
-    # cell (PERF.md section 7: on the chip the program's ghost lanes break
-    # the DHCP hit balance), so it reports what the other flood cell does
     "tiny-wire.flood": ("cgnat-1M-wire.flood-64B", "tiny-wire", "tiny-flood"),
+    "tiny-pppoe.flood": ("pppoe-cgnat-1M-wire.flood-64B", "tiny-pppoe",
+                         "tiny-flood"),
+    "tiny-dualstack.flood": ("dualstack-cgnat-1M-wire.flood-64B",
+                             "tiny-dualstack", "tiny-flood"),
 }
-REPORTS_LIKE = {"cgnat-1M-wire.flood-64B": "cgnat-1M.flood-64B"}
+
+# what the engine's own loop reports in each of its three cells (wire, PPPoE,
+# dual stack): the `wire.*` spans and sums and the loop's counters; and those
+# of them that are 0 in a sound rehearsal (no stale lane short of the pool's
+# wrap, no dirty table; the device is seen starved only when a beat finds
+# the ring empty)
+ENGINE_LOOP = {
+    "wire.dispatch_p50_us", "wire.device_p50_us", "wire.device_wait_p50_us",
+    "wire.reply_us_per_frame", "wire.ring_us_per_frame",
+    "wire.frames_per_step", "wire.device_starved_share",
+    "wire.unattributed_share", "wire.masked_lanes_per_step",
+    "engine.drain_built_per_step", "engine.drain_cached_per_step"}
+ENGINE_LOOP_ZERO_OK = {"wire.device_starved_share",
+                       "wire.masked_lanes_per_step",
+                       "engine.drain_built_per_step"}
 
 
 def _write(path, obj):
@@ -106,7 +131,7 @@ def tiny_dir(tmp_path_factory):
     for m in bench["end_to_end"]:
         if "workloads" in m:
             m["workloads"] += [tiny for real, tiny in stands_for.items()
-                               if REPORTS_LIKE.get(real, real) in m["workloads"]]
+                               if real in m["workloads"]]
     for path in glob.glob(os.path.join(bdir, "layers", "*.json")):
         m = json.load(open(path))
         m["cells"] += [stands_for[c] for c in list(m["cells"])]
@@ -159,7 +184,7 @@ def test_cell_rehearses_on_cpu(tiny_dir, capsys, cell):
     assert res["attempted"] > 0
     assert res["device"]["platform"] == "cpu"
     assert "memory_peak_bytes" not in res["device"]  # no device metric here
-    real = REPORTS_LIKE.get(TINY_CELLS[cell][0], TINY_CELLS[cell][0])
+    real = TINY_CELLS[cell][0]
     want = {m["name"] for m in BENCH["end_to_end"]
             if real in m.get("workloads", [real])}
     assert set(res["metrics"]) == want
@@ -210,19 +235,17 @@ def test_traced_wire_cell_reads_the_ring_lane_and_the_engines_tiling(tiny_dir,
     res, out = _run(tiny_dir, capsys, "tiny-wire.flood", "--trace", "1")
     assert res["correct"] is True, out[-14:]
     got = res["metrics"]
-    wire = {m["name"] for m in layers.layer_files(tiny_dir)
-            if m["name"].startswith("wire")}
-    assert len(wire) == 9
-    assert all("cgnat-1M-wire.flood-64B" in m["cells"]
-               for m in layers.layer_files(applib.BENCH_DIR)
-               if m["name"] in wire | {"gen.share", "loop.us_per_frame"})
-    # with a window always in flight the Tracer sees the device starved only
-    # when a beat finds the ring empty: the share may be 0, the others not
-    zero_ok = {"wire.device_starved_share"}
-    for name in wire - {"wire_step.device_p50_us"} | {"gen.share",
-                                                      "loop.us_per_frame"}:
+    wire = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
+            if m["name"].startswith("wire")
+            and "cgnat-1M-wire.flood-64B" in m["cells"]
+            and not m["read"]["kind"].startswith("trace_")}
+    positive = (ENGINE_LOOP - ENGINE_LOOP_ZERO_OK) | {"gen.share",
+                                                      "loop.us_per_frame"}
+    assert ENGINE_LOOP <= wire | {"engine.drain_built_per_step",
+                                  "engine.drain_cached_per_step"}
+    for name in wire | positive:  # a file added later may read 0 here
         assert got[name]["value"] >= 0 and (got[name]["value"] > 0
-                                            or name in zero_ok), name
+                                            or name not in positive), name
     assert got["wire.unattributed_share"]["value"] < 100.0
     # one window of at most the cap's frames a step, two in flight
     assert 1 <= got["wire.frames_per_step"]["value"] <= 1024
@@ -725,3 +748,106 @@ def test_trace_reduction_on_the_recorded_trace():
     assert got["collective_share"] == pytest.approx(0.1)
     assert got["breakdown"]["idle_gaps"] == [["bench.drive_once", pytest.approx(0.2)]]
     assert trace.reduce({"planes": {}}, 1, 1.0) is None
+
+
+# a device with three ops and two gaps; the harness's spans and the
+# program's two beats around them, on the trace's timeline (ns)
+_OPS = [["a", 0, 1e8], ["b", 5e8, 1e8], ["c", 9e8, 1e8]]
+_BENCH = [["bench.drive_once", 0.5e8, 5e8], ["bench.pop", 6e8, 0.1e8],
+          ["bench.push", 8.5e8, 0.1e8], ["bench.drive_once", 8.6e8, 2.4e8]]
+_CLOCK = 40_000_000_000_000  # the Tracer's clock where the trace reads 0.5e8
+_BEATS = [[0.5e8, 5e8, _CLOCK, 7], [8.6e8, 2.4e8, _CLOCK + int(8.1e8), 8]]
+_STAGES = ["ring", "dispatch", "device", "slow_path", "reply", "beat", "pack",
+           "drain"]
+
+
+def _lap(stage, start, dur, beat):  # a lap by where it lies on the trace
+    return ([_STAGES.index(stage), 0, _CLOCK + int(start - 0.5e8), int(dur)],
+            beat)
+
+
+def _hand(laps, beats=_BEATS):
+    data = {"planes": {"/device:TPU:0": {"XLA Ops": _OPS, "XLA Modules": []},
+                       "/host:CPU": {"bench": _BENCH, "beats": beats}}}
+    log = {"stages": _STAGES, "events": [e for e, _b in laps],
+           "beats": [b for _e, b in laps]}
+    got = trace.reduce(data, 1, 1.0, log if laps else None)
+    assert got["busy_s"] == pytest.approx(0.3)
+    assert sum(got["gaps"].values()) == pytest.approx(0.7)
+    assert got["breakdown"]["idle_gaps"] == [
+        [k, v] for k, v in sorted(got["gaps"].items(), key=lambda kv: -kv[1])]
+    return got["gaps"]
+
+
+# what the ledger keeps of a label as it stands
+LABEL = re.compile(r"^[A-Za-z0-9_.\-]{1,64}$")
+HAND_MADE = {
+    # between two steps the loop runs `reply` and then `dispatch`, with a
+    # `drain` lap inside it: the gap is theirs by overlap, the innermost
+    # wins, and a fed duration (`device`) or the beat itself claims nothing
+    "a gap split over the laps by overlap": (
+        [_lap("reply", 1e8, 1e8, 7), _lap("dispatch", 2.5e8, 2e8, 7),
+         _lap("drain", 3e8, 0.5e8, 7), _lap("device", 0, 10e8, 7),
+         _lap("beat", 0.5e8, 5e8, 7), _lap("pack", 8.7e8, 0.2e8, 8)],
+        {"drive_once.reply": 0.1, "drive_once.dispatch": 0.15,
+         "drive_once.drain": 0.05, "drive_once.no_lap": 0.12,
+         "drive_once.pack": 0.02, "bench.pop": 0.01, "bench.push": 0.01,
+         "between beats": 0.24}),
+    # the harness calls `app.tick()` between beats: that lap has beat id -1
+    # and lands through the nearest anchor
+    "a gap between beats under a slow_path lap": (
+        [_lap("slow_path", 6.2e8, 2e8, -1)],
+        {"between_beats.slow_path": 0.2, "between beats": 0.04,
+         "drive_once.no_lap": 0.44, "bench.pop": 0.01, "bench.push": 0.01}),
+    # no event log: the harness's own names, as before PR 36
+    "no event log": (
+        [], {"bench.drive_once": 0.44, "between beats": 0.24,
+             "bench.pop": 0.01, "bench.push": 0.01}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HAND_MADE))
+def test_an_idle_gap_is_named_by_the_programs_stage(case):
+    laps, want = HAND_MADE[case]
+    got = _hand(laps)
+    assert got == {k: pytest.approx(v) for k, v in want.items()}
+    assert all(LABEL.match(k) or k == "between beats" for k in got)
+
+
+def test_a_trace_without_anchors_reads_as_before():
+    """An event log and no `bng.beat` in the trace (a program from before
+    PR 25, or a profiler the Tracer did not see start): no lap can be put
+    on the trace's timeline, and the labels are the harness's own."""
+    laps = HAND_MADE["a gap split over the laps by overlap"][0]
+    assert _hand(laps, beats=[]) == {
+        k: pytest.approx(v) for k, v in HAND_MADE["no event log"][1].items()}
+
+
+def test_trace_reduction_on_the_recording_with_anchors():
+    """A traced chip run of `cgnat-1M-wire.flood-64B`, cut to its first
+    programs (`python -m benchmark.lib.trace <dir> <out> <n> programs`), with
+    the `bng.beat` anchors and the matching slice of the Tracer's event log:
+    the gaps between the programs are named by the engine loop's stages."""
+    base = os.path.join(applib.HERE, "testdata", "trace_anchored")
+    data, log, want = (json.load(open(base + ext))
+                       for ext in (".json", ".events.json", ".expect.json"))
+    beats = data["planes"]["/host:CPU"]["beats"]
+    assert len(beats) == want["anchors"] and len(log["events"]) == want["laps"]
+    got = trace.reduce(data, want["n_devices"], want["window_s"], log)
+    assert got["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
+    assert got["idle_share"] == pytest.approx(want["idle_share"], rel=1e-9)
+    assert got["gaps"] == {k: pytest.approx(v, rel=1e-9)
+                           for k, v in want["gaps"].items()}
+    assert all(LABEL.match(k) or k == "between beats" for k in got["gaps"])
+    stages = [k for k in got["gaps"]
+              if k.startswith("drive_once.") and k != "drive_once.no_lap"]
+    assert len(stages) >= 2 and "bench.drive_once" not in got["gaps"]
+    # the same recording without its event log: the harness's own names,
+    # and the stages' entries sum back to them
+    bare = trace.reduce(data, want["n_devices"], want["window_s"])["gaps"]
+    assert set(bare) <= {*trace.BENCH_SPANS, "between beats"}
+    for old, heads in (("bench.drive_once", ("drive_once.",)),
+                       ("between beats", ("between_beats.", "between beats"))):
+        assert sum(v for k, v in got["gaps"].items() if k.startswith(heads)) \
+            == pytest.approx(bare.get(old, 0.0), rel=1e-9, abs=1e-12)
+

@@ -1,20 +1,28 @@
 """The cell `dualstack-cgnat-1M-wire.flood-64B` in test_benchmark.py's own
-rehearsal directory, through the stand-in tests/conftest.py gives it (the
-fixture's literals lack the cell, and this directory's conftest.py may not
-be edited): its configuration, its kit and its four layer files are found
-by name, at 4,096 dual-stack subscribers of whom 128 are behind NAT.
+rehearsal directory, as the stand-in `tiny-dualstack.flood`: its
+configuration, its kit and its layer files are found by name, at 4,096
+dual-stack subscribers of whom 128 are behind NAT.
 tests/test_dualstack_cell_rehearsal.py is the longer rehearsal, past the
 pool's wrap and with both controls. No number from here is a device
 metric, and no position in a `workloads` list is pinned."""
 
-from test_benchmark import BENCH, TINY_CELLS, _run, tiny_dir  # noqa: F401
+from test_benchmark import (BENCH, ENGINE_LOOP, ENGINE_LOOP_ZERO_OK,  # noqa: F401
+                            TINY_CELLS, _run, tiny_dir)
 
 from benchmark.lib import app as applib
 from benchmark.lib import layers
 
 REAL = "dualstack-cgnat-1M-wire.flood-64B"
-FILES = {"dualstack_step.device_p50_us", "dualstack.loop_us_per_frame",
-         "dualstack.gen_share", "dualstack.beat_p99_us"}
+OWN = {"dualstack_step.device_p50_us", "dualstack.loop_us_per_frame",
+       "dualstack.gen_share", "dualstack.beat_p99_us"}  # PR 34's, the cell's alone
+# since PR 36 the engine's loop reports here what it reports in the wire
+# cell, and the counters of the stage beside it (no v6 miss, no v6 control frame
+# in a sound run: 0)
+FILES = OWN | ENGINE_LOOP | {"dualstack.v6_fwd_per_step",
+                             "dualstack.v6_miss_per_step",
+                             "dualstack.v6_ctrl_per_step"}
+ZERO_OK = ENGINE_LOOP_ZERO_OK | {"dualstack.v6_miss_per_step",
+                                 "dualstack.v6_ctrl_per_step"}
 
 
 def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
@@ -38,16 +46,12 @@ def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
     assert entry["reduced"] == cfg["reduced"]
     named = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
              if REAL in m["cells"]}
-    assert named == FILES
-    # kinds the pinned counts of span / counter / wire* files let in
-    assert all(m["read"]["kind"] in ("bench_span", "trace_program")
-               and m["cells"] == [REAL] and not m["name"].startswith("wire")
-               for m in layers.layer_files(applib.BENCH_DIR)
-               if m["name"] in FILES)
+    assert FILES <= named  # a later PR may add a file that lists the cell
     assert {m["name"] for m in BENCH["per_layer"]
-            if REAL in m["workloads"]} == FILES
-    assert all(m["workloads"] == [REAL] and m["moves"] == "served_kpps"
-               for m in BENCH["per_layer"] if m["name"] in FILES)
+            if REAL in m["workloads"]} == named
+    assert all(m["moves"] == "served_kpps" and (m["workloads"] == [REAL]
+                                                or m["name"] not in OWN)
+               for m in BENCH["per_layer"] if m["name"] in named)
     served = {m["name"]: m for m in BENCH["end_to_end"]}["served_kpps"]
     assert REAL in served["workloads"]
     kit = applib.load_kit(cfg)
@@ -82,8 +86,10 @@ def test_the_stand_in_rehearses_traced(tiny_dir, capsys):  # noqa: F811
     assert any(ln.startswith("cell: ") and ln.endswith("kit=dualstack")
                for ln in out)
     got = res["metrics"]
-    assert set(got) == FILES - {"dualstack_step.device_p50_us"}
-    assert all(m["value"] > 0 for m in got.values())
+    assert FILES - {"dualstack_step.device_p50_us"} <= set(got)
+    assert all(got[name]["value"] > 0 for name in FILES - ZERO_OK
+               if name in got)
+    assert all(got[name]["value"] >= 0 for name in ZERO_OK)
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
     assert said and "dualstack_step.device_p50_us" in said[0]
     sample = [ln for ln in out if ln.startswith("check sample: ")][0]

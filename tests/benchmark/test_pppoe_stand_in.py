@@ -1,19 +1,25 @@
 """The cell `pppoe-cgnat-1M-wire.flood-64B` in test_benchmark.py's own
-rehearsal directory, through the stand-in conftest.py gives it (the
-fixture's literals lack the cell): its configuration, its kit and its four
-layer files are found by name, at 4,096 subscribers of whom all 128 NAT
-subscribers are PPPoE. tests/test_pppoe_cell_rehearsal.py is the longer
+rehearsal directory, as the stand-in `tiny-pppoe.flood`: its configuration,
+its kit and its layer files are found by name, at 4,096 subscribers of whom
+all 128 NAT subscribers are PPPoE. tests/test_pppoe_cell_rehearsal.py is the longer
 rehearsal, past the pool's wrap and with both controls. No number from
 here is a device metric."""
 
-from test_benchmark import BENCH, TINY_CELLS, _run, tiny_dir  # noqa: F401
+from test_benchmark import (BENCH, ENGINE_LOOP, ENGINE_LOOP_ZERO_OK,  # noqa: F401
+                            TINY_CELLS, _run, tiny_dir)
 
 from benchmark.lib import app as applib
 from benchmark.lib import layers
 
 REAL = "pppoe-cgnat-1M-wire.flood-64B"
-FILES = {"pppoe_step.device_p50_us", "pppoe.loop_us_per_frame",
-         "pppoe.gen_share", "pppoe.beat_p99_us"}
+OWN = {"pppoe_step.device_p50_us", "pppoe.loop_us_per_frame",
+       "pppoe.gen_share", "pppoe.beat_p99_us"}  # PR 32's, the cell's alone
+# since PR 36 the engine's loop reports here what it reports in the wire
+# cell, and the counters of the stage beside it (no unknown session
+# in a sound run: 0)
+FILES = OWN | ENGINE_LOOP | {"pppoe.decap_per_step", "pppoe.encap_per_step", "pppoe.miss_per_step",
+                             "pppoe.tick_ms_per_s"}
+ZERO_OK = ENGINE_LOOP_ZERO_OK | {"pppoe.miss_per_step"}
 
 
 def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
@@ -27,15 +33,11 @@ def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
         "argv"] + ["--pppoe-enabled", "--pppoe-auth", "none"]
     named = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
              if REAL in m["cells"]}
-    assert named == FILES
-    # kinds the pinned counts of span / counter / wire* files let in
-    assert all(m["read"]["kind"] in ("bench_span", "trace_program")
-               for m in layers.layer_files(applib.BENCH_DIR)
-               if m["name"] in FILES)
+    assert FILES <= named  # a later PR may add a file that lists the cell
     assert {m["name"] for m in BENCH["per_layer"]
-            if REAL in m["workloads"]} == FILES
+            if REAL in m["workloads"]} == named
     served = {m["name"]: m for m in BENCH["end_to_end"]}["served_kpps"]
-    assert served["workloads"][-1] == REAL
+    assert REAL in served["workloads"]
     assert hasattr(applib.load_kit(cfg), "stage_bytes")
 
 
@@ -46,7 +48,9 @@ def test_the_stand_in_rehearses_traced(tiny_dir, capsys):  # noqa: F811
     assert any(ln.startswith("cell: ") and ln.endswith("kit=pppoe")
                for ln in out)
     got = res["metrics"]
-    assert set(got) == FILES - {"pppoe_step.device_p50_us"}
-    assert all(m["value"] > 0 for m in got.values())
+    assert FILES - {"pppoe_step.device_p50_us"} <= set(got)
+    assert all(got[name]["value"] > 0 for name in FILES - ZERO_OK
+               if name in got)
+    assert all(got[name]["value"] >= 0 for name in ZERO_OK)
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
     assert said and "pppoe_step.device_p50_us" in said[0]

@@ -1,7 +1,8 @@
 """CPU rehearsal of the layer files that read the Tracer's tiling, device
-occupancy and counters (PR 25, and PR 27's `wire.*` on the engine's own
-loop): in a `--trace 1` run of each cell, every such file that lists the
-cell returns a number. The values are a CPU's:
+occupancy and counters (PR 25, PR 27's `wire.*` on the engine's own loop,
+and every `span` / `counter` file added since): such a file is admitted by
+what it is, not by a count, and in a `--trace 1` run of each cell every
+such file that lists the cell returns a number. The values are a CPU's:
 no number from here is a device metric."""
 
 import glob
@@ -10,6 +11,8 @@ import os
 
 import pytest
 from test_benchmark import ROOT, TINY_CELLS, _run, tiny_dir  # noqa: F401
+
+from benchmark.lib import layers
 
 ACCEPTED = {  # the per-layer metrics PR 24's benchmark had
     "engine.dispatch_p50_us", "engine.reply_us_per_frame",
@@ -36,33 +39,24 @@ POSITIVE = {
     "sharded.tx_us_per_frame",
     "wire.ring_us_per_frame", "wire.dispatch_p50_us", "wire.device_p50_us",
     "wire.device_wait_p50_us", "wire.reply_us_per_frame",
-    "wire.frames_per_step"}
+    "wire.frames_per_step",
+    "pppoe.decap_per_step", "pppoe.encap_per_step",
+    "dualstack.v6_fwd_per_step", "engine.drain_cached_per_step",
+    "sched.drain_cached_per_step"}
 
 
 def test_the_new_files_are_data_and_run_on_a_program_without_the_spans():
-    """The driver lays these files over the parent's checkout too, whose
-    `STAGE_NAMES` and `LANE_NAMES` lacked what PR 25 added, and until PR 27
-    `read_span` raised on a name it did not find. So PR 25's `span` files
-    name only stages and lanes its parent had, and everything new went
-    through `counter`, which returns nothing where the path is missing;
-    PR 27's `wire.*` span files name lane `ring`, which PR 25's parent had
-    too. Since PR 27 a span file may name any stage: the reader returns
-    nothing for one the program lacks (test_benchmark.py)."""
-    parent_stages = {"ring", "admit", "lane_wait", "dispatch", "loop_fill",
-                     "loop_wait", "loop_retire", "device", "device_wait",
-                     "fleet", "worker", "slow_path", "reply", "ops",
-                     "wire_rx", "wire_tx", "total"}
-    parent_lanes = {"engine", "express", "bulk", "ring", "bench"}
-    assert len(NEW) == 29 + 8  # PR 25's, and PR 27's wire.* less the device trace's
+    """The rule that admits a `span` or `counter` file, whatever their
+    number: its `source` is its reader's, it is a `.json` under benchmark/,
+    and on a program without the Tracer's `trace` subtree its reader returns
+    nothing. The driver lays these files over the parent's checkout too: a
+    span file may name any stage or lane, the reader returns nothing for one
+    the program lacks (test_benchmark.py), and a counter path that is
+    missing reads as nothing. The tails a file may read are the next test's,
+    a number in every cell it lists the last one's."""
+    assert NEW
     for m in NEW.values():
-        read = m["read"]
-        assert m["source"] == {"span": "program_span",
-                               "counter": "program_counter"}[read["kind"]]
-        if read["kind"] == "span":
-            assert read["stage"] in parent_stages, m["name"]
-            assert read.get("lane", "bulk") in parent_lanes, m["name"]
-    # without the program's `trace` subtree a counter file reads nothing
-    from benchmark.lib import layers
+        assert m["source"] == layers.SOURCE_OF_KIND[m["read"]["kind"]], m["name"]
 
     class Plan:
         flood = True
@@ -77,7 +71,7 @@ def test_the_new_files_are_data_and_run_on_a_program_without_the_spans():
                                   recursive=True)
              if os.path.isfile(p) and "__pycache__" not in p
              and os.path.basename(p)[:-5] in NEW]
-    assert added and all(p.endswith(".json") for p in added)
+    assert len(added) == len(NEW) and all(p.endswith(".json") for p in added)
 
 
 def test_a_snapshot_serves_the_tails_a_file_reads_and_no_others():
@@ -129,3 +123,55 @@ def test_every_new_layer_file_returns_a_number_in_its_cell(tiny_dir, capsys,  # 
     # the `trace` subtree is in both, so every counter file above read it
     shares = [n for n in want if "unattributed_share" in n]
     assert shares and all(got[n]["value"] < 100.0 for n in shares)
+
+
+def test_a_counter_file_dropped_in_is_admitted_with_no_test_edited(tmp_path):
+    """The rule at work: a copy of the benchmark with its tests, one more
+    counter file in `layers/` and its entry in `BENCHMARK.json`, and the
+    tests that admit such a file, as they stand, pass over the copy. What
+    the file reads in its cell is the parametrised rehearsal's to show
+    (tests/test_dualstack_cell_rehearsal.py drops the same kind of file
+    into a copy and reads a number from it)."""
+    import shutil
+    import subprocess
+    import sys
+
+    for part in ("benchmark", os.path.join("tests", "benchmark")):
+        shutil.copytree(os.path.join(ROOT, part), tmp_path / part,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    cell = "pppoe-cgnat-1M-wire.flood-64B"
+    extra = {"name": "pppoe.frames_per_step", "unit": "frames",
+             "better": "higher", "source": "program_counter",
+             "layer": "engine (runtime/engine.py)", "moves": "served_kpps",
+             "cells": [cell],
+             "read": {"kind": "counter", "path": "ring.rx",
+                      "per": "engine.batches"}}
+    assert extra["name"] not in NEW
+    with open(tmp_path / "benchmark" / "layers" / (extra["name"] + ".json"),
+              "w") as f:
+        json.dump(extra, f)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = {k: v for k, v in extra.items() if k not in ("cells", "read")}
+    bench["per_layer"].append(dict(entry, workloads=[cell]))
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    here = os.path.join("tests", "benchmark")
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith(("PYTEST_", "COV_"))}
+    env.update(PYTHONPATH=ROOT, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:xdist", "-p", "no:randomly",
+         os.path.join(here, "test_trace_layers.py") + "::test_the_new_files_"
+         "are_data_and_run_on_a_program_without_the_spans",
+         os.path.join(here, "test_trace_layers.py") + "::test_a_snapshot_"
+         "serves_the_tails_a_file_reads_and_no_others",
+         os.path.join(here, "test_benchmark.py") + "::test_layer_files_and_"
+         "benchmark_json_agree",
+         os.path.join(here, "test_benchmark.py") + "::test_names_units_and_"
+         "lengths"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=tmp_path)
+    assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-2000:]
+    assert "4 passed" in out.stdout, out.stdout[-2000:]
+
