@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 
 from bng_tpu.ops.hashing import SEED1, SEED2, hash_words
+from bng_tpu.telemetry import spans as tele
 
 WAYS = 4
 SLOT_W = 8  # words per way row
@@ -493,8 +494,11 @@ class HostQTable:
         np.bitwise_or.at(ways, np.searchsorted(rr, ss // ROW_SLOTS),
                          np.uint32(1) << (ss % ROW_SLOTS).astype(np.uint32))
         rows[:n] = to_stored(self.rows)[rr[:n]]
-        return QTableUpdate(row=jnp.asarray(row), ways=jnp.asarray(ways),
-                            rows=jnp.asarray(rows))
+        t0 = tele.t()  # a drain that ships something shows as calls
+        upd = QTableUpdate(row=jnp.asarray(row), ways=jnp.asarray(ways),
+                           rows=jnp.asarray(rows))
+        tele.xfer(tele.UPLOAD, t0, row.nbytes + ways.nbytes + rows.nbytes, 3)
+        return upd
 
     def empty_update(self, max_slots: int) -> QTableUpdate:
         """All-padding QTableUpdate (no-op scatter), built without touching

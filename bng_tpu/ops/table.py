@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from bng_tpu.ops.hashing import SEED1, SEED2, hash_words, mix32
+from bng_tpu.telemetry import spans as tele
 
 WAYS = 4  # slots per bucket; one bucket = one contiguous gather
 MAX_KICKS = 128  # bounded cuckoo eviction walk (host side)
@@ -296,7 +297,9 @@ def placed(owner, name: str, host: np.ndarray) -> jax.Array:
     if hit is None or hit[0] != now:
         # from a copy: on the CPU backend asarray may alias host memory,
         # and the owner writes its array in place
+        t0 = tele.t()
         hit = cache[name] = (now, jnp.asarray(host.copy()))
+        tele.xfer(tele.UPLOAD, t0, host.nbytes)
     return hit[1]
 
 
@@ -590,11 +593,15 @@ class HostTable:
             ts = np.asarray(take, dtype=np.int32)
             idx[:n] = ts
             vv[:n] = self.vals[ts]
-        return TableUpdate(
+        t0 = tele.t()  # a drain that ships something shows as calls
+        upd = TableUpdate(
             bidx=jnp.asarray(bidx), brows=jnp.asarray(brows),
             sidx=jnp.asarray(sidx), srows=jnp.asarray(srows),
             idx=jnp.asarray(idx), vals=jnp.asarray(vv),
         )
+        tele.xfer(tele.UPLOAD, t0, bidx.nbytes + brows.nbytes + sidx.nbytes
+                  + srows.nbytes + idx.nbytes + vv.nbytes, 6)
+        return upd
 
     def empty_update(self, max_slots: int) -> TableUpdate:
         """An all-padding TableUpdate (applying it is a no-op scatter).

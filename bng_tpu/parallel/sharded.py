@@ -675,8 +675,25 @@ class ShardedCluster:
 
     # ---- device sync ----
     def _stack(self, arrs, spec):
+        """One leaf of every shard, stacked on the host and placed over
+        the mesh: a read back a shard (`fetch`, those that live on a
+        device) and one placement (`upload`)."""
+        t0 = tele.t()
         stacked = np.stack([np.asarray(a) for a in arrs])
-        return jax.device_put(stacked, NamedSharding(self.mesh, spec))
+        tele.fetched(t0, *arrs)
+        t0 = tele.t()
+        out = jax.device_put(stacked, NamedSharding(self.mesh, spec))
+        tele.xfer(tele.UPLOAD, t0, stacked.nbytes)
+        return out
+
+    @staticmethod
+    def _to_chip(host: np.ndarray):
+        """A shard's dense config array to the default device, as every
+        drain ships it (and `_stack` reads it back): an `upload`."""
+        t0 = tele.t()
+        out = jnp.asarray(host)
+        tele.xfer(tele.UPLOAD, t0, host.nbytes)
+        return out
 
     def _stack_per_shard(self, per_shard):
         """Stack a per-shard pytree list on the mesh axis (the one
@@ -712,11 +729,11 @@ class ShardedCluster:
                 self.qos[i].up.make_update(self.qos[i].update_slots),
                 self.qos[i].down.make_update(self.qos[i].update_slots),
                 self.antispoof_upd(i),
-                jnp.asarray(self.spoof[i].ranges),
-                jnp.asarray(self.spoof[i].config),
+                self._to_chip(self.spoof[i].ranges),
+                self._to_chip(self.spoof[i].config),
                 *((self.garden[i].subscribers.make_update(
                        self.garden[i].update_slots),
-                   jnp.asarray(self.garden[i].allowed))
+                   self._to_chip(self.garden[i].allowed))
                   if self.garden is not None else ()),
                 *(self.pppoe[i].make_updates()
                   if self.pppoe is not None else ()),
@@ -782,6 +799,8 @@ class ShardedCluster:
         sh = NamedSharding(self.mesh, P(AXIS))
         pkt_d = jax.device_put(pkt, sh)
         len_d = jax.device_put(length.astype(np.uint32), sh)
+        if t0 is not None:  # `pack` here is two placements and no more
+            tele.xfer(tele.UPLOAD, t0, pkt.nbytes + 4 * len(length), 2)
         tele.lap(tele.PACK, t0)
         t0 = tele.t()
         upd = self._drain_fastpath()
@@ -805,6 +824,9 @@ class ShardedCluster:
         pkt_d = jax.device_put(pkt, sh)
         len_d = jax.device_put(length.astype(np.uint32), sh)
         fa_d = jax.device_put(from_access, sh)
+        if t0 is not None:  # `pack` here is three placements and no more
+            tele.xfer(tele.UPLOAD, t0, pkt.nbytes + 4 * len(length)
+                      + from_access.nbytes, 3)
         tele.lap(tele.PACK, t0)
         # drain FIRST: a bulk-build resync rebinds self.tables, and Python
         # evaluates arguments left-to-right — reading self.tables before
@@ -838,10 +860,11 @@ class ShardedCluster:
             raise
         tele.device_up(tok)
         t0 = tele.t()
+        tf = tele.ready(is_reply, tok)  # armed: the wait, then the reads
         out = {"is_reply": np.asarray(is_reply)}
-        tele.device_down(tok)
         out.update(out_pkt=out_pkt, out_len=np.asarray(out_len),
                    dhcp_stats=np.asarray(stats))
+        tele.fetched(tf, is_reply, out_len, stats, tok=tok)
         tele.lap(tele.DEVICE_WAIT, t0, tok)
         self.telemetry.record_dhcp(
             length, out["is_reply"], int(out["dhcp_stats"][ST_HIT]))
@@ -1018,8 +1041,8 @@ class ShardedCluster:
         t0 = tele.t()
         if out[0] == "dhcp":
             _, is_reply, out_pkt, out_len, stats = out
+            tf = tele.ready(is_reply, tok)  # armed: the wait, then the reads
             is_reply_h = np.asarray(is_reply)
-            tele.device_down(tok)
             verdict = np.where(is_reply_h, np.uint8(VERDICT_TX),
                                np.uint8(VERDICT_PASS))
             punt = np.zeros((B,), dtype=bool)
@@ -1029,6 +1052,7 @@ class ShardedCluster:
             self._fold_stats(dhcp=stats_h)
             out_pkt_h = np.asarray(out_pkt)
             out_len_h = np.asarray(out_len).astype(np.uint32)
+            tele.fetched(tf, is_reply, stats, out_pkt, out_len, tok=tok)
             tele.lap(tele.DEVICE_WAIT, t0, tok)
             t0 = tele.t()
             self.telemetry.record_dhcp(length, is_reply_h,
@@ -1041,8 +1065,8 @@ class ShardedCluster:
             p_stats = tails.pop(0) if self.pppoe is not None else None
             mir = tails.pop(0) if self.edge is not None else None
             e_stats = tails.pop(0) if self.edge is not None else None
+            tf = tele.ready(verdict_d, tok)  # armed: the wait, then the reads
             verdict = np.asarray(verdict_d).astype(np.uint8)
-            tele.device_down(tok)
             punt = np.asarray(nat_punt)
             viol = np.asarray(viol_d)
             dhcp_h = np.asarray(dhcp_stats)
@@ -1058,6 +1082,9 @@ class ShardedCluster:
                                    if e_stats is not None else None))
             out_pkt_h = np.asarray(out_pkt)
             out_len_h = np.asarray(out_len).astype(np.uint32)
+            tele.fetched(tf, verdict_d, nat_punt, viol_d, dhcp_stats,
+                         nat_stats, qos_stats, spoof_stats, g_stats, p_stats,
+                         e_stats, out_pkt, out_len, tok=tok)
             tele.lap(tele.DEVICE_WAIT, t0, tok)
             self._probe(self._inflight)
             t0 = tele.t()
@@ -1089,7 +1116,9 @@ class ShardedCluster:
                 violation_sink(int(lane),
                                bytes(pkt[lane, : int(length[lane])]))
         if mir is not None and self.mirror_sink is not None:
+            t1 = tele.t()
             mirw = np.asarray(mir)
+            tele.fetched(t1, mir, tok=tok)
             for lane in np.nonzero((mirw != 0) & real)[0]:
                 # interception observes the ORIGINAL ring bytes even on
                 # lanes the verdict demux above dropped (Engine parity)
@@ -1214,8 +1243,8 @@ class ShardedCluster:
         pppoe_stats = [tails.pop(0)] if self.pppoe is not None else []
         edge_out = list(tails[:2]) if self.edge is not None else []
         t0 = tele.t()
+        tf = tele.ready(verdict, tok)  # armed: the wait, then the reads
         verdict_h = np.asarray(verdict)
-        tele.device_down(tok)
         res = {
             "verdict": verdict_h,
             "out_pkt": out_pkt,
@@ -1234,6 +1263,9 @@ class ShardedCluster:
                 "edge_stats": np.asarray(edge_out[1])}
                if edge_out else {}),
         }
+        tele.fetched(tf, verdict, out_len, dhcp_stats, nat_stats, qos_stats,
+                     spoof_stats, nat_punt, viol, *garden_stats,
+                     *pppoe_stats, *edge_out, tok=tok)
         tele.lap(tele.DEVICE_WAIT, t0, tok)
         self.telemetry.record_fused(
             length, res["verdict"], res["nat_punt"], res["violation"],

@@ -728,8 +728,8 @@ class Engine:
         # read self.tables AFTER the drain (a bulk-build resync rebinds it)
         tables_in = self.tables._replace(dhcp=dhcp_replica)
         res: PipelineResult = self._step(
-            tables_in, upd, jnp.asarray(pkt), jnp.asarray(length),
-            jnp.asarray(fa), now_s, now_us)
+            tables_in, upd, *self._upload_batch(pkt, length, fa),
+            now_s, now_us)
         # keep the authoritative dhcp chain out of the bulk rebind; the
         # replica-out threads back to the scheduler
         self.tables = res.tables._replace(dhcp=self.tables.dhcp)
@@ -1010,6 +1010,7 @@ class Engine:
         # asarray then creates a fresh device buffer — but a jax-array
         # input would ALIAS the caller's live buffer into the donation,
         # so copy it defensively rather than consume it.
+        tu = tele.t()
         pkt_d = (jnp.array(pkt, copy=True) if isinstance(pkt, jax.Array)
                  else jnp.asarray(pkt))
         len_d = jnp.asarray(length)
@@ -1020,6 +1021,9 @@ class Engine:
             upd = jax.device_put(upd, device)
             pkt_d = jax.device_put(pkt_d, device)
             len_d = jax.device_put(len_d, device)
+        if tu is not None:  # the two from the host; the placement is
+            # device to device
+            tele.xfer(tele.UPLOAD, tu, pkt.nbytes + length.nbytes, 2)
         dhcp_tables, is_reply, out_pkt, out_len, stats = self._dhcp_step(
             self.tables.dhcp, upd, pkt_d, len_d,
             np.uint32(int(now)))
@@ -1109,6 +1113,7 @@ class Engine:
         # lead columns. Callers stage from numpy (fresh device buffer);
         # a jax-array input would alias the caller's LIVE buffer into
         # the donation, so copy it defensively rather than consume it.
+        tu = tele.t()
         desc_d = (jnp.array(desc, copy=True) if isinstance(desc, jax.Array)
                   else jnp.asarray(desc))
         if device is not None:
@@ -1123,6 +1128,8 @@ class Engine:
             # arrays itself; an explicit device_put here costs ~0.3ms
             # of pure ceremony per dispatch on CPU
             now_d = jnp.uint32(int(now))
+        if tu is not None:  # the descriptors and the clock word
+            tele.xfer(tele.UPLOAD, tu, desc.nbytes + 4, 2)
         dhcp_tables, block, stats = express_exe(
             self.tables.dhcp, upd, desc_d, now_d)
         self.tables = self.tables._replace(dhcp=dhcp_tables)
@@ -1141,14 +1148,29 @@ class Engine:
         # drain FIRST: a bulk-build resync rebinds self.tables, and Python
         # evaluates arguments left-to-right — reading self.tables before
         # the drain would pass (and donate) the stale pre-resync reference
+        t0 = tele.t()
         upd = self._drain_updates()
-        res: PipelineResult = self._step(
-            self.tables, upd, jnp.asarray(pkt), jnp.asarray(length),
-            jnp.asarray(fa), now_s, now_us,
-        )
+        tele.lap(tele.DRAIN, t0)
+        staged = self._upload_batch(pkt, length, fa)
+        res: PipelineResult = self._step(self.tables, upd, *staged,
+                                         now_s, now_us)
         self.tables = res.tables
         self.stats.batches += 1
         return res
+
+    @staticmethod
+    def _upload_batch(pkt, length, fa):
+        """The staged window to the device: packet slots, lengths, access
+        flags, three host-to-device calls under one `upload` lap (the time
+        to return; the copies land while the call into the step is made).
+        `now_s` / `now_us` are numpy scalars: they cross inside the call
+        into the step, `dispatch`'s own."""
+        t0 = tele.t()
+        staged = (jnp.asarray(pkt), jnp.asarray(length), jnp.asarray(fa))
+        if t0 is not None:
+            tele.xfer(tele.UPLOAD, t0,
+                      pkt.nbytes + length.nbytes + fa.nbytes, 3)
+        return staged
 
     @staticmethod
     def _dispatch_fault() -> None:
@@ -1165,6 +1187,10 @@ class Engine:
                 time.sleep(min(max(fp.arg, 0.0), 0.05))
 
     def _fold_stats(self, res: PipelineResult) -> None:
+        """Fold a retired step's stats blocks into the host's counters:
+        one small read a block, under one `fetch` lap (no parent stage:
+        both retires call this between their laps)."""
+        t0 = tele.t()
         self.stats.dhcp += np.asarray(res.dhcp_stats, dtype=np.uint64)
         self.stats.nat += np.asarray(res.nat_stats, dtype=np.uint64)
         self.stats.qos += np.asarray(res.qos_stats, dtype=np.uint64)
@@ -1172,21 +1198,23 @@ class Engine:
         gs = getattr(res, "garden_stats", None)  # DHCP-only batches have none
         if gs is not None:
             self.stats.garden += np.asarray(gs, dtype=np.uint64)
-        ps = getattr(res, "pppoe_stats", None)
-        if ps is not None:
-            ps = np.asarray(ps, dtype=np.uint64)
+        ps_d = getattr(res, "pppoe_stats", None)
+        if ps_d is not None:
+            ps = np.asarray(ps_d, dtype=np.uint64)
             self.stats.pppoe += ps
             tele.pppoe_lanes(int(ps[PST_DECAP]), int(ps[PST_ENCAP]),
                              int(ps[PST_MISS]))
         es = getattr(res, "edge_stats", None)
         if es is not None:
             self.stats.edge += np.asarray(es, dtype=np.uint64)
-        vs = getattr(res, "v6_stats", None)
-        if vs is not None:
-            vs = np.asarray(vs, dtype=np.uint64)
+        vs_d = getattr(res, "v6_stats", None)
+        if vs_d is not None:
+            vs = np.asarray(vs_d, dtype=np.uint64)
             self.stats.v6 += vs
             tele.v6_lanes(int(vs[V6ST_FWD_UP] + vs[V6ST_FWD_DOWN]),
                           int(vs[V6ST_MISS]), int(vs[V6ST_CTRL]))
+        tele.fetched(t0, res.dhcp_stats, res.nat_stats, res.qos_stats,
+                     res.spoof_stats, gs, ps_d, es, vs_d)
 
     def _run_step(self, pkt, length, fa, now_s, now_us) -> PipelineResult:
         """Dispatch + fold (the synchronous step both process paths use)."""
@@ -1245,10 +1273,13 @@ class Engine:
                              n: int, now: float, tok=None) -> None:
         """Force the step's outputs and demux verdicts back to the ring."""
         t0 = tele.t()
+        # armed: the wait apart from the reads (the pipelined loop's
+        # window, seen ready); disarmed the first read waits and copies
+        tf = tele.ready(res.verdict, tok)
         vv = np.asarray(res.verdict)[:n]
-        tele.device_down(tok)  # the pipelined loop's window, seen ready
         out_pkt = np.asarray(res.out_pkt)
         out_len = np.asarray(res.out_len).astype(np.uint32)
+        tele.fetched(tf, res.verdict, res.out_pkt, res.out_len)
         tele.lap(tele.DEVICE_WAIT, t0)
         t0 = tele.t()
         ring.complete(vv.astype(np.uint8), out_pkt, out_len, n)
@@ -1258,15 +1289,22 @@ class Engine:
         self.stats.dropped += int((vv == VERDICT_DROP).sum())
         self.stats.passed += int((vv == VERDICT_PASS).sum())
 
+        # the flags the demux below needs, read side by side: one `fetch`
+        # lap inside `reply`
+        tf = tele.t()
         viol = np.asarray(res.spoof_violation)[:n]
+        punt = np.asarray(res.nat_punt)[:n]
+        mir = getattr(res, "mirror", None)  # DHCP-only batches have none
+        mirw = (np.asarray(mir)[:n]
+                if mir is not None and self.mirror_sink is not None else None)
+        tele.fetched(tf, res.spoof_violation, res.nat_punt,
+                     mir if mirw is not None else None)
         for lane in np.nonzero(viol)[0]:
             self._viol_log.report(ValueError("spoofed source address"),
                                   path="ring", lane=int(lane))
             if self.violation_sink is not None:
                 self.violation_sink(int(lane), bytes(pkt[lane, : int(length[lane])]))
-        mir = getattr(res, "mirror", None)  # DHCP-only batches have none
-        if mir is not None and self.mirror_sink is not None:
-            mirw = np.asarray(mir)[:n]
+        if mirw is not None:
             for lane in np.nonzero(mirw)[0]:
                 # original ring bytes: interception sees the frame as it
                 # arrived, regardless of the verdict demux above
@@ -1282,7 +1320,6 @@ class Engine:
         # server's socket-write role). Per-frame handler errors must not
         # abort the drain: a partially drained slow ring would misalign
         # every later batch's lane/punt matching (and wedge PyRing).
-        punt = np.asarray(res.nat_punt)[:n]
         slow_items = []  # (lane, frame); from_access flags kept aside
         slow_fa = {}
         punts = 0

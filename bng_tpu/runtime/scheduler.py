@@ -506,9 +506,11 @@ class TieredScheduler:
         n = len(entry.pending)
         tele.focus(entry.trace)
         t0 = tele.t()
+        # armed: the wait apart from the reads (spans.py ready)
+        tf = tele.ready(res.verdict, entry.trace)
         verdict = np.asarray(res.verdict)[:n]
-        tele.device_down(entry.trace)
         out_len = np.asarray(res.out_len)
+        tele.fetched(tf, res.verdict, res.out_len)
         tele.lap(tele.DEVICE_WAIT, t0, entry.trace)
         out_rows = None
         eng._fold_stats(res)
@@ -525,7 +527,7 @@ class TieredScheduler:
         for i, p in enumerate(entry.pending):
             if verdict[i] == VERDICT_TX:
                 if out_rows is None:
-                    out_rows = np.asarray(res.out_pkt)
+                    out_rows = self._fetch_rows(res.out_pkt)
                 frame = bytes(out_rows[i, : int(out_len[i])])
                 eng.stats.tx += 1
                 self._complete(p, LANE_EXPRESS, "tx", frame, now)
@@ -548,8 +550,9 @@ class TieredScheduler:
         n = len(entry.pending)
         tele.focus(entry.trace)
         t0 = tele.t()
+        tf = tele.ready(entry.res.block, entry.trace)
         block = np.asarray(entry.res.block)[:n]
-        tele.device_down(entry.trace)
+        tele.fetched(tf, entry.res.block)
         tele.lap(tele.DEVICE_WAIT, t0, entry.trace)
         eng._fold_stats(entry.res)
         now = self.clock()
@@ -764,11 +767,14 @@ class TieredScheduler:
         n = len(entry.pending)
         tele.focus(entry.trace)
         t0 = tele.t()
+        # armed: the wait apart from the reads (spans.py ready)
+        tf = tele.ready(res.verdict, entry.trace)
         vv = np.asarray(res.verdict)[:n]
-        tele.device_down(entry.trace)
         out_len = np.asarray(res.out_len)
         punt = np.asarray(res.nat_punt)[:n]
         viol = np.asarray(res.spoof_violation)[:n]
+        tele.fetched(tf, res.verdict, res.out_len, res.nat_punt,
+                     res.spoof_violation)
         tele.lap(tele.DEVICE_WAIT, t0, entry.trace)
         out_rows = None
         eng._fold_stats(res)
@@ -795,7 +801,7 @@ class TieredScheduler:
             v = int(vv[i])
             if v == VERDICT_TX or v == VERDICT_FWD:
                 if out_rows is None:
-                    out_rows = np.asarray(res.out_pkt)
+                    out_rows = self._fetch_rows(res.out_pkt)
                 frame = bytes(out_rows[i, : int(out_len[i])])
                 kind = "tx" if v == VERDICT_TX else "fwd"
                 if v == VERDICT_TX:
@@ -816,6 +822,15 @@ class TieredScheduler:
         tele.end_batch(entry.trace, punt=punts)
         self._observe_retire(LANE_BULK, entry, now)
         return n
+
+    @staticmethod
+    def _fetch_rows(out_pkt) -> np.ndarray:
+        """A retired batch's packet slots to the host, whole, at the first
+        lane that needs its bytes: one `fetch` lap inside `reply`."""
+        t0 = tele.t()
+        rows = np.asarray(out_pkt)
+        tele.fetched(t0, out_pkt)
+        return rows
 
     # -- completion delivery / observability -----------------------------
 

@@ -102,8 +102,9 @@ class SLOSpec:
 # burn_windows windows. `device` carries the paper target itself and
 # reads the EXPRESS lane only: that lane's `device` samples are an
 # express dispatch's occupancy BY READINESS on the served path (scheduler
-# retire; an upper bound on execution: launch latency, the outputs' copy
-# and the delay until the host looks are inside it, spans.py). Nothing
+# retire; an upper bound on execution: launch latency and the delay until
+# the host looks are inside it; the outputs' copy is not, an armed retire
+# blocks on its first output before it reads any: spans.py `ready`). Nothing
 # else feeds that lane: a bulk step's sample goes to lane `bulk`. The
 # target is NOT met today, so a `bng run --telemetry-enabled` monitor breaches
 # `device` in every window with express traffic, and is meant to: on a
@@ -117,9 +118,6 @@ DEFAULT_SLOS: tuple[SLOSpec, ...] = (
     SLOSpec("lane_wait", 50_000.0,
             description="scheduler enqueue -> dispatch (oldest frame)"),
     SLOSpec("dispatch", 50_000.0, description="host-side jitted dispatch"),
-    SLOSpec("loop_fill", 2_000.0, description="unstamped (spans.py)"),
-    SLOSpec("loop_wait", 100_000.0, description="unstamped (spans.py)"),
-    SLOSpec("loop_retire", 50_000.0, description="unstamped (spans.py)"),
     SLOSpec("device", HEADLINE_TARGETS["offer_device_only_p99_us"],
             lane="express",
             description="express dispatch on the device, by readiness "
@@ -146,6 +144,12 @@ DEFAULT_SLOS: tuple[SLOSpec, ...] = (
     SLOSpec("tx", 20_000.0, description="completions -> TX ring, per beat"),
     SLOSpec("sojourn", 1_000_000.0,
             description="per frame, enqueue -> completion"),
+    SLOSpec("upload", 200_000.0,
+            description="host inside host-to-device calls of a batch "
+                        "(time to return, not to land)"),
+    SLOSpec("fetch", 200_000.0,
+            description="host inside device-to-host reads of a batch's "
+                        "outputs, after they were seen ready"),
     SLOSpec("total", 500_000.0, description="batch begin -> end"),
 )
 

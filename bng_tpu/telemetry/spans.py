@@ -45,7 +45,28 @@ order:
        tx          completions -> ring.tx_inject / ring.complete
        sojourn     per frame, enqueue -> completion, by lane (fed once a
                    retired batch through observe_many)
+       upload      the host thread inside a host-to-device call on the hot
+                   path (`jnp.asarray` / `jax.device_put` of packet
+                   staging, lengths, flags, descriptors, and of an update
+                   batch that holds dirty slots). The time TO RETURN, not
+                   to land: the call comes back once the copy is queued
+                   (PR 35, the 12.6 MB staging triple: 1,052 us to return,
+                   2,902 landed); where the bytes land is the device
+                   trace's to show. A numpy scalar handed to a jitted call
+                   crosses inside that call: `dispatch`'s, not a lap here
+       fetch       the host thread inside a device-to-host read of a
+                   step's outputs (`np.asarray(res.<leaf>)`), AFTER the
+                   step was seen ready: armed, a retire blocks on its
+                   first output alone (`ready`), so `device_wait` less
+                   its `fetch` children is the wait and `fetch` the copies
        total       batch begin -> end (the client-visible wall time)
+
+   `upload` and `fetch` are children of the laps that enclose them
+   (`dispatch`, `drain`, `pack`; `device_wait`, `reply`) or stand under no
+   parent (`Engine._fold_stats`). A child closes before its parent, so it
+   takes the starvation under it and the parent keeps the rest: a parent
+   stage's `starved_ns` is its SELF share. `stage_ns` stays a plain sum
+   of samples (a child's time is in its parent's too).
 
 3. **Tracing is observation.** A span never mutates subsystem state;
    arming swaps one module global; telemetry failures never fault the
@@ -56,6 +77,17 @@ Two granularities:
 - `t()` / `lap(stage, t0)` — the hot-path pair: `t()` returns None when
   disarmed, `lap` no-ops on a None origin. Two hook calls per
   instrumented region.
+- `xfer(stage, t0, nbytes, calls=1)` — `lap` for a crossing between host
+  and chip (`upload` / `fetch`): the same lap, and four running counts
+  beside it, served by `sums()["xfer"]`: `upload_calls`, `upload_bytes`,
+  `fetch_calls`, `fetch_bytes`. Adjacent calls may share one lap with
+  `calls=k`. PR 35 found one `jnp.asarray` costs 262-306 us to return
+  whatever it holds: the count of crossings a step, not their bytes, sets
+  the cost. `ready(out, tok)` is its companion at a retire: armed it
+  blocks on `out`, says `device_down(tok)` and returns the origin of the
+  `fetch` lap that follows; disarmed it returns None and forces nothing.
+  `fetched(t0, *outs)` closes that lap: `xfer` with the bytes and the
+  count of the outputs that live on a device.
 - `span(stage)` — context-manager sugar for coarse paths (CLI, tests).
 
 Tiling (the one clock inside the loop): `beat_begin()` / `beat_end()`
@@ -102,16 +134,14 @@ from bng_tpu.telemetry.hist import LatencyHist
 # blue/green engine swap phases — runtime/ops.py, control/fleet.py):
 # each transition phase records one lap, so the histogram answers "how
 # long do operational state moves stall the dataplane". Nothing stamps
-# the loop_* stages or the `bench` lane; they hold their positions until
-# the benchmark's layer files stop naming them (ROADMAP B0).
-(RING, ADMIT, LANE_WAIT, DISPATCH, LOOP_FILL, LOOP_WAIT, LOOP_RETIRE,
- DEVICE, DEVICE_WAIT, FLEET, WORKER, SLOW, REPLY, OPS, WIRE_RX, WIRE_TX,
- BEAT, PACK, DRAIN, TX, SOJOURN, TOTAL) = range(22)
-STAGE_NAMES = ("ring", "admit", "lane_wait", "dispatch", "loop_fill",
-               "loop_wait", "loop_retire", "device", "device_wait",
-               "fleet", "worker", "slow_path", "reply", "ops", "wire_rx",
-               "wire_tx", "beat", "pack", "drain", "tx", "sojourn",
-               "total")
+# the `bench` lane; tests/test_slo.py feeds it.
+(RING, ADMIT, LANE_WAIT, DISPATCH, DEVICE, DEVICE_WAIT, FLEET, WORKER, SLOW,
+ REPLY, OPS, WIRE_RX, WIRE_TX, BEAT, PACK, DRAIN, TX, SOJOURN, UPLOAD, FETCH,
+ TOTAL) = range(21)
+STAGE_NAMES = ("ring", "admit", "lane_wait", "dispatch", "device",
+               "device_wait", "fleet", "worker", "slow_path", "reply", "ops",
+               "wire_rx", "wire_tx", "beat", "pack", "drain", "tx",
+               "sojourn", "upload", "fetch", "total")
 NSTAGES = len(STAGE_NAMES)
 
 # lane ids for batch records
@@ -191,6 +221,10 @@ class Tracer:
         # to the host for a destination it does not hold, and passed as
         # control (engine.py _fold_stats); 0 in a program without the stage
         self.v6_fwd = self.v6_miss = self.v6_ctrl = 0
+        # crossings between host and chip on the hot path (xfer): calls
+        # and bytes by direction, [upload, fetch]
+        self.xfer_calls = [0, 0]
+        self.xfer_bytes = [0, 0]
         self._frozen: dict | None = None  # sums() as finish() left them
 
     # -- batch records ----------------------------------------------------
@@ -269,6 +303,13 @@ class Tracer:
         self.stage_ns[stage] += now - t0
         self._log(stage, tok, t0, now - t0)
         self._cover_lap(stage, t0, now)
+
+    def xfer(self, stage: int, t0: int, nbytes: int, calls: int = 1,
+             tok: int | None = None) -> None:
+        """A lap of `upload` or `fetch`, and what crossed under it."""
+        self.lap(stage, t0, tok)
+        self.xfer_calls[stage - UPLOAD] += calls
+        self.xfer_bytes[stage - UPLOAD] += nbytes
 
     def stamp(self, stage: int, tok: int | None = None) -> None:
         """Point event: ns offset of reaching `stage` within the open
@@ -543,6 +584,10 @@ class Tracer:
             "v6_fwd": int(self.v6_fwd),
             "v6_miss": int(self.v6_miss),
             "v6_ctrl": int(self.v6_ctrl),
+            "xfer": {"upload_calls": int(self.xfer_calls[0]),
+                     "upload_bytes": int(self.xfer_bytes[0]),
+                     "fetch_calls": int(self.xfer_calls[1]),
+                     "fetch_bytes": int(self.xfer_bytes[1])},
         }
 
     def write_events(self, path: str) -> None:
@@ -603,6 +648,45 @@ def lap(stage: int, t0: int | None, tok: int | None = None) -> None:
     if _ACTIVE is None or t0 is None:
         return
     _ACTIVE.lap(stage, t0, tok)
+
+
+def xfer(stage: int, t0: int | None, nbytes: int, calls: int = 1,
+         tok: int | None = None) -> None:
+    """Close an `upload` / `fetch` span opened with t() (or ready()): a
+    lap, and `calls` crossings of `nbytes` in all added to the running
+    counts. Disarmed: global load + None compare; a call site that has to
+    compute `nbytes` does so under `if t0 is not None`."""
+    if _ACTIVE is None or t0 is None:
+        return
+    _ACTIVE.xfer(stage, t0, nbytes, calls, tok)
+
+
+def ready(out, tok: int | None = None) -> int | None:
+    """Armed only: block until the device output `out` is ready, say so
+    for `tok`'s dispatch (device_down) and return the clock: the origin of
+    the `fetch` lap over the reads that follow, so the wait and the copies
+    are told apart. Disarmed: None, and NOTHING is forced: the first read
+    waits and copies in one call, as it always did."""
+    if _ACTIVE is None:
+        return None
+    wait = getattr(out, "block_until_ready", None)
+    if wait is not None:
+        wait()
+    _ACTIVE.device_down(tok)
+    return _ACTIVE.clock()
+
+
+def fetched(t0: int | None, *outs, tok: int | None = None) -> None:
+    """Close the `fetch` span over the reads of `outs`, a step's outputs:
+    `xfer` with the bytes and the count of those that live on a device (a
+    host array among them, as a DHCP-only batch's punt flags are, crosses
+    nothing; None is skipped). Disarmed: global load + None compare, and no
+    `nbytes` is computed."""
+    if _ACTIVE is None or t0 is None:
+        return
+    on_device = [a for a in outs if hasattr(a, "block_until_ready")]
+    _ACTIVE.xfer(FETCH, t0, sum(int(a.nbytes) for a in on_device),
+                 len(on_device), tok)
 
 
 def stamp(stage: int, tok: int | None = None) -> None:

@@ -23,10 +23,12 @@ SCOPES = ("parse", "antispoof", "dhcp", "garden", "nat44", "qos", "edge",
           "pppoe", "v6", "rewrite", "updates", "stats")
 BEAT = "bng.beat"  # the Tracer's anchor annotation (telemetry/spans.py)
 # stages that are laps of the host thread (the rest are fed durations:
-# lane_wait, device, sojourn; or span batches across beats: total)
-HOST_LAPS = ("ring", "admit", "dispatch", "loop_fill", "loop_retire",
-             "device_wait", "fleet", "slow_path", "reply", "ops", "wire_rx",
-             "wire_tx", "pack", "drain", "tx")
+# lane_wait, device, sojourn; or span batches across beats: total).
+# `upload` and `fetch` are children of other laps: the shortest lap over a
+# gap's midpoint names it, so a child wins over its parent
+HOST_LAPS = ("ring", "admit", "dispatch", "device_wait", "fleet",
+             "slow_path", "reply", "ops", "wire_rx", "wire_tx", "pack",
+             "drain", "tx", "upload", "fetch")
 
 
 def _xplane_pb2():
@@ -76,6 +78,14 @@ def _scope_of(op_path: str) -> str:
     control test inside `antispoof`) owns what it adds."""
     return next((part for part in reversed(op_path.split("/"))
                  if part in SCOPES), "(no scope)")
+
+
+def _lap_over(laps, at: float):
+    """The innermost lap (start, duration, stage) that covers `at`: the
+    shortest one, so a child (`fetch` inside `device_wait`, `upload` inside
+    `dispatch`) names a gap and not its parent."""
+    return min((ln for ln in laps if ln[0] <= at < ln[0] + ln[1]),
+               key=lambda ln: ln[1], default=None)
 
 
 def reduce_trace(trace_dir: str, events_path: str | None = None) -> dict:
@@ -157,8 +167,7 @@ def reduce_trace(trace_dir: str, events_path: str | None = None) -> dict:
     for t0, t1 in busy[1:]:
         if t0 > end:
             mid = (end + t0) / 2
-            inner = min((ln for ln in laps if ln[0] <= mid < ln[0] + ln[1]),
-                        key=lambda ln: ln[1], default=None)
+            inner = _lap_over(laps, mid)
             beat = next((a for a in anchors if a[0] <= mid < a[0] + a[1]),
                         None)
             what = (inner[2] if inner else "beat (no lap)" if beat

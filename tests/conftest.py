@@ -49,30 +49,3 @@ def _bng_sanitize(request):
             h2d="disallow" if sanitize.strict() else "allow"):
         yield
 
-
-# ---------------------------------------------------------------------------
-# tests/benchmark: the stand-in of a cell added since its literals were written
-# ---------------------------------------------------------------------------
-# `tiny_dir` (tests/benchmark/test_benchmark.py) maps every layer file's
-# cells through the literals TINY_CELLS / TINY_ARGV / BASE_OF, and a cell
-# they lack stops every rehearsal with a KeyError. Files under
-# tests/benchmark/ may be added and not edited, and its conftest.py exists
-# (PR 32's stand-in), so the stand-in of `dualstack-cgnat-1M-wire.flood-64B`
-# is registered from here, before the fixture reads the literals: whether
-# the whole directory runs or test_benchmark.py alone. The next `benchmark`
-# issue moves the entries into the literals (PERF.md section 7 row 1 xv).
-
-@pytest.fixture(scope="module", autouse=True)
-def _dualstack_cell_has_a_stand_in():
-    import sys
-
-    tb = sys.modules.get("test_benchmark")
-    if tb is None:  # a module that does not rehearse through tiny_dir
-        return
-    # 4,096 dual-stack subscribers, 128 of them behind NAT
-    tb.TINY_ARGV.setdefault("tiny-dualstack",
-                            tb.TINY_ARGV["tiny-wire"] + ["--ipv6-fastpath"])
-    tb.BASE_OF.setdefault("tiny-dualstack", "dualstack-cgnat-1M-wire")
-    tb.TINY_CELLS.setdefault(
-        "tiny-dualstack.flood",
-        ("dualstack-cgnat-1M-wire.flood-64B", "tiny-dualstack", "tiny-flood"))

@@ -31,19 +31,18 @@ REAL = "dualstack-cgnat-1M-wire.flood-64B"
 CELL = "tiny-dualstack-1024.flood-4096"
 FILES = ("dualstack_step.device_p50_us", "dualstack.loop_us_per_frame",
          "dualstack.gen_share", "dualstack.beat_p99_us")
-# the counter layer files a later `benchmark` issue adds (PERF.md section 7
-# row 1 xv; tests/benchmark/test_trace_layers.py pins the count of such
-# files): dropped into the copy as data, read with no edit to the harness
-COUNTERS = [{
-    "name": f"dualstack.v6_{k}_per_step", "unit": "lanes",
-    "better": "higher" if k == "fwd" else "lower",
-    "source": "program_counter", "layer": "engine (runtime/engine.py)",
-    "moves": "served_kpps", "cells": [CELL],
-    "read": {"kind": "counter", "path": f"engine.trace.v6_{k}",
-             "per": "engine.batches"}} for k in ("fwd", "miss", "ctrl")]
-FRAMES = dict(COUNTERS[0], name="dualstack.frames_per_step", unit="frames",
-              read={"kind": "counter", "path": "ring.rx",
-                    "per": "engine.batches"})
+# the cell's counter files (PR 36's, and PR 37's crossings a step): the tiny
+# cell is appended to the real files' `cells` in the copy; only
+# `dualstack.frames_per_step`, which the benchmark does not have, is dropped in
+COUNTERS = ("dualstack.v6_fwd_per_step", "dualstack.v6_miss_per_step",
+            "dualstack.v6_ctrl_per_step", "wire.upload_calls_per_step",
+            "wire.fetch_calls_per_step")
+FRAMES = {"name": "dualstack.frames_per_step", "unit": "frames",
+          "better": "higher", "source": "program_counter",
+          "layer": "engine (runtime/engine.py)", "moves": "served_kpps",
+          "cells": [CELL],
+          "read": {"kind": "counter", "path": "ring.rx",
+                   "per": "engine.batches"}}
 
 
 def _write(path, obj):
@@ -83,13 +82,13 @@ def cell_dir(tmp_path_factory):
     for m in bench["end_to_end"]:
         if m["name"] == "served_kpps":
             m["workloads"].append(CELL)
-    for name in FILES:
+    for name in (*FILES, *COUNTERS):
         m = applib.load_named("layers", name, bdir)
-        assert m["cells"] == [REAL] and not name.startswith("wire")
+        assert REAL in m["cells"]
+        assert m["cells"] == [REAL] or name.startswith("wire")
         m["cells"].append(CELL)
         _write(os.path.join(bdir, "layers", name + ".json"), m)
-    for m in (*COUNTERS, FRAMES):
-        _write(os.path.join(bdir, "layers", m["name"] + ".json"), m)
+    _write(os.path.join(bdir, "layers", FRAMES["name"] + ".json"), FRAMES)
     _write(os.path.join(top, "BENCHMARK.json"), bench)
     return bdir
 
@@ -131,7 +130,7 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     assert "dualstack_step.device_p50_us" not in got  # no device trace here
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
     assert said and "dualstack_step.device_p50_us" in said[0]
-    # the three counters, through `engine.trace` by the dropped-in files:
+    # the three counters, through `engine.trace` by their layer files:
     # two in five of a retired window's data frames were forwarded as IPv6
     per_step = {k: got[f"dualstack.v6_{k}_per_step"]["value"]
                 for k in ("fwd", "miss", "ctrl")}
@@ -139,6 +138,13 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     assert per_step["miss"] == 0 and per_step["ctrl"] == 0
     assert 0.36 * 0.95 * frames < per_step["fwd"] < 0.44 * 0.95 * frames
     assert frames <= 1024
+    # a step's crossings: the staged window up; a retire reads verdict,
+    # out_pkt, out_len, the violation and punt flags and six stats blocks
+    # (dhcp, nat, qos, spoof, garden, v6), the window's last one after the
+    # Tracer is disarmed
+    assert got["wire.upload_calls_per_step"]["value"] == 3
+    assert got["wire.fetch_calls_per_step"]["value"] == \
+        pytest.approx(3 + 2 + 6, abs=0.25)
 
 
 def test_both_controls_fail(cell_dir, capsys):

@@ -257,3 +257,126 @@ def test_drain_tables_adds_up_to_the_tables_drained_and_is_silent_disarmed():
     engine._drain_updates()
     assert counts(tr) == (0, got["batches"] * n)
     assert spans.trace_sums()["drain_built"] == 0
+
+
+def test_an_armed_loop_counts_the_crossings_the_code_makes():
+    """`upload` / `fetch` (PR 37): every host-to-device call and every read
+    of a step's outputs on the engine's loop is a lap with its calls and
+    bytes. The literals are the crossings the code makes a step: a PR that
+    merges reads lowers them here."""
+    zero = spans.Tracer().sums()["xfer"]
+    assert zero == {"upload_calls": 0, "upload_bytes": 0,
+                    "fetch_calls": 0, "fetch_bytes": 0}
+    with spans.armed(keep_events=1 << 10) as tr:
+        got = _serve("py-scalar", True, "mixed", 20289)
+    steps = got["batches"]
+    s = tr.sums()
+    x = s["xfer"]
+    # a step, every table clean: the staged window (packet slots, lengths,
+    # access flags) and nothing from the drain; a new engine's first drain
+    # places its seven dense arrays once (pools, server, NAT hairpin / alg
+    # / config, spoof ranges / config: ops/table.py placed)
+    engine = _stack(20289)[0]
+    slot = engine.L  # the staging row, in bytes
+    dense = (engine.fastpath.pools, engine.fastpath.server,
+             engine.nat.hairpin, engine.nat.alg, engine.nat.config_array(),
+             engine.antispoof.ranges, engine.antispoof.config)
+    assert x["upload_calls"] == 3 * steps + len(dense) == 3 * 4 + 7
+    assert x["upload_bytes"] == steps * BATCH * (slot + 4 + 1) + sum(
+        a.nbytes for a in dense)
+    # a retire: verdict, out_pkt, out_len (inside `device_wait`), the
+    # violation and punt flags (inside `reply`), and _fold_stats' four
+    # blocks (dhcp, nat, qos, spoof; no garden, PPPoE, edge or v6 here)
+    assert x["fetch_calls"] == (3 + 2 + 4) * steps
+    stats_bytes = sum(4 * len(getattr(engine.stats, k))  # u32 on the chip
+                      for k in ("dhcp", "nat", "qos", "spoof"))
+    assert x["fetch_bytes"] == steps * (
+        BATCH * (4 + slot + 4)      # verdict i32, out_pkt, out_len u32
+        + BATCH * (1 + 1)           # spoof_violation, nat_punt: bool
+        + stats_bytes)
+    by_stage = {}
+    for stage, _lane, _t0, _dur in tr.events:
+        by_stage[stage] = by_stage.get(stage, 0) + 1
+    # one `drain` lap a dispatch (the loop's first), one `upload`, three
+    # `fetch` (device_wait's, reply's, _fold_stats')
+    assert by_stage[spans.DRAIN] == steps
+    assert by_stage[spans.UPLOAD] == steps + len(dense)
+    assert by_stage[spans.FETCH] == 3 * steps
+    assert s["stage_ns"]["upload"] <= s["stage_ns"]["dispatch"]
+    assert sum(s["starved_ns"].values()) == \
+        s["beat_starved_ns"] + s["starved_ns"]["outside"]
+
+    # a drain that ships something shows as calls: one dirty cuckoo table
+    # is six arrays, one dirty QoS table three, a dense array one
+    engine, macs, ips, _flows = _stack(20291)
+    engine._drain_updates()  # the dense arrays' first placement
+    with spans.armed() as tr:
+        engine._drain_updates()
+        assert tr.sums()["xfer"]["upload_calls"] == 0
+        assert engine.fastpath.touch_lease(macs[0], T0 + 5)
+        engine._drain_updates()
+        assert tr.sums()["xfer"]["upload_calls"] == 6
+        engine.qos.set_subscriber(int(ips[1]), down_bps=1, up_bps=1)
+        engine._drain_updates()
+        assert tr.sums()["xfer"]["upload_calls"] == 6 + 3 + 3
+        engine.antispoof.set_config(MODE_STRICT, log_violations=False)
+        engine._drain_updates()
+        assert tr.sums()["xfer"]["upload_calls"] == 12 + 1
+        assert tr.sums()["xfer"]["fetch_calls"] == 0
+
+
+class _Out:
+    """A stand-in for a step's device output: counts who waits on it."""
+
+    def __init__(self, host):
+        self.host = np.asarray(host)
+        self.nbytes = self.host.nbytes
+        self.waits = 0
+
+    def block_until_ready(self):
+        self.waits += 1
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return self.host if dtype is None else self.host.astype(dtype)
+
+
+def test_disarmed_a_retire_forces_nothing_it_did_not_force_before():
+    """Armed, a retire blocks on its first output alone, so that the wait
+    and the copies are told apart; disarmed there is no such call: the
+    first read waits and copies at once, as it always did."""
+    from types import SimpleNamespace
+
+    from bng_tpu.runtime.ring import VERDICT_DROP
+
+    engine, *_ = _stack(20292)
+    ring = PyRing(nframes=64, frame_size=1024, depth=8)
+
+    def retire():
+        pkt, length, flags = (np.zeros((BATCH, engine.L), np.uint8),
+                              np.zeros(BATCH, np.uint32),
+                              np.zeros(BATCH, np.uint32))
+        for _ in range(3):
+            assert ring.rx_push(b"\x02" * 64, from_access=True)
+        n = ring.assemble(pkt, length, flags)
+        assert n == 3
+        res = SimpleNamespace(
+            verdict=_Out(np.full(BATCH, VERDICT_DROP, np.uint8)),
+            out_pkt=_Out(pkt), out_len=_Out(length),
+            spoof_violation=_Out(np.zeros(BATCH, bool)),
+            nat_punt=_Out(np.zeros(BATCH, bool)))
+        engine._apply_ring_verdicts(ring, res, pkt, length, n, float(T0))
+        return res
+
+    try:
+        res = retire()
+        assert not spans.enabled()
+        assert [o.waits for o in vars(res).values()] == [0] * 5
+        with spans.armed() as tr:
+            res = retire()
+        assert res.verdict.waits == 1
+        assert sum(o.waits for o in vars(res).values()) == 1
+        assert tr.sums()["xfer"]["fetch_calls"] == 3 + 2
+        assert tr.sums()["xfer"]["upload_calls"] == 0
+    finally:
+        ring.close()

@@ -29,25 +29,18 @@ REAL = "pppoe-cgnat-1M-wire.flood-64B"
 CELL = "tiny-pppoe-1024.flood-4096"
 FILES = ("pppoe_step.device_p50_us", "pppoe.loop_us_per_frame",
          "pppoe.gen_share", "pppoe.beat_p99_us")
-# the counter layer files a later `benchmark` issue adds (PERF.md section 7
-# row 1 xiv; tests/benchmark/test_trace_layers.py pins the count of such
-# files): dropped into the copy as data, read with no edit to the harness
-COUNTERS = [{
-    "name": f"pppoe.{k}_per_step", "unit": "lanes",
-    "better": "lower" if k == "miss" else "higher",
-    "source": "program_counter", "layer": "engine (runtime/engine.py)",
-    "moves": "served_kpps", "cells": [CELL],
-    "read": {"kind": "counter", "path": f"engine.trace.pppoe_{k}",
-             "per": "engine.batches"}} for k in ("decap", "encap", "miss")]
-FRAMES = dict(COUNTERS[0], name="pppoe.frames_per_step", unit="frames",
-              read={"kind": "counter", "path": "ring.rx",
-                    "per": "engine.batches"})
-TICK = {"name": "pppoe.tick_ms_per_s", "unit": "ms/s", "better": "lower",
-        "source": "program_counter",
-        "layer": "slow path (control/dhcp_server.py)", "moves": "served_kpps",
-        "cells": [CELL],
-        "read": {"kind": "counter", "path": "engine.trace.stage_ns.slow_path",
-                 "per": "second", "scale": 1e-6}}
+# the cell's counter files (PR 36's, and PR 37's crossings a step): the tiny
+# cell is appended to the real files' `cells` in the copy; only
+# `pppoe.frames_per_step`, which the benchmark does not have, is dropped in
+COUNTERS = ("pppoe.decap_per_step", "pppoe.encap_per_step",
+            "pppoe.miss_per_step", "pppoe.tick_ms_per_s",
+            "wire.upload_calls_per_step", "wire.fetch_calls_per_step")
+FRAMES = {"name": "pppoe.frames_per_step", "unit": "frames",
+          "better": "higher", "source": "program_counter",
+          "layer": "engine (runtime/engine.py)", "moves": "served_kpps",
+          "cells": [CELL],
+          "read": {"kind": "counter", "path": "ring.rx",
+                   "per": "engine.batches"}}
 
 
 def _write(path, obj):
@@ -86,13 +79,13 @@ def cell_dir(tmp_path_factory):
     for m in bench["end_to_end"]:
         if m["name"] == "served_kpps":
             m["workloads"].append(CELL)
-    for name in FILES:
+    for name in (*FILES, *COUNTERS):
         m = applib.load_named("layers", name, bdir)
-        assert m["cells"] == [REAL] and not name.startswith("wire")
+        assert REAL in m["cells"]
+        assert m["cells"] == [REAL] or name.startswith("wire")
         m["cells"].append(CELL)
         _write(os.path.join(bdir, "layers", name + ".json"), m)
-    for m in (*COUNTERS, FRAMES, TICK):
-        _write(os.path.join(bdir, "layers", m["name"] + ".json"), m)
+    _write(os.path.join(bdir, "layers", FRAMES["name"] + ".json"), FRAMES)
     _write(os.path.join(top, "BENCHMARK.json"), bench)
     return bdir
 
@@ -133,7 +126,7 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     assert "pppoe_step.device_p50_us" not in got  # no device trace on the CPU
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
     assert said and "pppoe_step.device_p50_us" in said[0]
-    # the three counters, through `engine.trace` by the dropped-in files:
+    # the three counters, through `engine.trace` by their layer files:
     # every data frame of a retired window was decapsulated or encapsulated
     per_step = {k: got[f"pppoe.{k}_per_step"]["value"]
                 for k in ("decap", "encap", "miss")}
@@ -143,6 +136,13 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     assert 0.90 * frames < per_step["decap"] + per_step["encap"] < frames <= 1024
     # the once-a-second walk over the sessions, in stage `slow_path`
     assert got["pppoe.tick_ms_per_s"]["value"] > 0
+    # a step's crossings: the staged window up; a retire reads verdict,
+    # out_pkt, out_len, the violation and punt flags and six stats blocks
+    # (dhcp, nat, qos, spoof, garden, pppoe), the window's last one after
+    # the Tracer is disarmed
+    assert got["wire.upload_calls_per_step"]["value"] == 3
+    assert got["wire.fetch_calls_per_step"]["value"] == \
+        pytest.approx(3 + 2 + 6, abs=0.25)
 
 
 def test_both_controls_fail_by_the_sample(cell_dir, capsys):

@@ -31,6 +31,7 @@ from bng_tpu.control.dhcp_server import DHCPServer
 from bng_tpu.control.metrics import BNGMetrics
 from bng_tpu.control.nat import NATManager
 from bng_tpu.control.pool import Pool, PoolManager
+from bng_tpu.ops.express import XD_WORDS
 from bng_tpu.runtime.engine import AntispoofTables, Engine, QoSTables
 from bng_tpu.runtime.lanes import (CLOSE_DEADLINE, CLOSE_FULL, CompletionRing,
                                    InflightEntry, Lane, LaneConfig)
@@ -376,6 +377,59 @@ class TestTracedLoop:
         assert all(b >= 0 for e, b in zip(tr.events, tr.event_beats)
                    if e[0] == tele.SOJOURN)  # retired inside a beat
         assert tr.lane_hist(tele.LANE_BULK_L, tele.DEVICE).n >= 1
+
+    def test_one_armed_poll_counts_the_crossings_the_code_makes(self):
+        """`upload` / `fetch` (PR 37) on the scheduler's loop: one bulk and
+        one express dispatch, each retired. The literals are the crossings
+        the code makes: a PR that merges reads lowers them here."""
+        from bng_tpu.telemetry import spans as tele
+
+        engine, _, clock = build_stack(batch_size=8)
+        sched = TieredScheduler(engine, SchedulerConfig(
+            bulk_batch=8, bulk_depth=2, express_batch=4,
+            express_device_index=-1), clock=clock)
+        assert set(sched.stats_snapshot()["trace"]["xfer"]) == {
+            "upload_calls", "upload_bytes", "fetch_calls", "fetch_bytes"}
+        for k in range(2):  # compile, and place the dense arrays once
+            self._drive(sched, clock, 100 * k, n_bulk=8)
+        sched.flush()
+        with tele.armed(keep_events=1 << 10) as tr:
+            tele.beat_begin()
+            self._drive(sched, clock, 300, n_bulk=8)
+            sched.flush()
+            tele.beat_end()
+            snap = sched.stats_snapshot()["trace"]
+        assert snap["batches"] == 2  # one bulk step, one express batch
+        x = snap["xfer"]
+        L = engine.L
+        # bulk: packet slots, lengths, access flags; express: the
+        # descriptor rows and the clock word; every table clean
+        assert x["upload_calls"] == 3 + 2
+        assert x["upload_bytes"] == 8 * (L + 4 + 1) + 4 * XD_WORDS * 4 + 4
+        # bulk retire: verdict, out_len, punt, violation inside
+        # `device_wait`, then _fold_stats' four blocks; out_pkt stays on
+        # the chip (no lane of these frames is TX or FWD). Express retire:
+        # the verdict block (written over the descriptor rows), and the
+        # one stats block the program returns
+        assert x["fetch_calls"] == (4 + 4) + (1 + 1)
+        stats = sum(4 * len(getattr(engine.stats, k))
+                    for k in ("dhcp", "nat", "qos", "spoof"))
+        assert x["fetch_bytes"] == (8 * (4 + 4 + 1 + 1) + stats
+                                    + 4 * XD_WORDS * 4
+                                    + 4 * len(engine.stats.dhcp))
+        lanes = {}
+        for stage, lane, _t0, _dur in tr.events:
+            if stage in (tele.UPLOAD, tele.FETCH):
+                lanes.setdefault((stage, lane), []).append(1)
+        assert {k: len(v) for k, v in lanes.items()} == {
+            (tele.UPLOAD, tele.LANE_BULK_L): 1,
+            (tele.UPLOAD, tele.LANE_EXPRESS_L): 1,
+            (tele.FETCH, tele.LANE_BULK_L): 2,
+            (tele.FETCH, tele.LANE_EXPRESS_L): 2}
+        assert sum(snap["starved_ns"].values()) == \
+            snap["beat_starved_ns"] + snap["starved_ns"]["outside"]
+        # the children lie inside their parents
+        assert snap["stage_ns"]["upload"] <= snap["stage_ns"]["dispatch"]
 
     def test_always_on_integers_count_disarmed(self):
         engine, _, clock = build_stack(batch_size=8)

@@ -258,6 +258,79 @@ class TestShardedLoopOnTheTracer:
         assert beats == [0, 1, 2]  # one dispatch a beat, the flush none
 
 
+def test_an_armed_sharded_window_counts_the_crossings_the_code_makes():
+    """`upload` / `fetch` (PR 37) on the sharded loop: one fused window
+    over two shards. The drain reads each leaf of the update batch back
+    from every shard and places the stack over the mesh (S-d's mechanism as
+    a count); the literals are the crossings the code makes: a PR that
+    merges reads, or stops reading clean leaves back, lowers them here."""
+    from bng_tpu.control import packets
+    from bng_tpu.telemetry import spans as tele
+
+    cl = make_cluster()
+    macs = populate(cl)
+    cl.sync_tables()
+    ring = cl.make_ring(nframes=256, frame_size=2048, depth=64)
+
+    def beat(k):
+        for i, mac in enumerate(macs[:3]):
+            assert ring.rx_push(discover(mac, 70 + 10 * k + i),
+                                from_access=True)
+        assert ring.rx_push(packets.udp_packet(
+            macs[0], SERVER_MAC, ip_to_u32("10.0.0.50"),
+            ip_to_u32("93.184.216.34"), 40000, 443, b"x" * 64),
+            from_access=True)  # a data frame: the window rides the fused step
+        return cl.process_ring_pipelined(ring, NOW + k, k * 1000)
+
+    import jax
+
+    for k in range(3):  # compiled; the new flow punted, created and shipped
+        beat(k)
+    cl.flush_pipeline()
+    assert cl.pending_dirty() == 0
+    assert cl.garden is not None and cl.pppoe is None and cl.edge is None
+    leaves = 62  # of one shard's update batch (asserted below)
+    with tele.armed(keep_events=1 << 12) as tr:
+        tele.beat_begin()
+        beat(3)
+        cl.flush_pipeline()
+        tele.beat_end()
+        snap = cl.telemetry.snapshot()["trace"]
+    batch = jax.tree.leaves(cl._drain_updates())  # clean: ships nothing
+    assert (cl.n, len(batch)) == (2, leaves)
+    assert snap["batches"] == 1
+    x = snap["xfer"]
+    # `pack`: packet slots, lengths, access flags; the drain: one placement
+    # a leaf, and a shard's three dense arrays (spoof ranges, spoof config,
+    # garden allowlist) to chip 0 before they are read back
+    assert x["upload_calls"] == 3 + leaves + 3 * cl.n == 71
+    # the drain reads every leaf back from every shard; the retire reads
+    # ten outputs (verdict, punt, violation, five stats blocks with the
+    # garden's, out_pkt, out_len)
+    assert x["fetch_calls"] == leaves * cl.n + 10 == 134
+    B = cl.n * cl.b
+    stacked = sum(int(a.nbytes) for a in batch)
+    retire = B * (4 + 1 + 1 + 2048 + 4)  # verdict, punt, viol, out_pkt, len
+    stats = 4 * sum(len(cl.stats[k])  # five psum'd blocks, u32 on the mesh
+                    for k in ("dhcp", "nat", "qos", "spoof", "garden"))
+    assert x["fetch_bytes"] == stacked + retire + stats
+    assert x["upload_bytes"] == stacked + B * (2048 + 4 + 1) + sum(
+        a.nbytes for i in range(cl.n) for a in (
+            cl.spoof[i].ranges, cl.spoof[i].config, cl.garden[i].allowed))
+    by_stage = {}
+    for stage, _lane, _t0, _dur in tr.events:
+        by_stage[stage] = by_stage.get(stage, 0) + 1
+    # one `fetch` and one `upload` lap a leaf inside `drain`, the three
+    # placements of `pack` under one lap, the dense arrays one each
+    assert by_stage[tele.FETCH] == leaves + 1
+    assert by_stage[tele.UPLOAD] == leaves + 1 + 3 * cl.n
+    assert snap["stage_ns"]["fetch"] + snap["stage_ns"]["upload"] <= \
+        snap["stage_ns"]["drain"] + snap["stage_ns"]["device_wait"] \
+        + snap["stage_ns"]["pack"]
+    assert sum(snap["starved_ns"].values()) == \
+        snap["beat_starved_ns"] + snap["starved_ns"]["outside"]
+
+
 def save_bytes(cl, dhcp=None) -> bytes:
     return encode_checkpoint(
         build_sharded_checkpoint(cl, 1, float(NOW), dhcp=dhcp))

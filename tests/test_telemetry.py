@@ -51,7 +51,10 @@ class TestDisarmedOverhead:
         assert not spans.enabled()
         n = 200_000
         for fn, args in ((spans.t, ()), (spans.stamp, (spans.DISPATCH,)),
-                         (spans.lap, (spans.DISPATCH, None))):
+                         (spans.lap, (spans.DISPATCH, None)),
+                         (spans.xfer, (spans.UPLOAD, None, 0)),
+                         (spans.fetched, (None, None)),
+                         (spans.ready, (None,))):
             ns = (timeit.Timer(lambda: fn(*args)).timeit(n) / n) * 1e9
             assert ns < 2_000, f"{fn.__name__}: {ns:.0f} ns/call"
 
@@ -59,6 +62,9 @@ class TestDisarmedOverhead:
         assert spans.t() is None
         assert spans.begin_batch(spans.LANE_ENGINE, 8) is None
         spans.lap(spans.DISPATCH, None)
+        spans.xfer(spans.FETCH, None, 1 << 20, calls=3)
+        assert spans.ready(object()) is None
+        assert spans.trace_sums()["xfer"] == spans._ZERO_SUMS["xfer"]
         spans.end_batch(None)
         spans.add(shed=5)
         assert spans.trigger("worker_death") is None
@@ -614,6 +620,79 @@ class TestBeatTiling:
         assert s["stage_ns"]["ring"] == 200 + 500
         assert tr.stage_hist(spans.BEAT).n == 2
 
+    def test_a_child_xfer_lap_is_in_the_union_once_and_self_time_stands(self):
+        """`upload` / `fetch` are children of the laps that enclose them:
+        the beat's union counts the child once, so `beat_self_ns` is what it
+        was without the child; a crossing under no parent (`_fold_stats`)
+        takes its time out of `beat_self_ns`."""
+        def beat(children: bool, orphan: bool) -> dict:
+            clk = _Clock()
+            tr = Tracer(clock=clk, keep_events=64)
+            clk.now = 1_000
+            tr.beat_begin()
+            if children:
+                clk.now = 1_300                       # drain [1200,1300]
+                tr.lap(spans.DRAIN, 1_200)
+                clk.now = 1_450                       # upload [1300,1450]
+                tr.xfer(spans.UPLOAD, 1_300, 4_096, calls=3)
+            _lap(tr, clk, spans.DISPATCH, 1_100, 1_600)
+            if children:
+                clk.now = 1_900                       # fetch [1800,1900]
+                tr.xfer(spans.FETCH, 1_800, 512, calls=2)
+            _lap(tr, clk, spans.DEVICE_WAIT, 1_700, 1_900)
+            if orphan:
+                clk.now = 2_100                       # under no parent
+                tr.xfer(spans.FETCH, 2_000, 64, calls=4)
+            clk.now = 3_000
+            tr.beat_end()
+            return tr.sums()
+
+        bare, nested, both = beat(False, False), beat(True, False), \
+            beat(True, True)
+        assert bare["beat_self_ns"] == 2_000 - (500 + 200)
+        assert nested["beat_self_ns"] == bare["beat_self_ns"]
+        assert both["beat_self_ns"] == bare["beat_self_ns"] - 100
+        # the parents' own sums do not move; the children's are beside them
+        for s in (nested, both):
+            assert (s["stage_ns"]["dispatch"], s["stage_ns"]["device_wait"]) \
+                == (500, 200) == (bare["stage_ns"]["dispatch"],
+                                  bare["stage_ns"]["device_wait"])
+            assert s["stage_ns"]["upload"] == 150
+            assert s["stage_ns"]["drain"] == 100
+        assert (nested["stage_ns"]["fetch"], both["stage_ns"]["fetch"]) == \
+            (100, 200)
+        assert nested["xfer"] == {"upload_calls": 3, "upload_bytes": 4_096,
+                                  "fetch_calls": 2, "fetch_bytes": 512}
+        assert both["xfer"]["fetch_calls"] == 6
+        assert both["xfer"]["fetch_bytes"] == 512 + 64
+
+    def test_xfer_is_a_lap_with_an_event_a_lane_and_a_flight_record(self):
+        clk = _Clock()
+        rec = FlightRecorder(RecorderConfig(capacity=4))
+        tr = Tracer(recorder=rec, clock=clk, keep_events=16)
+        clk.now = 100
+        tr.beat_begin()
+        tok = tr.begin(spans.LANE_RING_L, 8)
+        clk.now = 400
+        tr.xfer(spans.UPLOAD, 150, 1 << 20, calls=3, tok=tok)
+        clk.now = 900
+        tr.xfer(spans.FETCH, 600, 1 << 10, tok=tok)
+        tr.end(tok)
+        tr.beat_end()
+        rows = list(zip(tr.events, tr.event_beats))
+        assert ((spans.UPLOAD, spans.LANE_RING_L, 150, 250), 0) in rows
+        assert ((spans.FETCH, spans.LANE_RING_L, 600, 300), 0) in rows
+        assert tr.lane_hist(spans.LANE_RING_L, spans.UPLOAD).n == 1
+        assert tr.lane_hist(spans.LANE_RING_L, spans.FETCH).n == 1
+        assert not tr.lane_hist(spans.LANE_ENGINE, spans.FETCH).n
+        assert rec._dur[0, spans.UPLOAD] == pytest.approx(0.25)
+        assert rec._dur[0, spans.FETCH] == pytest.approx(0.3)
+        # TOTAL stays the last stage: the recorder indexes it so
+        assert spans.STAGE_NAMES[-1] == "total" == \
+            spans.STAGE_NAMES[spans.TOTAL]
+        assert spans.STAGE_NAMES[spans.UPLOAD] == "upload"
+        assert spans.STAGE_NAMES[spans.FETCH] == "fetch"
+
     def test_events_stay_4_tuples_with_beat_ids_beside_them(self):
         clk = _Clock()
         tr = Tracer(clock=clk, keep_events=64)
@@ -741,6 +820,116 @@ class TestDeviceOccupancy:
         assert sum(st.values()) == 2_000 + 1_500
         assert s["beat_starved_ns"] == 2_000 + 1_500 - st["outside"]
 
+    def test_starvation_under_a_child_is_the_childs_the_parent_keeps_the_rest(
+            self):
+        """A child closes before its parent, so it takes the starvation
+        under it and the parent keeps what is left: a parent's `starved_ns`
+        is its self share, and parent + children is what the parent was
+        charged without them. `beat_starved_ns` does not move."""
+        def run(children: bool) -> dict:
+            clk = _Clock()
+            tr = Tracer(clock=clk)
+            a = tr.begin(spans.LANE_RING_L, 8)
+            clk.now = 1_000
+            tr.device_up(a)
+            clk.now = 5_000
+            tr.beat_begin()
+            clk.now = 6_000
+            tr.device_down(a)                   # idle from 6,000
+            b = tr.begin(spans.LANE_RING_L, 8)
+            if children:
+                clk.now = 6_300                 # drain [6200,6300]
+                tr.lap(spans.DRAIN, 6_200, b)
+                clk.now = 6_700                 # upload [6300,6700]
+                tr.xfer(spans.UPLOAD, 6_300, 1 << 20, calls=3, tok=b)
+            _lap(tr, clk, spans.DISPATCH, 6_100, 7_000, b)
+            tr.device_up(b)                     # window [6000,7000] closes
+            clk.now = 8_000
+            tr.device_down(b)                   # idle again from 8,000
+            if children:
+                clk.now = 8_400                 # fetch [8100,8400] in reply
+                tr.xfer(spans.FETCH, 8_100, 1 << 10, tok=b)
+            _lap(tr, clk, spans.REPLY, 8_000, 8_500, b)
+            clk.now = 9_000
+            tr.beat_end()
+            tr.finish()
+            return tr.sums()
+
+        bare, split = run(False), run(True)
+        st, ch = bare["starved_ns"], split["starved_ns"]
+        assert (st["dispatch"], st["reply"]) == (900, 500)
+        assert (st["upload"], st["fetch"], st["drain"]) == (0, 0, 0)
+        assert (ch["drain"], ch["upload"], ch["fetch"]) == (100, 400, 300)
+        assert ch["dispatch"] == 900 - 100 - 400     # its self share
+        assert ch["reply"] == 500 - 300
+        assert ch["dispatch"] + ch["drain"] + ch["upload"] == st["dispatch"]
+        assert ch["reply"] + ch["fetch"] == st["reply"]
+        assert ch["beat"] == st["beat"] and ch["outside"] == st["outside"]
+        assert split["beat_starved_ns"] == bare["beat_starved_ns"]
+        assert sum(ch.values()) == split["beat_starved_ns"] + ch["outside"]
+
+    def test_xfer_counts_are_always_served_armed_and_frozen_at_disarm(self):
+        keys = {"upload_calls", "upload_bytes", "fetch_calls", "fetch_bytes"}
+        assert set(spans._ZERO_SUMS["xfer"]) == keys
+        assert not any(spans._ZERO_SUMS["xfer"].values())
+        clk = _Clock()
+        tr = spans.arm(Tracer(clock=clk))
+        assert set(spans.trace_sums()["xfer"]) == keys
+        assert not any(spans.trace_sums()["xfer"].values())
+        t0 = spans.t()
+        clk.now = 50
+        spans.xfer(spans.UPLOAD, t0, 12_623_872, calls=3)
+        t0 = spans.t()
+        clk.now = 80
+        spans.xfer(spans.FETCH, t0, 8_192)
+        armed = spans.trace_sums()
+        assert armed["xfer"] == {"upload_calls": 3,
+                                 "upload_bytes": 12_623_872,
+                                 "fetch_calls": 1, "fetch_bytes": 8_192}
+        assert armed["stage_ns"]["upload"] == 50
+        assert armed["stage_ns"]["fetch"] == 30
+        spans.disarm()
+        frozen = spans.trace_sums()["xfer"]
+        spans.xfer(spans.FETCH, spans.t(), 1 << 20, calls=9)  # disarmed
+        tr.xfer(spans.FETCH, 0, 1 << 20)  # even on the tracer itself
+        assert spans.trace_sums()["xfer"] == frozen == armed["xfer"]
+
+    def test_ready_blocks_armed_only_and_fetched_counts_device_arrays(self):
+        """`ready`: armed it waits on the output, says the dispatch was
+        seen ready and opens the `fetch` lap; disarmed nothing is forced.
+        `fetched` closes it: host arrays among a step's outputs cross
+        nothing."""
+        class Out:
+            nbytes = 96
+
+            def __init__(self):
+                self.waits = 0
+
+            def block_until_ready(self):
+                self.waits += 1
+
+        out = Out()
+        assert spans.ready(out, 3) is None and out.waits == 0
+        clk = _Clock()
+        tr = spans.arm(Tracer(clock=clk))
+        tok = tr.begin(spans.LANE_BULK_L, 8)
+        clk.now = 1_000
+        spans.device_up(tok)
+        clk.now = 4_000
+        assert spans.ready(out, tok) == 4_000 and out.waits == 1
+        assert not tr.device_pending(tok)
+        assert tr.lane_hist(spans.LANE_BULK_L, spans.DEVICE).n == 1
+        assert spans.ready(np.zeros(4), tok) == 4_000  # a host array: no wait
+        clk.now = 4_500
+        spans.fetched(4_000, out, np.zeros(8, np.uint32), None, out, tok=tok)
+        assert tr.sums()["xfer"] == {"upload_calls": 0, "upload_bytes": 0,
+                                     "fetch_calls": 2, "fetch_bytes": 192}
+        assert tr.sums()["stage_ns"]["fetch"] == 500
+        spans.disarm()
+        spans.fetched(4_000, out, out)  # disarmed: a no-op
+        spans.fetched(None, out)
+        assert spans.trace_sums()["xfer"]["fetch_calls"] == 2
+
     def test_sums_freeze_at_disarm_and_stay_readable(self):
         clk = _Clock()
         zero = spans.trace_sums()
@@ -816,6 +1005,25 @@ class TestOneClockWithTheDeviceTrace:
         assert log["sums"]["beats"] == 3
         beats = [e for e in log["events"] if log["stages"][e[0]] == "beat"]
         assert len(beats) == 3
+
+    def test_a_gap_under_a_child_lap_is_named_by_the_child(self):
+        """The program's reducer names an idle gap by the shortest lap
+        over its midpoint: `fetch` inside `device_wait` or under no parent,
+        `upload` inside `dispatch`; and both are host laps it keeps."""
+        from bng_tpu.utils.profiling import HOST_LAPS, _lap_over
+
+        assert {"upload", "fetch"} <= set(HOST_LAPS)
+        assert set(HOST_LAPS) <= set(spans.STAGE_NAMES)
+        laps = [(100.0, 900.0, "dispatch"), (150.0, 50.0, "drain"),
+                (200.0, 300.0, "upload"), (2_000.0, 1_000.0, "device_wait"),
+                (2_600.0, 400.0, "fetch"), (3_100.0, 200.0, "fetch")]
+        assert _lap_over(laps, 160.0)[2] == "drain"
+        assert _lap_over(laps, 350.0)[2] == "upload"
+        assert _lap_over(laps, 800.0)[2] == "dispatch"
+        assert _lap_over(laps, 2_300.0)[2] == "device_wait"  # the wait alone
+        assert _lap_over(laps, 2_700.0)[2] == "fetch"
+        assert _lap_over(laps, 3_200.0)[2] == "fetch"        # _fold_stats
+        assert _lap_over(laps, 1_500.0) is None
 
     def test_scope_is_the_first_named_scope_on_the_op_path(self):
         from bng_tpu.utils.profiling import _scope_of

@@ -26,22 +26,14 @@ from benchmark import run as bench_run  # noqa: E402
 from benchmark.lib import app as applib  # noqa: E402
 
 CELL = "tiny-wire-2048.flood-8192"
-# the layer file a later `benchmark` issue adds (PERF.md section 7 row 1):
-# dropped into the copy as data, read with no edit to the harness
-MASKED = {
-    "name": "wire.masked_lanes_per_step", "unit": "lanes", "better": "lower",
-    "source": "program_counter", "layer": "engine (runtime/engine.py)",
-    "moves": "served_kpps", "cells": [CELL],
-    "read": {"kind": "counter", "path": "engine.trace.masked_lanes",
-             "per": "engine.batches"}}
-
-
-# PR 35's two (section 7 row 1 xvi): of the tables a step's drain went
-# over, those built and uploaded, and those answered from the chip
-DRAIN = [dict(MASKED, name=f"engine.drain_{kind}_per_step", unit="tables",
-              read={"kind": "counter", "path": f"engine.trace.drain_{kind}",
-                    "per": "engine.batches"})
-         for kind in ("built", "cached")]
+# the engine loop's counter files (PR 36's, and PR 37's crossings): the cell
+# is appended to the real files' `cells` in the copy, read with no edit to
+# the harness
+COUNTERS = ("wire.masked_lanes_per_step", "engine.drain_built_per_step",
+            "engine.drain_cached_per_step", "wire.frames_per_step",
+            "wire.ring_us_per_frame", "wire.upload_calls_per_step",
+            "wire.fetch_calls_per_step", "wire.upload_kb_per_step",
+            "wire.fetch_kb_per_step", "wire.drain_us_per_step")
 
 
 def _write(path, obj):
@@ -79,9 +71,7 @@ def wrap_dir(tmp_path_factory):
     for m in bench["end_to_end"]:
         if m["name"] == "served_kpps":
             m["workloads"].append(CELL)
-    for m in (MASKED, *DRAIN):
-        _write(os.path.join(bdir, "layers", m["name"] + ".json"), m)
-    for name in ("wire.frames_per_step", "wire.ring_us_per_frame"):
+    for name in COUNTERS:
         m = applib.load_named("layers", name, bdir)
         m["cells"].append(CELL)
         _write(os.path.join(bdir, "layers", name + ".json"), m)
@@ -109,19 +99,19 @@ def _run(wrap_dir, capsys, seed, *extra):
 def test_the_hit_balance_holds_across_the_pools_wrap(wrap_dir, capsys, seed,
                                                      trace):
     """Untraced as the driver times it, and traced: there `masked_lanes`
-    is read through `engine.trace` by the dropped-in layer file, above 0
-    once a short window lands in a buffer a full one used, and PR 35's
-    drain counters beside it."""
+    is read through `engine.trace` by its layer file, above 0 once a short
+    window lands in a buffer a full one used, PR 35's drain counters
+    beside it, and PR 37's crossings a step."""
     res, out = _run(wrap_dir, capsys, seed, "--trace", trace)
     assert "check dhcp_accepted_minus_device_hits=0 limit=0" in out
     assert res["correct"] is True and res["failed"] == 0, out[-14:]
     assert all(c["value"] == 0 for c in res["compared"].values())
     if trace == "1":
         got = res["metrics"]
-        assert got[MASKED["name"]]["unit"] == "lanes"
+        assert got["wire.masked_lanes_per_step"]["unit"] == "lanes"
         # far under a window a step: a buffer's stale lanes are cleared
         # once, not every time the short window comes round
-        assert 0 < got[MASKED["name"]]["value"] < \
+        assert 0 < got["wire.masked_lanes_per_step"]["value"] < \
             got["wire.frames_per_step"]["value"]
         assert got["wire.ring_us_per_frame"]["value"] > 0
         # the tables are clean in the window (no slow-path DHCP, no punt):
@@ -129,6 +119,19 @@ def test_the_hit_balance_holds_across_the_pools_wrap(wrap_dir, capsys, seed,
         # (3 fastpath, 3 NAT, 2 QoS, antispoof, the garden's)
         assert got["engine.drain_built_per_step"]["value"] == 0
         assert got["engine.drain_cached_per_step"]["value"] == 10
+        # so a step uploads its staged window and nothing else (packet
+        # slots, lengths, access flags), and a retire reads ten arrays:
+        # verdict, out_pkt, out_len; violation and punt flags; five stats
+        # blocks (dhcp, nat, qos, spoof, garden). The window's last
+        # dispatch retires after the Tracer is disarmed: ten reads short
+        assert got["wire.upload_calls_per_step"]["value"] == 3
+        assert got["wire.upload_kb_per_step"]["value"] == \
+            2048 * (1536 + 4 + 1) / 1024
+        assert got["wire.fetch_calls_per_step"]["value"] == \
+            pytest.approx(3 + 2 + 5, abs=0.25)
+        assert got["wire.fetch_kb_per_step"]["value"] == \
+            pytest.approx(2048 * (4 + 1536 + 4 + 1 + 1) / 1024, rel=0.03)
+        assert got["wire.drain_us_per_step"]["value"] > 0
 
 
 def test_the_stale_binding_control_still_fails_and_by_the_sample_alone(
