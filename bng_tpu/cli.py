@@ -1927,6 +1927,12 @@ class BNGApp:
             self._push_synthetic(ring)
         cluster = self.components.get("cluster")
         sched = self.components.get("scheduler")
+        engine = self.components.get("engine")
+        if engine is not None and engine is not self._rungs_built_for:
+            # before this loop's first window (and again after an engine
+            # swap): one program a rung of the fused step's ladder
+            with self._ctl:
+                self._build_step_rungs(engine, sched, ring)
         if cluster is not None:
             # the promoted serving path: double-buffered sharded ring
             # loop — ring-steered owner-shard batches, depth-2 windows
@@ -1977,6 +1983,25 @@ class BNGApp:
         return moved + pumped
 
     _warned_no_rx_pop = False
+    _rungs_built_for = None  # the engine whose step ladder is built
+
+    def _build_step_rungs(self, engine, sched, ring) -> None:
+        """Start-up builds one program a rung: `--batch-size` is the
+        largest window a step takes, and a step runs at the narrowest
+        rung of the ladder down from it that holds its window
+        (runtime/engine.py step_rungs). Every rung this app's loop can
+        reach is built and run once over an inert window here, so no
+        window waits for a compile: up to the bulk batch on the
+        scheduler's loop, up to the ring's depth on the engine's (an
+        assembled window is never longer)."""
+        t0 = time.time()
+        if sched is not None and hasattr(ring, "rx_pop"):
+            sched.build_bulk_rungs()
+        else:
+            engine.build_step_rungs(min(engine.B, ring.depth))
+        self._rungs_built_for = engine
+        self.log.info("step ladder built", batch_size=engine.B,
+                      seconds=round(time.time() - t0, 3))
 
     def _drive_scheduler(self, ring, sched) -> int:
         """One scheduler beat over the ring: RX frames into the lanes,
@@ -3291,18 +3316,29 @@ def run_perf(args) -> int:
 # entry points
 # ---------------------------------------------------------------------------
 
+_RUN_FLAG_HELP = {
+    "batch_size": "the largest window one fused step takes (default "
+                  "{default} lanes); a shorter window runs at the "
+                  "narrowest rung of the ladder down from it (by 8, floor "
+                  "128, at most three), and start-up builds one program a "
+                  "rung before the first window",
+}
+
+
 def _add_run_flags(p: argparse.ArgumentParser) -> None:
     defaults = BNGConfig()
     for f in dataclasses.fields(BNGConfig):
         flag = "--" + f.name.replace("_", "-")
         default = getattr(defaults, f.name)
+        text = _RUN_FLAG_HELP.get(f.name, "").format(default=default) or None
         if isinstance(default, bool):
-            p.add_argument(flag, dest=f.name, default=None,
+            p.add_argument(flag, dest=f.name, default=None, help=text,
                            action=argparse.BooleanOptionalAction)
         elif isinstance(default, list):
-            p.add_argument(flag, dest=f.name, default=None, nargs="*")
+            p.add_argument(flag, dest=f.name, default=None, nargs="*",
+                           help=text)
         else:
-            p.add_argument(flag, dest=f.name, default=None,
+            p.add_argument(flag, dest=f.name, default=None, help=text,
                            type=type(default))
     p.add_argument("--config", dest="config_file", default="")
 

@@ -116,6 +116,39 @@ def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
     )
 
 
+# the fused step's compile-shape ladder: a dispatched step runs at the
+# narrowest rung that holds its window, not at `--batch-size` (a lane
+# beyond the window is inert and costs the device what a live one costs).
+# Geometric, down from the configured batch; coarser than the DHCP
+# buckets below because a rung of the fused step is a 45 s cold compile
+# and, warm, 2.5 s of start-up to trace, lower and load (PERF.md section
+# 6: the 128 rung takes the renew cell's OFFER median down by 18% more
+# than 8,192 / 1,024 alone, which is what keeps it).
+STEP_RUNG_RATIO = 8
+STEP_RUNG_FLOOR = 128
+STEP_RUNGS_MAX = 3
+
+
+@functools.lru_cache(maxsize=64)
+def step_rungs(B: int) -> tuple[int, ...]:
+    """The lane counts a fused step of configured batch `B` may be
+    dispatched at, ascending; `B` itself is the last. A `B` at or under
+    the floor has one rung."""
+    rungs = [B]
+    while len(rungs) < STEP_RUNGS_MAX and rungs[-1] > STEP_RUNG_FLOOR:
+        rungs.append(max(STEP_RUNG_FLOOR, rungs[-1] // STEP_RUNG_RATIO))
+    return tuple(reversed(rungs))
+
+
+def step_rung(n: int, B: int) -> int:
+    """The narrowest rung of `B`'s ladder that holds a window of `n`
+    frames (`B` for a window longer than `B`: staging refuses that)."""
+    for b in step_rungs(B):
+        if n <= b:
+            return b
+    return B
+
+
 @functools.lru_cache(maxsize=8)
 def _pipeline_jit(geom: PipelineGeom):
     def step(tables, upd, pkt, length, from_access, now_s, now_us):
@@ -714,8 +747,10 @@ class Engine:
         caller. drain=False passes the cached no-op update batch — the
         scheduler owns the drain cadence; a prefetched batch from
         prefetch_bulk_updates() arrives via `upd` (overlap-drain mode)
-        and takes the drain's place. Returns (res, new_replica);
-        outputs are futures (retire at the completion ring, never here).
+        and takes the drain's place. The step runs at the lane count the
+        caller packed to (the scheduler picks the rung, step_rung).
+        Returns (res, new_replica); outputs are futures (retire at the
+        completion ring, never here).
         """
         now_s = np.uint32(int(now))
         now_us = np.uint32(int(now * 1e6) & 0xFFFFFFFF)
@@ -837,7 +872,7 @@ class Engine:
         tok = tele.begin_batch(tele.LANE_ENGINE, len(frames))
         t0 = tele.t()
         try:
-            res = self._run_step(pkt, length, fa, now_s, now_us)
+            res = self._run_step(pkt, length, fa, len(frames), now_s, now_us)
         except BaseException:
             tele.cancel_batch(tok)  # a failed dispatch must not leak a slot
             raise
@@ -1140,23 +1175,78 @@ class Engine:
             qos_stats=np.zeros(QOS_NSTATS, dtype=np.uint32),
             spoof_stats=np.zeros(ANTISPOOF_NSTATS, dtype=np.uint32))
 
-    def _dispatch_step(self, pkt, length, fa, now_s, now_us) -> PipelineResult:
+    def _dispatch_step(self, pkt, length, fa, n: int,
+                       now_s, now_us) -> PipelineResult:
         """Enqueue one jitted step (async — outputs are futures). The table
         state threads immediately; callers force outputs when they need
-        them (sync path: right away; pipelined path: one batch later)."""
+        them (sync path: right away; pipelined path: one batch later).
+
+        The step runs at the rung of its window: the first `b` rows of the
+        staged `[B, L]` buffers go to the device (contiguous views; rows
+        n..b are inert by the staging invariant, rows beyond `b` never
+        reach the chip) and the outputs are `[b]` / `[b, L]`. A window
+        over the next rung down runs the `B` program, today's."""
         self._dispatch_fault()
+        b = step_rung(n, self.B)
+        tele.step_lanes(b)
         # drain FIRST: a bulk-build resync rebinds self.tables, and Python
         # evaluates arguments left-to-right — reading self.tables before
         # the drain would pass (and donate) the stale pre-resync reference
         t0 = tele.t()
         upd = self._drain_updates()
         tele.lap(tele.DRAIN, t0)
-        staged = self._upload_batch(pkt, length, fa)
+        staged = self._upload_batch(pkt[:b], length[:b], fa[:b])
         res: PipelineResult = self._step(self.tables, upd, *staged,
                                          now_s, now_us)
         self.tables = res.tables
         self.stats.batches += 1
         return res
+
+    def build_step_rungs(self, max_n: int, batch: int | None = None,
+                         dhcp=None):
+        """Build every rung of the fused step's ladder that holds a window
+        of up to `max_n` frames: start-up's, so that no program is built
+        once a loop takes frames. One inert window (every length 0: no
+        table changes) goes through each rung, which traces, lowers and
+        compiles or loads it as a first window would, one after the other
+        on this thread (a load from the compile cache handed to a worker
+        thread took 4.9 s on the chip against 1.0 s here). `batch`: the
+        ladder's top where it is not `self.B` (the scheduler's bulk
+        batch). `dhcp`: the scheduler's bulk replica, threaded in place of
+        the authoritative chain as its dispatches thread it; the replica
+        as the last window left it is returned."""
+        batch = batch or self.B
+        rungs = [b for b in step_rungs(batch) if b <= step_rung(max_n, batch)]
+        now = self.clock()
+        now_s = np.uint32(int(now))
+        now_us = np.uint32(int(now * 1e6) & 0xFFFFFFFF)
+
+        def tables_in():
+            return (self.tables if dhcp is None
+                    else self.tables._replace(dhcp=dhcp))
+
+        held = [x for x in jax.tree_util.tree_leaves(tables_in())
+                if x.committed]
+        if held:
+            # one committed input (the replica, copied over from an express
+            # lane on a device of its own) commits every output of a step:
+            # build for the tables as every later step finds them
+            rest = jax.device_put(self.tables._replace(dhcp=None),
+                                  next(iter(held[0].devices())))
+            self.tables = rest._replace(dhcp=self.tables.dhcp)
+        upd = self._empty_updates()
+        for b in rungs:
+            inert = self._upload_batch(np.zeros((b, self.L), dtype=np.uint8),
+                                       np.zeros((b,), dtype=np.uint32),
+                                       np.zeros((b,), dtype=bool))
+            res = self._step(tables_in(), upd, *inert, now_s, now_us)
+            if dhcp is None:
+                self.tables = res.tables
+            else:
+                self.tables = res.tables._replace(dhcp=self.tables.dhcp)
+                dhcp = res.tables.dhcp
+        jax.block_until_ready(res.verdict)
+        return dhcp
 
     @staticmethod
     def _upload_batch(pkt, length, fa):
@@ -1216,9 +1306,10 @@ class Engine:
         tele.fetched(t0, res.dhcp_stats, res.nat_stats, res.qos_stats,
                      res.spoof_stats, gs, ps_d, es, vs_d)
 
-    def _run_step(self, pkt, length, fa, now_s, now_us) -> PipelineResult:
+    def _run_step(self, pkt, length, fa, n: int,
+                  now_s, now_us) -> PipelineResult:
         """Dispatch + fold (the synchronous step both process paths use)."""
-        res = self._dispatch_step(pkt, length, fa, now_s, now_us)
+        res = self._dispatch_step(pkt, length, fa, n, now_s, now_us)
         self._fold_stats(res)
         return res
 
@@ -1260,7 +1351,7 @@ class Engine:
             if bool(((flags[:n] & FLAG_DHCP_CTRL) != 0).all()):
                 res = self._run_dhcp_batch_sync(pkt, length, now)
             else:
-                res = self._run_step(pkt, length, fa, now_s, now_us)
+                res = self._run_step(pkt, length, fa, n, now_s, now_us)
         except BaseException:
             tele.cancel_batch(tok)  # a failed dispatch must not leak a slot
             raise
@@ -1422,7 +1513,7 @@ class Engine:
                     else:
                         res = self._dispatch_step(pkt, length,
                                                   (flags & 0x1) != 0,
-                                                  now_s, now_us)
+                                                  n, now_s, now_us)
                 except BaseException:
                     # fail closed: the assemble opened a ring window that
                     # must not wedge. complete() retires FIFO, so the

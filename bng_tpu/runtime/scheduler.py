@@ -61,7 +61,7 @@ from bng_tpu.telemetry import spans as tele
 from bng_tpu.telemetry.recorder import (TRIG_EXPRESS_AOT_MISS,
                                         TRIG_EXPRESS_FALLBACK)
 from bng_tpu.runtime import hostpath
-from bng_tpu.runtime.engine import _ExpressAotResult
+from bng_tpu.runtime.engine import _ExpressAotResult, step_rung
 from bng_tpu.runtime.lanes import (CLOSE_DEADLINE, CLOSE_FLUSH, CompletionRing,
                                    InflightEntry, Lane, LaneConfig, LANE_BULK,
                                    LANE_EXPRESS)
@@ -685,6 +685,26 @@ class TieredScheduler:
         self._replica_resync = eng.resync_count
         self._replica_refreshes += 1
 
+    def build_bulk_rungs(self) -> None:
+        """Start-up's: every rung of the fused step's ladder a bulk batch
+        can take, built and run once over the replica before the lane
+        takes frames (Engine.build_step_rungs)."""
+        if self._express_dev is not None:
+            # the express lane's placement first (a resync undoes it): the
+            # replica is then the copy across devices every refresh makes,
+            # and the rungs are built for the tables a step finds later
+            self.engine._place_dhcp_chain(self._express_dev)
+        # the first dispatch's refresh replaces (and counts) this replica
+        self._bulk_dhcp = jax.tree_util.tree_map(
+            self._copy_to_bulk, self.engine.tables.dhcp)
+        self._replica_resync = self.engine.resync_count
+        batch = self.bulk.cfg.batch
+        self._bulk_dhcp = self.engine.build_step_rungs(
+            batch, batch=batch, dhcp=self._bulk_dhcp)
+        # and the packet-free program a prefetched drain takes when the
+        # traffic goes quiet behind it (_flush_prefetched)
+        self.engine.apply_updates_now(self.engine._empty_updates())
+
     def _copy_to_bulk(self, x):
         """A buffer the bulk chain may freely donate: device transfer when
         the authority lives elsewhere, a fresh same-device copy otherwise
@@ -692,7 +712,11 @@ class TieredScheduler:
         buffer would consume the express chain's live tables)."""
         if self._bulk_dev not in x.devices():
             return jax.device_put(x, self._bulk_dev)
-        return jnp.copy(x)
+        # placed like the copy across devices: the chain an express program
+        # returns is committed and a fresh upload is not, and a replica
+        # that changed with them would have the fused step built again for
+        # each (one committed input commits every output of a step)
+        return jax.device_put(jnp.copy(x), self._bulk_dev)
 
     def _dispatch_bulk(self, pend, now: float,
                        reason: str) -> InflightEntry | None:
@@ -704,7 +728,10 @@ class TieredScheduler:
         tok = tele.begin_batch(tele.LANE_BULK_L, len(pend))
         if tok is not None:
             tele.observe(tele.LANE_WAIT, (now - pend[0].enq_t) * 1e6, tok)
-        B = self.bulk.cfg.batch
+        # the rung that holds the batch, not the configured batch: the
+        # step costs the device by the lanes it is traced at
+        B = step_rung(len(pend), self.bulk.cfg.batch)
+        tele.step_lanes(B)
         t0 = tele.t()
         pkt, length = eng._pack_frames([p.frame for p in pend], B)
         fa = np.zeros((B,), dtype=bool)
@@ -825,8 +852,9 @@ class TieredScheduler:
 
     @staticmethod
     def _fetch_rows(out_pkt) -> np.ndarray:
-        """A retired batch's packet slots to the host, whole, at the first
-        lane that needs its bytes: one `fetch` lap inside `reply`."""
+        """A retired batch's packet slots to the host, whole (the rung's
+        `[b, L]`), at the first lane that needs its bytes: one `fetch`
+        lap inside `reply`."""
         t0 = tele.t()
         rows = np.asarray(out_pkt)
         tele.fetched(t0, out_pkt)

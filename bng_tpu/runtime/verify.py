@@ -178,11 +178,12 @@ def _engine(g: Geometry):
         batch_size=g.batch, pkt_slot=g.pkt_slot)
 
 
-def build_pipeline(g: Geometry = TOY):
+def build_pipeline(g: Geometry = TOY, lanes: int | None = None):
     """The fused step as the Engine compiles it: updates applied inside,
-    tables donated."""
+    tables donated. `lanes`: a rung of the step's ladder under `g.batch`
+    (engine.py step_rungs), the width a shorter window is dispatched at."""
     eng = _engine(g)
-    B = g.batch
+    B = lanes or g.batch
     return eng._step, (
         eng.tables, eng._empty_updates(),
         jnp.zeros((B, g.pkt_slot), dtype=jnp.uint8),

@@ -209,6 +209,10 @@ class Tracer:
         # stale lanes the engine's pipelined loop made inert (engine.py
         # _mask_stale_lanes): lanes that held a length beyond a window's end
         self.masked_lanes = 0
+        # lanes the fused steps were dispatched at: the rung of the step
+        # ladder each window took (engine.py step_rung), summed where the
+        # rung is chosen on the engine's loop and the scheduler's bulk lane
+        self.step_lanes = 0
         # table batches the engine's update drains built and uploaded, and
         # those a clean table answered with the batch already on the chip
         # (engine.py _drain_with_resync)
@@ -576,6 +580,7 @@ class Tracer:
             "starved_ns": starved,
             "p99_us": p99,
             "masked_lanes": int(self.masked_lanes),
+            "step_lanes": int(self.step_lanes),
             "drain_built": int(self.drain_built),
             "drain_cached": int(self.drain_cached),
             "pppoe_decap": int(self.pppoe_decap),
@@ -788,6 +793,14 @@ def masked_lanes(n: int) -> None:
     if _ACTIVE is None:
         return
     _ACTIVE.masked_lanes += n
+
+
+def step_lanes(b: int) -> None:
+    """Count the `b` lanes one fused step is dispatched at (the rung that
+    holds its window). Disarmed: global load + None compare."""
+    if _ACTIVE is None:
+        return
+    _ACTIVE.step_lanes += b
 
 
 def drain_tables(built: int, cached: int) -> None:

@@ -8,6 +8,12 @@ buffer must leave the device exactly where `Engine.process_ring`, which
 starts every call from zeros, leaves it: the DHCP stats, NAT's session
 counters, the QoS buckets, and every reply's bytes. Seeded random tables
 and frames, tiny sizes, CPU.
+
+Since PR 39 a step runs at the rung of its window (engine.py step_rung):
+shape `two-rungs` has a batch of 256 (rungs 128 and 256) and windows that
+cross the boundary both ways, long then short into one buffer and short
+then long into the other. The stale rows between the window's end and the
+rung's are the inert ones; those beyond the rung never reach the chip.
 """
 
 import jax
@@ -39,9 +45,16 @@ RINGS = {
 # windows in frames, by call: the third lands in the first's buffer and
 # the fourth in the second's, each far shorter than what it finds there
 WINDOWS = (14, 12, 3, 2)
+# `two-rungs`: 140 (the 256 rung) then 3 (the 128 rung) into one buffer,
+# 12 (128) then 150 (256) into the other
+SHAPES = {
+    "one-rung": dict(batch=BATCH, windows=WINDOWS, nframes=128, depth=32),
+    "two-rungs": dict(batch=256, windows=(140, 12, 3, 150), nframes=1024,
+                      depth=256),
+}
 
 
-def _stack(seed):
+def _stack(seed, batch=BATCH):
     """One engine over seeded random tables: every subscriber has a DHCP
     row, a QoS row a few frames deep, a strict binding, a NAT block and
     two flows. Returns the engine and what the frames are made from."""
@@ -76,7 +89,7 @@ def _stack(seed):
             nat_ip, nat_port = nat.handle_new_flow(ip, dst, sport, 443, proto,
                                                    64, T0)
             flows.append((mac, ip, dst, sport, proto, nat_ip, nat_port))
-    engine = Engine(fastpath, nat, qos, spoof, batch_size=BATCH,
+    engine = Engine(fastpath, nat, qos, spoof, batch_size=batch,
                     clock=lambda: float(T0))
     return engine, macs, ips, flows
 
@@ -108,14 +121,14 @@ def _data(rng, flows):
                 payload), False  # the matching downstream, DNAT
 
 
-def _windows(path, seed, macs, ips, flows):
+def _windows(path, seed, macs, ips, flows, windows=WINDOWS):
     """The same seeded frames in the same windows for both loops. `mixed`:
     every window DHCP and data, so each rides the fused step. `dhcp`: the
     third window is DHCP alone, and rides the DHCP-only fast lane into the
     buffer a long mixed window used."""
     rng = np.random.default_rng(seed + 1)
     out = []
-    for k, size in enumerate(WINDOWS):
+    for k, size in enumerate(windows):
         if path == "dhcp" and k == 2:
             out.append([_dhcp(rng, macs, ips) for _ in range(size)])
             continue
@@ -126,9 +139,11 @@ def _windows(path, seed, macs, ips, flows):
     return out
 
 
-def _serve(ring_kind, pipelined, path, seed):
-    engine, macs, ips, flows = _stack(seed)
-    ring = RINGS[ring_kind](nframes=128, frame_size=1024, depth=32)
+def _serve(ring_kind, pipelined, path, seed, shape="one-rung"):
+    sh = SHAPES[shape]
+    engine, macs, ips, flows = _stack(seed, sh["batch"])
+    ring = RINGS[ring_kind](nframes=sh["nframes"], frame_size=1024,
+                            depth=sh["depth"])
     fast_lane = []
     real = engine._run_dhcp_batch
 
@@ -146,7 +161,8 @@ def _serve(ring_kind, pipelined, path, seed):
                 replies[name].append(got[0])
 
     try:
-        for k, win in enumerate(_windows(path, seed, macs, ips, flows)):
+        for k, win in enumerate(_windows(path, seed, macs, ips, flows,
+                                         sh["windows"])):
             for frame, from_access in win:
                 assert ring.rx_push(frame, from_access=from_access)
             step(ring, now=T0 + 0.02 * k)
@@ -171,14 +187,17 @@ def _serve(ring_kind, pipelined, path, seed):
     return state
 
 
-@pytest.mark.parametrize("path", ["mixed", "dhcp"])
+@pytest.mark.parametrize("path,shape", [("mixed", "one-rung"),
+                                        ("dhcp", "one-rung"),
+                                        ("mixed", "two-rungs")])
 @pytest.mark.parametrize("ring_kind", sorted(RINGS))
-def test_short_window_after_long_leaves_process_rings_state(ring_kind, path):
+def test_short_window_after_long_leaves_process_rings_state(ring_kind, path,
+                                                            shape):
     if ring_kind == "native" and load_native() is None:
         pytest.skip("native toolchain unavailable")
     seed = 20280 + sorted(RINGS).index(ring_kind)
-    want = _serve(ring_kind, False, path, seed)
-    got = _serve(ring_kind, True, path, seed)
+    want = _serve(ring_kind, False, path, seed, shape)
+    got = _serve(ring_kind, True, path, seed, shape)
 
     # the scenario is the one the defect needs: four dispatches, device
     # DHCP hits, NAT and QoS at work, the fast lane taken where meant
@@ -216,6 +235,25 @@ def test_masked_lanes_is_a_sum_of_every_tracer_and_counts_ghost_lanes():
     with spans.armed() as tr:
         _serve("py-scalar", False, "mixed", 20289)  # fresh zeros a call
     assert tr.sums()["masked_lanes"] == 0
+
+
+def test_windows_that_cross_a_rung_are_masked_over_the_whole_buffer():
+    """`step_lanes` (PR 39) sums the rung each window took; the mask's
+    high-water mark runs over the whole buffer, beyond the rung too: the
+    short window's buffer held 140 lanes and is cleared from 3 to 140,
+    though only rows 3 to 128 go to the chip."""
+    assert spans.Tracer().sums()["step_lanes"] == 0
+    assert spans._ZERO_SUMS["step_lanes"] == 0
+    windows = SHAPES["two-rungs"]["windows"]
+    with spans.armed() as tr:
+        got = _serve("py-scalar", True, "mixed", 20289, "two-rungs")
+    assert got["batches"] == len(windows)
+    assert tr.sums()["step_lanes"] == 256 + 128 + 128 + 256
+    assert tr.sums()["masked_lanes"] == windows[0] - windows[2]
+    assert spans.trace_sums()["step_lanes"] == tr.sums()["step_lanes"]
+    with spans.armed() as tr:
+        _serve("py-scalar", True, "mixed", 20289)  # a batch under the floor
+    assert tr.sums()["step_lanes"] == len(WINDOWS) * BATCH
 
 
 def test_drain_tables_adds_up_to_the_tables_drained_and_is_silent_disarmed():

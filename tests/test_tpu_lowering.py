@@ -81,6 +81,21 @@ def test_fused_step_has_no_while(fused_step):
     assert _whiles(fused_step) == []
 
 
+@pytest.mark.parametrize("lanes", [1024, 128])
+def test_fused_step_compiles_at_every_rung_of_its_ladder(one_chip, lanes):
+    """A window shorter than `--batch-size` runs at the narrowest rung that
+    holds it (engine.py step_rungs: 128 / 1,024 / 8,192 at the cells'
+    size). The narrower programs are the same step traced at fewer lanes:
+    they fit, and a smaller batch brings back no loop."""
+    from bng_tpu.runtime.engine import step_rungs
+
+    assert step_rungs(REAL_1M.batch) == (128, 1024, REAL_1M.batch)
+    compiled = compile_for(verify.build_pipeline(REAL_1M, lanes=lanes),
+                           one_chip)
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert _whiles(compiled) == []
+
+
 def test_fused_step_with_the_pppoe_stage_fits_and_has_no_while(one_chip):
     """`bng run --pppoe-enabled` at the 1M geometry, the two session
     tables sized for 65,535 sessions: the step whose decap and encap move

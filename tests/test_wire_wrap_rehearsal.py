@@ -9,7 +9,12 @@ the ring's whole depth, over a pool of 8,192 frames: the one short push
 at each wrap leaves a short window in a staging buffer a full window
 used, and before the engine kept those lanes inert the device counted
 them again (`dhcp_accepted_minus_device_hits` -887 and -694 for seeds 5
-and 6 on the parent). No number from here is a device metric.
+and 6 on the parent). Since PR 39 the windows cross rungs of the step's
+ladder too (2,048: 128 / 256 / 2,048): a full window of 1,024 runs the
+2,048 program, the short ones after a wrap the narrower rungs, whose
+stale rows between the window's end and the rung's are the inert ones and
+those beyond the rung never reach the chip. No number from here is a
+device metric.
 """
 
 import json
@@ -33,7 +38,8 @@ COUNTERS = ("wire.masked_lanes_per_step", "engine.drain_built_per_step",
             "engine.drain_cached_per_step", "wire.frames_per_step",
             "wire.ring_us_per_frame", "wire.upload_calls_per_step",
             "wire.fetch_calls_per_step", "wire.upload_kb_per_step",
-            "wire.fetch_kb_per_step", "wire.drain_us_per_step")
+            "wire.fetch_kb_per_step", "wire.drain_us_per_step",
+            "wire.lanes_per_step")
 
 
 def _write(path, obj):
@@ -125,12 +131,19 @@ def test_the_hit_balance_holds_across_the_pools_wrap(wrap_dir, capsys, seed,
         # blocks (dhcp, nat, qos, spoof, garden). The window's last
         # dispatch retires after the Tracer is disarmed: ten reads short
         assert got["wire.upload_calls_per_step"]["value"] == 3
-        assert got["wire.upload_kb_per_step"]["value"] == \
-            2048 * (1536 + 4 + 1) / 1024
+        # the windows crossed rungs: full ones (1,024 frames) took the 2,048
+        # program, short ones a narrower rung, so the mean is under 2,048
+        # and over the frames it carried; what goes up and comes back is
+        # the rung's rows and no others
+        lanes = got["wire.lanes_per_step"]["value"]
+        assert got["wire.lanes_per_step"]["unit"] == "lanes"
+        assert got["wire.frames_per_step"]["value"] < lanes < 2048
+        assert got["wire.upload_kb_per_step"]["value"] == pytest.approx(
+            lanes * (1536 + 4 + 1) / 1024, rel=1e-9)
         assert got["wire.fetch_calls_per_step"]["value"] == \
             pytest.approx(3 + 2 + 5, abs=0.25)
         assert got["wire.fetch_kb_per_step"]["value"] == \
-            pytest.approx(2048 * (4 + 1536 + 4 + 1 + 1) / 1024, rel=0.03)
+            pytest.approx(lanes * (4 + 1536 + 4 + 1 + 1) / 1024, rel=0.03)
         assert got["wire.drain_us_per_step"]["value"] > 0
 
 
