@@ -236,6 +236,13 @@ class BNGConfig:
     # chip; its table is sized from max_subscribers. Off: an IPv6 frame
     # is judged by antispoof and left to the host
     ipv6_fastpath: bool = False
+    # device qinq stage (ops/qinq.py): the access VLANs end on the chip. A
+    # forwarded upstream frame leaves without its tags, a forwarded
+    # downstream frame with its subscriber's S- and C-tag (in front of the
+    # PPPoE header where it has a session); the pair table is sized from
+    # max_subscribers and filled from leases and sessions that came up
+    # over tags. Off: a frame keeps the tags it came with
+    qinq_enabled: bool = False
     # wire (AF_XDP attach ladder; runtime/xsk.py)
     wire_if: str = ""  # NIC to bind AF_XDP on ("" = in-memory ring only)
     wire_queue: int = 0
@@ -435,6 +442,7 @@ class BNGApp:
                 (cfg.scheduler_enabled, "scheduler"),
                 (cfg.pppoe_enabled, "pppoe"),
                 (cfg.ipv6_fastpath, "ipv6-fastpath"),
+                (cfg.qinq_enabled, "qinq"),
                 (cfg.wire_if, "wire"),
                 (cfg.slowpath_workers > 1, "slowpath-fleet")) if flag]
             if self.sharded_blockers:
@@ -838,6 +846,17 @@ class BNGApp:
 
             v6_tables = c["v6_tables"] = V6FastPathTables(
                 c["antispoof"], **_sized(cfg.max_subscribers, "nbuckets"))
+        qinq_tables = None
+        if cfg.qinq_enabled and cfg.shards <= 1:
+            from bng_tpu.runtime.tables import QinQFastPathTables
+
+            qinq_tables = c["qinq_tables"] = QinQFastPathTables(
+                **_sized(cfg.max_subscribers, "nbuckets"))
+            if cfg.slowpath_workers <= 1:
+                # a lease's pair reaches the table where the lease's
+                # other rows are written (the fleet's workers own their
+                # lease books: a named blocker below)
+                dhcp.qinq = qinq_tables
         if cfg.shards > 1:
             # the cluster IS the dataplane: drive_once feeds its steered
             # ring loop; the slow path is attached per beat (10b)
@@ -847,6 +866,7 @@ class BNGApp:
                 fastpath=fastpath, nat=nat, qos=qos,
                 antispoof=c["antispoof"],
                 garden=garden_tables, pppoe=pppoe_tables, v6=v6_tables,
+                qinq=qinq_tables,
                 batch_size=cfg.batch_size, slow_path=dhcp.handle_frame,
                 clock=self.clock)
             self.log.info("engine built", batch_size=cfg.batch_size,
@@ -1042,6 +1062,8 @@ class BNGApp:
                     pool.allocate_specific(sess.assigned_ip,
                                            f"pppoe:{sess.client_mac.hex()}")
                 pppoe_tables.session_up(sess)
+                if qinq_tables is not None and len(sess.vlans) == 2:
+                    qinq_tables.bind(sess.assigned_ip, *sess.vlans)
                 if cfg.qos_enabled:
                     qos_hook(sess.assigned_ip,
                              sess.radius_attributes.get("qos_policy"))
@@ -1056,6 +1078,8 @@ class BNGApp:
             def _pppoe_close(event, _acct=acct):
                 sess = event.session
                 pppoe_tables.session_down(event)
+                if qinq_tables is not None and sess.assigned_ip:
+                    qinq_tables.unbind(sess.assigned_ip)
                 if cfg.qos_enabled and sess.assigned_ip:
                     qos.remove_subscriber(sess.assigned_ip)
                 if cfg.nat_enabled and sess.assigned_ip:
@@ -1139,6 +1163,7 @@ class BNGApp:
             blockers = [name for flag, name in (
                 (cfg.pppoe_enabled, "pppoe"),
                 (cfg.ipv6_fastpath, "ipv6-fastpath"),
+                (cfg.qinq_enabled, "qinq"),
                 (cfg.shards > 1, "sharded")) if flag]
             if blockers:
                 # more than a log line: the degradation is exported as
@@ -2276,6 +2301,14 @@ class BNGApp:
                 "bound": v6_tables.by_addr.count,
                 "device": {"fwd_up": fwd_up, "fwd_down": fwd_down,
                            "miss": miss, "ctrl": ctrl}}
+        qinq_tables = self.components.get("qinq_tables")
+        if qinq_tables is not None and eng is not None:
+            push, pop, miss, oversize = (int(x) for x in eng.stats.qinq)
+            out["qinq"] = {
+                "pairs": qinq_tables.by_ip.count,
+                "refused": qinq_tables.refused,
+                "device": {"push": push, "pop": pop, "miss": miss,
+                           "oversize": oversize}}
         nat = self.components.get("nat")
         if nat is not None:  # registered only when nat_enabled
             out["nat"] = {"sessions": nat.sessions.count,

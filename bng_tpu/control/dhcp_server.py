@@ -111,6 +111,10 @@ class DHCPServer:
         self.lease_time_cap = lease_time_cap
         self.lease_jitter_frac = lease_jitter_frac
         self.clock = clock
+        # the address -> S/C-tag table of the device's qinq stage
+        # (runtime.tables.QinQFastPathTables); the composition root sets
+        # it under `bng run --qinq-enabled`. Nil-safe like `tables`
+        self.qinq = None
         self.leases: dict[int, Lease] = {}  # mac_u64 -> Lease
         self.leases_by_cid: dict[bytes, int] = {}  # circuit_id -> mac_u64
         self._offers: dict[int, tuple[int, int]] = {}  # mac -> (ip, pool_id)
@@ -176,6 +180,17 @@ class DHCPServer:
             if mk is not None:
                 return self.leases.get(mk)
         return self.leases.get(self._mac_key(req))
+
+    def _line_of(self, vlans: list[int], profile: dict) -> tuple[int, int]:
+        """The S- and C-tag a lease is behind: the authenticator's, else,
+        under the 1:1 VLAN model (`qinq` set: a pair is one subscriber's
+        line), those of a double-tagged request. Without that model a
+        request's tags name no subscriber (a service VLAN is shared), and
+        a VLAN-tier row from them would answer every client behind it."""
+        s_tag, c_tag = profile.get("s_tag", 0), profile.get("c_tag", 0)
+        if not (s_tag or c_tag) and self.qinq is not None and len(vlans) == 2:
+            s_tag, c_tag = vlans
+        return s_tag, c_tag
 
     def _allocate_ip(self, req: DHCPPacket, client_class: int) -> tuple[int, int] | None:
         """Allocation cascade (parity: handleDiscover, server.go:398-553):
@@ -277,6 +292,14 @@ class DHCPServer:
                 if self.tables is not None:
                     self.tables.remove_circuit_id_subscriber(lease.circuit_id)
             lease.circuit_id, lease.remote_id = cid, rid
+            line = self._line_of(vlans, {})
+            if any(line) and line != (lease.s_tag, lease.c_tag):
+                # the subscriber moved to another line: the old pair's
+                # VLAN-tier row goes (bind below moves the pair itself)
+                if self.tables is not None and (lease.s_tag or lease.c_tag):
+                    self.tables.remove_vlan_subscriber(lease.s_tag,
+                                                       lease.c_tag)
+                lease.s_tag, lease.c_tag = line
         else:
             if existing is not None:
                 # same MAC granted a different IP: the old lease's address
@@ -288,11 +311,14 @@ class DHCPServer:
                     self.leases_by_cid.pop(existing.circuit_id, None)
                 if self.accounting_hook is not None:
                     self.accounting_hook("stop", existing, existing.session_id)
+                if self.qinq is not None:
+                    self.qinq.unbind(existing.ip)
             self._session_seq += 1
+            s_tag, c_tag = self._line_of(vlans, profile)
             lease = Lease(
                 mac=mac, ip=ip, pool_id=pool_id, expiry=now + lease_time,
                 circuit_id=cid, remote_id=rid,
-                s_tag=profile.get("s_tag", 0), c_tag=profile.get("c_tag", 0),
+                s_tag=s_tag, c_tag=c_tag,
                 session_id=f"bng-{now:x}-{self._session_seq:06x}",
                 username=profile.get("username", ""),
                 qos_policy=profile.get("qos_policy", ""),
@@ -341,6 +367,8 @@ class DHCPServer:
                 self.tables.remove_circuit_id_subscriber(lease.circuit_id)
             if lease.s_tag or lease.c_tag:
                 self.tables.remove_vlan_subscriber(lease.s_tag, lease.c_tag)
+        if self.qinq is not None:
+            self.qinq.unbind(lease.ip)
         if self.allocator is not None:
             self.allocator.release(lease.mac.hex())
         if self.release_hook is not None:
@@ -361,6 +389,11 @@ class DHCPServer:
         lease = self.leases.pop(mk, None)
         if lease is not None and self.tables is not None:
             self.tables.remove_subscriber(lease.mac)
+        if lease is not None and self.qinq is not None:
+            # the client comes back for another address on the same line
+            if lease.s_tag or lease.c_tag:
+                self.tables.remove_vlan_subscriber(lease.s_tag, lease.c_tag)
+            self.qinq.unbind(lease.ip)
 
     def _inform(self, req: DHCPPacket) -> DHCPPacket | None:
         self.stats.inform += 1
@@ -388,6 +421,11 @@ class DHCPServer:
                 lease.circuit_id, pool_id=pool.pool_id, ip=lease.ip,
                 lease_expiry=lease.expiry, client_class=lease.client_class,
             )
+        if self.qinq is not None and lease.s_tag and lease.c_tag:
+            if not self.qinq.bind(lease.ip, lease.s_tag, lease.c_tag):
+                # the registry holds the pair for another subscriber: this
+                # lease is behind no line, and neither row is written
+                lease.s_tag = lease.c_tag = 0
         if lease.s_tag or lease.c_tag:
             self.tables.add_vlan_subscriber(
                 lease.s_tag, lease.c_tag, pool_id=pool.pool_id, ip=lease.ip,
@@ -538,6 +576,8 @@ class DHCPServer:
                     self.tables.remove_circuit_id_subscriber(lease.circuit_id)
                 if lease.s_tag or lease.c_tag:
                     self.tables.remove_vlan_subscriber(lease.s_tag, lease.c_tag)
+            if self.qinq is not None:
+                self.qinq.unbind(lease.ip)
             if self.allocator is not None:
                 self.allocator.release(lease.mac.hex())
             if self.release_hook is not None:

@@ -11,6 +11,8 @@ from __future__ import annotations
 import threading
 from dataclasses import dataclass, field
 
+import numpy as np
+
 
 @dataclass(frozen=True)
 class VLANPair:
@@ -78,19 +80,20 @@ class QinQConfig:
 class QinQMapper:
     """Bidirectional VLANPair <-> subscriber-ID registry (qinq.go:100-210).
 
-    The registry is the control-plane source of truth; activation writes the
-    pair into the device vlan_subscriber table (runtime.tables) so the
-    fast path can do the 3-tier lookup the reference does in
-    bpf/dhcp_fastpath.c:653-681.
+    The registry is the control-plane source of truth: `bng run
+    --qinq-enabled` writes every pair of the device's by-address table
+    (runtime.tables.QinQFastPathTables, ops/qinq.py) through it, a lease's
+    and a PPPoE session's alike, with the subscriber's address as its id.
     """
 
     def __init__(self, config: QinQConfig | None = None):
         self.config = config or QinQConfig()
         self._lock = threading.Lock()
-        self._by_vlan: dict[VLANPair, str] = {}
-        self._by_subscriber: dict[str, VLANPair] = {}
+        # a subscriber's id is any hashable: a name, or its address
+        self._by_vlan: dict[VLANPair, object] = {}
+        self._by_subscriber: dict[object, VLANPair] = {}
 
-    def register(self, vlan: VLANPair, subscriber_id: str) -> None:
+    def register(self, vlan: VLANPair, subscriber_id) -> None:
         cfg = self.config
         if vlan.is_untagged and not cfg.allow_untagged:
             raise ValueError("untagged registration not allowed")
@@ -116,23 +119,51 @@ class QinQMapper:
             self._by_vlan[vlan] = subscriber_id
             self._by_subscriber[subscriber_id] = vlan
 
+    def register_bulk(self, s_tags, c_tags, subscriber_ids) -> None:
+        """`register` for subscribers that hold no pair yet, at the size of
+        a provisioning run or a warm restart, double-tagged pairs only: all
+        of them or, where one is refused (out of range, held already,
+        twice in the batch), none."""
+        s = np.asarray(s_tags, dtype=np.int64)
+        c = np.asarray(c_tags, dtype=np.int64)
+        sr, cr = self.config.s_tag_range, self.config.c_tag_range
+        if not ((s >= max(sr.start, 1)) & (s <= sr.end)
+                & (c >= max(cr.start, 1)) & (c <= cr.end)).all():
+            raise ValueError("a pair outside the allowed ranges")
+        pairs = [VLANPair(a, b) for a, b in zip(s.tolist(), c.tolist())]
+        ids = np.asarray(subscriber_ids).tolist()
+        by_vlan, by_sub = dict(zip(pairs, ids)), dict(zip(ids, pairs))
+        if len(by_vlan) != len(pairs) or len(by_sub) != len(pairs):
+            raise ValueError("a pair or a subscriber twice in one batch")
+        with self._lock:
+            if (self._by_vlan.keys() & by_vlan.keys()
+                    or self._by_subscriber.keys() & by_sub.keys()):
+                raise ValueError("a pair or a subscriber already registered")
+            self._by_vlan.update(by_vlan)
+            self._by_subscriber.update(by_sub)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._by_vlan.clear()
+            self._by_subscriber.clear()
+
     def unregister(self, vlan: VLANPair) -> None:
         with self._lock:
             sub = self._by_vlan.pop(vlan, None)
             if sub is not None and self._by_subscriber.get(sub) == vlan:
                 del self._by_subscriber[sub]
 
-    def unregister_subscriber(self, subscriber_id: str) -> None:
+    def unregister_subscriber(self, subscriber_id) -> None:
         with self._lock:
             vlan = self._by_subscriber.pop(subscriber_id, None)
             if vlan is not None:
                 self._by_vlan.pop(vlan, None)
 
-    def get_subscriber(self, vlan: VLANPair) -> str | None:
+    def get_subscriber(self, vlan: VLANPair):
         with self._lock:
             return self._by_vlan.get(vlan)
 
-    def get_vlan(self, subscriber_id: str) -> VLANPair | None:
+    def get_vlan(self, subscriber_id) -> VLANPair | None:
         with self._lock:
             return self._by_subscriber.get(subscriber_id)
 

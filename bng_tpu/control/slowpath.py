@@ -57,7 +57,14 @@ class SlowPathDemux:
             self.stats["unmatched"] += 1
             return None
         ethertype = int.from_bytes(frame[12:14], "big")
-        if ethertype in (0x8863, 0x8864) and self.pppoe is not None:
+        behind = ethertype
+        if ethertype in (0x8100, 0x88A8):
+            # a PPPoE client behind an S- and a C-tag: the server peels
+            # them as this does and answers on the same line
+            from bng_tpu.control.pppoe.codec import parse_eth_vlan
+
+            behind = parse_eth_vlan(frame)[2]
+        if behind in (0x8863, 0x8864) and self.pppoe is not None:
             self.stats["pppoe"] += 1
             replies = self.pppoe.handle_frame(frame, self.clock())
             # one reply rides back inline; extras queue for drain_pending()

@@ -87,6 +87,10 @@ class PipelineTables(NamedTuple):
     # address. None = no `v6` stage compiled in: an IPv6 frame is judged
     # by antispoof and otherwise left to the host, as before the stage.
     v6_by_addr: TableState | None = None
+    # the access VLANs (ops/qinq.py): a subscriber's IPv4 address -> its
+    # S- and C-tag. None = no `qinq` stage compiled in: a forwarded frame
+    # leaves with the tags it came with, and a downstream one with none.
+    qinq_by_ip: TableState | None = None
 
 
 class PipelineGeom(NamedTuple):
@@ -99,6 +103,7 @@ class PipelineGeom(NamedTuple):
     tap: TableGeom | None = None
     route: TableGeom | None = None
     v6: TableGeom | None = None
+    qinq: TableGeom | None = None
 
 
 class PipelineResult(NamedTuple):
@@ -122,11 +127,13 @@ class PipelineResult(NamedTuple):
     mirror: jax.Array | None = None
     edge_stats: jax.Array | None = None  # [EDGE_NSTATS] when edge on
     v6_stats: jax.Array | None = None  # [V6_NSTATS] when the v6 stage is on
+    qinq_stats: jax.Array | None = None  # [QINQ_NSTATS] when qinq is on
 
 
 # Each stage below runs under a `jax.named_scope` (parse, antispoof, dhcp,
-# garden, nat44, qos, edge, pppoe, v6, rewrite). Metadata only: the HLO and its
-# op names are the same, and a recorded device trace can be summed by stage
+# garden, nat44, qos, edge, pppoe, v6, rewrite, qinq). Metadata only: the HLO
+# and its op names are the same, and a recorded device trace can be summed by
+# stage
 # (`python -m bng_tpu.utils.profiling <trace dir>`).
 def pipeline_step(
     tables: PipelineTables,
@@ -301,6 +308,19 @@ def pipeline_step(
                       jnp.where(fwd, VERDICT_FWD, VERDICT_PASS)),
         ).astype(jnp.int32)
 
+    # --- the access VLANs (ops/qinq.py), last: every stage above has read
+    # its offsets behind the tags a frame came with. `down_key` is the
+    # subscriber's address on a downstream lane of either family.
+    qinq = None
+    if tables.qinq_by_ip is not None:
+        from bng_tpu.ops.qinq import qinq_stage
+
+        with jax.named_scope("qinq"):
+            qinq = qinq_stage(out_pkt, out_len, parsed.vlan_offset,
+                              from_access, verdict == VERDICT_FWD, down_key,
+                              tables.qinq_by_ip, geom.qinq)
+            out_pkt, out_len = qinq.out_pkt, qinq.out_len
+
     # NAT accounting only for lanes that actually forward: a packet the
     # pipeline drops (QoS/antispoof) must not advance session counters
     with jax.named_scope("nat44"):
@@ -332,4 +352,5 @@ def pipeline_step(
         edge_stats=edge_stats,
         v6_stats=(None if v6 is None
                   else v6_stats(v6, verdict == VERDICT_FWD)),
+        qinq_stats=None if qinq is None else qinq.stats,
     )

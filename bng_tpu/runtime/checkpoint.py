@@ -225,7 +225,7 @@ def _denamespace(prefix: str, arrays: dict) -> dict:
 def build_checkpoint(seq: int, now: float, *, engine=None, scheduler=None,
                      fastpath=None, nat=None, qos=None, antispoof=None,
                      garden=None, pppoe=None, edge=None, dhcp=None, ha=None,
-                     fleet=None, cluster_plan=None, v6=None,
+                     fleet=None, cluster_plan=None, v6=None, qinq=None,
                      node_id: str = "") -> Checkpoint:
     """Collect a consistent snapshot of the authoritative state.
 
@@ -245,6 +245,7 @@ def build_checkpoint(seq: int, now: float, *, engine=None, scheduler=None,
         pppoe = pppoe if pppoe is not None else engine.pppoe
         edge = edge if edge is not None else getattr(engine, "edge", None)
         v6 = v6 if v6 is not None else getattr(engine, "v6", None)
+        qinq = qinq if qinq is not None else getattr(engine, "qinq", None)
         if scheduler is not None:
             scheduler.quiesce()
         else:
@@ -295,6 +296,10 @@ def build_checkpoint(seq: int, now: float, *, engine=None, scheduler=None,
         m, a = v6.checkpoint_state()
         meta["components"]["v6"] = m
         arrays.update(_ns("v6", a))
+    if qinq is not None:
+        m, a = qinq.checkpoint_state()
+        meta["components"]["qinq"] = m
+        arrays.update(_ns("qinq", a))
     if dhcp is not None:
         meta["components"]["dhcp"] = dhcp.export_leases()
     if ha is not None:
@@ -433,6 +438,11 @@ def _verify_components(ckpt: Checkpoint, comps: dict, targets: dict) -> None:
                      {k: a.get(f"by_addr.{k}")
                       for k in ("keys", "vals", "used")},
                      comps["v6"]["geom"]["by_addr"], "v6.by_addr")
+    if "qinq" in comps:
+        a = _denamespace("qinq", ckpt.arrays)
+        _check_table(targets["qinq"].by_ip,
+                     {k: a.get(f"by_ip.{k}") for k in ("keys", "vals", "used")},
+                     comps["qinq"]["geom"]["by_ip"], "qinq.by_ip")
     # dry-parse the dict-driven components: their meta is consumed
     # during mutation, so a parse fault there must be caught HERE or the
     # reject would leave the process half-hydrated
@@ -478,7 +488,7 @@ def restore_checkpoint(ckpt: Checkpoint, *, engine=None, fastpath=None,
                        nat=None, qos=None, antispoof=None, garden=None,
                        pppoe=None, edge=None, dhcp=None, ha=None,
                        fleet=None, cluster_coord=None,
-                       v6=None) -> dict[str, int]:
+                       v6=None, qinq=None) -> dict[str, int]:
     """Hydrate the host mirrors from a decoded checkpoint and re-upload.
 
     Reject-on-mismatch: every table component present in the checkpoint
@@ -504,6 +514,7 @@ def restore_checkpoint(ckpt: Checkpoint, *, engine=None, fastpath=None,
         pppoe = pppoe if pppoe is not None else engine.pppoe
         edge = edge if edge is not None else getattr(engine, "edge", None)
         v6 = v6 if v6 is not None else getattr(engine, "v6", None)
+        qinq = qinq if qinq is not None else getattr(engine, "qinq", None)
     comps = dict(ckpt.meta.get("components", {}))
     for name in _PAYLOAD_JSON_COMPONENTS:
         if name in comps:
@@ -511,7 +522,7 @@ def restore_checkpoint(ckpt: Checkpoint, *, engine=None, fastpath=None,
     targets = {"fastpath": fastpath, "nat": nat, "qos": qos,
                "antispoof": antispoof, "garden": garden, "pppoe": pppoe,
                "edge": edge, "dhcp": dhcp, "ha": ha, "fleet": fleet,
-               "cluster_plan": cluster_coord, "v6": v6}
+               "cluster_plan": cluster_coord, "v6": v6, "qinq": qinq}
     missing = []
     for name in comps:
         tgt = targets.get(name)
@@ -575,6 +586,10 @@ def restore_checkpoint(ckpt: Checkpoint, *, engine=None, fastpath=None,
             got = v6.restore_state(comps["v6"],
                                    _denamespace("v6", ckpt.arrays))
             rows.update({f"v6.{k}": v for k, v in got.items()})
+        if "qinq" in comps:
+            got = qinq.restore_state(comps["qinq"],
+                                     _denamespace("qinq", ckpt.arrays))
+            rows.update({f"qinq.{k}": v for k, v in got.items()})
         if "dhcp" in comps or "fleet" in comps:
             worker_books = (list(comps["fleet"]["workers"])
                             if "fleet" in comps else [])

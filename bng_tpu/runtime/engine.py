@@ -49,6 +49,7 @@ from bng_tpu.ops.pipeline import (
     VERDICT_TX,
     pipeline_step,
 )
+from bng_tpu.ops.qinq import QINQ_NSTATS, QQ_MISS, QQ_POP, QQ_PUSH
 from bng_tpu.ops.qos import QOS_NSTATS
 from bng_tpu.ops.v6 import (V6_NSTATS, V6ST_CTRL, V6ST_FWD_DOWN, V6ST_FWD_UP,
                             V6ST_MISS)
@@ -58,8 +59,8 @@ from bng_tpu.ops.table import HostTable, TableGeom, apply_update, placed
 from bng_tpu.runtime import hostpath
 from bng_tpu.runtime.ring import FLAG_DHCP_CTRL
 from bng_tpu.runtime.tables import (FastPathTables, PPPoEFastPathTables,
-                                    V6FastPathTables, apply_fastpath_updates,
-                                    mac_key_rows)
+                                    QinQFastPathTables, V6FastPathTables,
+                                    apply_fastpath_updates, mac_key_rows)
 from bng_tpu.utils.structlog import ErrorLog, SlowPathErrorLog
 
 # default per-lane packet slot: a full MTU frame (1500) + headroom for
@@ -72,9 +73,9 @@ PKT_SLOT = 1536
 def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
     """upd layout: 7 mandatory entries + optional named tails — garden
     (garden_upd, allowed_rows), then pppoe (sid_upd, ip_upd), then edge
-    (tap_upd, tap_filters, tap_config, route_upd), then v6 (by_addr_upd) —
-    each present exactly when the corresponding device stage is compiled
-    in."""
+    (tap_upd, tap_filters, tap_config, route_upd), then v6 (by_addr_upd),
+    then qinq (by_ip_upd) — each present exactly when the corresponding
+    device stage is compiled in."""
     fp_upd, nat_upd, qup, qdown, sp_upd, sp_ranges, sp_config, *tails = upd
     tails = list(tails)
     g_state, g_allowed = tables.garden, tables.garden_allowed
@@ -95,6 +96,9 @@ def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
     v6_by_addr = tables.v6_by_addr
     if v6_by_addr is not None:
         v6_by_addr = apply_update(v6_by_addr, tails.pop(0))
+    qinq_by_ip = tables.qinq_by_ip
+    if qinq_by_ip is not None:
+        qinq_by_ip = apply_update(qinq_by_ip, tails.pop(0))
     return PipelineTables(
         dhcp=apply_fastpath_updates(tables.dhcp, fp_upd),
         nat=apply_nat_updates(tables.nat, nat_upd),
@@ -113,6 +117,7 @@ def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
         tap_config=e_config,
         route=e_route,
         v6_by_addr=v6_by_addr,
+        qinq_by_ip=qinq_by_ip,
     )
 
 
@@ -174,7 +179,7 @@ def _apply_updates_jit(geom: PipelineGeom, has_garden: bool, has_pppoe: bool,
     the authoritative chain may live on the express lane's own device —
     including it would force a cross-device program. geom rides in the
     key only to separate engines whose update pytrees differ (the v6
-    tail's presence is `geom.v6`'s)."""
+    and qinq tails' presence is `geom.v6`'s and `geom.qinq`'s)."""
     del geom, has_garden, has_pppoe, has_edge
 
     def apply_only(tables, upd):
@@ -199,6 +204,9 @@ def _apply_updates_jit(geom: PipelineGeom, has_garden: bool, has_pppoe: bool,
         v6_by_addr = tables.v6_by_addr
         if v6_by_addr is not None:
             v6_by_addr = apply_update(v6_by_addr, tails.pop(0))
+        qinq_by_ip = tables.qinq_by_ip
+        if qinq_by_ip is not None:
+            qinq_by_ip = apply_update(qinq_by_ip, tails.pop(0))
         from bng_tpu.control.nat import apply_nat_updates
 
         return tables._replace(
@@ -210,7 +218,7 @@ def _apply_updates_jit(geom: PipelineGeom, has_garden: bool, has_pppoe: bool,
             garden=g_state, garden_allowed=g_allowed,
             pppoe_by_sid=p_sid, pppoe_by_ip=p_ip,
             tap=e_tap, tap_filters=e_filters, tap_config=e_config,
-            route=e_route, v6_by_addr=v6_by_addr)
+            route=e_route, v6_by_addr=v6_by_addr, qinq_by_ip=qinq_by_ip)
 
     return jax.jit(apply_only, donate_argnums=(0,))
 
@@ -328,6 +336,10 @@ class EngineStats:
     # device IPv6 stage: forwarded up, forwarded down, downstream miss,
     # control passed to the host (ops/v6.py V6ST_*)
     v6: np.ndarray = field(default_factory=lambda: np.zeros(V6_NSTATS, dtype=np.uint64))
+    # device qinq stage: pairs pushed, tags popped, downstream lanes of a
+    # subscriber without a pair, pushes the slot had no room for
+    # (ops/qinq.py QQ_*)
+    qinq: np.ndarray = field(default_factory=lambda: np.zeros(QINQ_NSTATS, dtype=np.uint64))
     batches: int = 0
     tx: int = 0
     fwd: int = 0
@@ -505,6 +517,7 @@ class Engine:
         edge: "EdgeTables | None" = None,
         mirror_sink: Callable[[int, bytes, int], None] | None = None,
         v6: "V6FastPathTables | None" = None,
+        qinq: "QinQFastPathTables | None" = None,
     ):
         self.fastpath = fastpath
         self.nat = nat
@@ -527,6 +540,10 @@ class Engine:
         # judged by antispoof and left to the host); the composition root
         # passes V6FastPathTables under `bng run --ipv6-fastpath`
         self.v6 = v6
+        # None = no qinq stage in the compiled pipeline (a forwarded frame
+        # keeps the tags it came with); the composition root passes
+        # QinQFastPathTables under `bng run --qinq-enabled`
+        self.qinq = qinq
         # host retire hook for MIRROR-flagged lanes: (lane, frame, wid).
         # The MirrorPump (edge/compile.py) feeds RecordCC/HI3 export here.
         self.mirror_sink = mirror_sink
@@ -566,6 +583,7 @@ class Engine:
             tap=self.edge.geom if self.edge else None,
             route=self.edge.geom if self.edge else None,
             v6=self.v6.geom if self.v6 else None,
+            qinq=self.qinq.geom if self.qinq else None,
         )
         # `device_tables` adopts a prebuilt geometry-identical device
         # pytree (the blue/green standby's snapshot-hydrated chain,
@@ -614,6 +632,8 @@ class Engine:
                         if self.edge else None),
             route=(self.edge.route.device_state() if self.edge else None),
             v6_by_addr=(self.v6.by_addr.device_state() if self.v6 else None),
+            qinq_by_ip=(self.qinq.by_ip.device_state() if self.qinq
+                        else None),
         )
 
     def resync_tables(self) -> None:
@@ -660,8 +680,8 @@ class Engine:
         last placed and placed again when they differ (ops/table.py
         placed) — the step applies them wholesale, so a write made before
         this call is in this batch."""
-        sp, g, p, e, v = (self.antispoof, self.garden, self.pppoe, self.edge,
-                          self.v6)
+        sp, g, p, e, v, q = (self.antispoof, self.garden, self.pppoe,
+                             self.edge, self.v6, self.qinq)
 
         def one(t, slots):
             return t.make_update(slots) if rest else t.empty_update(slots)
@@ -680,6 +700,7 @@ class Engine:
             *((p.make_updates() if rest else p.empty_updates()) if p else ()),
             *((e.make_updates() if rest else e.empty_updates()) if e else ()),
             *((one(v.by_addr, v.update_slots),) if v else ()),
+            *((one(q.by_ip, q.update_slots),) if q else ()),
         )
 
     def _drain_updates(self):
@@ -1303,8 +1324,14 @@ class Engine:
             self.stats.v6 += vs
             tele.v6_lanes(int(vs[V6ST_FWD_UP] + vs[V6ST_FWD_DOWN]),
                           int(vs[V6ST_MISS]), int(vs[V6ST_CTRL]))
+        qs_d = getattr(res, "qinq_stats", None)
+        if qs_d is not None:
+            qs = np.asarray(qs_d, dtype=np.uint64)
+            self.stats.qinq += qs
+            tele.qinq_lanes(int(qs[QQ_PUSH]), int(qs[QQ_POP]),
+                            int(qs[QQ_MISS]))
         tele.fetched(t0, res.dhcp_stats, res.nat_stats, res.qos_stats,
-                     res.spoof_stats, gs, ps_d, es, vs_d)
+                     res.spoof_stats, gs, ps_d, es, vs_d, qs_d)
 
     def _run_step(self, pkt, length, fa, n: int,
                   now_s, now_us) -> PipelineResult:
@@ -1646,6 +1673,8 @@ class Engine:
             out["edge/route"] = self.edge.route
         if self.v6 is not None:
             out["v6/by_addr"] = self.v6.by_addr
+        if self.qinq is not None:
+            out["qinq/by_ip"] = self.qinq.by_ip
         return out
 
     def pending_dirty(self) -> int:

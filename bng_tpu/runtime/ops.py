@@ -70,7 +70,7 @@ def clone_mirrors(engine) -> dict:
     from bng_tpu.control.nat import NATManager
     from bng_tpu.runtime.engine import AntispoofTables, GardenTables, QoSTables
     from bng_tpu.runtime.tables import (FastPathTables, PPPoEFastPathTables,
-                                        V6FastPathTables)
+                                        QinQFastPathTables, V6FastPathTables)
 
     fp = engine.fastpath
     nat = engine.nat
@@ -109,6 +109,11 @@ def clone_mirrors(engine) -> dict:
             out["antispoof"], nbuckets=engine.v6.by_addr.nbuckets,
             stash=engine.v6.by_addr.stash,
             update_slots=engine.v6.update_slots)
+    if engine.qinq is not None:
+        out["qinq"] = QinQFastPathTables(
+            nbuckets=engine.qinq.by_ip.nbuckets,
+            stash=engine.qinq.by_ip.stash,
+            update_slots=engine.qinq.update_slots)
     return out
 
 
@@ -197,7 +202,7 @@ def blue_green_swap(components, *, audit: bool = True, metrics=None,
         ckpt = build_checkpoint(
             0, eng.clock(), fastpath=eng.fastpath, nat=eng.nat, qos=eng.qos,
             antispoof=eng.antispoof, garden=eng.garden, pppoe=eng.pppoe,
-            v6=eng.v6, node_id=node_id)
+            v6=eng.v6, qinq=eng.qinq, node_id=node_id)
         ckpt = roundtrip_checkpoint(ckpt)  # ops.snapshot chaos point
         report["quiesce_s"] = time.perf_counter() - t_q
         tele.lap(tele.OPS, t0)
@@ -214,13 +219,13 @@ def blue_green_swap(components, *, audit: bool = True, metrics=None,
             tmp["fastpath"], tmp["nat"], qos=tmp["qos"],
             antispoof=tmp["antispoof"], garden=tmp.get("garden"),
             pppoe=tmp.get("pppoe"), batch_size=eng.B, pkt_slot=eng.L,
-            clock=eng.clock, v6=tmp.get("v6"))
+            clock=eng.clock, v6=tmp.get("v6"), qinq=tmp.get("qinq"))
         standby = Engine(
             eng.fastpath, eng.nat, qos=eng.qos, antispoof=eng.antispoof,
             garden=eng.garden, pppoe=eng.pppoe, batch_size=eng.B,
             pkt_slot=eng.L, slow_path=eng.slow_path,
             violation_sink=eng.violation_sink, clock=eng.clock,
-            device_tables=hydrator.tables, v6=eng.v6)
+            device_tables=hydrator.tables, v6=eng.v6, qinq=eng.qinq)
         standby.slow_path_batch = eng.slow_path_batch
         standby.stats = eng.stats  # operational counters never reset
         report["hydrate_s"] = time.perf_counter() - t_h

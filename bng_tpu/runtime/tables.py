@@ -49,6 +49,7 @@ from bng_tpu.ops.pppoe import (
     PS_MAC_LO,
     PS_SESSION_ID,
 )
+from bng_tpu.ops.qinq import QINQ_WORDS, QV_C_TAG, QV_S_TAG
 from bng_tpu.ops.table import (HostTable, TableGeom, TableUpdate,
                                apply_update, placed)
 from bng_tpu.ops.v6 import V6_WORDS, VA_IPV4, VA_MAC_HI, VA_MAC_LO
@@ -132,6 +133,19 @@ class FastPathTables:
         v[AV_FLAGS] = flags
         return v
 
+    @staticmethod
+    def _assignments(n, pool_ids, ips, lease_expiries, vlan_ids,
+                     client_classes, flags) -> np.ndarray:
+        """`_assignment` for a bulk build: [n, ASSIGN_WORDS] rows."""
+        vals = np.zeros((n, ASSIGN_WORDS), dtype=np.uint32)
+        vals[:, AV_POOL_ID] = pool_ids
+        vals[:, AV_IP] = ips
+        vals[:, AV_VLAN] = vlan_ids
+        vals[:, AV_CLASS] = client_classes
+        vals[:, AV_LEASE_EXP] = lease_expiries
+        vals[:, AV_FLAGS] = flags
+        return vals
+
     def add_subscriber(self, mac, pool_id: int, ip: int, lease_expiry: int,
                        vlan_id: int = 0, client_class: int = 0, flags: int = 0) -> None:
         key = mac_to_u64(mac) if not isinstance(mac, int) else mac
@@ -150,14 +164,9 @@ class FastPathTables:
         Follow with device_tables() for a full upload.
         """
         keys = mac_key_rows(macs_u64)
-        vals = np.zeros((len(keys), ASSIGN_WORDS), dtype=np.uint32)
-        vals[:, AV_POOL_ID] = pool_ids
-        vals[:, AV_IP] = ips
-        vals[:, AV_VLAN] = vlan_ids
-        vals[:, AV_CLASS] = client_classes
-        vals[:, AV_LEASE_EXP] = lease_expiries
-        vals[:, AV_FLAGS] = flags
-        self.sub.bulk_insert(keys, vals)
+        self.sub.bulk_insert(keys, self._assignments(
+            len(keys), pool_ids, ips, lease_expiries, vlan_ids,
+            client_classes, flags))
 
     def remove_subscriber(self, mac) -> bool:
         key = mac_to_u64(mac) if not isinstance(mac, int) else mac
@@ -173,6 +182,18 @@ class FastPathTables:
                             lease_expiry: int, client_class: int = 0, flags: int = 0) -> None:
         self.vlan.insert([(s_tag << 16) | c_tag],
                          self._assignment(pool_id, ip, lease_expiry, 0, client_class, flags))
+
+    def add_vlan_subscribers_bulk(self, s_tags, c_tags, pool_ids, ips,
+                                  lease_expiries, client_classes=0,
+                                  flags=0) -> None:
+        """`add_subscribers_bulk`'s twin for `vlan_subscriber_pools`: a
+        row a {s_tag, c_tag} pair, the first tier of the device's lookup
+        (pairs unique and not already present)."""
+        keys = ((np.asarray(s_tags, dtype=np.uint32) << np.uint32(16))
+                | np.asarray(c_tags, dtype=np.uint32))
+        self.vlan.bulk_insert(keys[:, None], self._assignments(
+            len(keys), pool_ids, ips, lease_expiries, 0, client_classes,
+            flags))
 
     def remove_vlan_subscriber(self, s_tag: int, c_tag: int) -> bool:
         return self.vlan.delete([(s_tag << 16) | c_tag])
@@ -382,6 +403,92 @@ class PPPoEFastPathTables:
                 meta["geom"][t])
         self.server_mac[:] = arrays["server_mac"]
         return rows
+
+
+class QinQFastPathTables:
+    """Host side of the device `qinq` stage (ops/qinq.py): the table from a
+    subscriber's IPv4 address to its S- and C-tag, and the one writer of it.
+
+    Every write goes through `registry` (control/qinq.py `QinQMapper`, the
+    pkg/qinq registry): it holds which subscriber has which pair, refuses a
+    pair another subscriber holds and a pair outside the configured ranges,
+    and moves a subscriber that comes up on another line. The subscriber's
+    id there is its address. A lease (`DHCPServer`, where it writes
+    `vlan_subscriber_pools`) and a PPPoE session (`session_up`'s caller)
+    land in `bind` / `unbind`; `bulk_bind` is the same write for a
+    provisioning run or a warm restart at the subscriber tables' size.
+    """
+
+    def __init__(self, nbuckets: int = 1 << 12, stash: int = 64,
+                 update_slots: int = 128):
+        from bng_tpu.control.qinq import QinQConfig, QinQMapper
+
+        self.by_ip = HostTable(nbuckets, key_words=1, val_words=QINQ_WORDS,
+                               stash=stash, name="qinq_by_ip")
+        self.geom = TableGeom(nbuckets, stash)
+        self.update_slots = update_slots
+        # the device pushes two tags or none: a single tag registers nowhere
+        self.registry = QinQMapper(QinQConfig(allow_single_tagged=False))
+        self.refused = 0  # binds the registry said no to
+
+    @staticmethod
+    def _rows(s_tags, c_tags) -> np.ndarray:
+        rows = np.zeros((len(s_tags), QINQ_WORDS), dtype=np.uint32)
+        rows[:, QV_S_TAG] = s_tags
+        rows[:, QV_C_TAG] = c_tags
+        return rows
+
+    def bind(self, ip: int, s_tag: int, c_tag: int) -> bool:
+        """The subscriber at `ip` is behind this pair from now on (a pair
+        it held before is free again). False, and nothing written: the
+        registry refused the pair; the subscriber is served untagged and
+        the device counts its downstream frames as misses."""
+        from bng_tpu.control.qinq import VLANPair
+
+        try:
+            self.registry.register(VLANPair(int(s_tag), int(c_tag)), int(ip))
+        except ValueError:
+            self.refused += 1
+            return False
+        self.by_ip.insert([ip], self._rows([s_tag], [c_tag])[0])
+        return True
+
+    def unbind(self, ip: int) -> bool:
+        """Release, expiry or session down. False: no pair was held."""
+        self.registry.unregister_subscriber(int(ip))
+        return self.by_ip.delete([ip])
+
+    def pair_of(self, ip: int) -> tuple[int, int] | None:
+        row = self.by_ip.lookup([ip])
+        return None if row is None else (int(row[QV_S_TAG]), int(row[QV_C_TAG]))
+
+    def bulk_bind(self, ips, s_tags, c_tags) -> None:
+        """A pair each for subscribers that hold none yet, at the
+        1M-subscriber scale. As after any bulk build, the next upload is
+        a whole one (`Engine.resync_tables`)."""
+        ips = np.asarray(ips, dtype=np.uint32)
+        s_tags = np.asarray(s_tags, dtype=np.uint32)
+        c_tags = np.asarray(c_tags, dtype=np.uint32)
+        self.registry.register_bulk(s_tags, c_tags, ips)
+        self.by_ip.bulk_insert(ips[:, None], self._rows(s_tags, c_tags))
+
+    # -- checkpoint/warm-restart (runtime/checkpoint.py) ----------------
+    def checkpoint_state(self) -> tuple[dict, dict]:
+        return ({"geom": {"by_ip": self.by_ip.checkpoint_geom()}},
+                {f"by_ip.{k}": v
+                 for k, v in self.by_ip.checkpoint_arrays().items()})
+
+    def restore_state(self, meta: dict, arrays: dict) -> dict[str, int]:
+        """The table slot for slot, and the registry rebuilt from it."""
+        rows = self.by_ip.restore_arrays(
+            {k: arrays[f"by_ip.{k}"] for k in ("keys", "vals", "used")},
+            meta["geom"]["by_ip"])
+        live = np.nonzero(self.by_ip.used)[0]
+        self.registry.clear()
+        self.registry.register_bulk(self.by_ip.vals[live, QV_S_TAG],
+                                    self.by_ip.vals[live, QV_C_TAG],
+                                    self.by_ip.keys[live, 0])
+        return {"by_ip": rows}
 
 
 def v6_words(addr) -> np.ndarray:

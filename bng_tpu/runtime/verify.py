@@ -43,6 +43,7 @@ class Geometry(NamedTuple):
     stash: int = 64
     pppoe_nbuckets: int = 0  # PPPoE session tables; 0 = no PPPoE stage
     v6_nbuckets: int = 0  # IPv6 by-address table; 0 = no v6 stage
+    qinq_nbuckets: int = 0  # address -> S/C-tag table; 0 = no qinq stage
 
 
 TOY = Geometry()
@@ -60,6 +61,9 @@ REAL_1M_PPPOE = REAL_1M._replace(pppoe_nbuckets=1 << 15)
 # the same with the IPv6 stage compiled in, its by-address table sized for
 # 1,000,000 IA_NA bindings (`bng run --ipv6-fastpath`)
 REAL_1M_V6 = REAL_1M._replace(v6_nbuckets=1 << 19)
+# PPPoE and the access VLANs together, the pair table sized for 1,000,000
+# subscribers (`bng run --pppoe-enabled --qinq-enabled`)
+REAL_1M_QINQ = REAL_1M_PPPOE._replace(qinq_nbuckets=1 << 19)
 
 
 def compile_for(built, sharding=None):
@@ -159,7 +163,8 @@ def _engine(g: Geometry):
     from bng_tpu.control.nat import NATManager
     from bng_tpu.runtime.engine import (AntispoofTables, Engine, GardenTables,
                                         QoSTables)
-    from bng_tpu.runtime.tables import PPPoEFastPathTables, V6FastPathTables
+    from bng_tpu.runtime.tables import (PPPoEFastPathTables,
+                                        QinQFastPathTables, V6FastPathTables)
     from bng_tpu.utils.net import ip_to_u32
 
     spoof = AntispoofTables(nbuckets=g.side_nbuckets, stash=g.stash)
@@ -175,6 +180,8 @@ def _engine(g: Geometry):
                if g.pppoe_nbuckets else None),
         v6=(V6FastPathTables(spoof, nbuckets=g.v6_nbuckets, stash=g.stash)
             if g.v6_nbuckets else None),
+        qinq=(QinQFastPathTables(nbuckets=g.qinq_nbuckets, stash=g.stash)
+              if g.qinq_nbuckets else None),
         batch_size=g.batch, pkt_slot=g.pkt_slot)
 
 

@@ -225,6 +225,10 @@ class Tracer:
         # to the host for a destination it does not hold, and passed as
         # control (engine.py _fold_stats); 0 in a program without the stage
         self.v6_fwd = self.v6_miss = self.v6_ctrl = 0
+        # lanes the device qinq stage pushed a pair onto, popped the tags
+        # off, and forwarded downstream to a subscriber without a pair
+        # (engine.py _fold_stats); 0 in a program without the stage
+        self.qinq_push = self.qinq_pop = self.qinq_miss = 0
         # crossings between host and chip on the hot path (xfer): calls
         # and bytes by direction, [upload, fetch]
         self.xfer_calls = [0, 0]
@@ -589,6 +593,9 @@ class Tracer:
             "v6_fwd": int(self.v6_fwd),
             "v6_miss": int(self.v6_miss),
             "v6_ctrl": int(self.v6_ctrl),
+            "qinq_push": int(self.qinq_push),
+            "qinq_pop": int(self.qinq_pop),
+            "qinq_miss": int(self.qinq_miss),
             "xfer": {"upload_calls": int(self.xfer_calls[0]),
                      "upload_bytes": int(self.xfer_bytes[0]),
                      "fetch_calls": int(self.xfer_calls[1]),
@@ -831,6 +838,17 @@ def v6_lanes(fwd: int, miss: int, ctrl: int) -> None:
     _ACTIVE.v6_fwd += fwd
     _ACTIVE.v6_miss += miss
     _ACTIVE.v6_ctrl += ctrl
+
+
+def qinq_lanes(push: int, pop: int, miss: int) -> None:
+    """Count one retired step's qinq lanes: pairs pushed, tags popped,
+    downstream lanes of a subscriber without a pair. Disarmed: global load
+    + None compare."""
+    if _ACTIVE is None:
+        return
+    _ACTIVE.qinq_push += push
+    _ACTIVE.qinq_pop += pop
+    _ACTIVE.qinq_miss += miss
 
 
 def trigger(reason: str, detail: str = "") -> str | None:

@@ -20,7 +20,7 @@ import os
 # the `jax.named_scope` names of ops/pipeline.py, ops/express.py, the
 # update scatter (runtime/engine.py) and the sharded step's psums
 SCOPES = ("parse", "antispoof", "dhcp", "garden", "nat44", "qos", "edge",
-          "pppoe", "v6", "rewrite", "updates", "stats")
+          "pppoe", "v6", "qinq", "rewrite", "updates", "stats")
 BEAT = "bng.beat"  # the Tracer's anchor annotation (telemetry/spans.py)
 # stages that are laps of the host thread (the rest are fed durations:
 # lane_wait, device, sojourn; or span batches across beats: total).

@@ -20,7 +20,8 @@ import jax
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from bng_tpu.runtime import verify
-from bng_tpu.runtime.verify import (REAL_1M, REAL_1M_PPPOE, REAL_1M_V6,
+from bng_tpu.runtime.verify import (REAL_1M, REAL_1M_PPPOE, REAL_1M_QINQ,
+                                    REAL_1M_V6,
                                     compile_for)
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -120,6 +121,25 @@ def test_fused_step_with_the_v6_stage_fits_and_has_no_while(one_chip):
 
     assert REAL_1M_V6.v6_nbuckets == nbuckets_for(1_000_000)  # as cli.py sizes it
     compiled = compile_for(verify.build_pipeline(REAL_1M_V6), one_chip)
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert _whiles(compiled) == []
+    assert _table_relayouts(compiled, f"{(1 << 19) // 4},128") == []
+
+
+@pytest.mark.parametrize("lanes", [1024, None], ids=["rung-1024", "full"])
+def test_fused_step_with_the_qinq_stage_fits_and_has_no_while(one_chip, lanes):
+    """`bng run --pppoe-enabled --qinq-enabled` at the 1M geometry, the pair
+    table sized for 1,000,000 subscribers: the step that pops the tags off
+    the whole slot and pushes a pair onto it after the PPPoE encap, as
+    selects over static shifts, with one more table probe. It fits, and the
+    byte moves bring no loop. (What the pair table's probe costs in whole-
+    table copies is the chip's to say: PERF.md section 5.)"""
+    from bng_tpu.ops.table import nbuckets_for
+
+    assert REAL_1M_QINQ.qinq_nbuckets == nbuckets_for(1_000_000)  # as cli.py
+    assert REAL_1M_QINQ.pppoe_nbuckets == REAL_1M_PPPOE.pppoe_nbuckets
+    compiled = compile_for(verify.build_pipeline(REAL_1M_QINQ, lanes=lanes),
+                           one_chip)
     assert _device_bytes(compiled) < V5E_HBM_BYTES
     assert _whiles(compiled) == []
     assert _table_relayouts(compiled, f"{(1 << 19) // 4},128") == []
