@@ -472,15 +472,26 @@ class HostQTable:
         return len(self._dirty) - before
 
     def make_update(self, max_slots: int) -> QTableUpdate:
+        """Drain the dirty ways of up to max_slots stored rows into a
+        QTableUpdate on the device (`host_update`, uploaded); a clean
+        table's is `empty_update`'s, already placed."""
+        if not self.dirty_count():  # the batch that is already placed
+            return self.empty_update(max_slots)
+        host = self.host_update(max_slots)
+        t0 = tele.t()  # a drain that ships something shows as calls
+        upd = QTableUpdate(*(jnp.asarray(a) for a in host))
+        tele.xfer(tele.UPLOAD, t0, sum(a.nbytes for a in host), len(host))
+        return upd
+
+    def host_update(self, max_slots: int) -> QTableUpdate:
         """Drain the dirty ways of up to max_slots stored rows (bounded
-        host->HBM traffic): each row ships once, whole, with a bit per
-        dirty way."""
+        host->HBM traffic) into host arrays, all padding where nothing is
+        dirty: each row ships once, whole, with a bit per dirty way (see
+        HostTable.host_update for who takes it as it is)."""
         if self._dirty_all:
             raise RuntimeError(
                 f"qos table {self.name!r}: bulk_insert invalidated delta sync; "
                 "call device_state() for a full upload first")
-        if not self._dirty:  # nothing to ship: the batch that is already placed
-            return self.empty_update(max_slots)
         ss = np.asarray(sorted(self._dirty), dtype=np.int64)
         rr, first = np.unique(ss // ROW_SLOTS, return_index=True)
         n = min(len(rr), max_slots)
@@ -494,11 +505,7 @@ class HostQTable:
         np.bitwise_or.at(ways, np.searchsorted(rr, ss // ROW_SLOTS),
                          np.uint32(1) << (ss % ROW_SLOTS).astype(np.uint32))
         rows[:n] = to_stored(self.rows)[rr[:n]]
-        t0 = tele.t()  # a drain that ships something shows as calls
-        upd = QTableUpdate(row=jnp.asarray(row), ways=jnp.asarray(ways),
-                           rows=jnp.asarray(rows))
-        tele.xfer(tele.UPLOAD, t0, row.nbytes + ways.nbytes + rows.nbytes, 3)
-        return upd
+        return QTableUpdate(row=row, ways=ways, rows=rows)
 
     def empty_update(self, max_slots: int) -> QTableUpdate:
         """All-padding QTableUpdate (no-op scatter), built without touching

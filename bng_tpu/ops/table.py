@@ -552,21 +552,34 @@ class HostTable:
         return len(self._dirty) - before
 
     def make_update(self, max_slots: int) -> TableUpdate:
-        """Drain up to max_slots dirty slots into a fixed-size TableUpdate.
+        """Drain up to max_slots dirty slots into a fixed-size TableUpdate
+        on the device (`host_update`, uploaded). With nothing dirty the
+        batch is `empty_update`'s: the one that is already on the device,
+        so a clean table uploads nothing."""
+        if not self.dirty_count():  # the batch that is already placed
+            return self.empty_update(max_slots)
+        host = self.host_update(max_slots)
+        t0 = tele.t()  # a drain that ships something shows as calls
+        upd = TableUpdate(*(jnp.asarray(a) for a in host))
+        tele.xfer(tele.UPLOAD, t0, sum(a.nbytes for a in host), len(host))
+        return upd
+
+    def host_update(self, max_slots: int) -> TableUpdate:
+        """Drain up to max_slots dirty slots into a fixed-size TableUpdate
+        of host arrays (all padding where nothing is dirty): `make_update`
+        uploads it; a caller that places batches itself takes it as it is
+        (the sharded drain stacks one a shard over the mesh).
 
         Remaining dirty slots stay queued for the next batch (bounded
         host->HBM traffic per step, like bounded map-update syscalls).
         A drained bucket slot carries its whole (current) bucket row with
         still-dirty siblings masked used=0 (their vals have not shipped —
         see _pack_bucket_rows); each sibling rewrites the row on its own
-        drain. With nothing dirty the batch is `empty_update`'s: the one
-        that is already on the device, so a clean table uploads nothing."""
+        drain."""
         if self._dirty_all:
             raise RuntimeError(
                 f"table {self.name!r}: bulk_insert invalidated delta sync; "
                 "call device_state() for a full upload first")
-        if not self._dirty:  # nothing to ship: the batch that is already placed
-            return self.empty_update(max_slots)
         take = sorted(self._dirty)[:max_slots]
         self._dirty.difference_update(take)
         base = self.nbuckets * WAYS
@@ -593,15 +606,8 @@ class HostTable:
             ts = np.asarray(take, dtype=np.int32)
             idx[:n] = ts
             vv[:n] = self.vals[ts]
-        t0 = tele.t()  # a drain that ships something shows as calls
-        upd = TableUpdate(
-            bidx=jnp.asarray(bidx), brows=jnp.asarray(brows),
-            sidx=jnp.asarray(sidx), srows=jnp.asarray(srows),
-            idx=jnp.asarray(idx), vals=jnp.asarray(vv),
-        )
-        tele.xfer(tele.UPLOAD, t0, bidx.nbytes + brows.nbytes + sidx.nbytes
-                  + srows.nbytes + idx.nbytes + vv.nbytes, 6)
-        return upd
+        return TableUpdate(bidx=bidx, brows=brows, sidx=sidx, srows=srows,
+                           idx=idx, vals=vv)
 
     def empty_update(self, max_slots: int) -> TableUpdate:
         """An all-padding TableUpdate (applying it is a no-op scatter).

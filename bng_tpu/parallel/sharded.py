@@ -44,7 +44,7 @@ from bng_tpu.ops.pipeline import PipelineGeom, PipelineTables, pipeline_step
 from bng_tpu.ops.table import TableGeom, shard_owner
 from bng_tpu.runtime.engine import (AntispoofTables, GardenTables, QoSTables,
                                     _apply_all_updates)
-from bng_tpu.runtime.tables import (FastPathTables,
+from bng_tpu.runtime.tables import (FastPathTables, FastPathUpdates,
                                     PPPoEFastPathTables)
 from bng_tpu.telemetry import spans as tele
 from bng_tpu.utils.net import mac_to_u64, split_u64
@@ -393,6 +393,11 @@ class ShardedCluster:
         self._step = _sharded_step_jit(self.mesh, self.geom, self.n)
         self._dhcp_step = _sharded_dhcp_jit(self.mesh, self.geom, self.n)
         self.tables = None  # lazily built on first step / sync()
+        # the drain's own: a placed all-padding batch a table kind, and
+        # the dense arrays as last placed with their bytes (update leaves
+        # over the mesh only; never a shard's tables)
+        self._noop_upd: dict = {}
+        self._dense_placed: dict = {}
         # ping-pong ring staging: the in-flight batch owns one buffer set
         # while the next assembles into the other (Engine._staging role)
         self._ring_bufs = [None, None]
@@ -674,38 +679,59 @@ class ShardedCluster:
         return self.fastpath[self.dhcp_sub_shard(mac)].get_subscriber(mac)
 
     # ---- device sync ----
-    def _stack(self, arrs, spec):
-        """One leaf of every shard, stacked on the host and placed over
-        the mesh: a read back a shard (`fetch`, those that live on a
-        device) and one placement (`upload`)."""
+    def _place(self, stacked: np.ndarray):
+        """A host array with a leading mesh dimension, placed over the
+        mesh: one `upload`."""
         t0 = tele.t()
-        stacked = np.stack([np.asarray(a) for a in arrs])
-        tele.fetched(t0, *arrs)
-        t0 = tele.t()
-        out = jax.device_put(stacked, NamedSharding(self.mesh, spec))
+        out = jax.device_put(stacked, NamedSharding(self.mesh, P(AXIS)))
         tele.xfer(tele.UPLOAD, t0, stacked.nbytes)
         return out
 
-    @staticmethod
-    def _to_chip(host: np.ndarray):
-        """A shard's dense config array to the default device, as every
-        drain ships it (and `_stack` reads it back): an `upload`."""
+    def _stack(self, arrs):
+        """One leaf of every shard, stacked on the host and placed over
+        the mesh: a read back a shard (`fetch`, those that live on a
+        device) and one placement."""
         t0 = tele.t()
-        out = jnp.asarray(host)
-        tele.xfer(tele.UPLOAD, t0, host.nbytes)
-        return out
+        stacked = np.stack([np.asarray(a) for a in arrs])
+        tele.fetched(t0, *arrs)
+        return self._place(stacked)
 
     def _stack_per_shard(self, per_shard):
-        """Stack a per-shard pytree list on the mesh axis (the one
-        stacking/sharding convention — used by drains and sync)."""
-        return jax.tree.map(lambda *xs: self._stack(xs, P(AXIS)), *per_shard)
+        """Stack a per-shard pytree list on the mesh axis (sync_tables':
+        whole tables staged on chip 0; nothing here is kept)."""
+        return jax.tree.map(lambda *xs: self._stack(xs), *per_shard)
 
-    def _drain_with_resync(self, drain):
+    def _host_tables(self, fastpath_only: bool = False) -> list:
+        """Every shard's host tables that a drain ships deltas of."""
+        out = []
+        for i in range(self.n):
+            fp = self.fastpath[i]
+            out += [fp.sub, fp.vlan, fp.cid]
+            if fastpath_only:
+                continue
+            nat = self.nat[i]
+            out += [nat.sessions, nat.reverse, nat.sub_nat, self.qos[i].up,
+                    self.qos[i].down, self.spoof[i].bindings]
+            if self.garden is not None:
+                out.append(self.garden[i].subscribers)
+            if self.pppoe is not None:
+                out += [self.pppoe[i].by_sid, self.pppoe[i].by_ip]
+            if self.edge is not None:
+                out += [self.edge[i].tap, self.edge[i].route]
+        return out
+
+    def _drain_with_resync(self, drain, fastpath_only: bool = False):
         """Run a make-updates drain; on the bulk-build "full upload"
         signal answer with one full re-upload and drain again — the
         Engine._drain_with_resync contract, so a bulk build on a live
-        cluster does not brick the step loop. (The re-upload resets
-        device-authoritative counters/tokens, as documented there.)"""
+        cluster does not brick the step loop, and its count for the
+        tracer: of the shards' tables, those with something to ship and
+        the clean ones. (The re-upload resets device-authoritative
+        counters/tokens, as documented there.)"""
+        if tele.t() is not None:
+            tabs = self._host_tables(fastpath_only)
+            built = sum(1 for t in tabs if t.dirty_count())
+            tele.drain_tables(built, len(tabs) - built)
         try:
             return drain()
         except RuntimeError as e:
@@ -714,42 +740,87 @@ class ShardedCluster:
             self.sync_tables()
             return drain()
 
+    def _table_upd(self, owners: list, name: str):
+        """The update batch of the shards' table `name` (one kind: an
+        attribute of each of `owners`), stacked over the mesh. Clean on
+        every shard (the steady state): the all-padding batch placed when
+        the kind was first drained clean, the same arrays every step, and
+        no crossing. Dirty on any: each shard's batch built on the host
+        (padding for the clean ones), stacked and placed, one placement
+        a leaf. The placed no-op is geometry's, not contents': it
+        outlives a resync. Like `empty_update`'s cache it leans on no
+        program donating its updates."""
+        tables = [getattr(o, name) for o in owners]
+        kind = (type(owners[0]).__name__, name)
+        clean = not any(t.dirty_count() for t in tables)
+        if clean and kind in self._noop_upd:
+            return self._noop_upd[kind]
+        slots = owners[0].update_slots
+        upd = jax.tree.map(lambda *xs: self._place(np.stack(xs)),
+                           *[t.host_update(slots) for t in tables])
+        if clean:  # all padding
+            self._noop_upd[kind] = upd
+        return upd
+
+    def _dense_upd(self, owners: list, name: str):
+        """The shards' small dense array `name` (pools, server, hairpin,
+        ranges, allowlist: applied wholesale by every step; an attribute
+        of each of `owners`, or a method that builds it), stacked over
+        the mesh: placed again only when the bytes differ from what was
+        last placed, as `ops/table.py placed` does on one chip, so a
+        write in place before a drain is in that drain's batch."""
+        hosts = [getattr(o, name) for o in owners]
+        stacked = np.stack([h() if callable(h) else h for h in hosts])
+        kind = (type(owners[0]).__name__, name)
+        now = stacked.tobytes()
+        hit = self._dense_placed.get(kind)
+        if hit is None or hit[0] != now:
+            hit = self._dense_placed[kind] = (now, self._place(stacked))
+        return hit[1]
+
+    def _fastpath_upd(self) -> FastPathUpdates:
+        tab, dense, fp = self._table_upd, self._dense_upd, self.fastpath
+        return FastPathUpdates(
+            sub=tab(fp, "sub"), vlan=tab(fp, "vlan"), cid=tab(fp, "cid"),
+            pools=dense(fp, "pools"), server=dense(fp, "server"))
+
+    def _updates(self) -> tuple:
+        """The stacked update batch of a fused step, in
+        _apply_all_updates' order (the engine's `_updates`, a kind a
+        shard-stack: ROADMAP D1)."""
+        tab, dense = self._table_upd, self._dense_upd
+        nat, qos, sp, g, p, e = (self.nat, self.qos, self.spoof, self.garden,
+                                 self.pppoe, self.edge)
+        return (
+            self._fastpath_upd(),
+            (tab(nat, "sessions"), tab(nat, "reverse"), tab(nat, "sub_nat"),
+             dense(nat, "hairpin"), dense(nat, "alg"),
+             dense(nat, "config_array")),
+            tab(qos, "up"), tab(qos, "down"),
+            tab(sp, "bindings"), dense(sp, "ranges"), dense(sp, "config"),
+            *((tab(g, "subscribers"), dense(g, "allowed"))
+              if g is not None else ()),
+            *((tab(p, "by_sid"), tab(p, "by_ip")) if p is not None else ()),
+            *((tab(e, "tap"), dense(e, "tap_filters"),
+               dense(e, "tap_config"), tab(e, "route"))
+              if e is not None else ()),
+        )
+
     def _drain_updates(self):
-        """Per-shard bounded update batches, stacked on the mesh axis.
+        """The shards' bounded update batches, stacked on the mesh axis.
 
         Same mechanism as Engine._drain_updates: host writes since the
         last step ride into the donated jitted step as fixed-size deltas,
         so device-authoritative state (NAT session counters, QoS tokens)
-        is never clobbered by a full re-upload.
+        is never clobbered by a full re-upload. What crosses follows what
+        changed (`_table_upd`, `_dense_upd`): a clean mesh drains to the
+        batch that is already on its chips.
         """
-        return self._drain_with_resync(lambda: self._stack_per_shard([
-            (
-                self.fastpath[i].make_updates(),
-                self.nat[i].make_updates(),
-                self.qos[i].up.make_update(self.qos[i].update_slots),
-                self.qos[i].down.make_update(self.qos[i].update_slots),
-                self.antispoof_upd(i),
-                self._to_chip(self.spoof[i].ranges),
-                self._to_chip(self.spoof[i].config),
-                *((self.garden[i].subscribers.make_update(
-                       self.garden[i].update_slots),
-                   self._to_chip(self.garden[i].allowed))
-                  if self.garden is not None else ()),
-                *(self.pppoe[i].make_updates()
-                  if self.pppoe is not None else ()),
-                *(self.edge[i].make_updates()
-                  if self.edge is not None else ()),
-            )
-            for i in range(self.n)
-        ]))
+        return self._drain_with_resync(self._updates)
 
     def _drain_fastpath(self):
         """Fastpath-only drain (the DHCP fast lane's update path)."""
-        return self._drain_with_resync(lambda: self._stack_per_shard(
-            [self.fastpath[i].make_updates() for i in range(self.n)]))
-
-    def antispoof_upd(self, i: int):
-        return self.spoof[i].bindings.make_update(self.spoof[i].update_slots)
+        return self._drain_with_resync(self._fastpath_upd, fastpath_only=True)
 
     def sync_tables(self) -> None:
         """Full upload of every shard's tables, stacked on the mesh axis.
@@ -1341,23 +1412,7 @@ class ShardedCluster:
         """Dirty slots across every shard's drained host mirror — 0
         means the mesh device chain is current (Engine.pending_dirty
         parity; the auditor's drain-completion test)."""
-        total = 0
-        for i in range(self.n):
-            total += self.fastpath[i].dirty_count()
-            total += sum(t.dirty_count() for t in (
-                self.nat[i].sessions, self.nat[i].reverse,
-                self.nat[i].sub_nat))
-            total += self.qos[i].up.dirty_count()
-            total += self.qos[i].down.dirty_count()
-            total += self.spoof[i].bindings.dirty_count()
-            if self.garden is not None:
-                total += self.garden[i].subscribers.dirty_count()
-            if self.pppoe is not None:
-                total += self.pppoe[i].by_sid.dirty_count()
-                total += self.pppoe[i].by_ip.dirty_count()
-            if self.edge is not None:
-                total += self.edge[i].dirty_count()
-        return total
+        return sum(t.dirty_count() for t in self._host_tables())
 
     def shard_components(self, i: int) -> dict:
         """One shard's host authorities, keyed the way the checkpoint

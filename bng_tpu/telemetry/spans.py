@@ -213,9 +213,9 @@ class Tracer:
         # ladder each window took (engine.py step_rung), summed where the
         # rung is chosen on the engine's loop and the scheduler's bulk lane
         self.step_lanes = 0
-        # table batches the engine's update drains built and uploaded, and
-        # those a clean table answered with the batch already on the chip
-        # (engine.py _drain_with_resync)
+        # table batches the update drains built and uploaded, and those a
+        # clean table answered with the batch already on the chip
+        # (_drain_with_resync, the engine's and the sharded cluster's)
         self.drain_built = self.drain_cached = 0
         # lanes the device PPPoE stage decapsulated, encapsulated, and
         # punted for a session it does not hold (engine.py _fold_stats);

@@ -260,10 +260,12 @@ class TestShardedLoopOnTheTracer:
 
 def test_an_armed_sharded_window_counts_the_crossings_the_code_makes():
     """`upload` / `fetch` (PR 37) on the sharded loop: one fused window
-    over two shards. The drain reads each leaf of the update batch back
-    from every shard and places the stack over the mesh (S-d's mechanism as
-    a count); the literals are the crossings the code makes: a PR that
-    merges reads, or stops reading clean leaves back, lowers them here."""
+    over two shards whose tables are clean. The drain hands the step the
+    update batch that is already placed over the mesh (PR 41: it asks
+    each table for its dirty count and compares the dense arrays' bytes),
+    so the literals are `pack`'s three placements and the retire's ten
+    reads and nothing of the drain's; a PR that merges the retire's reads
+    lowers them here."""
     from bng_tpu.control import packets
     from bng_tpu.telemetry import spans as tele
 
@@ -289,7 +291,7 @@ def test_an_armed_sharded_window_counts_the_crossings_the_code_makes():
     cl.flush_pipeline()
     assert cl.pending_dirty() == 0
     assert cl.garden is not None and cl.pppoe is None and cl.edge is None
-    leaves = 62  # of one shard's update batch (asserted below)
+    before = jax.tree.leaves(cl._drain_updates())
     with tele.armed(keep_events=1 << 12) as tr:
         tele.beat_begin()
         beat(3)
@@ -297,36 +299,33 @@ def test_an_armed_sharded_window_counts_the_crossings_the_code_makes():
         tele.beat_end()
         snap = cl.telemetry.snapshot()["trace"]
     batch = jax.tree.leaves(cl._drain_updates())  # clean: ships nothing
-    assert (cl.n, len(batch)) == (2, leaves)
+    assert (cl.n, len(batch)) == (2, 62)
+    assert all(a is b for a, b in zip(before, batch))
     assert snap["batches"] == 1
+    # ten tables a shard, every one clean: the placed batch served
+    assert (snap["drain_built"], snap["drain_cached"]) == (0, 10 * cl.n)
     x = snap["xfer"]
-    # `pack`: packet slots, lengths, access flags; the drain: one placement
-    # a leaf, and a shard's three dense arrays (spoof ranges, spoof config,
-    # garden allowlist) to chip 0 before they are read back
-    assert x["upload_calls"] == 3 + leaves + 3 * cl.n == 71
-    # the drain reads every leaf back from every shard; the retire reads
-    # ten outputs (verdict, punt, violation, five stats blocks with the
-    # garden's, out_pkt, out_len)
-    assert x["fetch_calls"] == leaves * cl.n + 10 == 134
+    # `pack`: packet slots, lengths, access flags. The drain: none
+    assert x["upload_calls"] == 3
+    # the retire reads ten outputs (verdict, punt, violation, five stats
+    # blocks with the garden's, out_pkt, out_len). The drain: none
+    assert x["fetch_calls"] == 10
     B = cl.n * cl.b
-    stacked = sum(int(a.nbytes) for a in batch)
     retire = B * (4 + 1 + 1 + 2048 + 4)  # verdict, punt, viol, out_pkt, len
     stats = 4 * sum(len(cl.stats[k])  # five psum'd blocks, u32 on the mesh
                     for k in ("dhcp", "nat", "qos", "spoof", "garden"))
-    assert x["fetch_bytes"] == stacked + retire + stats
-    assert x["upload_bytes"] == stacked + B * (2048 + 4 + 1) + sum(
-        a.nbytes for i in range(cl.n) for a in (
-            cl.spoof[i].ranges, cl.spoof[i].config, cl.garden[i].allowed))
+    assert x["fetch_bytes"] == retire + stats
+    assert x["upload_bytes"] == B * (2048 + 4 + 1)
     by_stage = {}
     for stage, _lane, _t0, _dur in tr.events:
         by_stage[stage] = by_stage.get(stage, 0) + 1
-    # one `fetch` and one `upload` lap a leaf inside `drain`, the three
-    # placements of `pack` under one lap, the dense arrays one each
-    assert by_stage[tele.FETCH] == leaves + 1
-    assert by_stage[tele.UPLOAD] == leaves + 1 + 3 * cl.n
+    # the retire's reads under one `fetch` lap, `pack`'s three placements
+    # under one `upload` lap, one `drain` lap with no child
+    assert by_stage[tele.FETCH] == 1
+    assert by_stage[tele.UPLOAD] == 1
+    assert by_stage[tele.DRAIN] == 1
     assert snap["stage_ns"]["fetch"] + snap["stage_ns"]["upload"] <= \
-        snap["stage_ns"]["drain"] + snap["stage_ns"]["device_wait"] \
-        + snap["stage_ns"]["pack"]
+        snap["stage_ns"]["device_wait"] + snap["stage_ns"]["pack"]
     assert sum(snap["starved_ns"].values()) == \
         snap["beat_starved_ns"] + snap["starved_ns"]["outside"]
 
