@@ -106,7 +106,7 @@ BATCH_SCOPE: dict[str, set[str]] = {
                                      "_admit_scalar_fallback"},
     "bng_tpu/control/fleet.py": {"handle_batch", "_admit_vec"},
     "bng_tpu/runtime/hostpath.py": {
-        "pack_into", "classify_dhcp_batch", "shard_of_batch",
+        "pack_into", "classify_dhcp_batch", "shard_of_batch", "steer_batch",
         "peek_dhcp_batch", "bootp_off_batch", "fnv1a32_cols", "stage",
     },
 }

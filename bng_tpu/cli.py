@@ -24,6 +24,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import math
 import sys
 import time
 
@@ -269,6 +270,26 @@ def _sized(entries: int, *bucket_args: str) -> dict:
     return dict.fromkeys(bucket_args, nbuckets_for(entries))
 
 
+# What a shard's table takes over an even share of a cluster total: keys
+# split by FNV-1a32 of the address, a binomial whose fullest of four
+# shards sits 0.5% over its share at 250,000 a shard (six sigma) and
+# exactly on it for a pool of consecutive addresses (measured, PERF.md
+# section 4, PR 42); a thirty-second covers both with room, and moves
+# `nbuckets_for`'s power of two only within 3% under a boundary.
+SHARD_HEADROOM = 1 / 32
+
+
+def _shard_sized(entries: int, shards: int, unset: int) -> int:
+    """A shard's bucket count for a cluster-wide capacity of `entries`
+    (`nbuckets_for` of its share with the hash's headroom), or `unset`
+    when the config left the capacity unset."""
+    if not entries:
+        return unset
+    from bng_tpu.ops.table import nbuckets_for
+
+    return nbuckets_for(math.ceil(entries / shards * (1 + SHARD_HEADROOM)))
+
+
 def pppoe_sid(sess) -> str:
     """One Acct-Session-Id format for a PPPoE session — shared by
     accounting start/stop, the CoA locator, and HA replication keys
@@ -464,7 +485,12 @@ class BNGApp:
                 sub_nbuckets=cfg.shard_nbuckets,
                 vlan_nbuckets=max(64, cfg.shard_nbuckets // 4),
                 cid_nbuckets=max(64, cfg.shard_nbuckets // 4),
-                nat_sessions_nbuckets=cfg.shard_nbuckets,
+                # --max-nat-sessions / --max-nat-subscribers are cluster
+                # totals, as on one chip; unset, today's sizes stand
+                nat_sessions_nbuckets=_shard_sized(
+                    cfg.max_nat_sessions, cfg.shards, cfg.shard_nbuckets),
+                nat_sub_nbuckets=_shard_sized(
+                    cfg.max_nat_subscribers, cfg.shards, 256),
                 qos_nbuckets=cfg.shard_nbuckets,
                 spoof_nbuckets=cfg.shard_nbuckets,
                 public_ips=pub_ips,
@@ -477,7 +503,11 @@ class BNGApp:
                 lambda: c["cluster"])
             self.log.info("sharded cluster built", shards=cfg.shards,
                           batch_per_shard=cluster.b,
-                          nbuckets=cfg.shard_nbuckets)
+                          nbuckets=cfg.shard_nbuckets,
+                          nat_sessions_nbuckets=cluster.geom.nat.sessions.nbuckets,
+                          nat_sub_nbuckets=cluster.geom.nat.sub_nat.nbuckets,
+                          public_ips_per_shard=[len(m.public_ips)
+                                                for m in cluster.nat])
         else:
             fastpath = c["fastpath"] = FastPathTables(**_sized(
                 cfg.max_subscribers,
@@ -2275,6 +2305,17 @@ class BNGApp:
         cluster = self.components.get("cluster")
         if cluster is not None:
             out["sharded"] = cluster.stats_summary()
+            # each shard's pool, before `allocate_nat` returns None: what
+            # its host mirror holds and what its addresses have left
+            out["sharded"]["per_shard_nat"] = [
+                {k: p[k] for k in ("nat_sessions", "nat_blocks", "nat_pool")}
+                for p in cluster.telemetry.snapshot()["per_shard"]]
+            ring = self.components.get("ring")
+            if ring is not None:
+                rs = ring.stats()
+                out["sharded"]["steering"] = {
+                    k: int(rs.get(k, 0))
+                    for k in ("steer_pub_hit", "steer_pub_miss")}
             if self.sharded_blockers:
                 out["sharded_blockers"] = list(self.sharded_blockers)
         dhcp = self.components.get("dhcp")
@@ -3355,6 +3396,23 @@ _RUN_FLAG_HELP = {
                   "narrowest rung of the ladder down from it (by 8, floor "
                   "128, at most three), and start-up builds one program a "
                   "rung before the first window",
+    "nat_public_ips": "the CGNAT pool, one address an argument (default "
+                      "{default}); under --shards every address is used "
+                      "and each is owned by one shard: the list is dealt "
+                      "in contiguous runs in the order given, so list "
+                      "consecutive addresses (fewer addresses than shards: "
+                      "the block is extended consecutively)",
+    "max_nat_sessions": "NAT session capacity in entries, sizing the "
+                        "session and reverse tables (default {default}: "
+                        "the table's own size); under --shards the "
+                        "cluster's total, each shard sized for its share "
+                        "with 1/32 of headroom for the hash (unset: "
+                        "--shard-nbuckets)",
+    "max_nat_subscribers": "subscribers behind NAT, sizing the port-block "
+                           "table (default {default}: the table's own "
+                           "size); under --shards the cluster's total, "
+                           "split as --max-nat-sessions (unset: 256 "
+                           "buckets a shard)",
 }
 
 

@@ -368,18 +368,31 @@ class NATManager:
             return u_ip, u_port, u_ok
 
         if self.flags & FLAG_EIM:
-            # one external mapping per unique internal endpoint
-            ep = np.stack([src_ips, src_ports, protos], axis=1)
-            uq_ep, ep_inv = np.unique(ep, axis=0, return_inverse=True)
+            # one external mapping per unique internal endpoint, the
+            # endpoints in (address, port, protocol) order; where port and
+            # protocol fit their wire widths the three sort as one word
+            if not ((src_ports >> 16) | (protos >> 8)).any():
+                word = ((src_ips.astype(np.uint64) << np.uint64(24))
+                        | (src_ports.astype(np.uint64) << np.uint64(8))
+                        | protos.astype(np.uint64))
+                uq, ep_inv = np.unique(word, return_inverse=True)
+                uq_ep = np.stack([uq >> np.uint64(24),
+                                  (uq >> np.uint64(8)) & np.uint64(0xFFFF),
+                                  uq & np.uint64(0xFF)], axis=1).astype(np.uint32)
+            else:
+                ep = np.stack([src_ips, src_ports, protos], axis=1)
+                uq_ep, ep_inv = np.unique(ep, axis=0, return_inverse=True)
             n_ep = len(uq_ep)
+            keys = list(zip(*(uq_ep[:, c].tolist() for c in range(3))))
             ep_ip = np.zeros((n_ep,), dtype=np.uint32)
             ep_port = np.zeros((n_ep,), dtype=np.uint32)
             reused = np.zeros((n_ep,), dtype=bool)
-            for j in range(n_ep):
-                m = self.eim.get((int(uq_ep[j, 0]), int(uq_ep[j, 1]), int(uq_ep[j, 2])))
-                if m is not None:
-                    reused[j] = True
-                    ep_ip[j], ep_port[j] = m[0], m[1]
+            if self.eim:  # a fresh manager holds no mapping to reuse
+                for j, k in enumerate(keys):
+                    m = self.eim.get(k)
+                    if m is not None:
+                        reused[j] = True
+                        ep_ip[j], ep_port[j] = m[0], m[1]
             ep_ok = reused.copy()
             new_j = np.nonzero(~reused)[0]
             if len(new_j):
@@ -388,17 +401,20 @@ class NATManager:
             nat_ip = ep_ip[ep_inv]
             nat_port = ep_port[ep_inv]
             ok = ep_ok[ep_inv]
-            # refcount bookkeeping per endpoint
+            # refcount bookkeeping per endpoint: the reused ones one by
+            # one, the new ones as two bulk dict updates
             ep_counts = np.bincount(ep_inv, minlength=n_ep)
-            for j in range(n_ep):
-                if not ep_ok[j]:
-                    continue
-                k = (int(uq_ep[j, 0]), int(uq_ep[j, 1]), int(uq_ep[j, 2]))
-                if reused[j]:
-                    self.eim[k][2] += int(ep_counts[j])
-                else:
-                    self.eim[k] = [int(ep_ip[j]), int(ep_port[j]), int(ep_counts[j])]
-                    self._ext_ports[(int(ep_ip[j]), int(ep_port[j]), k[2])] = k
+            for j in np.nonzero(reused)[0]:
+                self.eim[keys[j]][2] += int(ep_counts[j])
+            made = np.nonzero(ep_ok & ~reused)[0]
+            made_keys = [keys[j] for j in made.tolist()]
+            ext = list(zip(ep_ip[made].tolist(), ep_port[made].tolist()))
+            self.eim.update(zip(made_keys, (
+                [ip, port, n] for (ip, port), n
+                in zip(ext, ep_counts[made].tolist()))))
+            self._ext_ports.update(zip(
+                ((ip, port, k[2]) for (ip, port), k in zip(ext, made_keys)),
+                made_keys))
         else:
             nat_ip, nat_port, ok = _assign_sequential(src_ips)
 
@@ -431,6 +447,17 @@ class NATManager:
             rrows[:, :4] = skey
             self.reverse.bulk_insert(rkey[sel], rrows[sel])
         return nat_ip, nat_port, ok
+
+    def pool_stats(self) -> dict:
+        """The pool as an operator needs it before `allocate_nat` returns
+        None: addresses owned, port blocks in use, port blocks still free
+        (never carved, or released: every carved block is in `blocks` or
+        on a free list)."""
+        lo, hi = self.port_range
+        room = len(self.public_ips) * ((hi - lo + 1) // self.ports_per_subscriber)
+        return {"addresses": len(self.public_ips),
+                "blocks_used": len(self.blocks),
+                "blocks_free": room - len(self.blocks)}
 
     def release_nat(self, private_ip: int, now: int = 0) -> bool:
         block = self.blocks.pop(private_ip, None)

@@ -205,9 +205,12 @@ class ShardTelemetry:
 
     VERDICT_NAMES = ("pass", "drop", "tx", "fwd")
 
-    def __init__(self, n_shards: int, batch_per_shard: int):
+    def __init__(self, n_shards: int, batch_per_shard: int, nat=None):
         self.n = n_shards
         self.b = batch_per_shard
+        # the shards' NATManagers, for what each host mirror holds (a
+        # cluster hands its own; None: a telemetry with no cluster)
+        self.nat = nat
         self.frames = np.zeros((n_shards,), dtype=np.int64)
         self.verdicts = np.zeros((n_shards, 4), dtype=np.int64)
         self.nat_punts = np.zeros((n_shards,), dtype=np.int64)
@@ -272,6 +275,11 @@ class ShardTelemetry:
                 "violations": int(self.violations[i]),
                 "dhcp_replies": int(self.dhcp_replies[i]),
             })
+            if self.nat is not None:
+                nat = self.nat[i]
+                per_shard[-1].update(nat_sessions=int(nat.sessions.count),
+                                     nat_blocks=len(nat.blocks),
+                                     nat_pool=nat.pool_stats())
         return {
             "shards": self.n,
             "steps": self.steps,
@@ -303,6 +311,7 @@ class ShardedCluster:
         cid_nbuckets: int = 64,
         max_pools: int = 16,
         nat_sessions_nbuckets: int = 256,
+        nat_sub_nbuckets: int = 256,
         nat_ports_per_subscriber: int = 1024,
         qos_nbuckets: int = 256,
         spoof_nbuckets: int = 256,
@@ -326,6 +335,7 @@ class ShardedCluster:
             sub_nbuckets=sub_nbuckets, vlan_nbuckets=vlan_nbuckets,
             cid_nbuckets=cid_nbuckets, max_pools=max_pools,
             nat_sessions_nbuckets=nat_sessions_nbuckets,
+            nat_sub_nbuckets=nat_sub_nbuckets,
             nat_ports_per_subscriber=nat_ports_per_subscriber,
             qos_nbuckets=qos_nbuckets, spoof_nbuckets=spoof_nbuckets,
             public_ips=list(public_ips) if public_ips else None,
@@ -347,11 +357,17 @@ class ShardedCluster:
                 f"need >= {n_shards} public IPs for {n_shards} shards "
                 f"(got {len(base_pub)}): each shard's NAT pool must own "
                 f"its public IPs exclusively")
+        # every address is used, each owned by one shard: the list is
+        # dealt in contiguous runs in the order given, so a pool that is
+        # one address range costs the ring one range test a shard
+        # (make_ring) however many addresses it holds
+        k = len(base_pub)
         self.nat = [
-            NATManager(public_ips=[base_pub[i]],
+            NATManager(public_ips=base_pub[i * k // n_shards:
+                                           (i + 1) * k // n_shards],
                        sessions_nbuckets=nat_sessions_nbuckets,
                        ports_per_subscriber=nat_ports_per_subscriber,
-                       sub_nat_nbuckets=256)
+                       sub_nat_nbuckets=nat_sub_nbuckets)
             for i in range(n_shards)
         ]
         self.qos = [QoSTables(nbuckets=qos_nbuckets) for _ in range(n_shards)]
@@ -415,7 +431,8 @@ class ShardedCluster:
         # owns a cluster AND a BNGMetrics exports it via
         # BNGMetrics.collect_sharded (the serving-path promotion's
         # scrape source — `bng run` has no cluster yet)
-        self.telemetry = ShardTelemetry(n_shards, batch_per_shard)
+        self.telemetry = ShardTelemetry(n_shards, batch_per_shard,
+                                        nat=self.nat)
         # NAT public-IP -> owner shard, resolved lazily for the missteer
         # classifier (ownership is fixed at construction: each shard's
         # NATManager keeps its public_ips for its lifetime)
@@ -453,7 +470,45 @@ class ShardedCluster:
 
         return fnv1a32(int(private_ip).to_bytes(4, "big")) % self.n
 
+    def affinity_shards(self, private_ips) -> np.ndarray:
+        """`affinity_shard_ip` of many addresses at once ([N] int64)."""
+        from bng_tpu.runtime.hostpath import fnv1a32_cols
+
+        ips = np.asarray(private_ips, dtype=np.uint32)
+        return (fnv1a32_cols(ips.astype(">u4").view(np.uint8).reshape(-1, 4))
+                % np.uint32(self.n)).astype(np.int64)
+
     # ---- subscriber-affinity service placement (owner-shard routing) ----
+    def bulk_allocate_nat(self, private_ips, now: int = 0) -> np.ndarray:
+        """Port blocks for many subscribers, each on its owner shard
+        (`NATManager.bulk_allocate_nat` a shard). Returns the blocks made
+        a shard ([n] int64): less than a shard's share of the addresses
+        means its pool is exhausted."""
+        ips = np.asarray(private_ips, dtype=np.uint32)
+        owner = self.affinity_shards(ips)
+        return np.array([self.nat[s].bulk_allocate_nat(ips[owner == s], now)
+                         for s in range(self.n)], dtype=np.int64)
+
+    def bulk_flows(self, src_ips, dst_ips, src_ports, dst_ports, protos,
+                   pkt_len: int, now: int):
+        """Sessions for many 5-tuples, each on its source's owner shard
+        (`NATManager.bulk_flows` a shard: the shards' managers share
+        nothing). Returns (nat_ips, nat_ports, ok) in the order given."""
+        src_ips = np.atleast_1d(np.asarray(src_ips, dtype=np.uint32))
+        nf = len(src_ips)
+        cols = [np.broadcast_to(np.asarray(c, dtype=np.uint32), (nf,))
+                for c in (dst_ips, src_ports, dst_ports, protos)]
+        owner = self.affinity_shards(src_ips)
+        nat_ip = np.zeros(nf, dtype=np.uint32)
+        nat_port = np.zeros(nf, dtype=np.uint32)
+        ok = np.zeros(nf, dtype=bool)
+        for s in range(self.n):
+            m = owner == s
+            if m.any():
+                nat_ip[m], nat_port[m], ok[m] = self.nat[s].bulk_flows(
+                    src_ips[m], *(c[m] for c in cols), pkt_len, now)
+        return nat_ip, nat_port, ok
+
     def allocate_nat(self, private_ip: int, now: int = 0):
         """Allocate a NAT port block on the subscriber's owner shard.
 
@@ -598,17 +653,38 @@ class ShardedCluster:
         step -> complete` is the full multichip I/O loop."""
         from bng_tpu.runtime.ring import make_ring as _mk
 
-        ring = _mk(nframes, frame_size, depth, prefer_native=prefer_native,
-                   n_shards=self.n)
-        for ip, s in self.pub_ip_map().items():
-            if not ring.steer_pub_ip(ip, s):
+        return self.steer_ring(_mk(nframes, frame_size, depth,
+                                   prefer_native=prefer_native,
+                                   n_shards=self.n))
+
+    def steer_ring(self, ring):
+        """Hand `ring` (one of `n` shards) this cluster's ownership of the
+        public pool; returns it. Raises when the ring cannot hold it."""
+        # ownership as the ring holds it: a run of consecutive addresses
+        # with one owner is one range test, a lone address an entry of
+        # the exact map. A pool dealt in contiguous runs is a range a
+        # shard, so steering costs the same at 4 addresses and at 16,000.
+        owners = sorted(self.pub_ip_map().items())
+        at = 0
+        while at < len(owners):
+            lo, s = owners[at]
+            end = at
+            while (end + 1 < len(owners)
+                   and owners[end + 1] == (owners[end][0] + 1, s)):
+                end += 1
+            hi = owners[end][0]
+            took = (ring.steer_pub_ip(lo, s) if hi == lo
+                    else ring.steer_pub_range(lo, hi, s))
+            if not took:
                 # an unregistered public IP would silently fall back to
                 # dst-IP hashing — every return packet punts on a wrong
                 # shard. A ring that cannot express the placement is a
                 # configuration error, not a degraded mode.
                 raise RuntimeError(
-                    f"ring steering table rejected public IP {ip:#x} "
-                    f"(capacity/probe bound); reduce public IPs per ring")
+                    f"ring steering tables rejected public IPs {lo:#x}.."
+                    f"{hi:#x} of shard {s} (capacity/probe bound): list "
+                    f"each shard's addresses as fewer contiguous runs")
+            at = end + 1
         return ring
 
     # ---- control-plane writes ----
@@ -1103,6 +1179,7 @@ class ShardedCluster:
         if entry is None:
             return 0
         from bng_tpu.ops.dhcp import ST_HIT
+        from bng_tpu.ops.nat44 import NST_DNAT, NST_SNAT
         from bng_tpu.runtime.ring import VERDICT_PASS, VERDICT_TX
 
         ring, out, pkt, length, flags, got, now_s, tok = entry
@@ -1141,8 +1218,14 @@ class ShardedCluster:
             punt = np.asarray(nat_punt)
             viol = np.asarray(viol_d)
             dhcp_h = np.asarray(dhcp_stats)
+            nat_h = np.asarray(nat_stats)
+            if tele.t() is not None:
+                # lanes this step translated and lanes NAT punted, from
+                # the blocks read here already
+                tele.nat_lanes(int(nat_h[NST_SNAT]) + int(nat_h[NST_DNAT]),
+                               int((punt & real).sum()))
             self._fold_stats(dhcp=dhcp_h,
-                             nat=np.asarray(nat_stats),
+                             nat=nat_h,
                              qos=np.asarray(qos_stats),
                              spoof=np.asarray(spoof_stats),
                              garden=(np.asarray(g_stats)
@@ -1367,8 +1450,12 @@ class ShardedCluster:
 
     def fetch_session_vals(self, shard: int) -> np.ndarray:
         """One shard's device-authoritative NAT session rows (counters +
-        last_seen) — the per-shard slice of the mesh-stacked array."""
-        return np.asarray(self.tables.nat.sessions.vals)[shard]
+        last_seen): that shard's own piece of the mesh-stacked array, read
+        from the chip that holds it and nothing of the others'."""
+        vals = self.tables.nat.sessions.vals
+        piece = next(p for p in vals.addressable_shards
+                     if (p.index[0].start or 0) == shard)
+        return np.asarray(piece.data)[0]
 
     def fold_device_authoritative(self) -> None:
         """Pull the device-WRITTEN words back into every shard's host
@@ -1450,13 +1537,19 @@ class ShardedCluster:
     def stats_summary(self) -> dict:
         """Aggregate serving counters for `bng run` stats() — the
         engine-stats analog of the sharded path."""
+        from bng_tpu.ops.nat44 import NST_DNAT, NST_SNAT
+
         t = self.telemetry
+        nat = self.stats.get("nat")
         return {
             "shards": self.n,
             "steps": t.steps,
             "frames": int(t.frames.sum()),
             "tx": int(t.verdicts[:, 2].sum()),
             "fwd": int(t.verdicts[:, 3].sum()),
+            # lanes NAT translated on the chips (SNAT and DNAT hits)
+            "nat_fwd": (int(nat[NST_SNAT]) + int(nat[NST_DNAT])
+                        if nat is not None else 0),
             "dropped": int(t.verdicts[:, 1].sum()),
             # legit slow-path punts only — missteers are split out
             # (same accounting as snapshot()'s pass_total)

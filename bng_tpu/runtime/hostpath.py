@@ -284,16 +284,32 @@ def classify_dhcp_batch(buf: np.ndarray, lens: np.ndarray) -> np.ndarray:
 def shard_of_batch(buf: np.ndarray, lens: np.ndarray, flags: np.ndarray,
                    n_shards: int,
                    pub_keys: np.ndarray | None = None,
-                   pub_vals: np.ndarray | None = None) -> np.ndarray:
-    """Vectorized ring.shard_of: [n] int64 owner shards. pub_keys must
-    be SORTED host-order NAT public IPs with pub_vals their owner
-    shards (PyRing keeps the sorted mirror of its steer map)."""
+                   pub_vals: np.ndarray | None = None,
+                   pub_his: np.ndarray | None = None) -> np.ndarray:
+    """`steer_batch`'s shards alone."""
+    return steer_batch(buf, lens, flags, n_shards, pub_keys, pub_vals,
+                       pub_his)[0]
+
+
+def steer_batch(buf: np.ndarray, lens: np.ndarray, flags: np.ndarray,
+                n_shards: int,
+                pub_keys: np.ndarray | None = None,
+                pub_vals: np.ndarray | None = None,
+                pub_his: np.ndarray | None = None):
+    """Vectorized ring.steer: ([n] int64 owner shards, [n] bool lanes
+    from the core steered by public-IP ownership, [n] bool lanes from the
+    core whose destination no pool holds). pub_keys must be the SORTED
+    first addresses (host order) of non-overlapping runs of NAT public
+    IPs, pub_his their last addresses (None: every run is one address)
+    and pub_vals their owner shards (PyRing keeps the sorted mirror of
+    its steer tables)."""
     n = buf.shape[0]
     lens = np.asarray(lens, dtype=np.int64)
     flags = np.asarray(flags, dtype=np.uint32)
     shard = np.zeros(n, dtype=np.int64)
+    none = np.zeros(n, dtype=bool)
     if n_shards == 1 or n == 0:
-        return shard
+        return shard, none, none
     alive = lens >= 14
     # sticky MAC hash — the DHCP-control / non-IPv4 / PPPoE-control fall
     # line (shard stays 0 for runts, like the scalar early return)
@@ -315,6 +331,7 @@ def shard_of_batch(buf: np.ndarray, lens: np.ndarray, flags: np.ndarray,
                      ).astype(np.int64)[up]
     # downstream IPv4: NAT pub-IP ownership, else FNV of dst IP
     down = ip4 & ~from_access
+    hit = none
     if down.any():
         dst = _ip_cols(buf, off + 16)
         dfnv = (fnv1a32_cols(dst) % np.uint32(n_shards)).astype(np.int64)
@@ -324,9 +341,11 @@ def shard_of_batch(buf: np.ndarray, lens: np.ndarray, flags: np.ndarray,
                        | (dst[:, 1].astype(np.uint64) << 16)
                        | (dst[:, 2].astype(np.uint64) << 8)
                        | dst[:, 3])
-            pos = np.searchsorted(pub_keys, dst_u32)
-            pos_c = np.minimum(pos, len(pub_keys) - 1)
-            hit = down & (pub_keys[pos_c] == dst_u32)
+            # the run that starts at or below the address, if any
+            pos_c = np.maximum(
+                np.searchsorted(pub_keys, dst_u32, side="right") - 1, 0)
+            his = pub_keys if pub_his is None else pub_his
+            hit = down & (pub_keys[pos_c] <= dst_u32) & (dst_u32 <= his[pos_c])
             owner = pub_vals[pos_c]
             hit &= owner < n_shards  # scalar: out-of-range owner ignored
             shard[hit] = owner[hit].astype(np.int64)
@@ -345,7 +364,7 @@ def shard_of_batch(buf: np.ndarray, lens: np.ndarray, flags: np.ndarray,
             isrc = _ip_cols(buf, off + 8 + 12)
             shard[ppp] = (fnv1a32_cols(isrc) % np.uint32(n_shards)
                           ).astype(np.int64)[ppp]
-    return shard
+    return shard, hit, down & ~hit
 
 
 def _ip_cols(buf: np.ndarray, off: np.ndarray) -> np.ndarray:

@@ -76,21 +76,38 @@ def classify_dhcp(frame: bytes) -> int:
     return FLAG_DHCP_CTRL if magic == 0x63825363 else 0
 
 
-def shard_of(frame: bytes, flags: int, n_shards: int,
-             pub_ips: dict[int, int] | None = None) -> int:
+def pub_owner(ip: int, n_shards: int, pub_ips: dict[int, int] | None,
+              pub_ranges=None) -> int | None:
+    """Owner shard of a NAT public IP: the ranges [(lo, hi, shard)], then
+    the exact map; None when no shard's pool holds it (bngring.cpp
+    pub_owner)."""
+    for lo, hi, s in pub_ranges or ():
+        if lo <= ip <= hi:
+            return s
+    s = pub_ips.get(ip) if pub_ips else None
+    return s if s is not None and s < n_shards else None
+
+
+def steer(frame: bytes, flags: int, n_shards: int,
+          pub_ips: dict[int, int] | None = None,
+          pub_ranges=None) -> tuple[int, bool | None]:
     """Owner-shard steering decision — the PyRing mirror of bngring.cpp's
-    bng_ring_shard_of; must agree bit-for-bit (spec in bngring.h).
+    steer; must agree bit-for-bit (spec in bngring.h). Returns the shard
+    and what the public-IP tables said: True for a frame from the core
+    steered by ownership, False for one whose destination no shard's pool
+    holds (it fell back to the hash), None for every other frame.
 
     The subscriber-affinity placement chip-local NAT/QoS/antispoof state
     depends on (parallel/sharded.py): upstream by FNV-1a32(src IP),
-    downstream by NAT-public-IP ownership (pub_ips: host-order IP ->
-    shard) falling back to FNV-1a32(dst IP), DHCP-control and non-IPv4
-    frames by FNV-1a32(src MAC). `flags` are the descriptor flags AFTER
-    classification (FROM_ACCESS | DHCP_CTRL)."""
+    downstream by NAT-public-IP ownership (pub_ranges: (lo, hi, shard)
+    runs, then pub_ips: host-order IP -> shard) falling back to
+    FNV-1a32(dst IP), DHCP-control and non-IPv4 frames by FNV-1a32(src
+    MAC). `flags` are the descriptor flags AFTER classification
+    (FROM_ACCESS | DHCP_CTRL)."""
     from bng_tpu.utils.net import fnv1a32
 
     if n_shards == 1 or len(frame) < 14:
-        return 0
+        return 0, None
     if not (flags & FLAG_DHCP_CTRL):
         off = 12
         et = (frame[off] << 8) | frame[off + 1]
@@ -104,13 +121,13 @@ def shard_of(frame: bytes, flags: int, n_shards: int,
         off += 2  # L3 start
         if et == 0x0800 and len(frame) >= off + 20 and (frame[off] >> 4) == 4:
             if flags & FLAG_FROM_ACCESS:
-                return fnv1a32(frame[off + 12 : off + 16]) % n_shards
+                return fnv1a32(frame[off + 12 : off + 16]) % n_shards, None
             dst = frame[off + 16 : off + 20]
-            if pub_ips:
-                s = pub_ips.get(int.from_bytes(dst, "big"))
-                if s is not None and s < n_shards:
-                    return s
-            return fnv1a32(dst) % n_shards
+            s = pub_owner(int.from_bytes(dst, "big"), n_shards, pub_ips,
+                          pub_ranges)
+            if s is not None:
+                return s, True
+            return fnv1a32(dst) % n_shards, False
         if (et == 0x8864 and (flags & FLAG_FROM_ACCESS)
                 and len(frame) >= off + 8 + 20
                 and frame[off] == 0x11 and frame[off + 1] == 0
@@ -121,8 +138,15 @@ def shard_of(frame: bytes, flags: int, n_shards: int,
             # chip-local NAT/QoS/session state is placed with. PPPoE
             # control (discovery/LCP/auth/IPCP) falls through to the
             # sticky MAC hash; any shard's slow path handles it.
-            return fnv1a32(frame[off + 8 + 12 : off + 8 + 16]) % n_shards
-    return fnv1a32(frame[6:12]) % n_shards
+            return (fnv1a32(frame[off + 8 + 12 : off + 8 + 16]) % n_shards,
+                    None)
+    return fnv1a32(frame[6:12]) % n_shards, None
+
+
+def shard_of(frame: bytes, flags: int, n_shards: int,
+             pub_ips: dict[int, int] | None = None, pub_ranges=None) -> int:
+    """`steer`'s shard alone (parity tests, the missteer classifier)."""
+    return steer(frame, flags, n_shards, pub_ips, pub_ranges)[0]
 
 
 class RingStats(C.Structure):
@@ -136,6 +160,8 @@ class RingStats(C.Structure):
         ("rx_full", C.c_uint64),
         ("tx_full", C.c_uint64),
         ("bad_desc", C.c_uint64),
+        ("steer_pub_hit", C.c_uint64),
+        ("steer_pub_miss", C.c_uint64),
     ]
 
 
@@ -173,6 +199,9 @@ def _configure(lib: C.CDLL) -> None:
     lib.bng_ring_n_shards.argtypes = [C.c_void_p]
     lib.bng_ring_steer_pub_ip.restype = C.c_int
     lib.bng_ring_steer_pub_ip.argtypes = [C.c_void_p, C.c_uint32, C.c_uint32]
+    lib.bng_ring_steer_pub_range.restype = C.c_int
+    lib.bng_ring_steer_pub_range.argtypes = [C.c_void_p, C.c_uint32,
+                                             C.c_uint32, C.c_uint32]
     lib.bng_ring_shard_of.restype = C.c_uint32
     lib.bng_ring_shard_of.argtypes = [C.c_void_p, C.POINTER(C.c_uint8),
                                       C.c_uint32, C.c_uint32]
@@ -364,6 +393,13 @@ class NativeRing:
         """Register a NAT public IP (host order) as owned by `shard`."""
         return self._lib.bng_ring_steer_pub_ip(self._h, ip, shard) == 0
 
+    def steer_pub_range(self, lo: int, hi: int, shard: int) -> bool:
+        """Register the NAT public IPs lo..hi (host order, inclusive) as
+        owned by `shard`: one entry a run. False when the table is full
+        or the range overlaps a registered one."""
+        return self._lib.bng_ring_steer_pub_range(self._h, lo, hi,
+                                                  shard) == 0
+
     def shard_of(self, frame: bytes, flags: int) -> int:
         buf = np.frombuffer(frame, dtype=np.uint8)
         return int(self._lib.bng_ring_shard_of(self._h, _u8p(buf),
@@ -542,7 +578,8 @@ class PyRing:
         # (slot-id array, valid-lane mask) pairs
         self._inflight: list = []
         self._pub_ips: dict[int, int] = {}
-        self._pub_sorted = None  # (keys u64 sorted, vals i64) mirror
+        self._pub_ranges: list[tuple[int, int, int]] = []  # (lo, hi, shard)
+        self._pub_sorted = None  # (los, vals, his) sorted by lo: the mirror
         self._stats = {k: 0 for k, _ in RingStats._fields_}
         self._stats["tx_refused"] = 0  # tx_inject said no: the frame is gone
         if self._vec:
@@ -580,20 +617,45 @@ class PyRing:
         self._pub_sorted = None
         return True
 
+    RANGES_MAX = 64  # bngring.cpp PubRanges::MAX
+
+    def steer_pub_range(self, lo: int, hi: int, shard: int) -> bool:
+        """bng_ring_steer_pub_range's twin, refusals included."""
+        if (shard >= self.n_shards or lo > hi
+                or len(self._pub_ranges) >= self.RANGES_MAX
+                or any(lo <= b and a <= hi for a, b, _ in self._pub_ranges)):
+            return False
+        self._pub_ranges.append((lo, hi, shard))
+        self._pub_sorted = None
+        return True
+
+    def steer(self, frame: bytes, flags: int):
+        return steer(frame, flags, self.n_shards, self._pub_ips,
+                     self._pub_ranges)
+
     def shard_of(self, frame: bytes, flags: int) -> int:
-        return shard_of(frame, flags, self.n_shards, self._pub_ips)
+        return self.steer(frame, flags)[0]
 
     def _pub_arrays(self):
-        """Sorted-array mirror of the pub-IP steer map (rebuilt lazily
-        after steer_pub_ip) — the vector path's O(log n) membership."""
+        """Sorted-array mirror of the steer tables (rebuilt lazily after
+        steer_pub_ip / steer_pub_range) — the vector path's O(log n)
+        membership: every run's first address, its owner and its last
+        address, an exact IP a run of one; one inside a registered range
+        is left out, the range is looked up first."""
         if self._pub_sorted is None:
-            keys = np.fromiter(self._pub_ips.keys(), dtype=np.uint64,
-                               count=len(self._pub_ips))
-            vals = np.fromiter(self._pub_ips.values(), dtype=np.int64,
-                               count=len(self._pub_ips))
-            order = np.argsort(keys)
-            self._pub_sorted = (keys[order], vals[order])
+            runs = list(self._pub_ranges) + [
+                (ip, ip, s) for ip, s in self._pub_ips.items()
+                if pub_owner(ip, self.n_shards, None,
+                             self._pub_ranges) is None]
+            runs.sort()
+            cols = np.array(runs, dtype=np.int64).reshape(-1, 3)
+            self._pub_sorted = (cols[:, 0].astype(np.uint64), cols[:, 2],
+                                cols[:, 1].astype(np.uint64))
         return self._pub_sorted
+
+    def _count_steered(self, hit: int, miss: int) -> None:
+        self._stats["steer_pub_hit"] += hit
+        self._stats["steer_pub_miss"] += miss
 
     # -- producer ---------------------------------------------------------
 
@@ -604,11 +666,13 @@ class PyRing:
         fl = FLAG_FROM_ACCESS if from_access else 0
         if from_access:  # direction gate — see classify_dhcp docstring
             fl |= classify_dhcp(frame)
-        shard = self.shard_of(frame, fl)
+        shard, pub = self.steer(frame, fl)
         if self._free == 0 or self._shard_depth(shard) >= self.depth:
             self._stats["fill_empty" if self._free == 0 else "rx_full"] += 1
             return False
         self._free -= 1
+        if pub is not None:
+            self._count_steered(pub, not pub)
         if self._vec:
             self._enqueue_slot(shard, self._stage_slot(frame, fl))
         else:
@@ -661,11 +725,11 @@ class PyRing:
         if from_access:
             fl |= hostpath.classify_dhcp_batch(buf, lens)
         if self.n_shards > 1:
-            keys, vals = self._pub_arrays()
-            shards = hostpath.shard_of_batch(buf, lens, fl, self.n_shards,
-                                             keys, vals)
+            shards, hit, miss = hostpath.steer_batch(
+                buf, lens, fl, self.n_shards, *self._pub_arrays())
         else:
             shards = np.zeros(n, dtype=np.int64)
+            hit = miss = np.zeros(n, dtype=bool)
         counts = np.bincount(shards, minlength=self.n_shards)
         if ((self._rxc + counts) > self.depth).any():
             # per-shard backpressure mid-batch: scalar decisions
@@ -676,6 +740,7 @@ class PyRing:
         for s in np.nonzero(counts)[0]:
             self._enqueue_slots(int(s), slots[shards == s])
         self._free -= n
+        self._count_steered(int(hit.sum()), int(miss.sum()))
         return n
 
     def tx_inject(self, frame: bytes, from_access: bool = True) -> bool:

@@ -229,6 +229,10 @@ class Tracer:
         # off, and forwarded downstream to a subscriber without a pair
         # (engine.py _fold_stats); 0 in a program without the stage
         self.qinq_push = self.qinq_pop = self.qinq_miss = 0
+        # lanes the sharded steps translated (SNAT and DNAT hits, summed
+        # over the mesh) and lanes NAT punted to the host (sharded.py
+        # _retire); 0 on the one-chip loops
+        self.nat_fwd = self.nat_punt = 0
         # crossings between host and chip on the hot path (xfer): calls
         # and bytes by direction, [upload, fetch]
         self.xfer_calls = [0, 0]
@@ -596,6 +600,8 @@ class Tracer:
             "qinq_push": int(self.qinq_push),
             "qinq_pop": int(self.qinq_pop),
             "qinq_miss": int(self.qinq_miss),
+            "nat_fwd": int(self.nat_fwd),
+            "nat_punt": int(self.nat_punt),
             "xfer": {"upload_calls": int(self.xfer_calls[0]),
                      "upload_bytes": int(self.xfer_bytes[0]),
                      "fetch_calls": int(self.xfer_calls[1]),
@@ -849,6 +855,15 @@ def qinq_lanes(push: int, pop: int, miss: int) -> None:
     _ACTIVE.qinq_push += push
     _ACTIVE.qinq_pop += pop
     _ACTIVE.qinq_miss += miss
+
+
+def nat_lanes(fwd: int, punt: int) -> None:
+    """Count one retired sharded step's NAT lanes: translated (SNAT and
+    DNAT hits) and punted. Disarmed: global load + None compare."""
+    if _ACTIVE is None:
+        return
+    _ACTIVE.nat_fwd += fwd
+    _ACTIVE.nat_punt += punt
 
 
 def trigger(reason: str, detail: str = "") -> str | None:

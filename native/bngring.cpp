@@ -167,6 +167,22 @@ struct PubMap {
   Ent ents[SLOTS];
 };
 
+/* Public-IP RANGES -> shard: a deployment's pool is thousands of
+ * addresses dealt to the shards in contiguous runs, so ownership is a
+ * handful of [lo, hi] tests and the cost a frame does not grow with the
+ * pool. Append-only, no two ranges overlap (the writer refuses one that
+ * would); same single-writer publication as PubMap: the entry's words
+ * first, then `n` with release, and a reader that loads `n` with acquire
+ * sees whole entries. Looked up BEFORE the exact map. */
+struct PubRanges {
+  static constexpr uint32_t MAX = 64;
+  struct Ent {
+    std::atomic<uint32_t> lo{0}, hi{0}, shard{0};
+  };
+  Ent ents[MAX];
+  std::atomic<uint32_t> n{0};
+};
+
 struct bng_ring {
   uint8_t *umem = nullptr;
   uint64_t umem_size = 0;
@@ -180,6 +196,7 @@ struct bng_ring {
   Ring fwd;  /* engine FWD verdicts -> wire (other port) */
   Ring slow; /* engine PASS verdicts -> slow path */
   PubMap pubmap; /* downstream steering: NAT public IP -> owner shard */
+  PubRanges pubranges; /* ... and contiguous runs of them */
 
   /* in-flight batches (assemble..complete windows). TWO slots so a
    * double-buffered engine can assemble+dispatch batch k+1 before
@@ -360,11 +377,49 @@ int bng_ring_steer_pub_ip(bng_ring *r, uint32_t ip, uint32_t shard) {
   return 0;
 }
 
-/* Steering decision — spec in bngring.h; Python twin: ring.py shard_of.
+int bng_ring_steer_pub_range(bng_ring *r, uint32_t lo, uint32_t hi,
+                             uint32_t shard) {
+  PubRanges &t = r->pubranges;
+  uint32_t n = t.n.load(std::memory_order_relaxed);
+  if (shard >= r->n_shards || lo > hi || n >= PubRanges::MAX) return -1;
+  for (uint32_t i = 0; i < n; i++)
+    if (lo <= t.ents[i].hi.load(std::memory_order_relaxed) &&
+        t.ents[i].lo.load(std::memory_order_relaxed) <= hi)
+      return -1; /* overlap: ownership is exclusive */
+  t.ents[n].lo.store(lo, std::memory_order_relaxed);
+  t.ents[n].hi.store(hi, std::memory_order_relaxed);
+  t.ents[n].shard.store(shard, std::memory_order_relaxed);
+  t.n.store(n + 1, std::memory_order_release);
+  return 0;
+}
+
+/* Owner shard of a NAT public IP: the ranges, then the exact map; -1 when
+ * no shard's pool holds it. */
+static int pub_owner(const bng_ring *r, uint32_t ip) {
+  const PubRanges &t = r->pubranges;
+  uint32_t nr = t.n.load(std::memory_order_acquire);
+  for (uint32_t i = 0; i < nr; i++)
+    if (ip >= t.ents[i].lo.load(std::memory_order_relaxed) &&
+        ip <= t.ents[i].hi.load(std::memory_order_relaxed))
+      return static_cast<int>(t.ents[i].shard.load(std::memory_order_relaxed));
+  int slot = pubmap_find(r->pubmap, ip, /*for_insert=*/false);
+  if (slot < 0) return -1;
+  uint32_t s =
+      r->pubmap.ents[slot].shard_plus1.load(std::memory_order_relaxed) - 1;
+  return s < r->n_shards ? static_cast<int>(s) : -1;
+}
+
+/* What steer() saw of the public-IP tables, for the always-on counters:
+ * a frame from the core steered by ownership, or one whose destination is
+ * in no shard's pool (it fell back to the hash). */
+enum { STEER_OTHER = 0, STEER_PUB_HIT = 1, STEER_PUB_MISS = 2 };
+
+/* Steering decision — spec in bngring.h; Python twin: ring.py steer.
  * Walks the same L2/L3 prefix as classify_dhcp (0-2 VLAN tags). */
-uint32_t bng_ring_shard_of(bng_ring *r, const uint8_t *p, uint32_t len,
-                           uint32_t flags) {
+static uint32_t steer(const bng_ring *r, const uint8_t *p, uint32_t len,
+                      uint32_t flags, int *pub) {
   uint32_t n = r->n_shards;
+  *pub = STEER_OTHER;
   if (n == 1) return 0;
   if (len < 14) return 0;
   if (!(flags & BNG_DESC_F_DHCP_CTRL)) {
@@ -386,13 +441,9 @@ uint32_t bng_ring_shard_of(bng_ring *r, const uint8_t *p, uint32_t len,
       uint32_t dip = (static_cast<uint32_t>(dst[0]) << 24) |
                      (static_cast<uint32_t>(dst[1]) << 16) |
                      (static_cast<uint32_t>(dst[2]) << 8) | dst[3];
-      int slot = pubmap_find(r->pubmap, dip, /*for_insert=*/false);
-      if (slot >= 0) {
-        uint32_t s =
-            r->pubmap.ents[slot].shard_plus1.load(std::memory_order_relaxed) -
-            1;
-        if (s < n) return s;
-      }
+      int owner = pub_owner(r, dip);
+      *pub = owner >= 0 ? STEER_PUB_HIT : STEER_PUB_MISS;
+      if (owner >= 0) return static_cast<uint32_t>(owner);
       return fnv1a32_bytes(dst, 4) % n;
     }
     /* PPPoE session DATA (PPP proto IPv4): steer by the INNER src IP —
@@ -410,6 +461,29 @@ uint32_t bng_ring_shard_of(bng_ring *r, const uint8_t *p, uint32_t len,
   return fnv1a32_bytes(p + 6, 6) % n;
 }
 
+uint32_t bng_ring_shard_of(bng_ring *r, const uint8_t *p, uint32_t len,
+                           uint32_t flags) {
+  int pub;
+  return steer(r, p, len, flags, &pub);
+}
+
+/* Steer one classified frame onto its shard's RX queue, counting what
+ * the public-IP tables said of a frame the queue took. */
+static bool rx_enqueue(bng_ring *r, uint64_t addr, uint32_t len,
+                       uint32_t flags) {
+  int pub;
+  uint32_t shard = steer(r, r->umem + addr, len, flags, &pub);
+  bng_desc d{addr, len, flags};
+  if (!r->rxq[shard].push(d)) {
+    r->stats.rx_full++;
+    recycle(r, addr);
+    return false;
+  }
+  if (pub == STEER_PUB_HIT) r->stats.steer_pub_hit++;
+  else if (pub == STEER_PUB_MISS) r->stats.steer_pub_miss++;
+  return true;
+}
+
 int bng_ring_rx_submit(bng_ring *r, uint64_t addr, uint32_t len,
                        uint32_t flags) {
   if (!valid_addr(r, addr) || len > r->frame_size) {
@@ -424,14 +498,7 @@ int bng_ring_rx_submit(bng_ring *r, uint64_t addr, uint32_t len,
   flags &= ~BNG_DESC_F_DHCP_CTRL;
   if (flags & BNG_DESC_F_FROM_ACCESS)
     flags |= classify_dhcp(r->umem + addr, len);
-  uint32_t shard = bng_ring_shard_of(r, r->umem + addr, len, flags);
-  bng_desc d{addr, len, flags};
-  if (!r->rxq[shard].push(d)) {
-    r->stats.rx_full++;
-    recycle(r, addr);
-    return -1;
-  }
-  return 0;
+  return rx_enqueue(r, addr, len, flags) ? 0 : -1;
 }
 
 uint32_t bng_ring_rx_reserve_batch(bng_ring *r, uint64_t *out_addrs,
@@ -465,13 +532,7 @@ uint32_t bng_ring_rx_submit_batch(bng_ring *r, const uint64_t *addrs,
     uint32_t fl = flags & ~BNG_DESC_F_DHCP_CTRL; /* rx_submit gate */
     if (fl & BNG_DESC_F_FROM_ACCESS)
       fl |= classify_dhcp(r->umem + addr, lens[i]);
-    uint32_t shard = bng_ring_shard_of(r, r->umem + addr, lens[i], fl);
-    bng_desc d{addr, lens[i], fl};
-    if (!r->rxq[shard].push(d)) {
-      r->stats.rx_full++;
-      recycle(r, addr);
-      continue;
-    }
+    if (!rx_enqueue(r, addr, lens[i], fl)) continue;
     out_ok[i] = 1;
     ok_n++;
   }
@@ -784,6 +845,6 @@ uint32_t bng_abi_desc_addr_off(void) { return offsetof(bng_desc, addr); }
 uint32_t bng_abi_desc_len_off(void) { return offsetof(bng_desc, len); }
 uint32_t bng_abi_desc_flags_off(void) { return offsetof(bng_desc, flags); }
 uint32_t bng_abi_stats_size(void) { return sizeof(bng_ring_stats); }
-uint32_t bng_abi_version(void) { return 3; }
+uint32_t bng_abi_version(void) { return 4; }
 
 } /* extern "C" */

@@ -83,6 +83,8 @@ typedef struct bng_ring_stats {
   uint64_t rx_full;     /* producer stalls: rx ring full */
   uint64_t tx_full;     /* tx/fwd/slow ring full -> frame dropped */
   uint64_t bad_desc;    /* descriptor validation failures */
+  uint64_t steer_pub_hit;  /* core-side frames steered by pool ownership */
+  uint64_t steer_pub_miss; /* ... whose dst no shard's pool holds (hash) */
 } bng_ring_stats;
 
 typedef struct bng_ring bng_ring; /* opaque */
@@ -108,9 +110,11 @@ bng_ring *bng_ring_create(uint32_t nframes, uint32_t frame_size,
  *   - access-side IPv4: FNV-1a32(4 src-IP bytes, wire order) % n —
  *     the subscriber's private IP, matching the control plane's
  *     affinity placement of NAT/QoS/antispoof state.
- *   - network-side IPv4: public-IP exact-match table (set per shard via
- *     bng_ring_steer_pub_ip — downstream NAT state lives on the shard
- *     that owns the public IP); miss -> FNV-1a32(4 dst-IP bytes) % n.
+ *   - network-side IPv4: the owner of the destination, by the ranges
+ *     (bng_ring_steer_pub_range) and then the exact-match table
+ *     (bng_ring_steer_pub_ip) — downstream NAT state lives on the shard
+ *     that owns the public IP; miss -> FNV-1a32(4 dst-IP bytes) % n.
+ *     A frame the RX queue took counts stats.steer_pub_hit / _miss.
  *   - access-side PPPoE session DATA (ethertype 0x8864, ver_type 0x11,
  *     code 0, PPP proto 0x0021, inner version 4): FNV-1a32(4 INNER
  *     src-IP bytes) % n — the decap'd packet's affinity key, so the
@@ -128,6 +132,14 @@ uint32_t bng_ring_n_shards(bng_ring *r);
  * Bounded-probe open addressing; returns 0, or -1 when the map is full /
  * shard out of range. Updating an existing IP's shard is allowed. */
 int bng_ring_steer_pub_ip(bng_ring *r, uint32_t ip, uint32_t shard);
+
+/* Register the NAT public IPs lo..hi (host byte order, inclusive) as
+ * owned by `shard`: what a pool dealt in contiguous runs costs the ring,
+ * one entry a run however many addresses it holds. Returns 0, or -1 when
+ * the table is full (64), the range overlaps a registered one, lo > hi or
+ * the shard is out of range. */
+int bng_ring_steer_pub_range(bng_ring *r, uint32_t lo, uint32_t hi,
+                             uint32_t shard);
 
 /* Steering decision for a frame (exposed for parity tests and
  * non-UMEM producers). flags: the would-be descriptor flags AFTER

@@ -49,3 +49,33 @@ def _bng_sanitize(request):
             h2d="disallow" if sanitize.strict() else "allow"):
         yield
 
+
+
+# ---------------------------------------------------------------------------
+# the stand-in of a benchmark cell added since tests/benchmark was written
+# ---------------------------------------------------------------------------
+# `tiny_dir` (tests/benchmark/test_benchmark.py) gives every layer file's
+# cells a tiny stand-in through three literals, and a layer file that names
+# a cell they lack stops every rehearsal with a KeyError. PR 42 adds
+# `cgnat-sharded4-1M.flood-64B` and may edit no file under tests/benchmark,
+# so its stand-in `tiny4-nat.flood` (a `4` in the name: `tiny_dir` gives
+# such a cell four chips) is added to them from here, before the fixture
+# reads them, as PR 34 did; the next `benchmark` issue moves the entries
+# into the literals (PERF.md section 7 row 1 xvii).
+
+@pytest.fixture(scope="module", autouse=True)
+def _shardnat_cell_has_a_stand_in():
+    import sys
+
+    tb = sys.modules.get("test_benchmark")
+    if tb is None:  # not a module that rehearses through tiny_dir
+        return
+    # tiny-sharded's four shards with the two capacities that size a
+    # shard's NAT tables; tiny_dir gives it 4 public addresses, one a shard
+    tb.TINY_ARGV.setdefault(
+        "tiny4-nat", tb.TINY_ARGV["tiny-sharded"]
+        + ["--max-nat-sessions", "512", "--max-nat-subscribers", "128"])
+    tb.BASE_OF.setdefault("tiny4-nat", "ipoe-cgnat-sharded4-1M")
+    tb.TINY_CELLS.setdefault(
+        "tiny4-nat.flood",
+        ("cgnat-sharded4-1M.flood-64B", "tiny4-nat", "tiny-flood-32"))

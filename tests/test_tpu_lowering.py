@@ -216,6 +216,34 @@ def test_sharded_step_loops_over_no_session_table(sharded_step):
     assert over_table == []
 
 
+def test_sharded_step_compiles_with_a_deployments_nat_a_shard(topo):
+    """`ipoe-cgnat-sharded4-1M` (PR 42): the same four-way mesh step with a
+    shard's NAT tables at a one-chip deployment's size, 1,000,000 sessions
+    and 250,000 port blocks a chip as `bng run --shards 4
+    --max-nat-sessions 4000000 --max-nat-subscribers 1000000` sizes them.
+    It fits, still exchanges only the DHCP lookup, and no `while` carries
+    the larger session table either."""
+    from bng_tpu import cli
+    from bng_tpu.parallel.sharded import AXIS
+
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+    per_shard = REAL_1M._replace(
+        batch=REAL_1M.batch // 4, sub_nbuckets=1 << 17,
+        side_nbuckets=1 << 17,
+        nat_sessions_nbuckets=cli._shard_sized(4_000_000, 4, 0),
+        sub_nat_nbuckets=cli._shard_sized(1_000_000, 4, 0))
+    assert (per_shard.nat_sessions_nbuckets, per_shard.sub_nat_nbuckets) == (
+        1 << 19, 1 << 17)
+    fn, args = verify.build_sharded(mesh, per_shard)
+    compiled = compile_for((fn, args))
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert "all-to-all" in compiled.as_text()
+    S = args[0].nat.sessions.vals.shape[1]
+    shapes = (f"{S},16]", f"16,{S}]", f"[{16 * S}]")
+    assert [w for w in _whiles(compiled)
+            if any(shape in w for shape in shapes)] == []
+
+
 @pytest.mark.slow  # ~47s of CPU compiles
 def test_gate_harness_compiles_on_any_backend():
     """The checks must compile on the attached backend, so harness API
