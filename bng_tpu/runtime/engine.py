@@ -786,6 +786,7 @@ class Engine:
         res: PipelineResult = self._step(
             tables_in, upd, *self._upload_batch(pkt, length, fa),
             now_s, now_us)
+        self._start_host_copies(res)
         # keep the authoritative dhcp chain out of the bulk rebind; the
         # replica-out threads back to the scheduler
         self.tables = res.tables._replace(dhcp=self.tables.dhcp)
@@ -1088,12 +1089,14 @@ class Engine:
         verdict = jnp.where(is_reply, np.uint8(VERDICT_TX),
                             np.uint8(VERDICT_PASS))
         no = np.zeros((B,), dtype=bool)
-        return _DhcpBatchResult(
+        res = _DhcpBatchResult(
             verdict=verdict, out_pkt=out_pkt, out_len=out_len,
             nat_punt=no, spoof_violation=no, dhcp_stats=stats,
             nat_stats=np.zeros(NAT_NSTATS, dtype=np.uint32),
             qos_stats=np.zeros(QOS_NSTATS, dtype=np.uint32),
             spoof_stats=np.zeros(ANTISPOOF_NSTATS, dtype=np.uint32))
+        self._start_host_copies(res)
+        return res
 
     def _run_dhcp_batch_sync(self, pkt, length, now: float) -> "_DhcpBatchResult":
         """Dispatch + fold — the sync-path pairing (mirrors _run_step for
@@ -1196,6 +1199,33 @@ class Engine:
             qos_stats=np.zeros(QOS_NSTATS, dtype=np.uint32),
             spoof_stats=np.zeros(ANTISPOOF_NSTATS, dtype=np.uint32))
 
+    # the leaves of a step's result that a retire reads on the host, of
+    # whichever program (a result lacks the blocks of stages that are not
+    # compiled in; a DHCP-only batch's flags and zero blocks are host
+    # arrays). A stage's new stats block is named HERE: its copy then
+    # starts at dispatch and its read at the retire crosses nothing. Never
+    # `tables`: they thread to the next step and are donated.
+    _RETIRE_READS = ("verdict", "out_pkt", "out_len", "spoof_violation",
+                     "nat_punt", "dhcp_stats", "nat_stats", "qos_stats",
+                     "spoof_stats", "garden_stats", "pppoe_stats",
+                     "edge_stats", "v6_stats", "qinq_stats")
+
+    def _start_host_copies(self, res) -> None:
+        """Start the device-to-host copy of every output of `res` that its
+        retire will read, now, at dispatch: the runtime orders each copy
+        behind the step that writes it, and by the retire (a beat later on
+        the pipelined loops) `np.asarray` finds the bytes on the host
+        instead of making one blocking round trip an output (0.39-0.45 ms
+        each on the chip whatever it holds, PERF.md §6 PR 37, PR 43). The
+        mirror column only where a sink reads it."""
+        outs = [getattr(res, name, None) for name in self._RETIRE_READS]
+        if self.mirror_sink is not None:
+            outs.append(getattr(res, "mirror", None))
+        outs = [a for a in outs if isinstance(a, jax.Array)]
+        for a in outs:
+            a.copy_to_host_async()
+        tele.prefetched(outs)
+
     def _dispatch_step(self, pkt, length, fa, n: int,
                        now_s, now_us) -> PipelineResult:
         """Enqueue one jitted step (async — outputs are futures). The table
@@ -1219,6 +1249,7 @@ class Engine:
         staged = self._upload_batch(pkt[:b], length[:b], fa[:b])
         res: PipelineResult = self._step(self.tables, upd, *staged,
                                          now_s, now_us)
+        self._start_host_copies(res)
         self.tables = res.tables
         self.stats.batches += 1
         return res

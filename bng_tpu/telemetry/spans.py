@@ -87,7 +87,13 @@ Two granularities:
   blocks on `out`, says `device_down(tok)` and returns the origin of the
   `fetch` lap that follows; disarmed it returns None and forces nothing.
   `fetched(t0, *outs)` closes that lap: `xfer` with the bytes and the
-  count of the outputs that live on a device.
+  count of the outputs that live on a device and whose copy to the host
+  had not been started. `prefetched(outs)` is the dispatch's side of it
+  (PR 43): the engine has started the host copy of a step's outputs, a
+  fifth count `prefetch_calls` takes them and the Tracer remembers them
+  (weakly, by identity) until the retire's `fetched` leaves them out. The
+  `fetch` lap still times the reads: microseconds over a copy that has
+  landed, a long lap over one still in flight.
 - `span(stage)` — context-manager sugar for coarse paths (CLI, tests).
 
 Tiling (the one clock inside the loop): `beat_begin()` / `beat_end()`
@@ -122,6 +128,7 @@ from __future__ import annotations
 import json
 import os
 import time
+import weakref
 from collections import deque
 
 import numpy as np
@@ -237,6 +244,11 @@ class Tracer:
         # and bytes by direction, [upload, fetch]
         self.xfer_calls = [0, 0]
         self.xfer_bytes = [0, 0]
+        # outputs whose copy to the host was started at dispatch
+        # (prefetched): counted here and not as fetch calls; id -> the
+        # array, held weakly, so one that is never retired leaves nothing
+        self.prefetch_calls = 0
+        self._prefetched = weakref.WeakValueDictionary()
         self._frozen: dict | None = None  # sums() as finish() left them
 
     # -- batch records ----------------------------------------------------
@@ -322,6 +334,17 @@ class Tracer:
         self.lap(stage, t0, tok)
         self.xfer_calls[stage - UPLOAD] += calls
         self.xfer_bytes[stage - UPLOAD] += nbytes
+
+    def prefetched(self, outs) -> None:
+        """The host copy of each of `outs` was started at dispatch."""
+        self.prefetch_calls += len(outs)
+        for a in outs:
+            self._prefetched[id(a)] = a
+
+    def was_prefetched(self, a) -> bool:
+        """`a` is an output `prefetched` was handed (forgotten here: a
+        retire reads an output once)."""
+        return self._prefetched.pop(id(a), None) is a
 
     def stamp(self, stage: int, tok: int | None = None) -> None:
         """Point event: ns offset of reaching `stage` within the open
@@ -605,7 +628,8 @@ class Tracer:
             "xfer": {"upload_calls": int(self.xfer_calls[0]),
                      "upload_bytes": int(self.xfer_bytes[0]),
                      "fetch_calls": int(self.xfer_calls[1]),
-                     "fetch_bytes": int(self.xfer_bytes[1])},
+                     "fetch_bytes": int(self.xfer_bytes[1]),
+                     "prefetch_calls": int(self.prefetch_calls)},
         }
 
     def write_events(self, path: str) -> None:
@@ -696,15 +720,27 @@ def ready(out, tok: int | None = None) -> int | None:
 
 def fetched(t0: int | None, *outs, tok: int | None = None) -> None:
     """Close the `fetch` span over the reads of `outs`, a step's outputs:
-    `xfer` with the bytes and the count of those that live on a device (a
-    host array among them, as a DHCP-only batch's punt flags are, crosses
-    nothing; None is skipped). Disarmed: global load + None compare, and no
-    `nbytes` is computed."""
+    `xfer` with the bytes and the count of those that live on a device and
+    were not `prefetched` (a host array among them, as a DHCP-only batch's
+    punt flags are, crosses nothing; None is skipped; one whose copy was
+    started at dispatch is read from the host). Disarmed: global load +
+    None compare, and no `nbytes` is computed."""
     if _ACTIVE is None or t0 is None:
         return
-    on_device = [a for a in outs if hasattr(a, "block_until_ready")]
+    on_device = [a for a in outs if hasattr(a, "block_until_ready")
+                 and not _ACTIVE.was_prefetched(a)]
     _ACTIVE.xfer(FETCH, t0, sum(int(a.nbytes) for a in on_device),
                  len(on_device), tok)
+
+
+def prefetched(outs) -> None:
+    """Count `outs`, a dispatched step's outputs whose copy to the host
+    the engine has just started, as `prefetch_calls`, and remember them so
+    that `fetched` does not count their reads as crossings. Disarmed:
+    global load + None compare."""
+    if _ACTIVE is None:
+        return
+    _ACTIVE.prefetched(outs)
 
 
 def stamp(stage: int, tok: int | None = None) -> None:

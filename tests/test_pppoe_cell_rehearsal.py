@@ -34,7 +34,8 @@ FILES = ("pppoe_step.device_p50_us", "pppoe.loop_us_per_frame",
 # `pppoe.frames_per_step`, which the benchmark does not have, is dropped in
 COUNTERS = ("pppoe.decap_per_step", "pppoe.encap_per_step",
             "pppoe.miss_per_step", "pppoe.tick_ms_per_s",
-            "wire.upload_calls_per_step", "wire.fetch_calls_per_step")
+            "wire.upload_calls_per_step", "wire.fetch_calls_per_step",
+            "wire.prefetch_calls_per_step")
 FRAMES = {"name": "pppoe.frames_per_step", "unit": "frames",
           "better": "higher", "source": "program_counter",
           "layer": "engine (runtime/engine.py)", "moves": "served_kpps",
@@ -138,10 +139,11 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     assert got["pppoe.tick_ms_per_s"]["value"] > 0
     # a step's crossings: the staged window up; a retire reads verdict,
     # out_pkt, out_len, the violation and punt flags and six stats blocks
-    # (dhcp, nat, qos, spoof, garden, pppoe), the window's last one after
-    # the Tracer is disarmed
+    # (dhcp, nat, qos, spoof, garden, pppoe): since PR 43 each one's copy
+    # was started at its step's dispatch, so the reads cross nothing
     assert got["wire.upload_calls_per_step"]["value"] == 3
-    assert got["wire.fetch_calls_per_step"]["value"] == \
+    assert got["wire.fetch_calls_per_step"]["value"] == 0
+    assert got["wire.prefetch_calls_per_step"]["value"] == \
         pytest.approx(3 + 2 + 6, abs=0.25)
 
 

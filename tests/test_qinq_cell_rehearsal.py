@@ -44,6 +44,12 @@ DROPPED = [
      "moves": "served_kpps", "cells": [CELL],
      "read": {"kind": "counter", "path": "engine.trace.xfer.fetch_calls",
               "per": "engine.batches"}},
+    {"name": "qinq.prefetch_calls_per_step", "unit": "calls",
+     "better": "higher", "source": "program_counter",
+     "layer": "engine (runtime/engine.py)", "moves": "served_kpps",
+     "cells": [CELL],
+     "read": {"kind": "counter", "path": "engine.trace.xfer.prefetch_calls",
+              "per": "engine.batches"}},
 ]
 SIZES = {"subscribers": 4096, "nat_subscribers": 1024,
          "flows_per_nat_subscriber": 2, "pppoe_sessions": 256}
@@ -141,8 +147,10 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     frames = got["qinq.frames_per_step"]["value"]  # 5% of them DHCP
     assert 0.90 * frames < push + pop < frames <= 1024
     # a retire's reads: P's eleven (verdict, out_pkt, out_len, two flag
-    # columns, six stats blocks) and the stage's one block more
-    assert got["qinq.fetch_calls_per_step"]["value"] == \
+    # columns, six stats blocks) and the stage's one block more, each
+    # one's copy started at its step's dispatch since PR 43
+    assert got["qinq.fetch_calls_per_step"]["value"] == 0
+    assert got["qinq.prefetch_calls_per_step"]["value"] == \
         pytest.approx(3 + 2 + 7, abs=0.25)
 
 

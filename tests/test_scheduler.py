@@ -389,7 +389,8 @@ class TestTracedLoop:
             bulk_batch=8, bulk_depth=2, express_batch=4,
             express_device_index=-1), clock=clock)
         assert set(sched.stats_snapshot()["trace"]["xfer"]) == {
-            "upload_calls", "upload_bytes", "fetch_calls", "fetch_bytes"}
+            "upload_calls", "upload_bytes", "fetch_calls", "fetch_bytes",
+            "prefetch_calls"}
         for k in range(2):  # compile, and place the dense arrays once
             self._drive(sched, clock, 100 * k, n_bulk=8)
         sched.flush()
@@ -407,15 +408,15 @@ class TestTracedLoop:
         assert x["upload_calls"] == 3 + 2
         assert x["upload_bytes"] == 8 * (L + 4 + 1) + 4 * XD_WORDS * 4 + 4
         # bulk retire: verdict, out_len, punt, violation inside
-        # `device_wait`, then _fold_stats' four blocks; out_pkt stays on
-        # the chip (no lane of these frames is TX or FWD). Express retire:
-        # the verdict block (written over the descriptor rows), and the
-        # one stats block the program returns
-        assert x["fetch_calls"] == (4 + 4) + (1 + 1)
-        stats = sum(4 * len(getattr(engine.stats, k))
-                    for k in ("dhcp", "nat", "qos", "spoof"))
-        assert x["fetch_bytes"] == (8 * (4 + 4 + 1 + 1) + stats
-                                    + 4 * XD_WORDS * 4
+        # `device_wait`, then _fold_stats' four blocks: since PR 43 the
+        # copy of each was started at dispatch (out_pkt's too, which no
+        # lane of these frames needs: none is TX or FWD), so they cross
+        # nothing at the retire. Express retire: the verdict block
+        # (written over the descriptor rows), and the one stats block the
+        # program returns, forced as before
+        assert x["prefetch_calls"] == 5 + 4
+        assert x["fetch_calls"] == 1 + 1
+        assert x["fetch_bytes"] == (4 * XD_WORDS * 4
                                     + 4 * len(engine.stats.dhcp))
         lanes = {}
         for stage, lane, _t0, _dur in tr.events:

@@ -309,7 +309,7 @@ def test_an_armed_sharded_window_counts_the_crossings_the_code_makes():
     assert x["upload_calls"] == 3
     # the retire reads ten outputs (verdict, punt, violation, five stats
     # blocks with the garden's, out_pkt, out_len). The drain: none
-    assert x["fetch_calls"] == 10
+    assert x["fetch_calls"] == 10 and x["prefetch_calls"] == 0
     B = cl.n * cl.b
     retire = B * (4 + 1 + 1 + 2048 + 4)  # verdict, punt, viol, out_pkt, len
     stats = 4 * sum(len(cl.stats[k])  # five psum'd blocks, u32 on the mesh

@@ -662,7 +662,8 @@ class TestBeatTiling:
         assert (nested["stage_ns"]["fetch"], both["stage_ns"]["fetch"]) == \
             (100, 200)
         assert nested["xfer"] == {"upload_calls": 3, "upload_bytes": 4_096,
-                                  "fetch_calls": 2, "fetch_bytes": 512}
+                                  "fetch_calls": 2, "fetch_bytes": 512,
+                                  "prefetch_calls": 0}
         assert both["xfer"]["fetch_calls"] == 6
         assert both["xfer"]["fetch_bytes"] == 512 + 64
 
@@ -869,7 +870,8 @@ class TestDeviceOccupancy:
         assert sum(ch.values()) == split["beat_starved_ns"] + ch["outside"]
 
     def test_xfer_counts_are_always_served_armed_and_frozen_at_disarm(self):
-        keys = {"upload_calls", "upload_bytes", "fetch_calls", "fetch_bytes"}
+        keys = {"upload_calls", "upload_bytes", "fetch_calls", "fetch_bytes",
+                "prefetch_calls"}
         assert set(spans._ZERO_SUMS["xfer"]) == keys
         assert not any(spans._ZERO_SUMS["xfer"].values())
         clk = _Clock()
@@ -885,7 +887,8 @@ class TestDeviceOccupancy:
         armed = spans.trace_sums()
         assert armed["xfer"] == {"upload_calls": 3,
                                  "upload_bytes": 12_623_872,
-                                 "fetch_calls": 1, "fetch_bytes": 8_192}
+                                 "fetch_calls": 1, "fetch_bytes": 8_192,
+                                 "prefetch_calls": 0}
         assert armed["stage_ns"]["upload"] == 50
         assert armed["stage_ns"]["fetch"] == 30
         spans.disarm()
@@ -923,7 +926,8 @@ class TestDeviceOccupancy:
         clk.now = 4_500
         spans.fetched(4_000, out, np.zeros(8, np.uint32), None, out, tok=tok)
         assert tr.sums()["xfer"] == {"upload_calls": 0, "upload_bytes": 0,
-                                     "fetch_calls": 2, "fetch_bytes": 192}
+                                     "fetch_calls": 2, "fetch_bytes": 192,
+                                     "prefetch_calls": 0}
         assert tr.sums()["stage_ns"]["fetch"] == 500
         spans.disarm()
         spans.fetched(4_000, out, out)  # disarmed: a no-op

@@ -39,7 +39,7 @@ COUNTERS = ("wire.masked_lanes_per_step", "engine.drain_built_per_step",
             "wire.ring_us_per_frame", "wire.upload_calls_per_step",
             "wire.fetch_calls_per_step", "wire.upload_kb_per_step",
             "wire.fetch_kb_per_step", "wire.drain_us_per_step",
-            "wire.lanes_per_step")
+            "wire.lanes_per_step", "wire.prefetch_calls_per_step")
 
 
 def _write(path, obj):
@@ -128,8 +128,7 @@ def test_the_hit_balance_holds_across_the_pools_wrap(wrap_dir, capsys, seed,
         # so a step uploads its staged window and nothing else (packet
         # slots, lengths, access flags), and a retire reads ten arrays:
         # verdict, out_pkt, out_len; violation and punt flags; five stats
-        # blocks (dhcp, nat, qos, spoof, garden). The window's last
-        # dispatch retires after the Tracer is disarmed: ten reads short
+        # blocks (dhcp, nat, qos, spoof, garden)
         assert got["wire.upload_calls_per_step"]["value"] == 3
         # the windows crossed rungs: full ones (1,024 frames) took the 2,048
         # program, short ones a narrower rung, so the mean is under 2,048
@@ -140,10 +139,12 @@ def test_the_hit_balance_holds_across_the_pools_wrap(wrap_dir, capsys, seed,
         assert got["wire.frames_per_step"]["value"] < lanes < 2048
         assert got["wire.upload_kb_per_step"]["value"] == pytest.approx(
             lanes * (1536 + 4 + 1) / 1024, rel=1e-9)
-        assert got["wire.fetch_calls_per_step"]["value"] == \
+        # since PR 43 the copy of each of the ten is started when its step
+        # is dispatched: none is a blocking crossing at the retire
+        assert got["wire.prefetch_calls_per_step"]["value"] == \
             pytest.approx(3 + 2 + 5, abs=0.25)
-        assert got["wire.fetch_kb_per_step"]["value"] == \
-            pytest.approx(lanes * (4 + 1536 + 4 + 1 + 1) / 1024, rel=0.03)
+        assert got["wire.fetch_calls_per_step"]["value"] == 0
+        assert got["wire.fetch_kb_per_step"]["value"] == 0
         assert got["wire.drain_us_per_step"]["value"] > 0
 
 
