@@ -25,7 +25,12 @@ SURVEY.md §2.3, with ICI collectives):
 Host side: ShardedCluster owns one host-table stack per shard, routes
 control-plane writes to the owner shard (DHCP tables by key hash; NAT/QoS/
 spoof by the subscriber-affinity shard), and stacks the per-shard device
-arrays with a leading mesh dimension.
+arrays with a leading mesh dimension. Its serving loop
+(`process_ring_pipelined`) is a beat of: assemble a window off the
+steered ring, place it over the mesh, drain the host's table writes,
+dispatch the step and start every output's copy to the host
+(`_start_host_copies`), then retire the window dispatched a beat before
+from host memory (`_retire`: the reads, `ring.complete`, the slow path).
 """
 
 from __future__ import annotations
@@ -43,7 +48,7 @@ from bng_tpu.edge.tables import EdgeTables
 from bng_tpu.ops.pipeline import PipelineGeom, PipelineTables, pipeline_step
 from bng_tpu.ops.table import TableGeom, shard_owner
 from bng_tpu.runtime.engine import (AntispoofTables, GardenTables, QoSTables,
-                                    _apply_all_updates)
+                                    _apply_all_updates, start_host_copies)
 from bng_tpu.runtime.tables import (FastPathTables, FastPathUpdates,
                                     PPPoEFastPathTables)
 from bng_tpu.telemetry import spans as tele
@@ -85,6 +90,26 @@ def _sharded_geom(geom: PipelineGeom, n: int) -> PipelineGeom:
         cid=geom.dhcp.cid._replace(axis=AXIS, n_shards=n),
     )
     return geom._replace(dhcp=dhcp)
+
+
+def _step_out_names(geom: PipelineGeom) -> tuple[str, ...]:
+    """The tuple `_sharded_step_jit`'s program returns, leaf by leaf in its
+    order (`local_step`'s `out`). A stage's new output is named HERE and
+    nowhere else for its host copy to start at dispatch
+    (`ShardedCluster._start_host_copies`)."""
+    names = ("verdict", "out_pkt", "out_len", "tables", "dhcp_stats",
+             "nat_stats", "qos_stats", "spoof_stats", "nat_punt", "violation")
+    if geom.garden is not None:
+        names += ("garden_stats",)
+    if geom.pppoe is not None:
+        names += ("pppoe_stats",)
+    if geom.tap is not None:
+        names += ("mirror", "edge_stats")
+    return names
+
+
+# the same for `_sharded_dhcp_jit`'s program
+_DHCP_OUT_NAMES = ("tables", "is_reply", "out_pkt", "out_len", "stats")
 
 
 @functools.lru_cache(maxsize=4)
@@ -182,7 +207,10 @@ class ShardTelemetry:
     there is no per-shard latency to tell apart: the loop's times are
     the Tracer's (telemetry/spans.py, lane `sharded`: ring, pack, drain,
     dispatch, device, device_wait, reply, tx), stamped once a step by the
-    loop itself, and the snapshot carries the Tracer's tiling and
+    loop itself (`dispatch` holds the start of every output's copy to the
+    host, `device_wait` the retire's reads of what has landed: `fetch`
+    times them and counts no crossing, `xfer.prefetch_calls` counts the
+    starts), and the snapshot carries the Tracer's tiling and
     device-occupancy sums as its `trace` subtree. What DOES differ per
     shard is the work: verdict counts (tx/fwd/drop/pass), NAT egress-miss
     punts and antispoof violations are counted from each shard's lane
@@ -407,6 +435,7 @@ class ShardedCluster:
         )
         self.table_impl = "xla"  # read by benchmark/lib/app.py selectors()
         self._step = _sharded_step_jit(self.mesh, self.geom, self.n)
+        self._step_out_names = _step_out_names(self.geom)
         self._dhcp_step = _sharded_dhcp_jit(self.mesh, self.geom, self.n)
         self.tables = None  # lazily built on first step / sync()
         # the drain's own: a placed all-padding batch a table kind, and
@@ -937,9 +966,25 @@ class ShardedCluster:
             per_shard.append(t)
         self.tables = self._stack_per_shard(per_shard)
 
+    def _start_host_copies(self, names, outs) -> None:
+        """Start, at dispatch, the device-to-host copy of every leaf of a
+        mesh step's result `outs` (named by `names`) that its retire
+        reads: by then (a beat later on the pipelined loop) each
+        `np.asarray` finds the bytes on the host instead of making one
+        blocking gather from the chips an output (0.87 ms each over four
+        chips, ten a fused step: PERF.md §6 PR 44). A sharded leaf starts
+        one copy an addressable shard, a replicated stats block one. Every
+        leaf but the tables, which thread to the next step and are
+        donated; the mirror column only where a sink reads it."""
+        start_host_copies(
+            a for name, a in zip(names, outs, strict=True)
+            if name != "tables"
+            and (name != "mirror" or self.mirror_sink is not None))
+
     def _dispatch_dhcp(self, pkt, length, now_s: int):
-        """device_put + fastpath drain + donated sharded DHCP step.
-        Outputs stay device futures (async half)."""
+        """device_put + fastpath drain + donated sharded DHCP step, and
+        the start of the outputs' copies to the host. Nothing is waited
+        for: the outputs are futures on their way (async half)."""
         if self.tables is None:
             self.sync_tables()
         t0 = tele.t()
@@ -953,17 +998,20 @@ class ShardedCluster:
         upd = self._drain_fastpath()
         tele.lap(tele.DRAIN, t0)
         t0 = tele.t()
-        dhcp1, is_reply, out_pkt, out_len, stats = self._dhcp_step(
-            self.tables.dhcp, upd, pkt_d, len_d, jnp.uint32(now_s))
-        self.tables = self.tables._replace(dhcp=dhcp1)
+        raw = self._dhcp_step(self.tables.dhcp, upd, pkt_d, len_d,
+                              jnp.uint32(now_s))
+        self.tables = self.tables._replace(dhcp=raw[0])
+        self._start_host_copies(_DHCP_OUT_NAMES, raw)
         tele.lap(tele.DISPATCH, t0)
-        return is_reply, out_pkt, out_len, stats
+        return raw[1:]
 
     def _dispatch_fused(self, pkt, length, from_access, now_s: int,
                         now_us: int):
-        """device_put + full drain + donated sharded step. The ONE owner
-        of the drain-before-tables-read donation invariant; outputs stay
-        device futures (async half)."""
+        """device_put + full drain + donated sharded step, and the start
+        of the outputs' copies to the host. The ONE owner of the
+        drain-before-tables-read donation invariant and of the copies'
+        start (inside the `dispatch` lap: what the starts cost is there);
+        nothing is waited for (async half)."""
         if self.tables is None:
             self.sync_tables()
         t0 = tele.t()
@@ -985,6 +1033,7 @@ class ShardedCluster:
         raw = self._step(self.tables, upd, pkt_d, len_d, fa_d,
                          jnp.uint32(now_s), jnp.uint32(now_us))
         self.tables = raw[3]
+        self._start_host_copies(self._step_out_names, raw)
         tele.lap(tele.DISPATCH, t0)
         return raw
 
@@ -1066,7 +1115,9 @@ class ShardedCluster:
         """Double-buffered multichip ring loop: dispatch batch k+1, THEN
         retire k — host demux overlaps device execution, the same
         two-window design as Engine.process_ring_pipelined (engine.py)
-        which the single-chip path uses to hold latency at load. Requires
+        which the single-chip path uses to hold latency at load. Batch
+        k's outputs have been on their way to the host since its own
+        dispatch, a beat ago: its retire reads host memory. Requires
         ring backends tolerating two outstanding assemble..complete
         windows (bngring MAX_INFLIGHT=2; complete() retires FIFO in this
         loop's order). Call flush_pipeline() before reading final state.
@@ -1130,9 +1181,10 @@ class ShardedCluster:
     def _dispatch_ring_batch(self, ring, pkt, length, flags, got,
                              now_s: int, now_us: int, t_ring=None,
                              prev=None):
-        """Dispatch one assembled window to the mesh WITHOUT forcing the
-        outputs (they stay device futures until _retire) — the async half
-        of the beat, so a pipelined caller overlaps demux with compute.
+        """Dispatch one assembled window to the mesh WITHOUT waiting for
+        its outputs (their copies to the host are started and nothing is
+        read until _retire) — the async half of the beat, so a pipelined
+        caller overlaps demux with compute and with the copies.
         `t_ring` is the Tracer origin of the assemble that filled the
         window; `prev` the window still in flight, probed for readiness
         between the stages. The entry's last field is the batch token."""
@@ -1174,8 +1226,11 @@ class ShardedCluster:
                 tele.device_down(entry[-1])
 
     def _retire(self, entry, slow_path, violation_sink) -> int:
-        """Force a dispatched window's outputs and demux verdicts back to
-        its ring (the sync half of the beat)."""
+        """Read a dispatched window's outputs on the host and demux
+        verdicts back to its ring (the sync half of the beat). Every
+        output's copy was started at its dispatch: a read finds the bytes
+        landed, or blocks until they have (never before the step wrote
+        them), and no frame is completed before its verdict is here."""
         if entry is None:
             return 0
         from bng_tpu.ops.dhcp import ST_HIT

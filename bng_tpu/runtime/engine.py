@@ -154,6 +154,19 @@ def step_rung(n: int, B: int) -> int:
     return B
 
 
+def start_host_copies(outs) -> None:
+    """Start the device-to-host copy of each of `outs` that lives on a
+    device (None and host arrays among them are skipped), and tell the
+    Tracer (`prefetch_calls`): a later `np.asarray` of one finds the bytes
+    on the host, or blocks until they have landed, and starts no crossing
+    of its own. The runtime orders each copy behind the step that writes
+    the output."""
+    outs = [a for a in outs if isinstance(a, jax.Array)]
+    for a in outs:
+        a.copy_to_host_async()
+    tele.prefetched(outs)
+
+
 @functools.lru_cache(maxsize=8)
 def _pipeline_jit(geom: PipelineGeom):
     def step(tables, upd, pkt, length, from_access, now_s, now_us):
@@ -1221,10 +1234,7 @@ class Engine:
         outs = [getattr(res, name, None) for name in self._RETIRE_READS]
         if self.mirror_sink is not None:
             outs.append(getattr(res, "mirror", None))
-        outs = [a for a in outs if isinstance(a, jax.Array)]
-        for a in outs:
-            a.copy_to_host_async()
-        tele.prefetched(outs)
+        start_host_copies(outs)
 
     def _dispatch_step(self, pkt, length, fa, n: int,
                        now_s, now_us) -> PipelineResult:

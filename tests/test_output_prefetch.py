@@ -12,9 +12,8 @@ number from here is a device metric.
     the scheduler's, provisioned and fed by their benchmark kits: shipped
     against stubbed, byte for byte
 (b) `prefetch_calls` a step is the number of on-device leaves the retire
-    reads for the stage set, `fetch_calls` 0 there (the sharded loop, which
-    is not touched, reads `prefetch_calls` 0 and its ten `fetch_calls` as
-    before in tests/test_sharded_serving.py's armed window)
+    reads for the stage set, `fetch_calls` 0 there (the sharded loop's
+    are tests/test_sharded_prefetch.py's, PR 44)
 (c) the fail-closed branch and `flush_pipeline` retire FIFO with a
     prefetched result in flight
 (d) a result that is never retired leaks nothing

@@ -264,8 +264,8 @@ def test_an_armed_sharded_window_counts_the_crossings_the_code_makes():
     update batch that is already placed over the mesh (PR 41: it asks
     each table for its dirty count and compares the dense arrays' bytes),
     so the literals are `pack`'s three placements and the retire's ten
-    reads and nothing of the drain's; a PR that merges the retire's reads
-    lowers them here."""
+    reads, started at the dispatch, and nothing of the drain's; a PR that
+    merges the retire's reads lowers them here."""
     from bng_tpu.control import packets
     from bng_tpu.telemetry import spans as tele
 
@@ -308,13 +308,12 @@ def test_an_armed_sharded_window_counts_the_crossings_the_code_makes():
     # `pack`: packet slots, lengths, access flags. The drain: none
     assert x["upload_calls"] == 3
     # the retire reads ten outputs (verdict, punt, violation, five stats
-    # blocks with the garden's, out_pkt, out_len). The drain: none
-    assert x["fetch_calls"] == 10 and x["prefetch_calls"] == 0
+    # blocks with the garden's, out_pkt, out_len), and the copy of every
+    # one was started at the window's dispatch (PR 44): the reads cross
+    # nothing. The drain: none
+    assert x["prefetch_calls"] == 10
+    assert (x["fetch_calls"], x["fetch_bytes"]) == (0, 0)
     B = cl.n * cl.b
-    retire = B * (4 + 1 + 1 + 2048 + 4)  # verdict, punt, viol, out_pkt, len
-    stats = 4 * sum(len(cl.stats[k])  # five psum'd blocks, u32 on the mesh
-                    for k in ("dhcp", "nat", "qos", "spoof", "garden"))
-    assert x["fetch_bytes"] == retire + stats
     assert x["upload_bytes"] == B * (2048 + 4 + 1)
     by_stage = {}
     for stage, _lane, _t0, _dur in tr.events:

@@ -50,6 +50,12 @@ DROPPED = [
      "cells": [CELL],
      "read": {"kind": "counter", "path": "sharded.trace.xfer.fetch_calls",
               "per": "sharded.trace.batches"}},
+    {"name": "shardnat.prefetch_calls_per_step", "unit": "calls",
+     "better": "higher", "source": "program_counter",
+     "layer": "sharded engine (parallel/sharded.py)", "moves": "served_kpps",
+     "cells": [CELL],
+     "read": {"kind": "counter", "path": "sharded.trace.xfer.prefetch_calls",
+              "per": "sharded.trace.batches"}},
     {"name": "shardnat.steer_hit_per_s", "unit": "frames/s",
      "better": "higher", "source": "program_counter",
      "layer": "ring (runtime/ring.py)", "moves": "served_kpps",
@@ -165,8 +171,10 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
                  "shardnat.dispatch_us_per_step"):
         assert got[name]["value"] > 0, name
     # the two stamps ride the blocks a retire reads already: ten reads a
-    # fused step as in S (a DHCP-only window's four pull the mean down)
-    assert 9.0 < got["shardnat.fetch_calls_per_step"]["value"] <= 10.0
+    # fused step as in S (a DHCP-only window's four pull the mean down),
+    # every one's copy started at its dispatch (PR 44), none a crossing
+    assert 9.0 < got["shardnat.prefetch_calls_per_step"]["value"] <= 10.0
+    assert got["shardnat.fetch_calls_per_step"]["value"] == 0
 
 
 def test_both_controls_fail_by_the_sample(cell_dir, capsys):
