@@ -21,11 +21,10 @@
 #                       scenarios compile the fused pipeline once,
 #                       ~30 s on CPU; ~90-120 s/run total). The long
 #                       soak lives under @pytest.mark.slow.
-#   make verify-perf  — SLO engine + perf-ledger tests (`perf` marker,
-#                       tests/test_slo.py + tests/test_ledger.py, < 30 s)
-#                       then `bng perf gate` against the repo's real
-#                       bench_runs.jsonl (rc contract: 0 clean / 1
-#                       regression / 2 internal / 3 incomparable-cohort).
+#   make verify-perf  — SLO engine tests (`perf` marker,
+#                       tests/test_slo.py, < 30 s). The record of the
+#                       system's speed is the benchmark's
+#                       (`benchmark/`, `PERF_LEDGER.jsonl`, `PERF.md`).
 #                       A prerequisite of `verify` (whose tier-1 line
 #                       deselects `perf`; a bare ROADMAP tier-1 run
 #                       still includes it).
@@ -71,8 +70,7 @@
 #                       N->1->N re-shard round-trips + reject paths,
 #                       sharded blue/green swap + crash-at-flip, the
 #                       composed `bng run --shards 2` DORA-and-renewal
-#                       end-to-end, and the ledger n_shards cohort
-#                       identity. A prerequisite of `verify` (whose
+#                       end-to-end. A prerequisite of `verify` (whose
 #                       tier-1 line deselects `sharded`; a bare ROADMAP
 #                       tier-1 run still includes them).
 #   make verify-express — AOT express OFFER-path gate (ISSUE 13):
@@ -82,10 +80,9 @@
 #                       reply identity vs the codec-built reply, AOT
 #                       cache hit-without-retrace and loud-miss
 #                       fallback (counter + flight dump + ring-meta
-#                       program identity), ledger express_path
-#                       identity, and the SLO device-budget smoke. A
-#                       prerequisite of `verify` (whose tier-1 line
-#                       deselects `express`).
+#                       program identity), and the SLO device-budget
+#                       smoke. A prerequisite of `verify` (whose tier-1
+#                       line deselects `express`).
 #   make verify-hostpath — vectorized host serving path (ISSUE 14):
 #                       scalar-vs-vector byte identity over the frame
 #                       corpus (classify/steer/peek kernels, PyRing
@@ -228,10 +225,8 @@ verify-storm:
 verify-perf:
 	set -o pipefail; \
 	timeout -k 10 30 env JAX_PLATFORMS=cpu \
-	$(PY) -m pytest tests/test_slo.py tests/test_ledger.py \
+	$(PY) -m pytest tests/test_slo.py \
 	  $(PYTEST_FLAGS) -m 'perf and not slow' \
-	&& timeout -k 10 30 env JAX_PLATFORMS=cpu \
-	$(PY) -m bng_tpu.cli perf gate --ledger bench_runs.jsonl \
 	&& echo "verify-perf OK"
 
 verify-ops:

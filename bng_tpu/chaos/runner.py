@@ -169,38 +169,6 @@ def run_report(seed: int = 1, names: list[str] | None = None,
     return report
 
 
-def bench_lines(report: dict) -> list[dict]:
-    """One diffable bench_runs.jsonl line per scenario: the
-    scenario/shed/degraded triple the loadtest BenchmarkResult also
-    carries, so storm runs and load runs diff with the same tooling.
-    (Wallclock stamps are the appender's job — these lines stay
-    deterministic.)"""
-    lines = []
-    for name, r in sorted(report.get("scenarios", {}).items()):
-        degraded = {}
-        for key, label in (("counted_block", "nat_block"),
-                           ("counted_port", "nat_port"),
-                           ("blocks_refused", "nat_block_refused")):
-            if r.get(key):
-                degraded[label] = r[key]
-        line = {
-            "metric": "storm", "scenario": name,
-            "ok": bool(r.get("ok", False)),
-            "seed": r.get("seed"),
-            "shed": dict(r.get("shed", {})),
-            "degraded": degraded,
-            "violations": dict(r.get("violations", {})),
-        }
-        # the SLO verdict (telemetry/slo.py check_budget) rides every
-        # storm bench line so the perf gate's consumers see WHICH stage
-        # blew its envelope, not just a boolean
-        if isinstance(r.get("budget"), dict):
-            line["slo"] = {"ok": bool(r["budget"].get("ok", False)),
-                           "breaches": list(r["budget"].get("breaches", ()))}
-        lines.append(line)
-    return lines
-
-
 def canonical_json(report: dict) -> str:
     """Byte-deterministic serialization (sorted keys, fixed separators)
     — the string two same-seed runs are compared on."""

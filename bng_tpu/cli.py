@@ -2653,39 +2653,10 @@ def run_loadtest(args) -> int:
     if tracer is not None:
         # SLO verdict over the per-stage breakdown (telemetry/slo.py):
         # the same vocabulary the storm budgets and `bng run`'s live
-        # monitor gate on, persisted so the lines are gate-consumable
+        # monitor gate on; it rides the JSON report
         from bng_tpu.telemetry import slo as slo_mod
 
         res.slo = slo_mod.evaluate(tracer.breakdown(lanes=True))
-    if getattr(args, "bench_log", ""):
-        # schema'd ledger line (telemetry/ledger.py): stage_breakdown +
-        # SLO verdict + env fingerprint ride every loadtest run so
-        # `bng perf gate` can trend it like a bench line
-        from bng_tpu.telemetry import ledger as ledger_mod
-
-        try:
-            ledger_mod.append(args.bench_log, {
-                "metric": "loadtest req/s",
-                "value": round(res.rps, 1),
-                "unit": "req/s",
-                "scenario": res.scenario,
-                "batch": args.batch_size,
-                "subscribers": args.macs,
-                "workers": workers,
-                "program": res.program,
-                "latency_p99_us": round(res.latency_p99_us, 1),
-                "request_p99_us": res.request_p99_us,
-                "shed": res.shed,
-                "degraded": res.degraded,
-                # only present on traced runs: an empty dict would read
-                # as "instrumentation on, every stage vanished"
-                **({"slo": res.slo, "stage_breakdown": stage_breakdown}
-                   if tracer is not None else {}),
-                "env": ledger_mod.environment_fingerprint(),
-            })
-        except OSError as e:
-            print(f"loadtest: bench-log append failed: {e}",
-                  file=sys.stderr)
     if args.json_out:
         out = res.to_dict()
         if fleet is not None:
@@ -3013,8 +2984,8 @@ def run_chaos(args) -> int:
     from bng_tpu.utils.jaxenv import force_cpu
 
     force_cpu(8)
-    from bng_tpu.chaos.runner import (bench_lines, canonical_json,
-                                      run_report, scenario_catalog)
+    from bng_tpu.chaos.runner import (canonical_json, run_report,
+                                      scenario_catalog)
 
     if getattr(args, "list", False):
         for name, desc in scenario_catalog():
@@ -3038,18 +3009,6 @@ def run_chaos(args) -> int:
     if args.out:
         with open(args.out, "w") as f:
             f.write(text + "\n")
-    if args.bench_log:
-        # diffable per-scenario lines in the perf ledger; the
-        # wallclock/run_id/schema stamp lives only in the appender
-        # (telemetry/ledger.py), never in the compared report bytes
-        from bng_tpu.telemetry import ledger as ledger_mod
-
-        try:
-            for line in bench_lines(report):
-                ledger_mod.append(args.bench_log, line)
-        except OSError as e:
-            print(f"chaos run: bench-log append failed: {e}",
-                  file=sys.stderr)
     print(text)
     return 0 if report["ok"] else 1
 
@@ -3325,67 +3284,6 @@ def run_cluster(args) -> int:
         coord.close()
 
 
-def run_perf(args) -> int:
-    """`bng perf gate|import` — the perf-regression ledger verbs
-    (telemetry/ledger.py; no jax import, runs cold in milliseconds).
-
-    gate: robust per-stage trend regression detection for the newest
-    ledger line against its last-K COMPARABLE predecessors (same
-    metric + backend class + device kind + batch geometry — a
-    CPU-fallback run is never scored against a TPU cohort). rc contract:
-    0 clean / 1 regression (stderr names the stage) / 2 internal /
-    3 incomparable cohort.
-
-    import: one-shot normalizer migrating pre-schema bench_runs.jsonl
-    lines to the current schema (schema_version 0 tag, stable legacy
-    run_ids, best-effort env fingerprint from the `device` field)."""
-    from bng_tpu.telemetry import ledger as ledger_mod
-
-    path = args.ledger or ledger_mod.default_ledger_path()
-    if args.perf_cmd == "import":
-        try:
-            lines = ledger_mod.read(path)
-        except OSError as e:
-            print(f"perf import: cannot read {path}: {e}", file=sys.stderr)
-            return 2
-        migrated = ledger_mod.import_legacy(lines)
-        n_legacy = sum(1 for ln in migrated
-                       if ln.get("schema_version") == 0)
-        out_path = args.out
-        if args.in_place:
-            out_path = path
-            backup = path + ".bak"
-            import shutil
-
-            shutil.copyfile(path, backup)
-            print(f"perf import: backup at {backup}", file=sys.stderr)
-        if not out_path:
-            for ln in migrated:
-                print(json.dumps(ln))
-        else:
-            with open(out_path, "w") as f:
-                for ln in migrated:
-                    f.write(json.dumps(ln) + "\n")
-        print(f"perf import: {len(migrated)} lines "
-              f"({n_legacy} tagged schema_version 0)"
-              + (f" -> {out_path}" if out_path else " -> stdout"),
-              file=sys.stderr)
-        return 0
-
-    # gate
-    rep = ledger_mod.gate_file(
-        path, last_k=args.last_k, min_cohort=args.min_cohort,
-        include_legacy=not args.no_legacy, metric=args.metric)
-    if args.json_out:
-        print(json.dumps(rep.to_dict(), indent=2, sort_keys=True))
-    else:
-        print(rep.format_text())
-    if rep.regressions:
-        names = ", ".join(r["key"] for r in rep.regressions)
-        print(f"perf gate: REGRESSION in {names}", file=sys.stderr)
-    return rep.rc
-
-
 # ---------------------------------------------------------------------------
 # entry points
 # ---------------------------------------------------------------------------
@@ -3496,11 +3394,6 @@ def main(argv: list[str] | None = None) -> int:
     loadp.add_argument("--trace", action="store_true",
                        help="arm the telemetry tracer for the run and "
                             "report the per-stage latency breakdown")
-    loadp.add_argument("--bench-log", default="",
-                       help="append a schema'd perf-ledger line (stage "
-                            "breakdown + SLO verdict + env fingerprint) "
-                            "to this jsonl file — gate with `bng perf "
-                            "gate --ledger FILE`")
     loadp.add_argument("--wire", nargs="?", const="mem", default=None,
                        metavar="IFNAME",
                        help="drive batches through the full wire loop "
@@ -3595,10 +3488,6 @@ def main(argv: list[str] | None = None) -> int:
                       help="scale factor for the storm scenarios' "
                            "subscriber counts (1.0 = the published "
                            "storms: flash crowd at 100k)")
-    crun.add_argument("--bench-log", default="",
-                      help="append one diffable line per scenario "
-                           "(scenario/shed/degraded) to this jsonl file "
-                           "(bench_runs.jsonl convention)")
     caud = chaos_sub.add_parser(
         "audit", help="build the app from run flags and audit the state "
                       "authorities; rc=2 on any violation")
@@ -3708,44 +3597,6 @@ def main(argv: list[str] | None = None) -> int:
                      "+ delta replay + audited atomic flip (rollback on "
                      "failure)")
 
-    # perf ledger + regression gate (telemetry/ledger.py)
-    perfp = sub.add_parser(
-        "perf", help="perf-regression ledger over bench_runs.jsonl: "
-                     "schema import + per-stage trend gate")
-    perf_sub = perfp.add_subparsers(dest="perf_cmd", required=True)
-    pgate = perf_sub.add_parser(
-        "gate", help="gate the newest ledger line against its last-K "
-                     "comparable runs (median/MAD per stage); rc: 0 "
-                     "clean / 1 regression / 2 internal / 3 "
-                     "incomparable-cohort")
-    pgate.add_argument("--ledger", default="",
-                       help="ledger path (default $BNG_BENCH_LOG or the "
-                            "repo's bench_runs.jsonl)")
-    pgate.add_argument("--metric", default="",
-                       help="gate the newest line of this metric only")
-    pgate.add_argument("--last-k", type=int, default=8,
-                       help="cohort depth: compare against the last K "
-                            "comparable runs")
-    pgate.add_argument("--min-cohort", type=int, default=3,
-                       help="minimum comparable history before the "
-                            "trend gate claims anything")
-    pgate.add_argument("--no-legacy", action="store_true",
-                       help="exclude schema_version<1 (pre-schema) "
-                            "lines from cohorts")
-    pgate.add_argument("--json", action="store_true", dest="json_out")
-    pimp = perf_sub.add_parser(
-        "import", help="one-shot normalizer: migrate pre-schema ledger "
-                       "lines to the current schema (schema_version 0 "
-                       "tag, legacy run_ids, env from `device`)")
-    pimp.add_argument("--ledger", default="",
-                      help="ledger path (default $BNG_BENCH_LOG or the "
-                           "repo's bench_runs.jsonl)")
-    pimp.add_argument("--out", default="",
-                      help="write migrated lines here (default stdout)")
-    pimp.add_argument("--in-place", action="store_true",
-                      help="rewrite the ledger in place (backup at "
-                           "<ledger>.bak)")
-
     checkp = sub.add_parser(
         "check", help="bngcheck: dataplane-invariant static analyzer "
                       "(rc=1 on any non-baselined finding)")
@@ -3776,8 +3627,6 @@ def main(argv: list[str] | None = None) -> int:
         return run_ctl(args)
     if args.command == "trace":
         return run_trace(args)
-    if args.command == "perf":
-        return run_perf(args)
     if args.command in ("run", "stats"):
         app = BNGApp(_config_from_args(args))
         try:

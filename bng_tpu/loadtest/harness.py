@@ -57,7 +57,7 @@ class BenchmarkConfig:
 
     # label for the traffic shape that drove the run ("" = the default
     # steady DORA/renewal mix); storm scenarios stamp their name here so
-    # bench_runs.jsonl lines are diffable per scenario
+    # reports are diffable per scenario
     scenario: str = ""
 
 
@@ -100,7 +100,7 @@ class BenchmarkResult:
     # lane) or "fused_pipeline" — numbers are not comparable across the two
     program: str = ""
     # traffic shape that drove the run (BenchmarkConfig.scenario) — storm
-    # runs stamp their name so bench_runs.jsonl lines diff per scenario
+    # runs stamp their name so reports diff per scenario
     scenario: str = ""
     # admission shed counts by reason (inbox_full / deadline /
     # request_overflow / chaos) — every shed is a COUNTED degradation
@@ -111,8 +111,7 @@ class BenchmarkResult:
     degraded: dict = dataclasses.field(default_factory=dict)
     # per-stage SLO verdict (telemetry/slo.py evaluate over the armed
     # tracer's breakdown): {"ok": bool, "breaches": [stage...]} — empty
-    # when the run was untraced. Rides to_dict so loadtest JSON and
-    # --bench-log ledger lines are perf-gate-consumable.
+    # when the run was untraced. Rides to_dict into the loadtest JSON.
     slo: dict = dataclasses.field(default_factory=dict)
 
     def meets_targets(self, cfg: BenchmarkConfig) -> list[str]:

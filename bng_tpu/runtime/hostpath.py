@@ -64,15 +64,6 @@ def resolved_host_path() -> str:
     return HOST_PATH
 
 
-def current_host_path_label() -> str:
-    """Best-effort label for fingerprints/bench lines — never raises
-    (ledger.environment_fingerprint calls this via sys.modules)."""
-    try:
-        return resolved_host_path()
-    except Exception:  # noqa: BLE001 — a bad env var must not sink a line
-        return HOST_PATH
-
-
 # ---------------------------------------------------------------------------
 # frame staging: list[bytes] -> [n, L] matrix (the SoA entry point)
 # ---------------------------------------------------------------------------

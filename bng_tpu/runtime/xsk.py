@@ -90,15 +90,6 @@ def resolved_wire_pump() -> str:
     return WIRE_PUMP
 
 
-def current_wire_pump_label() -> str:
-    """Best-effort label for fingerprints/bench lines — never raises
-    (ledger.environment_fingerprint calls this via sys.modules)."""
-    try:
-        return resolved_wire_pump()
-    except Exception:  # noqa: BLE001 — a bad env var must not sink a line
-        return WIRE_PUMP
-
-
 def _configure(lib: C.CDLL) -> None:
     lib.bng_xsk_probe.restype = C.c_int
     lib.bng_xsk_probe.argtypes = []

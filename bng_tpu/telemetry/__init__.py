@@ -4,8 +4,7 @@ stage-latency histograms (see spans.py / recorder.py / hist.py).
 Import surface: `from bng_tpu.telemetry import spans` at instrumented
 call sites (module-level hooks, fault_point-style disarmed cost);
 Tracer/FlightRecorder/LatencyHist here for composition roots. The SLO
-engine (slo.py) and the perf ledger/gate (ledger.py) are imported as
-submodules by their consumers — ledger stays jax-free by design.
+engine (slo.py) is imported as a submodule by its consumers.
 """
 
 from bng_tpu.telemetry.hist import LatencyHist, NBUCKETS

@@ -3,10 +3,11 @@ stage vocabulary, evaluated everywhere a stage histogram exists.
 
 The paper's headline targets (>=100 Mpps NAT44+DHCP aggregate, p99
 OFFER device time < 50us) were instrumented by PR 5 but enforced
-nowhere: storm budgets lived as ad-hoc tuples inside chaos/storms.py,
-`bng run` evaluated nothing live, and bench_runs.jsonl was a pile of
-schema-less lines nobody read. This module is the ONE registry those
-consumers now share:
+nowhere: storm budgets lived as ad-hoc tuples inside chaos/storms.py
+and `bng run` evaluated nothing live. This module is the ONE registry
+those consumers now share. It judges a run; it keeps no history (the
+record of the system's speed is the benchmark's, `benchmark/` and
+`PERF_LEDGER.jsonl`):
 
 - ``SLOSpec`` — a per-stage p99 budget (stage name validated against
   spans.STAGE_NAMES at construction: an SLO on a stage that does not
@@ -14,8 +15,7 @@ consumers now share:
   that the unbudgeted stage is where the regression hides).
 - ``DEFAULT_SLOS`` / ``HEADLINE_TARGETS`` — the shipped registry: one
   envelope per stage of the packet lifecycle plus the paper's headline
-  numbers (telemetry/ledger.py's trend gate reports against the same
-  constants).
+  numbers.
 - ``evaluate(breakdown)`` — one-shot p99 verdict over a
   Tracer.breakdown() dict (loadtest reports, bench artifacts).
 - ``SLOMonitor`` — the live half for `bng run`: rolling burn-rate
@@ -48,8 +48,8 @@ from bng_tpu.telemetry import spans as tele
 from bng_tpu.telemetry.hist import counts_percentile
 from bng_tpu.telemetry.spans import LANE_NAMES, STAGE_NAMES
 
-# the paper's headline targets (BASELINE.md / PAPER.md): the trend gate
-# (telemetry/ledger.py) annotates every gated run against these.
+# the paper's headline targets (BASELINE.md / PAPER.md): the `device`
+# envelope below is the first of them.
 HEADLINE_TARGETS = {
     # <50us p99 for the device-only OFFER program @1M subscribers
     "offer_device_only_p99_us": 50.0,

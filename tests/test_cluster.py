@@ -628,7 +628,7 @@ class TestResilienceProbes:
 
 
 # ---------------------------------------------------------------------------
-# metrics + ledger cohort identity
+# metrics
 # ---------------------------------------------------------------------------
 
 class TestClusterMetrics:
@@ -672,36 +672,3 @@ class TestClusterMetrics:
             == {"pppoe", "sharded"}
         m.record_fleet_blocked([])
         assert m.slowpath_fleet_blocked.labeled() == []
-
-
-class TestLedgerInstances:
-    def _line(self, i, n_instances=None, value=10.0):
-        line = {"metric": "serve Mpps", "value": value, "unit": "Mpps",
-                "run_id": f"r{i}", "ts": f"2026-08-0{(i % 7) + 1}",
-                "schema_version": 1, "batch": 1024,
-                "env": {"backend": "tpu", "device_kind": "TPU v4"}}
-        if n_instances is not None:
-            line["n_instances"] = n_instances
-        return line
-
-    def test_legacy_lines_default_to_one_instance(self):
-        from bng_tpu.telemetry.ledger import cohort_key, n_instances
-
-        legacy = self._line(0)
-        assert n_instances(legacy) == 1
-        stamped = self._line(1, n_instances=1)
-        assert cohort_key(legacy) == cohort_key(stamped)
-
-    def test_cluster_lines_refuse_single_instance_history(self, tmp_path):
-        from bng_tpu.telemetry import ledger as lg
-
-        path = tmp_path / "bench_runs.jsonl"
-        for i in range(5):
-            lg.append(str(path), self._line(i))
-        cand = self._line(9, n_instances=4, value=35.0)
-        lg.append(str(path), cand)
-        rep = lg.gate_file(str(path))
-        assert rep.rc == 3  # incomparable cohort, never a regression
-        # the refusal names BOTH sides of the identity
-        note = " ".join(rep.notes)
-        assert "instances=4" in note and "instances=1" in note

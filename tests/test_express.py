@@ -489,36 +489,3 @@ class TestSloSmoke:
             tracer.observe_many(tele.DEVICE, [400.0] * 640)
             verdict = slo.evaluate(tracer.breakdown())
             assert not verdict["ok"] and "device" in verdict["breaches"]
-
-
-# ---------------------------------------------------------------------------
-# ledger identity: the two architectures never trend against each other
-# ---------------------------------------------------------------------------
-
-class TestLedgerIdentity:
-    def _line(self, path, v):
-        return {"metric": "OFFER p99 device-isolated (scheduler)",
-                "value": v, "unit": "us", "device": "TFRT_CPU_0",
-                "express_path": path, "subscribers": 2000,
-                "offer_device_only_p99_us": v,
-                "env": {"platform": "cpu"}}
-
-    def test_express_path_joins_cohort_key(self):
-        from bng_tpu.telemetry import ledger
-
-        a, b = self._line("jit-full", 40.0), self._line("aot-express", 40.0)
-        assert ledger.cohort_key(a) != ledger.cohort_key(b)
-        # unstamped legacy lines ARE the jit-full cohort
-        legacy = self._line("jit-full", 40.0)
-        del legacy["express_path"]
-        assert ledger.cohort_key(legacy) == ledger.cohort_key(a)
-
-    def test_cross_architecture_comparison_refused_naming_both(self):
-        from bng_tpu.telemetry import ledger
-
-        lines = [self._line("jit-full", 40.0 + i) for i in range(4)]
-        lines.append(self._line("aot-express", 400.0))  # would "regress"
-        rep = ledger.gate(lines)
-        assert rep.rc == ledger.GATE_INCOMPARABLE
-        note = " ".join(rep.notes)
-        assert "aot-express" in note and "jit-full" in note

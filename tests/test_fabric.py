@@ -8,8 +8,8 @@ accusation quorum, gray serving-word stall, startup grace, reset), the
 carve plan's host axis, the RADIUS/CoA fan-out through the slow-path
 fleet (MAC-affine auth, relay accounting, degraded cache), the
 accounting spool across failover, the resilience probe wall-time fix,
-the bng_fabric_* metric families, the ledger n_hosts cohort, and the
-two fabric chaos scenarios' byte-determinism.
+the bng_fabric_* metric families, and the two fabric chaos scenarios'
+byte-determinism.
 """
 
 import json
@@ -738,41 +738,6 @@ class TestFabricMetrics:
             assert m.fabric_coa_relayed.value() == 0
         finally:
             fleet.close()
-
-
-class TestLedgerHosts:
-    def _line(self, i, n_hosts=None, value=10.0):
-        line = {"metric": "serve Mpps", "value": value, "unit": "Mpps",
-                "run_id": f"r{i}", "ts": f"2026-08-0{(i % 7) + 1}",
-                "schema_version": 1, "batch": 1024,
-                "env": {"backend": "tpu", "device_kind": "TPU v4"}}
-        if n_hosts is not None:
-            line["n_hosts"] = n_hosts
-        return line
-
-    def test_legacy_lines_default_to_one_host(self):
-        from bng_tpu.telemetry.ledger import cohort_key, n_hosts
-
-        legacy = self._line(0)
-        assert n_hosts(legacy) == 1
-        stamped = self._line(1, n_hosts=1)
-        assert cohort_key(legacy) == cohort_key(stamped)
-        assert n_hosts({"env": {"n_hosts": 3}}) == 3
-        assert n_hosts({"n_hosts": "junk"}) == 1
-
-    def test_multi_host_lines_refuse_single_host_history(self, tmp_path):
-        from bng_tpu.telemetry import ledger as lg
-
-        path = tmp_path / "bench_runs.jsonl"
-        for i in range(5):
-            lg.append(str(path), self._line(i))
-        cand = self._line(9, n_hosts=3, value=35.0)
-        lg.append(str(path), cand)
-        rep = lg.gate_file(str(path))
-        assert rep.rc == 3  # incomparable cohort, never a regression
-        note = " ".join(rep.notes)
-        # the refusal names BOTH widths
-        assert "hosts=3" in note and "hosts=1" in note
 
 
 class TestFabricChaosScenarios:

@@ -11,10 +11,7 @@ Covers the promotion contract end to end:
 * sharded blue/green swap — audited flip, crash-at-flip keeps the
   active cluster;
 * `bng run --shards N` — the composed app serves DORA through the
-  steered ring with zero missteers, checkpoints, swaps, audits;
-* ledger cohort identity — `n_shards` keys the cohort, a sharded
-  candidate against single-device history refuses with both identities
-  named (rc=3).
+  steered ring with zero missteers, checkpoints, swaps, audits.
 
 Every cluster here shares ONE geometry (the cli --shards default at
 shard_nbuckets=64) so the mesh programs compile once per suite run.
@@ -582,55 +579,6 @@ class TestShardedApp:
         assert reply is not None
         assert cl.telemetry.psum_dhcp_hits > hits_before
         assert cl.telemetry.snapshot()["missteer_total"] == 0
-
-
-# ---------------------------------------------------------------------------
-# ledger cohort identity: n_shards
-# ---------------------------------------------------------------------------
-
-@pytest.mark.perf
-class TestLedgerShardIdentity:
-    def _line(self, value, shards=None, devices=None, **extra):
-        ln = {"metric": "Sharded serving Mpps (ring-steered)",
-              "value": value, "unit": "Mpps", "batch": 128,
-              "device": "cpu", "schema_version": 1}
-        if shards is not None:
-            ln["n_shards"] = shards
-        if devices is not None:
-            ln["devices"] = devices
-        ln.update(extra)
-        return ln
-
-    def test_n_shards_defaults_and_legacy_devices(self):
-        from bng_tpu.telemetry import ledger
-
-        assert ledger.n_shards({}) == 1
-        assert ledger.n_shards({"n_shards": 8}) == 8
-        assert ledger.n_shards({"devices": 4}) == 4  # config-5 spelling
-        assert ledger.cohort_key(self._line(1.0, shards=8)) != \
-            ledger.cohort_key(self._line(1.0, shards=1))
-
-    def test_sharded_candidate_refuses_single_device_history(self):
-        """rc=3 with BOTH identities named: an aggregate 8-shard Mpps
-        line never trends against single-device history."""
-        from bng_tpu.telemetry import ledger
-
-        lines = [self._line(1.0) for _ in range(4)]
-        lines.append(self._line(8.0, shards=8))
-        rep = ledger.gate(lines)
-        assert rep.rc == ledger.GATE_INCOMPARABLE
-        note = " ".join(rep.notes)
-        assert "shards=8" in note and "shards=1" in note
-
-    def test_same_shard_cohort_gates_normally(self):
-        from bng_tpu.telemetry import ledger
-
-        lines = [self._line(8.0, shards=8) for _ in range(5)]
-        lines.append(self._line(7.9, shards=8))
-        assert ledger.gate(lines).rc == ledger.GATE_OK
-        lines[-1] = self._line(2.0, shards=8)  # 4x collapse
-        rep = ledger.gate(lines)
-        assert rep.rc == ledger.GATE_REGRESSION
 
 
 # ---------------------------------------------------------------------------

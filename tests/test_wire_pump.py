@@ -336,13 +336,11 @@ class TestFrameAccounting:
         ring.close()
 
 
-class TestSelectorAndLedger:
+class TestSelector:
     def test_env_selector_validates(self, monkeypatch):
         monkeypatch.setattr(xsk, "WIRE_PUMP", "bogus")
         with pytest.raises(ValueError, match="BNG_WIRE_PUMP"):
             xsk.resolved_wire_pump()
-        # the fingerprint label must never raise (ledger best-effort)
-        assert xsk.current_wire_pump_label() == "bogus"
 
     @needs_native
     def test_explicit_bad_path_refused(self):
@@ -351,40 +349,6 @@ class TestSelectorAndLedger:
         with pytest.raises(ValueError, match="unknown wire pump"):
             xsk.WirePump(ring, kern, path="turbo")
         ring.close()
-
-    def test_ledger_cohort_identity(self):
-        """wire_pump joins the cohort key: legacy lines default scalar,
-        and a cross-path trend refuses with rc=3 naming both paths."""
-        from bng_tpu.telemetry import ledger
-
-        def line(i, wp=None, v=100.0):
-            ln = {"schema_version": 1, "run_id": f"r{i}",
-                  "ts": "2026-08-04T00:00:00",
-                  "metric": "wire pump p50 (wire_rx+wire_tx)",
-                  "value": v, "unit": "us", "vs_baseline": 1.0,
-                  "env": {"platform": "cpu", "device_kind": "cpu"}}
-            if wp:
-                ln["wire_pump"] = wp
-            return ln
-
-        assert ledger.wire_pump(line(0)) == "scalar"  # legacy default
-        assert ledger.wire_pump(line(0, wp="vector")) == "vector"
-        env_line = line(0)
-        env_line["env"]["wire_pump"] = "vector"
-        assert ledger.wire_pump(env_line) == "vector"
-        assert ledger.cohort_key(line(0)) != ledger.cohort_key(
-            line(0, wp="vector"))
-
-        hist = [line(i) for i in range(4)]  # legacy scalar history
-        rep = ledger.gate(hist + [line(9, wp="vector", v=10.0)])
-        assert rep.rc == 3
-        joined = " ".join(rep.notes)
-        assert "wire='vector'" in joined and "wire=scalar" in joined
-        # same-path trend still gates normally
-        rep2 = ledger.gate(
-            [line(i, wp="vector") for i in range(4)]
-            + [line(9, wp="vector", v=101.0)])
-        assert rep2.rc == 0
 
 
 class TestWireTelemetry:
