@@ -47,11 +47,13 @@ DRIVE, BETWEEN = "bench.drive_once", "between beats"
 BEAT = "bng.beat"  # the Tracer's anchor annotation (telemetry/spans.py)
 # the Tracer's stages that are laps of the host thread (the rest are fed
 # durations: lane_wait, device, sojourn; the container: beat; or span batches
-# across beats: total). A stage a later program stamps and this list lacks
-# reads as `no_lap`. The original is bng_tpu/utils/profiling.py HOST_LAPS
-HOST_LAPS = ("ring", "admit", "dispatch", "loop_fill", "loop_retire",
-             "device_wait", "fleet", "slow_path", "reply", "ops", "wire_rx",
-             "wire_tx", "pack", "drain", "tx")
+# across beats: total). `upload` and `fetch` (PR 37) close inside `dispatch`
+# and `reply`, and the innermost lap wins. A stage a later program stamps and
+# this list lacks reads as `no_lap`. The original is
+# bng_tpu/utils/profiling.py HOST_LAPS
+HOST_LAPS = ("ring", "admit", "dispatch", "upload", "device_wait", "fetch",
+             "fleet", "slow_path", "reply", "ops", "wire_rx", "wire_tx",
+             "pack", "drain", "tx")
 EVENTS_FILE = "events.json"  # the event log's slice beside a recorded trace
 
 

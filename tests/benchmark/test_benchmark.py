@@ -46,11 +46,20 @@ TINY_ARGV["tiny-wire"] = [a for a in TINY_ARGV["tiny-cgnat"]
 TINY_ARGV["tiny-pppoe"] = TINY_ARGV["tiny-wire"] + ["--pppoe-enabled",
                                                     "--pppoe-auth", "none"]
 TINY_ARGV["tiny-dualstack"] = TINY_ARGV["tiny-wire"] + ["--ipv6-fastpath"]
+# behind S-tag/C-tag pairs, the kit's default making a quarter of the NAT
+# subscribers PPPoE; and tiny-sharded's four shards with the two capacities
+# that size a shard's NAT tables (tiny_dir gives it 4 public addresses, one
+# a shard; the `4` in its cell's name gives it four chips)
+TINY_ARGV["tiny-qinq"] = TINY_ARGV["tiny-pppoe"] + ["--qinq-enabled"]
+TINY_ARGV["tiny4-nat"] = TINY_ARGV["tiny-sharded"] + [
+    "--max-nat-sessions", "512", "--max-nat-subscribers", "128"]
 DROPIN_KIT = "ipoe-dot1q"
 BASE_OF = {"tiny-cgnat": "ipoe-cgnat-1M", "tiny-sharded": "ipoe-sharded4-1M",
            "tiny-wire": "ipoe-cgnat-1M-wire",
            "tiny-pppoe": "pppoe-cgnat-1M-wire",
-           "tiny-dualstack": "dualstack-cgnat-1M-wire"}
+           "tiny-dualstack": "dualstack-cgnat-1M-wire",
+           "tiny-qinq": "qinq-pppoe-cgnat-1M-wire",
+           "tiny4-nat": "ipoe-cgnat-sharded4-1M"}
 # tiny cell -> (the cell its layer files name, config, traffic). A layer file
 # that names a cell without a stand-in here stops `tiny_dir` with a KeyError:
 # a PR that adds a cell adds its stand-in to these three literals
@@ -63,10 +72,14 @@ TINY_CELLS = {
                          "tiny-flood"),
     "tiny-dualstack.flood": ("dualstack-cgnat-1M-wire.flood-64B",
                              "tiny-dualstack", "tiny-flood"),
+    "tiny-qinq.flood": ("qinq-pppoe-cgnat-1M-wire.flood-64B", "tiny-qinq",
+                        "tiny-flood"),
+    "tiny4-nat.flood": ("cgnat-sharded4-1M.flood-64B", "tiny4-nat",
+                        "tiny-flood-32"),
 }
 
-# what the engine's own loop reports in each of its three cells (wire, PPPoE,
-# dual stack): the `wire.*` spans and sums and the loop's counters; and those
+# what the engine's own loop reports in each of its four cells (wire, PPPoE,
+# dual stack, QinQ): the `wire.*` spans and sums and the loop's counters; and those
 # of them that are 0 in a sound rehearsal (no stale lane short of the pool's
 # wrap, no dirty table; the device is seen starved only when a beat finds
 # the ring empty)
@@ -249,9 +262,9 @@ def test_traced_wire_cell_reads_the_ring_lane_and_the_engines_tiling(tiny_dir,
     assert got["wire.unattributed_share"]["value"] < 100.0
     # one window of at most the cap's frames a step, two in flight
     assert 1 <= got["wire.frames_per_step"]["value"] <= 1024
-    assert "wire_step.device_p50_us" not in got
+    assert "fused_step.device_p50_us" not in got
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
-    assert said and "wire_step.device_p50_us" in said[0]
+    assert said and "fused_step.device_p50_us" in said[0]
     sel = [ln for ln in out if ln.startswith("selectors: ")][0]
     assert sel.endswith("ring=NativeRing loop=engine") and "host_path=" in sel
     assert not any(name.startswith("sched.") for name in got)
@@ -606,6 +619,30 @@ def test_layer_files_and_benchmark_json_agree():
     assert peaks["TPU v5 lite"]["hbm_gbytes_per_s"] == 819
 
 
+# -- one file, one entry; the count (PR 48) ------------------------------------
+
+PER_LAYER_LIMIT = 128  # the format's
+LAYER_FILES = {os.path.basename(p)[:-5]: json.load(open(p)) for p in glob.glob(
+    os.path.join(ROOT, "benchmark", "layers", "*.json"))}
+
+
+def test_per_layer_is_within_the_formats_limit():
+    n = len(BENCH["per_layer"])
+    print(f"per_layer holds {n} of {PER_LAYER_LIMIT} entries")
+    assert n <= PER_LAYER_LIMIT and n == len(LAYER_FILES)
+
+
+@pytest.mark.parametrize("name", sorted(LAYER_FILES))
+def test_a_layer_files_cells_are_its_entrys_workloads(name):
+    """One file, one entry: the same cells in the same order, which is the
+    order of `workloads`."""
+    m = LAYER_FILES[name]
+    entries = {e["name"]: e for e in BENCH["per_layer"]}
+    order = [w["name"] for w in BENCH["workloads"]]
+    assert m["name"] == name and entries[name]["workloads"] == m["cells"]
+    assert m["cells"] == [c for c in order if c in m["cells"]] != []
+
+
 # -- the generator's frames ---------------------------------------------------
 
 def test_patched_frames_equal_the_codecs():
@@ -758,7 +795,7 @@ _BENCH = [["bench.drive_once", 0.5e8, 5e8], ["bench.pop", 6e8, 0.1e8],
 _CLOCK = 40_000_000_000_000  # the Tracer's clock where the trace reads 0.5e8
 _BEATS = [[0.5e8, 5e8, _CLOCK, 7], [8.6e8, 2.4e8, _CLOCK + int(8.1e8), 8]]
 _STAGES = ["ring", "dispatch", "device", "slow_path", "reply", "beat", "pack",
-           "drain"]
+           "drain", "upload", "fetch"]
 
 
 def _lap(stage, start, dur, beat):  # a lap by where it lies on the trace
@@ -799,6 +836,16 @@ HAND_MADE = {
         [_lap("slow_path", 6.2e8, 2e8, -1)],
         {"between_beats.slow_path": 0.2, "between beats": 0.04,
          "drive_once.no_lap": 0.44, "bench.pop": 0.01, "bench.push": 0.01}),
+    # a crossing between host and chip is a lap of its own (PR 37), closed
+    # inside `dispatch` or `reply`: the innermost wins, the parent keeps the
+    # rest, and the gap's total is what it was
+    "an upload inside dispatch and a fetch inside reply": (
+        [_lap("reply", 1e8, 1e8, 7), _lap("fetch", 1.2e8, 0.1e8, 7),
+         _lap("dispatch", 2e8, 2e8, 7), _lap("upload", 2.5e8, 0.5e8, 7)],
+        {"drive_once.reply": 0.09, "drive_once.fetch": 0.01,
+         "drive_once.dispatch": 0.15, "drive_once.upload": 0.05,
+         "drive_once.no_lap": 0.14, "bench.pop": 0.01, "bench.push": 0.01,
+         "between beats": 0.24}),
     # no event log: the harness's own names, as before PR 36
     "no event log": (
         [], {"bench.drive_once": 0.44, "between beats": 0.24,
@@ -812,6 +859,17 @@ def test_an_idle_gap_is_named_by_the_programs_stage(case):
     got = _hand(laps)
     assert got == {k: pytest.approx(v) for k, v in want.items()}
     assert all(LABEL.match(k) or k == "between beats" for k in got)
+
+
+def test_every_host_lap_is_a_stage_the_program_stamps():
+    """`HOST_LAPS` filters the event log by name: a name the Tracer dropped
+    (`loop_fill`, `loop_retire`: PR 37) only sits there, and one it gained
+    and the list lacks (`upload`, `fetch`) reads as `no_lap`."""
+    from bng_tpu.telemetry import spans
+
+    assert set(trace.HOST_LAPS) <= set(spans.STAGE_NAMES)
+    assert {"upload", "fetch"} <= set(trace.HOST_LAPS)
+    assert len(set(trace.HOST_LAPS)) == len(trace.HOST_LAPS)
 
 
 def test_a_trace_without_anchors_reads_as_before():

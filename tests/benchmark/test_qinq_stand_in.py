@@ -1,21 +1,25 @@
 """The cell `qinq-pppoe-cgnat-1M-wire.flood-64B` in test_benchmark.py's own
-rehearsal directory, as the stand-in `tiny-qinq.flood` (registered from
-conftest.py): its configuration, its kit and its layer files are found by
-name, at 4,096 subscribers behind a pair each, 128 of them behind NAT and 32
-of those PPPoE. tests/test_qinq_cell_rehearsal.py is the longer rehearsal,
-past the pool's wrap and with both controls. No number from here is a device
-metric."""
+rehearsal directory, as the stand-in `tiny-qinq.flood`: its configuration,
+its kit and its layer files are found by name, at 4,096 subscribers behind a
+pair each, 128 of them behind NAT and 32 of those PPPoE.
+tests/test_qinq_cell_rehearsal.py is the longer rehearsal, past the pool's
+wrap and with both controls. No number from here is a device metric."""
 
-from test_benchmark import BENCH, TINY_CELLS, _run, tiny_dir  # noqa: F401
+from test_benchmark import (BENCH, ENGINE_LOOP, ENGINE_LOOP_ZERO_OK,  # noqa: F401
+                            TINY_CELLS, _run, tiny_dir)
 
 from benchmark.lib import app as applib
 from benchmark.lib import layers
 
 REAL = "qinq-pppoe-cgnat-1M-wire.flood-64B"
+# PR 40's eight, the cell's alone; and, since PR 48, what the engine's loop
+# reports in W, P and D (Q runs the same loop and the same stamps)
+STEP = "qinq_step.device_p50_us"
 FILES = {"qinq.push_per_step", "qinq.pop_per_step", "qinq.miss_per_step",
-         "qinq_step.device_p50_us", "qinq.loop_us_per_frame",
-         "qinq.gen_share", "qinq.beat_p99_us", "qinq.tick_ms_per_s"}
-ZERO_OK = {"qinq.miss_per_step"}  # every subscriber holds a pair
+         STEP, "qinq.loop_us_per_frame", "qinq.gen_share", "qinq.beat_p99_us",
+         "qinq.tick_ms_per_s"} | ENGINE_LOOP
+# every subscriber holds a pair
+ZERO_OK = ENGINE_LOOP_ZERO_OK | {"qinq.miss_per_step"}
 
 
 def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
@@ -72,12 +76,12 @@ def test_the_stand_in_rehearses_traced(tiny_dir, capsys):  # noqa: F811
                for ln in out)
     assert res["compared"]["sample_kinds_missing"] == {"value": 0, "limit": 0}
     got = res["metrics"]
-    assert FILES - {"qinq_step.device_p50_us"} <= set(got)
+    assert FILES - {STEP} <= set(got)
     assert all(got[name]["value"] > 0 for name in FILES - ZERO_OK
                if name in got)
     assert got["qinq.miss_per_step"]["value"] == 0
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
-    assert said and "qinq_step.device_p50_us" in said[0]
+    assert said and STEP in said[0]
 
 
 def test_both_controls_fail_the_stand_in(tiny_dir, capsys):  # noqa: F811

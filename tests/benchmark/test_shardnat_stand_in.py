@@ -1,8 +1,8 @@
 """The cell `cgnat-sharded4-1M.flood-64B` in test_benchmark.py's own
-rehearsal directory, as the stand-in `tiny4-nat.flood` (registered from
-tests/conftest.py; the `4` in its name gives it four chips there): its
-configuration, its kit and its layer files are found by name, at 4,096
-subscribers of whom 128 are behind NAT, one public address a shard.
+rehearsal directory, as the stand-in `tiny4-nat.flood` (the `4` in its name
+gives it four chips there): its configuration, its kit and its layer files
+are found by name, at 4,096 subscribers of whom 128 are behind NAT, one
+public address a shard.
 tests/test_shardnat_cell_rehearsal.py is the longer rehearsal (every
 subscriber behind NAT, 20 addresses a shard, past the pool's wrap, both
 controls, the starved pool). No number from here is a device metric."""
