@@ -1108,7 +1108,7 @@ def production_day(seed: int, scale: float = 1.0) -> dict:
     # slow path; only a BOUND subscriber spoofing a foreign source is a
     # violation — exactly the reference's per-subscriber mode column
     spoof.set_config(MODE_DISABLED, True)
-    edge = EdgeTables(nbuckets=256)
+    edge = EdgeTables(tap_nbuckets=256, route_nbuckets=256)
     policies = PolicyManager([
         QoSPolicy("gold", download_bps=400_000_000,
                   upload_bps=200_000_000),

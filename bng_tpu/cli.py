@@ -244,6 +244,14 @@ class BNGConfig:
     # max_subscribers and filled from leases and sessions that came up
     # over tags. Off: a frame keeps the tags it came with
     qinq_enabled: bool = False
+    # device edge stage (bng_tpu/edge): a wholesale / open-access box. An
+    # upstream data frame leaves with the L2 destination of the next hop
+    # its subscriber's class elects among the routing manager's upstreams
+    # (one route row a subscriber, sized from max_subscribers, written
+    # when a lease commits), and a frame of a subscriber under an active
+    # warrant is mirrored to the intercept manager's exporter (the tap
+    # table holds edge.MAX_WARRANTS rows). Off: no stage, no tables
+    edge_enabled: bool = False
     # wire (AF_XDP attach ladder; runtime/xsk.py)
     wire_if: str = ""  # NIC to bind AF_XDP on ("" = in-memory ring only)
     wire_queue: int = 0
@@ -464,6 +472,7 @@ class BNGApp:
                 (cfg.pppoe_enabled, "pppoe"),
                 (cfg.ipv6_fastpath, "ipv6-fastpath"),
                 (cfg.qinq_enabled, "qinq"),
+                (cfg.edge_enabled, "edge"),
                 (cfg.wire_if, "wire"),
                 (cfg.slowpath_workers > 1, "slowpath-fleet")) if flag]
             if self.sharded_blockers:
@@ -882,11 +891,26 @@ class BNGApp:
 
             qinq_tables = c["qinq_tables"] = QinQFastPathTables(
                 **_sized(cfg.max_subscribers, "nbuckets"))
-            if cfg.slowpath_workers <= 1:
-                # a lease's pair reaches the table where the lease's
-                # other rows are written (the fleet's workers own their
-                # lease books: a named blocker below)
-                dhcp.qinq = qinq_tables
+            # a lease's pair reaches the table where the lease's other
+            # rows are written: the in-process server's (`qinq` is a fleet
+            # blocker below, so no worker ever owns a lease book here)
+            dhcp.qinq = qinq_tables
+        edge_tables = None
+        if cfg.edge_enabled and cfg.shards <= 1:
+            from bng_tpu.control.intercept import InterceptManager
+            from bng_tpu.edge import (MAX_WARRANTS, EdgeTables,
+                                      InterceptTapProgram, MirrorPump)
+
+            # a route row a subscriber, a tap row a warrant: two sizes
+            edge_tables = c["edge_tables"] = EdgeTables(
+                **_sized(cfg.max_subscribers, "route_nbuckets"),
+                **_sized(MAX_WARRANTS, "tap_nbuckets"))
+            c["intercept"] = InterceptManager(clock=self.clock)
+            c["tap_program"] = InterceptTapProgram(
+                edge_tables, c["intercept"], clock=self.clock)
+            # the retire's sink: mirrored lanes -> record_cc -> the
+            # exporter of the warrant's delivery method
+            c["mirror_pump"] = MirrorPump(c["tap_program"])
         if cfg.shards > 1:
             # the cluster IS the dataplane: drive_once feeds its steered
             # ring loop; the slow path is attached per beat (10b)
@@ -896,7 +920,8 @@ class BNGApp:
                 fastpath=fastpath, nat=nat, qos=qos,
                 antispoof=c["antispoof"],
                 garden=garden_tables, pppoe=pppoe_tables, v6=v6_tables,
-                qinq=qinq_tables,
+                qinq=qinq_tables, edge=edge_tables,
+                mirror_sink=c.get("mirror_pump"),
                 batch_size=cfg.batch_size, slow_path=dhcp.handle_frame,
                 clock=self.clock)
             self.log.info("engine built", batch_size=cfg.batch_size,
@@ -1194,6 +1219,7 @@ class BNGApp:
                 (cfg.pppoe_enabled, "pppoe"),
                 (cfg.ipv6_fastpath, "ipv6-fastpath"),
                 (cfg.qinq_enabled, "qinq"),
+                (cfg.edge_enabled, "edge"),
                 (cfg.shards > 1, "sharded")) if flag]
             if blockers:
                 # more than a log line: the degradation is exported as
@@ -1670,6 +1696,34 @@ class BNGApp:
             raise ValueError(
                 f"routing_platform={cfg.routing_platform!r}: "
                 f"expected 'stub' or 'linux'")
+        if edge_tables is not None:
+            # the routing manager's upstreams steer for real: a subscriber's
+            # route row holds the gateway MAC its class elects among them
+            # (the source's RouteSubscriberToISP, one policy route a
+            # subscriber at session start). Upstreams come from
+            # `routing.add_upstream`, their L2 next hops from
+            # `route_program.set_neighbor`, the tables a class may use from
+            # `route_program.class_tables`
+            from bng_tpu.edge import CLASS_CODES, RouteProgram
+
+            route_prog = c["route_program"] = RouteProgram(
+                edge_tables, c["routing"])
+            route_prog.attach()
+            # the in-process server commits every lease: `edge` is a fleet
+            # blocker (step 10b2), so no worker ever owns a lease book here
+            klass_of = {code: name for name, code in CLASS_CODES.items()}
+            prev_edge_hook = dhcp.accounting_hook
+
+            def _edge_lease(event, lease, sid, _rp=route_prog):
+                if prev_edge_hook is not None:
+                    prev_edge_hook(event, lease, sid)
+                if event == "start":
+                    _rp.bind_subscriber(lease.ip, klass_of.get(
+                        lease.client_class, "residential"))
+                elif event == "stop":
+                    _rp.unbind_subscriber(lease.ip)
+
+            dhcp.accounting_hook = _edge_lease
         if cfg.bgp_enabled:
             from bng_tpu.control.routing import (BGPConfig, BGPController,
                                                  vtysh_executor)
@@ -2200,6 +2254,11 @@ class BNGApp:
                 c["cluster"].expire(int(now))
             else:
                 c["engine"].expire(int(now))
+            if "tap_program" in c:
+                # warrants past their window leave the device's tap table,
+                # warrants that came in since the last sweep are armed
+                c["intercept"].expire_warrants(max_reaps=budget)
+                c["tap_program"].sync()
             fleet = c.get("fleet")
             if fleet is not None:
                 # fleet workers own their lease books; the sweep fans
@@ -2350,6 +2409,16 @@ class BNGApp:
                 "refused": qinq_tables.refused,
                 "device": {"push": push, "pop": pop, "miss": miss,
                            "oversize": oversize}}
+        edge_tables = self.components.get("edge_tables")
+        if edge_tables is not None and eng is not None:
+            mirrored, filtered, rewrites, misses = (
+                int(x) for x in eng.stats.edge)
+            out["edge"] = {
+                "routes": edge_tables.route.count,
+                "taps": edge_tables.tap.count,
+                "sink": dict(self.components["mirror_pump"].stats),
+                "device": {"mirrored": mirrored, "filtered": filtered,
+                           "rewrites": rewrites, "route_miss": misses}}
         nat = self.components.get("nat")
         if nat is not None:  # registered only when nat_enabled
             out["nat"] = {"sessions": nat.sessions.count,
@@ -3289,6 +3358,14 @@ def run_cluster(args) -> int:
 # ---------------------------------------------------------------------------
 
 _RUN_FLAG_HELP = {
+    "edge_enabled": "compile the edge stage into the fused step: an "
+                    "upstream data frame leaves for the gateway MAC its "
+                    "subscriber's class elects among the routing manager's "
+                    "upstreams (a route row a subscriber, sized from "
+                    "--max-subscribers), and the frames of a subscriber "
+                    "under an active warrant are mirrored to the intercept "
+                    "manager's exporter; refused by name under --shards and "
+                    "beside a slow-path fleet",
     "batch_size": "the largest window one fused step takes (default "
                   "{default} lanes); a shorter window runs at the "
                   "narrowest rung of the ladder down from it (by 8, floor "

@@ -15,12 +15,12 @@ from bng_tpu.edge.ops import (EDGE_NSTATS, EST_MIRRORED, EST_ROUTE_MISSES,
                               EST_ROUTE_REWRITES, EST_TAP_FILTERED,
                               ROUTE_WORDS, TAP_WORDS, RouteResult, TapResult,
                               route_rewrite, tap_match)
-from bng_tpu.edge.tables import MAX_TAP_FILTERS, EdgeTables
+from bng_tpu.edge.tables import MAX_TAP_FILTERS, MAX_WARRANTS, EdgeTables
 
 __all__ = [
     "CLASS_CODES", "EDGE_NSTATS", "EST_MIRRORED", "EST_ROUTE_MISSES",
     "EST_ROUTE_REWRITES", "EST_TAP_FILTERED", "EdgeTables",
-    "InterceptTapProgram", "MAX_TAP_FILTERS", "MirrorPump", "ROUTE_WORDS",
+    "InterceptTapProgram", "MAX_TAP_FILTERS", "MAX_WARRANTS", "MirrorPump", "ROUTE_WORDS",
     "RouteProgram", "RouteResult", "TAP_WORDS", "TapResult",
     "route_rewrite", "tap_match",
 ]

@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import time
 
+import numpy as np
+
 from bng_tpu.control.intercept import (Direction, InterceptManager, Warrant,
                                        WarrantStatus)
 from bng_tpu.edge.tables import EdgeTables
@@ -213,19 +215,31 @@ class RouteProgram:
             out.append(up)
         return out
 
+    def _ladder(self, klass: str):
+        """The election, for one subscriber or an array of them: the
+        class's eligible upstreams in name order and the running sum of
+        their weights. A subscriber takes the first upstream whose sum is
+        above its hash modulo the last one (`_pick`)."""
+        ups = self._eligible(klass)
+        return ups, np.cumsum([max(1, u.weight) for u in ups], dtype=np.uint32)
+
+    @staticmethod
+    def _pick(acc, h):
+        return np.searchsorted(acc, h % acc[-1], side="right")
+
+    def _row_words(self, up, klass: str):
+        mac = self._neighbors[up.gateway]
+        return (int.from_bytes(mac[:2], "big"), int.from_bytes(mac[2:6], "big"),
+                up.table, CLASS_CODES.get(klass, 0))
+
     def select(self, sub_ip: int, klass: str):
         """(upstream, mac) for a subscriber, or None if nothing routes."""
-        ups = self._eligible(klass)
-        total = sum(max(1, u.weight) for u in ups)
-        if total == 0:
+        ups, acc = self._ladder(klass)
+        if not ups:
             return None
-        h = fnv1a32(int(sub_ip).to_bytes(4, "big")) % total
-        acc = 0
-        for up in ups:
-            acc += max(1, up.weight)
-            if h < acc:
-                return up, self._neighbors[up.gateway]
-        return None  # unreachable
+        h = np.uint32(fnv1a32(int(sub_ip).to_bytes(4, "big")))
+        up = ups[int(self._pick(acc, h))]
+        return up, self._neighbors[up.gateway]
 
     def expected_row(self, sub_ip: int):
         """(mac_hi, mac_lo, table, class_code) the device row must hold
@@ -234,12 +248,7 @@ class RouteProgram:
         if klass is None:
             return None
         sel = self.select(sub_ip, klass)
-        if sel is None:
-            return None
-        up, mac = sel
-        return (int.from_bytes(mac[:2], "big"),
-                int.from_bytes(mac[2:6], "big"),
-                up.table, CLASS_CODES.get(klass, 0))
+        return None if sel is None else self._row_words(sel[0], klass)
 
     # -- binding + recompile -------------------------------------------
     def bind_subscriber(self, ip: str | int,
@@ -251,6 +260,51 @@ class RouteProgram:
         self._bindings[sub] = klass
         self.stats["bound"] += 1
         return self._install(sub) is not None
+
+    def bulk_bind(self, ips, klasses="residential") -> int:
+        """`bind_subscriber` for subscribers that are not bound yet, at the
+        1M-subscriber scale: the same selection (the hash over an array,
+        the class's eligible upstreams once a class) and the same rows as
+        one call each, through `EdgeTables.bulk_set_routes`. `klasses`:
+        one class name for all, or one a subscriber. Returns how many got
+        a row; the rest had nothing eligible (row absent, counted
+        unroutable). As after any bulk build, the next upload is a whole
+        one (`Engine.resync_tables`)."""
+        from bng_tpu.runtime.hostpath import fnv1a32_cols
+
+        ips = np.asarray(ips, dtype=np.uint32)
+        names = ([klasses] * len(ips) if isinstance(klasses, str)
+                 else list(klasses))
+        if len(names) != len(ips):
+            raise ValueError(f"{len(names)} classes for {len(ips)} subscribers")
+        keys = ips.tolist()
+        if len(set(keys)) != len(keys) or (
+                self._bindings and not self._bindings.keys().isdisjoint(keys)):
+            raise ValueError("bulk_bind takes subscribers that are not "
+                             "bound yet, each once")
+        kinds = sorted(set(names))
+        at = {k: i for i, k in enumerate(kinds)}
+        kind = np.fromiter(map(at.__getitem__, names), np.int64, len(names))
+        h = fnv1a32_cols(ips.astype(">u4").view(np.uint8).reshape(-1, 4))
+        routed = np.zeros(len(ips), dtype=bool)
+        # a subscriber's (mac_hi, mac_lo, table, class code)
+        row = np.zeros((len(ips), 4), dtype=np.uint32)
+        for i, klass in enumerate(kinds):
+            ups, acc = self._ladder(klass)
+            if not ups:
+                continue
+            mine = np.nonzero(kind == i)[0]
+            per_up = np.asarray([self._row_words(u, klass) for u in ups],
+                                dtype=np.uint32)
+            routed[mine] = True
+            row[mine] = per_up[self._pick(acc, h[mine])]
+        self._bindings.update(zip(keys, names))
+        self.edge.bulk_set_routes(ips[routed], *row[routed].T)
+        n = int(routed.sum())
+        self.stats["bound"] += len(ips)
+        self.stats["deltas"] += n
+        self.stats["unroutable"] += len(ips) - n
+        return n
 
     def unbind_subscriber(self, ip: str | int) -> bool:
         sub = ip if isinstance(ip, int) else ip_to_u32(ip)

@@ -34,6 +34,8 @@ from bng_tpu.edge.ops import (
 from bng_tpu.ops.table import HostTable, TableGeom, placed
 
 MAX_TAP_FILTERS = 64
+# the warrants `bng run --edge-enabled` sizes its tap table for
+MAX_WARRANTS = 4096
 
 
 class EdgeTables:
@@ -45,17 +47,22 @@ class EdgeTables:
     (they are tiny), exactly like FastPathTables' pools/server arrays.
     """
 
-    def __init__(self, nbuckets: int = 1 << 10, stash: int = 64,
+    def __init__(self, tap_nbuckets: int = 1 << 10,
+                 route_nbuckets: int = 1 << 10, stash: int = 64,
                  update_slots: int = 64,
                  max_filters: int = MAX_TAP_FILTERS):
-        self.tap = HostTable(nbuckets, key_words=1, val_words=TAP_WORDS,
+        # the two tables are sized apart: a tap row a warrant, a route row
+        # a subscriber
+        self.tap = HostTable(tap_nbuckets, key_words=1, val_words=TAP_WORDS,
                              stash=stash, name="edge_tap")
-        self.route = HostTable(nbuckets, key_words=1, val_words=ROUTE_WORDS,
-                               stash=stash, name="edge_route")
+        self.route = HostTable(route_nbuckets, key_words=1,
+                               val_words=ROUTE_WORDS, stash=stash,
+                               name="edge_route")
         self.tap_filters = np.zeros((max_filters, TAP_FILTER_COLS),
                                     dtype=np.uint32)
         self.tap_config = np.zeros((TAP_CONFIG_WORDS,), dtype=np.uint32)
-        self.geom = TableGeom(nbuckets, stash)
+        self.tap_geom = TableGeom(tap_nbuckets, stash)
+        self.route_geom = TableGeom(route_nbuckets, stash)
         self.update_slots = update_slots
         self._armed = 0  # live tap rows (the TC_ARMED predicate source)
 
@@ -125,6 +132,21 @@ class EdgeTables:
         row[RW_TABLE] = table_id
         row[RW_CLASS] = klass
         self.route.insert([subscriber_ip], row)
+
+    def bulk_set_routes(self, subscriber_ips, mac_hi, mac_lo, table_ids,
+                        klasses) -> None:
+        """`set_route` for subscribers that hold no row yet, at the
+        1M-subscriber scale: the same row words, one vectorized build. As
+        after any bulk build, the next upload is a whole one
+        (`Engine.resync_tables`)."""
+        ips = np.asarray(subscriber_ips, dtype=np.uint32)
+        rows = np.zeros((len(ips), ROUTE_WORDS), dtype=np.uint32)
+        rows[:, RW_FLAG] = 1
+        rows[:, RW_MAC_HI] = mac_hi
+        rows[:, RW_MAC_LO] = mac_lo
+        rows[:, RW_TABLE] = table_ids
+        rows[:, RW_CLASS] = klasses
+        self.route.bulk_insert(ips[:, None], rows)
 
     def clear_route(self, subscriber_ip: int) -> bool:
         return self.route.delete([subscriber_ip])

@@ -418,7 +418,8 @@ class ShardedCluster:
         # affinity key, so the ring already steers the matching lanes to
         # the shard holding the row. Optional: a cluster without warrants
         # or route policy compiles the stage out entirely.
-        self.edge = ([EdgeTables(nbuckets=edge_nbuckets)
+        self.edge = ([EdgeTables(tap_nbuckets=edge_nbuckets,
+                                 route_nbuckets=edge_nbuckets)
                       for _ in range(n_shards)] if edge_enabled else None)
         # host retire hook for MIRROR-flagged lanes (lane, frame, wid) —
         # the Engine.mirror_sink analog; wire a MirrorPump here
@@ -430,8 +431,8 @@ class ShardedCluster:
             spoof=self.spoof[0].geom,
             garden=self.garden[0].geom if garden_enabled else None,
             pppoe=self.pppoe[0].geom if pppoe_enabled else None,
-            tap=self.edge[0].geom if edge_enabled else None,
-            route=self.edge[0].geom if edge_enabled else None,
+            tap=self.edge[0].tap_geom if edge_enabled else None,
+            route=self.edge[0].route_geom if edge_enabled else None,
         )
         self.table_impl = "xla"  # read by benchmark/lib/app.py selectors()
         self._step = _sharded_step_jit(self.mesh, self.geom, self.n)

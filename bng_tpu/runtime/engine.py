@@ -35,6 +35,8 @@ from bng_tpu.analysis.sanitize import owned_by
 from bng_tpu.chaos.faults import FaultInjectedError, fault_point
 from bng_tpu.telemetry import spans as tele
 from bng_tpu.control.nat import NATManager, apply_nat_updates
+from bng_tpu.edge.ops import (EST_MIRRORED, EST_ROUTE_MISSES,
+                              EST_ROUTE_REWRITES, EST_TAP_FILTERED)
 from bng_tpu.ops.antispoof import ANTISPOOF_NSTATS, AntispoofGeom
 from bng_tpu.ops.dhcp import NSTATS as DHCP_NSTATS
 from bng_tpu.ops.nat44 import NAT_NSTATS
@@ -593,8 +595,8 @@ class Engine:
             spoof=self.antispoof.geom,
             garden=self.garden.geom if self.garden else None,
             pppoe=self.pppoe.geom if self.pppoe else None,
-            tap=self.edge.geom if self.edge else None,
-            route=self.edge.geom if self.edge else None,
+            tap=self.edge.tap_geom if self.edge else None,
+            route=self.edge.route_geom if self.edge else None,
             v6=self.v6.geom if self.v6 else None,
             qinq=self.qinq.geom if self.qinq else None,
         )
@@ -1356,9 +1358,13 @@ class Engine:
             self.stats.pppoe += ps
             tele.pppoe_lanes(int(ps[PST_DECAP]), int(ps[PST_ENCAP]),
                              int(ps[PST_MISS]))
-        es = getattr(res, "edge_stats", None)
-        if es is not None:
-            self.stats.edge += np.asarray(es, dtype=np.uint64)
+        es_d = getattr(res, "edge_stats", None)
+        if es_d is not None:
+            es = np.asarray(es_d, dtype=np.uint64)
+            self.stats.edge += es
+            tele.edge_lanes(int(es[EST_MIRRORED]), int(es[EST_TAP_FILTERED]),
+                            int(es[EST_ROUTE_REWRITES]),
+                            int(es[EST_ROUTE_MISSES]))
         vs_d = getattr(res, "v6_stats", None)
         if vs_d is not None:
             vs = np.asarray(vs_d, dtype=np.uint64)
@@ -1372,7 +1378,7 @@ class Engine:
             tele.qinq_lanes(int(qs[QQ_PUSH]), int(qs[QQ_POP]),
                             int(qs[QQ_MISS]))
         tele.fetched(t0, res.dhcp_stats, res.nat_stats, res.qos_stats,
-                     res.spoof_stats, gs, ps_d, es, vs_d, qs_d)
+                     res.spoof_stats, gs, ps_d, es_d, vs_d, qs_d)
 
     def _run_step(self, pkt, length, fa, n: int,
                   now_s, now_us) -> PipelineResult:
@@ -1464,12 +1470,14 @@ class Engine:
             if self.violation_sink is not None:
                 self.violation_sink(int(lane), bytes(pkt[lane, : int(length[lane])]))
         if mirw is not None:
+            tm = tele.t()
             for lane in np.nonzero(mirw)[0]:
                 # original ring bytes: interception sees the frame as it
                 # arrived, regardless of the verdict demux above
                 self.mirror_sink(int(lane),
                                  bytes(pkt[lane, : int(length[lane])]),
                                  int(mirw[lane]))
+            tele.lap(tele.MIRROR, tm)
 
         # Drain the slow ring: the slow ring preserves lane order (PASS
         # frames are queued in lane order by complete()), so align pops

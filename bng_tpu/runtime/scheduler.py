@@ -800,8 +800,11 @@ class TieredScheduler:
         out_len = np.asarray(res.out_len)
         punt = np.asarray(res.nat_punt)[:n]
         viol = np.asarray(res.spoof_violation)[:n]
+        mir = (getattr(res, "mirror", None)
+               if eng.mirror_sink is not None else None)
+        mirw = np.asarray(mir)[:n] if mir is not None else None
         tele.fetched(tf, res.verdict, res.out_len, res.nat_punt,
-                     res.spoof_violation)
+                     res.spoof_violation, mir)
         tele.lap(tele.DEVICE_WAIT, t0, entry.trace)
         out_rows = None
         eng._fold_stats(res)
@@ -844,6 +847,13 @@ class TieredScheduler:
                 self._complete(p, LANE_BULK, "slow", replies.get(i), now)
             if viol[i] and eng.violation_sink is not None:
                 eng.violation_sink(i, p.frame)
+        if mirw is not None:
+            tm = tele.t()
+            for i in np.nonzero(mirw)[0]:
+                # the frame as it was submitted, whatever its verdict
+                eng.mirror_sink(int(i), entry.pending[i].frame,
+                                int(mirw[i]))
+            tele.lap(tele.MIRROR, tm, entry.trace)
         tele.lap(tele.REPLY, t0, entry.trace)
         self._trace_sojourn(entry, tele.LANE_BULK_L)
         tele.end_batch(entry.trace, punt=punts)

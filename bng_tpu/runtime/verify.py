@@ -44,6 +44,8 @@ class Geometry(NamedTuple):
     pppoe_nbuckets: int = 0  # PPPoE session tables; 0 = no PPPoE stage
     v6_nbuckets: int = 0  # IPv6 by-address table; 0 = no v6 stage
     qinq_nbuckets: int = 0  # address -> S/C-tag table; 0 = no qinq stage
+    route_nbuckets: int = 0  # next-hop table of the edge stage; 0 = no stage
+    tap_nbuckets: int = 0  # the edge stage's tap table (a row a warrant)
 
 
 TOY = Geometry()
@@ -64,6 +66,9 @@ REAL_1M_V6 = REAL_1M._replace(v6_nbuckets=1 << 19)
 # PPPoE and the access VLANs together, the pair table sized for 1,000,000
 # subscribers (`bng run --pppoe-enabled --qinq-enabled`)
 REAL_1M_QINQ = REAL_1M_PPPOE._replace(qinq_nbuckets=1 << 19)
+# the edge stage compiled in: a route row a subscriber, and the tap table at
+# its default 4,096 warrants (`bng run --edge-enabled`)
+REAL_1M_EDGE = REAL_1M._replace(route_nbuckets=1 << 19, tap_nbuckets=1 << 12)
 
 
 def compile_for(built, sharding=None):
@@ -161,6 +166,7 @@ def _engine(g: Geometry):
     """A single-device Engine over empty tables of geometry `g`, the
     walled-garden gate on (the `bng run` default)."""
     from bng_tpu.control.nat import NATManager
+    from bng_tpu.edge import EdgeTables
     from bng_tpu.runtime.engine import (AntispoofTables, Engine, GardenTables,
                                         QoSTables)
     from bng_tpu.runtime.tables import (PPPoEFastPathTables,
@@ -182,6 +188,9 @@ def _engine(g: Geometry):
             if g.v6_nbuckets else None),
         qinq=(QinQFastPathTables(nbuckets=g.qinq_nbuckets, stash=g.stash)
               if g.qinq_nbuckets else None),
+        edge=(EdgeTables(route_nbuckets=g.route_nbuckets,
+                         tap_nbuckets=g.tap_nbuckets, stash=g.stash)
+              if g.route_nbuckets else None),
         batch_size=g.batch, pkt_slot=g.pkt_slot)
 
 

@@ -150,6 +150,9 @@ DEFAULT_SLOS: tuple[SLOSpec, ...] = (
     SLOSpec("fetch", 200_000.0,
             description="host inside device-to-host reads of a batch's "
                         "outputs, after they were seen ready"),
+    SLOSpec("mirror", 200_000.0,
+            description="host handing a retired batch's mirrored lanes to "
+                        "the intercept sink"),
     SLOSpec("total", 500_000.0, description="batch begin -> end"),
 )
 

@@ -59,13 +59,18 @@ order:
                    step was seen ready: armed, a retire blocks on its
                    first output alone (`ready`), so `device_wait` less
                    its `fetch` children is the wait and `fetch` the copies
+       mirror      the host thread handing a retired step's mirrored lanes
+                   to the intercept sink (`Engine.mirror_sink`): one lap a
+                   retire around the extraction of the lanes whose mirror
+                   word is set and the sink's calls, inside `reply`; only
+                   where a sink is set (`bng run --edge-enabled`)
        total       batch begin -> end (the client-visible wall time)
 
    `upload` and `fetch` are children of the laps that enclose them
    (`dispatch`, `drain`, `pack`; `device_wait`, `reply`) or stand under no
-   parent (`Engine._fold_stats`). A child closes before its parent, so it
-   takes the starvation under it and the parent keeps the rest: a parent
-   stage's `starved_ns` is its SELF share. `stage_ns` stays a plain sum
+   parent (`Engine._fold_stats`); `mirror` is a child of `reply`. A child
+   closes before its parent, so it takes the starvation under it and the
+   parent keeps the rest: a parent stage's `starved_ns` is its SELF share. `stage_ns` stays a plain sum
    of samples (a child's time is in its parent's too).
 
 3. **Tracing is observation.** A span never mutates subsystem state;
@@ -144,11 +149,11 @@ from bng_tpu.telemetry.hist import LatencyHist
 # the `bench` lane; tests/test_slo.py feeds it.
 (RING, ADMIT, LANE_WAIT, DISPATCH, DEVICE, DEVICE_WAIT, FLEET, WORKER, SLOW,
  REPLY, OPS, WIRE_RX, WIRE_TX, BEAT, PACK, DRAIN, TX, SOJOURN, UPLOAD, FETCH,
- TOTAL) = range(21)
+ MIRROR, TOTAL) = range(22)
 STAGE_NAMES = ("ring", "admit", "lane_wait", "dispatch", "device",
                "device_wait", "fleet", "worker", "slow_path", "reply", "ops",
                "wire_rx", "wire_tx", "beat", "pack", "drain", "tx",
-               "sojourn", "upload", "fetch", "total")
+               "sojourn", "upload", "fetch", "mirror", "total")
 NSTAGES = len(STAGE_NAMES)
 
 # lane ids for batch records
@@ -236,6 +241,11 @@ class Tracer:
         # off, and forwarded downstream to a subscriber without a pair
         # (engine.py _fold_stats); 0 in a program without the stage
         self.qinq_push = self.qinq_pop = self.qinq_miss = 0
+        # lanes the device edge stage mirrored for a warrant, matched a tap
+        # and filtered out, rewrote to a next hop, and found no route row
+        # for (engine.py _fold_stats); 0 in a program without the stage
+        self.edge_mirrored = self.edge_filtered = 0
+        self.edge_rewrites = self.edge_route_miss = 0
         # lanes the sharded steps translated (SNAT and DNAT hits, summed
         # over the mesh) and lanes NAT punted to the host (sharded.py
         # _retire); 0 on the one-chip loops
@@ -623,6 +633,10 @@ class Tracer:
             "qinq_push": int(self.qinq_push),
             "qinq_pop": int(self.qinq_pop),
             "qinq_miss": int(self.qinq_miss),
+            "edge_mirrored": int(self.edge_mirrored),
+            "edge_filtered": int(self.edge_filtered),
+            "edge_rewrites": int(self.edge_rewrites),
+            "edge_route_miss": int(self.edge_route_miss),
             "nat_fwd": int(self.nat_fwd),
             "nat_punt": int(self.nat_punt),
             "xfer": {"upload_calls": int(self.xfer_calls[0]),
@@ -891,6 +905,19 @@ def qinq_lanes(push: int, pop: int, miss: int) -> None:
     _ACTIVE.qinq_push += push
     _ACTIVE.qinq_pop += pop
     _ACTIVE.qinq_miss += miss
+
+
+def edge_lanes(mirrored: int, filtered: int, rewrites: int,
+               misses: int) -> None:
+    """Count one retired step's edge lanes: mirrored for a warrant, tapped
+    but filtered out, rewritten to a next hop, upstream data lanes without
+    a route row. Disarmed: global load + None compare."""
+    if _ACTIVE is None:
+        return
+    _ACTIVE.edge_mirrored += mirrored
+    _ACTIVE.edge_filtered += filtered
+    _ACTIVE.edge_rewrites += rewrites
+    _ACTIVE.edge_route_miss += misses
 
 
 def nat_lanes(fwd: int, punt: int) -> None:

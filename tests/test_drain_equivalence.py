@@ -74,7 +74,7 @@ def _stack():
     engine = Engine(fastpath, nat, qos, spoof,
                     garden=GardenTables(nbuckets=64),
                     pppoe=PPPoEFastPathTables(nbuckets=64, stash=8),
-                    edge=EdgeTables(nbuckets=64),
+                    edge=EdgeTables(tap_nbuckets=64, route_nbuckets=64),
                     v6=V6FastPathTables(spoof, nbuckets=64),
                     batch_size=BATCH, clock=lambda: float(T0))
     return engine

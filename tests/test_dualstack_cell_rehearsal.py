@@ -26,18 +26,14 @@ sys.path.insert(0, ROOT)
 
 from benchmark import run as bench_run  # noqa: E402
 from benchmark.lib import app as applib  # noqa: E402
+import cellfiles  # noqa: E402
 
 REAL = "dualstack-cgnat-1M-wire.flood-64B"
 CELL = "tiny-dualstack-1024.flood-4096"
-FILES = ("dualstack_step.device_p50_us", "dualstack.loop_us_per_frame",
-         "dualstack.gen_share", "dualstack.beat_p99_us")
-# the cell's counter files (PR 36's, and PR 37's crossings a step): the tiny
-# cell is appended to the real files' `cells` in the copy; only
-# `dualstack.frames_per_step`, which the benchmark does not have, is dropped in
-COUNTERS = ("dualstack.v6_fwd_per_step", "dualstack.v6_miss_per_step",
-            "dualstack.v6_ctrl_per_step", "wire.upload_calls_per_step",
-            "wire.fetch_calls_per_step", "wire.prefetch_calls_per_step")
-FRAMES = {"name": "dualstack.frames_per_step", "unit": "frames",
+# the cell's layer files are taken from what lists the cell (cellfiles.py),
+# by what each reads; only `tiny.frames_per_step`, which the benchmark
+# does not have, is dropped in
+FRAMES = {"name": "tiny.frames_per_step", "unit": "frames",
           "better": "higher", "source": "program_counter",
           "layer": "engine (runtime/engine.py)", "moves": "served_kpps",
           "cells": [CELL],
@@ -82,12 +78,8 @@ def cell_dir(tmp_path_factory):
     for m in bench["end_to_end"]:
         if m["name"] == "served_kpps":
             m["workloads"].append(CELL)
-    for name in (*FILES, *COUNTERS):
-        m = applib.load_named("layers", name, bdir)
-        assert REAL in m["cells"]
-        assert m["cells"] == [REAL] or name.startswith("wire")
-        m["cells"].append(CELL)
-        _write(os.path.join(bdir, "layers", name + ".json"), m)
+    assert all(m["moves"] == "served_kpps"
+               for m in cellfiles.stand_in(bdir, REAL, CELL))
     _write(os.path.join(bdir, "layers", FRAMES["name"] + ".json"), FRAMES)
     _write(os.path.join(top, "BENCHMARK.json"), bench)
     return bdir
@@ -124,17 +116,24 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     if trace == "0":
         assert set(got) == {"served_kpps", "setup_s"}
         return
-    for name in FILES[1:]:
-        assert got[name]["value"] > 0, name
-    assert got["dualstack.gen_share"]["value"] < 100.0
-    assert "dualstack_step.device_p50_us" not in got  # no device trace here
+    files = cellfiles.listed(cell_dir, REAL)
+    name = {k: cellfiles.reading(files, **read) for k, read in (
+        ("gen", cellfiles.GEN_SHARE), ("loop", cellfiles.LOOP_US),
+        ("beat", cellfiles.BEAT_P99), ("step", cellfiles.STEP_P50),
+        ("up", cellfiles.UPLOAD_CALLS), ("fetch", cellfiles.FETCH_CALLS),
+        ("prefetch", cellfiles.PREFETCH_CALLS),
+        *((k, cellfiles.counter(f"engine.trace.v6_{k}"))
+          for k in ("fwd", "miss", "ctrl")))}
+    for k in ("loop", "gen", "beat"):
+        assert got[name[k]]["value"] > 0, name[k]
+    assert got[name["gen"]]["value"] < 100.0
+    assert name["step"] not in got  # no device trace here
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
-    assert said and "dualstack_step.device_p50_us" in said[0]
+    assert said and name["step"] in said[0]
     # the three counters, through `engine.trace` by their layer files:
     # two in five of a retired window's data frames were forwarded as IPv6
-    per_step = {k: got[f"dualstack.v6_{k}_per_step"]["value"]
-                for k in ("fwd", "miss", "ctrl")}
-    frames = got["dualstack.frames_per_step"]["value"]  # 5% of them DHCP
+    per_step = {k: got[name[k]]["value"] for k in ("fwd", "miss", "ctrl")}
+    frames = got["tiny.frames_per_step"]["value"]  # 5% of them DHCP
     assert per_step["miss"] == 0 and per_step["ctrl"] == 0
     assert 0.36 * 0.95 * frames < per_step["fwd"] < 0.44 * 0.95 * frames
     assert frames <= 1024
@@ -142,9 +141,9 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     # out_pkt, out_len, the violation and punt flags and six stats blocks
     # (dhcp, nat, qos, spoof, garden, v6): since PR 43 each one's copy was
     # started at its step's dispatch, so the reads cross nothing
-    assert got["wire.upload_calls_per_step"]["value"] == 3
-    assert got["wire.fetch_calls_per_step"]["value"] == 0
-    assert got["wire.prefetch_calls_per_step"]["value"] == \
+    assert got[name["up"]]["value"] == 3
+    assert got[name["fetch"]]["value"] == 0
+    assert got[name["prefetch"]]["value"] == \
         pytest.approx(3 + 2 + 6, abs=0.25)
 
 

@@ -67,11 +67,11 @@ class TestKernels:
             edge.tap.device_state(),
             jnp.asarray(edge.tap_filters),
             jnp.asarray(edge.tap_config),
-            edge.geom)
+            edge.tap_geom)
         return np.asarray(res.mirror), np.asarray(res.stats)
 
     def test_unfiltered_tap_mirrors_every_lane(self):
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         ip = ip_to_u32("10.0.0.5")
         edge.arm_tap(ip, 7)
         mirror, stats = self._match(edge, [ip, ip + 1], [1000, 1000],
@@ -80,7 +80,7 @@ class TestKernels:
         assert stats[EST_MIRRORED] == 1
 
     def test_port_filter_matches_src_or_dst(self):
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         ip = ip_to_u32("10.0.0.5")
         edge.arm_tap(ip, 3, [(443, 0, 0)])
         mirror, stats = self._match(edge, [ip, ip, ip],
@@ -91,7 +91,7 @@ class TestKernels:
         assert stats[EST_TAP_FILTERED] == 1
 
     def test_zero_warrant_config_adds_no_device_work(self):
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         ip = ip_to_u32("10.0.0.5")
         mirror, stats = self._match(edge, [ip], [1], [2])
         assert mirror.tolist() == [0]
@@ -100,7 +100,7 @@ class TestKernels:
         assert edge.tap_config[TC_ARMED] == 0
 
     def test_disarmed_after_reap_stops_mirroring(self):
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         ip = ip_to_u32("10.0.0.5")
         edge.arm_tap(ip, 7)
         edge.disarm_tap(ip)
@@ -108,7 +108,7 @@ class TestKernels:
         assert mirror.tolist() == [0]
 
     def test_route_rewrite_stamps_next_hop_mac(self):
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         ip = ip_to_u32("10.0.0.5")
         edge.set_route(ip, NH_A, 100, CLASS_CODES["business"])
         frame = packets.udp_packet(b"\x02" * 6, SERVER_MAC, ip,
@@ -120,7 +120,7 @@ class TestKernels:
             jnp.frombuffer(frame, jnp.uint8))
         res = route_rewrite(pkt, jnp.asarray([ip, ip + 9], jnp.uint32),
                             jnp.asarray([True, True]),
-                            edge.route.device_state(), edge.geom)
+                            edge.route.device_state(), edge.route_geom)
         out = np.asarray(res.out_pkt)
         assert bytes(out[0, :6]) == NH_A  # hit: rewritten
         assert bytes(out[1, :6]) == frame[:6]  # miss: untouched
@@ -133,7 +133,7 @@ class TestKernels:
 
 class TestEdgeTables:
     def test_route_flap_is_bounded_deltas_not_resync(self):
-        edge = EdgeTables(nbuckets=256)
+        edge = EdgeTables(tap_nbuckets=256, route_nbuckets=256)
         ips = [ip_to_u32("10.0.1.0") + i for i in range(32)]
         for ip in ips:
             edge.set_route(ip, NH_A, 100, 1)
@@ -145,7 +145,7 @@ class TestEdgeTables:
         assert edge.dirty_count() == 4
 
     def test_set_tap_filters_keeps_foreign_rows(self):
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         edge.arm_tap(1, 1, [(80, 0, 0)])
         edge.arm_tap(2, 2, [(443, 0, 0), (8443, 0, 0)])
         edge.set_tap_filters(1, [(53, 17, 0)])
@@ -156,12 +156,12 @@ class TestEdgeTables:
         assert by_wid == {1: [53], 2: [443, 8443]}
 
     def test_checkpoint_state_roundtrip(self):
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         ip = ip_to_u32("10.0.0.5")
         edge.arm_tap(ip, 3, [(443, 6, 0)])
         edge.set_route(ip, NH_A, 7, 2)
         meta, arrays = edge.checkpoint_state()
-        e2 = EdgeTables(nbuckets=64)
+        e2 = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         e2.restore_state(meta, arrays)
         assert e2.get_tap(ip)[TW_WID] == 3
         assert e2.tap_config[TC_ARMED] == 1
@@ -179,7 +179,7 @@ class TestEdgeTables:
 class TestInterceptCompile:
     def _stack(self, clk):
         im = InterceptManager(clock=lambda: clk[0])
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         prog = InterceptTapProgram(edge, im, clock=lambda: clk[0])
         return im, edge, prog
 
@@ -247,7 +247,7 @@ class TestAuditEdge:
                                    health_target="192.0.2.1", weight=1))
         platform.reachable["192.0.2.1"] = 0.01
         rman.check_health()
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         tp = InterceptTapProgram(edge, im, clock=lambda: clk[0])
         rp = RouteProgram(edge, rman)
         rp.attach()
@@ -347,7 +347,7 @@ class TestEngineEdge:
                                 ip, now))
         spoof = AntispoofTables(nbuckets=64)
         spoof.set_config(MODE_DISABLED, True)
-        edge = EdgeTables(nbuckets=64)
+        edge = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         mirrored = []
         eng = Engine(fastpath, nat, antispoof=spoof, edge=edge,
                      batch_size=8, slow_path=server.handle_frame,
@@ -487,12 +487,12 @@ class TestShardedEdge:
                                                 restore_checkpoint,
                                                 roundtrip_checkpoint)
 
-        e = EdgeTables(nbuckets=64)
+        e = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         ip = ip_to_u32("10.0.0.5")
         e.arm_tap(ip, 3, [(443, 6, 0)])
         e.set_route(ip, NH_A, 7, 2)
         ck = roundtrip_checkpoint(build_checkpoint(1, 0.0, edge=e))
-        e2 = EdgeTables(nbuckets=64)
+        e2 = EdgeTables(tap_nbuckets=64, route_nbuckets=64)
         rows = restore_checkpoint(ck, edge=e2)
         assert rows["edge.tap"] == 1 and rows["edge.route"] == 1
         assert e2.get_tap(ip) is not None

@@ -22,21 +22,16 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+import cellfiles  # noqa: E402
 from benchmark import run as bench_run  # noqa: E402
 from benchmark.lib import app as applib  # noqa: E402
 
 REAL = "pppoe-cgnat-1M-wire.flood-64B"
 CELL = "tiny-pppoe-1024.flood-4096"
-FILES = ("pppoe_step.device_p50_us", "pppoe.loop_us_per_frame",
-         "pppoe.gen_share", "pppoe.beat_p99_us")
-# the cell's counter files (PR 36's, and PR 37's crossings a step): the tiny
-# cell is appended to the real files' `cells` in the copy; only
-# `pppoe.frames_per_step`, which the benchmark does not have, is dropped in
-COUNTERS = ("pppoe.decap_per_step", "pppoe.encap_per_step",
-            "pppoe.miss_per_step", "pppoe.tick_ms_per_s",
-            "wire.upload_calls_per_step", "wire.fetch_calls_per_step",
-            "wire.prefetch_calls_per_step")
-FRAMES = {"name": "pppoe.frames_per_step", "unit": "frames",
+# the cell's layer files are taken from what lists the cell (cellfiles.py),
+# by what each reads; only `tiny.frames_per_step`, which the benchmark does
+# not have, is dropped in
+FRAMES = {"name": "tiny.frames_per_step", "unit": "frames",
           "better": "higher", "source": "program_counter",
           "layer": "engine (runtime/engine.py)", "moves": "served_kpps",
           "cells": [CELL],
@@ -80,12 +75,8 @@ def cell_dir(tmp_path_factory):
     for m in bench["end_to_end"]:
         if m["name"] == "served_kpps":
             m["workloads"].append(CELL)
-    for name in (*FILES, *COUNTERS):
-        m = applib.load_named("layers", name, bdir)
-        assert REAL in m["cells"]
-        assert m["cells"] == [REAL] or name.startswith("wire")
-        m["cells"].append(CELL)
-        _write(os.path.join(bdir, "layers", name + ".json"), m)
+    assert all(m["moves"] == "served_kpps"
+               for m in cellfiles.stand_in(bdir, REAL, CELL))
     _write(os.path.join(bdir, "layers", FRAMES["name"] + ".json"), FRAMES)
     _write(os.path.join(top, "BENCHMARK.json"), bench)
     return bdir
@@ -121,29 +112,37 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     if trace == "0":
         assert set(got) == {"served_kpps", "setup_s"}
         return
-    for name in FILES[1:]:
-        assert got[name]["value"] > 0, name
-    assert got["pppoe.gen_share"]["value"] < 100.0
-    assert "pppoe_step.device_p50_us" not in got  # no device trace on the CPU
+    files = cellfiles.listed(cell_dir, REAL)
+    name = {k: cellfiles.reading(files, **read) for k, read in (
+        ("gen", cellfiles.GEN_SHARE), ("loop", cellfiles.LOOP_US),
+        ("beat", cellfiles.BEAT_P99), ("step", cellfiles.STEP_P50),
+        ("tick", cellfiles.TICK_MS), ("up", cellfiles.UPLOAD_CALLS),
+        ("fetch", cellfiles.FETCH_CALLS),
+        ("prefetch", cellfiles.PREFETCH_CALLS),
+        *((k, cellfiles.counter(f"engine.trace.pppoe_{k}"))
+          for k in ("decap", "encap", "miss")))}
+    for k in ("loop", "gen", "beat"):
+        assert got[name[k]]["value"] > 0, name[k]
+    assert got[name["gen"]]["value"] < 100.0
+    assert name["step"] not in got  # no device trace on the CPU
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
-    assert said and "pppoe_step.device_p50_us" in said[0]
+    assert said and name["step"] in said[0]
     # the three counters, through `engine.trace` by their layer files:
     # every data frame of a retired window was decapsulated or encapsulated
-    per_step = {k: got[f"pppoe.{k}_per_step"]["value"]
-                for k in ("decap", "encap", "miss")}
+    per_step = {k: got[name[k]]["value"] for k in ("decap", "encap", "miss")}
     assert per_step["decap"] > 0 and per_step["encap"] > 0
     assert per_step["miss"] == 0
-    frames = got["pppoe.frames_per_step"]["value"]  # 5% of them DHCP
+    frames = got["tiny.frames_per_step"]["value"]  # 5% of them DHCP
     assert 0.90 * frames < per_step["decap"] + per_step["encap"] < frames <= 1024
     # the once-a-second walk over the sessions, in stage `slow_path`
-    assert got["pppoe.tick_ms_per_s"]["value"] > 0
+    assert got[name["tick"]]["value"] > 0
     # a step's crossings: the staged window up; a retire reads verdict,
     # out_pkt, out_len, the violation and punt flags and six stats blocks
     # (dhcp, nat, qos, spoof, garden, pppoe): since PR 43 each one's copy
     # was started at its step's dispatch, so the reads cross nothing
-    assert got["wire.upload_calls_per_step"]["value"] == 3
-    assert got["wire.fetch_calls_per_step"]["value"] == 0
-    assert got["wire.prefetch_calls_per_step"]["value"] == \
+    assert got[name["up"]]["value"] == 3
+    assert got[name["fetch"]]["value"] == 0
+    assert got[name["prefetch"]]["value"] == \
         pytest.approx(3 + 2 + 6, abs=0.25)
 
 

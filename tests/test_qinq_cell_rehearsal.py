@@ -26,25 +26,24 @@ sys.path.insert(0, ROOT)
 
 from benchmark import run as bench_run  # noqa: E402
 from benchmark.lib import app as applib  # noqa: E402
+import cellfiles  # noqa: E402
 
 REAL = "qinq-pppoe-cgnat-1M-wire.flood-64B"
 CELL = "tiny-qinq-1024.flood-4096"
-FILES = ("qinq.push_per_step", "qinq.pop_per_step", "qinq.miss_per_step",
-         "qinq_step.device_p50_us", "qinq.loop_us_per_frame",
-         "qinq.gen_share", "qinq.beat_p99_us", "qinq.tick_ms_per_s")
-# dropped in: files the benchmark has for the loop's other cells, read here
-# (no file lists this cell: `wire.*`'s `cells` may not be edited)
+# the cell's layer files are taken from what lists the cell (cellfiles.py),
+# by what each reads. Dropped in: reads the rehearsal wants under a name of
+# its own
 DROPPED = [
-    {"name": "qinq.frames_per_step", "unit": "frames", "better": "higher",
+    {"name": "tiny.frames_per_step", "unit": "frames", "better": "higher",
      "source": "program_counter", "layer": "engine (runtime/engine.py)",
      "moves": "served_kpps", "cells": [CELL],
      "read": {"kind": "counter", "path": "ring.rx", "per": "engine.batches"}},
-    {"name": "qinq.fetch_calls_per_step", "unit": "calls", "better": "lower",
+    {"name": "tiny.fetch_calls_per_step", "unit": "calls", "better": "lower",
      "source": "program_counter", "layer": "engine (runtime/engine.py)",
      "moves": "served_kpps", "cells": [CELL],
      "read": {"kind": "counter", "path": "engine.trace.xfer.fetch_calls",
               "per": "engine.batches"}},
-    {"name": "qinq.prefetch_calls_per_step", "unit": "calls",
+    {"name": "tiny.prefetch_calls_per_step", "unit": "calls",
      "better": "higher", "source": "program_counter",
      "layer": "engine (runtime/engine.py)", "moves": "served_kpps",
      "cells": [CELL],
@@ -91,11 +90,8 @@ def cell_dir(tmp_path_factory):
     for m in bench["end_to_end"]:
         if m["name"] == "served_kpps":
             m["workloads"].append(CELL)
-    for name in FILES:
-        m = applib.load_named("layers", name, bdir)
-        assert m["cells"] == [REAL] and m["moves"] == "served_kpps"
-        m["cells"].append(CELL)
-        _write(os.path.join(bdir, "layers", name + ".json"), m)
+    assert all(m["moves"] == "served_kpps"
+               for m in cellfiles.stand_in(bdir, REAL, CELL))
     for m in DROPPED:
         _write(os.path.join(bdir, "layers", m["name"] + ".json"), m)
     _write(os.path.join(top, "BENCHMARK.json"), bench)
@@ -132,25 +128,31 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     if trace == "0":
         assert set(got) == {"served_kpps", "setup_s"}
         return
-    for name in ("qinq.loop_us_per_frame", "qinq.gen_share",
-                 "qinq.beat_p99_us", "qinq.tick_ms_per_s"):
-        assert got[name]["value"] > 0, name
-    assert got["qinq.gen_share"]["value"] < 100.0
-    assert "qinq_step.device_p50_us" not in got  # no device trace on the CPU
+    files = cellfiles.listed(cell_dir, REAL)
+    name = {k: cellfiles.reading(files, **read) for k, read in (
+        ("gen", cellfiles.GEN_SHARE), ("loop", cellfiles.LOOP_US),
+        ("beat", cellfiles.BEAT_P99), ("step", cellfiles.STEP_P50),
+        ("tick", cellfiles.TICK_MS),
+        *((k, cellfiles.counter(f"engine.trace.qinq_{k}"))
+          for k in ("push", "pop", "miss")))}
+    for k in ("loop", "gen", "beat", "tick"):
+        assert got[name[k]]["value"] > 0, name[k]
+    assert got[name["gen"]]["value"] < 100.0
+    assert name["step"] not in got  # no device trace on the CPU
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
-    assert said and "qinq_step.device_p50_us" in said[0]
+    assert said and name["step"] in said[0]
     # the three counters, through `engine.trace` by their layer files: every
     # data frame of a retired window was popped or pushed, none missed
-    push, pop, miss = (got[f"qinq.{k}_per_step"]["value"]
+    push, pop, miss = (got[name[k]]["value"]
                        for k in ("push", "pop", "miss"))
     assert push > 0 and pop > 0 and miss == 0
-    frames = got["qinq.frames_per_step"]["value"]  # 5% of them DHCP
+    frames = got["tiny.frames_per_step"]["value"]  # 5% of them DHCP
     assert 0.90 * frames < push + pop < frames <= 1024
     # a retire's reads: P's eleven (verdict, out_pkt, out_len, two flag
     # columns, six stats blocks) and the stage's one block more, each
     # one's copy started at its step's dispatch since PR 43
-    assert got["qinq.fetch_calls_per_step"]["value"] == 0
-    assert got["qinq.prefetch_calls_per_step"]["value"] == \
+    assert got["tiny.fetch_calls_per_step"]["value"] == 0
+    assert got["tiny.prefetch_calls_per_step"]["value"] == \
         pytest.approx(3 + 2 + 7, abs=0.25)
 
 
@@ -222,6 +224,36 @@ def test_the_generators_frames_are_the_framing_the_reference_strips():
     assert lengths == {"dhcp": {370}, "ipoe-up": {68}, "pppoe-up": {76},
                        "down": {60}}
     assert kit.stage_bytes(8192, 1536) == 4 * 8192 * 1536
+
+
+def test_the_cell_is_in_the_benchmark_as_pr40_put_it():
+    """What tests/benchmark/test_qinq_stand_in.py holds of the cell's entries,
+    but for its pin that the cell is the LAST name in `served_kpps.workloads`
+    (a cell added since is appended after it; tests/conftest.py marks that
+    test): the cell, its configuration, and one entry a file that lists it."""
+    from benchmark.lib import layers
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cell = {w["name"]: w for w in bench["workloads"]}[REAL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "qinq-pppoe-cgnat-1M-wire", "flood-64B", 1)
+    assert "no frame crossed a link" in cell["why"]
+    cfg = applib.load_named("configs", cell["config"])
+    base = applib.load_named("configs", "pppoe-cgnat-1M-wire")
+    assert cfg["kit"] == "qinq" and cfg["reduced"] == ["max_nat_sessions"]
+    assert cfg["architecture"] is None and "framing" in cfg
+    assert cfg["sizes"] == dict(base["sizes"], qinq_pairs=1_000_000)
+    assert cfg["argv"] == base["argv"] + ["--qinq-enabled"]
+    assert "QinQ" not in cfg["off"]
+    assert cfg["guarantees"] == applib.load_named("configs", "ipoe-cgnat-1M")[
+        "guarantees"]
+    named = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
+             if REAL in m["cells"]}
+    assert named and {m["name"] for m in bench["per_layer"]
+                      if REAL in m["workloads"]} == named
+    served = {m["name"]: m for m in bench["end_to_end"]}["served_kpps"]
+    assert REAL in served["workloads"]
 
 
 def test_the_tables_take_a_million_pairs_through_the_bulk_writers():

@@ -21,42 +21,52 @@ sys.path.insert(0, ROOT)
 
 from benchmark import run as bench_run  # noqa: E402
 from benchmark.lib import app as applib  # noqa: E402
-from benchmark.lib import layers  # noqa: E402
+import cellfiles  # noqa: E402
 
 pytestmark = pytest.mark.sharded
 
 REAL = "cgnat-sharded4-1M.flood-64B"
 CELL = "tiny4-nat-4096.flood-2048"
 STARVED = "tiny4-nat-starved.flood-2048"
-FILES = ("shardnat_step.device_p50_us", "shardnat.collective_share",
-         "shardnat.nat_fwd_per_step", "shardnat.nat_punt_per_step",
-         "shardnat.steer_miss_per_s", "shardnat.frame_imbalance",
-         "shardnat.loop_us_per_frame", "shardnat.gen_share",
-         "shardnat.device_wait_us_per_step", "shardnat.dispatch_us_per_step",
-         "shardnat.drain_built_per_step", "shardnat.device_starved_share")
-NO_DEVICE = {"shardnat_step.device_p50_us", "shardnat.collective_share"}
+# the cell's layer files are taken from what lists the cell (cellfiles.py),
+# by what each reads: a merge of a `shardnat.*` repeat into the `sharded.*`
+# file it repeats renames what the cell reports and edits nothing here
+SHARDED = "sharded.trace."
+READS = {
+    "step": cellfiles.STEP_P50,
+    "collective": dict(kind="trace_device", stat="collective_share"),
+    "nat_fwd": cellfiles.counter(SHARDED + "nat_fwd"),
+    "nat_punt": cellfiles.counter(SHARDED + "nat_punt"),
+    "steer_miss": cellfiles.counter("ring.steer_pub_miss"),
+    "imbalance": cellfiles.counter("sharded.per_shard.*.frames"),
+    "loop": cellfiles.LOOP_US, "gen": cellfiles.GEN_SHARE,
+    "device_wait": cellfiles.counter(SHARDED + "stage_ns.device_wait"),
+    "dispatch": cellfiles.counter(SHARDED + "stage_ns.dispatch"),
+    "drain_built": cellfiles.counter(SHARDED + "drain_built"),
+    "starved": cellfiles.counter(SHARDED + "beat_starved_ns")}
+NO_DEVICE = ("step", "collective")
 # dropped in: what the `sharded.*` files read in S (their `cells` may not
 # be edited), read here to hold "no new read from the chips"
 DROPPED = [
-    {"name": "shardnat.frames_per_step", "unit": "frames", "better": "higher",
+    {"name": "tiny.frames_per_step", "unit": "frames", "better": "higher",
      "source": "program_counter",
      "layer": "sharded engine (parallel/sharded.py)", "moves": "served_kpps",
      "cells": [CELL],
      "read": {"kind": "counter", "path": "ring.rx",
               "per": "sharded.trace.batches"}},
-    {"name": "shardnat.fetch_calls_per_step", "unit": "calls",
+    {"name": "tiny.fetch_calls_per_step", "unit": "calls",
      "better": "lower", "source": "program_counter",
      "layer": "sharded engine (parallel/sharded.py)", "moves": "served_kpps",
      "cells": [CELL],
      "read": {"kind": "counter", "path": "sharded.trace.xfer.fetch_calls",
               "per": "sharded.trace.batches"}},
-    {"name": "shardnat.prefetch_calls_per_step", "unit": "calls",
+    {"name": "tiny.prefetch_calls_per_step", "unit": "calls",
      "better": "higher", "source": "program_counter",
      "layer": "sharded engine (parallel/sharded.py)", "moves": "served_kpps",
      "cells": [CELL],
      "read": {"kind": "counter", "path": "sharded.trace.xfer.prefetch_calls",
               "per": "sharded.trace.batches"}},
-    {"name": "shardnat.steer_hit_per_s", "unit": "frames/s",
+    {"name": "tiny.steer_hit_per_s", "unit": "frames/s",
      "better": "higher", "source": "program_counter",
      "layer": "ring (runtime/ring.py)", "moves": "served_kpps",
      "cells": [CELL],
@@ -108,11 +118,8 @@ def cell_dir(tmp_path_factory):
         for m in bench["end_to_end"]:
             if m["name"] == "served_kpps":
                 m["workloads"].append(cell)
-    for name in FILES:
-        m = applib.load_named("layers", name, bdir)
-        assert m["cells"] == [REAL] and m["moves"] == "served_kpps"
-        m["cells"].append(CELL)
-        _write(os.path.join(bdir, "layers", name + ".json"), m)
+    assert all(m["moves"] == "served_kpps"
+               for m in cellfiles.stand_in(bdir, REAL, CELL))
     for m in DROPPED:
         _write(os.path.join(bdir, "layers", m["name"] + ".json"), m)
     _write(os.path.join(top, "BENCHMARK.json"), bench)
@@ -153,28 +160,28 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     if trace == "0":
         assert set(got) == {"served_kpps", "setup_s"}
         return
-    assert set(FILES) - NO_DEVICE <= set(got)
+    files = cellfiles.listed(cell_dir, REAL)
+    name = {k: cellfiles.reading(files, **read) for k, read in READS.items()}
+    assert {name[k] for k in READS if k not in NO_DEVICE} <= set(got)
     left = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
-    assert left and all(name in left[0] for name in NO_DEVICE)
+    assert left and all(name[k] in left[0] for k in NO_DEVICE)
     # every data frame of a retired step was translated on its own shard:
     # none punted, none steered by the hash, no table built in a drain
-    frames = got["shardnat.frames_per_step"]["value"]
-    fwd = got["shardnat.nat_fwd_per_step"]["value"]
+    frames = got["tiny.frames_per_step"]["value"]
+    fwd = got[name["nat_fwd"]]["value"]
     assert 0.90 * frames < fwd < frames <= 1024  # 5% of them DHCP
-    assert got["shardnat.nat_punt_per_step"]["value"] == 0
-    assert got["shardnat.steer_miss_per_s"]["value"] == 0
-    assert got["shardnat.steer_hit_per_s"]["value"] > 0
-    assert got["shardnat.drain_built_per_step"]["value"] == 0
-    assert 1.0 <= got["shardnat.frame_imbalance"]["value"] < 1.5
-    for name in ("shardnat.loop_us_per_frame", "shardnat.gen_share",
-                 "shardnat.device_wait_us_per_step",
-                 "shardnat.dispatch_us_per_step"):
-        assert got[name]["value"] > 0, name
+    assert got[name["nat_punt"]]["value"] == 0
+    assert got[name["steer_miss"]]["value"] == 0
+    assert got["tiny.steer_hit_per_s"]["value"] > 0
+    assert got[name["drain_built"]]["value"] == 0
+    assert 1.0 <= got[name["imbalance"]]["value"] < 1.5
+    for k in ("loop", "gen", "device_wait", "dispatch"):
+        assert got[name[k]]["value"] > 0, name[k]
     # the two stamps ride the blocks a retire reads already: ten reads a
     # fused step as in S (a DHCP-only window's four pull the mean down),
     # every one's copy started at its dispatch (PR 44), none a crossing
-    assert 9.0 < got["shardnat.prefetch_calls_per_step"]["value"] <= 10.0
-    assert got["shardnat.fetch_calls_per_step"]["value"] == 0
+    assert 9.0 < got["tiny.prefetch_calls_per_step"]["value"] <= 10.0
+    assert got["tiny.fetch_calls_per_step"]["value"] == 0
 
 
 def test_both_controls_fail_by_the_sample(cell_dir, capsys):
@@ -200,12 +207,18 @@ def test_a_pool_too_small_for_a_shard_fails_at_once(cell_dir, capsys):
 
 
 def test_every_file_of_the_cell_is_listed_with_its_cells():
+    """Every file that lists the cell has an entry of its name with the same
+    list, in the same order, and every entry that lists it a file; each of
+    the reads the rehearsal holds is served by exactly one of them."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     listed = {m["name"]: m for m in bench["per_layer"]
               if REAL in m["workloads"]}
-    files = {m["name"]: m for m in layers.layer_files(applib.BENCH_DIR)
-             if REAL in m["cells"]}
-    assert set(listed) == set(files) == set(FILES)
-    assert all(m["workloads"] == files[n]["cells"] == [REAL]
-               and m["moves"] == "served_kpps" for n, m in listed.items())
+    files = {m["name"]: m for m in cellfiles.listed(applib.BENCH_DIR, REAL)}
+    assert set(listed) == set(files) and len(files) >= len(READS)
+    assert all(m["workloads"] == files[n]["cells"]
+               and m["moves"] == files[n]["moves"] == "served_kpps"
+               for n, m in listed.items())
+    names = [cellfiles.reading(list(files.values()), **read)
+             for read in READS.values()]
+    assert len(set(names)) == len(READS)

@@ -97,7 +97,8 @@ def make_engine(stages) -> Engine:
                 if "garden" in stages else None),
         pppoe=(PPPoEFastPathTables(nbuckets=32, **kw)
                if "pppoe" in stages else None),
-        edge=(EdgeTables(nbuckets=32, max_filters=4, **kw)
+        edge=(EdgeTables(tap_nbuckets=32, route_nbuckets=64, max_filters=4,
+                         **kw)  # two sizes, as `bng run --edge-enabled` builds
               if "edge" in stages else None),
         v6=(V6FastPathTables(sp, nbuckets=32, **kw)
             if "v6" in stages else None),
@@ -498,8 +499,8 @@ SNAPSHOT = {  # in the order the components are written
                    **table_of("by_ip", 32, 1, 8), "server_mac": u32(2)}},
     "edge": {
         "meta": ["geom", "max_filters"],
-        "geom": {"tap": geom_of(32, 1, 8), "route": geom_of(32, 1, 8)},
-        "arrays": {**table_of("tap", 32, 1, 8), **table_of("route", 32, 1, 8),
+        "geom": {"tap": geom_of(32, 1, 8), "route": geom_of(64, 1, 8)},
+        "arrays": {**table_of("tap", 32, 1, 8), **table_of("route", 64, 1, 8),
                    "tap_filters": u32(4, 4), "tap_config": u32(2)}},
     "v6": {
         "meta": ["geom"],

@@ -20,8 +20,8 @@ import jax
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from bng_tpu.runtime import verify
-from bng_tpu.runtime.verify import (REAL_1M, REAL_1M_PPPOE, REAL_1M_QINQ,
-                                    REAL_1M_V6,
+from bng_tpu.runtime.verify import (REAL_1M, REAL_1M_EDGE, REAL_1M_PPPOE,
+                                    REAL_1M_QINQ, REAL_1M_V6,
                                     compile_for)
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -139,6 +139,24 @@ def test_fused_step_with_the_qinq_stage_fits_and_has_no_while(one_chip, lanes):
     assert REAL_1M_QINQ.qinq_nbuckets == nbuckets_for(1_000_000)  # as cli.py
     assert REAL_1M_QINQ.pppoe_nbuckets == REAL_1M_PPPOE.pppoe_nbuckets
     compiled = compile_for(verify.build_pipeline(REAL_1M_QINQ, lanes=lanes),
+                           one_chip)
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert _whiles(compiled) == []
+    assert _table_relayouts(compiled, f"{(1 << 19) // 4},128") == []
+
+
+@pytest.mark.parametrize("lanes", [1024, None], ids=["rung-1024", "full"])
+def test_fused_step_with_the_edge_stage_fits_and_has_no_while(one_chip, lanes):
+    """`bng run --edge-enabled` at the 1M geometry, the route table sized
+    for 1,000,000 subscribers and the tap table for its default 4,096
+    warrants (two geometries): the step with two more probes, the [B, 64]
+    filter scan under its `cond` and the six-byte MAC stamp. It fits, the
+    stamp brings no loop, and the route table stays in one physical form."""
+    from bng_tpu.ops.table import nbuckets_for
+
+    assert REAL_1M_EDGE.route_nbuckets == nbuckets_for(1_000_000)  # as cli.py
+    assert REAL_1M_EDGE.tap_nbuckets == nbuckets_for(4096)
+    compiled = compile_for(verify.build_pipeline(REAL_1M_EDGE, lanes=lanes),
                            one_chip)
     assert _device_bytes(compiled) < V5E_HBM_BYTES
     assert _whiles(compiled) == []
