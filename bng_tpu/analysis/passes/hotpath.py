@@ -56,7 +56,9 @@ DISPATCH_SCOPE: dict[str, set[str]] = {
     "bng_tpu/runtime/engine.py": {
         "_dispatch_step", "_run_dhcp_batch", "dispatch_scheduled_bulk",
         "_drain_updates", "_make_bulk_updates", "_empty_updates",
-        "_updates", "_drain_with_resync", "_drain_fastpath_updates",
+        "_updates", "_drain", "_drain_fastpath_updates",
+        "_apply_drained", "apply_updates_now", "_fresh_dense",
+        "_place_dense", "_place_dense_dhcp",
         "_pack_frames", "_dispatch_fault", "_staging",
     },
     "bng_tpu/runtime/scheduler.py": {
@@ -79,7 +81,10 @@ FORCE_CALLS = {"asarray", "array", "device_get", "item", "copy_to_host"}
 # calls whose *result* is a device-step future (taint sources)
 DISPATCH_CALLS = {"_step", "_dhcp_step", "_dispatch_step",
                   "_run_dhcp_batch", "_run_step", "dispatch_scheduled_bulk",
-                  "pipeline_step", "dhcp_fastpath"}
+                  "pipeline_step", "dhcp_fastpath",
+                  # the packet-free programs ahead of a step (a dirty
+                  # drain's): their result is the tables, as much a future
+                  "_apply_fastpath_jit", "_apply_updates_jit"}
 SCALAR_FORCES = {"float", "int", "bool"}
 
 ALLOC_NODES = (ast.List, ast.Dict, ast.Set, ast.ListComp, ast.DictComp,

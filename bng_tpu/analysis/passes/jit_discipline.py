@@ -10,13 +10,17 @@ donation hazards that have actually bitten TPU dataplanes like this one:
   pattern: the cache is keyed on geometry so engines with one shape
   share one compile).
 
-* **BNG011 — missing donation on a table-updating step.** A jitted step
-  whose body applies host table deltas (`apply_fastpath_updates`,
-  `apply_nat_updates`, `apply_update`, `apply_qupdate`, ...) threads
-  the device tables through itself; without `donate_argnums` the old
-  table buffers stay live across the step and HBM holds two copies of
-  every table — the ROADMAP perf campaign's "donation/layout audit of
-  the jitted step" as a repeatable pass.
+* **BNG011 — missing donation on a program that threads the tables.** A
+  jitted program that applies host table deltas (`apply_fastpath_updates`,
+  `apply_nat_updates`, `apply_update`, `apply_qupdate`, ...: the mesh
+  loop's step in its body, and the engine's two packet-free apply
+  programs, which ARE such a function under `jax.jit`), or whose body
+  runs a step's entry (`pipeline_step`, `dhcp_fastpath`,
+  `express_verdicts`: since PR 50 no one-chip step applies a delta),
+  threads the device tables through itself; without `donate_argnums`
+  the old table buffers stay live across the call and HBM holds two
+  copies of every table — the ROADMAP perf campaign's "donation/layout
+  audit of the jitted step" as a repeatable pass.
 
 * **BNG012 — per-batch Python scalar as a traced argument.** Calling a
   jitted step with a bare `int(...)`/`float(...)`/arithmetic scalar
@@ -43,8 +47,10 @@ APPLY_FNS = {"apply_fastpath_updates", "apply_nat_updates", "apply_update",
 # block aliases the descriptor staging buffer, so an undonated express
 # step silently doubles both the table HBM and the per-dispatch
 # allocation (ISSUE 13). Recognized like the apply fns: donation is
-# required even if a refactor ever drops the in-step update apply.
-EXPRESS_ENTRY_FNS = {"express_verdicts"}
+# required even if a refactor ever drops the in-step update apply
+# (PR 50 did, for every one-chip step: the fused and the DHCP-only
+# program's entries stand beside it, for the same reason).
+EXPRESS_ENTRY_FNS = {"express_verdicts", "pipeline_step", "dhcp_fastpath"}
 CACHE_DECORATORS = {"lru_cache", "cache"}
 # jitted-step callables at call sites (the engine/scheduler convention).
 # `express_exe` is the AOT-compiled express executable (the engine's
@@ -136,6 +142,7 @@ class JitDisciplinePass(Pass):
                 f"(the `_pipeline_jit` factory pattern is the fix)",
                 scope=scope, detail=f"jit-in-{fn.name}")
         # donation audit: does the jitted function apply table updates?
+        target = None
         if decorated is not None:
             inner = decorated
         else:
@@ -145,7 +152,11 @@ class JitDisciplinePass(Pass):
             inner = self._resolve_local_fn(node, target)
         must_donate = APPLY_FNS | EXPRESS_ENTRY_FNS
         applies = False
-        if inner is not None:
+        if isinstance(target, ast.Name) and target.id in APPLY_FNS:
+            # an apply function jitted as it stands (the engine's packet-
+            # free programs: `jax.jit(_apply_all_updates, donate_...)`)
+            applies = True
+        elif inner is not None:
             applies = any(isinstance(n, ast.Call)
                           and call_name(n) in must_donate
                           for n in ast.walk(inner))

@@ -8,8 +8,12 @@ maps via syscalls, the engine
    consumer; a C++ ring feeds this in production, synthetic sources in
    tests/bench),
 2. drains bounded table-update batches from the host managers (the
-   bpf_map_update_elem replacement),
-3. invokes ONE donated jitted step: updates -> fused pipeline -> verdicts,
+   bpf_map_update_elem replacement): a table set with nothing dirty costs
+   nothing, a dirty one goes through a packet-free apply program ahead of
+   the step, and a dense config array that changed is put in the tables on
+   the host,
+3. invokes ONE donated jitted step over the tables, the window and the
+   clock: fused pipeline -> verdicts,
 4. applies verdicts: TX/FWD frames out, DROP counted, PASS lanes handed to
    the slow-path handlers (DHCP server, NAT new-flow manager) exactly like
    XDP_PASS delivers to the Go servers,
@@ -77,7 +81,11 @@ def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
     (garden_upd, allowed_rows), then pppoe (sid_upd, ip_upd), then edge
     (tap_upd, tap_filters, tap_config, route_upd), then v6 (by_addr_upd),
     then qinq (by_ip_upd) — each present exactly when the corresponding
-    device stage is compiled in."""
+    device stage is compiled in. Called from no one-chip step: the mesh
+    loop's step applies its batch with it (parallel/sharded.py), and the
+    engine's packet-free program is this function under one `jax.jit`
+    (`_apply_updates_jit`), over tables whose dhcp chain is None and stays
+    so (the chain has a program of its own, `_apply_fastpath_jit`)."""
     fp_upd, nat_upd, qup, qdown, sp_upd, sp_ranges, sp_config, *tails = upd
     tails = list(tails)
     g_state, g_allowed = tables.garden, tables.garden_allowed
@@ -102,7 +110,8 @@ def _apply_all_updates(tables: PipelineTables, upd) -> PipelineTables:
     if qinq_by_ip is not None:
         qinq_by_ip = apply_update(qinq_by_ip, tails.pop(0))
     return PipelineTables(
-        dhcp=apply_fastpath_updates(tables.dhcp, fp_upd),
+        dhcp=(apply_fastpath_updates(tables.dhcp, fp_upd)
+              if tables.dhcp is not None else None),
         nat=apply_nat_updates(tables.nat, nat_upd),
         qos_up=apply_qupdate(tables.qos_up, qup),
         qos_down=apply_qupdate(tables.qos_down, qdown),
@@ -171,71 +180,26 @@ def start_host_copies(outs) -> None:
 
 @functools.lru_cache(maxsize=8)
 def _pipeline_jit(geom: PipelineGeom):
-    def step(tables, upd, pkt, length, from_access, now_s, now_us):
-        tables = _apply_all_updates(tables, upd)
+    def step(tables, pkt, length, from_access, now_s, now_us):
         return pipeline_step(tables, pkt, length, from_access, geom,
                              now_s, now_us)
 
-    # donate the device tables: updates + counter writes are in-place
+    # donate the device tables: counter and token writes are in-place
     return jax.jit(step, donate_argnums=(0,))
 
 
-@functools.lru_cache(maxsize=8)
-def _apply_updates_jit(geom: PipelineGeom, has_garden: bool, has_pppoe: bool,
-                       has_edge: bool = False):
-    """Packet-free update application — the scheduler's safety net for a
-    PREFETCHED bulk drain that no later batch consumed (overlap-drain
-    mode builds the scatter for step N+1 while step N executes; at
-    flush/quiesce a dangling prefetch must still reach the device or
-    the host mirrors and HBM silently diverge).
-
-    The dhcp chain is passed as None and threads through UNTOUCHED: a
-    bulk drain's fastpath entry is always the empty no-op update, and
-    the authoritative chain may live on the express lane's own device —
-    including it would force a cross-device program. geom rides in the
-    key only to separate engines whose update pytrees differ (the v6
-    and qinq tails' presence is `geom.v6`'s and `geom.qinq`'s)."""
-    del geom, has_garden, has_pppoe, has_edge
-
-    def apply_only(tables, upd):
-        fp_upd, nat_upd, qup, qdown, sp_upd, sp_ranges, sp_config, *tails = upd
-        del fp_upd  # the bulk drain's fastpath entry is a no-op by design
-        tails = list(tails)
-        g_state, g_allowed = tables.garden, tables.garden_allowed
-        if tables.garden is not None:
-            g_state = apply_update(tables.garden, tails.pop(0))
-            g_allowed = tails.pop(0)
-        p_sid, p_ip = tables.pppoe_by_sid, tables.pppoe_by_ip
-        if p_sid is not None:
-            p_sid = apply_update(p_sid, tails.pop(0))
-            p_ip = apply_update(p_ip, tails.pop(0))
-        e_tap, e_filters, e_config, e_route = (tables.tap, tables.tap_filters,
-                                               tables.tap_config, tables.route)
-        if e_tap is not None:
-            e_tap = apply_update(e_tap, tails.pop(0))
-            e_filters = tails.pop(0)
-            e_config = tails.pop(0)
-            e_route = apply_update(e_route, tails.pop(0))
-        v6_by_addr = tables.v6_by_addr
-        if v6_by_addr is not None:
-            v6_by_addr = apply_update(v6_by_addr, tails.pop(0))
-        qinq_by_ip = tables.qinq_by_ip
-        if qinq_by_ip is not None:
-            qinq_by_ip = apply_update(qinq_by_ip, tails.pop(0))
-        from bng_tpu.control.nat import apply_nat_updates
-
-        return tables._replace(
-            nat=apply_nat_updates(tables.nat, nat_upd),
-            qos_up=apply_qupdate(tables.qos_up, qup),
-            qos_down=apply_qupdate(tables.qos_down, qdown),
-            spoof=apply_update(tables.spoof, sp_upd),
-            spoof_ranges=sp_ranges, spoof_config=sp_config,
-            garden=g_state, garden_allowed=g_allowed,
-            pppoe_by_sid=p_sid, pppoe_by_ip=p_ip,
-            tap=e_tap, tap_filters=e_filters, tap_config=e_config,
-            route=e_route, v6_by_addr=v6_by_addr, qinq_by_ip=qinq_by_ip)
-
-    return jax.jit(apply_only, donate_argnums=(0,))
+# The two packet-free programs a DIRTY drain goes through, ahead of the
+# step that reads its tables (a clean drain calls neither: no step takes an
+# update batch, so a table set with nothing to ship passes nothing and
+# scatters nothing). One for the dhcp chain, which may live on the express
+# lane's own device and is threaded by three programs; one for every other
+# table, over tables whose dhcp chain is None and threads through untouched
+# (a bulk drain never ships the chain's tables, and including it would
+# force a program across devices). Both donate the tables like a step and
+# are built where the steps are (build_step_rungs, compile_express_aot): a
+# first dirty drain builds nothing.
+_apply_fastpath_jit = jax.jit(apply_fastpath_updates, donate_argnums=(0,))
+_apply_updates_jit = jax.jit(_apply_all_updates, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=8)
@@ -251,7 +215,7 @@ def _dhcp_jit(geom):
     dhcp table leaves as the fused step, so the two programs can never
     fork state.
 
-    The packet batch is donated too (argnum 2): out_pkt is shaped
+    The packet batch is donated too (argnum 1): out_pkt is shaped
     exactly like pkt, so XLA aliases the reply buffer onto the input
     staging upload instead of allocating per dispatch — the VERDICT r5
     input-output-aliasing item on the express-lane OFFER program.
@@ -261,13 +225,12 @@ def _dhcp_jit(geom):
     from bng_tpu.ops.dhcp import dhcp_fastpath
     from bng_tpu.ops.parse import parse_batch
 
-    def step(dhcp_tables, upd, pkt, length, now_s):
-        dhcp_tables = apply_fastpath_updates(dhcp_tables, upd)
+    def step(dhcp_tables, pkt, length, now_s):
         par = parse_batch(pkt, length)
         res = dhcp_fastpath(pkt, length, par, dhcp_tables, geom, now_s)
         return dhcp_tables, res.is_reply, res.out_pkt, res.out_len, res.stats
 
-    return jax.jit(step, donate_argnums=(0, 2))
+    return jax.jit(step, donate_argnums=(0, 1))
 
 
 @functools.lru_cache(maxsize=8)
@@ -279,9 +242,9 @@ def _express_jit(geom):
     vlan/cid lane columns extracted once at admission) and emits only
     the verdict block (verdict + yiaddr + pool/lease words); the host
     patches replies into preassembled wire templates at retire. Donates
-    the dhcp chain (argnum 0 — updates scatter in place, one
-    authoritative chain shared with the full programs) AND the
-    descriptor batch (argnum 2 — the verdict block is shaped exactly
+    the dhcp chain (argnum 0 — one authoritative chain shared with the
+    full programs and the apply program, threaded through unchanged) AND
+    the descriptor batch (argnum 1 — the verdict block is shaped exactly
     like it, so XLA aliases the output onto the input staging upload;
     every caller stages descriptors from numpy, never a live device
     array).
@@ -292,12 +255,11 @@ def _express_jit(geom):
     a dispatch pays neither trace nor jit-cache lookup."""
     from bng_tpu.ops.express import express_verdicts
 
-    def step(dhcp_tables, upd, desc, now_s):
-        dhcp_tables = apply_fastpath_updates(dhcp_tables, upd)
+    def step(dhcp_tables, desc, now_s):
         res = express_verdicts(dhcp_tables, desc, geom, now_s)
         return dhcp_tables, res.block, res.stats
 
-    return jax.jit(step, donate_argnums=(0, 2))
+    return jax.jit(step, donate_argnums=(0, 1))
 
 
 # AOT-compiled express executables, shared across engines of one
@@ -604,7 +566,12 @@ class Engine:
         # pytree (the blue/green standby's snapshot-hydrated chain,
         # runtime/ops.py) in place of the init upload — without it the
         # standby would pay a full H2D upload of the live mirrors only
-        # to discard it, doubling the swap's quiesce-held hydrate cost
+        # to discard it, doubling the swap's quiesce-held hydrate cost.
+        # `_dense_held`, chain -> {field: bytes}: the dense config arrays
+        # as the device tables hold them (_fresh_dense). Empty for an
+        # adopted pytree: the first drain then places every one
+        self._dense_held: dict = {}
+        self._replica_out = None  # the bulk replica as the last step left it
         self.tables: PipelineTables = (
             device_tables if device_tables is not None
             else self._device_tables())
@@ -622,6 +589,11 @@ class Engine:
                             if self.host_path == "vector" else None)
 
     def _device_tables(self) -> PipelineTables:
+        # what this upload holds of the dense arrays (the replica's comes
+        # with its next copy of the chain)
+        self._dense_held = {
+            chain: {f: a.tobytes() for f, a in self._dense_arrays(chain)}
+            for chain in ("dhcp", "nat", "rest")}
         return PipelineTables(
             dhcp=self.fastpath.device_tables(),
             nat=self.nat.device_tables(),
@@ -662,49 +634,63 @@ class Engine:
         self.tables = self._device_tables()
         self.resync_count += 1
 
-    def _drain_with_resync(self, drain, fastpath: bool, rest: bool):
-        """Run a make-updates drain over the fastpath tables, the rest, or
-        both; on the bulk-build "full upload" signal (bulk_insert
-        abandoned dirty tracking) answer with one full device re-upload
-        and drain again (now-clean) — a bulk build on a live engine must
-        not brick the step loop. The tracer hears how many of the drained
-        tables hold something to ship (a batch is built and uploaded)
-        and how many are clean (the batch already on the chip serves)."""
-        if tele.t() is not None:
-            tabs = [t for name, t in self.host_mirror_tables().items()
-                    if (fastpath if name.startswith("fastpath/") else rest)]
-            built = sum(1 for t in tabs if t.dirty_count())
-            tele.drain_tables(built, len(tabs) - built)
+    def _drain(self, fastpath: bool, rest: bool):
+        """Drain the dirty sets of the fastpath tables, of every other
+        table, or of both, by what the host mirrors show: (the dhcp
+        chain's batch or None, the other tables' batch or None). A chain
+        with NOTHING dirty yields None and costs nothing: no batch is
+        built, no call is made, no program scatters (the steady state:
+        lease and session writes arrive in bursts from the slow path). A
+        chain with something to ship yields its bounded batch
+        (make_update: the dirty tables' rows uploaded, the all-padding
+        batch already on the chip for its clean ones), for the
+        packet-free program ahead of the step (_apply_drained). On the
+        bulk-build "full upload" signal (bulk_insert abandoned dirty
+        tracking) the answer is one full device re-upload, after which
+        nothing is left to ship — a bulk build on a live engine must not
+        brick the step loop. The tracer hears how many of the drained
+        tables hold something to ship (what the apply call carries) and
+        how many are clean (and cost nothing)."""
+        fp_dirty = rest_dirty = clean = 0
+        for name, t in self.host_mirror_tables().items():
+            of_chain = name.startswith("fastpath/")
+            if not (fastpath if of_chain else rest):
+                continue
+            if not t.dirty_count():
+                clean += 1
+            elif of_chain:
+                fp_dirty += 1
+            else:
+                rest_dirty += 1
+        tele.drain_tables(fp_dirty + rest_dirty, clean)
         try:
-            return drain()
+            return (self.fastpath.make_updates() if fp_dirty else None,
+                    self._updates(True) if rest_dirty else None)
         except RuntimeError as e:
             if "full upload" not in str(e):
                 raise
             self.resync_tables()
-            return drain()
+            return None, None
 
-    def _updates(self, fastpath: bool, rest: bool):
-        """The update batch a fused step receives, in _apply_all_updates'
-        order. `fastpath` / `rest`: whether the dirty sets of the fastpath
-        tables / of every other table are drained (make_update), or stay
-        queued behind the no-op batch (empty_update). Only what changed is
-        uploaded either way: a clean table's batch is the one already on
-        the chip (HostTable.make_update), and the dense config arrays
-        (spoof ranges/config, garden allowlist, NAT hairpin/alg/config,
-        DHCP pools/server, the edge set's) are compared with what was
-        last placed and placed again when they differ (ops/table.py
-        placed) — the step applies them wholesale, so a write made before
-        this call is in this batch."""
+    def _updates(self, drain: bool):
+        """The update batch of every table but the dhcp chain's, in
+        _apply_all_updates' order with None in the chain's place (the
+        packet-free program runs over tables without the chain).
+        `drain`: the dirty sets are drained (make_update), or stay queued
+        behind the all-padding batch (empty_update: what start-up builds
+        the program with). Only what changed is uploaded: a clean table's
+        batch is the one already on the chip (HostTable.make_update), and
+        the dense config arrays are placed again when they differ from
+        what was last placed (ops/table.py placed)."""
         sp, g, p, e, v, q = (self.antispoof, self.garden, self.pppoe,
                              self.edge, self.v6, self.qinq)
 
         def one(t, slots):
-            return t.make_update(slots) if rest else t.empty_update(slots)
+            return t.make_update(slots) if drain else t.empty_update(slots)
 
         return (
-            (self.fastpath.make_updates() if fastpath
-             else self.fastpath.empty_updates()),
-            self.nat.make_updates() if rest else self.nat.empty_updates(),
+            None,
+            self.nat.make_updates() if drain else self.nat.empty_updates(),
             one(self.qos.up, self.qos.update_slots),
             one(self.qos.down, self.qos.update_slots),
             one(sp.bindings, sp.update_slots),
@@ -712,15 +698,90 @@ class Engine:
             placed(sp, "config", sp.config),
             *((one(g.subscribers, g.update_slots),
                placed(g, "allowed", g.allowed)) if g else ()),
-            *((p.make_updates() if rest else p.empty_updates()) if p else ()),
-            *((e.make_updates() if rest else e.empty_updates()) if e else ()),
+            *((p.make_updates() if drain else p.empty_updates()) if p else ()),
+            *((e.make_updates() if drain else e.empty_updates()) if e else ()),
             *((one(v.by_addr, v.update_slots),) if v else ()),
             *((one(q.by_ip, q.update_slots),) if q else ()),
         )
 
-    def _drain_updates(self):
-        return self._drain_with_resync(lambda: self._updates(True, True),
-                                       True, True)
+    def _empty_updates(self):
+        """The all-padding batch of every table but the dhcp chain's: no
+        dirty set is consumed. What start-up builds the packet-free
+        program with (build_step_rungs)."""
+        return self._updates(False)
+
+    def _dense_arrays(self, chain: str):
+        """(field, host array) of the small dense config arrays of one
+        chain of the device tables: "dhcp" (DHCPTables: pools, server),
+        "nat" (NATTables: hairpin, ALG ports, config) or "rest" (the
+        PipelineTables' own: spoof ranges and config, the garden
+        allowlist, the edge set's filters and config)."""
+        if chain == "dhcp":
+            return (("pools", self.fastpath.pools),
+                    ("server", self.fastpath.server))
+        if chain == "nat":
+            return (("hairpin_ips", self.nat.hairpin),
+                    ("alg_ports", self.nat.alg),
+                    ("config", self.nat.config_array()))
+        sp, g, e = self.antispoof, self.garden, self.edge
+        return (("spoof_ranges", sp.ranges), ("spoof_config", sp.config),
+                *((("garden_allowed", g.allowed),) if g else ()),
+                *((("tap_filters", e.tap_filters),
+                   ("tap_config", e.tap_config)) if e else ()))
+
+    def _fresh_dense(self, chain: str, node, held: str | None = None):
+        """`node` (the DHCPTables, NATTables or PipelineTables that holds
+        `chain`'s dense arrays) with those whose host bytes are not what it
+        holds replaced; `node` itself in the steady state. `held`: whose
+        record of what is held, the chain's own unless `node` is the bulk
+        replica. No program runs: the fields are `_replace`d on the host,
+        and a write made before a dispatch is in the tables that
+        dispatch's step reads. The compare is on bytes (16 KB at the
+        most), so a write in place is seen. Each array is a fresh upload
+        of a copy (the step donates the tables it is given, so no cached
+        array may go into them, and on the CPU backend asarray may alias
+        host memory the owner writes in place), placed as the array it
+        replaces is: on its device where that one is committed to one
+        (the express lane's own; every table behind a committed bulk
+        replica), so the programs built for the tables still fit them."""
+        rec = self._dense_held.setdefault(held or chain, {})
+        new = {}
+        for f, host in self._dense_arrays(chain):
+            now = host.tobytes()
+            if rec.get(f) != now:
+                rec[f] = now
+                old = getattr(node, f)
+                t0 = tele.t()
+                new[f] = (jax.device_put(host.copy(), next(iter(old.devices())))
+                          if old.committed else jnp.asarray(host.copy()))
+                tele.xfer(tele.UPLOAD, t0, host.nbytes)
+        return node._replace(**new) if new else node
+
+    def _place_dense(self) -> None:
+        """The dense config arrays, as the host holds them now, into
+        `self.tables`: every table's but the dhcp chain's."""
+        t = self._fresh_dense("rest", self.tables)
+        nat = self._fresh_dense("nat", t.nat)
+        self.tables = t if nat is t.nat else t._replace(nat=nat)
+
+    def _place_dense_dhcp(self) -> None:
+        """Pools and server config, as the host holds them now, into the
+        authoritative dhcp chain."""
+        dhcp = self._fresh_dense("dhcp", self.tables.dhcp)
+        if dhcp is not self.tables.dhcp:
+            self.tables = self.tables._replace(dhcp=dhcp)
+
+    def _apply_drained(self, fp_upd, rest_upd, device=None) -> None:
+        """A drain's batches through the packet-free programs, on the same
+        tables, donated, before `self.tables` is read for the step. None:
+        that chain shipped nothing and no call is made."""
+        if fp_upd is not None:
+            if device is not None:
+                fp_upd = jax.device_put(fp_upd, device)
+            self.tables = self.tables._replace(
+                dhcp=_apply_fastpath_jit(self.tables.dhcp, fp_upd))
+        if rest_upd is not None:
+            self.apply_updates_now(rest_upd)
 
     # -- latency-tiered scheduler support (runtime/scheduler.py) ----------
     #
@@ -731,45 +792,57 @@ class Engine:
     # the dhcp leaves). These helpers keep the donation bookkeeping here,
     # next to the invariants they must preserve.
 
-    def _make_bulk_updates(self):
-        """Update drain for a scheduler bulk step: real deltas for every
-        bulk-owned table, a NO-OP for the fastpath tables — the express
-        lane is the single consumer of the fastpath drain (one
-        authoritative device DHCP chain, never forked)."""
-        return self._drain_with_resync(lambda: self._updates(False, True),
-                                       False, True)
-
-    def _empty_updates(self):
-        """No-op update batch for scheduler bulk steps between
-        drain-cadence points: no dirty set is consumed, and the dense
-        config arrays are as live as in any other batch (_updates)."""
-        return self._updates(False, False)
-
     def prefetch_bulk_updates(self):
         """Build (and start uploading) the NEXT bulk drain's update batch
         while the current step still executes — the overlap-drain half of
-        VERDICT r5 item 3. Consumes the host dirty sets exactly like the
-        in-dispatch drain (the delta is simply built one step early;
-        writes landing after the prefetch ride the following drain), and
-        the jnp.asarray transfers inside start their H2D copies
-        immediately, so by the next dispatch the scatter operands are
-        already device-resident. The caller (TieredScheduler) OWNS the
-        returned batch: it must reach the device via the next
-        dispatch_scheduled_bulk(upd=...) or apply_updates_now(), or host
-        and HBM silently diverge."""
+        VERDICT r5 item 3. Consumes the host dirty sets of every table but
+        the fastpath's (the express lane is the single consumer of the
+        fastpath drain: one authoritative device DHCP chain, never
+        forked) exactly like the in-dispatch drain: the delta is simply
+        built one step early; writes landing after the prefetch ride the
+        following drain, and the jnp.asarray transfers inside start their
+        H2D copies immediately, so by the next dispatch the scatter
+        operands are already device-resident. With nothing dirty the
+        batch is `()`: drained, and nothing to apply. The caller
+        (TieredScheduler) OWNS the returned batch: it must reach the
+        device via the next dispatch_scheduled_bulk(upd=...) or
+        apply_updates_now(), or host and HBM silently diverge."""
         return self._make_bulk_updates()
 
+    def _make_bulk_updates(self):
+        """Update drain for a scheduler bulk step: the batch of every
+        bulk-owned table, `()` with nothing dirty among them; the
+        fastpath tables' dirty sets stay queued for the express lane."""
+        return self._drain(False, True)[1] or ()
+
     def apply_updates_now(self, upd) -> None:
-        """Apply one already-built BULK update batch with no packet batch
-        — the flush/quiesce path for a prefetched drain no later batch
-        consumed. Donates and rebinds the non-dhcp device tables like
-        the step; the authoritative dhcp chain (possibly express-lane
-        device-resident) never enters the program."""
-        step = _apply_updates_jit(self.geom, self.garden is not None,
-                                  self.pppoe is not None,
-                                  self.edge is not None)
-        rest = step(self.tables._replace(dhcp=None), upd)
+        """Apply one already-built BULK update batch with no packet batch:
+        the road of every dirty drain of the tables outside the dhcp
+        chain, ahead of its step, and of a prefetched drain no later batch
+        consumed (flush/quiesce). An empty batch (`()`: the drain found
+        nothing dirty) makes no call. Donates and rebinds the non-dhcp
+        device tables like a step; the authoritative dhcp chain (possibly
+        express-lane device-resident) never enters the program."""
+        if not upd:
+            return
+        rest = _apply_updates_jit(self.tables._replace(dhcp=None), upd)
         self.tables = rest._replace(dhcp=self.tables.dhcp)
+
+    def dhcp_replica(self, copy):
+        """A copy of the authoritative dhcp chain for the bulk lane, leaf
+        by leaf through `copy`, with the chain's record of the dense
+        arrays it holds (dispatch_scheduled_bulk keeps pools and server
+        live on it between copies)."""
+        rep = jax.tree_util.tree_map(copy, self.tables.dhcp)
+        self._note_replica(rep, self._dense_held.get("dhcp", {}))
+        return rep
+
+    def _note_replica(self, replica, held=None) -> None:
+        """`replica` is the bulk replica as the engine last saw it; with
+        `held`, what it holds of the dense arrays."""
+        self._replica_out = replica
+        if held is not None:
+            self._dense_held["replica"] = dict(held)
 
     def dispatch_scheduled_bulk(self, pkt, length, fa, now: float,
                                 dhcp_replica, drain: bool = True,
@@ -780,31 +853,40 @@ class Engine:
         authoritative dhcp chain: self.tables.dhcp is NOT an input, so the
         express program's next dispatch has no data dependency on this
         step. The replica is donated and threaded bulk->bulk by the
-        caller. drain=False passes the cached no-op update batch — the
-        scheduler owns the drain cadence; a prefetched batch from
-        prefetch_bulk_updates() arrives via `upd` (overlap-drain mode)
-        and takes the drain's place. The step runs at the lane count the
-        caller packed to (the scheduler picks the rung, step_rung).
+        caller. The step takes no update batch: drain=True drains the
+        bulk-owned tables here and a dirty drain goes through the
+        packet-free program ahead of the step (apply_updates_now; a clean
+        one makes no call); drain=False drains nothing — the scheduler
+        owns the drain cadence; a prefetched batch from
+        prefetch_bulk_updates() arrives via `upd` (overlap-drain mode),
+        takes the drain's place and the same road. The dense config
+        arrays are as live as the host's either way (_place_dense, and
+        pools / server on the replica). The step runs at the lane count
+        the caller packed to (the scheduler picks the rung, step_rung).
         Returns (res, new_replica); outputs are futures (retire at the
         completion ring, never here).
         """
         now_s = np.uint32(int(now))
         now_us = np.uint32(int(now * 1e6) & 0xFFFFFFFF)
-        if upd is not None:
-            pass  # prefetched drain: built (and uploading) since step N-1
-        elif drain:
+        if upd is None and drain:
             upd = self._make_bulk_updates()
-        else:
-            upd = self._empty_updates()
+        # a prefetched drain was built (and uploading) since step N-1
+        self.apply_updates_now(upd)
+        self._place_dense()
+        if dhcp_replica is not self._replica_out:
+            # not the replica the last step left, nor a copy this engine
+            # made (dhcp_replica): what it holds is not on record
+            self._dense_held["replica"] = {}
+        dhcp_replica = self._fresh_dense("dhcp", dhcp_replica, held="replica")
         # read self.tables AFTER the drain (a bulk-build resync rebinds it)
         tables_in = self.tables._replace(dhcp=dhcp_replica)
         res: PipelineResult = self._step(
-            tables_in, upd, *self._upload_batch(pkt, length, fa),
-            now_s, now_us)
+            tables_in, *self._upload_batch(pkt, length, fa), now_s, now_us)
         self._start_host_copies(res)
         # keep the authoritative dhcp chain out of the bulk rebind; the
         # replica-out threads back to the scheduler
         self.tables = res.tables._replace(dhcp=self.tables.dhcp)
+        self._note_replica(res.tables.dhcp)
         self.stats.batches += 1
         return res, res.tables.dhcp
 
@@ -1076,7 +1158,7 @@ class Engine:
         specific device — the scheduler's express lane."""
         self._dispatch_fault()
         B = pkt.shape[0]
-        upd = self._drain_fastpath_updates()
+        self._drain_fastpath_updates(device)
         # donation safety: the program donates the packet batch (out_pkt
         # aliases the staging upload). Every caller stages from numpy —
         # asarray then creates a fresh device buffer — but a jax-array
@@ -1087,18 +1169,13 @@ class Engine:
                  else jnp.asarray(pkt))
         len_d = jnp.asarray(length)
         if device is not None:
-            # placement AFTER the drain: a bulk-build resync inside it
-            # rebinds self.tables onto the default device
-            self._place_dhcp_chain(device)
-            upd = jax.device_put(upd, device)
             pkt_d = jax.device_put(pkt_d, device)
             len_d = jax.device_put(len_d, device)
         if tu is not None:  # the two from the host; the placement is
             # device to device
             tele.xfer(tele.UPLOAD, tu, pkt.nbytes + length.nbytes, 2)
         dhcp_tables, is_reply, out_pkt, out_len, stats = self._dhcp_step(
-            self.tables.dhcp, upd, pkt_d, len_d,
-            np.uint32(int(now)))
+            self.tables.dhcp, pkt_d, len_d, np.uint32(int(now)))
         self.tables = self.tables._replace(dhcp=dhcp_tables)
         self.stats.batches += 1
         verdict = jnp.where(is_reply, np.uint8(VERDICT_TX),
@@ -1120,24 +1197,34 @@ class Engine:
         self._fold_stats(res)
         return res
 
-    def _drain_fastpath_updates(self):
-        """Fastpath-only update drain for the express programs. The
-        steady-state fast lane has NOTHING dirty (lease writes arrive in
-        bursts from the slow path), and a clean table's make_update is
-        the batch already on the chip; any dirty slot takes the real
-        bounded drain, so an OFFER always sees the newest lease. Shapes
-        are identical either way — both feed the same compiled programs."""
-        return self._drain_with_resync(self.fastpath.make_updates, True, False)
+    def _drain_fastpath_updates(self, device=None) -> None:
+        """The dhcp chain, up to every host write made before this call:
+        the express programs' drain. The steady-state fast lane has
+        NOTHING dirty (lease writes arrive in bursts from the slow path)
+        and then this makes no call at all; any dirty slot takes the real
+        bounded drain through the chain's packet-free program
+        (_apply_fastpath_jit), so an OFFER always sees the newest lease;
+        pools and server config that changed on the host go into the
+        chain on the host. `device`: where the chain lives when the
+        express lane has a device of its own. That placement comes AFTER
+        the drain: a bulk-build resync inside it rebinds self.tables onto
+        the default device."""
+        fp_upd, _ = self._drain(True, False)
+        if device is not None:
+            self._place_dhcp_chain(device)
+        self._apply_drained(fp_upd, None, device)
+        self._place_dense_dhcp()
 
     # -- AOT express OFFER path (runtime/scheduler.py fast lane) ----------
 
     def _express_aot_key(self, batch: int, device) -> tuple:
         # DHCPGeom covers only bucket/stash shapes; the compiled
         # executable's avals also bake the dense pools array
-        # ([max_pools, POOL_WORDS]) and the update-batch scatter shapes
-        # (update_slots) — two engines differing only there must not
-        # share an executable (a call-time shape mismatch would crash
-        # the dispatch instead of falling back)
+        # ([max_pools, POOL_WORDS]) — two engines differing only there
+        # must not share an executable (a call-time shape mismatch would
+        # crash the dispatch instead of falling back). update_slots is
+        # the apply program's shape, and stays in the key: the hit that
+        # skips the compile skips that program's build too
         return (self.fastpath.geom, len(self.fastpath.pools),
                 self.fastpath.update_slots, batch,
                 None if device is None else str(device))
@@ -1153,9 +1240,13 @@ class Engine:
         one fixed batch geometry — engine/scheduler init time, NEVER the
         dispatch path. Cached on (geometry, device) so engines of
         one shape share a single executable. Lowering uses the live
-        chain's avals plus an EMPTY update batch (same pytree shapes as
-        a real drain; a real make_updates() here would consume dirty
-        state the next dispatch needs)."""
+        chain's avals. The chain's packet-free program is built here too
+        (one run over an EMPTY update batch, the pytree shapes of a real
+        drain, placed as a real drain's: a real make_updates() here
+        would consume dirty state the next dispatch needs), so the first
+        dirty drain of a window builds nothing. A cache hit skips both:
+        the engine that compiled the executable built that program for
+        the same shapes."""
         from bng_tpu.ops.express import XD_WORDS
 
         key = self._express_aot_key(batch, device)
@@ -1165,11 +1256,17 @@ class Engine:
         if device is not None:
             self._place_dhcp_chain(device)
         dev = device if device is not None else jax.devices()[0]
-        upd = jax.device_put(self.fastpath.empty_updates(), dev)
         desc = jax.device_put(jnp.zeros((batch, XD_WORDS), jnp.uint32), dev)
         now_d = jax.device_put(jnp.uint32(0), dev)
         exe = _express_jit(self.fastpath.geom).lower(
-            self.tables.dhcp, upd, desc, now_d).compile()
+            self.tables.dhcp, desc, now_d).compile()
+        # one inert batch (no descriptor: no lane answers, the tables are
+        # read only), so that the apply program is built for the chain as
+        # every later drain finds it: the one an executable returns is
+        # committed to its device, and a fresh upload is not
+        dhcp_tables, _block, _stats = exe(self.tables.dhcp, desc, now_d)
+        self.tables = self.tables._replace(dhcp=dhcp_tables)
+        self._apply_drained(self.fastpath.empty_updates(), None, device)
         _EXPRESS_AOT[key] = exe
         return exe
 
@@ -1181,7 +1278,7 @@ class Engine:
         lease), the authoritative dhcp chain threads (donated) through
         the program, outputs stay futures until the ring retire."""
         self._dispatch_fault()
-        upd = self._drain_fastpath_updates()
+        self._drain_fastpath_updates(device)
         # donation safety (the _run_dhcp_batch pkt guard): the program
         # donates the descriptor and writes the verdict block over its
         # lead columns. Callers stage from numpy (fresh device buffer);
@@ -1191,10 +1288,6 @@ class Engine:
         desc_d = (jnp.array(desc, copy=True) if isinstance(desc, jax.Array)
                   else jnp.asarray(desc))
         if device is not None:
-            # placement AFTER the drain: a bulk-build resync inside it
-            # rebinds self.tables onto the default device
-            self._place_dhcp_chain(device)
-            upd = jax.device_put(upd, device)
             desc_d = jax.device_put(desc_d, device)
             now_d = jax.device_put(jnp.uint32(int(now)), device)
         else:
@@ -1205,7 +1298,7 @@ class Engine:
         if tu is not None:  # the descriptors and the clock word
             tele.xfer(tele.UPLOAD, tu, desc.nbytes + 4, 2)
         dhcp_tables, block, stats = express_exe(
-            self.tables.dhcp, upd, desc_d, now_d)
+            self.tables.dhcp, desc_d, now_d)
         self.tables = self.tables._replace(dhcp=dhcp_tables)
         self.stats.batches += 1
         return _ExpressAotResult(
@@ -1238,6 +1331,15 @@ class Engine:
             outs.append(getattr(res, "mirror", None))
         start_host_copies(outs)
 
+    def _drain_updates(self) -> None:
+        """Every device table, up to every host write made before this
+        call: the fused step's drain. Clean (the steady state): no call;
+        a dirty chain goes through its packet-free program; a dense config
+        array that changed is `_replace`d on the host."""
+        self._apply_drained(*self._drain(True, True))
+        self._place_dense()
+        self._place_dense_dhcp()
+
     def _dispatch_step(self, pkt, length, fa, n: int,
                        now_s, now_us) -> PipelineResult:
         """Enqueue one jitted step (async — outputs are futures). The table
@@ -1256,10 +1358,10 @@ class Engine:
         # evaluates arguments left-to-right — reading self.tables before
         # the drain would pass (and donate) the stale pre-resync reference
         t0 = tele.t()
-        upd = self._drain_updates()
+        self._drain_updates()
         tele.lap(tele.DRAIN, t0)
         staged = self._upload_batch(pkt[:b], length[:b], fa[:b])
-        res: PipelineResult = self._step(self.tables, upd, *staged,
+        res: PipelineResult = self._step(self.tables, *staged,
                                          now_s, now_us)
         self._start_host_copies(res)
         self.tables = res.tables
@@ -1274,7 +1376,8 @@ class Engine:
         table changes) goes through each rung, which traces, lowers and
         compiles or loads it as a first window would, one after the other
         on this thread (a load from the compile cache handed to a worker
-        thread took 4.9 s on the chip against 1.0 s here). `batch`: the
+        thread took 4.9 s on the chip against 1.0 s here). The two
+        packet-free apply programs are built with them. `batch`: the
         ladder's top where it is not `self.B` (the scheduler's bulk
         batch). `dhcp`: the scheduler's bulk replica, threaded in place of
         the authoritative chain as its dispatches thread it; the replica
@@ -1298,18 +1401,26 @@ class Engine:
             rest = jax.device_put(self.tables._replace(dhcp=None),
                                   next(iter(held[0].devices())))
             self.tables = rest._replace(dhcp=self.tables.dhcp)
-        upd = self._empty_updates()
         for b in rungs:
             inert = self._upload_batch(np.zeros((b, self.L), dtype=np.uint8),
                                        np.zeros((b,), dtype=np.uint32),
                                        np.zeros((b,), dtype=bool))
-            res = self._step(tables_in(), upd, *inert, now_s, now_us)
+            res = self._step(tables_in(), *inert, now_s, now_us)
             if dhcp is None:
                 self.tables = res.tables
             else:
                 self.tables = res.tables._replace(dhcp=self.tables.dhcp)
                 dhcp = res.tables.dhcp
-        jax.block_until_ready(res.verdict)
+        # and the packet-free programs a dirty drain takes ahead of a step,
+        # over the tables as a step leaves them: the chain's own only where
+        # this loop threads the authoritative chain (the scheduler's
+        # express lane builds it on its device, compile_express_aot)
+        self._apply_drained(
+            self.fastpath.empty_updates() if dhcp is None else None,
+            self._empty_updates())
+        jax.block_until_ready((res.verdict, self.tables))
+        if dhcp is not None:
+            self._note_replica(dhcp)
         return dhcp
 
     @staticmethod
@@ -1692,8 +1803,11 @@ class Engine:
         verify gate already enforced that. This is the ONE sanctioned
         rebind of .tables outside the step/resync paths; the delta
         accumulated since the snapshot is replayed afterwards through
-        the normal bounded update drain (ops.replay_delta_since)."""
+        the normal bounded update drain (ops.replay_delta_since); what
+        the adopted pytree holds of the dense config arrays is not on
+        record, so the next drain places every one."""
         self.tables = tables
+        self._dense_held = {}
 
     def host_mirror_tables(self) -> dict:
         """{name: HostTable|HostQTable} of every sparse host mirror this

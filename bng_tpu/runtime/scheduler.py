@@ -30,10 +30,11 @@ for the TPU re-host:
 
 The scheduler also owns the cadence of the engine's bounded table-update
 drain: the express lane drains the fastpath delta before every dispatch
-(an OFFER must see the newest lease), while bulk steps apply real
-NAT/QoS/antispoof deltas only every `drain_every` dispatches and cached
-no-op update batches in between (zero host->HBM traffic on non-drain
-steps).
+(an OFFER must see the newest lease), while the bulk-owned tables are
+drained only every `drain_every` bulk dispatches. No step program takes an
+update batch: a drain that finds nothing dirty makes no call and moves
+nothing, and one that finds something goes through the engine's
+packet-free program ahead of the step (Engine.apply_updates_now).
 
 Single-process, poll-driven: `submit()` frames, `poll()` each beat (the
 CLI run loop), or use `process()` — the batch-synchronous facade the
@@ -679,8 +680,7 @@ class TieredScheduler:
                 and self._replica_resync == eng.resync_count):
             return
         t0 = tele.t()
-        self._bulk_dhcp = jax.tree_util.tree_map(self._copy_to_bulk,
-                                                 eng.tables.dhcp)
+        self._bulk_dhcp = eng.dhcp_replica(self._copy_to_bulk)
         tele.lap(tele.DRAIN, t0)
         self._replica_resync = eng.resync_count
         self._replica_refreshes += 1
@@ -688,22 +688,22 @@ class TieredScheduler:
     def build_bulk_rungs(self) -> None:
         """Start-up's: every rung of the fused step's ladder a bulk batch
         can take, built and run once over the replica before the lane
-        takes frames (Engine.build_step_rungs)."""
+        takes frames, and the bulk tables' packet-free apply program
+        (Engine.build_step_rungs)."""
         if self._express_dev is not None:
             # the express lane's placement first (a resync undoes it): the
             # replica is then the copy across devices every refresh makes,
             # and the rungs are built for the tables a step finds later
             self.engine._place_dhcp_chain(self._express_dev)
         # the first dispatch's refresh replaces (and counts) this replica
-        self._bulk_dhcp = jax.tree_util.tree_map(
-            self._copy_to_bulk, self.engine.tables.dhcp)
+        self._bulk_dhcp = self.engine.dhcp_replica(self._copy_to_bulk)
         self._replica_resync = self.engine.resync_count
         batch = self.bulk.cfg.batch
+        # with the packet-free program a dirty or a prefetched drain takes
+        # (Engine.apply_updates_now; _flush_prefetched when the traffic
+        # goes quiet behind a prefetch)
         self._bulk_dhcp = self.engine.build_step_rungs(
             batch, batch=batch, dhcp=self._bulk_dhcp)
-        # and the packet-free program a prefetched drain takes when the
-        # traffic goes quiet behind it (_flush_prefetched)
-        self.engine.apply_updates_now(self.engine._empty_updates())
 
     def _copy_to_bulk(self, x):
         """A buffer the bulk chain may freely donate: device transfer when

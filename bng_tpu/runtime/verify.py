@@ -141,7 +141,7 @@ def build_dhcp_express(g: Geometry = TOY):
     B = g.express_batch
     fp = _fastpath(g)
     return _dhcp_jit(fp.geom), (
-        fp.device_tables(), fp.empty_updates(),
+        fp.device_tables(),
         jnp.zeros((B, g.pkt_slot), dtype=jnp.uint8),
         jnp.zeros((B,), dtype=jnp.uint32), jnp.uint32(1))
 
@@ -157,9 +157,29 @@ def build_express_aot(g: Geometry = TOY):
 
     fp = _fastpath(g)
     return _express_jit(fp.geom), (
-        fp.device_tables(), fp.empty_updates(),
+        fp.device_tables(),
         jnp.zeros((g.express_batch, XD_WORDS), dtype=jnp.uint32),
         jnp.uint32(1))
+
+
+def build_apply_fastpath(g: Geometry = TOY):
+    """The dhcp chain's packet-free apply program: what a dirty fastpath
+    drain goes through ahead of its step (no step takes an update batch),
+    the chain donated."""
+    from bng_tpu.runtime.engine import _apply_fastpath_jit
+
+    fp = _fastpath(g)
+    return _apply_fastpath_jit, (fp.device_tables(), fp.empty_updates())
+
+
+def build_apply_updates(g: Geometry = TOY):
+    """The same for every table outside the dhcp chain
+    (Engine.apply_updates_now): tables without the chain, donated."""
+    from bng_tpu.runtime.engine import _apply_updates_jit
+
+    eng = _engine(g)
+    return _apply_updates_jit, (eng.tables._replace(dhcp=None),
+                                eng._empty_updates())
 
 
 def _engine(g: Geometry):
@@ -195,13 +215,14 @@ def _engine(g: Geometry):
 
 
 def build_pipeline(g: Geometry = TOY, lanes: int | None = None):
-    """The fused step as the Engine compiles it: updates applied inside,
-    tables donated. `lanes`: a rung of the step's ladder under `g.batch`
-    (engine.py step_rungs), the width a shorter window is dispatched at."""
+    """The fused step as the Engine compiles it: tables (donated), window
+    and clock, no update batch. `lanes`: a rung of the step's ladder under
+    `g.batch` (engine.py step_rungs), the width a shorter window is
+    dispatched at."""
     eng = _engine(g)
     B = lanes or g.batch
     return eng._step, (
-        eng.tables, eng._empty_updates(),
+        eng.tables,
         jnp.zeros((B, g.pkt_slot), dtype=jnp.uint8),
         jnp.full((B,), 300, dtype=jnp.uint32), jnp.ones((B,), dtype=bool),
         jnp.uint32(1), jnp.uint32(1))
@@ -232,7 +253,9 @@ def build_sharded(mesh, g: Geometry = TOY):
     now = jax.ShapeDtypeStruct((), jnp.uint32, sharding=whole)
     return _sharded_step_jit(mesh, eng.geom, n), (
         jax.tree.map(stacked, eng.tables),
-        jax.tree.map(stacked, eng._empty_updates()),
+        # the mesh step still takes its batch: the chain's in front
+        jax.tree.map(stacked, (eng.fastpath.empty_updates(),
+                               *eng._empty_updates()[1:])),
         lanes(g.pkt_slot, dtype=jnp.uint8), lanes(dtype=jnp.uint32),
         lanes(dtype=jnp.bool_), now, now)
 
@@ -269,6 +292,8 @@ CHECKS: list[tuple[str, Callable[[], None]]] = [
     # offer_device_only_p99_us gate measures on the express lane
     ("express_aot", _compiles(build_express_aot)),
     ("fused_pipeline_step", _compiles(build_pipeline)),
+    ("apply_fastpath", _compiles(build_apply_fastpath)),
+    ("apply_updates", _compiles(build_apply_updates)),
     ("sharded_step", _check_sharded),
 ]
 

@@ -326,6 +326,44 @@ def make_express(geom):
 """})
         assert run_on(tmp_path, {"jit-discipline"}) == []
 
+    @pytest.mark.parametrize("entry", ["pipeline_step", "dhcp_fastpath"])
+    @pytest.mark.parametrize("donate,want", [("", {"BNG011"}),
+                                             (", donate_argnums=(0,)", set())])
+    def test_step_without_an_update_argument_still_donates(
+            self, tmp_path, entry, donate, want):
+        # PR 50: no one-chip step applies a delta, and each still threads
+        # the tables it is given (counters, tokens; the chain unchanged)
+        write_tree(tmp_path, {"bng_tpu/runtime/thing.py": f"""\
+import functools
+
+import jax
+
+
+@functools.lru_cache(maxsize=8)
+def make_step(geom):
+    def step(tables, pkt, length, now_s):
+        return {entry}(tables, pkt, length, geom, now_s)
+    return jax.jit(step{donate})
+"""})
+        assert codes_of(run_on(tmp_path, {"jit-discipline"})) == want
+
+    @pytest.mark.parametrize("fn", ["_apply_all_updates",
+                                    "apply_fastpath_updates"])
+    @pytest.mark.parametrize("donate,want", [("", {"BNG011"}),
+                                             (", donate_argnums=(0,)", set())])
+    def test_apply_function_jitted_as_it_stands_donates(
+            self, tmp_path, fn, donate, want):
+        # the engine's two packet-free programs: the apply function itself
+        # under one module-level jit (no factory: BNG010 has no say)
+        write_tree(tmp_path, {"bng_tpu/runtime/thing.py": f"""\
+import jax
+
+from somewhere import {fn}
+
+_apply_jit = jax.jit({fn}{donate})
+"""})
+        assert codes_of(run_on(tmp_path, {"jit-discipline"})) == want
+
     def test_bare_scalar_at_express_exe_call_flagged(self, tmp_path):
         # the AOT executable call site obeys the same fixed-width
         # scalar discipline as the jitted steps

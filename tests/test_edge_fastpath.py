@@ -498,7 +498,7 @@ def test_beside_a_blocked_fleet_a_committed_lease_still_gets_its_route_row():
 def lowered(eng) -> str:
     b = eng.B
     return eng._step.lower(
-        eng.tables, eng._empty_updates(), np.zeros((b, eng.L), np.uint8),
+        eng.tables, np.zeros((b, eng.L), np.uint8),
         np.zeros((b,), np.uint32), np.zeros((b,), bool), np.uint32(T0),
         np.uint32(0)).as_text()
 

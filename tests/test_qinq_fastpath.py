@@ -382,8 +382,7 @@ def test_beside_the_v6_stage_an_ipv6_lane_is_popped_and_pushed_like_a_v4_one():
 def _step_hlo(st) -> str:
     eng = st.engine
     return str(eng._step.lower(
-        eng.tables, eng._drain_updates(),
-        jnp.zeros((BATCH, eng.L), jnp.uint8), jnp.zeros((BATCH,), jnp.uint32),
+        eng.tables, jnp.zeros((BATCH, eng.L), jnp.uint8), jnp.zeros((BATCH,), jnp.uint32),
         jnp.zeros((BATCH,), bool), np.uint32(1), np.uint32(1)
     ).compiler_ir(dialect="stablehlo"))
 

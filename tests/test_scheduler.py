@@ -32,6 +32,7 @@ from bng_tpu.control.metrics import BNGMetrics
 from bng_tpu.control.nat import NATManager
 from bng_tpu.control.pool import Pool, PoolManager
 from bng_tpu.ops.express import XD_WORDS
+from bng_tpu.runtime import engine as engine_mod
 from bng_tpu.runtime.engine import AntispoofTables, Engine, QoSTables
 from bng_tpu.runtime.lanes import (CLOSE_DEADLINE, CLOSE_FULL, CompletionRing,
                                    InflightEntry, Lane, LaneConfig)
@@ -75,6 +76,32 @@ def build_stack(batch_size=8, clock=None, slow_path="server"):
     engine = Engine(fastpath, nat, qos, spoof, batch_size=batch_size,
                     slow_path=sp, clock=clock)
     return engine, server, clock
+
+
+def spy_bulk_drains(engine):
+    """([what each bulk drain returned], [each non-empty batch that went
+    through the packet-free apply program]) from now on."""
+    drains, applied = [], []
+    make, apply_now = engine._make_bulk_updates, engine.apply_updates_now
+    engine._make_bulk_updates = lambda: (drains.append(make()), drains[-1])[1]
+    engine.apply_updates_now = lambda upd: (
+        applied.append(upd) if upd else None, apply_now(upd))[1]
+    return drains, applied
+
+
+def bulk_dispatch(engine, drain: bool, replica=None):
+    """One inert window through the scheduler's bulk road; the replica as
+    the step left it."""
+    import numpy as np
+
+    if replica is None:
+        replica = engine.dhcp_replica(jax.numpy.copy)
+    pkt = np.zeros((engine.B, engine.L), np.uint8)
+    length = np.zeros((engine.B,), np.uint32)
+    _res, replica = engine.dispatch_scheduled_bulk(
+        pkt, length, np.zeros((engine.B,), bool), 1_753_000_000.0, replica,
+        drain=drain)
+    return replica
 
 
 def discover(mac: bytes, xid: int) -> bytes:
@@ -469,20 +496,19 @@ class TestUpdateDrainCadence:
         sched = TieredScheduler(engine, SchedulerConfig(
             bulk_batch=8, bulk_depth=2, drain_every=3,
             overlap_drain=False), clock=clock)
-        nat_calls = []
-        orig = engine.nat.make_updates
-        engine.nat.make_updates = lambda: (nat_calls.append(1), orig())[1]
+        drains, applied = spy_bulk_drains(engine)
         for i in range(6 * 8):  # six bulk dispatches under sustained load
             sched.submit(data_frame(i))
         sched.poll()
         sched.flush()
         assert sched.bulk.stats.batches == 6
         # drains at bulk_seq 0, 3 — every third dispatch only
-        assert len(nat_calls) == 2
+        assert len(drains) == 2
         assert sched._drains_applied == 2
         assert sched._drains_prefetched == 0
-        # the no-drain steps reused the cached no-op scatter buffers
-        assert engine.nat.sessions._empty_upd_cache
+        # nothing was dirty at either: no drain built a batch, and no step
+        # of the six was preceded by an apply call
+        assert drains == [(), ()] and applied == []
 
     def test_overlap_drain_prefetches_next_scatter(self):
         """overlap_drain (default): the drain-due step's scatter is built
@@ -493,17 +519,15 @@ class TestUpdateDrainCadence:
         engine, _, clock = build_stack(batch_size=8)
         sched = TieredScheduler(engine, SchedulerConfig(
             bulk_batch=8, bulk_depth=2, drain_every=3), clock=clock)
-        nat_calls = []
-        orig = engine.nat.make_updates
-        engine.nat.make_updates = lambda: (nat_calls.append(1), orig())[1]
+        drains, applied = spy_bulk_drains(engine)
         for i in range(6 * 8):
             sched.submit(data_frame(i))
         sched.poll()
         sched.flush()
         assert sched.bulk.stats.batches == 6
-        # builds: in-dispatch at seq 0, prefetched for seq 3 and seq 6;
-        # seq 6 never dispatched, so its batch applied at flush
-        assert len(nat_calls) == 3
+        # drains: in-dispatch at seq 0, prefetched for seq 3 and seq 6;
+        # seq 6 never dispatched, so its (empty) batch is settled at flush
+        assert len(drains) == 3 and applied == []
         assert sched._drains_prefetched == 2
         assert sched._drains_applied == 3  # seq 0, seq 3, flush-applied
         assert sched._prefetched_upd is None
@@ -535,86 +559,106 @@ class TestUpdateDrainCadence:
         assert np.array_equal(dev_row, engine.qos.up.rows[slot])  # ...and on device
 
     def test_no_drain_steps_carry_live_dense_config(self):
-        """The no-op batch must NOT snapshot the dense config arrays: the
-        step applies them wholesale, so a cached copy would revert live
-        antispoof/garden/NAT config on every no-drain step."""
+        """A step that drains nothing must still read the dense config
+        arrays as the host holds them: a no-drain bulk step after a
+        config change has it in its tables (and the all-padding batch the
+        apply program is built with still carries it, not a build-time
+        snapshot)."""
         engine, _, clock = build_stack()
         engine._empty_updates()  # primes the scatter caches
         engine.antispoof.add_allowed_range(ip_to_u32("172.16.0.0"), 12)
         after = engine._empty_updates()
         import numpy as np
 
-        # upd layout: spoof ranges ride at index 5; a no-drain batch
-        # built after the config change must carry it (no build-time
-        # snapshot; jnp.asarray may or may not alias host memory, so
-        # only the fresh-batch property is contractual)
+        # upd layout: spoof ranges ride at index 5
         sp_ranges = np.asarray(after[5])
         assert (sp_ranges[:, 1] == ip_to_u32("172.16.0.0")).any()
+        bulk_dispatch(engine, drain=False)
+        on_chip = np.asarray(engine.tables.spoof_ranges)
+        assert (on_chip[:, 1] == ip_to_u32("172.16.0.0")).any()
 
-    @pytest.mark.parametrize("drain", ["_empty_updates", "_drain_updates",
-                                       "_make_bulk_updates"])
+    @pytest.mark.parametrize("drain", ["no_drain_bulk", "_drain_updates",
+                                       "drain_bulk"])
     @pytest.mark.parametrize("array", ["spoof_ranges", "nat_hairpin",
                                        "nat_config", "pools", "server"])
     def test_every_batch_carries_live_dense_config(self, drain, array):
-        """The twin of the test above for every builder and for the nat /
-        fastpath arrays (PR 35: a dense array is placed once and placed
-        again when its bytes changed). A change made after a batch was
-        built is in the NEXT batch, the batch built before keeps what it
-        was given, and an unchanged array is the same device array."""
+        """The twin of the test above for every road to a step and for the
+        nat / fastpath arrays (PR 35: a dense array is placed once and
+        placed again when its bytes changed; PR 50: into the tables, on the
+        host, with no program). A change made before a dispatch is in the
+        tables its step reads (the bulk replica's for pools and server on
+        the bulk roads), and an unchanged array crosses nothing."""
         import numpy as np
 
         engine, _, clock = build_stack()
-        build = getattr(engine, drain)
+        replica = [engine.dhcp_replica(jax.numpy.copy)]
+
+        def bulk(drain_flag):
+            replica[0] = bulk_dispatch(engine, drain_flag, replica[0])
+
+        sync = {"no_drain_bulk": lambda: bulk(False),
+                "drain_bulk": lambda: bulk(True),
+                "_drain_updates": engine._drain_updates}[drain]
+
+        def dhcp():
+            return (engine.tables.dhcp if drain == "_drain_updates"
+                    else replica[0])
+
         write, leaf, seen = {
             "spoof_ranges": (
                 lambda: engine.antispoof.add_allowed_range(
                     ip_to_u32("172.16.0.0"), 12),
-                lambda u: u[5],
+                lambda: engine.tables.spoof_ranges,
                 lambda a: (a[:, 1] == ip_to_u32("172.16.0.0")).any()),
             "nat_hairpin": (
                 lambda: engine.nat.add_hairpin_ip(ip_to_u32("203.0.113.9")),
-                lambda u: u[1][3],
+                lambda: engine.tables.nat.hairpin_ips,
                 lambda a: (a == ip_to_u32("203.0.113.9")).any()),
             # config_array() is a fresh array a call: the compare is on
             # bytes, not on the array's identity
             "nat_config": (
                 lambda: setattr(engine.nat, "ports_per_subscriber", 77),
-                lambda u: u[1][5],
+                lambda: engine.tables.nat.config,
                 lambda a: int(a[3]) == 77),
             "pools": (
                 lambda: engine.fastpath.add_pool(
                     3, ip_to_u32("10.3.0.0"), 24, ip_to_u32("10.3.0.1")),
-                lambda u: u[0].pools,
+                lambda: dhcp().pools,
                 lambda a: (a[3] != 0).any()),
             "server": (
                 lambda: engine.fastpath.set_server_config(
                     SERVER_MAC, ip_to_u32("10.0.0.2")),
-                lambda u: u[0].server,
+                lambda: dhcp().server,
                 lambda a: (a == ip_to_u32("10.0.0.2")).any()),
         }[array]
-        before = build()
-        assert not seen(np.asarray(leaf(before)))
-        assert leaf(build()) is leaf(before)  # unchanged: nothing placed
-        write()
-        after = build()
-        assert seen(np.asarray(leaf(after)))
-        assert leaf(after) is not leaf(before)
-        assert not seen(np.asarray(leaf(before)))  # a batch is a snapshot
-        assert leaf(build()) is leaf(after)
-        # the other dense arrays were not placed again for this write
-        others = [lambda u: u[5], lambda u: u[6], lambda u: u[1][3],
-                  lambda u: u[1][4], lambda u: u[1][5],
-                  lambda u: u[0].pools, lambda u: u[0].server]
-        same = sum(o(after) is o(before) for o in others)
-        assert same == len(others) - 1
+        placed = []  # the fields each sync put into the tables
+        orig = engine._fresh_dense
 
-    def test_express_drains_fastpath_every_dispatch(self):
+        def fresh(chain, node, **kw):
+            out = orig(chain, node, **kw)
+            placed.extend(f for f in node._fields
+                          if getattr(out, f) is not getattr(node, f))
+            return out
+
+        engine._fresh_dense = fresh
+        sync()
+        assert not seen(np.asarray(leaf()))
+        assert placed == []  # unchanged since the upload: nothing crosses
+        write()
+        sync()
+        assert seen(np.asarray(leaf()))
+        assert len(placed) == 1  # this array, and no other for this write
+        sync()
+        assert seen(np.asarray(leaf())) and len(placed) == 1
+
+    def test_express_drains_fastpath_every_dispatch(self, monkeypatch):
         """The drain is LOGICALLY per-dispatch, and only what changed is
         uploaded (PR 35; PR 13 had a shortcut of its own here): clean
-        tables answer with the batch already on the chip, so a dispatch
-        with nothing dirty builds no table batch (`drain_built` 0), while
-        ANY dirty slot is built and shipped on the very next dispatch
-        (lease visibility pinned by the next test)."""
+        tables cost nothing, so a dispatch with nothing dirty builds no
+        table batch (`drain_built` 0) and makes no apply call (PR 50: no
+        step takes a batch), while ANY dirty slot is built, shipped and
+        applied ahead of the very next dispatch (lease visibility pinned
+        by the next test)."""
         import numpy as np
 
         from bng_tpu.telemetry import spans
@@ -622,25 +666,23 @@ class TestUpdateDrainCadence:
         engine, _, clock = build_stack()
         sched = TieredScheduler(engine, SchedulerConfig(
             express_batch=8), clock=clock)
-        fp, drained = engine.fastpath, []
+        fp, drained, applied = engine.fastpath, [], []
         orig = engine._drain_fastpath_updates
         engine._drain_fastpath_updates = (
-            lambda: (drained.append(orig()), drained[-1])[1])
+            lambda device=None: (drained.append(1), orig(device))[1])
+        monkeypatch.setattr(
+            engine_mod, "_apply_fastpath_jit",
+            lambda t, u, _f=engine_mod._apply_fastpath_jit:
+            (applied.append(u), _f(t, u))[1])
         n = fp.update_slots
         with spans.armed() as tr:
             for i in range(16):
                 sched.submit(discover(mac_of(300 + i), 0x5000 + i))
             sched.poll()
             assert sched.express.stats.batches == 2
-            # a drain a dispatch, and nothing was dirty at either: every
-            # leaf is the cached batch's, nothing was built or uploaded
-            assert len(drained) == 2
-            for upd in drained:
-                assert upd.sub is fp.sub.empty_update(n)
-                assert upd.vlan is fp.vlan.empty_update(n)
-                assert upd.cid is fp.cid.empty_update(n)
-            assert drained[0].pools is drained[1].pools
-            assert drained[0].server is drained[1].server
+            # a drain a dispatch, and nothing was dirty at either: no
+            # batch was built or uploaded, and no apply call was made
+            assert len(drained) == 2 and applied == []
             assert tr.sums()["drain_built"] == 0
             assert tr.sums()["drain_cached"] == 2 * 3
             # a host-side table write makes the NEXT dispatch drain for real
@@ -651,9 +693,11 @@ class TestUpdateDrainCadence:
                 sched.submit(discover(mac_of(320 + i), 0x5100 + i))
             sched.poll()
             assert sched.express.stats.batches == 3
-            assert len(drained) == 3
-            assert drained[2].sub is not fp.sub.empty_update(n)  # built
-            assert drained[2].vlan is fp.vlan.empty_update(n)  # still clean
+            assert len(drained) == 3 and len(applied) == 1
+            # (placed on the express lane's device: compare what they hold)
+            assert (np.asarray(applied[0].sub.bidx) < fp.sub.nbuckets).any()
+            assert np.array_equal(applied[0].vlan.bidx,  # still clean
+                                  fp.vlan.empty_update(n).bidx)
             assert tr.sums()["drain_built"] == 1
             assert tr.sums()["drain_cached"] == 2 * 3 + 2
         assert fp.dirty_count() == 0  # delta shipped

@@ -2,8 +2,9 @@
 
 A device stage's tables are named by hand in some eighteen functions of
 four files (`runtime/engine.py`, `runtime/checkpoint.py`, `runtime/ops.py`,
-`parallel/sharded.py`): the device pytree, the update tuple and the two
-programs that apply it, the host mirrors a drain walks, the checkpoint's
+`parallel/sharded.py`): the device pytree, the update tuple and the
+programs that apply it (the mesh's step, and since PR 50 the engine's two
+packet-free ones: no one-chip step takes the tuple), the host mirrors a drain walks, the checkpoint's
 components, the outputs a retire reads, the blue/green twin, the mesh's
 stacked tuple. A stage taken out of one of them (or added to all but one)
 fails here, per stage set, and the snapshot's format is pinned against a
@@ -76,8 +77,7 @@ def _no_program_cache(monkeypatch):
     programs and runs none. The factories' lru caches (eight and four
     entries) hold what the other test files of this worker compiled, and
     twelve tiny geometries would push those out: hand out uncached ones."""
-    for mod, names in ((eng, ("_pipeline_jit", "_apply_updates_jit",
-                              "_dhcp_jit", "_express_jit")),
+    for mod, names in ((eng, ("_pipeline_jit", "_dhcp_jit", "_express_jit")),
                        (sh, ("_sharded_step_jit", "_sharded_dhcp_jit"))):
         for name in names:
             monkeypatch.setattr(mod, name, getattr(mod, name).__wrapped__)
@@ -176,31 +176,41 @@ def unread_leaves(fn, tables, upd) -> list[str]:
 # (a) the update tuple fits both programs that apply it
 # ---------------------------------------------------------------------------
 
+def full_updates(e: Engine, drain: bool) -> tuple:
+    """The whole update tuple as the mesh's step unpacks it: the dhcp
+    chain's batch in front of the engine's (which holds None there)."""
+    fp = e.fastpath.make_updates() if drain else e.fastpath.empty_updates()
+    return (fp, *e._updates(drain)[1:])
+
+
 @engine_sets
-@pytest.mark.parametrize("program", ["fused", "apply_only"])
+@pytest.mark.parametrize("program", ["fused", "apply_only", "apply_fastpath"])
 def test_update_tuple_fits_the_program(stages, program):
     """`Engine._updates` and the program that unpacks it agree on what the
     tuple holds: the tables come back as the tree they went in, and no
     entry of the tuple is left unread (an entry the program does not pop
-    shifts every tail behind it, or is silently never applied)."""
+    shifts every tail behind it, or is silently never applied). `fused`:
+    the whole tuple over the whole table set, as the mesh's step applies
+    it; `apply_only` / `apply_fastpath`: the engine's two packet-free
+    programs, each over its chain."""
     e = make_engine(stages)
-    drained, empty = e._updates(True, True), e._updates(False, False)
+    drained, empty = e._updates(True), e._updates(False)
     assert shapes(drained) == shapes(empty)
+    assert drained[0] is None  # the chain's batch is the other program's
     if program == "fused":
-        fn, tables, upd, unread_ok = (eng._apply_all_updates, e.tables,
-                                      drained, [])
-    else:
-        fn = eng._apply_updates_jit(e.geom, e.garden is not None,
-                                    e.pppoe is not None,
-                                    e.edge is not None).__wrapped__
+        fn, tables, upd = (eng._apply_all_updates, e.tables,
+                           full_updates(e, True))
+    elif program == "apply_only":
+        fn = eng._apply_updates_jit.__wrapped__
         tables, upd = e.tables._replace(dhcp=None), empty
-        # a bulk drain's fastpath entry is a no-op by design: the
-        # authoritative dhcp chain never enters this program
-        unread_ok = [p for p in leaf_paths(upd) if p.startswith("[0]")]
+    else:
+        fn = eng._apply_fastpath_jit.__wrapped__
+        tables, upd = e.tables.dhcp, e.fastpath.make_updates()
+        assert shapes(upd) == shapes(e.fastpath.empty_updates())
     out = jax.eval_shape(fn, tables, upd)
     assert jax.tree.structure(out) == jax.tree.structure(tables)
     assert shapes(out) == shapes(tables)
-    assert unread_leaves(fn, tables, upd) == unread_ok
+    assert unread_leaves(fn, tables, upd) == []
 
 
 # ---------------------------------------------------------------------------
@@ -319,7 +329,7 @@ def test_step_outputs_are_read_at_retire_or_listed(stages):
     e = make_engine(stages)
     S = jax.ShapeDtypeStruct
     res = jax.eval_shape(
-        e._step, e.tables, e._updates(True, True), S((e.B, e.L), jnp.uint8),
+        e._step, e.tables, S((e.B, e.L), jnp.uint8),
         S((e.B,), jnp.uint32), S((e.B,), jnp.bool_), S((), jnp.uint32),
         S((), jnp.uint32))
     returned = {k for k, v in res._asdict().items() if v is not None}
@@ -401,7 +411,7 @@ def test_mesh_update_tuple_is_the_engines_stacked(stages):
     cl = make_cluster(stages)
     assert set(cl.shard_components(0)) == set(BASE_OWNERS) | set(stages)
     stacked = cl._updates()
-    one = shard_engine(cl, 0)._updates(True, True)
+    one = full_updates(shard_engine(cl, 0), True)
     # the same kinds in the same order, entry by entry ...
     assert jax.tree.structure(stacked) == jax.tree.structure(one)
     # ... and every leaf the engine's with the mesh axis in front

@@ -170,6 +170,44 @@ def test_fused_step_with_the_edge_stage_fits_and_has_no_while(one_chip, lanes):
 def test_express_programs_compile(one_chip, build):
     compiled = compile_for(build(REAL_1M), one_chip)
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+    # PR 50's finding: no step applies an update batch any more, and each
+    # still moves the chain's widest table (the circuit-id probe rows,
+    # 134 MB) from the form the device holds it in, {0,1}, to the {1,0} its
+    # 64-word row gathers read: once, 625 us of an 830 us express program.
+    # The parent's trace named that copy after `ops/table.py:152` because
+    # it copied the scatter's result; the scatter itself runs in place in
+    # {0,1}. The cure is the table's shape (ROADMAP S6), not the batch.
+    assert len(_table_relayouts(compiled, CID_KROWS)) == 1
+
+
+# the circuit-id table's probe rows at 1M subscribers: [nbuckets, 4 ways of
+# way_stride(8 key words) = 16]
+CID_KROWS = f"{1 << 19},64"
+
+
+def test_fused_step_relayouts_the_cid_rows_once_for_its_gathers(fused_step):
+    """The same for the fused step: the one copy is the gathers', and with
+    no update batch in the program nothing else of scope `updates` is left
+    (PERF.md section 6 PR 50)."""
+    moves = _table_relayouts(fused_step, CID_KROWS)
+    assert len(moves) == 1 and "{1,0" in moves[0].split(" copy(")[0]
+    assert "scatter" not in moves[0]
+
+
+@pytest.mark.parametrize("build", [
+    verify.build_apply_fastpath,  # engine.py _apply_fastpath_jit
+    verify.build_apply_updates,  # engine.py _apply_updates_jit
+], ids=["apply_fastpath", "apply_updates"])
+def test_apply_programs_compile(one_chip, build):
+    """The two packet-free programs a DIRTY drain goes through ahead of
+    its step: row scatters in the form the device holds each table in, in
+    place (donated), and no whole-table move: what a dirty beat adds on
+    the device is small."""
+    compiled = compile_for(build(REAL_1M), one_chip)
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert _whiles(compiled) == []
+    if build is verify.build_apply_fastpath:
+        assert _table_relayouts(compiled, CID_KROWS) == []
 
 
 def test_table_probe_compiles(one_chip):

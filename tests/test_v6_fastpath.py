@@ -319,8 +319,7 @@ def test_a_v6_lane_leaves_nat_dhcp_and_garden_alone():
 def _step_hlo(st) -> str:
     eng = st.engine
     return str(eng._step.lower(
-        eng.tables, eng._drain_updates(),
-        jnp.zeros((BATCH, eng.L), jnp.uint8), jnp.zeros((BATCH,), jnp.uint32),
+        eng.tables, jnp.zeros((BATCH, eng.L), jnp.uint8), jnp.zeros((BATCH,), jnp.uint32),
         jnp.zeros((BATCH,), bool), np.uint32(1), np.uint32(1)
     ).compiler_ir(dialect="stablehlo"))
 
