@@ -178,9 +178,24 @@ def start_host_copies(outs) -> None:
     tele.prefetched(outs)
 
 
+def split_window(window):
+    """A staged window's block (hostpath.seal_window: one host-to-device
+    call a window) taken apart on the device: `pkt [b, L] u8`, `length [b]
+    u32`, `from_access [b] bool`. `b` is static, read off the block's
+    shape."""
+    rows, width = window.shape
+    b = hostpath.window_lanes(rows, width)
+    m = hostpath.WINDOW_META_BYTES
+    planes = window[b:].reshape(-1)[: m * b].reshape(m, b).astype(jnp.uint32)
+    length = (planes[0] | (planes[1] << 8) | (planes[2] << 16)
+              | (planes[3] << 24))
+    return window[:b], length, planes[4] != 0
+
+
 @functools.lru_cache(maxsize=8)
 def _pipeline_jit(geom: PipelineGeom):
-    def step(tables, pkt, length, from_access, now_s, now_us):
+    def step(tables, window, now_s, now_us):
+        pkt, length, from_access = split_window(window)
         return pipeline_step(tables, pkt, length, from_access, geom,
                              now_s, now_us)
 
@@ -881,7 +896,7 @@ class Engine:
         # read self.tables AFTER the drain (a bulk-build resync rebinds it)
         tables_in = self.tables._replace(dhcp=dhcp_replica)
         res: PipelineResult = self._step(
-            tables_in, *self._upload_batch(pkt, length, fa), now_s, now_us)
+            tables_in, self._upload_batch(pkt, length, fa), now_s, now_us)
         self._start_host_copies(res)
         # keep the authoritative dhcp chain out of the bulk rebind; the
         # replica-out threads back to the scheduler
@@ -910,7 +925,7 @@ class Engine:
                     f"frame of {int(lens.max())} bytes exceeds engine "
                     f"pkt_slot {self.L}")
             return self._stage_pool.stage(frames, B, lens=lens)
-        pkt = np.zeros((B, self.L), dtype=np.uint8)
+        pkt = hostpath.window_buffer(B, self.L)
         length = np.zeros((B,), dtype=np.uint32)
         for i, f in enumerate(frames):
             if len(f) > self.L:
@@ -981,11 +996,15 @@ class Engine:
         now_s = np.uint32(int(now))
         now_us = np.uint32(int(now * 1e6) & 0xFFFFFFFF)
 
-        pkt, length = self._pack_frames(frames, self.B)
+        # staged at the rung the step will run at (_dispatch_step), as the
+        # scheduler's bulk lane stages: the block's meta rows are then the
+        # staging buffer's own tail
+        b = step_rung(len(frames), self.B)
+        pkt, length = self._pack_frames(frames, b)
         if isinstance(from_access, bool):
-            fa = np.full((self.B,), from_access, dtype=bool)
+            fa = np.full((b,), from_access, dtype=bool)
         else:
-            fa = np.zeros((self.B,), dtype=bool)
+            fa = np.zeros((b,), dtype=bool)
             fa[: len(from_access)] = from_access
 
         tok = tele.begin_batch(tele.LANE_ENGINE, len(frames))
@@ -1284,21 +1303,19 @@ class Engine:
         # lead columns. Callers stage from numpy (fresh device buffer);
         # a jax-array input would alias the caller's LIVE buffer into
         # the donation, so copy it defensively rather than consume it.
+        # The descriptor crosses alone, in ONE call, straight to the lane's
+        # device where it has one; the clock word is a numpy scalar and
+        # crosses inside the call into the executable, as the fused step's
+        # `now_s` / `now_us` do.
         tu = tele.t()
-        desc_d = (jnp.array(desc, copy=True) if isinstance(desc, jax.Array)
-                  else jnp.asarray(desc))
-        if device is not None:
-            desc_d = jax.device_put(desc_d, device)
-            now_d = jax.device_put(jnp.uint32(int(now)), device)
-        else:
-            # default device: the compiled executable places host
-            # arrays itself; an explicit device_put here costs ~0.3ms
-            # of pure ceremony per dispatch on CPU
-            now_d = jnp.uint32(int(now))
-        if tu is not None:  # the descriptors and the clock word
-            tele.xfer(tele.UPLOAD, tu, desc.nbytes + 4, 2)
+        if isinstance(desc, jax.Array):
+            desc = jnp.array(desc, copy=True)
+        desc_d = (jnp.asarray(desc) if device is None
+                  else jax.device_put(desc, device))
+        if tu is not None:
+            tele.xfer(tele.UPLOAD, tu, desc.nbytes, 1)
         dhcp_tables, block, stats = express_exe(
-            self.tables.dhcp, desc_d, now_d)
+            self.tables.dhcp, desc_d, np.uint32(int(now)))
         self.tables = self.tables._replace(dhcp=dhcp_tables)
         self.stats.batches += 1
         return _ExpressAotResult(
@@ -1347,10 +1364,12 @@ class Engine:
         them (sync path: right away; pipelined path: one batch later).
 
         The step runs at the rung of its window: the first `b` rows of the
-        staged `[B, L]` buffers go to the device (contiguous views; rows
-        n..b are inert by the staging invariant, rows beyond `b` never
-        reach the chip) and the outputs are `[b]` / `[b, L]`. A window
-        over the next rung down runs the `B` program, today's."""
+        staged `[B, L]` buffer go to the device with the first `b` lengths
+        and flags in the rows behind them, one block in one call
+        (_upload_batch; rows n..b are inert by the staging invariant, the
+        packet rows beyond `b` never reach the chip) and the outputs are
+        `[b]` / `[b, L]`. A window over the next rung down runs the `B`
+        program."""
         self._dispatch_fault()
         b = step_rung(n, self.B)
         tele.step_lanes(b)
@@ -1360,9 +1379,8 @@ class Engine:
         t0 = tele.t()
         self._drain_updates()
         tele.lap(tele.DRAIN, t0)
-        staged = self._upload_batch(pkt[:b], length[:b], fa[:b])
-        res: PipelineResult = self._step(self.tables, *staged,
-                                         now_s, now_us)
+        window = self._upload_batch(pkt[:b], length[:b], fa[:b])
+        res: PipelineResult = self._step(self.tables, window, now_s, now_us)
         self._start_host_copies(res)
         self.tables = res.tables
         self.stats.batches += 1
@@ -1402,10 +1420,10 @@ class Engine:
                                   next(iter(held[0].devices())))
             self.tables = rest._replace(dhcp=self.tables.dhcp)
         for b in rungs:
-            inert = self._upload_batch(np.zeros((b, self.L), dtype=np.uint8),
+            inert = self._upload_batch(hostpath.window_buffer(b, self.L),
                                        np.zeros((b,), dtype=np.uint32),
                                        np.zeros((b,), dtype=bool))
-            res = self._step(tables_in(), *inert, now_s, now_us)
+            res = self._step(tables_in(), inert, now_s, now_us)
             if dhcp is None:
                 self.tables = res.tables
             else:
@@ -1425,17 +1443,19 @@ class Engine:
 
     @staticmethod
     def _upload_batch(pkt, length, fa):
-        """The staged window to the device: packet slots, lengths, access
-        flags, three host-to-device calls under one `upload` lap (the time
-        to return; the copies land while the call into the step is made).
+        """The staged window to the device: ONE host-to-device call, of the
+        block that holds the packet slots and, in the rows behind them,
+        the lengths and the access flags (hostpath.seal_window; the step
+        takes it apart, split_window), under one `upload` lap (the time to
+        return; the copy lands while the call into the step is made).
         `now_s` / `now_us` are numpy scalars: they cross inside the call
         into the step, `dispatch`'s own."""
+        block = hostpath.seal_window(pkt, length, fa)
         t0 = tele.t()
-        staged = (jnp.asarray(pkt), jnp.asarray(length), jnp.asarray(fa))
+        window = jnp.asarray(block)
         if t0 is not None:
-            tele.xfer(tele.UPLOAD, t0,
-                      pkt.nbytes + length.nbytes + fa.nbytes, 3)
-        return staged
+            tele.xfer(tele.UPLOAD, t0, block.nbytes, 1)
+        return window
 
     @staticmethod
     def _dispatch_fault() -> None:
@@ -1513,7 +1533,7 @@ class Engine:
             # retire it (against the ring it came from — not necessarily
             # this one) or the sync path would starve (assemble -> 0)
             self.flush_pipeline()
-        pkt = np.zeros((self.B, self.L), dtype=np.uint8)
+        pkt = hostpath.window_buffer(self.B, self.L)
         length = np.zeros((self.B,), dtype=np.uint32)
         flags = np.zeros((self.B,), dtype=np.uint32)
         t0 = tele.t()
@@ -1629,7 +1649,7 @@ class Engine:
         owns one while the next assembles into the other)."""
         if self._stage_bufs[idx] is None:
             self._stage_bufs[idx] = (
-                np.zeros((self.B, self.L), dtype=np.uint8),
+                hostpath.window_buffer(self.B, self.L),
                 np.zeros((self.B,), dtype=np.uint32),
                 np.zeros((self.B,), dtype=np.uint32),
             )

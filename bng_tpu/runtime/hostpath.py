@@ -11,8 +11,9 @@ near batch/4ms no matter how fast the chips get.
 
 This module is the batch-native replacement: every per-frame classifier
 and field extractor on the ring->dispatch->reply path, re-expressed as
-NumPy over a [n, L] uint8 frame matrix + length/flag columns. Two hard
-rules:
+NumPy over a [n, L] uint8 frame matrix + length/flag columns (what goes
+to the chip is one block a window: the matrix with the columns' planes in
+the rows behind it, seal_window below). Two hard rules:
 
 1. **The scalar functions stay the oracle.** Each kernel here mirrors
    its scalar twin (`ring.classify_dhcp`, `ring.shard_of`,
@@ -123,9 +124,81 @@ def pack_rows(frames: list[bytes], width: int | None = None
     return buf, out_len
 
 
+# ---------------------------------------------------------------------------
+# the staged window: ONE contiguous block a dispatch
+# ---------------------------------------------------------------------------
+#
+# A window goes to the chip in one host-to-device call (a call costs some
+# 0.3 ms on a v5e whatever it holds, PERF.md section 6 PR 51), so its
+# lengths and access flags ride the block the packet slots are in: rung
+# `b`'s block is rows [0, b + k) of the staging buffer, the slots in rows
+# [0, b) and, flat over rows [b, b + k), five planes of `b` bytes: the
+# four bytes of each lane's length, least significant first, then its
+# access flag. The fused step takes the block apart on the device
+# (runtime/engine.py split_window). A staging buffer is allocated with the
+# tail rows its widest rung needs (window_buffer); a narrower rung's planes
+# land in the rows of lanes beyond it, which its window does not use.
+
+WINDOW_META_BYTES = 5  # a lane's length (u32) and its access flag
+
+
+def window_meta_rows(b: int, width: int) -> int:
+    """Rows of `width` bytes that hold the planes of `b` lanes."""
+    return -(-WINDOW_META_BYTES * b // width)
+
+
+def window_rows(b: int, width: int) -> int:
+    return b + window_meta_rows(b, width)
+
+
+def window_lanes(rows: int, width: int) -> int:
+    """The lane count of a block of `rows` rows: window_rows' inverse
+    (it is strictly increasing in `b`)."""
+    for k in range(1, rows):
+        if window_meta_rows(rows - k, width) == k:
+            return rows - k
+    raise ValueError(f"no window has {rows} rows of {width} bytes")
+
+
+def window_buffer(B: int, width: int) -> np.ndarray:
+    """A zeroed `[B, width]` staging matrix that is the head of a block
+    with the tail rows seal_window needs: every rung up to `B` is sealed
+    in place."""
+    return np.zeros((window_rows(B, width), width), dtype=np.uint8)[:B]
+
+
+def seal_window(pkt: np.ndarray, length: np.ndarray,
+                fa: np.ndarray) -> np.ndarray:
+    """The block of a staged window of `b = len(pkt)` lanes: `length` and
+    `fa` written into the rows behind the slots. In place where `pkt` is
+    the head of a window_buffer (every staging site of the serving loops);
+    any other matrix is copied into a fresh block first."""
+    b, width = pkt.shape
+    rows = window_rows(b, width)
+    base = pkt.base
+    if (isinstance(base, np.ndarray) and base.base is None
+            and base.dtype == np.uint8 and base.strides == pkt.strides
+            and base.shape[0] >= rows
+            and base.flags.c_contiguous
+            and base.ctypes.data == pkt.ctypes.data):
+        block = base[:rows]
+    else:
+        block = np.empty((rows, width), dtype=np.uint8)
+        block[:b] = pkt
+    planes = block[b:].reshape(-1)[:WINDOW_META_BYTES * b].reshape(
+        WINDOW_META_BYTES, b)
+    planes[:4] = np.ascontiguousarray(length, dtype="<u4").view(
+        np.uint8).reshape(b, 4).T
+    planes[4] = fa
+    return block
+
+
 class StagingPool:
     """Cycling pool of preallocated (pkt, length) staging pairs — the
-    per-dispatch `np.zeros([B, L])` + per-frame-copy hoist. `depth`
+    per-dispatch `np.zeros([B, L])` + per-frame-copy hoist. Each `pkt` is
+    the head of a block with the tail rows its window's lengths and flags
+    go up in (window_buffer: the dispatch seals it in place, one
+    host-to-device call a window). `depth`
     must cover the maximum number of dispatches in flight PLUS one
     being staged: a buffer is only rewritten after the dispatch that
     consumed it retired (jnp.asarray copies host->device eagerly, but
@@ -151,7 +224,7 @@ class StagingPool:
         if depth <= self.depth:
             return
         for B, ring in self._bufs.items():
-            ring.extend([np.zeros((B, self.width), dtype=np.uint8),
+            ring.extend([window_buffer(B, self.width),
                          np.zeros((B,), dtype=np.uint32), 0]
                         for _ in range(depth - len(ring)))
         self.depth = depth
@@ -165,13 +238,13 @@ class StagingPool:
         mark)."""
         n = len(frames)
         if B * self.width > self.max_bytes:
-            pkt = np.zeros((B, self.width), dtype=np.uint8)
+            pkt = window_buffer(B, self.width)
             length = np.zeros((B,), dtype=np.uint32)
             pack_into(frames, pkt, length, lens=lens)
             return pkt, length
         ring = self._bufs.get(B)
         if ring is None:
-            ring = [[np.zeros((B, self.width), dtype=np.uint8),
+            ring = [[window_buffer(B, self.width),
                      np.zeros((B,), dtype=np.uint32), 0]
                     for _ in range(self.depth)]
             self._bufs[B] = ring

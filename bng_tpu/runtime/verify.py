@@ -215,17 +215,20 @@ def _engine(g: Geometry):
 
 
 def build_pipeline(g: Geometry = TOY, lanes: int | None = None):
-    """The fused step as the Engine compiles it: tables (donated), window
-    and clock, no update batch. `lanes`: a rung of the step's ladder under
-    `g.batch` (engine.py step_rungs), the width a shorter window is
-    dispatched at."""
+    """The fused step as the Engine compiles it: tables (donated), the
+    window's one block (hostpath.seal_window: packet slots, lengths and
+    access flags) and clock, no update batch. `lanes`: a rung of the
+    step's ladder under `g.batch` (engine.py step_rungs), the width a
+    shorter window is dispatched at."""
+    from bng_tpu.runtime import hostpath
+
     eng = _engine(g)
     B = lanes or g.batch
-    return eng._step, (
-        eng.tables,
-        jnp.zeros((B, g.pkt_slot), dtype=jnp.uint8),
-        jnp.full((B,), 300, dtype=jnp.uint32), jnp.ones((B,), dtype=bool),
-        jnp.uint32(1), jnp.uint32(1))
+    block = hostpath.seal_window(
+        hostpath.window_buffer(B, g.pkt_slot),
+        np.full((B,), 300, dtype=np.uint32), np.ones((B,), dtype=bool))
+    return eng._step, (eng.tables, jnp.asarray(block),
+                       jnp.uint32(1), jnp.uint32(1))
 
 
 def build_sharded(mesh, g: Geometry = TOY):

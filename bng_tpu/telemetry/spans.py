@@ -46,9 +46,11 @@ order:
        sojourn     per frame, enqueue -> completion, by lane (fed once a
                    retired batch through observe_many)
        upload      the host thread inside a host-to-device call on the hot
-                   path (`jnp.asarray` / `jax.device_put` of packet
-                   staging, lengths, flags, descriptors, and of an update
-                   batch that holds dirty slots). The time TO RETURN, not
+                   path (`jnp.asarray` / `jax.device_put` of a staged
+                   window's one block (packet slots, lengths, flags: one
+                   call a window since PR 51), of an express batch's
+                   descriptors, and of an update batch that holds dirty
+                   slots). The time TO RETURN, not
                    to land: the call comes back once the copy is queued
                    (PR 35, the 12.6 MB staging triple: 1,052 us to return,
                    2,902 landed); where the bytes land is the device

@@ -29,6 +29,7 @@ from bng_tpu.control.routing import (LinkState, RoutingManager,  # noqa: E402
 from bng_tpu.edge import (EST_MIRRORED, EST_ROUTE_MISSES,  # noqa: E402
                           EST_ROUTE_REWRITES, EST_TAP_FILTERED, MAX_WARRANTS,
                           EdgeTables, RouteProgram)
+from bng_tpu.runtime import hostpath  # noqa: E402
 from bng_tpu.runtime.engine import AntispoofTables, Engine, QoSTables  # noqa: E402
 from bng_tpu.runtime.ring import PyRing  # noqa: E402
 from bng_tpu.runtime.tables import FastPathTables  # noqa: E402
@@ -498,9 +499,8 @@ def test_beside_a_blocked_fleet_a_committed_lease_still_gets_its_route_row():
 def lowered(eng) -> str:
     b = eng.B
     return eng._step.lower(
-        eng.tables, np.zeros((b, eng.L), np.uint8),
-        np.zeros((b,), np.uint32), np.zeros((b,), bool), np.uint32(T0),
-        np.uint32(0)).as_text()
+        eng.tables, np.zeros((hostpath.window_rows(b, eng.L), eng.L), np.uint8),
+        np.uint32(T0), np.uint32(0)).as_text()
 
 
 def test_without_the_flag_the_lowered_step_is_the_one_it_was():

@@ -36,6 +36,7 @@ from bng_tpu.ops.express import XD_WORDS, express_verdicts, parse_express
 from bng_tpu.ops.parse import PROTO_TCP, parse_batch
 from bng_tpu.ops.pipeline import pipeline_step
 from bng_tpu.runtime import engine as eng_mod
+from bng_tpu.runtime import hostpath
 from bng_tpu.runtime.engine import (AntispoofTables, Engine, GardenTables,
                                     QoSTables)
 from bng_tpu.runtime.scheduler import SchedulerConfig, TieredScheduler
@@ -340,10 +341,12 @@ def test_no_step_signature_holds_an_update_leaf(program):
     e = make_engine()
     S = jax.ShapeDtypeStruct
     if program == "fused":
-        lowered = e._step.lower(e.tables, S((B, L), jnp.uint8),
-                                S((B,), jnp.uint32), S((B,), jnp.bool_),
-                                S((), jnp.uint32), S((), jnp.uint32))
-        tables, rest = e.tables, 5
+        # the window is one block since PR 51: the tables' leaves, the
+        # block and the two clock words
+        lowered = e._step.lower(
+            e.tables, S((hostpath.window_rows(B, L), L), jnp.uint8),
+            S((), jnp.uint32), S((), jnp.uint32))
+        tables, rest = e.tables, 3
     elif program == "dhcp_only":
         lowered = e._dhcp_step.lower(e.tables.dhcp, S((B, L), jnp.uint8),
                                      S((B,), jnp.uint32), S((), jnp.uint32))

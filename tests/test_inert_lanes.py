@@ -25,7 +25,9 @@ from bng_tpu.control.nat import NATManager
 from bng_tpu.control.pool import Pool, PoolManager
 from bng_tpu.ops.antispoof import MODE_STRICT
 from bng_tpu.ops.dhcp import ST_HIT
-from bng_tpu.runtime.engine import AntispoofTables, Engine, QoSTables
+from bng_tpu.runtime import hostpath
+from bng_tpu.runtime.engine import (AntispoofTables, Engine, QoSTables,
+                                    split_window, step_rung)
 from bng_tpu.runtime.ring import NativeRing, PyRing, load_native
 from bng_tpu.runtime.tables import FastPathTables
 from bng_tpu.telemetry import spans
@@ -221,6 +223,49 @@ def test_short_window_after_long_leaves_process_rings_state(ring_kind, path,
     assert got["replies"] == want["replies"]
 
 
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("ring_kind", sorted(RINGS))
+def test_a_short_windows_block_holds_nothing_beyond_its_frames(
+        ring_kind, shape, monkeypatch):
+    """Since PR 51 the lengths and the access flags reach the device inside
+    the window's one block (hostpath.seal_window, written in place into the
+    staging buffer once the rung is known). The invariant is the block's:
+    read as the step reads it (engine.py split_window), a window's lanes
+    are its frames with their flags, and every lane beyond them has length
+    0 and flags 0, in the short window that lands where a long one was
+    (and where that one's planes were) too."""
+    if ring_kind == "native" and load_native() is None:
+        pytest.skip("native toolchain unavailable")
+    blocks = []
+    real = hostpath.seal_window
+
+    def spy(pkt, length, fa):
+        block = real(pkt, length, fa)
+        blocks.append((block.copy(), block.ctypes.data == pkt.ctypes.data))
+        return block
+
+    monkeypatch.setattr(hostpath, "seal_window", spy)
+    sh = SHAPES[shape]
+    seed = 20310 + sorted(RINGS).index(ring_kind)
+    got = _serve(ring_kind, True, "mixed", seed, shape)
+    _engine, macs, ips, flows = _stack(seed, sh["batch"])
+    sent = _windows("mixed", seed, macs, ips, flows, sh["windows"])
+    assert got["batches"] == len(blocks) == len(sent) == 4
+    split = jax.jit(split_window)
+    for (block, in_place), win in zip(blocks, sent):
+        assert in_place  # the staging buffer's own rows, no copy
+        n, b = len(win), step_rung(len(win), sh["batch"])
+        pkt, length, fa = (np.asarray(x) for x in split(block))
+        assert pkt.shape == (b, block.shape[1]) and n <= b
+        for lane, (frame, from_access) in enumerate(win):
+            assert length[lane] == len(frame)
+            assert bytes(pkt[lane, :len(frame)]) == frame
+            assert bool(fa[lane]) == from_access
+        assert not length[n:].any() and not fa[n:].any()
+    # not vacuous: the third window is shorter than the one its buffer held
+    assert len(sent[2]) < len(sent[0])
+
+
 def test_masked_lanes_is_a_sum_of_every_tracer_and_counts_ghost_lanes():
     zero = spans.Tracer().sums()
     assert zero["masked_lanes"] == 0  # never armed: the key, at zero
@@ -312,15 +357,18 @@ def test_an_armed_loop_counts_the_crossings_the_code_makes():
     steps = got["batches"]
     s = tr.sums()
     x = s["xfer"]
-    # a step, every table clean: the staged window (packet slots, lengths,
-    # access flags) and nothing from the drain, not on a new engine's
+    # a step, every table clean: the staged window, ONE block in one call
+    # since PR 51 (the packet slots with the lengths' and the access
+    # flags' planes in the rows behind them, hostpath.seal_window), and
+    # nothing from the drain, not on a new engine's
     # first either (PR 50: its seven dense arrays are in the tables it
     # uploaded, and one crosses again only when the host's bytes differ
     # from what the tables hold, Engine._fresh_dense)
     engine = _stack(20289)[0]
     slot = engine.L  # the staging row, in bytes
-    assert x["upload_calls"] == 3 * steps == 3 * 4
-    assert x["upload_bytes"] == steps * BATCH * (slot + 4 + 1)
+    assert x["upload_calls"] == steps == 4
+    assert x["upload_bytes"] == steps * slot * hostpath.window_rows(BATCH,
+                                                                    slot)
     # a retire: verdict, out_pkt, out_len (inside `device_wait`), the
     # violation and punt flags (inside `reply`), and _fold_stats' four
     # blocks (dhcp, nat, qos, spoof; no garden, PPPoE, edge or v6 here):

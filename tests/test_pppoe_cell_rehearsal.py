@@ -136,11 +136,12 @@ def test_the_cell_is_correct_past_the_pools_wrap(cell_dir, capsys, seed, trace):
     assert 0.90 * frames < per_step["decap"] + per_step["encap"] < frames <= 1024
     # the once-a-second walk over the sessions, in stage `slow_path`
     assert got[name["tick"]]["value"] > 0
-    # a step's crossings: the staged window up; a retire reads verdict,
+    # a step's crossings: the staged window up (one block in one call since
+    # PR 51); a retire reads verdict,
     # out_pkt, out_len, the violation and punt flags and six stats blocks
     # (dhcp, nat, qos, spoof, garden, pppoe): since PR 43 each one's copy
     # was started at its step's dispatch, so the reads cross nothing
-    assert got[name["up"]]["value"] == 3
+    assert got[name["up"]]["value"] == 1
     assert got[name["fetch"]]["value"] == 0
     assert got[name["prefetch"]]["value"] == \
         pytest.approx(3 + 2 + 6, abs=0.25)

@@ -40,6 +40,7 @@ from bng_tpu.control.pool import Pool, PoolManager  # noqa: E402
 from bng_tpu.control.pppoe import codec  # noqa: E402
 from bng_tpu.ops import antispoof as A  # noqa: E402
 from bng_tpu.ops.qinq import QQ_MISS, QQ_OVERSIZE, QQ_POP, QQ_PUSH  # noqa: E402
+from bng_tpu.runtime import hostpath  # noqa: E402
 from bng_tpu.runtime.engine import AntispoofTables, Engine, QoSTables  # noqa: E402
 from bng_tpu.runtime.ring import PyRing  # noqa: E402
 from bng_tpu.runtime.tables import (FastPathTables, PPPoEFastPathTables,  # noqa: E402
@@ -382,8 +383,9 @@ def test_beside_the_v6_stage_an_ipv6_lane_is_popped_and_pushed_like_a_v4_one():
 def _step_hlo(st) -> str:
     eng = st.engine
     return str(eng._step.lower(
-        eng.tables, jnp.zeros((BATCH, eng.L), jnp.uint8), jnp.zeros((BATCH,), jnp.uint32),
-        jnp.zeros((BATCH,), bool), np.uint32(1), np.uint32(1)
+        eng.tables,
+        jnp.zeros((hostpath.window_rows(BATCH, eng.L), eng.L), jnp.uint8),
+        np.uint32(1), np.uint32(1)
     ).compiler_ir(dialect="stablehlo"))
 
 

@@ -33,6 +33,7 @@ from bng_tpu.control.nat import NATManager
 from bng_tpu.control.pool import Pool, PoolManager
 from bng_tpu.ops.express import XD_WORDS
 from bng_tpu.runtime import engine as engine_mod
+from bng_tpu.runtime import hostpath
 from bng_tpu.runtime.engine import AntispoofTables, Engine, QoSTables
 from bng_tpu.runtime.lanes import (CLOSE_DEADLINE, CLOSE_FULL, CompletionRing,
                                    InflightEntry, Lane, LaneConfig)
@@ -430,10 +431,13 @@ class TestTracedLoop:
         assert snap["batches"] == 2  # one bulk step, one express batch
         x = snap["xfer"]
         L = engine.L
-        # bulk: packet slots, lengths, access flags; express: the
-        # descriptor rows and the clock word; every table clean
-        assert x["upload_calls"] == 3 + 2
-        assert x["upload_bytes"] == 8 * (L + 4 + 1) + 4 * XD_WORDS * 4 + 4
+        # bulk: the window's one block (packet slots, and the planes of
+        # the lengths and access flags behind them); express: the
+        # descriptor rows alone (the clock word crosses inside the call);
+        # every table clean
+        assert x["upload_calls"] == 1 + 1
+        assert x["upload_bytes"] == (
+            hostpath.window_rows(8, L) * L + 4 * XD_WORDS * 4)
         # bulk retire: verdict, out_len, punt, violation inside
         # `device_wait`, then _fold_stats' four blocks: since PR 43 the
         # copy of each was started at dispatch (out_pkt's too, which no

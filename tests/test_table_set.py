@@ -29,6 +29,7 @@ from bng_tpu.ops.qtable import QTableState
 from bng_tpu.ops.table import TableState
 from bng_tpu.parallel import sharded as sh
 from bng_tpu.runtime import engine as eng
+from bng_tpu.runtime import hostpath
 from bng_tpu.runtime import ops
 from bng_tpu.runtime.checkpoint import (_resolve_component_meta,
                                         build_checkpoint, decode_checkpoint,
@@ -329,9 +330,9 @@ def test_step_outputs_are_read_at_retire_or_listed(stages):
     e = make_engine(stages)
     S = jax.ShapeDtypeStruct
     res = jax.eval_shape(
-        e._step, e.tables, S((e.B, e.L), jnp.uint8),
-        S((e.B,), jnp.uint32), S((e.B,), jnp.bool_), S((), jnp.uint32),
-        S((), jnp.uint32))
+        e._step, e.tables,
+        S((hostpath.window_rows(e.B, e.L), e.L), jnp.uint8),
+        S((), jnp.uint32), S((), jnp.uint32))
     returned = {k for k, v in res._asdict().items() if v is not None}
     assert returned - set(Engine._RETIRE_READS) <= NOT_READ_AT_RETIRE
     assert set(Engine._RETIRE_READS) <= set(PipelineResult._fields)

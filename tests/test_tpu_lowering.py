@@ -82,6 +82,31 @@ def test_fused_step_has_no_while(fused_step):
     assert _whiles(fused_step) == []
 
 
+def _n_leaves(tree) -> int:
+    return len(jax.tree_util.tree_leaves(tree))
+
+
+def test_fused_step_takes_its_tables_and_three_more(fused_step):
+    """Since PR 51 a window is ONE block (hostpath.seal_window: packet
+    slots, and the lengths' and access flags' planes in the rows behind
+    them): the lowered signature holds the tables' 34 leaves, the block
+    and the two clock words, where it held 34 + 5. Taking the block apart
+    on the device (engine.py split_window) brings no loop (the test above)
+    and no move of a table (the relayout tests below stand as they were)."""
+    from bng_tpu.runtime import hostpath
+
+    (tables, window, now_s, now_us), _kw = fused_step.args_info
+    assert _n_leaves(tables) == 34
+    assert _n_leaves(fused_step.args_info) == 34 + 3
+    B, L = REAL_1M.batch, REAL_1M.pkt_slot
+    assert window.shape == (hostpath.window_rows(B, L), L) == (B + 27, L)
+    assert window.dtype == np.uint8
+    assert now_s.shape == now_us.shape == ()
+    # the block is read, never written: no output aliases it
+    assert not window.donated and all(
+        x.donated for x in jax.tree_util.tree_leaves(tables))
+
+
 @pytest.mark.parametrize("lanes", [1024, 128])
 def test_fused_step_compiles_at_every_rung_of_its_ladder(one_chip, lanes):
     """A window shorter than `--batch-size` runs at the narrowest rung that
@@ -170,6 +195,13 @@ def test_fused_step_with_the_edge_stage_fits_and_has_no_while(one_chip, lanes):
 def test_express_programs_compile(one_chip, build):
     compiled = compile_for(build(REAL_1M), one_chip)
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+    # the chain's leaves (three tables of three, pools, server), and the
+    # window or descriptor rows with the clock word (and the DHCP-only
+    # program's lengths): since PR 51 the express dispatch places the
+    # descriptor alone, the clock word crosses inside the call
+    (chain, *rest), _kw = compiled.args_info
+    assert _n_leaves(chain) == 11
+    assert len(rest) == (3 if build is verify.build_dhcp_express else 2)
     # PR 50's finding: no step applies an update batch any more, and each
     # still moves the chain's widest table (the circuit-id probe rows,
     # 134 MB) from the form the device holds it in, {0,1}, to the {1,0} its

@@ -33,6 +33,7 @@ from bng_tpu.control import dhcp_codec, packets  # noqa: E402
 from bng_tpu.control.nat import NATManager  # noqa: E402
 from bng_tpu.control.pool import Pool, PoolManager  # noqa: E402
 from bng_tpu.ops import antispoof as A  # noqa: E402
+from bng_tpu.runtime import hostpath  # noqa: E402
 from bng_tpu.runtime.engine import AntispoofTables, Engine, QoSTables  # noqa: E402
 from bng_tpu.runtime.tables import (FastPathTables, V6FastPathTables,  # noqa: E402
                                     v6_words)
@@ -319,8 +320,9 @@ def test_a_v6_lane_leaves_nat_dhcp_and_garden_alone():
 def _step_hlo(st) -> str:
     eng = st.engine
     return str(eng._step.lower(
-        eng.tables, jnp.zeros((BATCH, eng.L), jnp.uint8), jnp.zeros((BATCH,), jnp.uint32),
-        jnp.zeros((BATCH,), bool), np.uint32(1), np.uint32(1)
+        eng.tables,
+        jnp.zeros((hostpath.window_rows(BATCH, eng.L), eng.L), jnp.uint8),
+        np.uint32(1), np.uint32(1)
     ).compiler_ir(dialect="stablehlo"))
 
 
