@@ -48,12 +48,13 @@ BEAT = "bng.beat"  # the Tracer's anchor annotation (telemetry/spans.py)
 # the Tracer's stages that are laps of the host thread (the rest are fed
 # durations: lane_wait, device, sojourn; the container: beat; or span batches
 # across beats: total). `upload` and `fetch` (PR 37) close inside `dispatch`
-# and `reply`, and the innermost lap wins. A stage a later program stamps and
+# and `reply`, `mirror` (PR 49: the hand-over to the intercept sink) inside
+# `reply`, and the innermost lap wins. A stage a later program stamps and
 # this list lacks reads as `no_lap`. The original is
 # bng_tpu/utils/profiling.py HOST_LAPS
 HOST_LAPS = ("ring", "admit", "dispatch", "upload", "device_wait", "fetch",
-             "fleet", "slow_path", "reply", "ops", "wire_rx", "wire_tx",
-             "pack", "drain", "tx")
+             "fleet", "slow_path", "reply", "mirror", "ops", "wire_rx",
+             "wire_tx", "pack", "drain", "tx")
 EVENTS_FILE = "events.json"  # the event log's slice beside a recorded trace
 
 

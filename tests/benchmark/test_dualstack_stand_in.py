@@ -1,26 +1,27 @@
 """The cell `dualstack-cgnat-1M-wire.flood-64B` in test_benchmark.py's own
 rehearsal directory, as the stand-in `tiny-dualstack.flood`: its
-configuration, its kit and its layer files are found by name, at 4,096
+configuration and its kit are found by name, its layer files by what lists
+the cell and by what each reads (`test_benchmark.generic`), at 4,096
 dual-stack subscribers of whom 128 are behind NAT.
 tests/test_dualstack_cell_rehearsal.py is the longer rehearsal, past the
 pool's wrap and with both controls. No number from here is a device
 metric, and no position in a `workloads` list is pinned."""
 
 from test_benchmark import (BENCH, ENGINE_LOOP, ENGINE_LOOP_ZERO_OK,  # noqa: F401
-                            TINY_CELLS, _run, tiny_dir)
+                            TINY_CELLS, _run, generic, listed, tiny_dir)
 
 from benchmark.lib import app as applib
-from benchmark.lib import layers
 
 REAL = "dualstack-cgnat-1M-wire.flood-64B"
-OWN = {"dualstack_step.device_p50_us", "dualstack.loop_us_per_frame",
-       "dualstack.gen_share", "dualstack.beat_p99_us"}  # PR 34's, the cell's alone
-# since PR 36 the engine's loop reports here what it reports in the wire
-# cell, and the counters of the stage beside it (no v6 miss, no v6 control frame
-# in a sound run: 0)
-FILES = OWN | ENGINE_LOOP | {"dualstack.v6_fwd_per_step",
-                             "dualstack.v6_miss_per_step",
-                             "dualstack.v6_ctrl_per_step"}
+# the loop's generic reads (PR 34 brought them under the cell's prefix; since
+# PR 52 the cell is listed in the files that held them first)
+LOOP = generic(REAL, "step", "loop", "gen", "beat")
+# the stage's own counters, the cell's alone (no v6 miss, no v6 control frame
+# in a sound run: 0); since PR 36 the engine's loop reports here what it
+# reports in the wire cell
+OWN = {"dualstack.v6_fwd_per_step", "dualstack.v6_miss_per_step",
+       "dualstack.v6_ctrl_per_step"}
+FILES = OWN | ENGINE_LOOP | set(LOOP.values())
 ZERO_OK = ENGINE_LOOP_ZERO_OK | {"dualstack.v6_miss_per_step",
                                  "dualstack.v6_ctrl_per_step"}
 
@@ -44,8 +45,7 @@ def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
     entry = {c["name"]: c for c in BENCH["configs"]}[cell["config"]]
     assert entry["source"] == cfg["source"] and len(entry["source"]) <= 200
     assert entry["reduced"] == cfg["reduced"]
-    named = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
-             if REAL in m["cells"]}
+    named = set(listed(REAL))
     assert FILES <= named  # a later PR may add a file that lists the cell
     assert {m["name"] for m in BENCH["per_layer"]
             if REAL in m["workloads"]} == named
@@ -86,11 +86,11 @@ def test_the_stand_in_rehearses_traced(tiny_dir, capsys):  # noqa: F811
     assert any(ln.startswith("cell: ") and ln.endswith("kit=dualstack")
                for ln in out)
     got = res["metrics"]
-    assert FILES - {"dualstack_step.device_p50_us"} <= set(got)
+    assert FILES - {LOOP["step"]} <= set(got)
     assert all(got[name]["value"] > 0 for name in FILES - ZERO_OK
                if name in got)
     assert all(got[name]["value"] >= 0 for name in ZERO_OK)
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
-    assert said and "dualstack_step.device_p50_us" in said[0]
+    assert said and LOOP["step"] in said[0]
     sample = [ln for ln in out if ln.startswith("check sample: ")][0]
     assert "IPv6 byte-for-byte" in sample and "none-" not in sample

@@ -1,20 +1,30 @@
 """The cell `multiisp-li-cgnat-1M-wire.flood-64B` in test_benchmark.py's own
-rehearsal directory, as the stand-in `tiny-multiisp.flood` (tests/conftest.py
-adds it to the three literals): its configuration, its kit and its layer
-file are found by name, at 4,096 subscribers with a route row each over four
+rehearsal directory, as the stand-in `tiny-multiisp.flood`: its
+configuration and its kit are found by name, its layer files by what lists
+the cell, at 4,096 subscribers with a route row each over four
 upstreams, 128 of them behind NAT and 32 of those under a warrant.
 tests/test_edge_cell_rehearsal.py is the longer rehearsal, past the pool's
 wrap, with both controls and a sink that loses frames. No number from here
 is a device metric."""
 
-from test_benchmark import BENCH, TINY_CELLS, _run, tiny_dir  # noqa: F401
+from test_benchmark import (BENCH, ENGINE_LOOP, TINY_CELLS, _run,  # noqa: F401
+                            generic, listed, tiny_dir)
 
 from benchmark.lib import app as applib
-from benchmark.lib import layers
 
 REAL = "multiisp-li-cgnat-1M-wire.flood-64B"
 W = "cgnat-1M-wire.flood-64B"
 LAP = "edge.mirror_us_per_step"
+# three of the stage's four counts (stamps PR 49, files PR 52): above 0
+# where the stage ran. The fourth, route misses a step, is 0 where every
+# subscriber holds a route row, and so are three of the loop's counters in a
+# sound run: tests/test_edge_cell_rehearsal.py holds every `counter` file
+# that lists the cell above 0, so the cell is not listed in W's three and the
+# fourth count has no file yet (PERF.md section 7 row 1)
+COUNTS = {"edge.rewrites_per_step", "edge.mirrored_per_step",
+          "edge.filtered_per_step"}
+ZERO_IN_A_SOUND_RUN = {"engine.drain_built_per_step",
+                       "wire.fetch_calls_per_step", "wire.fetch_kb_per_step"}
 
 
 def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
@@ -36,11 +46,16 @@ def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
                                 upstreams=4, warrants=1024,
                                 filtered_warrants=64)
     assert cfg["off"] == [x for x in base["off"] if x != "edge taps"]
-    assert len(cfg["guarantees"] + cfg.get("guarantees_edge", [])) == 6
-    # one file lists the cell, and one entry (a later PR may add more)
-    named = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
-             if REAL in m["cells"]}
-    assert LAP in named
+    assert len(cfg["guarantees"]) == 6 and "guarantees_edge" not in cfg
+    # since PR 52 the cell reports what its loop reports in W, the stage's
+    # lap and three of its four counts (a later PR may add more)
+    named = set(listed(REAL))
+    loop = set(generic(REAL, "step", "loop", "gen", "beat").values())
+    assert {LAP} | COUNTS | (ENGINE_LOOP - ZERO_IN_A_SOUND_RUN) | loop <= named
+    assert {n for n in listed(W) if n.startswith(("wire.", "engine."))} \
+        - named == ZERO_IN_A_SOUND_RUN
+    assert not any(m["read"].get("path", "").endswith("edge_route_miss")
+                   for m in listed(REAL).values())
     assert {m["name"] for m in BENCH["per_layer"]
             if REAL in m["workloads"]} == named
     served = {m["name"]: m for m in BENCH["end_to_end"]}["served_kpps"]
@@ -64,10 +79,11 @@ def test_the_stand_in_rehearses_traced(tiny_dir, capsys):  # noqa: F811
     sample = [ln for ln in out if ln.startswith("check sample: ")][0]
     assert "next hop's MAC" in sample and "intercept sink: " in sample
     got = res["metrics"]
-    want = {m["name"] for m in layers.layer_files(tiny_dir)
-            if REAL in m["cells"]}
-    assert LAP in want <= set(got)
+    step = generic(REAL, "step")["step"]  # no device trace on the CPU
+    assert set(got) == set(listed(REAL, tiny_dir)) - {step}
     assert got[LAP]["value"] > 0 and got[LAP]["unit"] == "us"
+    # the tiny set arms warrants on NAT subscribers, some with a filter row
+    assert all(got[name]["value"] > 0 for name in COUNTS), got
 
 
 def test_the_lap_is_left_out_where_the_stage_is_off(tiny_dir, capsys):  # noqa: F811
@@ -77,7 +93,7 @@ def test_the_lap_is_left_out_where_the_stage_is_off(tiny_dir, capsys):  # noqa: 
     res, _out = _run(tiny_dir, capsys, "tiny-wire.flood", "--trace", "1")
     assert res["correct"] is True
     assert TINY_CELLS["tiny-wire.flood"][0] == W
-    assert LAP not in res["metrics"]
+    assert not ({LAP} | COUNTS) & set(res["metrics"])
 
 
 def test_both_controls_fail_the_stand_in(tiny_dir, capsys):  # noqa: F811

@@ -1,24 +1,25 @@
 """The cell `pppoe-cgnat-1M-wire.flood-64B` in test_benchmark.py's own
-rehearsal directory, as the stand-in `tiny-pppoe.flood`: its configuration,
-its kit and its layer files are found by name, at 4,096 subscribers of whom
+rehearsal directory, as the stand-in `tiny-pppoe.flood`: its configuration
+and its kit are found by name, its layer files by what lists the cell and by
+what each reads (`test_benchmark.generic`), at 4,096 subscribers of whom
 all 128 NAT subscribers are PPPoE. tests/test_pppoe_cell_rehearsal.py is the longer
 rehearsal, past the pool's wrap and with both controls. No number from
 here is a device metric."""
 
 from test_benchmark import (BENCH, ENGINE_LOOP, ENGINE_LOOP_ZERO_OK,  # noqa: F401
-                            TINY_CELLS, _run, tiny_dir)
+                            TINY_CELLS, _run, generic, listed, tiny_dir)
 
 from benchmark.lib import app as applib
-from benchmark.lib import layers
 
 REAL = "pppoe-cgnat-1M-wire.flood-64B"
-OWN = {"pppoe_step.device_p50_us", "pppoe.loop_us_per_frame",
-       "pppoe.gen_share", "pppoe.beat_p99_us"}  # PR 32's, the cell's alone
+# the loop's generic reads (PR 32 brought them under the cell's prefix; since
+# PR 52 the cell is listed in the files that held them first)
+LOOP = generic(REAL, "step", "loop", "gen", "beat", "tick")
 # since PR 36 the engine's loop reports here what it reports in the wire
 # cell, and the counters of the stage beside it (no unknown session
 # in a sound run: 0)
-FILES = OWN | ENGINE_LOOP | {"pppoe.decap_per_step", "pppoe.encap_per_step", "pppoe.miss_per_step",
-                             "pppoe.tick_ms_per_s"}
+FILES = set(LOOP.values()) | ENGINE_LOOP | {
+    "pppoe.decap_per_step", "pppoe.encap_per_step", "pppoe.miss_per_step"}
 ZERO_OK = ENGINE_LOOP_ZERO_OK | {"pppoe.miss_per_step"}
 
 
@@ -31,8 +32,7 @@ def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
     assert cfg["sizes"]["pppoe_sessions"] == 0xFFFF and "framing" in cfg
     assert cfg["argv"] == applib.load_named("configs", "ipoe-cgnat-1M-wire")[
         "argv"] + ["--pppoe-enabled", "--pppoe-auth", "none"]
-    named = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
-             if REAL in m["cells"]}
+    named = set(listed(REAL))
     assert FILES <= named  # a later PR may add a file that lists the cell
     assert {m["name"] for m in BENCH["per_layer"]
             if REAL in m["workloads"]} == named
@@ -48,9 +48,9 @@ def test_the_stand_in_rehearses_traced(tiny_dir, capsys):  # noqa: F811
     assert any(ln.startswith("cell: ") and ln.endswith("kit=pppoe")
                for ln in out)
     got = res["metrics"]
-    assert FILES - {"pppoe_step.device_p50_us"} <= set(got)
+    assert FILES - {LOOP["step"]} <= set(got)
     assert all(got[name]["value"] > 0 for name in FILES - ZERO_OK
                if name in got)
     assert all(got[name]["value"] >= 0 for name in ZERO_OK)
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
-    assert said and "pppoe_step.device_p50_us" in said[0]
+    assert said and LOOP["step"] in said[0]

@@ -1,23 +1,25 @@
 """The cell `qinq-pppoe-cgnat-1M-wire.flood-64B` in test_benchmark.py's own
-rehearsal directory, as the stand-in `tiny-qinq.flood`: its configuration,
-its kit and its layer files are found by name, at 4,096 subscribers behind a
+rehearsal directory, as the stand-in `tiny-qinq.flood`: its configuration
+and its kit are found by name, its layer files by what lists the cell and by
+what each reads (`test_benchmark.generic`), at 4,096 subscribers behind a
 pair each, 128 of them behind NAT and 32 of those PPPoE.
 tests/test_qinq_cell_rehearsal.py is the longer rehearsal, past the pool's
 wrap and with both controls. No number from here is a device metric."""
 
 from test_benchmark import (BENCH, ENGINE_LOOP, ENGINE_LOOP_ZERO_OK,  # noqa: F401
-                            TINY_CELLS, _run, tiny_dir)
+                            TINY_CELLS, _run, generic, listed, tiny_dir)
 
 from benchmark.lib import app as applib
-from benchmark.lib import layers
 
 REAL = "qinq-pppoe-cgnat-1M-wire.flood-64B"
-# PR 40's eight, the cell's alone; and, since PR 48, what the engine's loop
-# reports in W, P and D (Q runs the same loop and the same stamps)
-STEP = "qinq_step.device_p50_us"
+# the stage's three counters, the cell's alone; the loop's generic reads
+# (PR 40 brought them under the cell's prefix; since PR 52 the cell is listed
+# in the files that held them first); and, since PR 48, what the engine's
+# loop reports in W, P and D (Q runs the same loop and the same stamps)
+LOOP = generic(REAL, "step", "loop", "gen", "beat", "tick")
+STEP = LOOP["step"]
 FILES = {"qinq.push_per_step", "qinq.pop_per_step", "qinq.miss_per_step",
-         STEP, "qinq.loop_us_per_frame", "qinq.gen_share", "qinq.beat_p99_us",
-         "qinq.tick_ms_per_s"} | ENGINE_LOOP
+         *LOOP.values()} | ENGINE_LOOP
 # every subscriber holds a pair
 ZERO_OK = ENGINE_LOOP_ZERO_OK | {"qinq.miss_per_step"}
 
@@ -38,13 +40,12 @@ def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
     assert "QinQ" not in cfg["off"]
     assert cfg["guarantees"] == applib.load_named("configs", "ipoe-cgnat-1M")[
         "guarantees"]
-    named = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
-             if REAL in m["cells"]}
+    named = set(listed(REAL))
     assert FILES <= named  # a later PR may add a file that lists the cell
     assert {m["name"] for m in BENCH["per_layer"]
             if REAL in m["workloads"]} == named
     served = {m["name"]: m for m in BENCH["end_to_end"]}["served_kpps"]
-    assert served["workloads"][-1] == REAL
+    assert REAL in served["workloads"]  # a later cell is appended after it
     assert applib.load_kit(cfg).stage_bytes(8192, 1536) == 4 * 8192 * 1536
 
 

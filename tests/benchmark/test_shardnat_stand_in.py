@@ -1,29 +1,39 @@
 """The cell `cgnat-sharded4-1M.flood-64B` in test_benchmark.py's own
 rehearsal directory, as the stand-in `tiny4-nat.flood` (the `4` in its name
-gives it four chips there): its configuration, its kit and its layer files
-are found by name, at 4,096 subscribers of whom 128 are behind NAT, one
-public address a shard.
+gives it four chips there): its configuration and its kit are found by
+name, its layer files by what lists the cell and by what each reads
+(`test_benchmark.generic`), at 4,096 subscribers of whom 128 are behind NAT,
+one public address a shard.
 tests/test_shardnat_cell_rehearsal.py is the longer rehearsal (every
 subscriber behind NAT, 20 addresses a shard, past the pool's wrap, both
 controls, the starved pool). No number from here is a device metric."""
 
 import pytest
-from test_benchmark import BENCH, TINY_CELLS, _run, tiny_dir  # noqa: F401
+from test_benchmark import (BENCH, TINY_CELLS, _run, generic,  # noqa: F401
+                            listed, reading, tiny_dir)
 
 from benchmark.lib import app as applib
-from benchmark.lib import layers
 
 REAL = "cgnat-sharded4-1M.flood-64B"
 OLD = "sharded4-1M.flood-64B"
-FILES = {"shardnat_step.device_p50_us", "shardnat.collective_share",
-         "shardnat.nat_fwd_per_step", "shardnat.nat_punt_per_step",
-         "shardnat.steer_miss_per_s", "shardnat.frame_imbalance",
-         "shardnat.loop_us_per_frame", "shardnat.gen_share",
-         "shardnat.device_wait_us_per_step", "shardnat.dispatch_us_per_step",
-         "shardnat.drain_built_per_step", "shardnat.device_starved_share"}
-NO_DEVICE = {"shardnat_step.device_p50_us", "shardnat.collective_share"}
+# the cell's own three; the loop's generic reads; and the mesh loop's seven
+# that PR 42 brought under the cell's prefix (since PR 52 the cell is listed
+# in S's files, which held those reads first)
+OWN = {"shardnat.nat_fwd_per_step", "shardnat.nat_punt_per_step",
+       "shardnat.steer_miss_per_s"}
+LOOP = generic(REAL, "step", "loop", "gen")
+MESH = {k: reading(listed(REAL), **read) for k, read in {
+    "collective": dict(kind="trace_device", stat="collective_share"),
+    "imbalance": dict(kind="counter", path="sharded.per_shard.*.frames"),
+    "wait": dict(kind="counter", path="sharded.trace.stage_ns.device_wait"),
+    "dispatch": dict(kind="counter", path="sharded.trace.stage_ns.dispatch"),
+    "built": dict(kind="counter", path="sharded.trace.drain_built"),
+    "starved": dict(kind="counter", path="sharded.trace.beat_starved_ns"),
+}.items()}
+FILES = OWN | set(LOOP.values()) | set(MESH.values())
+NO_DEVICE = {LOOP["step"], MESH["collective"]}
 ZERO_OK = {"shardnat.nat_punt_per_step", "shardnat.steer_miss_per_s",
-           "shardnat.drain_built_per_step", "shardnat.device_starved_share"}
+           MESH["built"], MESH["starved"]}
 
 
 @pytest.fixture(autouse=True)
@@ -55,9 +65,9 @@ def test_the_cell_and_its_files_are_in_the_benchmark_by_name():
     assert cfg["guarantees"] == applib.load_named("configs", "ipoe-cgnat-1M")[
         "guarantees"]
     assert "one owner a public address" in cfg["sharding"]
-    named = {m["name"] for m in layers.layer_files(applib.BENCH_DIR)
-             if REAL in m["cells"]}
-    assert FILES <= named  # a later PR may add a file that lists the cell
+    named = set(listed(REAL))
+    # twelve, as before the merge; a later PR may add a file that lists the cell
+    assert FILES <= named and len(FILES) == 12
     assert {m["name"] for m in BENCH["per_layer"]
             if REAL in m["workloads"]} == named
     served = {m["name"]: m for m in BENCH["end_to_end"]}["served_kpps"]
@@ -101,12 +111,14 @@ def test_the_stand_in_rehearses_traced(tiny_dir, capsys):  # noqa: F811
     assert FILES - NO_DEVICE <= set(got)
     assert all(got[name]["value"] > 0 for name in FILES - NO_DEVICE - ZERO_OK)
     for name in ("shardnat.nat_punt_per_step", "shardnat.steer_miss_per_s",
-                 "shardnat.drain_built_per_step"):
+                 MESH["built"]):
         assert got[name]["value"] == 0, name
     said = [ln for ln in out if ln.startswith("per-layer metrics with nothing")]
     assert said and all(name in said[0] for name in NO_DEVICE)
-    # the files of the other four-chip cell are not this cell's
-    assert not [name for name in got if name.startswith("sharded")]
+    # what the other four-chip cell reports beside these is not this cell's
+    assert set(got) == set(listed(REAL)) - NO_DEVICE
+    others = set(listed(OLD)) - set(listed(REAL))
+    assert others and not others & set(got)
 
 
 def test_both_controls_fail_the_stand_in(tiny_dir, capsys):  # noqa: F811

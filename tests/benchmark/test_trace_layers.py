@@ -127,12 +127,18 @@ def test_every_new_layer_file_returns_a_number_in_its_cell(tiny_dir, capsys,  # 
     assert all(got[n]["value"] < 100.0 for n in shares)
 
 
-def test_a_counter_file_dropped_in_is_admitted_with_no_test_edited(tmp_path):
+@pytest.mark.parametrize("path, refused", [("ring.tx", 0), ("ring.rx", 2)],
+                         ids=["a read no file holds", "a repeat"])
+def test_a_counter_file_dropped_in_is_admitted_with_no_test_edited(
+        tmp_path, path, refused):
     """The rule at work: a copy of the benchmark with its tests, one more
     counter file in `layers/` and its entry in `BENCHMARK.json`, and the
     tests that admit such a file, as they stand, pass over the copy: the
-    count is within the format's limit and every file's `cells` are its
-    entry's `workloads`. What
+    count is within the format's limit, every file's `cells` are its
+    entry's `workloads`, and its cell reports no read twice. A file that
+    is a second name for a read of its cell (`ring.rx` per `engine.batches`
+    is `wire.frames_per_step`, which lists the cell) is refused by that last
+    test alone, in the twin's case and in the original's. What
     the file reads in its cell is the parametrised rehearsal's to show
     (tests/test_dualstack_cell_rehearsal.py drops the same kind of file
     into a copy and reads a number from it)."""
@@ -144,11 +150,11 @@ def test_a_counter_file_dropped_in_is_admitted_with_no_test_edited(tmp_path):
         shutil.copytree(os.path.join(ROOT, part), tmp_path / part,
                         ignore=shutil.ignore_patterns("__pycache__"))
     cell = "pppoe-cgnat-1M-wire.flood-64B"
-    extra = {"name": "pppoe.frames_per_step", "unit": "frames",
+    extra = {"name": "pppoe.ring_frames_per_step", "unit": "frames",
              "better": "higher", "source": "program_counter",
              "layer": "engine (runtime/engine.py)", "moves": "served_kpps",
              "cells": [cell],
-             "read": {"kind": "counter", "path": "ring.rx",
+             "read": {"kind": "counter", "path": path,
                       "per": "engine.batches"}}
     assert extra["name"] not in NEW
     with open(tmp_path / "benchmark" / "layers" / (extra["name"] + ".json"),
@@ -178,9 +184,17 @@ def test_a_counter_file_dropped_in_is_admitted_with_no_test_edited(tmp_path):
          os.path.join(here, "test_benchmark.py") + "::test_per_layer_is_"
          "within_the_formats_limit",
          os.path.join(here, "test_benchmark.py") + "::test_a_layer_files_"
-         "cells_are_its_entrys_workloads"],
+         "cells_are_its_entrys_workloads",
+         os.path.join(here, "test_benchmark.py") + "::test_no_cell_reports_"
+         "this_files_read_under_a_second_name"],
         capture_output=True, text=True, timeout=600, env=env, cwd=tmp_path)
-    assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-2000:]
     files = len(layers.layer_files(os.path.join(ROOT, "benchmark"))) + 1
-    assert f"{5 + files} passed" in out.stdout, out.stdout[-2000:]
-
+    assert f"{5 + 2 * files - refused} passed" in out.stdout, \
+        out.stdout[-3000:] + out.stderr[-2000:]
+    if not refused:
+        assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-2000:]
+        return
+    assert f"{refused} failed" in out.stdout, out.stdout[-3000:]
+    for name in (extra["name"], "wire.frames_per_step"):
+        assert ("FAILED tests/benchmark/test_benchmark.py::test_no_cell_reports_"
+                f"this_files_read_under_a_second_name[{name}]") in out.stdout
