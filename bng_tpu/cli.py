@@ -2360,10 +2360,14 @@ class BNGApp:
         if eng is not None:
             out["engine"] = {
                 "batches": eng.stats.batches, "tx": eng.stats.tx,
-                "passed": eng.stats.passed, "dropped": eng.stats.dropped}
+                "passed": eng.stats.passed, "dropped": eng.stats.dropped,
+                # frames NAT punted for a new flow, by what became of them
+                "new_flows": dataclasses.asdict(eng.newflows.stats)}
         cluster = self.components.get("cluster")
         if cluster is not None:
             out["sharded"] = cluster.stats_summary()
+            out["sharded"]["new_flows"] = dataclasses.asdict(
+                cluster.newflows.stats)
             # each shard's pool, before `allocate_nat` returns None: what
             # its host mirror holds and what its addresses have left
             out["sharded"]["per_shard_nat"] = [

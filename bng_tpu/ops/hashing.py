@@ -48,3 +48,22 @@ def hash_words(words, seed):
     for w in words[1:]:
         h = mix32(h ^ w)
     return h
+
+
+def hash_words_int(words, seed: int) -> int:
+    """`hash_words` of ONE key given as Python ints: the same 32-bit
+    arithmetic, masked by hand, with no array in it. The host tables hash
+    a single key some seven times an insert (ops/table.py HostTable), and
+    one-element numpy arrays cost a microsecond an operation: 65 us a hash
+    against 2 (tests/test_newflow_forwarding.py holds the two equal bit for
+    bit)."""
+    m1, m2, mask = int(_M1), int(_M2), 0xFFFFFFFF
+    h = None
+    for w in words:
+        h = (w ^ int(seed)) if h is None else (h ^ w)
+        h ^= h >> 16
+        h = (h * m1) & mask
+        h ^= h >> 15
+        h = (h * m2) & mask
+        h ^= h >> 16
+    return h

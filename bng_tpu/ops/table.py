@@ -40,7 +40,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bng_tpu.ops.hashing import SEED1, SEED2, hash_words, mix32
+from bng_tpu.ops.hashing import (SEED1, SEED2, hash_words, hash_words_int,
+                                 mix32)
 from bng_tpu.telemetry import spans as tele
 
 WAYS = 4  # slots per bucket; one bucket = one contiguous gather
@@ -338,11 +339,12 @@ class HostTable:
 
     # -- hashing (must match device_lookup exactly) --
     def _buckets(self, key: np.ndarray) -> tuple[int, int]:
-        # 1-element arrays, not scalars: numpy scalar uint32 ops raise on
-        # overflow while array ops wrap (and must match device semantics).
-        words = [key[k : k + 1] for k in range(self.K)]
-        m = np.uint32(self.nbuckets - 1)
-        return int((hash_words(words, SEED1) & m)[0]), int((hash_words(words, SEED2) & m)[0])
+        # one key: plain ints, masked by hand (hash_words_int is
+        # hash_words bit for bit, and must match device semantics)
+        words = [int(w) for w in key]
+        m = self.nbuckets - 1
+        return (hash_words_int(words, SEED1) & m,
+                hash_words_int(words, SEED2) & m)
 
     def _find_slot(self, key: np.ndarray) -> int | None:
         b1, b2 = self._buckets(key)

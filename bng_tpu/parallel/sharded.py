@@ -451,6 +451,14 @@ class ShardedCluster:
         self._inflight = None  # process_ring_pipelined window
         # per-step psum deltas folded by process_ring (Engine.stats role)
         self.stats: dict = {"slow_errors": 0}
+        # frames NAT punted for a new flow: the create on the owner shard,
+        # and the frames waiting to go through the mesh a second time
+        # (runtime/newflow.py; Engine.newflows role)
+        from bng_tpu.runtime.newflow import NewFlows
+
+        self.newflows = NewFlows(
+            lambda *flow: self.handle_new_flow(*flow)[1],
+            bound=max(n_shards * batch_per_shard // 4, 1))
         # count AND log slow-path failures (rate-limited; Engine parity)
         from bng_tpu.utils.structlog import SlowPathErrorLog
 
@@ -1101,12 +1109,12 @@ class ShardedCluster:
             self.flush_pipeline(slow_path, violation_sink)
         t0 = tele.t()
         pkt, length, flags = self._staging(self._stage_idx, pkt_slot)
-        got = ring.assemble_sharded(pkt, length, flags)
-        if not got:
+        got, held = self._fill_window(ring, pkt, length, flags)
+        if not got and not held:
             tele.lap(tele.RING, t0)
             return 0
         entry = self._dispatch_ring_batch(ring, pkt, length, flags, got,
-                                          now_s, now_us, t0)
+                                          now_s, now_us, t0, held=held)
         self._retire(entry, slow_path, violation_sink)
         return got
 
@@ -1136,14 +1144,14 @@ class ShardedCluster:
             t0 = tele.t()
             idx = 1 - self._stage_idx
             pkt, length, flags = self._staging(idx, pkt_slot)
-            got = ring.assemble_sharded(pkt, length, flags)
-            if not got:
+            got, held = self._fill_window(ring, pkt, length, flags)
+            if not got and not held:
                 tele.lap(tele.RING, t0)
             else:
                 try:
                     entry = self._dispatch_ring_batch(
                         ring, pkt, length, flags, got, now_s, now_us, t0,
-                        prev)
+                        prev, held)
                 except BaseException:
                     # fail closed: the assemble opened a ring window that
                     # must not wedge. complete() retires FIFO, so the
@@ -1153,8 +1161,10 @@ class ShardedCluster:
                     self._retire(prev, slow_path, violation_sink)
                     prev = None
                     B = self.n * self.b
-                    ring.complete(np.full((B,), VERDICT_DROP, dtype=np.uint8),
-                                  pkt, length, B)
+                    if got:
+                        ring.complete(
+                            np.full((B,), VERDICT_DROP, dtype=np.uint8),
+                            pkt, length, B)
                     raise
                 self._inflight = entry
                 self._stage_idx = idx
@@ -1179,9 +1189,40 @@ class ShardedCluster:
                                     np.zeros((B,), dtype=np.uint32))
         return self._ring_bufs[idx]
 
+    def _fill_window(self, ring, pkt, length, flags) -> tuple[int, tuple]:
+        """One window into a staging buffer: the ring's frames by shard
+        (assemble_sharded), then the frames waiting for their second pass
+        (runtime/newflow.py), each in the first padding lane of the region
+        of the shard the ring steers it to; one whose region is full waits
+        for the next window, and so does every held frame behind it (they
+        leave in the order they came). Returns (frames the ring staged,
+        (held frames, their lanes)): the ring opened a window only where
+        it staged a frame, and `complete` skips the lanes it did not fill."""
+        got = ring.assemble_sharded(pkt, length, flags)
+        nf = self.newflows
+        if not len(nf):
+            return got, ()
+        if not got:  # no window: the rows are as the last one left them
+            length[:] = 0
+            flags[:] = 0
+        used = (length > 0).reshape(self.n, self.b).sum(axis=1)
+        held, lanes, waiting = [], [], []
+        for frame, fl in nf.take(len(nf)):
+            s = ring.shard_of(frame, fl)
+            if waiting or used[s] >= self.b:
+                waiting.append((frame, fl))
+                continue
+            lane = int(s * self.b + used[s])
+            used[s] += 1
+            nf.stage(pkt, length, flags, lane, frame, fl)
+            held.append((frame, fl))
+            lanes.append(lane)
+        nf.put_back(waiting)
+        return got, ((held, lanes) if held else ())
+
     def _dispatch_ring_batch(self, ring, pkt, length, flags, got,
                              now_s: int, now_us: int, t_ring=None,
-                             prev=None):
+                             prev=None, held=()):
         """Dispatch one assembled window to the mesh WITHOUT waiting for
         its outputs (their copies to the host are started and nothing is
         read until _retire) — the async half of the beat, so a pipelined
@@ -1212,7 +1253,7 @@ class ShardedCluster:
             raise
         self._probe(prev)  # before this window goes up: was the mesh idle?
         tele.device_up(tok)
-        return (ring, out, pkt, length, flags, got, now_s, tok)
+        return (ring, out, pkt, length, flags, got, now_s, held, tok)
 
     @staticmethod
     def _probe(entry) -> None:
@@ -1238,9 +1279,12 @@ class ShardedCluster:
         from bng_tpu.ops.nat44 import NST_DNAT, NST_SNAT
         from bng_tpu.runtime.ring import VERDICT_PASS, VERDICT_TX
 
-        ring, out, pkt, length, flags, got, now_s, tok = entry
+        ring, out, pkt, length, flags, got, now_s, held, tok = entry
         B = self.n * self.b
         real = length > 0
+        if held:  # lanes on their second pass: not the ring's window's
+            real = real.copy()
+            real[held[1]] = False
         tele.focus(tok)
         t0 = tele.t()
         if out[0] == "dhcp":
@@ -1316,7 +1360,11 @@ class ShardedCluster:
         tele.lap(tele.REPLY, t0, tok)
         self._probe(self._inflight)
         t0 = tele.t()
-        ring.complete(verdict, out_pkt_h, out_len_h, B)
+        if got:
+            ring.complete(verdict, out_pkt_h, out_len_h, B)
+        if held:
+            self.newflows.retire_held(ring, *held, verdict, out_pkt_h,
+                                      out_len_h)
         tele.lap(tele.TX, t0, tok)
         self._probe(self._inflight)
 
@@ -1343,7 +1391,10 @@ class ShardedCluster:
             frame, fl = got_f
             try:
                 if punt[lane]:
-                    self._punt_new_flow(frame, int(now_s))
+                    # the create on the owner shard; a refused flow's
+                    # frame is a counted drop (newflows.stats)
+                    self.newflows.punt(frame, fl, int(now_s),
+                                       self.pppoe is not None)
                 elif slow_path is not None:
                     reply = slow_path(frame)
                     if reply is not None:
@@ -1405,28 +1456,6 @@ class ShardedCluster:
                 and (frame[off + 8] >> 4) == 4):
             return fnv1a32(frame[off + 8 + 12 : off + 8 + 16]) % self.n
         return None
-
-    def _punt_new_flow(self, frame: bytes, now: int) -> None:
-        """Device egress-miss: create the session on the OWNER shard
-        (Engine._punt_new_flow with owner routing in front)."""
-        from bng_tpu.control import packets as P
-        from bng_tpu.runtime.engine import Engine
-
-        if self.pppoe is not None:
-            # the punt carries the ORIGINAL ring bytes — for a PPPoE
-            # subscriber still session-framed; strip to the inner IPv4
-            # view or the flow permanently blackholes (Engine parity)
-            frame = Engine._strip_pppoe_host(frame)
-        try:
-            d = P.decode(frame)
-        except Exception:
-            return
-        if d.ethertype != 0x0800:
-            return
-        src_port = d.icmp_id if d.proto == 1 else d.src_port
-        dst_port = 0 if d.proto == 1 else d.dst_port
-        self.handle_new_flow(d.src_ip, d.dst_ip, src_port, dst_port,
-                             d.proto, len(frame), now)
 
     def step(self, pkt: np.ndarray, length: np.ndarray, from_access: np.ndarray,
              now_s: int, now_us: int):

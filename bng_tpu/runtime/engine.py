@@ -63,6 +63,7 @@ from bng_tpu.ops.antispoof import ANTISPOOF_WORDS
 from bng_tpu.ops.qtable import HostQTable, QTableGeom, apply_qupdate
 from bng_tpu.ops.table import HostTable, TableGeom, apply_update, placed
 from bng_tpu.runtime import hostpath
+from bng_tpu.runtime.newflow import NewFlows
 from bng_tpu.runtime.ring import FLAG_DHCP_CTRL
 from bng_tpu.runtime.tables import (FastPathTables, PPPoEFastPathTables,
                                     QinQFastPathTables, V6FastPathTables,
@@ -551,6 +552,12 @@ class Engine:
         self.violation_sink = violation_sink
         self.clock = clock
         self.stats = EngineStats()
+        # frames NAT punted for a new flow: the create, and the frames
+        # waiting to go through the chip a second time (runtime/newflow.py;
+        # at most a quarter of a window waits, the rest is the ring's)
+        self.newflows = NewFlows(
+            lambda *flow: self.nat.handle_new_flow(*flow),
+            bound=max(batch_size // 4, 1))
         self._inflight = None  # pipelined ring mode (process_ring_pipelined)
         self._stage_bufs = [None, None]  # ping-pong staging (lazy alloc)
         self._stage_high = [0, 0]  # lanes each buffer's last window filled
@@ -1048,8 +1055,12 @@ class Engine:
             else:
                 self.stats.passed += 1
                 if punt[i]:
+                    # the caller holds the frame (no ring to send it
+                    # round on): the session is created, the lane is
+                    # reported among `slow` with no reply
                     try:
-                        self._punt_new_flow(frames[i], int(now))
+                        self.newflows.create(frames[i], int(now),
+                                             self.pppoe is not None)
                     except Exception as e:  # noqa: BLE001 — untrusted input
                         self.stats.slow_errors += 1
                         self._slow_err_log.report(e, path="process", lane=i)
@@ -1537,10 +1548,10 @@ class Engine:
         length = np.zeros((self.B,), dtype=np.uint32)
         flags = np.zeros((self.B,), dtype=np.uint32)
         t0 = tele.t()
-        n = ring.assemble(pkt, length, flags)
+        n, held = self._fill_window(ring, pkt, length, flags)
         if n == 0:
             return 0
-        tok = tele.begin_batch(tele.LANE_RING_L, n)
+        tok = tele.begin_batch(tele.LANE_RING_L, n - len(held))
         tele.lap(tele.RING, t0, tok)
         now = now if now is not None else self.clock()
         now_s = np.uint32(int(now))
@@ -1561,38 +1572,71 @@ class Engine:
             tele.cancel_batch(tok)  # a failed dispatch must not leak a slot
             raise
         tele.lap(tele.DISPATCH, t0, tok)
-        self._apply_ring_verdicts(ring, res, pkt, length, n, now)
+        self._apply_ring_verdicts(ring, res, pkt, length, n, now, held=held)
         tele.end_batch(tok)
         return n
 
+    def _fill_window(self, ring, pkt, length, flags) -> tuple[int, list]:
+        """One window into a staging buffer: the frames waiting for their
+        second pass (runtime/newflow.py) in its first lanes, the ring's
+        behind them. Returns (lanes filled, the held frames among them):
+        the ring's window, if it opened one, is lanes len(held)..n, and is
+        completed as that (_apply_ring_verdicts). No held frame (every
+        window of a deployment that opens no flow): one length check and
+        the ring's own assemble."""
+        held = self.newflows.take(self.B // 2) if len(self.newflows) else []
+        h = len(held)
+        for i, (frame, fl) in enumerate(held):
+            self.newflows.stage(pkt, length, flags, i, frame, fl)
+        if not h:
+            return ring.assemble(pkt, length, flags), held
+        return h + ring.assemble(pkt[h:], length[h:], flags[h:]), held
+
     def _apply_ring_verdicts(self, ring, res: PipelineResult, pkt, length,
-                             n: int, now: float, tok=None) -> None:
-        """Force the step's outputs and demux verdicts back to the ring."""
+                             n: int, now: float, tok=None, held=()) -> None:
+        """Force the step's outputs and demux verdicts back to the ring.
+        `held`: the frames on their second pass in the window's first
+        lanes (_fill_window); the ring's own window is the lanes behind
+        them, and every count of accepted frames is over those alone."""
         t0 = tele.t()
         # armed: the wait apart from the reads (the pipelined loop's
         # window, seen ready); disarmed the first read waits and copies
         tf = tele.ready(res.verdict, tok)
-        vv = np.asarray(res.verdict)[:n]
+        h = len(held)
+        vv = np.asarray(res.verdict)[h:n]
         out_pkt = np.asarray(res.out_pkt)
         out_len = np.asarray(res.out_len).astype(np.uint32)
         tele.fetched(tf, res.verdict, res.out_pkt, res.out_len)
         tele.lap(tele.DEVICE_WAIT, t0)
         t0 = tele.t()
-        ring.complete(vv.astype(np.uint8), out_pkt, out_len, n)
+        if not h:
+            ring.complete(vv.astype(np.uint8), out_pkt, out_len, n)
+        elif n > h:
+            ring.complete(vv.astype(np.uint8), out_pkt[h:], out_len[h:], n - h)
 
         self.stats.tx += int((vv == VERDICT_TX).sum())
         self.stats.fwd += int((vv == VERDICT_FWD).sum())
         self.stats.dropped += int((vv == VERDICT_DROP).sum())
         self.stats.passed += int((vv == VERDICT_PASS).sum())
+        if h:
+            # behind the window's own: a second packet of the flow that
+            # sits in this window's ring lanes left by `complete` above
+            fwd, gone = self.newflows.retire_held(
+                ring, held, range(h), np.asarray(res.verdict), out_pkt,
+                out_len)
+            self.stats.fwd += fwd
+            self.stats.dropped += gone
 
         # the flags the demux below needs, read side by side: one `fetch`
         # lap inside `reply`
         tf = tele.t()
-        viol = np.asarray(res.spoof_violation)[:n]
-        punt = np.asarray(res.nat_punt)[:n]
+        viol = np.asarray(res.spoof_violation)[h:n]
+        punt = np.asarray(res.nat_punt)[h:n]
         mir = getattr(res, "mirror", None)  # DHCP-only batches have none
-        mirw = (np.asarray(mir)[:n]
+        mirw = (np.asarray(mir)[h:n]
                 if mir is not None and self.mirror_sink is not None else None)
+        if h:  # the sinks below index the window's own lanes
+            pkt, length = pkt[h:], length[h:]
         tele.fetched(tf, res.spoof_violation, res.nat_punt,
                      mir if mirw is not None else None)
         for lane in np.nonzero(viol)[0]:
@@ -1629,10 +1673,15 @@ class Engine:
             if punt[lane]:
                 punts += 1
                 try:
-                    self._punt_new_flow(frame, int(now))
+                    kept = self.newflows.punt(frame, fl, int(now),
+                                              self.pppoe is not None)
                 except Exception as e:  # noqa: BLE001 — untrusted input
+                    kept = False
                     self.stats.slow_errors += 1
                     self._slow_err_log.report(e, path="ring", lane=int(lane))
+                # a frame that will not go round (refused flow, no room to
+                # wait in) is a counted drop, not a silent consumption
+                self.stats.dropped += not kept
             else:
                 slow_items.append((int(lane), frame))
                 slow_fa[int(lane)] = (fl & 0x1) != 0
@@ -1703,10 +1752,10 @@ class Engine:
             idx = 1 - self._stage_idx
             pkt, length, flags = self._staging(idx)
             t0 = tele.t()
-            n = ring.assemble(pkt, length, flags)
+            n, held = self._fill_window(ring, pkt, length, flags)
             if n:
                 self._mask_stale_lanes(idx, n)
-                tok = tele.begin_batch(tele.LANE_RING_L, n)
+                tok = tele.begin_batch(tele.LANE_RING_L, n - len(held))
                 tele.lap(tele.RING, t0, tok)
                 now_s = np.uint32(int(now))
                 now_us = np.uint32(int(now * 1e6) & 0xFFFFFFFF)
@@ -1729,12 +1778,16 @@ class Engine:
                     tele.cancel_batch(tok)
                     self._retire(prev)
                     prev = None
-                    ring.complete(np.full((n,), VERDICT_DROP, dtype=np.uint8),
-                                  pkt, length, n)
+                    h = len(held)  # frames on their second pass go with it
+                    self.stats.dropped += h
+                    if n > h:
+                        ring.complete(
+                            np.full((n - h,), VERDICT_DROP, dtype=np.uint8),
+                            pkt[h:], length[h:], n - h)
                     raise
                 tele.lap(tele.DISPATCH, t0, tok)
                 tele.device_up(tok)
-                self._inflight = (ring, res, pkt, length, n, now, tok)
+                self._inflight = (ring, res, pkt, length, n, now, tok, held)
                 self._stage_idx = idx
         finally:
             # 2. retire the previous batch (even if dispatch raised) while
@@ -1746,9 +1799,9 @@ class Engine:
         """Apply a pipelined batch's verdicts to the ring it came from."""
         if entry is None:
             return 0
-        ring, res, pkt, length, n, now, tok = entry
+        ring, res, pkt, length, n, now, tok, held = entry
         tele.focus(tok)
-        self._apply_ring_verdicts(ring, res, pkt, length, n, now, tok)
+        self._apply_ring_verdicts(ring, res, pkt, length, n, now, tok, held)
         self._fold_stats(res)
         tele.end_batch(tok)
         return n
@@ -1761,41 +1814,6 @@ class Engine:
         entry = self._inflight
         self._inflight = None
         return self._retire(entry)
-
-    @staticmethod
-    def _strip_pppoe_host(frame: bytes) -> bytes:
-        """Host-side mirror of the device decap for NAT punt frames: the
-        punt handler sees the ORIGINAL ring bytes, which for a PPPoE
-        subscriber still carry the session framing the device stripped.
-        Returns the inner Ethernet+IPv4 view (or the frame unchanged)."""
-        off = 12
-        et = int.from_bytes(frame[off : off + 2], "big")
-        while et in (0x8100, 0x88A8) and len(frame) >= off + 8:
-            off += 4
-            et = int.from_bytes(frame[off : off + 2], "big")
-        if et != 0x8864 or len(frame) < off + 10:
-            return frame
-        if int.from_bytes(frame[off + 8 : off + 10], "big") != 0x0021:
-            return frame
-        return frame[:off] + b"\x08\x00" + frame[off + 10 :]
-
-    def _punt_new_flow(self, frame: bytes, now: int) -> None:
-        """Device egress-miss: create the session host-side (packet 1 of a
-        new flow; parity with the conntrack-hybrid slow path)."""
-        from bng_tpu.control import packets as P
-
-        if self.pppoe is not None:
-            frame = self._strip_pppoe_host(frame)
-        try:
-            d = P.decode(frame)
-        except Exception:
-            return
-        if d.ethertype != 0x0800:
-            return
-        src_port = d.icmp_id if d.proto == 1 else d.src_port
-        dst_port = 0 if d.proto == 1 else d.dst_port
-        self.nat.handle_new_flow(d.src_ip, d.dst_ip, src_port, dst_port,
-                                 d.proto, len(frame), now)
 
     def fetch_session_vals(self) -> np.ndarray:
         """Device-authoritative session counters for accounting/expiry."""

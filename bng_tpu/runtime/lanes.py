@@ -93,6 +93,14 @@ class Lane:
         self.stats.enqueued += 1
         return True
 
+    def requeue_front(self, frames: list[PendingFrame]) -> None:
+        """Frames this lane dispatched once, back at its head in their
+        order for a second pass (the first packets of NAT flows the host
+        has just admitted, runtime/newflow.py). They keep their enqueue
+        time, so the oldest-age close fires for them at once; not counted
+        as enqueued again."""
+        self.q.extendleft(reversed(frames))
+
     def oldest_age_us(self, now: float) -> float:
         return (now - self.q[0].enq_t) * 1e6 if self.q else 0.0
 

@@ -240,9 +240,11 @@ def _configure(lib: C.CDLL) -> None:
                        C.POINTER(C.c_uint32), C.POINTER(C.c_uint32)]
     lib.bng_ring_frame_free.restype = C.c_int
     lib.bng_ring_frame_free.argtypes = [C.c_void_p, C.c_uint64]
-    lib.bng_ring_tx_inject.restype = C.c_int
-    lib.bng_ring_tx_inject.argtypes = [C.c_void_p, C.POINTER(C.c_uint8),
-                                       C.c_uint32, C.c_uint32]
+    for name in ("tx_inject", "fwd_inject"):
+        fn = getattr(lib, f"bng_ring_{name}")
+        fn.restype = C.c_int
+        fn.argtypes = [C.c_void_p, C.POINTER(C.c_uint8), C.c_uint32,
+                       C.c_uint32]
     lib.bng_batch_complete.restype = C.c_int
     lib.bng_batch_complete.argtypes = [
         C.c_void_p, C.POINTER(C.c_uint8), C.POINTER(C.c_uint8),
@@ -350,6 +352,17 @@ class NativeRing:
         if not ok:
             self._tx_refused += 1
         return ok
+
+    def fwd_inject(self, frame: bytes, flags: int = FLAG_FROM_ACCESS) -> bool:
+        """A host-held frame onto the FWD ring, where `complete` queues a
+        lane with the verdict FWD: the first packet of a NAT flow the host
+        admitted, translated on its second pass through the chip
+        (engine.py HeldFrames). `flags` are the frame's own. Refused (no
+        free frame, ring full): the caller's frame is gone, and the
+        caller counts it."""
+        buf = np.frombuffer(frame, dtype=np.uint8)
+        return self._lib.bng_ring_fwd_inject(self._h, _u8p(buf), len(frame),
+                                             flags) == 0
 
     # -- batch wire verbs (the vector wire pump, runtime/xsk.py) --------
     def umem_view(self) -> np.ndarray:
@@ -756,6 +769,17 @@ class PyRing:
         else:
             self._tx.append((frame, fl))
         self._stats["tx"] += 1
+        return True
+
+    def fwd_inject(self, frame: bytes, flags: int = FLAG_FROM_ACCESS) -> bool:
+        """NativeRing.fwd_inject: a host-held frame onto the FWD ring."""
+        if (len(frame) > self.frame_size or self._free == 0
+                or len(self._fwd) >= self.depth):
+            return False
+        self._free -= 1
+        self._fwd.append(int(self._stage_slot(frame, flags)) if self._vec
+                         else (frame, flags))
+        self._stats["fwd"] += 1
         return True
 
     # -- vector SoA plumbing ---------------------------------------------

@@ -43,6 +43,7 @@ loadtest harness drives.
 
 from __future__ import annotations
 
+import functools
 import os
 from collections import deque
 from dataclasses import dataclass
@@ -66,6 +67,7 @@ from bng_tpu.runtime.engine import _ExpressAotResult, step_rung
 from bng_tpu.runtime.lanes import (CLOSE_DEADLINE, CLOSE_FLUSH, CompletionRing,
                                    InflightEntry, Lane, LaneConfig, LANE_BULK,
                                    LANE_EXPRESS)
+from bng_tpu.runtime.newflow import SECOND_PASS
 from bng_tpu.runtime.ring import classify_dhcp
 from bng_tpu.utils.net import prefix_to_mask
 from bng_tpu.utils.structlog import get_logger
@@ -749,6 +751,13 @@ class TieredScheduler:
             drain = (upd is not None
                      or self.cfg.drain_every <= 1
                      or self._bulk_seq % self.cfg.drain_every == 0)
+            if any(p.desc is SECOND_PASS for p in pend):
+                # the first packet of a flow the host has just admitted:
+                # its session was written after any prefetched drain was
+                # built, so that batch goes first and a fresh drain
+                # follows, whatever the cadence says
+                eng.apply_updates_now(upd)
+                upd, drain = None, True
             before = eng.resync_count
             try:
                 res, self._bulk_dhcp = eng.dispatch_scheduled_bulk(
@@ -813,22 +822,37 @@ class TieredScheduler:
         # drains through the batched slow path in one fan-out
         slow_items = []
         punts = 0
+        # lane -> its frame, kept for a second pass: the lane's own queue
+        # holds it (at its head, below), so this loop has no other. Built
+        # at the first punt: a retire without one allocates nothing for it
+        again: dict = None
         for i, p in enumerate(entry.pending):
-            if int(vv[i]) in (VERDICT_TX, VERDICT_FWD, VERDICT_DROP):
+            if (int(vv[i]) in (VERDICT_TX, VERDICT_FWD, VERDICT_DROP)
+                    or p.desc is SECOND_PASS):
                 continue
             if punt[i]:
                 punts += 1
+                if again is None:
+                    again = {}
                 try:
-                    eng._punt_new_flow(p.frame, int(entry.dispatch_t))
+                    eng.newflows.punt(
+                        p.frame, 0, int(entry.dispatch_t),
+                        eng.pppoe is not None,
+                        hold=functools.partial(self._hold_for_second_pass,
+                                               again, i, p))
                 except Exception as e:  # noqa: BLE001 — untrusted input
                     eng.stats.slow_errors += 1
                     eng._slow_err_log.report(e, path="sched_bulk", lane=i)
             else:
                 slow_items.append((i, p.frame, p.enq_t))
+        if again:
+            self.bulk.requeue_front(list(again.values()))
         replies = dict(eng._handle_slow_lanes(slow_items, path="sched_bulk"))
         t0 = tele.t()
         for i, p in enumerate(entry.pending):
             v = int(vv[i])
+            if p.desc is SECOND_PASS and not eng.newflows.second_pass(v):
+                v = VERDICT_DROP  # punted again, or lost: a counted drop
             if v == VERDICT_TX or v == VERDICT_FWD:
                 if out_rows is None:
                     out_rows = self._fetch_rows(res.out_pkt)
@@ -844,7 +868,13 @@ class TieredScheduler:
                 self._complete(p, LANE_BULK, "drop", None, now)
             else:
                 eng.stats.passed += 1
-                self._complete(p, LANE_BULK, "slow", replies.get(i), now)
+                if again and i in again:
+                    continue  # not done: it completes on its second pass
+                if punt[i]:  # a refused flow's frame: a counted drop
+                    eng.stats.dropped += 1
+                    self._complete(p, LANE_BULK, "drop", None, now)
+                else:
+                    self._complete(p, LANE_BULK, "slow", replies.get(i), now)
             if viol[i] and eng.violation_sink is not None:
                 eng.violation_sink(i, p.frame)
         if mirw is not None:
@@ -859,6 +889,16 @@ class TieredScheduler:
         tele.end_batch(entry.trace, punt=punts)
         self._observe_retire(LANE_BULK, entry, now)
         return n
+
+    def _hold_for_second_pass(self, again: dict, i: int, p, _frame=None,
+                              _flags=None) -> bool:
+        """Keep lane `i`'s frame of a retiring batch for the head of the
+        bulk lane, while the lane has room for it (`NewFlows.punt`'s
+        `hold`: it passes the frame and its flags, which `p` holds)."""
+        if len(self.bulk) + len(again) >= self.bulk.cfg.max_queue:
+            return False
+        again[i] = p._replace(desc=SECOND_PASS)
+        return True
 
     @staticmethod
     def _fetch_rows(out_pkt) -> np.ndarray:

@@ -153,6 +153,9 @@ DEFAULT_SLOS: tuple[SLOSpec, ...] = (
     SLOSpec("mirror", 200_000.0,
             description="host handing a retired batch's mirrored lanes to "
                         "the intercept sink"),
+    SLOSpec("punt", 200_000.0,
+            description="host serving one frame NAT punted for a new flow: "
+                        "the create and the hand-back for its second pass"),
     SLOSpec("total", 500_000.0, description="batch begin -> end"),
 )
 

@@ -66,11 +66,17 @@ order:
                    retire around the extraction of the lanes whose mirror
                    word is set and the sink's calls, inside `reply`; only
                    where a sink is set (`bng run --edge-enabled`)
+       punt        the host thread serving one frame NAT punted for a
+                   new flow: decode, `NATManager.handle_new_flow` (the
+                   mapping, the session and reverse rows, the compliance
+                   log's call) and the hand-back of the frame for its
+                   second pass (runtime/newflow.py NewFlows.punt): one lap
+                   a punted frame, inside `reply`
        total       batch begin -> end (the client-visible wall time)
 
    `upload` and `fetch` are children of the laps that enclose them
    (`dispatch`, `drain`, `pack`; `device_wait`, `reply`) or stand under no
-   parent (`Engine._fold_stats`); `mirror` is a child of `reply`. A child
+   parent (`Engine._fold_stats`); `mirror` and `punt` are children of `reply`. A child
    closes before its parent, so it takes the starvation under it and the
    parent keeps the rest: a parent stage's `starved_ns` is its SELF share. `stage_ns` stays a plain sum
    of samples (a child's time is in its parent's too).
@@ -151,11 +157,11 @@ from bng_tpu.telemetry.hist import LatencyHist
 # the `bench` lane; tests/test_slo.py feeds it.
 (RING, ADMIT, LANE_WAIT, DISPATCH, DEVICE, DEVICE_WAIT, FLEET, WORKER, SLOW,
  REPLY, OPS, WIRE_RX, WIRE_TX, BEAT, PACK, DRAIN, TX, SOJOURN, UPLOAD, FETCH,
- MIRROR, TOTAL) = range(22)
+ MIRROR, PUNT, TOTAL) = range(23)
 STAGE_NAMES = ("ring", "admit", "lane_wait", "dispatch", "device",
                "device_wait", "fleet", "worker", "slow_path", "reply", "ops",
                "wire_rx", "wire_tx", "beat", "pack", "drain", "tx",
-               "sojourn", "upload", "fetch", "mirror", "total")
+               "sojourn", "upload", "fetch", "mirror", "punt", "total")
 NSTAGES = len(STAGE_NAMES)
 
 # lane ids for batch records
@@ -248,6 +254,17 @@ class Tracer:
         # for (engine.py _fold_stats); 0 in a program without the stage
         self.edge_mirrored = self.edge_filtered = 0
         self.edge_rewrites = self.edge_route_miss = 0
+        # frames NAT punted for a new flow, by what became of them
+        # (runtime/newflow.py): the host created (or found) the session,
+        # refused it (no block, block full: a counted drop), sent the
+        # frame through the chip a second time, saw it punt again there
+        # (dropped), had no room to hold it (dropped), lost it on its
+        # second pass (DROP, or the FWD ring refused it); and the most
+        # frames that waited for a second pass at once
+        self.newflow_admitted = self.newflow_refused = 0
+        self.newflow_requeued = self.newflow_again = 0
+        self.newflow_hold_full = self.newflow_lost = 0
+        self.newflow_hold_high = 0
         # lanes the sharded steps translated (SNAT and DNAT hits, summed
         # over the mesh) and lanes NAT punted to the host (sharded.py
         # _retire); 0 on the one-chip loops
@@ -641,6 +658,13 @@ class Tracer:
             "edge_route_miss": int(self.edge_route_miss),
             "nat_fwd": int(self.nat_fwd),
             "nat_punt": int(self.nat_punt),
+            "newflow_admitted": int(self.newflow_admitted),
+            "newflow_refused": int(self.newflow_refused),
+            "newflow_requeued": int(self.newflow_requeued),
+            "newflow_again": int(self.newflow_again),
+            "newflow_hold_full": int(self.newflow_hold_full),
+            "newflow_lost": int(self.newflow_lost),
+            "newflow_hold_high": int(self.newflow_hold_high),
             "xfer": {"upload_calls": int(self.xfer_calls[0]),
                      "upload_bytes": int(self.xfer_bytes[0]),
                      "fetch_calls": int(self.xfer_calls[1]),
@@ -929,6 +953,25 @@ def nat_lanes(fwd: int, punt: int) -> None:
         return
     _ACTIVE.nat_fwd += fwd
     _ACTIVE.nat_punt += punt
+
+
+def new_flows(admitted: int = 0, refused: int = 0, requeued: int = 0,
+              again: int = 0, hold_full: int = 0, lost: int = 0,
+              hold_high: int = 0) -> None:
+    """Count frames NAT punted for a new flow by what became of them
+    (runtime/newflow.py); `hold_high` is a level, kept as its maximum.
+    Disarmed: global load + None compare."""
+    tr = _ACTIVE
+    if tr is None:
+        return
+    tr.newflow_admitted += admitted
+    tr.newflow_refused += refused
+    tr.newflow_requeued += requeued
+    tr.newflow_again += again
+    tr.newflow_hold_full += hold_full
+    tr.newflow_lost += lost
+    if hold_high > tr.newflow_hold_high:
+        tr.newflow_hold_high = hold_high
 
 
 def trigger(reason: str, detail: str = "") -> str | None:

@@ -24,11 +24,11 @@ SCOPES = ("parse", "antispoof", "dhcp", "garden", "nat44", "qos", "edge",
 BEAT = "bng.beat"  # the Tracer's anchor annotation (telemetry/spans.py)
 # stages that are laps of the host thread (the rest are fed durations:
 # lane_wait, device, sojourn; or span batches across beats: total).
-# `upload`, `fetch` and `mirror` are children of other laps: the shortest
-# lap over a gap's midpoint names it, so a child wins over its parent
+# `upload`, `fetch`, `mirror` and `punt` are children of other laps: the
+# shortest lap over a gap's midpoint names it, so a child wins over its parent
 HOST_LAPS = ("ring", "admit", "dispatch", "device_wait", "fleet",
              "slow_path", "reply", "ops", "wire_rx", "wire_tx", "pack",
-             "drain", "tx", "upload", "fetch", "mirror")
+             "drain", "tx", "upload", "fetch", "mirror", "punt")
 
 
 def _xplane_pb2():

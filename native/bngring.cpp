@@ -690,8 +690,11 @@ int bng_batch_complete(bng_ring *r, const uint8_t *verdict,
   return 0;
 }
 
-int bng_ring_tx_inject(bng_ring *r, const uint8_t *data, uint32_t len,
-                       uint32_t flags) {
+/* A host-held frame onto one of the two output rings: a fresh UMEM frame,
+ * the bytes copied in, queued where complete() queues a lane of that
+ * verdict. */
+static int inject(bng_ring *r, Ring &dst, uint64_t &stat,
+                  const uint8_t *data, uint32_t len, uint32_t flags) {
   if (len > r->frame_size) {
     r->stats.bad_desc++;
     return -1;
@@ -704,13 +707,23 @@ int bng_ring_tx_inject(bng_ring *r, const uint8_t *data, uint32_t len,
   memcpy(r->umem + d.addr, data, len);
   d.len = len;
   d.flags = flags;
-  if (!r->tx.push(d)) {
+  if (!dst.push(d)) {
     r->stats.tx_full++;
     r->fill.push(d);
     return -1;
   }
-  r->stats.tx++;
+  stat++;
   return 0;
+}
+
+int bng_ring_tx_inject(bng_ring *r, const uint8_t *data, uint32_t len,
+                       uint32_t flags) {
+  return inject(r, r->tx, r->stats.tx, data, len, flags);
+}
+
+int bng_ring_fwd_inject(bng_ring *r, const uint8_t *data, uint32_t len,
+                        uint32_t flags) {
+  return inject(r, r->fwd, r->stats.fwd, data, len, flags);
 }
 
 /* Descriptor-based output pops for the AF_XDP wire: the frame STAYS in
@@ -845,6 +858,6 @@ uint32_t bng_abi_desc_addr_off(void) { return offsetof(bng_desc, addr); }
 uint32_t bng_abi_desc_len_off(void) { return offsetof(bng_desc, len); }
 uint32_t bng_abi_desc_flags_off(void) { return offsetof(bng_desc, flags); }
 uint32_t bng_abi_stats_size(void) { return sizeof(bng_ring_stats); }
-uint32_t bng_abi_version(void) { return 4; }
+uint32_t bng_abi_version(void) { return 5; }
 
 } /* extern "C" */

@@ -19,13 +19,14 @@
  *     rx     wire thread (rx_submit/push)  engine thread (batch_assemble)
  *     tx     engine thread (complete,      wire thread (tx_pop, wire_pump)
  *            tx_inject)
- *     fwd    engine thread (complete)      wire thread (fwd_pop, wire_pump)
+ *     fwd    engine thread (complete,      wire thread (fwd_pop, wire_pump)
+ *            fwd_inject)
  *     slow   engine thread (complete)      slow-path thread (slow_pop)
  *
  * The FILL pool is the exception: frame alloc/free crosses all three
  * threads (wire allocates + recycles rx-full rejects; engine frees drops
- * and allocates for tx_inject; slow-path recycles after slow_pop), so it
- * is a bounded MPMC ring (per-slot sequence numbers) and every API is
+ * and allocates for tx_inject / fwd_inject; slow-path recycles after
+ * slow_pop), so it is a bounded MPMC ring (per-slot sequence numbers) and every API is
  * fill-safe from any thread. Single-threaded drivers (the Python engine
  * loop, tests) trivially satisfy the contract.
  *
@@ -250,6 +251,14 @@ int bng_batch_complete(bng_ring *r, const uint8_t *verdict,
  * -1 if no free frame / ring full. */
 int bng_ring_tx_inject(bng_ring *r, const uint8_t *data, uint32_t len,
                        uint32_t flags);
+
+/* Inject a host-held frame onto the FWD ring: the first packet of a NAT
+ * flow the host has just admitted, once the device has translated it on
+ * its second pass (runtime/engine.py HeldFrames; bpf/nat44.c:752-801, the
+ * same packet leaves SNATed). `flags` are the frame's own, as complete()
+ * would have kept them. Returns 0, or -1 if no free frame / ring full. */
+int bng_ring_fwd_inject(bng_ring *r, const uint8_t *data, uint32_t len,
+                        uint32_t flags);
 
 /* Descriptor-based output pops for the AF_XDP wire: the frame stays in
  * UMEM (zero-copy TX); return it to the fill pool with

@@ -10,6 +10,7 @@ tests/test_edge_cell_rehearsal.py. Seeded, tiny sizes, CPU.
 
 import os
 import sys
+import time
 
 import jax
 import numpy as np
@@ -421,11 +422,15 @@ def test_the_schedulers_loop_hands_mirrored_frames_to_the_sink():
         with tele.armed() as tr:
             for frame in (hit, miss, hit):
                 assert a.ring.rx_push(frame, from_access=True)
-            for _ in range(400):  # the bulk lane closes on its deadline
+            for beat in range(4000):  # the bulk lane closes on its deadline
                 a.app.drive_once()
                 if len(a.sunk) == 2 and not len(a.c["scheduler"]._bulk_ring):
                     break
                 a.now += 0.01  # the clock the lanes' deadlines read
+                if beat >= 400:
+                    # the lane retires a step when it is ready and never
+                    # waits for one: on a loaded CPU give the step time
+                    time.sleep(0.005)
             sums = tr.sums()
         assert a.sunk == [("w-s", hit), ("w-s", hit)]
         assert (sums["edge_mirrored"], sums["edge_filtered"]) == (2, 1)

@@ -547,7 +547,10 @@ class App:
     def offer(self, frame, from_access=True):
         """One frame through the loop: (replies on TX, frames forwarded)."""
         assert self.ring.rx_push(frame, from_access=from_access)
-        for _ in range(3):  # the pipelined loop retires a beat later
+        # the pipelined loop retires a beat later; the first frame of a NAT
+        # flow goes through the chip twice (dispatch, retire and hold,
+        # dispatch behind the apply, retire: PR 53)
+        for _ in range(4):
             self.app.drive_once()
         tx, fwd = [], []
         while (got := self.ring.tx_pop()) is not None:
@@ -595,10 +598,11 @@ class App:
             up = codec.eth_frame(server_mac, mac, 0x8864, codec.PPPoEPacket(
                 code=codec.CODE_SESSION, session_id=session_id,
                 payload=codec.ppp_frame(0x0021, plain[14:])).encode())
-        fwd_up = []
-        for _ in range(2):  # the first frame of a flow makes its session
-            fwd_up = self.offer(Plain.tag(up, line))[1] or fwd_up
-        assert len(fwd_up) == 1
+        # the first frame of a flow makes its session and leaves translated
+        # itself (PR 53), as the second does: the same bytes
+        first = self.offer(Plain.tag(up, line))[1]
+        fwd_up = self.offer(Plain.tag(up, line))[1]
+        assert len(fwd_up) == 1 and first == fwd_up
         d = packets.decode(fwd_up[0])
         down = packets.udp_packet(ROUTER_MAC, server_mac, REMOTE, d.src_ip,
                                   443, d.src_port, b"down-" + bytes(8))

@@ -52,7 +52,7 @@ class TestABI:
     def test_stats_layout_and_version(self):
         lib = load_native()
         assert lib.bng_abi_stats_size() == C.sizeof(RingStats)
-        assert lib.bng_abi_version() == 4  # PR 42: two counters, the ranges
+        assert lib.bng_abi_version() == 5  # PR 53: fwd_inject
 
 
 class TestRingBasics:

@@ -553,23 +553,27 @@ def _drive_wire_scenarios(engine, ring, kern, pump):
     assert engine.stats.tx == tx_before + 1  # on-device, not slow path
     out["dora"] = offers + acks + offers2
 
-    # 2. NAT: packet 1 punts (no egress), packet 2 SNATs on device
+    # 2. NAT: packet 1 punts, the host creates its session, and the same
+    #    packet egresses SNATed on its second pass through the chip (PR 53;
+    #    nat44.c:686-801); packet 2 SNATs on device, to the same bytes
     sub_ip = ip_to_u32("10.0.0.55")
     f = packets.udp_packet(bytes.fromhex("02c0ffee0010"), SERVER_MAC,
                            sub_ip, ip_to_u32("93.184.216.34"), 40000, 443,
                            b"nat-payload")
-    punted = roundtrip([f])
-    assert punted == [], "new-flow punt must not egress"
+    passed_before = engine.stats.passed
+    first = roundtrip([f])
+    assert len(first) == 1, "packet 1 of a new flow must egress translated"
+    assert engine.stats.passed == passed_before + 1  # it did punt, once
     natted = roundtrip([f])
-    assert len(natted) == 1
+    assert natted == first and engine.stats.passed == passed_before + 1
     d = packets.decode(natted[0])
     assert d.src_ip == ip_to_u32("203.0.113.1")  # SNAT applied
-    out["nat"] = natted
+    out["nat"] = first + natted
 
-    # 3. QoS: an ESTABLISHED flow (punt first, then device SNAT+shape):
-    #    the 1500-byte bucket passes some ~442-byte frames to the wire
-    #    and the over-budget drops never egress
-    assert roundtrip([_qos_frame()]) == []  # punt creates the session
+    # 3. QoS: an ESTABLISHED flow (its first frame punts and goes round,
+    #    then device SNAT+shape): the 1500-byte bucket passes some
+    #    ~442-byte frames to the wire and the over-budget drops never egress
+    assert len(roundtrip([_qos_frame()])) == 1  # punt creates the session
     dropped_before = engine.stats.dropped
     shaped = roundtrip([_qos_frame() for _ in range(4)])
     n_dropped = engine.stats.dropped - dropped_before
@@ -577,12 +581,12 @@ def _drive_wire_scenarios(engine, ring, kern, pump):
     assert len(shaped) == 4 - n_dropped >= 1
     out["qos"] = shaped
 
-    # 4. PPPoE: data frame 1 punts (inner-flow NAT miss), frame 2
-    #    decaps + SNATs on device
+    # 4. PPPoE: data frame 1 punts (inner-flow NAT miss) and goes round,
+    #    frame 2 decaps + SNATs on device: the same bytes both times
     up = _pppoe_data()
-    assert roundtrip([up]) == []
+    first = roundtrip([up])
     fwd = roundtrip([up])
-    assert len(fwd) == 1
+    assert len(fwd) == 1 and first == fwd
     d = packets.decode(fwd[0])
     assert d.ethertype == 0x0800  # PPPoE framing stripped on device
     assert d.src_ip == ip_to_u32("203.0.113.1")
