@@ -61,6 +61,7 @@ from bng_tpu.ops.nat44 import (
 from bng_tpu.ops.parse import PROTO_ICMP, PROTO_TCP, PROTO_UDP
 from bng_tpu.ops.table import (HostTable, TableGeom, TableUpdate,
                                apply_update, placed)
+from bng_tpu.telemetry import spans as tele
 from bng_tpu.utils.structlog import ErrorLog
 
 # timeouts in seconds (parity: bpf/nat44.c:49-53)
@@ -420,31 +421,10 @@ class NATManager:
 
         sel = np.nonzero(ok)[0]
         if len(sel):
-            skey = np.stack(
-                [src_ips, dst_ips,
-                 ((src_ports & 0xFFFF) << np.uint32(16)) | (dstp & 0xFFFF),
-                 protos], axis=1).astype(np.uint32)
-            rows = np.zeros((nf, SESSION_WORDS), dtype=np.uint32)
-            rows[:, SV_NAT_IP] = nat_ip
-            rows[:, SV_NAT_PORT] = nat_port
-            rows[:, SV_ORIG_IP] = src_ips
-            rows[:, SV_ORIG_PORT] = src_ports
-            rows[:, SV_DEST_IP] = dst_ips
-            rows[:, SV_DEST_PORT] = dstp
-            rows[:, SV_CREATED] = now
-            rows[:, SV_LAST_SEEN] = now
-            rows[:, SV_STATE] = NAT_STATE_NEW
-            rows[:, SV_PROTO] = protos
-            rows[:, SV_PKTS_OUT] = 1
-            rows[:, SV_BYTES_OUT] = pkt_len
+            skey = self._session_keys(src_ips, dst_ips, src_ports, dstp, protos)
+            rows, rkey, rrows = self._flow_rows(skey, src_ports, dstp, nat_ip,
+                                                nat_port, pkt_len, now)
             self.sessions.bulk_insert(skey[sel], rows[sel])
-            r_src = np.where(protos == PROTO_ICMP, 0, dstp).astype(np.uint32)
-            rkey = np.stack(
-                [dst_ips, nat_ip,
-                 ((r_src & 0xFFFF) << np.uint32(16)) | (nat_port & 0xFFFF),
-                 protos], axis=1).astype(np.uint32)
-            rrows = np.zeros((len(skey), REVERSE_WORDS), dtype=np.uint32)
-            rrows[:, :4] = skey
             self.reverse.bulk_insert(rkey[sel], rrows[sel])
         return nat_ip, nat_port, ok
 
@@ -525,66 +505,151 @@ class NATManager:
     def _key(src_ip, dst_ip, src_port, dst_port, proto):
         return [src_ip, dst_ip, ((src_port & 0xFFFF) << 16) | (dst_port & 0xFFFF), proto]
 
+    @staticmethod
+    def _session_keys(src_ips, dst_ips, src_ports, dst_ports, protos) -> np.ndarray:
+        """`_key` of many flows: [n, 4] uint32 from uint32 columns."""
+        return np.stack(
+            [src_ips, dst_ips,
+             ((src_ports & 0xFFFF) << np.uint32(16)) | (dst_ports & 0xFFFF),
+             protos], axis=1).astype(np.uint32)
+
+    @staticmethod
+    def _flow_rows(skey: np.ndarray, src_ports, dst_ports, nat_ip, nat_port,
+                   pkt_len, now: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The session rows, reverse keys and reverse rows of new flows with
+        session keys `skey`, mapped to (`nat_ip`, `nat_port`): uint32
+        columns, an ICMP flow's `dst_ports` already 0, so that its reverse
+        key is (0, echo id) (parity: nat44.c:846-851 -- ingress src_port=0,
+        dst_port=id)."""
+        rows = np.zeros((len(skey), SESSION_WORDS), dtype=np.uint32)
+        rows[:, SV_NAT_IP] = nat_ip
+        rows[:, SV_NAT_PORT] = nat_port
+        rows[:, SV_ORIG_IP] = skey[:, 0]
+        rows[:, SV_ORIG_PORT] = src_ports
+        rows[:, SV_DEST_IP] = skey[:, 1]
+        rows[:, SV_DEST_PORT] = dst_ports
+        rows[:, SV_CREATED] = now
+        rows[:, SV_LAST_SEEN] = now
+        rows[:, SV_STATE] = NAT_STATE_NEW
+        rows[:, SV_PROTO] = skey[:, 3]
+        rows[:, SV_PKTS_OUT] = 1
+        rows[:, SV_BYTES_OUT] = pkt_len
+        rkey = np.stack(
+            [skey[:, 1], nat_ip,
+             ((dst_ports & 0xFFFF) << np.uint32(16)) | (nat_port & 0xFFFF),
+             skey[:, 3]], axis=1).astype(np.uint32)
+        rrows = np.zeros((len(skey), REVERSE_WORDS), dtype=np.uint32)
+        rrows[:, :4] = skey
+        return rows, rkey, rrows
+
     def handle_new_flow(self, src_ip: int, dst_ip: int, src_port: int,
                         dst_port: int, proto: int, pkt_len: int, now: int,
                         is_hairpin: bool = False) -> tuple[int, int] | None:
-        """Create session + reverse rows for a punted first packet.
+        """Create session + reverse rows for a punted first packet: the
+        batch of one of `handle_new_flows`.
 
         Returns (nat_ip, nat_port) or None (no allocation / exhaustion).
         ICMP key convention matches the device: egress (echo_id, 0).
         """
-        block = self.blocks.get(src_ip)
-        if block is None:
-            return None
-        if proto == PROTO_ICMP:
-            dst_port = 0
-        skey = self._key(src_ip, dst_ip, src_port, dst_port, proto)
-        existing = self.sessions.lookup(skey)
-        if existing is not None:
-            return int(existing[SV_NAT_IP]), int(existing[SV_NAT_PORT])
+        got = self.handle_new_flows([src_ip], [dst_ip], [src_port], [dst_port],
+                                    [proto], [pkt_len], now, is_hairpin)[0]
+        if isinstance(got, Exception):
+            raise got
+        return got
 
-        if self.flags & FLAG_EIM:
-            got = self._get_eim(src_ip, src_port, proto, block, now)
-        else:
-            p = self._allocate_port(block, src_port, proto)
-            got = (block["public_ip"], p) if p else None
-        if got is None:
-            self._log(LOG_PORT_EXHAUSTION, block["subscriber_id"], src_ip,
-                      block["public_ip"], src_port, 0, dst_ip, dst_port, proto, now)
-            self.exhausted["port"] += 1
-            self._exhaust_log.report(
-                NATExhaustedError(f"port block {block['port_start']}-"
-                                  f"{block['port_end']} full for subscriber "
-                                  f"{block['subscriber_id']}"),
-                resource="port")
-            return None
-        nat_ip, nat_port = got
+    def handle_new_flows(self, src_ips, dst_ips, src_ports, dst_ports, protos,
+                         pkt_lens, now: int, is_hairpin: bool = False) -> list:
+        """Open the flows a retired window punted, in one batch: the state
+        it leaves is the one `handle_new_flow` would, called on each flow
+        in lane order.
 
-        row = np.zeros((SESSION_WORDS,), dtype=np.uint32)
-        row[SV_NAT_IP] = nat_ip
-        row[SV_NAT_PORT] = nat_port
-        row[SV_ORIG_IP] = src_ip
-        row[SV_ORIG_PORT] = src_port
-        row[SV_DEST_IP] = dst_ip
-        row[SV_DEST_PORT] = dst_port
-        row[SV_CREATED] = now
-        row[SV_LAST_SEEN] = now
-        row[SV_STATE] = NAT_STATE_NEW
-        row[SV_PROTO] = proto
-        row[SV_PKTS_OUT] = 1
-        row[SV_BYTES_OUT] = pkt_len
-        self.sessions.insert(skey, row)
-        # reverse: remote -> nat endpoint. ICMP matches (0, echo_id)
-        # (parity: nat44.c:846-851 — ingress src_port=0, dst_port=id)
-        r_src_port = 0 if proto == PROTO_ICMP else dst_port
-        rkey = self._key(dst_ip, nat_ip, r_src_port, nat_port, proto)
-        rrow = np.zeros((REVERSE_WORDS,), dtype=np.uint32)
-        rrow[:4] = skey
-        self.reverse.insert(rkey, rrow)
-        self._log(LOG_SESSION_CREATE, block["subscriber_id"], src_ip, nat_ip,
-                  src_port, nat_port, dst_ip, dst_port, proto, now,
-                  flags=1 if is_hairpin else 0)
-        return nat_ip, nat_port
+        The batch's session keys are hashed and probed once: a flow whose
+        session exists gets the mapping it holds (a second packet racing
+        the apply), a key that repeats inside the batch is created once
+        and answered twice. The mapping (EIM, the port from the block) is
+        dict work and stays a loop in lane order, so the ports are the
+        ones the one-by-one path gives. The session and reverse rows are
+        two arrays and one placement a table (`HostTable.insert_many`).
+        One compliance record a session, in lane order.
+
+        Returns per flow (nat_ip, nat_port), None (no block, or its block
+        is full), or the RuntimeError of a table that had no room for that
+        flow's row alone (the rest of the batch is created).
+        """
+        col = [np.asarray(c, dtype=np.uint32)
+               for c in (src_ips, dst_ips, src_ports, dst_ports, protos)]
+        col[3] = np.where(col[4] == PROTO_ICMP, 0, col[3]).astype(np.uint32)
+        skey = self._session_keys(*col)
+        keys = list(map(tuple, skey.tolist()))
+        flows = zip(*(c.tolist() for c in col))
+        probed = self.sessions.probe(skey)
+        held = probed[0].tolist()
+        answers: list = [None] * len(keys)
+        made: list[int] = []  # the flows to create, in lane order
+        first: dict[tuple, int] = {}  # a created flow's key -> its lane
+        logs: list[tuple] = []  # (lane, _log's arguments), in lane order
+        eim = self.flags & FLAG_EIM
+        for i, (src_ip, dst_ip, src_port, dst_port, proto) in enumerate(flows):
+            block = self.blocks.get(src_ip)
+            if block is None:
+                continue
+            if held[i] >= 0:
+                row = self.sessions.vals[held[i]]
+                answers[i] = (int(row[SV_NAT_IP]), int(row[SV_NAT_PORT]))
+                continue
+            if keys[i] in first:
+                answers[i] = answers[first[keys[i]]]
+                continue
+            if eim:
+                got = self._get_eim(src_ip, src_port, proto, block, now)
+            else:
+                p = self._allocate_port(block, src_port, proto)
+                got = (block["public_ip"], p) if p else None
+            if got is None:
+                logs.append((i, (LOG_PORT_EXHAUSTION, block["subscriber_id"],
+                                 src_ip, block["public_ip"], src_port, 0,
+                                 dst_ip, dst_port, proto, now)))
+                self.exhausted["port"] += 1
+                self._exhaust_log.report(
+                    NATExhaustedError(f"port block {block['port_start']}-"
+                                      f"{block['port_end']} full for subscriber "
+                                      f"{block['subscriber_id']}"),
+                    resource="port")
+                continue
+            first[keys[i]] = i
+            answers[i] = got
+            made.append(i)
+            logs.append((i, (LOG_SESSION_CREATE, block["subscriber_id"], src_ip,
+                             got[0], src_port, got[1], dst_ip, dst_port, proto,
+                             now, 1 if is_hairpin else 0)))
+        if made:
+            self._place_flows(made, col, skey, probed, answers, pkt_lens, now)
+        for i, args in logs:
+            if not isinstance(answers[i], Exception):
+                self._log(*args)
+        return answers
+
+    def _place_flows(self, made: list[int], col: list, skey: np.ndarray,
+                     probed: tuple, answers: list, pkt_lens, now: int) -> None:
+        """The session and reverse rows of the flows `made` (lanes of the
+        batch, their mappings in `answers`) as two arrays, one placement a
+        table. A flow a table had no room for gets the error as its answer,
+        and a flow without its session row gets no reverse row."""
+        sel = np.asarray(made)
+        nat = np.array([answers[i] for i in made], dtype=np.uint32)
+        rows, rkey, rrows = self._flow_rows(
+            skey[sel], col[2][sel], col[3][sel], nat[:, 0], nat[:, 1],
+            np.asarray(pkt_lens, dtype=np.uint32)[sel], now)
+        walked, failed = self.sessions.insert_many(
+            skey[sel], rows, tuple(a[sel] for a in probed))
+        kept = [j for j in range(len(made)) if j not in failed]
+        r_walked, r_failed = self.reverse.insert_many(rkey[kept], rrows[kept])
+        for j, e in r_failed.items():
+            failed[kept[j]] = e
+        for j, e in failed.items():
+            answers[made[j]] = e
+        tele.new_flows(creates=1, singles=len(
+            set(walked.tolist()) | {kept[j] for j in r_walked.tolist()}))
 
     # -- expiry (host sweep over device-authoritative last_seen) --
     def expire_sessions(self, now: int, device_vals: np.ndarray | None = None) -> int:

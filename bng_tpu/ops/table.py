@@ -46,6 +46,10 @@ from bng_tpu.telemetry import spans as tele
 
 WAYS = 4  # slots per bucket; one bucket = one contiguous gather
 MAX_KICKS = 128  # bounded cuckoo eviction walk (host side)
+# both hash functions in one pass over a batch of keys (HostTable.probe):
+# a [2, 1] seed column broadcasts every word array to [2, n]
+_SEEDS = np.array([[SEED1], [SEED2]], dtype=np.uint32)
+_WAY = np.arange(WAYS)
 
 
 def nbuckets_for(entries: int) -> int:
@@ -410,6 +414,36 @@ class HostTable:
             self._place(slot, old_key, old_val)
         raise RuntimeError(f"table {self.name!r} full (count={self.count})")
 
+    def _place_free(self, keys: np.ndarray, vals: np.ndarray, b1: np.ndarray,
+                    b2: np.ndarray) -> tuple[list[np.ndarray], np.ndarray]:
+        """Place absent, distinct keys into free ways of their buckets `b1`
+        / `b2`: 8 vectorized passes (2 buckets x 4 ways), first-wins per
+        slot within a pass (np.unique). Returns the slots filled, a pass
+        each, and the mask of the keys that found every way taken (the
+        cuckoo-kick walk's)."""
+        unplaced = np.ones((len(keys),), dtype=bool)
+        placed_slots: list[np.ndarray] = []
+        for side in (b1, b2):
+            for w in range(WAYS):
+                idxs = np.nonzero(unplaced)[0]
+                if len(idxs) == 0:
+                    break
+                slot = side[idxs] * WAYS + w
+                free = self.used[slot] == 0
+                idxs, slot = idxs[free], slot[free]
+                if len(idxs) == 0:
+                    continue
+                # first-wins per slot within this pass
+                uq_slot, first = np.unique(slot, return_index=True)
+                take = idxs[first]
+                self.keys[uq_slot] = keys[take]
+                self.vals[uq_slot] = vals[take]
+                self.used[uq_slot] = 1
+                unplaced[take] = False
+                placed_slots.append(uq_slot)
+        self.count += sum(len(s) for s in placed_slots)
+        return placed_slots, unplaced
+
     def bulk_insert(self, keys: np.ndarray, vals: np.ndarray) -> None:
         """Vectorized batch insert for initial table builds (1M-entry scale).
 
@@ -436,27 +470,7 @@ class HostTable:
         b1 = (hash_words(words, SEED1) & m).astype(np.int64)
         b2 = (hash_words(words, SEED2) & m).astype(np.int64)
 
-        unplaced = np.ones((n,), dtype=bool)
-        placed_slots: list[np.ndarray] = []
-        for side in (b1, b2):
-            for w in range(WAYS):
-                idxs = np.nonzero(unplaced)[0]
-                if len(idxs) == 0:
-                    break
-                slot = side[idxs] * WAYS + w
-                free = self.used[slot] == 0
-                idxs, slot = idxs[free], slot[free]
-                if len(idxs) == 0:
-                    continue
-                # first-wins per slot within this pass
-                uq_slot, first = np.unique(slot, return_index=True)
-                take = idxs[first]
-                self.keys[uq_slot] = keys[take]
-                self.vals[uq_slot] = vals[take]
-                self.used[uq_slot] = 1
-                unplaced[take] = False
-                placed_slots.append(uq_slot)
-        self.count += sum(len(s) for s in placed_slots)
+        placed_slots, unplaced = self._place_free(keys, vals, b1, b2)
 
         residue = np.nonzero(unplaced)[0]
         for i in residue:  # cuckoo-kick / stash path for the stragglers
@@ -469,6 +483,83 @@ class HostTable:
         else:
             for s in placed_slots:
                 self._dirty.update(int(x) for x in s)
+
+    # -- batches on a live table (a retire's new flows: control/nat.py) --
+    def probe(self, keys: np.ndarray) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """`_find_slot` of every row of `keys` ([n, K] uint32) in one
+        vector pass: both hashes at once, the eight candidate ways
+        gathered and compared, the stash by one broadcast where it holds
+        anything. Returns (slot, b1, b2): the slot of each key, -1 where
+        the table does not hold it, and its two buckets, which
+        `insert_many` takes back instead of hashing again."""
+        n = len(keys)
+        b = (hash_words([keys[:, k] for k in range(self.K)], _SEEDS)
+             & np.uint32(self.nbuckets - 1)).astype(np.int64)  # [2, n]
+        cand = (b.T[:, :, None] * WAYS + _WAY).reshape(n, 2 * WAYS)
+        match = ((self.keys[cand] == keys[:, None, :]).all(-1)
+                 & (self.used[cand] != 0))
+        base = self.nbuckets * WAYS
+        if self.used[base:].any():
+            match = np.concatenate(
+                [match, (self.keys[base:] == keys[:, None, :]).all(-1)
+                 & (self.used[base:] != 0)], axis=1)
+            cand = np.concatenate(
+                [cand, np.broadcast_to(np.arange(base, self.S), (n, self.stash))],
+                axis=1)
+        row, first = np.arange(n), match.argmax(1)
+        slot = np.where(match[row, first], cand[row, first], -1)
+        return slot, b[0], b[1]
+
+    def lookup_many(self, keys) -> tuple[np.ndarray, np.ndarray]:
+        """`lookup` of every row of `keys` by one `probe`: (found [n] bool,
+        vals [n, V] uint32, zeros where not found)."""
+        keys = np.asarray(keys, dtype=np.uint32).reshape(-1, self.K)
+        slot = self.probe(keys)[0]
+        found = slot >= 0
+        return found, np.where(found[:, None], self.vals[slot], 0).astype(np.uint32)
+
+    def insert_many(self, keys, vals, probed=None) -> tuple[np.ndarray, dict]:
+        """`insert` of every row in order, as one batch on a live table:
+        hash once, probe once, a key the table holds updated in place, the
+        rest placed into free ways by `bulk_insert`'s first-wins passes,
+        and only the residue whose eight ways are all taken through
+        `insert`'s kick walk one by one. EVERY slot written goes into the
+        dirty set whatever the batch's length (`bulk_insert` above `stash`
+        rows abandons it for a full upload: right at start-up, 134 MB in a
+        serving window). A key that repeats takes its last value.
+
+        `probed`: what `probe(keys)` returned, where the caller has it
+        (its keys then are distinct). Returns (walked, failed): the rows
+        that took the kick walk, and {row: RuntimeError} for those the
+        walk found no room for (the table is full for that key alone; the
+        rest of the batch is in)."""
+        keys = np.ascontiguousarray(np.asarray(keys, dtype=np.uint32).reshape(-1, self.K))
+        vals = np.ascontiguousarray(np.asarray(vals, dtype=np.uint32).reshape(-1, self.V))
+        rows = np.arange(len(keys))  # the batch's row of each working row
+        if probed is None:
+            last = {k: i for i, k in enumerate(map(tuple, keys.tolist()))}
+            if len(last) < len(keys):
+                rows = np.fromiter(last.values(), dtype=np.int64, count=len(last))
+                keys, vals = keys[rows], vals[rows]
+            probed = self.probe(keys)
+        slot, b1, b2 = probed
+        held = slot >= 0
+        if held.any():
+            self.vals[slot[held]] = vals[held]
+            self._dirty.update(slot[held].tolist())
+            new = np.nonzero(~held)[0]
+            keys, vals, b1, b2, rows = (a[new] for a in (keys, vals, b1, b2, rows))
+        placed_slots, unplaced = self._place_free(keys, vals, b1, b2)
+        for s in placed_slots:
+            self._dirty.update(s.tolist())
+        walked = np.nonzero(unplaced)[0]
+        failed = {}
+        for i in walked.tolist():
+            try:
+                self.insert(keys[i], vals[i])
+            except RuntimeError as e:
+                failed[int(rows[i])] = e
+        return rows[walked], failed
 
     def delete(self, key) -> bool:
         key = np.asarray(key, dtype=np.uint32).reshape(self.K)

@@ -457,7 +457,7 @@ class ShardedCluster:
         from bng_tpu.runtime.newflow import NewFlows
 
         self.newflows = NewFlows(
-            lambda *flow: self.handle_new_flow(*flow)[1],
+            lambda *flows: self.handle_new_flows(*flows),
             bound=max(n_shards * batch_per_shard // 4, 1))
         # count AND log slow-path failures (rate-limited; Engine parity)
         from bng_tpu.utils.structlog import SlowPathErrorLog
@@ -559,6 +559,24 @@ class ShardedCluster:
     def handle_new_flow(self, src_ip: int, *args, **kw):
         o = self.affinity_shard_ip(src_ip)
         return o, self.nat[o].handle_new_flow(src_ip, *args, **kw)
+
+    def handle_new_flows(self, src_ips, dst_ips, src_ports, dst_ports, protos,
+                         pkt_lens, now: int) -> list:
+        """`NATManager.handle_new_flows` over the mesh: each flow is
+        created on its owner shard's manager, a shard's flows in one batch
+        and in their lane order (the shards' managers share no state, so
+        the state is the one-by-one path's). Answers in lane order."""
+        cols = (src_ips, dst_ips, src_ports, dst_ports, protos, pkt_lens)
+        by_owner: dict[int, list[int]] = {}
+        for i, ip in enumerate(src_ips):
+            by_owner.setdefault(self.affinity_shard_ip(ip), []).append(i)
+        answers: list = [None] * len(src_ips)
+        for o, lanes in by_owner.items():
+            got = self.nat[o].handle_new_flows(
+                *([c[i] for i in lanes] for c in cols), now)
+            for i, g in zip(lanes, got):
+                answers[i] = g
+        return answers
 
     def set_qos(self, private_ip: int, **kw) -> int:
         o = self.affinity_shard_ip(private_ip)
@@ -1383,31 +1401,44 @@ class ShardedCluster:
                 self.mirror_sink(int(lane),
                                  bytes(pkt[lane, : int(length[lane])]),
                                  int(mirw[lane]))
-        # slow drain, lane-aligned with the PASS lanes complete() queued
+        # slow drain, lane-aligned with the PASS lanes complete() queued;
+        # the punted lanes' (frame, ring flags, lane) are served in one
+        # batch after the walk (built at the first punt)
+        punted = None
         for lane in np.nonzero((verdict == VERDICT_PASS) & real)[0]:
             got_f = ring.slow_pop()
             if got_f is None:
                 break  # slow ring overflowed during complete()
             frame, fl = got_f
+            if punt[lane]:
+                if punted is None:
+                    punted = []
+                punted.append((frame, fl, int(lane)))
+                continue
             try:
-                if punt[lane]:
-                    # the create on the owner shard; a refused flow's
-                    # frame is a counted drop (newflows.stats)
-                    self.newflows.punt(frame, fl, int(now_s),
-                                       self.pppoe is not None)
-                elif slow_path is not None:
+                if slow_path is not None:
                     reply = slow_path(frame)
                     if reply is not None:
                         t1 = tele.t()
                         ring.tx_inject(reply, from_access=(fl & 0x1) != 0)
                         tele.lap(tele.TX, t1, tok)
             except Exception as e:  # noqa: BLE001 — slow path is untrusted input
-                self.stats["slow_errors"] += 1
-                self._slow_err_log.report(e, path="ring", lane=int(lane))
+                self._slow_error(int(lane), e)
+        if punted is not None:
+            # the creates on the owner shards; a refused flow's frame is a
+            # counted drop (newflows.stats)
+            frames, fls, lanes = zip(*punted)
+            self.newflows.punt_many(
+                frames, fls, int(now_s), self.pppoe is not None,
+                on_error=lambda i, e: self._slow_error(lanes[i], e))
         tele.lap(tele.SLOW, t0, tok)
         self._probe(self._inflight)
         tele.end_batch(tok)
         return got
+
+    def _slow_error(self, lane: int, e: Exception) -> None:
+        self.stats["slow_errors"] += 1
+        self._slow_err_log.report(e, path="ring", lane=lane)
 
     def _fold_stats(self, **deltas) -> None:
         for k, v in deltas.items():

@@ -556,7 +556,7 @@ class Engine:
         # waiting to go through the chip a second time (runtime/newflow.py;
         # at most a quarter of a window waits, the rest is the ring's)
         self.newflows = NewFlows(
-            lambda *flow: self.nat.handle_new_flow(*flow),
+            lambda *flows: self.nat.handle_new_flows(*flows),
             bound=max(batch_size // 4, 1))
         self._inflight = None  # pipelined ring mode (process_ring_pipelined)
         self._stage_bufs = [None, None]  # ping-pong staging (lazy alloc)
@@ -1664,27 +1664,33 @@ class Engine:
         # every later batch's lane/punt matching (and wedge PyRing).
         slow_items = []  # (lane, frame); from_access flags kept aside
         slow_fa = {}
-        punts = 0
+        # the punted lanes' (frame, ring flags, lane), served in one batch
+        # after the walk. Built at the first punt: a retire without one
+        # allocates nothing for it and makes no call
+        punted = None
         for lane in np.nonzero(vv == VERDICT_PASS)[0]:
             got = ring.slow_pop()
             if got is None:
                 break  # slow ring overflowed during complete()
             frame, fl = got
             if punt[lane]:
-                punts += 1
-                try:
-                    kept = self.newflows.punt(frame, fl, int(now),
-                                              self.pppoe is not None)
-                except Exception as e:  # noqa: BLE001 — untrusted input
-                    kept = False
-                    self.stats.slow_errors += 1
-                    self._slow_err_log.report(e, path="ring", lane=int(lane))
-                # a frame that will not go round (refused flow, no room to
-                # wait in) is a counted drop, not a silent consumption
-                self.stats.dropped += not kept
+                if punted is None:
+                    punted = []
+                punted.append((frame, fl, int(lane)))
             else:
                 slow_items.append((int(lane), frame))
                 slow_fa[int(lane)] = (fl & 0x1) != 0
+        punts = 0
+        if punted is not None:
+            frames, fls, lanes = zip(*punted)
+            punts = len(frames)
+            kept = self.newflows.punt_many(
+                frames, fls, int(now), self.pppoe is not None,
+                on_error=self.punt_reporter("ring", lanes))
+            # a frame that will not go round (refused flow, no room to
+            # wait in, a create that raised) is a counted drop, not a
+            # silent consumption
+            self.stats.dropped += kept.count(False)
         tele.lap(tele.REPLY, t0)
         tele.add(punt=punts)
         # fan-out/fan-in: replies come back re-merged in lane order, so
@@ -1692,6 +1698,15 @@ class Engine:
         for lane, reply in self._handle_slow_lanes(slow_items, path="ring"):
             if reply is not None:
                 ring.tx_inject(reply, from_access=slow_fa[lane])
+
+    def punt_reporter(self, path: str, lanes):
+        """`NewFlows.punt_many`'s `on_error` for a retire on loop `path`
+        whose punted frames came from `lanes`: a frame whose create raised
+        is counted and logged with its lane, as any slow-path failure."""
+        def report(i: int, e: Exception) -> None:
+            self.stats.slow_errors += 1
+            self._slow_err_log.report(e, path=path, lane=lanes[i])
+        return report
 
     def _staging(self, idx: int):
         """Ping-pong staging buffers (allocated once; the in-flight batch

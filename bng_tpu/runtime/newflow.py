@@ -5,9 +5,9 @@ The device finds no session for an upstream frame and passes it up with
 (bpf/nat44.c:686-801: session miss -> EIM mapping -> port from the
 subscriber's block -> session and reverse entry -> SNAT rewrite of the
 packet in hand, TC_ACT_OK; no block or a full one: TC_ACT_SHOT,
-:698-705). Here the host creates the session (`NATManager.handle_new_flow`)
-and the frame goes **through the chip a second time**: the tables are dirty
-after the create, the packet-free apply program runs ahead of the next step
+:698-705). Here the host creates the sessions of the flows a retired window
+punted, in one batch (`NATManager.handle_new_flows`), and each frame goes
+**through the chip a second time**: the tables are dirty after the create, the packet-free apply program runs ahead of the next step
 to be dispatched, and the frame, at the head of that step's window, is
 translated by the one NAT rewrite the tree has (PPPoE decap, tag handling
 and both checksums with it). A later packet of the flow that punts before
@@ -31,6 +31,7 @@ cannot decode and a hold queue that is full are counted drops too.
 
 from __future__ import annotations
 
+import struct
 from collections import deque
 from dataclasses import dataclass
 
@@ -74,14 +75,51 @@ def strip_pppoe(frame: bytes) -> bytes:
     return frame[:off] + b"\x08\x00" + frame[off + 10:]
 
 
+_U16 = struct.Struct("!H")
+_IPV4 = struct.Struct("!B8xB2xII")  # version/IHL, protocol, source, destination
+# source and destination port by protocol, over the bytes `packets.decode`
+# reads of that header (so the same frames count as truncated): UDP's
+# eight, TCP's up to the checksum, an ICMP echo's id as the source port
+_L4 = {17: struct.Struct("!HH4x"), 6: struct.Struct("!HH14x"),
+       1: struct.Struct("!4xH")}
+
+
+def flow_of(frame: bytes, pppoe: bool) -> tuple | None:
+    """(src_ip, dst_ip, src_port, dst_port, proto, length) of a punted
+    frame, read at its fixed offsets after the VLAN / PPPoE strip: field
+    for field what `packets.decode` gives (the header length honoured, an
+    ICMP echo's id as its source port and 0 as its destination port, the
+    length that of the stripped view). None: not an IPv4 frame, or cut
+    short of what it says it holds."""
+    view = strip_pppoe(frame) if pppoe else frame
+    try:
+        off = 12
+        et = _U16.unpack_from(view, off)[0]
+        while et in (0x8100, 0x88A8):
+            off += 4
+            et = _U16.unpack_from(view, off)[0]
+        if et != 0x0800:
+            return None
+        ver_ihl, proto, src_ip, dst_ip = _IPV4.unpack_from(view, off + 2)
+        l4 = _L4.get(proto)
+        ports = (l4.unpack_from(view, off + 2 + (ver_ihl & 0x0F) * 4)
+                 if l4 is not None else ())
+    except struct.error:
+        return None
+    src_port, dst_port = (*ports, 0, 0)[:2]
+    return src_ip, dst_ip, src_port, dst_port, proto, len(view)
+
+
 class NewFlows:
     """The punt handler of one loop: the create, the counts, and (for the
     ring loops) the frames waiting for their second pass."""
 
-    def __init__(self, handle_new_flow, bound: int):
-        # (src_ip, dst_ip, src_port, dst_port, proto, pkt_len, now) ->
-        # (nat_ip, nat_port) | None; read at call time by the owner
-        self.handle_new_flow = handle_new_flow
+    def __init__(self, handle_new_flows, bound: int):
+        # (src_ips, dst_ips, src_ports, dst_ports, protos, pkt_lens, now)
+        # -> per flow (nat_ip, nat_port) | None | the error that flow
+        # alone met (control/nat.py NATManager.handle_new_flows); read at
+        # call time by the owner
+        self.handle_new_flows = handle_new_flows
         self.bound = bound
         self.stats = NewFlowStats()
         self._held: deque = deque()  # (frame as it arrived, ring flags)
@@ -91,24 +129,30 @@ class NewFlows:
 
     # -- the create ------------------------------------------------------
 
-    def create(self, frame: bytes, now: int, pppoe: bool) -> bool:
-        """Create the session of a punted frame's flow (packet 1 of a new
-        flow; parity with the conntrack-hybrid slow path). True where the
-        flow holds a session now. Alone, it is `Engine.process`'s: that
-        caller holds the frame itself."""
-        from bng_tpu.control import packets as P
-
-        view = strip_pppoe(frame) if pppoe else frame
+    def _open(self, frames, now: int, pppoe: bool) -> list:
+        """Create the sessions of punted frames' flows (packet 1 of a new
+        flow; parity with the conntrack-hybrid slow path), in one batch.
+        Per frame: the mapping its flow holds now, None (refused: no
+        block, block full, not an IPv4 frame), or the error the create met
+        for it. An error that is not one flow's is every flow's."""
+        parsed = [flow_of(frame, pppoe) for frame in frames]
+        flows = [f for f in parsed if f is not None]
+        if not flows:
+            return parsed
         try:
-            d = P.decode(view)
-        except Exception:  # noqa: BLE001 — untrusted input
-            return False
-        if d.ethertype != 0x0800:
-            return False
-        src_port = d.icmp_id if d.proto == 1 else d.src_port
-        dst_port = 0 if d.proto == 1 else d.dst_port
-        return self.handle_new_flow(d.src_ip, d.dst_ip, src_port, dst_port,
-                                    d.proto, len(view), now) is not None
+            got = iter(self.handle_new_flows(*zip(*flows), now))
+        except Exception as e:  # noqa: BLE001 — untrusted input
+            return [None if f is None else e for f in parsed]
+        return [None if f is None else next(got) for f in parsed]
+
+    def create(self, frame: bytes, now: int, pppoe: bool) -> bool:
+        """The batch of one, with no hand-back: `Engine.process`'s, whose
+        caller holds the frame itself. True where the flow holds a session
+        now; an error the create met is raised."""
+        got = self._open([frame], now, pppoe)[0]
+        if isinstance(got, Exception):
+            raise got
+        return got is not None
 
     def _hold(self, frame: bytes, flags: int) -> bool:
         if len(self._held) >= self.bound:
@@ -116,30 +160,39 @@ class NewFlows:
         self._held.append((frame, flags))
         return True
 
-    def punt(self, frame: bytes, flags: int, now: int, pppoe: bool,
-             hold=None) -> bool:
-        """One punted frame: create the session, then hand the frame back
-        for its second pass -- into this queue, or through `hold(frame,
-        flags) -> bool` where the loop has a queue of its own (the
-        scheduler's bulk lane). False: the frame is dropped, and counted
-        here. One `punt` lap a frame, inside the retire's `reply`."""
+    def punt_many(self, frames, flags, now: int, pppoe: bool, *, on_error,
+                  hold=None) -> list[bool]:
+        """The frames a retired window punted, in lane order: create their
+        sessions in one batch, then hand each frame back for its second
+        pass -- into this queue, or through `hold[i](frame, flags) -> bool`
+        where the loop has a queue of its own (the scheduler's bulk lane).
+        False: that frame is dropped, and counted here (`refused`,
+        `hold_full`) or by `on_error(i, error)` where the create raised
+        for it; the frames around it are served. One `punt` lap a batch,
+        inside the retire's `reply`."""
         t0 = tele.t()
         st = self.stats
-        try:
-            if not self.create(frame, now, pppoe):
-                st.refused += 1
-                tele.new_flows(refused=1)
-                return False
-            st.admitted += 1
-            if not (hold or self._hold)(frame, flags):
-                st.hold_full += 1
-                tele.new_flows(admitted=1, hold_full=1)
-                return False
-            st.hold_high = max(st.hold_high, len(self._held))
-            tele.new_flows(admitted=1, hold_high=len(self._held))
-            return True
-        finally:
-            tele.lap(tele.PUNT, t0)
+        admitted = refused = hold_full = 0
+        kept = [False] * len(frames)
+        for i, got in enumerate(self._open(frames, now, pppoe)):
+            if got is None:
+                refused += 1
+            elif isinstance(got, Exception):
+                on_error(i, got)
+            else:
+                admitted += 1
+                if (hold[i] if hold else self._hold)(frames[i], flags[i]):
+                    kept[i] = True
+                else:
+                    hold_full += 1
+        st.admitted += admitted
+        st.refused += refused
+        st.hold_full += hold_full
+        st.hold_high = max(st.hold_high, len(self._held))
+        tele.new_flows(admitted=admitted, refused=refused, hold_full=hold_full,
+                       hold_high=len(self._held))
+        tele.lap(tele.PUNT, t0)
+        return kept
 
     # -- the second pass ---------------------------------------------------
 

@@ -821,30 +821,31 @@ class TieredScheduler:
         # NAT punts stay inline (parent-owned manager); everything else
         # drains through the batched slow path in one fan-out
         slow_items = []
-        punts = 0
-        # lane -> its frame, kept for a second pass: the lane's own queue
-        # holds it (at its head, below), so this loop has no other. Built
-        # at the first punt: a retire without one allocates nothing for it
+        # the punted lanes, served in one batch after the walk: their
+        # indices, and a hold each that keeps the lane's frame for a second
+        # pass in `again` (lane -> its frame: the lane's own queue holds it,
+        # at its head, below, so this loop has no other). Built at the
+        # first punt: a retire without one allocates nothing for it
         again: dict = None
         for i, p in enumerate(entry.pending):
             if (int(vv[i]) in (VERDICT_TX, VERDICT_FWD, VERDICT_DROP)
                     or p.desc is SECOND_PASS):
                 continue
             if punt[i]:
-                punts += 1
                 if again is None:
-                    again = {}
-                try:
-                    eng.newflows.punt(
-                        p.frame, 0, int(entry.dispatch_t),
-                        eng.pppoe is not None,
-                        hold=functools.partial(self._hold_for_second_pass,
+                    again, lanes, holds = {}, [], []
+                lanes.append(i)
+                holds.append(functools.partial(self._hold_for_second_pass,
                                                again, i, p))
-                except Exception as e:  # noqa: BLE001 — untrusted input
-                    eng.stats.slow_errors += 1
-                    eng._slow_err_log.report(e, path="sched_bulk", lane=i)
             else:
                 slow_items.append((i, p.frame, p.enq_t))
+        punts = 0
+        if again is not None:
+            punts = len(lanes)
+            eng.newflows.punt_many(
+                [entry.pending[i].frame for i in lanes], [0] * punts,
+                int(entry.dispatch_t), eng.pppoe is not None,
+                on_error=eng.punt_reporter("sched_bulk", lanes), hold=holds)
         if again:
             self.bulk.requeue_front(list(again.values()))
         replies = dict(eng._handle_slow_lanes(slow_items, path="sched_bulk"))
@@ -893,8 +894,9 @@ class TieredScheduler:
     def _hold_for_second_pass(self, again: dict, i: int, p, _frame=None,
                               _flags=None) -> bool:
         """Keep lane `i`'s frame of a retiring batch for the head of the
-        bulk lane, while the lane has room for it (`NewFlows.punt`'s
-        `hold`: it passes the frame and its flags, which `p` holds)."""
+        bulk lane, while the lane has room for it (one of
+        `NewFlows.punt_many`'s `hold`: it passes the frame and its flags,
+        which `p` holds)."""
         if len(self.bulk) + len(again) >= self.bulk.cfg.max_queue:
             return False
         again[i] = p._replace(desc=SECOND_PASS)

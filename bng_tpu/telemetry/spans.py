@@ -66,12 +66,13 @@ order:
                    retire around the extraction of the lanes whose mirror
                    word is set and the sink's calls, inside `reply`; only
                    where a sink is set (`bng run --edge-enabled`)
-       punt        the host thread serving one frame NAT punted for a
-                   new flow: decode, `NATManager.handle_new_flow` (the
-                   mapping, the session and reverse rows, the compliance
-                   log's call) and the hand-back of the frame for its
-                   second pass (runtime/newflow.py NewFlows.punt): one lap
-                   a punted frame, inside `reply`
+       punt        the host thread serving the frames of a retired window
+                   that NAT punted for a new flow: decode,
+                   `NATManager.handle_new_flows` (the mappings, the session
+                   and reverse rows, the compliance log's calls) and the
+                   hand-back of each frame for its second pass
+                   (runtime/newflow.py NewFlows.punt_many): one lap a
+                   retire that punted, inside `reply`
        total       batch begin -> end (the client-visible wall time)
 
    `upload` and `fetch` are children of the laps that enclose them
@@ -260,7 +261,11 @@ class Tracer:
         # frame through the chip a second time, saw it punt again there
         # (dropped), had no room to hold it (dropped), lost it on its
         # second pass (DROP, or the FWD ring refused it); and the most
-        # frames that waited for a second pass at once
+        # frames that waited for a second pass at once. `creates`: the
+        # batches that opened at least one flow (control/nat.py
+        # handle_new_flows), `singles`: the flows of them whose row took
+        # the host table's one-by-one kick walk
+        self.newflow_creates = self.newflow_singles = 0
         self.newflow_admitted = self.newflow_refused = 0
         self.newflow_requeued = self.newflow_again = 0
         self.newflow_hold_full = self.newflow_lost = 0
@@ -658,6 +663,8 @@ class Tracer:
             "edge_route_miss": int(self.edge_route_miss),
             "nat_fwd": int(self.nat_fwd),
             "nat_punt": int(self.nat_punt),
+            "newflow_creates": int(self.newflow_creates),
+            "newflow_singles": int(self.newflow_singles),
             "newflow_admitted": int(self.newflow_admitted),
             "newflow_refused": int(self.newflow_refused),
             "newflow_requeued": int(self.newflow_requeued),
@@ -957,13 +964,16 @@ def nat_lanes(fwd: int, punt: int) -> None:
 
 def new_flows(admitted: int = 0, refused: int = 0, requeued: int = 0,
               again: int = 0, hold_full: int = 0, lost: int = 0,
-              hold_high: int = 0) -> None:
+              hold_high: int = 0, creates: int = 0, singles: int = 0) -> None:
     """Count frames NAT punted for a new flow by what became of them
-    (runtime/newflow.py); `hold_high` is a level, kept as its maximum.
-    Disarmed: global load + None compare."""
+    (runtime/newflow.py), and the batches that opened them (`creates`,
+    `singles`: control/nat.py); `hold_high` is a level, kept as its
+    maximum. Disarmed: global load + None compare."""
     tr = _ACTIVE
     if tr is None:
         return
+    tr.newflow_creates += creates
+    tr.newflow_singles += singles
     tr.newflow_admitted += admitted
     tr.newflow_refused += refused
     tr.newflow_requeued += requeued

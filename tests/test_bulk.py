@@ -86,6 +86,134 @@ class TestHostTableBulkInsert:
         np.testing.assert_array_equal(got, keys)
 
 
+def _rows(rng, n, k, v):
+    keys = rng.integers(0, 1 << 32, (n, k), dtype=np.uint64).astype(np.uint32)
+    vals = rng.integers(0, 1 << 32, (n, v), dtype=np.uint64).astype(np.uint32)
+    return keys, vals
+
+
+class TestHostTableInsertMany:
+    """The batch verbs of a LIVE table (PR 54: a retire's new flows):
+    `insert_many` is `insert` of every row in order, `probe` /
+    `lookup_many` are `_find_slot` / `lookup` of every key, and every slot
+    written is dirty whatever the batch's length."""
+
+    @pytest.mark.parametrize("n", [1, 2, 21, 64, 65, 300])
+    def test_matches_per_key_insert_and_stays_on_delta_sync(self, n):
+        rng = np.random.default_rng([54, n])
+        a = HostTable(1 << 9, key_words=4, val_words=5, stash=64, name="a")
+        b = HostTable(1 << 9, key_words=4, val_words=5, stash=64, name="b")
+        base_k, base_v = _rows(rng, 1000, 4, 5)  # half full, as provisioned
+        for t in (a, b):
+            t.bulk_insert(base_k, base_v)
+            t.device_state()
+        keys, vals = _rows(rng, n, 4, 5)
+        walked, failed = a.insert_many(keys, vals)
+        for k, v in zip(keys, vals):
+            b.insert(k, v)
+        assert not failed and a.count == b.count == 1000 + n
+        assert not a._dirty_all and a.dirty_count() >= n
+        found, got = a.lookup_many(keys)
+        assert bool(found.all())
+        np.testing.assert_array_equal(got, vals)
+        np.testing.assert_array_equal(a.lookup_batch_host(keys), vals)
+        np.testing.assert_array_equal(b.lookup_batch_host(keys), vals)
+        np.testing.assert_array_equal(a.lookup_batch_host(base_k), base_v)
+        # every slot the batch wrote is queued: the chip answers after one
+        # bounded drain for a batch under the update's size
+        slots = a.probe(keys)[0]
+        assert set(slots.tolist()) <= a._dirty
+        state = a.device_state()
+        res = device_lookup(state, jnp.asarray(keys), a.nbuckets, a.stash)
+        assert bool(res.found.all())
+        np.testing.assert_array_equal(np.asarray(res.slot), slots)
+
+    def test_full_buckets_send_the_residue_through_the_kick_walk_and_the_stash(self):
+        rng = np.random.default_rng(5402)
+        t = HostTable(2, key_words=2, val_words=2, stash=8, name="tiny")
+        keys, vals = _rows(rng, 8 + 5 + 6, 2, 2)
+        assert t.insert_many(keys[:8], vals[:8])[1] == {}
+        assert t.count == 8 and not t.used[8:].any()  # both buckets full
+        # five more: no way is free, each walks, kicks, and lands in the stash
+        walked, failed = t.insert_many(keys[8:13], vals[8:13])
+        assert walked.tolist() == [0, 1, 2, 3, 4] and not failed
+        assert t.count == 13 and int(t.used[8:].sum()) == 5
+        found, got = t.lookup_many(keys[:13])  # stash hits among them
+        assert bool(found.all())
+        np.testing.assert_array_equal(got, vals[:13])
+        np.testing.assert_array_equal(t.lookup_batch_host(keys[:13]), vals[:13])
+        # six more into three stash slots: the table is full for three keys
+        # alone, they are named, and the rest of the batch is in
+        walked, failed = t.insert_many(keys[13:], vals[13:])
+        assert walked.tolist() == list(range(6)) and sorted(failed) == [3, 4, 5]
+        assert all(isinstance(e, RuntimeError) for e in failed.values())
+        assert t.count == 16
+        found, got = t.lookup_many(keys)
+        assert found.tolist() == [True] * 16 + [False] * 3
+        np.testing.assert_array_equal(got[:16], vals[:16])
+        np.testing.assert_array_equal(t.lookup_batch_host(keys[:16]), vals[:16])
+        assert not got[16:].any() and not t._dirty_all
+
+    def test_present_keys_update_in_place_and_a_repeat_takes_its_last_value(self):
+        rng = np.random.default_rng(5403)
+        t = HostTable(1 << 8, key_words=3, val_words=4, stash=16)
+        keys, vals = _rows(rng, 40, 3, 4)
+        t.insert_many(keys[:20], vals[:20])
+        slots = t.probe(keys[:20])[0].copy()
+        t.device_state()
+        # ten held keys with new values, twenty new, one of them three times
+        again_k = np.concatenate([keys[5:15], keys[20:], keys[25:26], keys[25:26]])
+        again_v = np.concatenate([vals[5:15] + 1, vals[20:], vals[25:26] + 7,
+                                  vals[25:26] + 9])
+        walked, failed = t.insert_many(again_k, again_v)
+        assert not failed and t.count == 40
+        np.testing.assert_array_equal(t.probe(keys[:20])[0], slots)  # in place
+        want = vals.copy()
+        want[5:15] += 1
+        want[25] += 9
+        found, got = t.lookup_many(keys)
+        assert bool(found.all())
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(t.lookup_batch_host(keys), want)
+        assert t._dirty == set(t.probe(again_k)[0].tolist())
+
+    @pytest.mark.parametrize("stashed", [0, 3])
+    def test_the_vector_probe_is_find_slot_key_by_key(self, stashed):
+        rng = np.random.default_rng([5404, stashed])
+        t = HostTable(1 << 6, key_words=4, val_words=2, stash=8)
+        keys, vals = _rows(rng, 200, 4, 2)
+        t.insert_many(keys[:150], vals[:150])
+        base = t.nbuckets * 4
+        for i in range(stashed):  # stash hits, placed there by hand
+            t._place(base + 2 * i, keys[150 + i], vals[150 + i])
+        slot, b1, b2 = t.probe(keys)
+        for k, s, x, y in zip(keys, slot.tolist(), b1.tolist(), b2.tolist()):
+            assert (x, y) == t._buckets(k)
+            assert s == (-1 if t._find_slot(k) is None else t._find_slot(k))
+        assert int((slot >= base).sum()) == stashed
+        found, got = t.lookup_many(keys)
+        np.testing.assert_array_equal(got, t.lookup_batch_host(keys))
+        assert found.tolist() == [t.lookup(k) is not None for k in keys]
+
+    @pytest.mark.parametrize("n, dirty_all", [(63, False), (64, False),
+                                              (65, True), (300, True)])
+    def test_bulk_insert_keeps_its_provisioning_behaviour(self, n, dirty_all):
+        """`bulk_insert` above `stash` rows still abandons the dirty set for
+        a full upload (start-up's); the live verb never does."""
+        rng = np.random.default_rng([5405, n])
+        keys, vals = _rows(rng, n, 2, 2)
+        t = HostTable(1 << 9, key_words=2, val_words=2, stash=64)
+        t.bulk_insert(keys, vals)
+        assert t._dirty_all is dirty_all
+        assert t.dirty_count() == (t.S if dirty_all else n)
+        assert (t._dirty == set()) is dirty_all
+        live = HostTable(1 << 9, key_words=2, val_words=2, stash=64)
+        live.insert_many(keys, vals)
+        assert not live._dirty_all and live.dirty_count() == n
+        np.testing.assert_array_equal(live.keys, t.keys)  # the same passes
+        np.testing.assert_array_equal(live.vals, t.vals)
+
+
 class TestFastPathBulk:
     def test_bulk_subscribers_visible_on_device(self):
         n = 5000

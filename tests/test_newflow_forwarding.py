@@ -258,8 +258,8 @@ def test_a_frame_that_punts_again_is_counted_and_does_not_loop(loop,
     """A create that reports a mapping and writes no session (a fault: the
     apply runs ahead of the step that carries the frame): the frame punts
     again on its second pass, is counted, dropped, and does not go round."""
-    monkeypatch.setattr(loop.newflows, "handle_new_flow",
-                        lambda *flow: (PUB[0], 1024))
+    monkeypatch.setattr(loop.newflows, "handle_new_flows",
+                        lambda src, *rest: [(PUB[0], 1024)] * len(src))
     st, before = loop.newflows.stats, len(loop.out)
     again0, drops0, req0 = st.again, loop.dropped(), st.requeued
     loop.push(up_frame(SUB_BASE + 20, ip_to_u32("93.184.9.9"), 52000, 443, 6,
@@ -285,6 +285,147 @@ def test_the_counts_close(loop):
     if loop.kind == "engine":
         assert loop.ring.stats()["rx"] == loop.pushed
         assert loop.ring.stats()["slow"] == punts
+
+
+# -- one create a retire (PR 54) ------------------------------------------------
+
+def _errors(loop) -> int:
+    return (loop.cl.stats["slow_errors"] if loop.kind == "cluster"
+            else loop.eng.stats.slow_errors)
+
+
+def _counting(loop, monkeypatch, spoil=None) -> list:
+    """Count the loop's create calls (the flows of each); `spoil(answers)`
+    edits a call's answers before the loop sees them."""
+    calls, real = [], loop.newflows.handle_new_flows
+
+    def create(*cols):
+        calls.append(len(cols[0]))
+        got = real(*cols)
+        if spoil is not None:
+            spoil(got)
+        return got
+
+    monkeypatch.setattr(loop.newflows, "handle_new_flows", create)
+    return calls
+
+
+def _window_of(loop, first_sub: int, k: int, port: int) -> list:
+    """k first packets from k subscribers, pushed into one window."""
+    flows = [(SUB_BASE + first_sub + i, ip_to_u32("93.184.9.9"), port, 443, 17)
+             for i in range(k)]
+    for i, flow in enumerate(flows):
+        assert loop.plain.open(*flow) is not None
+        loop.push(up_frame(*flow, 200 + i))
+    return flows
+
+
+def _fids(frames) -> list[int]:
+    return [int.from_bytes(f[-4:], "big") for f in frames]
+
+
+@pytest.mark.parametrize("armed", [True, False], ids=["armed", "disarmed"])
+def test_a_windows_punts_are_one_create_and_one_lap(loop, monkeypatch, armed):
+    """Four punted lanes of one window (the ring loops hold a quarter of a
+    16-lane window): one `handle_new_flows` over the four, one `punt` lap,
+    `newflow_creates` one a manager that opened a flow (one on a chip, one an owner shard on the mesh); disarmed, the
+    same frames leave and nothing is counted."""
+    calls = _counting(loop, monkeypatch)
+    st, before = loop.newflows.stats, len(loop.out)
+    admitted0 = st.admitted
+    tr = tele.arm(tele.Tracer(keep_events=1 << 12)) if armed else None
+    try:
+        flows = _window_of(loop, 24 if armed else 30, 4, 55000)
+        loop.beat(8)
+    finally:
+        tele.disarm()
+    assert calls == [4] and st.admitted == admitted0 + 4
+    got = loop.out[before:]
+    assert sorted(_fids(got)) == list(range(200, 204))
+    if loop.kind != "cluster":  # one queue: in the order they came
+        assert _fids(got) == list(range(200, 204))
+    sums = (tr or tele.Tracer()).sums()
+    if not armed:
+        assert (sums["newflow_creates"], sums["newflow_admitted"],
+                sums["stage_ns"]["punt"]) == (0, 0, 0)
+        return
+    owners = ({loop.cl.affinity_shard_ip(f[0]) for f in flows}
+              if loop.kind == "cluster" else {0})
+    assert sums["newflow_creates"] == len(owners)
+    assert (sums["newflow_admitted"], sums["newflow_singles"]) == (4, 0)
+    assert sum(1 for e in tr.events if e[0] == tele.PUNT) == 1
+    assert sums["stage_ns"]["punt"] > 0
+    if loop.kind == "engine":  # the ring loop's retire: a child of `reply`
+        assert sums["stage_ns"]["punt"] <= sums["stage_ns"]["reply"]
+
+
+def test_a_window_without_a_punt_makes_no_call(loop, monkeypatch):
+    def never(*a, **kw):
+        raise AssertionError("no lane punted: nothing to serve")
+
+    monkeypatch.setattr(loop.newflows, "punt_many", never)
+    calls = _counting(loop, monkeypatch)
+    before = len(loop.out)
+    tr = tele.arm(tele.Tracer(keep_events=1 << 12))
+    try:
+        for i in range(6):  # provisioned flows: the chip answers alone
+            loop.push(up_frame(SUB_BASE + i, REMOTE, 40000, 443, 17, 300 + i))
+        loop.beat(6)
+    finally:
+        tele.disarm()
+    assert len(loop.out) == before + 6 and not calls
+    assert not any(e[0] == tele.PUNT for e in tr.events)
+    assert tr.sums()["newflow_creates"] == 0
+
+
+def test_one_bad_flow_in_a_batch_costs_that_frame_alone(loop, monkeypatch):
+    """The create raises for the middle flow of five (a table with no room
+    for its row): it is reported with its lane and dropped, the four around
+    it leave translated in order, and the next window is served."""
+    def spoil(answers):
+        if len(answers) == 5:
+            answers[2] = RuntimeError("table 'nat_sessions' full")
+
+    calls = _counting(loop, monkeypatch, spoil)
+    st, before, err0 = loop.newflows.stats, len(loop.out), _errors(loop)
+    drops0, admitted0 = loop.dropped(), st.admitted
+    _window_of(loop, 36, 5, 56000)
+    loop.beat(8)
+    got = loop.out[before:]
+    assert calls == [5] and _errors(loop) == err0 + 1
+    assert st.admitted == admitted0 + 4 and len(loop.newflows) == 0
+    if loop.kind == "cluster":  # the window's lanes go by shard
+        assert len(got) == 4 and set(_fids(got)) < set(range(200, 205))
+    else:
+        assert _fids(got) == [200, 201, 203, 204]
+        assert loop.dropped() == drops0 + 1
+    _window_of(loop, 42, 2, 56000)  # the drain was not cut short
+    loop.beat(8)
+    assert calls == [5, 2] and len(loop.out) == before + 6
+
+
+def test_a_create_that_raises_whole_drops_its_batch_and_no_more(loop,
+                                                                monkeypatch):
+    def boom(*cols):
+        raise ValueError("not one flow's fault")
+
+    real = loop.newflows.handle_new_flows
+    monkeypatch.setattr(loop.newflows, "handle_new_flows", boom)
+    before, err0, drops0 = len(loop.out), _errors(loop), loop.dropped()
+    loop.push(up_frame(SUB_BASE + 45, ip_to_u32("93.184.9.9"), 57000, 443, 17,
+                       400))
+    loop.push(up_frame(SUB_BASE + 46, ip_to_u32("93.184.9.9"), 57000, 443, 17,
+                       401))
+    loop.beat(6)
+    assert len(loop.out) == before and _errors(loop) == err0 + 2
+    if loop.kind != "cluster":
+        assert loop.dropped() == drops0 + 2
+    monkeypatch.setattr(loop.newflows, "handle_new_flows", real)
+    flow = (SUB_BASE + 47, ip_to_u32("93.184.9.9"), 57000, 443, 17)
+    loop.plain.open(*flow)
+    loop.push(up_frame(*flow, 402))
+    loop.beat(6)
+    assert _fids(loop.out[before:]) == [402]
 
 
 @pytest.mark.parametrize("ring_cls", [PyRing] + ([NativeRing]
